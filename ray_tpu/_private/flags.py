@@ -301,8 +301,6 @@ _SPECS = [
        "lifecycle records for external consumers); empty disables"),
     _b("RAY_TPU_USAGE_STATS_DISABLED", False,
        "Disable local usage-stats recording"),
-    _s("RTPU_JAX_CACHE_DIR", "/tmp/jax_cache",
-       "JAX persistent compilation cache directory"),
     _s("RAY_TPU_STORAGE_PATH", "",
        "Default checkpoint/result storage root for Train/Tune runs"),
     _s("RTPU_PIP_ARGS", "",

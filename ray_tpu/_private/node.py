@@ -4,16 +4,15 @@ Counterpart of /root/reference/python/ray/_private/node.py: a head node owns
 the GCS, the scheduler ("raylet-lite"), and the native shared-memory object
 store daemon, all rooted in a session directory under /tmp/ray_tpu/.
 Resource detection treats TPU chips as first-class: ``RAY_TPU_NUM_CHIPS``
-overrides, else /dev/accel* (TPU VM) or an already-imported jax backend is
-consulted — we never import jax here, since grabbing the TPU belongs to the
-worker that wins the ``TPU`` resource.
+overrides, else the chips' device files are counted.  JAX is never asked:
+starting its backend takes the chips for this process, and they belong to
+the worker that wins the ``TPU`` resource.
 """
 
 from __future__ import annotations
 
 import glob
 import os
-import sys
 import threading
 import time
 from typing import Optional
@@ -54,19 +53,11 @@ def detect_num_tpu_chips() -> int:
     env = os.environ.get("RAY_TPU_NUM_CHIPS")
     if env is not None:
         return int(env)
-    accels = glob.glob("/dev/accel*") + [
+    # one file per chip: /dev/accelN, or /dev/vfio/N where the chips are
+    # passed through (a v5e host; /dev/vfio/vfio is the control device)
+    return len(glob.glob("/dev/accel*") + [
         p for p in glob.glob("/dev/vfio/*")
-        if os.path.basename(p).isdigit()  # skip the /dev/vfio/vfio control dev
-    ]
-    if accels:
-        return len(accels)
-    jax_mod = sys.modules.get("jax")
-    if jax_mod is not None:
-        try:
-            return len([d for d in jax_mod.devices() if d.platform != "cpu"])
-        except Exception:
-            return 0
-    return 0
+        if os.path.basename(p).isdigit()])
 
 
 def default_resources() -> dict:

@@ -14,8 +14,9 @@ the reference is:
 
 The Scheduler class wires them together and serves the node's socket (worker
 registration, task completion, peer spillback, control RPCs).  TPU
-specifics: ``TPU`` is a first-class resource, and a worker granted TPU chips
-receives ``TPU_VISIBLE_CHIPS`` so concurrent JAX processes don't fight over
+specifics: ``TPU`` is a first-class resource, and a spec granted TPU chips
+runs in a process spawned for it with those chips in its environment
+(``_lease_worker``), so concurrent JAX processes don't fight over
 the same device.  The listen address may be a unix path (same-host) or
 "host:port" (multi-host TCP) — see protocol.connect_addr.
 """
@@ -25,6 +26,7 @@ from __future__ import annotations
 import itertools
 import os
 import struct
+import subprocess
 import threading
 import time
 import traceback
@@ -240,9 +242,9 @@ class Scheduler:
         self._task_index: dict[bytes, TaskSpec] = {}  # task_id -> spec (pending/running)
         self._cancelled: set[bytes] = set()  # force-cancelled running tasks
         # Physical TPU chip index allocator: grants concrete chip indices so
-        # concurrent TPU tasks never receive overlapping TPU_VISIBLE_CHIPS.
-        self._free_chips: list[int] = list(
-            range(int(node_resources.get("TPU", 0))))
+        # concurrent TPU processes never receive overlapping chips.
+        self._n_chips = int(node_resources.get("TPU", 0))
+        self._free_chips: list[int] = list(range(self._n_chips))
         self._shutdown = False
 
         # -- cluster state (multi-node) ---------------------------------
@@ -1885,6 +1887,56 @@ class Scheduler:
             # stale entry (conn dropped or worker claimed by an actor):
             # skip it; C++ already forgot dropped conns
 
+    def _lease_worker(self, spec: TaskSpec) -> Optional[WorkerState]:
+        """The worker process that runs ``spec``, or None while one has to
+        start first: the spec stays pending and is looked at again when
+        the new worker registers.
+
+        A spec granted TPU chips gets a process of its own, spawned with
+        those chips in its environment.  libtpu binds a process to its
+        chips when its backend starts and frees them only when the process
+        ends, so such a worker is never taken from the shared pool (where
+        JAX is held to the CPU) nor returned to it: it runs its one grant
+        and is ended (``_end_chip_worker``)."""
+        n_chips = int((spec.resources or {}).get("TPU", 0))
+        if n_chips < 1:
+            w = self._find_idle_worker()
+            if w is None:
+                self._pool.maybe_grow()
+            return w
+        unused = [w for w in self._workers.values()
+                  if w.alive and w.held_chips and not w.in_flight
+                  and w.actor_id is None and (w.idle or w.conn is None)]
+        for w in unused:
+            if w.conn is None and w.proc.poll() is not None:
+                # died before it registered: no connection will ever
+                # close to say so, and its chips would be lost
+                unused.remove(w)
+                self._on_worker_death(w)
+                break
+        fits = [w for w in unused if len(w.held_chips) == n_chips]
+        for w in fits:
+            if w.conn is not None:
+                return w
+        if fits:
+            return None  # still starting
+        if len(self._free_chips) >= n_chips:
+            chips = [self._free_chips.pop(0) for _ in range(n_chips)]
+            self._pool.spawn_worker(chips, self._n_chips)
+        else:
+            # a worker spawned for a spec that was cancelled meanwhile
+            # holds chips of another count: its death returns them
+            for w in unused:
+                self._pool.terminate_worker(w)
+        return None
+
+    def _end_chip_worker(self, worker: WorkerState):
+        """The grant of a chip-bound worker is over: end the process.  Its
+        resources and chips go back in ``_on_worker_death``, once it is
+        really gone."""
+        worker.idle = False
+        self._pool.terminate_worker(worker)
+
     def _native_release_worker(self, w: WorkerState):
         """Return a Python-lane leased worker to the shared idle pool."""
         if (self._raylet_native and w.conn_id is not None and w.alive
@@ -2087,7 +2139,8 @@ class Scheduler:
                 if self._raylet_native and cid is not None:
                     worker.conn_id = cid
                     self._conn_workers[cid] = worker
-                    self._node_srv.raylet_bind_worker(cid)
+                    if not worker.held_chips:  # those never join the pool
+                        self._node_srv.raylet_bind_worker(cid)
                 self._wake.notify_all()
             # GCS worker table (reference: WorkerInfoGcsService,
             # gcs_service.proto:363): lifecycle is cluster-visible and
@@ -2951,18 +3004,23 @@ class Scheduler:
                     self.gcs.update_actor(spec.actor_id, state=gcs_mod.DEAD,
                                           death_cause=msg.get("error"))
                     self._cleanup_actor_kv(spec.actor_id)
-                    self._release_worker_grants(worker)
                     worker.actor_id = None
                     self._actor_workers.pop(spec.actor_id, None)
-                    worker.idle = True
-                    self._native_release_worker(worker)
+                    self._return_worker(worker)
             elif spec.kind == TASK:
-                self._release_worker_grants(worker)
-                worker.idle = True
-                self._native_release_worker(worker)
+                self._return_worker(worker)
             # ACTOR_METHOD: worker stays bound to the actor; nothing to release.
             self._wake.notify_all()
         self._notify_origin(spec)
+
+    def _return_worker(self, worker: WorkerState):
+        """A worker's task (or failed actor creation) is over."""
+        if worker.held_chips:
+            self._end_chip_worker(worker)
+            return
+        self._release_worker_grants(worker)
+        worker.idle = True
+        self._native_release_worker(worker)
 
     def _on_native_memory_pressure(self, used: int, total: int):
         """0x7e marker from the C++ monitor: run the kill policy (the
@@ -3205,6 +3263,15 @@ class Scheduler:
         worker.held_resources = {}
         worker.held_pg = None
         if worker.held_chips:
+            # Only reached from _on_worker_death.  The connection closing
+            # is not the process ending: hand the chips on only when no
+            # process can still have them open.
+            if worker.proc is not None:
+                try:
+                    worker.proc.wait(timeout=5.0)
+                except subprocess.TimeoutExpired:
+                    worker.proc.kill()
+                    worker.proc.wait()
             self._free_chips.extend(worker.held_chips)
             self._free_chips.sort()
             worker.held_chips = []
@@ -3461,11 +3528,10 @@ class Scheduler:
                 else:
                     remaining.append(spec)
                 continue
-            w = self._find_idle_worker()
+            w = self._lease_worker(spec)
             if w is None:
                 self._return_resources(spec, granted)
                 remaining.append(spec)
-                self._pool.maybe_grow()
                 continue
             w.idle = False
             w.held_resources = granted
@@ -3521,10 +3587,11 @@ class Scheduler:
                     # this shape can't start here now — every spec
                     # behind the head would fail the same check
                     break
-                w = self._find_idle_worker()
+                w = self._lease_worker(spec)
                 if w is None:
                     self._return_resources(spec, granted)
-                    self._pool.maybe_grow()
+                    if "TPU" in (spec.resources or {}):
+                        break  # its own process is starting
                     # no idle worker: no shaped spec can dispatch
                     self._pending.prune_empty()
                     return progress
@@ -3578,15 +3645,8 @@ class Scheduler:
                 m["dispatched"].inc()
             except Exception:
                 pass
-        tpus = spec.resources.get("TPU", 0) if spec.resources else 0
-        env: dict[str, str] = {}
-        n_chips = int(tpus)
-        if n_chips >= 1 and len(self._free_chips) >= n_chips:
-            chips = [self._free_chips.pop(0) for _ in range(n_chips)]
-            w.held_chips.extend(chips)
-            env["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in chips)
         try:
-            w.conn.send({"t": "task", "spec": spec, "env": env})
+            w.conn.send({"t": "task", "spec": spec})
         except OSError:
             # Worker died between selection and send; its reader thread will
             # run _on_worker_death, which retries/fails this in-flight spec.

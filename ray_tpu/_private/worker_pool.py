@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ray_tpu._private.protocol import Connection
+from ray_tpu.util.accelerators.tpu import chip_env
 
 
 @dataclass
@@ -44,7 +45,10 @@ class WorkerState:
     # Native-lane in-flight count, refreshed by _handle_memory_pressure
     # before victim selection (C++ owns the authoritative table).
     native_inflight: int = 0
-    held_chips: list = field(default_factory=list)  # physical TPU chip indices
+    # Physical TPU chip indices this PROCESS was spawned with.  Such a
+    # worker runs one grant (a task, or an actor for its life) and is then
+    # ended: libtpu frees a chip only when its process ends.
+    held_chips: list = field(default_factory=list)
 
 
 class WorkerPool:
@@ -77,10 +81,18 @@ class WorkerPool:
     def logs_dir(self) -> str:
         return os.path.join(os.path.dirname(self.store_socket), "logs")
 
-    def spawn_worker(self) -> WorkerState:
+    def spawn_worker(self, chips: Optional[list] = None,
+                     chips_on_node: int = 0) -> WorkerState:
+        """Start a worker process.  ``chips`` binds it to those TPU chips;
+        without them JAX in the worker is held to the CPU, so that a
+        process that was granted no chip can never take one."""
         worker_id = os.urandom(8)
         env = dict(os.environ)
         env.update(self.worker_env)
+        if chips:
+            env.update(chip_env(chips, chips_on_node))
+        else:
+            env["JAX_PLATFORMS"] = "cpu"
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
@@ -112,19 +124,22 @@ class WorkerPool:
         finally:
             out.close()  # the child holds its own descriptors now
             err.close()
-        w = WorkerState(worker_id=worker_id, proc=proc)
+        w = WorkerState(worker_id=worker_id, proc=proc,
+                        held_chips=list(chips or ()))
         self.workers[worker_id] = w
         return w
 
     def find_idle_worker(self) -> Optional[WorkerState]:
         for w in self.workers.values():
-            if w.alive and w.idle and w.conn is not None and w.actor_id is None:
+            if (w.alive and w.idle and w.conn is not None
+                    and w.actor_id is None and not w.held_chips):
                 return w
         return None
 
     def maybe_grow(self):
         n_normal = len([w for w in self.workers.values()
-                        if w.alive and w.actor_id is None])
+                        if w.alive and w.actor_id is None
+                        and not w.held_chips])
         if n_normal < self.max_workers:
             self.spawn_worker()
 

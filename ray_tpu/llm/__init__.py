@@ -6,35 +6,33 @@ engine here is native — paged KV cache, bucketed prefill, one compiled
 decode step, continuous batching (engine.py, model.py, paged_cache.py) —
 served OpenAI-compatibly on ray_tpu.serve (server.py) and over Datasets
 (batch.py).
+
+Names resolve on first use: the configuration records and the application
+builder need no JAX, so a driver that only describes a deployment never
+imports it (the replica that is granted the chip does).
 """
 
-from ray_tpu.llm.batch import ProcessorConfig, build_llm_processor
-from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.llm.paged_cache import CacheConfig, PageAllocator
-from ray_tpu.llm.pd_disagg import (
-    DecodeServer,
-    PDRouter,
-    PrefillServer,
-    build_pd_openai_app,
-)
-from ray_tpu.llm.server import LLMConfig, LLMServer, build_openai_app
-from ray_tpu.llm.tokenizer import ByteTokenizer, get_tokenizer
+import importlib
 
-__all__ = [
-    "ByteTokenizer",
-    "CacheConfig",
-    "EngineConfig",
-    "LLMConfig",
-    "LLMEngine",
-    "LLMServer",
-    "PageAllocator",
-    "ProcessorConfig",
-    "SamplingParams",
-    "DecodeServer",
-    "PDRouter",
-    "PrefillServer",
-    "build_llm_processor",
-    "build_openai_app",
-    "build_pd_openai_app",
-    "get_tokenizer",
-]
+_HOME = {
+    "ProcessorConfig": "batch", "build_llm_processor": "batch",
+    "EngineConfig": "config", "SamplingParams": "config",
+    "LLMEngine": "engine",
+    "CacheConfig": "paged_cache", "PageAllocator": "paged_cache",
+    "DecodeServer": "pd_disagg", "PDRouter": "pd_disagg",
+    "PrefillServer": "pd_disagg", "build_pd_openai_app": "pd_disagg",
+    "LLMConfig": "server", "LLMServer": "server",
+    "build_openai_app": "server",
+    "ByteTokenizer": "tokenizer", "get_tokenizer": "tokenizer",
+}
+
+__all__ = sorted(_HOME)
+
+
+def __getattr__(name: str):
+    home = _HOME.get(name)
+    if home is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(importlib.import_module(f"{__name__}.{home}"), name)
+    globals()[name] = value
+    return value

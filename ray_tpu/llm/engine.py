@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.llm import model as lm
+from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
                                      init_cache)
@@ -135,31 +136,6 @@ def _inject_kv_pages_impl(cache_k, cache_v, idx, kv_k, kv_v):
 
 
 _inject_kv_pages = jax.jit(_inject_kv_pages_impl, donate_argnums=(0, 1))
-
-
-@dataclass
-class EngineConfig:
-    max_slots: int = 8  # concurrent sequences in the decode batch
-    num_pages: int = 512
-    page_size: int = 16
-    max_seq_len: int = 1024
-    prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024)
-
-    def bucket_for(self, n: int) -> int:
-        for b in self.prefill_buckets:
-            if n <= b:
-                return b
-        raise ValueError(f"prompt length {n} exceeds largest bucket "
-                         f"{self.prefill_buckets[-1]}")
-
-
-@dataclass
-class SamplingParams:
-    max_tokens: int = 64
-    temperature: float = 0.0  # 0 => greedy
-    top_p: float = 1.0
-    stop_token_ids: tuple = ()
-    seed: Optional[int] = None
 
 
 @dataclass
@@ -1136,7 +1112,7 @@ class LLMEngine:
         all_greedy = all(s.request.params.temperature <= 0
                          for _, s in active_slots)
         # Burst decode: chain several device-fed greedy steps and fetch
-        # once.  The host round trip (PCIe/tunnel) costs many times the
+        # once.  The host round trip (device to host) costs many times the
         # decode compute itself; each step's argmax token feeds the
         # next step ON DEVICE.  Overshoot is safe: a slot that finishes
         # mid-burst keeps writing into its own (or the null) pages and

@@ -17,9 +17,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ray_tpu import serve
 from ray_tpu.llm import kv_tier as kv_tier_mod
-from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.tokenizer import get_tokenizer
-from ray_tpu.models.llama import LlamaConfig
 
 
 @dataclass
@@ -41,6 +40,10 @@ class LLMServer:
     """The engine-owning deployment (one engine per replica)."""
 
     def __init__(self, llm_config: LLMConfig):
+        # JAX comes in here, in the replica that holds the chip, and not
+        # with the module: a driver describes the app without it
+        from ray_tpu.llm.engine import LLMEngine
+
         self._config = llm_config
         if llm_config.model_loader is None:
             raise ValueError("LLMConfig.model_loader is required")

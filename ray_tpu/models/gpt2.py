@@ -122,7 +122,7 @@ def layer_norm(x, g, b, eps):
     return (out * g + b).astype(x.dtype)
 
 
-def _layer(cfg: GPT2Config, x, p, attn_impl):
+def _layer(cfg: GPT2Config, x, p, attn_impl, mesh, rules):
     b, s, d = x.shape
     h = layer_norm(x, p["ln1_g"], p["ln1_b"], cfg.norm_eps)
     qkv = h @ p["attn"]["wqkv"].astype(h.dtype) + p["attn"]["bqkv"].astype(
@@ -130,7 +130,8 @@ def _layer(cfg: GPT2Config, x, p, attn_impl):
     q, k, v = jnp.split(qkv, 3, axis=-1)
     shape = (b, s, cfg.n_heads, cfg.head_dim)
     attn = flash_attention(q.reshape(shape), k.reshape(shape),
-                           v.reshape(shape), causal=True, impl=attn_impl)
+                           v.reshape(shape), causal=True, impl=attn_impl,
+                           mesh=mesh, rules=rules)
     attn = attn.reshape(b, s, d)
     x = x + attn @ p["attn"]["wo"].astype(h.dtype) + p["attn"]["bo"].astype(
         h.dtype)
@@ -143,13 +144,14 @@ def _layer(cfg: GPT2Config, x, p, attn_impl):
     return x
 
 
-def trunk(params, tokens, cfg: GPT2Config, attn_impl: str = "auto"):
+def trunk(params, tokens, cfg: GPT2Config, attn_impl: str = "auto",
+          mesh=None, rules=None):
     """Embeddings -> final layer norm, WITHOUT the LM head: (b, s, d)."""
     dtype = jnp.dtype(cfg.dtype)
     s = tokens.shape[1]
     x = (params["wte"][tokens] + params["wpe"][:s][None]).astype(dtype)
 
-    step = partial(_layer, cfg, attn_impl=attn_impl)
+    step = partial(_layer, cfg, attn_impl=attn_impl, mesh=mesh, rules=rules)
     if cfg.remat:
         step = jax.checkpoint(step)
 
@@ -160,17 +162,20 @@ def trunk(params, tokens, cfg: GPT2Config, attn_impl: str = "auto"):
     return layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.norm_eps)
 
 
-def apply(params, tokens, cfg: GPT2Config, attn_impl: str = "auto"):
-    x = trunk(params, tokens, cfg, attn_impl)
+def apply(params, tokens, cfg: GPT2Config, attn_impl: str = "auto",
+          mesh=None, rules=None):
+    x = trunk(params, tokens, cfg, attn_impl, mesh=mesh, rules=rules)
     # tied LM head: bf16 operands with fp32 accumulation — the MXU's
     # native mode (an fp32 matmul here halves the headline throughput)
     return jnp.dot(x, params["wte"].T.astype(x.dtype),
                    preferred_element_type=jnp.float32)
 
 
-def loss_fn(params, tokens, cfg: GPT2Config, attn_impl: str = "auto"):
+def loss_fn(params, tokens, cfg: GPT2Config, attn_impl: str = "auto",
+            mesh=None, rules=None):
     from ray_tpu.models.losses import chunked_softmax_xent
 
-    x = trunk(params, tokens[:, :-1], cfg, attn_impl)
+    x = trunk(params, tokens[:, :-1], cfg, attn_impl, mesh=mesh,
+              rules=rules)
     return chunked_softmax_xent(x, params["wte"].T, tokens[:, 1:],
                                 chunk=cfg.loss_chunk)

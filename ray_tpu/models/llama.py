@@ -157,7 +157,8 @@ def _attention(q, k, v, attn_impl, mesh, rules=None):
             raise ValueError(f"attn_impl={attn_impl!r} requires a mesh")
         return sequence_parallel_attention(q, k, v, mesh, impl=attn_impl,
                                            causal=True, rules=rules)
-    return flash_attention(q, k, v, causal=True, impl=attn_impl)
+    return flash_attention(q, k, v, causal=True, impl=attn_impl, mesh=mesh,
+                           rules=rules)
 
 
 def _layer(cfg: LlamaConfig, x, layer_params, positions, attn_impl, mesh,

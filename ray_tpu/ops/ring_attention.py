@@ -33,16 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import NEG_INF, flash_attention, repeat_kv_heads
-from ray_tpu.parallel.sharding import shard_map, to_partition_spec
-
-
-def _axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` across versions: older jax lacks it; there
-    ``psum(1, axis)`` is statically resolved to the same number."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+from ray_tpu.parallel.sharding import to_partition_spec
 
 
 def _shard_positions(idx, s_loc: int, sp: int, layout: str):
@@ -103,7 +94,7 @@ def ring_attention(
     ``impl="zigzag"`` uses; correctness is exact for both layouts (masks
     compare true global positions).
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     if sm_scale is None:
@@ -177,7 +168,7 @@ def ulysses_attention(
     holds the FULL sequence for heads/sp heads and runs dense (flash)
     attention locally; a reverse all-to-all restores sequence sharding.
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % sp != 0:
         raise ValueError(f"ulysses needs heads ({h}) % sp ({sp}) == 0")
@@ -232,7 +223,8 @@ def sequence_parallel_attention(
     with layout="zigzag" directly and skip both gathers.
     """
     if mesh.shape.get(sp_axis, 1) == 1:
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                               mesh=mesh, rules=rules)
 
     q_spec = to_partition_spec(("batch", "seq", "heads", "head_dim"), rules)
     kv_spec = to_partition_spec(("batch", "seq", "kv_heads", "head_dim"),
@@ -255,7 +247,7 @@ def sequence_parallel_attention(
             ql, kl, vl, sp_axis, causal=causal, sm_scale=sm_scale,
             layout="zigzag" if impl == "zigzag" else "contiguous")
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,

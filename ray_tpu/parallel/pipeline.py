@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel.sharding import shard_map, to_partition_spec
+from ray_tpu.parallel.sharding import to_partition_spec
 
 
 def pipeline_apply(
@@ -113,7 +113,7 @@ def pipeline_apply(
         outputs = jax.lax.psum(outputs * mask, pp_axis)
         return outputs.reshape(b_local, *x_local.shape[1:])
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(params_specs, x_spec),
         out_specs=x_spec,

@@ -91,17 +91,3 @@ def shard_tree(tree, logical_tree, mesh: Mesh, rules: Optional[dict] = None):
     """Device-put a pytree according to its logical specs."""
     shardings = named_shardings(logical_tree, mesh, rules)
     return jax.tree.map(jax.device_put, tree, shardings)
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: older releases only ship it
-    as ``jax.experimental.shard_map`` and spell ``check_vma`` as
-    ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)

@@ -30,14 +30,24 @@ from ray_tpu.train.context import (
 from ray_tpu.train.controller import Result, TrainController, TrainingFailedError
 from ray_tpu.train.gbdt import LightGBMTrainer, XGBoostTrainer
 from ray_tpu.train.torch import TorchConfig, TorchTrainer
-from ray_tpu.train.step import (
-    create_train_state,
-    data_sharding,
-    default_optimizer,
-    make_train_step,
-)
 from ray_tpu.train.trainer import DataParallelTrainer, JaxTrainer
 from ray_tpu.train.worker_group import TrainWorker, WorkerGroup
+
+# The step builders are the only part that needs JAX.  They resolve on first
+# use, so that a driver that describes a trainer never imports it: the
+# worker that is granted the chip does.
+_STEP = ("create_train_state", "data_sharding", "default_optimizer",
+         "make_train_step")
+
+
+def __getattr__(name: str):
+    if name not in _STEP:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    from ray_tpu.train import step
+
+    value = globals()[name] = getattr(step, name)
+    return value
+
 
 __all__ = [
     "LightGBMTrainer",

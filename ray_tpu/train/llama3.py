@@ -84,15 +84,9 @@ def llama3_train_loop(config: dict):
         state = create_train_state(llama, cfg, mesh, opt,
                                    jax.random.PRNGKey(config.get("seed", 0)))
         # Pin the output state to the input layout: the step is AOT-compiled
-        # below and iterated, so it must be a sharding fixed point.  Scalar
-        # leaves (the step counter) come back single-device — replicate
-        # them over the mesh so input and output trees agree.
+        # below and iterated, so it must be a sharding fixed point.
         rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-        state_sh = jax.tree_util.tree_map(
-            lambda x: x.sharding
-            if isinstance(x.sharding, jax.sharding.NamedSharding) else rep,
-            state)
-        state = jax.device_put(state, state_sh)
+        state_sh = jax.tree_util.tree_map(lambda x: x.sharding, state)
         step = make_train_step(llama, cfg, mesh, opt,
                                attn_impl=config.get("attn_impl", "flash"),
                                out_shardings=(state_sh, rep))

@@ -25,8 +25,34 @@ def data_sharding(mesh: Mesh, rules: Optional[dict] = None) -> NamedSharding:
     return NamedSharding(mesh, to_partition_spec(("batch", "seq"), rules))
 
 
-def create_train_state(
+def train_state_shardings(
     model: Any,  # module with init/param_logical_specs
+    cfg: Any,
+    mesh: Mesh,
+    optimizer: optax.GradientTransformation,
+    rules: Optional[dict] = None,
+):
+    """Layout of the train state on the mesh, as a tree of shardings.
+
+    Every copy of the parameter tree inside the optimizer state (adam's
+    moments) is laid out like the parameters, and the counters are
+    replicated.  It has to be said: nothing in ``optimizer.init`` depends
+    on the parameters' values, so left to propagation the whole optimizer
+    state lands on the first device.
+    """
+    params = named_shardings(model.param_logical_specs(cfg), mesh, rules)
+    replicated = NamedSharding(mesh, P())
+    abstract_params = jax.eval_shape(
+        lambda k: model.init(cfg, k), jax.random.PRNGKey(0))
+    opt_state = optax.tree_utils.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, abstract_params), params,
+        transform_non_params=lambda _: replicated)
+    return {"params": params, "opt_state": opt_state, "step": replicated}
+
+
+def create_train_state(
+    model: Any,
     cfg: Any,
     mesh: Mesh,
     optimizer: optax.GradientTransformation,
@@ -35,16 +61,15 @@ def create_train_state(
 ):
     """Initialize sharded params + optimizer state on the mesh.
 
-    Params are materialized directly into their shards (init runs under jit
-    with output shardings, so no host-side full copy exists); the optimizer
-    state inherits the param shardings by propagation.
+    Everything is materialized directly into its shards (init runs under
+    jit with output shardings, so no host-side full copy exists).
     """
-    param_shardings = named_shardings(
-        model.param_logical_specs(cfg), mesh, rules)
+    layout = train_state_shardings(model, cfg, mesh, optimizer, rules)
     params = jax.jit(
-        lambda k: model.init(cfg, k), out_shardings=param_shardings)(key)
-    opt_state = jax.jit(optimizer.init)(params)
-    step = jnp.zeros((), jnp.int32)
+        lambda k: model.init(cfg, k), out_shardings=layout["params"])(key)
+    opt_state = jax.jit(
+        optimizer.init, out_shardings=layout["opt_state"])(params)
+    step = jax.device_put(jnp.zeros((), jnp.int32), layout["step"])
     return {"params": params, "opt_state": opt_state, "step": step}
 
 
@@ -62,7 +87,8 @@ def make_train_step(
     """Build the jitted SPMD train step: (state, batch) -> (state, metrics).
 
     attn_impl "ring"/"ulysses" enables sequence-parallel attention over the
-    mesh's sp axis (model must accept attn_impl/mesh kwargs in loss_fn).
+    mesh's sp axis.  The model's loss_fn takes attn_impl/mesh/rules: the
+    attention kernel needs the mesh to run per shard (ops/attention.py).
 
     ``out_shardings`` (a pytree prefix for ``(new_state, metrics)``) pins
     the output layout.  Required when the step is AOT-compiled and called
@@ -70,11 +96,9 @@ def make_train_step(
     and the fixed executable then rejects its own output as input.
     """
     if loss_fn is None:
-        loss_kwargs = {}
+        loss_kwargs = {"mesh": mesh, "rules": rules}
         if attn_impl is not None:
             loss_kwargs["attn_impl"] = attn_impl
-        if attn_impl in ("ring", "zigzag", "ulysses"):
-            loss_kwargs.update(mesh=mesh, rules=rules)
         loss = lambda p, b: model.loss_fn(p, b, cfg, **loss_kwargs)  # noqa: E731
     else:
         loss = loss_fn

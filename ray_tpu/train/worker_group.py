@@ -12,7 +12,6 @@ torch.distributed process groups.
 
 from __future__ import annotations
 
-import os
 import socket
 import threading
 import traceback
@@ -60,20 +59,6 @@ class TrainWorker:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,
                 num_processes=world_size, process_id=rank)
-        # Persistent compilation cache: elastic re-meshing recompiles the
-        # train step per mesh shape — cache hits make resuming at a
-        # previously-seen world size near-instant (SURVEY §7 "cached
-        # compilations per mesh shape").
-        try:
-            import jax
-
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.environ.get("RTPU_JAX_CACHE_DIR", "/tmp/jax_cache"))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0)
-        except Exception:
-            pass
         return True
 
     def run(self, fn_blob: bytes, config: Optional[dict]) -> bool:

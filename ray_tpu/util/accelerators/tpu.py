@@ -33,6 +33,28 @@ TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 TPU_ACCELERATOR_TYPE_ENV = "TPU_ACCELERATOR_TYPE"
 TPU_WORKER_HOSTNAMES_ENV = "TPU_WORKER_HOSTNAMES"
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
+
+
+def chip_env(chips: list[int], chips_on_node: int) -> dict[str, str]:
+    """Environment that binds a NEW process to ``chips`` of this host.
+
+    libtpu reads it once, when the process first starts its backend, so it
+    is set at spawn and never changed.  A process granted every chip of
+    the host runs with the machine's own defaults.  Anything less is a
+    sub-slice: the chip list alone lets two processes collide on libtpu's
+    lock file, so the process is also told that its "host" is just those
+    chips (reference: _private/accelerators/tpu.py
+    set_current_process_visible_accelerator_ids).
+    """
+    if len(chips) == chips_on_node:
+        return {}
+    return {
+        TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chips),
+        TPU_CHIPS_PER_HOST_BOUNDS_ENV: f"1,{len(chips)},1",
+        TPU_HOST_BOUNDS_ENV: "1,1,1",
+    }
 
 
 def parse_accelerator_type(accelerator_type: str) -> tuple[str, int]:

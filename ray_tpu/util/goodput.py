@@ -137,14 +137,10 @@ def analytic_step_flops(n_params: int, tokens: int) -> float:
 def compiled_flops(compiled) -> Optional[float]:
     """Counted flops from a compiled executable's cost analysis, or None.
 
-    Accepts anything with ``cost_analysis()`` (jax ``Compiled`` objects);
-    tolerates the list-of-dicts shape older jax returns.
+    Accepts anything with ``cost_analysis()`` (jax ``Compiled`` objects).
     """
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0) or 0.0)
+        flops = float(compiled.cost_analysis().get("flops", 0.0) or 0.0)
         return flops if flops > 0 else None
     except Exception:
         return None

@@ -13,7 +13,6 @@ from ray_tpu.parallel.mesh import (
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     logical_spec,
-    shard_map,
     to_partition_spec,
 )
 
@@ -95,9 +94,9 @@ def test_dcn_multi_slice_mesh():
         return jax.lax.psum(v, ("dcn", "fsdp"))
 
     out = jax.jit(
-        shard_map(summed, mesh=mesh,
-                  in_specs=P(("dcn", "dp", "fsdp")),
-                  out_specs=P(("dcn", "dp", "fsdp"))))(xs)
+        jax.shard_map(summed, mesh=mesh,
+                      in_specs=P(("dcn", "dp", "fsdp")),
+                      out_specs=P(("dcn", "dp", "fsdp"))))(xs)
     assert out.shape == x.shape
 
 
@@ -127,3 +126,37 @@ def test_dcn_train_step_dp_across_slices():
         state, metrics = step(state, tokens)
         loss = float(metrics["loss"])
     assert jnp.isfinite(loss)
+
+
+def test_optimizer_state_is_laid_out_like_the_parameters():
+    """Nothing in ``optimizer.init`` depends on the parameters' values, so
+    left to propagation adam's moments all land on the first device (64 GB
+    of them for an 8B model).  They must follow the parameters instead, and
+    no device may hold more than its share."""
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+    from ray_tpu.train.step import create_train_state, default_optimizer
+
+    devices = jax.devices()[:4]
+    mesh = create_mesh(MeshConfig(fsdp=2, tp=2), devices=devices)
+    cfg = llama.LlamaConfig.tiny()
+    with mesh:
+        state = create_train_state(llama, cfg, mesh, default_optimizer(),
+                                   jax.random.PRNGKey(0))
+    adam = state["opt_state"][1][0]
+    params = jax.tree.leaves(state["params"])
+    for moments in (adam.mu, adam.nu):
+        for moment, param in zip(jax.tree.leaves(moments), params,
+                                 strict=True):
+            assert moment.sharding == param.sharding
+    held = {d.id: 0 for d in devices}
+    total = 0
+    for leaf in jax.tree.leaves(state):
+        assert leaf.sharding.device_set == set(devices)
+        total += leaf.nbytes
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] += shard.data.nbytes
+    # the norms and counters are replicated; everything else is quartered
+    assert max(held.values()) < 0.3 * total, held

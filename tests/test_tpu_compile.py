@@ -1,0 +1,223 @@
+"""Compile the main path for a TPU v5e that is described, not attached.
+
+The chip's compiler is installed here and compiles for a ``v5e:2x2``
+topology without one (on-chip-measurement guide, section 2).  Interpret-mode
+tests cannot see what it refuses: a block that is not aligned to the tiling,
+a kernel that wants more fast memory than there is, a Mosaic kernel that
+GSPMD is asked to partition.  Nothing runs, so these say nothing about
+results or speed; they keep every later PR from shipping a kernel the chip
+would turn down.  Skipped where the topology cannot be described.
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.llm import model as lm
+from ray_tpu.models import llama
+from ray_tpu.ops import attention
+from ray_tpu.parallel.mesh import AXIS_ORDER
+from ray_tpu.train.step import (
+    default_optimizer,
+    make_train_step,
+    train_state_shardings,
+)
+
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile against
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out (guide, section 2).
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` placed by ``sharding`` (one, or a matching tree)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _flash_grad(causal=True):
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, causal=causal, impl="pallas")
+        return out.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """``flash_attention`` asks ``jax.default_backend()``, which is the CPU
+    here, so the test (not the program) steers it to the compiled kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("batch,seq,heads,head_dim", [
+    (12, 1024, 12, 64),    # GPT-2 124M, the smoke's train phase
+    (1, 2048, 32, 128),    # Llama-3-8B heads at the four-chip step's length
+    # shapes the chip's compiler refused before the operands were blocked
+    # and padded: whole-sequence operands past VMEM, and a length that is
+    # not a multiple of 8
+    (1, 8192, 32, 128),
+    (1, 32768, 4, 128),
+    (1, 100, 4, 64),
+])
+def test_flash_forward_and_backward_compile(topo, as_tpu, batch, seq, heads,
+                                            head_dim):
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((batch, seq, heads, head_dim), jnp.bfloat16,
+                             sharding=one)
+    compiled = jax.jit(_flash_grad()).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dkv, dq
+
+
+def _smoke_llama(n_layers):
+    return dataclasses.replace(llama.LlamaConfig.llama3_8b(),
+                               n_layers=n_layers, remat=False)
+
+
+def _serving_shapes(cfg, num_pages=1024, page_size=16):
+    params = jax.eval_shape(
+        lambda k: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                               llama.init(cfg, k)), jax.random.PRNGKey(0))
+    cache = jax.ShapeDtypeStruct(
+        (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim),
+        jnp.bfloat16)
+    return params, cache
+
+
+def _footprint(compiled):
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_engine_prefill_and_decode_compile_at_llama_widths(topo):
+    """The engine's two programs at full Llama-3-8B widths and the smoke's
+    depth fit one chip next to the weights and the page pool."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = _smoke_llama(n_layers=16)
+    params, cache = _on(one, _serving_shapes(cfg))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    bucket, slots, pages_per_seq = 128, 8, 64
+    prefill = lm.prefill.lower(
+        params, i32(bucket), cache, cache, i32(bucket), i32(), i32(bucket),
+        cfg).compile()
+    decode = lm.decode_step_greedy.lower(
+        params, i32(slots), cache, cache, i32(slots, pages_per_seq),
+        i32(slots), jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one),
+        cfg).compile()
+    for program in (prefill, decode):
+        assert _footprint(program) < 0.8 * HBM_BYTES
+
+
+def _train_step(model, cfg, mesh, batch, seq, attn_impl=None):
+    """The step ``create_train_state``/``make_train_step`` build, compiled
+    from shapes laid out as ``create_train_state`` lays out arrays."""
+    opt = default_optimizer()
+    params = jax.eval_shape(lambda k: model.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    state = _on(train_state_shardings(model, cfg, mesh, opt),
+                {"params": params,
+                 "opt_state": jax.eval_shape(opt.init, params),
+                 "step": jax.ShapeDtypeStruct((), jnp.int32)})
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32,
+                                  sharding=NamedSharding(mesh, P()))
+    step = make_train_step(model, cfg, mesh, opt, attn_impl=attn_impl)
+    with mesh:
+        return step.lower(state, tokens).compile()
+
+
+def test_fsdp_tp_flash_step_compiles_on_four_chips(topo, as_tpu):
+    """The north-star recipe's layout (fsdp x tp, ``attn_impl="flash"``):
+    GSPMD cannot partition a Mosaic kernel, so without the shard_map around
+    it this is ``NotImplementedError: Mosaic kernels cannot be
+    automatically partitioned``."""
+    shape = {"fsdp": 2, "tp": 2}
+    mesh = Mesh(np.array(topo.devices).reshape(
+        tuple(shape.get(a, 1) for a in AXIS_ORDER)), AXIS_ORDER)
+    # the recipe's own small stand-in (same GQA ratio and sharding
+    # structure): at full widths this compile takes 19 s, and the chip run
+    # (chip_smoke.py --chips 4) is what checks those
+    compiled = _train_step(llama, llama.LlamaConfig.llama3_8b_dry(), mesh,
+                           batch=2, seq=512, attn_impl="flash")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text  # fsdp parameters
+    assert "all-reduce" in text or "reduce-scatter" in text  # grads, tp
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,match", [
+    ((3, 256, 8, 64), 8, r"batch \(3\).*multiple"),
+    ((2, 256, 6, 64), 6, r"heads \(6\).*multiple"),
+    ((2, 256, 8, 64), 2, r"kv_heads \(2\).*multiple"),
+])
+def test_flash_on_a_mesh_refuses_unsplittable_shapes_clearly(
+        q_shape, kv_heads, match):
+    """What the sharded kernel path cannot take is refused in Python, by
+    name, and not by a Mosaic or GSPMD stack trace."""
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(
+        tuple({"fsdp": 2, "tp": 4}.get(a, 1) for a in AXIS_ORDER)),
+        AXIS_ORDER)
+    q = jnp.zeros(q_shape, jnp.bfloat16)
+    kv = jnp.zeros(q_shape[:2] + (kv_heads, q_shape[3]), jnp.bfloat16)
+    with pytest.raises(ValueError, match=match):
+        attention.flash_attention(q, kv, kv, impl="pallas", mesh=mesh)
+
+
+def test_heads_must_be_a_multiple_of_kv_heads():
+    q = jnp.zeros((1, 128, 6, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, 128, 4, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="multiple of kv_heads"):
+        attention.flash_attention(q, kv, kv)
+
+
+def test_sharded_flash_matches_reference_on_virtual_devices():
+    """The shard_map path computes what the one-device path computes
+    (interpreted kernels on the CPU's virtual devices)."""
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(
+        tuple({"fsdp": 2, "tp": 2}.get(a, 1) for a in AXIS_ORDER)),
+        AXIS_ORDER)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (2, 200, 4, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 200, 2, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 200, 2, 32), jnp.float32)
+
+    def loss(impl, mesh):
+        return lambda q, k, v: jnp.sum(attention.flash_attention(
+            q, k, v, impl=impl, mesh=mesh) ** 2)
+
+    want = jax.grad(loss("xla", None), argnums=(0, 1, 2))(q, k, v)
+    with mesh:
+        got = jax.jit(jax.grad(loss("pallas", mesh), argnums=(0, 1, 2)))(
+            q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4,
+                                   rtol=2e-4)
