@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU: set it before anything imports
+jax, and make the repository importable."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
