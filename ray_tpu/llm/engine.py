@@ -17,7 +17,6 @@ import queue as queue_mod
 import threading
 import time
 import uuid
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -138,6 +137,172 @@ def _inject_kv_pages_impl(cache_k, cache_v, idx, kv_k, kv_v):
 _inject_kv_pages = jax.jit(_inject_kv_pages_impl, donate_argnums=(0, 1))
 
 
+# ---------------------------------------------------------------------------
+# Engine-loop anatomy (ISSUE 24): what the scheduler THREAD was doing, next
+# to the per-request spans that say who was waiting.  Each iteration of
+# _loop is cut into phases that do not overlap and together cover it.  The
+# names are read by PERF.md and benchmarks/trace/host_phases.py.
+
+P_HYDRATE = "llm.loop.hydrate"
+P_ADMIT = "llm.loop.admit"
+P_PREFILL_HOST = "llm.loop.prefill_host"
+P_PREFILL_DISPATCH = "llm.loop.prefill_dispatch"
+P_PREFILL_FETCH = "llm.loop.prefill_fetch"
+P_PREFILL_EMIT = "llm.loop.prefill_emit"
+P_DECODE_HOST = "llm.loop.decode_host"
+P_DECODE_DISPATCH = "llm.loop.decode_dispatch"
+P_DECODE_FETCH = "llm.loop.decode_fetch"
+P_DECODE_EMIT = "llm.loop.decode_emit"
+P_GAUGES = "llm.loop.gauges"
+P_IDLE = "llm.loop.idle"
+S_LOOP = "llm.loop"  # an iteration's umbrella span
+S_COMPILE = "xla.compile"
+
+# what the values a call site leaves in ``vals`` are called in the record
+_PHASE_ATTRS = {
+    P_HYDRATE: ("pages",), P_ADMIT: ("outcome",),
+    P_PREFILL_HOST: ("bucket", "prefix_len"),
+    P_PREFILL_DISPATCH: ("bucket",), P_PREFILL_FETCH: (),
+    P_PREFILL_EMIT: (), P_DECODE_HOST: ("active_slots", "burst"),
+    P_DECODE_DISPATCH: ("burst",), P_DECODE_FETCH: ("burst",),
+    P_DECODE_EMIT: ("tokens", "slots_released"), P_GAUGES: (),
+    S_COMPILE: ("seconds", "phase", "program"),
+}
+
+# A phase (other than idle) that lasts longer is reported as an
+# ``llm.loop_stall`` event: a burst's decode_fetch is 0.9 s at most.
+STALL_S = 2.0
+
+_mono = time.monotonic  # the loop's one clock (a test stretches it)
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_sampled_loops: Dict[int, "_LoopPhases"] = {}  # engine thread -> its phases
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_compile(name: str, secs: float, **kw) -> None:
+    """``jax.monitoring`` listener: a backend compilation that ran on a
+    sampled engine thread goes on that loop's timeline."""
+    if name == _COMPILE_EVENT:
+        ph = _sampled_loops.get(threading.get_ident())
+        if ph is not None:
+            ph.note_compile(secs, kw.get("fun_name"))
+
+
+class _LoopPhases:
+    """The phase open on one engine thread, and (when the loop is sampled)
+    the records of the iteration so far.  Only that thread touches it.
+
+    ``begin`` closes the open phase and opens the next at the same reading
+    of the clock, so phases are disjoint and cover the iteration by
+    construction.  Every phase is a ``jax.profiler.TraceAnnotation``: in a
+    profiler session it lands on the host plane, on the profiler's clock,
+    beside the device's operations; with no session that is a flag test.
+    When the loop is NOT sampled nothing else happens: no record, no dict,
+    no lock, no other clock.  When it is, a working iteration's phases are
+    banked through ``tracing.record_span`` under one ``llm.loop`` span, in
+    traces of the loop's own (``tracing.LoopTrace``)."""
+
+    __slots__ = ("sampled", "it", "name", "t0", "req", "vals", "_ann",
+                 "_done", "_trace", "_idle_t0", "_idle_it", "_idle_n",
+                 "_slots")
+
+    def __init__(self, slots: list):
+        self.sampled = False
+        self.it = 0  # iteration number, on every annotation and record
+        self.name: Optional[str] = None
+        self.t0 = 0.0
+        self.req: Optional[_Request] = None  # whom the open phase serves
+        self.vals: tuple = ()  # its attributes, by _PHASE_ATTRS[name]
+        self._ann = None
+        self._done: list = []  # (name, t0, t1, req, vals), sampled only
+        self._trace = tracing.LoopTrace()
+        self._idle_t0: Optional[float] = None
+        self._idle_it = self._idle_n = 0
+        self._slots = slots  # the engine's slot table, for the stall event
+
+    def begin(self, name: str, req: Optional[_Request] = None,
+              vals: tuple = ()) -> None:
+        """Open ``name``; attributes known only at its end go to ``vals``
+        before the next ``begin``."""
+        t = _mono()
+        if self.name is not None:
+            self._close(t)
+        self.name, self.t0, self.req, self.vals = name, t, req, vals
+        self._ann = jax.profiler.TraceAnnotation(name, it=self.it)
+        self._ann.__enter__()
+
+    def end(self) -> None:
+        if self.name is not None:
+            self._close(_mono())
+            self.name = self.req = None
+
+    def _close(self, t: float) -> None:
+        self._ann.__exit__(None, None, None)
+        if self.sampled:
+            self._done.append((self.name, self.t0, t, self.req, self.vals))
+            if t - self.t0 > STALL_S:
+                self._stall(t - self.t0)
+
+    def finish_iteration(self, worked: bool) -> None:
+        """Close the iteration: bank its phases if it did anything, else
+        fold it into the one idle span that lasts until work is found."""
+        self.end()
+        if not self.sampled:
+            return
+        now = _mono()
+        start = min((r[1] for r in self._done), default=now)
+        if not worked:
+            if self._idle_t0 is None:
+                self._idle_t0, self._idle_it, self._idle_n = start, self.it, 0
+            self._idle_n += 1
+        else:
+            wall = time.time() - now  # wall time = the loop's clock + this
+            self.flush_idle(start, wall)
+            tid = self._trace.take(len(self._done) + 1)
+            loop_id = tracing.record_span(
+                tid, S_LOOP, start + wall, self._done[-1][2] + wall,
+                kind="engine", attrs={"it": self.it})
+            for name, t0, t1, req, vals in self._done:
+                attrs = dict(zip(_PHASE_ATTRS[name], vals), it=self.it)
+                if req is not None:
+                    attrs["request_id"] = req.request_id
+                    if req.trace_ctx is not None:
+                        attrs["request_trace_id"] = req.trace_ctx[0]
+                tracing.record_span(tid, name, t0 + wall, t1 + wall,
+                                    parent_id=loop_id, kind="engine",
+                                    attrs=attrs)
+        self._done.clear()
+
+    def flush_idle(self, until: float, wall: float) -> None:
+        if self._idle_t0 is not None:
+            tracing.record_span(
+                self._trace.take(1), P_IDLE, self._idle_t0 + wall,
+                until + wall, kind="engine",
+                attrs={"it": self._idle_it, "iterations": self._idle_n})
+            self._idle_t0 = None
+
+    def note_compile(self, secs: float, program) -> None:
+        t = _mono()
+        self._done.append((S_COMPILE, t - secs, t, self.req,
+                           (round(secs, 6), self.name, program)))
+
+    def _stall(self, seconds: float) -> None:
+        try:
+            from ray_tpu.util import events
+
+            events.emit(
+                "llm.loop_stall", severity="warning",
+                message=f"engine loop spent {seconds:.2f} s in {self.name} "
+                        f"(iteration {self.it})",
+                data={"phase": self.name, "seconds": round(seconds, 3),
+                      "it": self.it, "active_slots": sum(
+                          s is not None for s in self._slots)})
+        except Exception:
+            pass
+
+
 @dataclass
 class _Request:
     request_id: str
@@ -234,19 +399,19 @@ class LLMEngine:
         # bounded unfairness, misses can't starve.
         self._admit_age_cap_s = float(
             os.environ.get("RTPU_ADMIT_AGE_CAP_S", "0.25") or 0.25)
-        # Queue/admission observability (VERDICT round-2: the serving
-        # bench conflated queue wait with prefill; these separate them):
-        # recent per-request queue waits (submit -> admission) and prefill
-        # compute times, rings of the last 128.
-        self._queue_waits: "deque[float]" = deque(maxlen=128)
-        self._prefill_times: "deque[float]" = deque(maxlen=128)
         self._m = _engine_metrics()
+        # what the scheduler thread is doing, phase by phase (ISSUE 24)
+        self._ph = _LoopPhases(self._slots)
         self._gauges_at = 0.0  # last gauge refresh (throttled in _loop)
 
     # ------------------------- public API ---------------------------------
 
     def start(self):
         if self._thread is None:
+            from ray_tpu._private import flags
+
+            # the loop is sampled iff requests are: read once, here
+            self._ph.sampled = float(flags.get("RTPU_TRACE_SAMPLE")) > 0
             self._thread = threading.Thread(target=self._loop, daemon=True)
             self._thread.start()
 
@@ -348,26 +513,11 @@ class LLMEngine:
 
     def stats(self) -> dict:
         active = sum(s is not None for s in self._slots)
-
-        def _pctile(ring, frac):
-            # the scheduler thread appends concurrently; a mid-iteration
-            # append at maxlen pops the head and invalidates the iterator
-            for _ in range(4):
-                try:
-                    xs = sorted(ring)
-                    break
-                except RuntimeError:
-                    continue
-            else:
-                return None
-            return round(xs[int((len(xs) - 1) * frac)] * 1e3, 2) \
-                if xs else None
-
         pc = self.prefix_cache
         # per-family heat rows (root digest hex + hits + resident blocks):
         # the controller's KV replication policy ranks families across
         # replicas from these.  family_stats iterates a dict the scheduler
-        # thread mutates — retry like _pctile.
+        # thread mutates, which invalidates the iterator: retry.
         kv_families: List[dict] = []
         if pc is not None:
             for _ in range(4):
@@ -386,18 +536,38 @@ class LLMEngine:
                 # + recent block digests — the router's KV-locality signal
                 "prefix_cache": pc.stats() if pc is not None else None,
                 "resident_pages": self.allocator.num_resident(),
-                "prefix_digests": pc.digests() if pc is not None else [],
-                # admission observability: time requests spent queued
-                # before a slot/pages freed up, vs pure prefill compute
-                "p50_queue_wait_ms": _pctile(self._queue_waits, 0.5),
-                "p90_queue_wait_ms": _pctile(self._queue_waits, 0.9),
-                "p50_prefill_ms": _pctile(self._prefill_times, 0.5),
-                "p90_prefill_ms": _pctile(self._prefill_times, 0.9)}
+                "prefix_digests": pc.digests() if pc is not None else []}
 
     # ------------------------- scheduler loop ------------------------------
 
     def _loop(self):
+        ph = self._ph
+        if ph.sampled:
+            self._watch_compiles()
+        try:
+            self._run_loop(ph)
+        finally:
+            ph.end()
+            if ph.sampled:
+                _sampled_loops.pop(threading.get_ident(), None)
+                now = _mono()
+                ph.flush_idle(now, time.time() - now)
+
+    def _watch_compiles(self) -> None:
+        """Put this thread's backend compilations on the loop's timeline
+        (one listener a process, registered by its first sampled loop:
+        jax.monitoring has no way to take one back)."""
+        global _compile_listener_on
+        _sampled_loops[threading.get_ident()] = self._ph
+        with _compile_listener_lock:
+            if not _compile_listener_on:
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile)
+                _compile_listener_on = True
+
+    def _run_loop(self, ph: _LoopPhases):
         while not self._stop.is_set():
+            ph.it += 1
             try:
                 hydrated = self._drain_hydrations()
                 admitted = self._admit()
@@ -408,6 +578,7 @@ class LLMEngine:
                 import traceback
 
                 traceback.print_exc()
+                ph.finish_iteration(True)
                 for i, s in enumerate(self._slots):
                     if s is not None:
                         s.request.out_queue.put(e)
@@ -422,12 +593,17 @@ class LLMEngine:
                     req.out_queue.put(e)
                     req.out_queue.put(None)
                 continue
+            worked = admitted or stepped or hydrated
             now = time.monotonic()
             if now - self._gauges_at >= 0.25:
                 self._gauges_at = now
+                if worked:  # an idle iteration's refresh is idle time
+                    ph.begin(P_GAUGES)
                 self._refresh_gauges()
-            if not admitted and not stepped and not hydrated:
-                time.sleep(0.002)
+            ph.finish_iteration(worked)
+            if not worked:
+                with jax.profiler.TraceAnnotation(P_IDLE, it=ph.it):
+                    time.sleep(0.002)
 
     def _refresh_gauges(self):
         m = self._m
@@ -542,10 +718,17 @@ class LLMEngine:
         """Move waiting requests into free slots while pages last
         (vLLM analogue: Scheduler admitting to the running batch)."""
         admitted = False
+        ph = self._ph
         while True:
+            # everything up to the prefill is the admit phase; the phase a
+            # prefill leaves open (prefill_emit) lasts through the slot
+            # set-up below and ends here
+            ph.begin(P_ADMIT)
             req = self._pick_waiting()
             if req is None:
+                ph.vals = ("none_waiting",)
                 return admitted
+            ph.req = req
             # prefill_only completes inline and occupies no decode slot, so
             # it is admitted even with all slots busy (only pages gate it)
             if req.kind != "prefill_only":
@@ -553,6 +736,7 @@ class LLMEngine:
                                   if s is None), None)
                 if free_slot is None:
                     self._waiting.queue.appendleft(req)  # type: ignore[attr-defined]
+                    ph.vals = ("no_slot",)
                     return admitted
             if req.kind == "prefill_only":
                 # KV only lives for the prefill compute+extract; afterwards
@@ -562,7 +746,9 @@ class LLMEngine:
                 n_pages = -(-len(req.prompt_tokens) // self.cfg.page_size)
                 if not self._reserve(n_pages):
                     self._waiting.queue.appendleft(req)  # type: ignore[attr-defined]
+                    ph.vals = ("no_pages",)
                     return admitted
+                ph.vals = ("admitted",)
                 pages = self.allocator.allocate(n_pages)
                 rng = (np.random.default_rng(req.params.seed)
                        if req.params.temperature > 0 else None)
@@ -621,7 +807,9 @@ class LLMEngine:
             if not self._reserve(need_total - len(matched)):
                 self.allocator.free(pin)  # unpin; stays resident
                 self._waiting.queue.appendleft(req)  # type: ignore[attr-defined]
+                ph.vals = ("no_pages",)
                 return admitted
+            ph.vals = ("admitted",)
             pages = matched + self.allocator.allocate(
                 need_total - len(matched))
             prefix_len = len(matched) * self.cfg.page_size
@@ -721,6 +909,8 @@ class LLMEngine:
                  prefix_len: int = 0) -> int:
         n = len(req.prompt_tokens)
         ps = self.cfg.page_size
+        ph = self._ph
+        ph.begin(P_PREFILL_HOST, req)
         t0 = time.monotonic()
         if prefix_len > 0:
             # prefix-cache hit: pages[:prefix_len//ps] already hold the
@@ -740,11 +930,10 @@ class LLMEngine:
             slot_positions = positions % ps
             table = np.zeros(self.max_pages_per_seq, np.int32)
             table[:len(pages)] = pages
-            logits, self.cache_k, self.cache_v = lm.prefill_with_prefix(
-                self.params, jnp.asarray(tokens), self.cache_k,
-                self.cache_v, jnp.asarray(page_rows), jnp.int32(ls),
-                jnp.asarray(slot_positions), jnp.asarray(table),
-                jnp.asarray(positions), self.model_cfg)
+            program = lm.prefill_with_prefix
+            args = (jnp.asarray(page_rows), jnp.int32(ls),
+                    jnp.asarray(slot_positions), jnp.asarray(table),
+                    jnp.asarray(positions))
         else:
             bucket = self.cfg.bucket_for(n)
             tokens = np.zeros(bucket, np.int32)
@@ -757,15 +946,21 @@ class LLMEngine:
                 pi = i // ps
                 page_rows[i] = pages[pi] if pi < len(pages) else 0
             slot_positions = np.arange(bucket, dtype=np.int32) % ps
-            logits, self.cache_k, self.cache_v = lm.prefill(
-                self.params, jnp.asarray(tokens), self.cache_k,
-                self.cache_v, jnp.asarray(page_rows), jnp.int32(n),
-                jnp.asarray(slot_positions), self.model_cfg)
-        out = self._sample_one(np.asarray(logits), req.params, rng)
+            program = lm.prefill
+            args = (jnp.asarray(page_rows), jnp.int32(n),
+                    jnp.asarray(slot_positions))
+        tokens = jnp.asarray(tokens)
+        ph.vals = (bucket, prefix_len)
+        ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
+        logits, self.cache_k, self.cache_v = program(
+            self.params, tokens, self.cache_k, self.cache_v, *args,
+            self.model_cfg)
+        ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
+        logits = np.asarray(logits)
+        ph.begin(P_PREFILL_EMIT, req)
+        out = self._sample_one(logits, req.params, rng)
         self._stats["prefills"] += 1
         dt = time.monotonic() - t0
-        self._prefill_times.append(dt)
-        self._queue_waits.append(t0 - req.submitted_at)
         self._stats["admitted"] += 1
         self._m["prefills"].inc()
         self._m["admitted"].inc()
@@ -947,6 +1142,8 @@ class LLMEngine:
                 root_hex = self._hydrate_q.get_nowait()
             except queue_mod.Empty:
                 break
+            if self._ph.name != P_HYDRATE:
+                self._ph.begin(P_HYDRATE, vals=(0,))
             rec = tier.lookup(root_hex)
             if rec is None:
                 continue  # nothing sealed under that root (yet)
@@ -960,6 +1157,7 @@ class LLMEngine:
             if n:
                 did = True
                 self._note_kv_pull(n)
+                self._ph.vals = (self._ph.vals[0] + n,)
         return did
 
     def _hydrate_spine(self, spine: List[int], kv_k, kv_v,
@@ -1109,6 +1307,8 @@ class LLMEngine:
                         if s is not None]
         if not active_slots:
             return False
+        ph = self._ph
+        ph.begin(P_DECODE_HOST)
         all_greedy = all(s.request.params.temperature <= 0
                          for _, s in active_slots)
         # Burst decode: chain several device-fed greedy steps and fetch
@@ -1143,6 +1343,7 @@ class LLMEngine:
         active_slots = [(i, s) for i, s in enumerate(self._slots)
                         if s is not None]
         if not active_slots:
+            ph.vals = (0, burst)
             return True  # everything preempted; _admit resumes them
         B = self.cfg.max_slots
         P = self.max_pages_per_seq
@@ -1155,11 +1356,14 @@ class LLMEngine:
             positions[i] = s.num_tokens  # position of the new token
             tables[i, :len(s.pages)] = s.pages
             active[i] = True
+        toks_dev = jnp.asarray(tokens)
+        pos_dev = jnp.asarray(positions)
+        tables_dev = jnp.asarray(tables)
+        active_dev = jnp.asarray(active)
+        emitted = self._stats["tokens_generated"]
+        ph.vals = (len(active_slots), burst)
+        ph.begin(P_DECODE_DISPATCH, vals=(burst,))
         if all_greedy:
-            toks_dev = jnp.asarray(tokens)
-            pos_dev = jnp.asarray(positions)
-            tables_dev = jnp.asarray(tables)
-            active_dev = jnp.asarray(active)
             steps = []
             for j in range(burst):
                 toks_dev, self.cache_k, self.cache_v = \
@@ -1168,9 +1372,12 @@ class LLMEngine:
                         tables_dev, pos_dev + j, active_dev,
                         self.model_cfg)
                 steps.append(toks_dev)
+            # the host waits for the device
+            ph.begin(P_DECODE_FETCH, vals=(burst,))
             # ONE host round trip for the whole burst (stack on device)
             rows = np.asarray(jnp.stack(steps)) if burst > 1 else [
                 np.asarray(steps[0])]
+            ph.begin(P_DECODE_EMIT)
             self._stats["decode_steps"] += burst
             self._m["decode_steps"].inc(burst)
             for row in rows:
@@ -1178,17 +1385,22 @@ class LLMEngine:
                     if self._slots[i] is not s:
                         continue  # finished earlier in this burst
                     self._accept_token(i, s, int(row[i]))
-            return True
-        logits, self.cache_k, self.cache_v = lm.decode_step(
-            self.params, jnp.asarray(tokens), self.cache_k,
-            self.cache_v, jnp.asarray(tables), jnp.asarray(positions),
-            jnp.asarray(active), self.model_cfg)
-        logits_np = np.asarray(logits)
-        self._stats["decode_steps"] += 1
-        self._m["decode_steps"].inc()
-        for i, s in active_slots:
-            tok = self._sample_one(logits_np[i], s.request.params, s.rng)
-            self._accept_token(i, s, tok)
+        else:
+            logits, self.cache_k, self.cache_v = lm.decode_step(
+                self.params, toks_dev, self.cache_k, self.cache_v,
+                tables_dev, pos_dev, active_dev, self.model_cfg)
+            ph.begin(P_DECODE_FETCH, vals=(burst,))
+            logits_np = np.asarray(logits)
+            ph.begin(P_DECODE_EMIT)
+            self._stats["decode_steps"] += 1
+            self._m["decode_steps"].inc()
+            for i, s in active_slots:
+                tok = self._sample_one(logits_np[i], s.request.params,
+                                       s.rng)
+                self._accept_token(i, s, tok)
+        if ph.sampled:
+            ph.vals = (self._stats["tokens_generated"] - emitted,
+                       sum(self._slots[i] is not s for i, s in active_slots))
         return True
 
     def _accept_token(self, i: int, s: _Slot, tok: int):

@@ -23,16 +23,20 @@ import os
 import random
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
-
-_spans: List[Dict[str, Any]] = []
-_lock = threading.Lock()
-_enabled = False
 
 # Spans carrying a trace id queue here until pushed to the node scheduler
 # ("spans_push").  Bounded: tracing is observability, not ground truth.
 _remote_buf: List[Dict[str, Any]] = []
 _REMOTE_BUF_CAP = 50_000
+
+# The process-local copy behind collected_spans()/export_chrome_trace():
+# the newest spans only, since a serving replica records them for as long
+# as it lives (its engine loop alone banks several a second).
+_spans: "deque[Dict[str, Any]]" = deque(maxlen=_REMOTE_BUF_CAP)
+_lock = threading.Lock()
+_enabled = False
 
 _tls = threading.local()
 
@@ -243,6 +247,27 @@ def record_span(trace_id: str, name: str, start_ts: float, end_ts: float, *,
         "args": dict(attrs or {}),
     })
     return sid
+
+
+class LoopTrace:
+    """Trace ids for a loop that runs as long as its process (the serving
+    engine's scheduler loop): its spans cannot live in one trace, which the
+    node scheduler caps at 10,000 spans, nor in one trace an iteration,
+    which would push the requests' traces out of ``RTPU_TRACE_CAP``.  The
+    id is rotated once ``budget`` spans have been charged to it, so a loop
+    that banks ~100 spans a second costs under one trace a minute."""
+
+    def __init__(self, budget: int = 9_000):
+        self._budget = budget
+        self._id: Optional[str] = None
+        self._n = 0
+
+    def take(self, n_spans: int) -> str:
+        """The trace id that the caller's next ``n_spans`` spans go to."""
+        if self._id is None or self._n + n_spans > self._budget:
+            self._id, self._n = new_trace_id(), 0
+        self._n += n_spans
+        return self._id
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,280 @@
+"""Engine-loop anatomy (ISSUE 24): what the scheduler thread was doing.
+
+The loop's phases as banked spans (ordered, disjoint, covering an
+iteration; one idle span per idle stretch; a prefill's phases naming their
+request; compilations naming the phase they interrupted), the rotation of
+the loop's trace id, the stall event, and the off path: with
+``RTPU_TRACE_SAMPLE=0`` the loop reaches no span or event code and the
+tokens are what they were.
+"""
+
+import threading
+import time
+
+import jax
+import pytest
+
+from ray_tpu.llm import engine as engine_mod
+from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.models import llama
+from ray_tpu.util import events as events_mod
+from ray_tpu.util import tracing
+
+PROMPTS = ([5, 6, 7, 8, 9], list(range(20, 42)))
+LOOP_PHASES = {getattr(engine_mod, n) for n in dir(engine_mod)
+               if n.startswith("P_")}
+
+
+def _engine(model):
+    params, cfg = model
+    return LLMEngine(params, cfg, EngineConfig(
+        max_slots=2, num_pages=64, page_size=8, max_seq_len=256,
+        prefill_buckets=(16, 32)))
+
+
+@pytest.fixture(scope="module")
+def model():
+    # a vocabulary no other test file uses: the programs compile here, so
+    # the sampled run has compilations to put on its timeline
+    cfg = llama.LlamaConfig(
+        vocab_size=131, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq_len=256, dtype="float32", remat=False)
+    return llama.init(cfg, jax.random.PRNGKey(0)), cfg
+
+
+@pytest.fixture(scope="module")
+def sampled_run(model):
+    """One sampled engine: two requests with 0.3 s of nothing between
+    them.  Returns (records banked by the loop, the tokens, the stats)."""
+    recs = []
+    mp = pytest.MonkeyPatch()
+    mp.setenv("RTPU_TRACE_SAMPLE", "1.0")
+    orig = tracing._record
+    mp.setattr(tracing, "_record", lambda r: (recs.append(r), orig(r))[1])
+    eng = _engine(model)
+    try:
+        eng.start()
+        outs = [eng.generate(PROMPTS[0], SamplingParams(max_tokens=12))]
+        time.sleep(0.3)
+        outs.append(eng.generate(PROMPTS[1], SamplingParams(max_tokens=20)))
+        stats = eng.stats()
+    finally:
+        eng.stop()
+        mp.undo()
+    return recs, outs, stats
+
+
+def _by_iteration(recs):
+    its = {}
+    for r in recs:
+        if r["name"] in LOOP_PHASES and r["name"] != engine_mod.P_IDLE:
+            its.setdefault(r["args"]["it"], []).append(r)
+    return its
+
+
+def test_phases_are_ordered_disjoint_and_cover_their_iteration(sampled_run):
+    recs, _, _ = sampled_run
+    loops = {r["args"]["it"]: r for r in recs
+             if r["name"] == engine_mod.S_LOOP}
+    its = _by_iteration(recs)
+    assert len(loops) >= 4 and set(loops) == set(its)
+    for it, phases in its.items():
+        loop = loops[it]
+        assert all(p["parent_id"] == loop["span_id"]
+                   and p["trace_id"] == loop["trace_id"] for p in phases)
+        # banked in the order they ran, each starting where the last ended
+        for a, b in zip(phases, phases[1:]):
+            assert a["end_ts"] <= b["start_ts"] + 1e-9, (a, b)
+        covered = sum(p["end_ts"] - p["start_ts"] for p in phases)
+        assert abs(covered - (loop["end_ts"] - loop["start_ts"])) < 1e-3
+        assert phases[0]["start_ts"] == loop["start_ts"]
+        assert phases[-1]["end_ts"] == loop["end_ts"]
+    # a decode iteration runs host -> dispatch -> fetch -> emit
+    names = [p["name"].rsplit(".", 1)[1] for p in its[max(its)]]
+    assert names[-4:] == ["decode_host", "decode_dispatch", "decode_fetch",
+                          "decode_emit"]
+    emit = its[max(its)][-1]["args"]
+    assert emit["slots_released"] == 1 and emit["tokens"] >= 1
+
+
+def test_an_idle_stretch_is_one_span(sampled_run):
+    recs, _, _ = sampled_run
+    first_end = max(r["end_ts"] for r in recs if r["args"].get(
+        "request_id") and r["args"]["it"] == min(_by_iteration(recs)))
+    idle = [r for r in recs if r["name"] == engine_mod.P_IDLE
+            and r["start_ts"] >= first_end]
+    assert len(idle) == 1, idle
+    assert 0.29 <= idle[0]["end_ts"] - idle[0]["start_ts"] < 2.0
+    # one span for ~150 sleeps of 2 ms, and it says so
+    assert idle[0]["args"]["iterations"] >= 20
+
+
+def test_a_prefills_phases_name_their_request(sampled_run):
+    recs, _, _ = sampled_run
+    prefill = [r for r in recs if ".prefill_" in r["name"]]
+    ids = {r["args"]["request_id"] for r in prefill}
+    assert len(ids) == 2
+    for rid in ids:
+        mine = [r["name"].rsplit(".", 1)[1] for r in prefill
+                if r["args"]["request_id"] == rid]
+        assert mine == ["prefill_host", "prefill_dispatch",
+                        "prefill_fetch", "prefill_emit"]
+    host = next(r for r in prefill if r["name"].endswith("prefill_host"))
+    assert host["args"]["bucket"] in (16, 32)
+    assert host["args"]["prefix_len"] == 0
+    admits = [r["args"] for r in recs if r["name"] == engine_mod.P_ADMIT]
+    assert {a["request_id"] for a in admits
+            if a["outcome"] == "admitted"} == ids
+    assert any(a["outcome"] == "none_waiting" for a in admits)
+
+
+def test_a_compilation_names_the_phase_it_interrupted(model, sampled_run,
+                                                      monkeypatch):
+    # through jax.monitoring itself, on a thread that plays the engine's
+    recs = []
+    monkeypatch.setattr(tracing, "_record", recs.append)
+    eng = _engine(model)
+    ph = eng._ph
+    ph.sampled = True
+    req = engine_mod._Request("req-c0ffee", [1, 2, 3], SamplingParams())
+    eng._watch_compiles()
+    try:
+        ph.it = 41
+        ph.begin(engine_mod.P_PREFILL_DISPATCH, req)
+        jax.monitoring.record_event_duration_secs(
+            engine_mod._COMPILE_EVENT, 0.25, fun_name="jit(prefill)")
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/jaxpr_trace_duration", 0.1, fun_name="x")
+        ph.finish_iteration(True)
+    finally:
+        engine_mod._sampled_loops.pop(threading.get_ident())
+    loop, compile_, phase = recs
+    assert (loop["name"], phase["name"]) == (
+        engine_mod.S_LOOP, engine_mod.P_PREFILL_DISPATCH)
+    assert compile_["name"] == engine_mod.S_COMPILE
+    assert compile_["args"] == {
+        "seconds": 0.25, "phase": engine_mod.P_PREFILL_DISPATCH,
+        "program": "jit(prefill)", "it": 41, "request_id": "req-c0ffee"}
+    assert compile_["end_ts"] - compile_["start_ts"] == pytest.approx(0.25)
+    assert compile_["parent_id"] == loop["span_id"]
+    # another thread's compilation is not this loop's
+    t = threading.Thread(
+        target=jax.monitoring.record_event_duration_secs,
+        args=(engine_mod._COMPILE_EVENT, 0.5))
+    t.start()
+    t.join(10)
+    assert len(recs) == 3 and not ph._done
+    # and what the real engine compiled (nothing, if the persistent cache
+    # held its programs) interrupted a dispatch or a fetch
+    for c in (r for r in sampled_run[0]
+              if r["name"] == engine_mod.S_COMPILE):
+        assert c["args"]["phase"].rsplit("_", 1)[1] in ("dispatch", "fetch")
+        assert c["args"]["it"] >= 1 and c["args"]["seconds"] > 0
+
+
+def test_off_path_reaches_no_span_code_and_tokens_are_the_same(
+        model, sampled_run, monkeypatch):
+    _, sampled_outs, _ = sampled_run
+
+    def boom(*a, **k):
+        raise AssertionError("the unsampled loop reached span/event code")
+
+    monkeypatch.setenv("RTPU_TRACE_SAMPLE", "0")
+    for target, name in ((tracing, "record_span"), (tracing, "_record"),
+                         (tracing, "_ensure_flusher"),
+                         (tracing, "new_span_id"), (events_mod, "emit")):
+        monkeypatch.setattr(target, name, boom)
+    before = set(threading.enumerate())
+    eng = _engine(model)
+    try:
+        eng.start()
+        outs = [eng.generate(PROMPTS[0], SamplingParams(max_tokens=12))]
+        time.sleep(0.05)
+        outs.append(eng.generate(PROMPTS[1], SamplingParams(max_tokens=20)))
+        started = set(threading.enumerate()) - before
+        # the loop itself and nothing else: no heartbeat, flusher or timer
+        assert started == {eng._thread}
+        assert threading.get_ident() not in engine_mod._sampled_loops
+        assert eng._thread.ident not in engine_mod._sampled_loops
+    finally:
+        eng.stop()
+    assert outs == sampled_outs  # greedy, token for token
+    assert not eng._ph.sampled and eng._ph._done == []
+    assert eng._ph.it > 4
+
+
+def _iterate(ph, n_phases=7):
+    ph.it += 1
+    for _ in range(n_phases):
+        ph.begin(engine_mod.P_DECODE_FETCH)
+    ph.finish_iteration(True)
+
+
+def test_the_loops_trace_id_rotates_under_the_cap(monkeypatch):
+    recs = []
+    monkeypatch.setattr(tracing, "_record", recs.append)
+    ph = engine_mod._LoopPhases([])
+    ph.sampled = True
+    for _ in range(600):  # ten minutes of full-occupancy decode
+        _iterate(ph, n_phases=13)  # two admissions and a burst
+    per_trace = {}
+    for r in recs:
+        per_trace[r["trace_id"]] = per_trace.get(r["trace_id"], 0) + 1
+    assert len(recs) == 600 * 14
+    assert len(per_trace) <= 2 and max(per_trace.values()) <= 9_000
+    # an iteration's spans stay together, in one trace
+    for r in recs:
+        if r["name"] != engine_mod.S_LOOP:
+            assert r["parent_id"] and r["trace_id"] in per_trace
+    # the rotation itself, at a small budget
+    t = tracing.LoopTrace(budget=10)
+    ids = [t.take(4) for _ in range(5)]
+    assert ids[0] == ids[1] != ids[2] == ids[3] != ids[4]
+
+
+def test_a_phase_stretched_past_two_seconds_is_one_stall_event(monkeypatch):
+    now = [100.0]
+    emitted = []
+    monkeypatch.setattr(engine_mod, "_mono", lambda: now[0])
+    monkeypatch.setattr(tracing, "_record", lambda r: None)
+    monkeypatch.setattr(events_mod, "emit",
+                        lambda kind, **kw: emitted.append((kind, kw)))
+    ph = engine_mod._LoopPhases([object()] * 3 + [None])
+    ph.sampled = True
+    ph.it = 7
+    ph.begin(engine_mod.P_DECODE_HOST)
+    now[0] += 0.9
+    ph.begin(engine_mod.P_DECODE_FETCH)  # a normal burst: no event
+    now[0] += 1.9
+    ph.begin(engine_mod.P_DECODE_EMIT)
+    now[0] += 3.5  # the whole process stood still here
+    ph.finish_iteration(True)
+    for _ in range(1200):  # 2.4 s with nothing to do is not a stall
+        ph.it += 1
+        ph.begin(engine_mod.P_ADMIT)
+        now[0] += 0.002
+        ph.finish_iteration(False)
+    assert [k for k, _ in emitted] == ["llm.loop_stall"]
+    data = emitted[0][1]["data"]
+    assert data == {"phase": engine_mod.P_DECODE_EMIT, "seconds": 3.5,
+                    "it": 7, "active_slots": 3}
+    # unsampled, the same stretch is not even looked at
+    ph.sampled = False
+    ph.begin(engine_mod.P_DECODE_EMIT)
+    now[0] += 5.0
+    ph.end()
+    assert len(emitted) == 1
+
+
+def test_stats_serves_its_counters_without_the_rings(sampled_run, model):
+    _, outs, stats = sampled_run
+    assert stats["prefills"] == 2 and stats["admitted"] == 2
+    assert stats["tokens_generated"] == sum(len(o) for o in outs) == 32
+    assert stats["decode_steps"] >= 30
+    assert {"active_slots", "free_pages", "waiting", "prefix_cache",
+            "resident_pages", "kv_families"} <= set(stats)
+    assert not [k for k in stats if k.startswith(("p50_", "p90_"))]
+    eng = _engine(model)
+    assert not hasattr(eng, "_queue_waits")
+    assert not hasattr(eng, "_prefill_times")
+    assert eng.stats()["tokens_generated"] == 0  # before any loop ran
