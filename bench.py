@@ -80,9 +80,12 @@ def serving_bench() -> dict:
     from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
     from ray_tpu.models import llama
 
+    # 6 heads of 128 over 2 KV heads, not GPT-2's 12 of 64: on the chip the
+    # decode kernel copies whole pages, and a page of 64-wide heads is not
+    # made of whole tiles (ops/paged_attention.py refuses it by name)
     cfg = llama.LlamaConfig(
-        vocab_size=32_000, d_model=768, n_layers=12, n_heads=12,
-        n_kv_heads=12, d_ff=3072, max_seq_len=1024, remat=False)
+        vocab_size=32_000, d_model=768, n_layers=12, n_heads=6,
+        n_kv_heads=2, d_ff=3072, max_seq_len=1024, remat=False)
     params = llama.init(cfg, jax.random.PRNGKey(0))
     engine = LLMEngine(params, cfg, EngineConfig(
         max_slots=16, num_pages=512, page_size=16, max_seq_len=1024))

@@ -66,6 +66,10 @@ def _engine_metrics():
                     "llm_prefills_total", "Prefill executions"),
                 "decode_steps": Counter(
                     "llm_decode_steps_total", "Batched decode steps"),
+                "decode_pages_read": Counter(
+                    "llm_decode_pages_read_total", "KV pages the decode "
+                    "kernel walked: per step and active slot, the pages "
+                    "its position reaches"),
                 "tokens": Counter(
                     "llm_tokens_total", "Tokens emitted to callers"),
                 "admitted": Counter(
@@ -388,6 +392,7 @@ class LLMEngine:
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
         self._stats = {"prefills": 0, "decode_steps": 0,
+                       "decode_pages_read": 0,
                        "tokens_generated": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
                        "prefill_tokens_saved": 0, "cow_copies": 0,
@@ -1360,6 +1365,13 @@ class LLMEngine:
         pos_dev = jnp.asarray(positions)
         tables_dev = jnp.asarray(tables)
         active_dev = jnp.asarray(active)
+        # what the paged kernel walks: step j of the burst attends to
+        # positions 0..position+j of each active slot, a page at a time
+        pages_read = int(np.minimum(
+            (positions[active][:, None] + np.arange(burst))
+            // self.cfg.page_size + 1, P).sum())
+        self._stats["decode_pages_read"] += pages_read
+        self._m["decode_pages_read"].inc(pages_read)
         emitted = self._stats["tokens_generated"]
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))

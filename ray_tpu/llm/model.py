@@ -2,12 +2,24 @@
 
 The training-side model (models/llama.py) has no KV cache; these are the
 inference twins, built for XLA's compilation model: ONE compiled decode step
-for the whole engine (static [max_slots] batch; inactive slots masked) and
-one compiled prefill per length bucket.  All control flow that depends on
-sequence length is expressed with masks and gathers, never Python branches.
-The reference gets this from vLLM's CUDA kernels; here it is jax/XLA native
-(SURVEY.md §7 step 8: "continuous-batching engine on TPU, paged attention,
-static-shape token buckets to avoid recompiles").
+for the whole engine (static [max_slots] batch) and one compiled prefill per
+length bucket.  Nothing that depends on a sequence's length is a Python
+branch or a program variant.
+
+The decode step carries the two page pools through its layer scan whole: a
+layer scatters its B new K/V rows into the pool in place, then
+``ops/paged_attention.paged_decode_attention`` walks each slot's page table
+as far as that slot's position and reads those pages out of the pool where
+it lies, at KV-head width (one page read serves every query head of a
+group), with a running float32 softmax; an inactive slot costs nothing.
+The donated pools are aliased to the outputs: nothing pool-sized is
+sliced, stacked or copied.  Prefill attends within the sequence it is
+given (a dense causal ``[L, L]`` score matrix); ``prefill_with_prefix``
+still gathers the whole page table and repeats K/V to the query heads, as
+the decode step did before the kernel (PERF.md section 7).
+The reference gets this from vLLM's CUDA kernels; here it is jax/XLA and
+Pallas native (SURVEY.md §7 step 8: "continuous-batching engine on TPU,
+paged attention, static-shape token buckets to avoid recompiles").
 """
 
 from __future__ import annotations
@@ -19,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig, rms_norm, rope
+from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
 def _qkv(cfg: LlamaConfig, p, h):
@@ -150,51 +163,48 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     tokens: [B] int32 current token per slot; positions: [B] its position;
     page_tables: [B, P] page ids (0 = null page); active: [B] bool.
     Returns (logits [B, V], cache_k, cache_v).
+
+    The pools are carried through the layer scan whole, not scanned over:
+    a layer writes its B new rows into the pool in place and the paged
+    kernel reads that layer's pages out of the same buffer, so the donated
+    pools are aliased to the outputs and never sliced, stacked or copied.
     """
-    B = tokens.shape[0]
     P = page_tables.shape[1]
     page_size = cache_k.shape[2]
     x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [B, D]
 
-    # where this step's k/v lands: slot b writes page_tables[b, pos//ps]
+    # where this step's k/v lands: slot b writes page_tables[b, pos//ps].
+    # Inactive slots, and a burst's overshoot past the table's last page,
+    # write into the null page (page 0) — harmless scratch
+    in_table = active & (positions < P * page_size)
     write_page = jnp.take_along_axis(
-        page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-    # inactive slots write into the null page (page 0) — harmless scratch
-    write_page = jnp.where(active, write_page, 0)
+        page_tables, jnp.minimum(positions // page_size, P - 1)[:, None],
+        axis=1)[:, 0]
+    write_page = jnp.where(in_table, write_page, 0)
     write_slot = positions % page_size
+    # attend up to and including the current token; 0 skips the slot
+    lengths = jnp.where(active, positions + 1, 0)
 
-    def body(x, layer):
-        p, ck_l, cv_l = layer
+    def body(carry, layer):
+        x, ck, cv = carry
+        p, li = layer
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(cfg, p, h)  # q: [B, H, d]; k,v: [B, Hkv, d]
         q = rope(q[:, None], positions[:, None],
                  cfg.rope_theta)[:, 0]
         k = rope(k[:, None], positions[:, None],
                  cfg.rope_theta)[:, 0]
-        ck_l = ck_l.at[write_page, write_slot].set(k)
-        cv_l = cv_l.at[write_page, write_slot].set(v)
-        # gather each slot's pages: [B, P, ps, Hkv, d] -> [B, P*ps, Hkv, d]
-        keys = ck_l[page_tables].reshape(B, P * page_size,
-                                         cfg.n_kv_heads, cfg.head_dim)
-        vals = cv_l[page_tables].reshape(B, P * page_size,
-                                         cfg.n_kv_heads, cfg.head_dim)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        keys = jnp.repeat(keys, rep, axis=2)  # [B, T, H, d]
-        vals = jnp.repeat(vals, rep, axis=2)
-        scores = jnp.einsum("bhd,bthd->bht", q, keys) \
-            / (cfg.head_dim ** 0.5)
-        tpos = jnp.arange(P * page_size)[None]  # [1, T]
-        mask = tpos <= positions[:, None]  # attend up to current token
-        scores = jnp.where(mask[:, None, :], scores, -1e30)
-        attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("bht,bthd->bhd", attn.astype(vals.dtype), vals)
-        x = x + out.reshape(B, -1) @ p["attn"]["wo"].astype(x.dtype)
+        ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
+        cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+        out = paged_decode_attention(q, ck, cv, page_tables, lengths, li)
+        x = x + out.reshape(x.shape[0], -1) @ p["attn"]["wo"].astype(x.dtype)
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         x = x + _mlp(p, h)
-        return x, (ck_l, cv_l)
+        return (x, ck, cv), None
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["layers"], cache_k, cache_v))
+    (x, cache_k, cache_v), _ = jax.lax.scan(
+        body, (x, cache_k, cache_v),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = x.astype(jnp.float32) @ params["lm_head"]
     return logits, cache_k, cache_v

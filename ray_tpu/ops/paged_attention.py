@@ -1,0 +1,222 @@
+"""Paged decode attention for TPU: one query token a slot, read from the
+page pool where it lies.
+
+The decode step's attention used to gather every slot's whole page table
+into a dense ``[B, P * page_size, n_kv_heads, d]`` K and V, repeat both to
+the query heads' width and mask what was not there.  This kernel walks each
+slot's page table only as far as the slot's length, copies those pages
+straight out of the pool (``[n_layers, num_pages, page_size, n_kv_heads,
+head_dim]``, the layer indexed here, so no per-layer slice of the pool is
+ever made), and keeps a running float32 softmax over blocks of pages.
+
+One kernel invocation serves the whole batch.  Lengths, page tables and the
+layer ride in SMEM (scalar prefetch); q and the output sit whole in VMEM
+(``B * H * d`` elements each); the pools stay in HBM.  A compute block is
+``pages_per_block`` pages: a page is one contiguous ``page_size * n_kv_heads
+* head_dim`` run of the pool (32 KB at 16 x 8 x 128 bf16), too small to
+hide a copy's latency behind its own compute, so a block's pages are copied
+together into one of two VMEM buffers while the other is being computed on,
+and the prefetch runs across slot boundaries (the last block of a slot
+starts the first block of the next active one).  Pages past a slot's length
+are neither copied nor computed; a slot of length 0 is skipped and returns
+zeros.
+
+Grouped-query attention without a repeat: a block's K lies in VMEM as
+``[tokens * n_kv_heads, d]`` rows (the pool's own order), all H query heads
+multiply all of it in one MXU call, and the columns of another KV head are
+masked out with the columns past the length.  ``n_kv_heads`` times the
+useful multiply-adds, on a kernel that is bound by its copies: one K/V page
+read serves every query head of every group.
+
+Off the TPU the same kernel runs through the Pallas interpreter, as
+``flash_attention`` does.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import NEG_INF
+
+# tokens a compute block holds unless the caller says otherwise: on a v5e,
+# 16-token pages of 8 KV heads x 128 read at 419 / 574 / 666 / 643 GB/s in
+# blocks of 4 / 8 / 16 / 32 pages (PERF.md, PR 25)
+BLOCK_TOKENS = 256
+
+
+def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
+                         v_pool, o_ref, k_buf, v_buf, sems, *,
+                         sm_scale: float):
+    B, H, d = q_ref.shape
+    _, ppb, ps, n_kv, _ = k_buf.shape
+    P = tables_ref.shape[0] // B
+    block_tokens = ppb * ps
+    cols = block_tokens * n_kv
+    layer = layer_ref[0]
+
+    def block_copies(b, blk, buf, wait: bool):
+        """Start (or wait for) the copies of block ``blk`` of slot ``b``
+        into buffer ``buf``: only the pages the slot's length reaches."""
+        n_pages = (lengths_ref[b] + ps - 1) // ps
+        for i in range(ppb):
+            pg = blk * ppb + i
+
+            @pl.when(pg < n_pages)
+            def _():
+                # a wait needs the copy's shape and semaphore, not its source
+                page = 0 if wait else tables_ref[b * P + pg]
+                for s, (pool, dst) in enumerate(((k_pool, k_buf),
+                                                 (v_pool, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        pool.at[layer, page], dst.at[buf, i], sems.at[s, buf])
+                    copy.wait() if wait else copy.start()
+
+    def next_active(b):
+        """First slot after ``b`` with something to attend to, or B."""
+        return jax.lax.while_loop(
+            lambda n: (n < B) & (lengths_ref[jnp.minimum(n, B - 1)] == 0),
+            lambda n: n + 1, b + 1)
+
+    # A column of a block's scores is (token, kv head), in the pool's order;
+    # a query head keeps the columns of its own group's KV head.
+    row = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1)
+    own_head = (col % n_kv) == (row // (H // n_kv))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    # A block's last pages may not be copied; what lies there (an earlier
+    # block's rows, or at first whatever VMEM held) is masked out of the
+    # scores but still multiplied by p == 0, so it has to be finite.
+    v_buf[...] = jnp.zeros_like(v_buf)
+    first = next_active(-1)
+
+    @pl.when(first < B)
+    def _():
+        block_copies(first, 0, 0, wait=False)
+
+    def slot(carry):
+        b, buf = carry
+        length = lengths_ref[b]
+        n_blocks = (length + block_tokens - 1) // block_tokens
+        nxt = next_active(b)
+        q = q_ref[b]
+
+        def block(i, carry):
+            m, l, acc, buf = carry
+            more = i + 1 < n_blocks
+
+            @pl.when(more | (nxt < B))
+            def _():
+                block_copies(jnp.where(more, b, jnp.minimum(nxt, B - 1)),
+                             jnp.where(more, i + 1, 0), 1 - buf, wait=False)
+
+            block_copies(b, i, buf, wait=True)
+            k = k_buf[buf].reshape(cols, d)
+            v = v_buf[buf].reshape(cols, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            keep = own_head & (col < (length - i * block_tokens) * n_kv)
+            s = jnp.where(keep, s * sm_scale, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)  # masked columns: exactly 0
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - buf
+
+        # block 0 holds position 0, which every query head attends to, so
+        # the running max is finite from the first block on
+        _, l, acc, buf = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.full((H, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, d), jnp.float32), buf))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return nxt, buf
+
+    jax.lax.while_loop(lambda c: c[0] < B, slot, (first, 0))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("pages_per_block", "interpret"))
+def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer, *,
+                  pages_per_block: int, interpret: bool):
+    B, H, d = q.shape
+    _, _, ps, n_kv, _ = k_pool.shape
+    P = page_tables.shape[1]
+    ppb = min(pages_per_block, P)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel,
+                          sm_scale=1.0 / math.sqrt(d)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),
+            in_specs=[vmem, any_space, any_space],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, ps, n_kv, d), k_pool.dtype),
+                pltpu.VMEM((2, ppb, ps, n_kv, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # (k | v, buffer)
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(jnp.minimum(lengths, P * ps).astype(jnp.int32),
+      page_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q, k_pool, v_pool)
+
+
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, page_tables: jax.Array,
+                           lengths: jax.Array, layer, *,
+                           pages_per_block: int | None = None) -> jax.Array:
+    """Attention of one query token a slot over that slot's cached tokens.
+
+    q: [B, H, d], the new token's (rotated) query heads.  k_pool / v_pool:
+    [n_layers, num_pages, page_size, n_kv_heads, d], the whole pool; only
+    ``layer`` (an int32 scalar, traced or not) is read.  page_tables:
+    [B, P] page ids.  lengths: [B] tokens to attend to in each slot,
+    positions ``0 .. lengths[b] - 1`` through the table; 0 marks an inactive
+    slot, whose output row is zeros.  The new token's own K and V must
+    already be in the pool.  Returns [B, H, d] in q's dtype; operands go to
+    the MXU in the pool's dtype, scores and the softmax state are float32.
+    ``pages_per_block`` defaults to ``BLOCK_TOKENS`` worth of pages.
+    """
+    if q.ndim != 3 or k_pool.ndim != 5 or k_pool.shape != v_pool.shape:
+        raise ValueError(
+            f"paged decode attention takes q [B, H, d] and two pools "
+            f"[layers, pages, page_size, kv_heads, d] of one shape; got "
+            f"{q.shape}, {k_pool.shape}, {v_pool.shape}")
+    B, H, d = q.shape
+    n_kv = k_pool.shape[3]
+    if k_pool.shape[4] != d or H % n_kv != 0:
+        raise ValueError(
+            f"query heads ({H} of {d}) must be a multiple of the pool's KV "
+            f"heads ({n_kv} of {k_pool.shape[4]}) at the same head_dim")
+    if page_tables.shape[0] != B or lengths.shape != (B,):
+        raise ValueError(
+            f"page_tables {page_tables.shape} and lengths {lengths.shape} "
+            f"must lead with q's batch ({B})")
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and (d % 128 != 0 or (n_kv % 8 != 0 and n_kv not in (2, 4))):
+        raise ValueError(
+            f"on the TPU the paged decode kernel copies whole pages, and a "
+            f"[page_size, {n_kv}, {d}] page is not made of whole tiles: "
+            f"head_dim must be a multiple of 128 and the KV heads 2, 4 or a "
+            f"multiple of 8")
+    if pages_per_block is None:
+        pages_per_block = max(1, BLOCK_TOKENS // k_pool.shape[2])
+    return _paged_decode(q.astype(k_pool.dtype), k_pool, v_pool, page_tables,
+                         lengths, layer, pages_per_block=pages_per_block,
+                         interpret=not on_tpu).astype(q.dtype)

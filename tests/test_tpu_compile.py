@@ -119,23 +119,78 @@ def _footprint(compiled):
             + m.output_size_in_bytes - m.alias_size_in_bytes)
 
 
-def test_engine_prefill_and_decode_compile_at_llama_widths(topo):
+def _decode_program(cfg, one, slots, pages_per_seq, num_pages=1024):
+    params, cache = _on(one, _serving_shapes(cfg, num_pages=num_pages))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    return lm.decode_step_greedy.lower(
+        params, i32(slots), cache, cache, i32(slots, pages_per_seq),
+        i32(slots), jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one),
+        cfg).compile()
+
+
+def test_engine_prefill_and_decode_compile_at_llama_widths(topo, as_tpu):
     """The engine's two programs at full Llama-3-8B widths and the smoke's
     depth fit one chip next to the weights and the page pool."""
     one = SingleDeviceSharding(topo.devices[0])
     cfg = _smoke_llama(n_layers=16)
     params, cache = _on(one, _serving_shapes(cfg))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-    bucket, slots, pages_per_seq = 128, 8, 64
+    bucket = 128
     prefill = lm.prefill.lower(
         params, i32(bucket), cache, cache, i32(bucket), i32(), i32(bucket),
         cfg).compile()
-    decode = lm.decode_step_greedy.lower(
-        params, i32(slots), cache, cache, i32(slots, pages_per_seq),
-        i32(slots), jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one),
-        cfg).compile()
+    decode = _decode_program(cfg, one, slots=8, pages_per_seq=64)
+    assert "paged_decode_attention" in decode.as_text()
     for program in (prefill, decode):
         assert _footprint(program) < 0.8 * HBM_BYTES
+
+
+V5E_BYTES_LIMIT = 16.91e9  # memory_stats()["bytes_limit"] on the chip
+
+
+@pytest.mark.parametrize("n_layers", [16, 20])
+def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers):
+    """The benchmark's own decode program (Mistral-7B widths, 32 slots of
+    128 pages over a pool of 3,072): both donated pools are aliased to the
+    outputs and nothing pool-sized is planned beside them.  Scanned over,
+    the pool was held twice, and at 20 layers the chip's compiler refused
+    the program (17.06 GiB of 15.75)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=2048, rope_theta=1e6,
+        dtype="bfloat16", remat=False)
+    decode = _decode_program(cfg, one, slots=32, pages_per_seq=128,
+                             num_pages=3072)
+    one_pool = n_layers * 3072 * 16 * 8 * 128 * 2
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * one_pool
+    assert m.temp_size_in_bytes < one_pool
+    assert _footprint(decode) < V5E_BYTES_LIMIT
+    text = decode.as_text()
+    assert "paged_decode_attention" in text
+    pool_shape = f"bf16[{n_layers},3072,16,8,128]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and line.split(" = ")[1].startswith(
+                    pool_shape)]
+
+
+@pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [
+    (12, 12, 64),   # GPT-2-sized heads: half a lane tile
+    (12, 6, 128),   # KV heads that do not fill a sublane tile
+])
+def test_decode_kernel_refuses_pages_that_are_not_whole_tiles(
+        as_tpu, n_heads, n_kv_heads, head_dim):
+    """Mosaic slices a page out of the pool only when its last two
+    dimensions are whole tiles; said in Python, by name, before it is
+    said by the compiler."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    pool = jnp.zeros((2, 8, 16, n_kv_heads, head_dim), jnp.bfloat16)
+    with pytest.raises(ValueError, match="whole tiles"):
+        paged_decode_attention(
+            jnp.zeros((4, n_heads, head_dim), jnp.bfloat16), pool, pool,
+            jnp.zeros((4, 4), jnp.int32), jnp.ones((4,), jnp.int32), 0)
 
 
 def _train_step(model, cfg, mesh, batch, seq, attn_impl=None):
