@@ -10,6 +10,7 @@ implements /v1/completions, /v1/chat/completions, and /v1/models.
 
 from __future__ import annotations
 
+import threading
 import time
 import uuid
 from dataclasses import dataclass, field
@@ -36,6 +37,30 @@ class LLMConfig:
     default_max_tokens: int = 64
 
 
+# Shared by every LLMServer of the process, like the engine's instruments
+# (llm/engine.py _engine_metrics); engine_stats() carries each server's own.
+_METRICS = None
+_metrics_lock = threading.Lock()
+
+
+def _stream_metrics():
+    global _METRICS
+    with _metrics_lock:
+        if _METRICS is None:
+            from ray_tpu.util.metrics import Counter
+
+            _METRICS = {
+                "stream_events": Counter(
+                    "llm_stream_events_total", "SSE token events yielded "
+                    "to streaming callers"),
+                "stream_chunks": Counter(
+                    "llm_stream_chunks_total", "Stream chunks (one proxy "
+                    "pull each) that carried at least one token event; "
+                    "events / chunks is how many tokens a pull moves"),
+            }
+        return _METRICS
+
+
 class LLMServer:
     """The engine-owning deployment (one engine per replica)."""
 
@@ -57,6 +82,11 @@ class LLMServer:
                                  llm_config.engine_config,
                                  kv_tier=self._tier)
         self._engine.start()
+        # streams run on the proxy's pull threads, several at once
+        self._m = _stream_metrics()
+        self._stream_lock = threading.Lock()
+        self._stream_events = 0
+        self._stream_chunks = 0
         if self._tier is not None:
             # Warm restart: a replica the controller just restarted (or a
             # fresh scale-up) re-hydrates the cluster's hottest families
@@ -91,13 +121,30 @@ class LLMServer:
     def _sse_stream(self, tokens: List[int], params: SamplingParams,
                     rid: str, model: str, chat: bool, trace_ctx=None):
         """Token stream -> OpenAI SSE chunks (reference gets this from
-        vLLM; the engine already streams per-request token queues)."""
+        vLLM; the engine already streams per-request token queues).
+
+        A yielded chunk is one pull of the proxy (one actor round trip,
+        serve/proxy.py stream_to_client), and pulls are what that path
+        runs out of, not events.  So a chunk carries the events of every
+        token the engine had emitted when the pull arrived: it waits for
+        one and never for a second."""
         import json as _json
         import queue as _queue
 
         from ray_tpu.util import tracing
 
         obj = "chat.completion.chunk" if chat else "text_completion"
+
+        def event(choice: dict) -> str:
+            return "data: " + _json.dumps(
+                {"id": rid, "object": obj, "created": int(time.time()),
+                 "model": model, "choices": [{"index": 0, **choice}]}
+            ) + "\n\n"
+
+        def error(message: str) -> str:
+            return "data: " + _json.dumps(
+                {"error": {"message": message}}) + "\n\n"
+
         try:
             # the generator body runs lazily on the proxy's pull thread,
             # where the registration-time task span is long gone: restore
@@ -105,55 +152,61 @@ class LLMServer:
             with tracing.use_context(trace_ctx):
                 req = self._engine.submit(tokens, params)
         except Exception as e:  # frame submit rejections as SSE errors
-            yield ("data: " + _json.dumps(
-                {"error": {"message": f"{type(e).__name__}: {e}"}}) + "\n\n")
+            yield error(f"{type(e).__name__}: {e}")
             yield "data: [DONE]\n\n"
             return
         if chat:
-            first = {"id": rid, "object": obj, "created": int(time.time()),
-                     "model": model,
-                     "choices": [{"index": 0, "delta": {"role": "assistant"},
-                                  "finish_reason": None}]}
-            yield f"data: {_json.dumps(first)}\n\n"
+            yield event({"delta": {"role": "assistant"},
+                         "finish_reason": None})
         n = 0
         deadline = time.monotonic() + 600.0
         while True:
             try:
                 # bounded waits: a dead engine loop pushes no terminator,
                 # and a stream must never hang its replica pull thread
-                tok = req.out_queue.get(timeout=5.0)
+                ready = [req.out_queue.get(timeout=5.0)]
             except _queue.Empty:
                 thread = self._engine._thread
                 if ((thread is not None and not thread.is_alive()
                      and not self._engine._stop.is_set())
                         or time.monotonic() > deadline):
-                    yield ("data: " + _json.dumps({"error": {
-                        "message": "engine stopped mid-stream"}}) + "\n\n")
-                    break
+                    yield error("engine stopped mid-stream") \
+                        + "data: [DONE]\n\n"
+                    return
                 continue
-            if isinstance(tok, Exception):
-                err = {"error": {"message": str(tok)}}
-                yield f"data: {_json.dumps(err)}\n\n"
-                break
-            if tok is None:
-                reason = "length" if n >= params.max_tokens else "stop"
-                delta = ({"delta": {}} if chat else {"text": ""})
-                final = {"id": rid, "object": obj,
-                         "created": int(time.time()), "model": model,
-                         "choices": [{"index": 0, **delta,
-                                      "finish_reason": reason}]}
-                yield f"data: {_json.dumps(final)}\n\n"
-                break
-            n += 1
-            piece = self._tok.decode([tok])
-            payload = ({"delta": {"content": piece}} if chat
-                       else {"text": piece})
-            chunk = {"id": rid, "object": obj, "created": int(time.time()),
-                     "model": model,
-                     "choices": [{"index": 0, **payload,
-                                  "finish_reason": None}]}
-            yield f"data: {_json.dumps(chunk)}\n\n"
-        yield "data: [DONE]\n\n"
+            try:
+                while True:
+                    ready.append(req.out_queue.get_nowait())
+            except _queue.Empty:
+                pass
+            out = []
+            last = None  # the event that ends the stream, once drained
+            for tok in ready:
+                if isinstance(tok, Exception):
+                    last = error(str(tok))
+                    break
+                if tok is None:
+                    last = event({
+                        **({"delta": {}} if chat else {"text": ""}),
+                        "finish_reason": ("length" if n >= params.max_tokens
+                                          else "stop")})
+                    break
+                n += 1
+                piece = self._tok.decode([tok])
+                out.append(event({
+                    **({"delta": {"content": piece}} if chat
+                       else {"text": piece}),
+                    "finish_reason": None}))
+            if out:
+                self._m["stream_events"].inc(len(out))
+                self._m["stream_chunks"].inc()
+                with self._stream_lock:
+                    self._stream_events += len(out)
+                    self._stream_chunks += 1
+            if last is not None:
+                yield "".join(out) + last + "data: [DONE]\n\n"
+                return
+            yield "".join(out)
 
     def completions_stream(self, body: dict):
         from ray_tpu.serve import StreamingResponse
@@ -229,7 +282,13 @@ class LLMServer:
                                      SamplingParams(**params))
 
     def engine_stats(self) -> dict:
-        return self._engine.stats()
+        """The engine's counters and, beside them, this server's stream
+        counters: `stream_events` token events left in `stream_chunks`
+        chunks, so events / chunks is what one pull of the proxy moves."""
+        with self._stream_lock:
+            streams = {"stream_events": self._stream_events,
+                       "stream_chunks": self._stream_chunks}
+        return {**self._engine.stats(), **streams}
 
     def kv_prehydrate(self, roots) -> int:
         """Controller KV replication fan-out: pull these family spines

@@ -170,9 +170,15 @@ class ProxyActor:
                                   "StreamReplica")
 
             # One chunk per pull: a batched pull would BLOCK on a slow
-            # generator and destroy incremental delivery; round trips ride
-            # the direct actor transport (~sub-ms), so per-chunk cost is
-            # fine — producers wanting throughput yield bigger chunks.
+            # generator and destroy incremental delivery.  A pull is an
+            # actor round trip, and pulls are what this path runs out of:
+            # a proxy moves 1,400-1,900 a second over all its streams
+            # whatever a chunk carries (two CPU sizings, PERF.md; its
+            # interpreter saturates one core, 32 or 48 streams alike),
+            # and each takes ~0.35 ms of the replica's interpreter from
+            # whatever else runs there.
+            # So producers wanting throughput yield what they have ready
+            # as ONE chunk (llm/server.py _sse_stream does).
             # Pulls run on a DEDICATED executor: each blocks for the full
             # inter-chunk wait, and parking them on the default pool would
             # starve dispatch of every other request.
