@@ -13,8 +13,9 @@ scheduler granted the chip:
 * serve: ``build_openai_app`` -> ``serve.run`` -> HTTP ``/v1/completions``
   through proxy, OpenAI router, handle, replica and engine, on Llama-3-8B at
   its full widths with the depth cut to fit one chip, weights from ``--seed``.
-* train: ``JaxTrainer.fit()`` on GPT-2 124M at its published size, the step
-  built as ``bench.py`` builds it, with a checkpointed ``train.report``.
+* train: ``JaxTrainer.fit()`` on GPT-2 124M at its published size
+  (``create_train_state`` + ``make_train_step`` over the default mesh), with
+  a checkpointed ``train.report``.
 
 One JSON object per phase on its own line, and as the LAST line
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``, the
@@ -118,7 +119,8 @@ def _llama_cfg(n_layers: int, **changes):
 def _gpt2_cfg():
     from ray_tpu.models import gpt2
 
-    return gpt2.GPT2Config(remat=False, loss_chunk=0)  # bench.py's
+    # 124M fits the chip without remat or the chunked loss
+    return gpt2.GPT2Config(remat=False, loss_chunk=0)
 
 
 def _device_bytes() -> int:
@@ -129,8 +131,8 @@ def _device_bytes() -> int:
 
 def _barriers() -> dict:
     """One long matmul chain, timed to ``block_until_ready`` and to a value
-    fetch.  bench.py trusts the first; an earlier runtime returned from it
-    before the chain had run."""
+    fetch.  Timings here trust the first; an earlier runtime returned from
+    it before the chain had run."""
     import jax
     import jax.numpy as jnp
 
@@ -215,8 +217,8 @@ def _save_and_report(state, report: dict):
 
 
 def _gpt2_loop(config: dict):
-    """GPT-2 124M at its published size, state and step built exactly as
-    bench.py builds them, on a batch that leaves the chip headroom."""
+    """GPT-2 124M at its published size on a batch that leaves the chip
+    headroom."""
     import jax
     import jax.numpy as jnp
 
@@ -235,8 +237,8 @@ def _gpt2_loop(config: dict):
         state = create_train_state(gpt2, cfg, mesh, opt,
                                    jax.random.PRNGKey(config["seed"]))
         step = make_train_step(gpt2, cfg, mesh, opt)
-        # bench.py's batch of 12 plans 15.4 of the chip's 16 GB: take the
-        # largest batch whose compiled step leaves headroom
+        # a batch of 12 plans 15.4 of the chip's 16 GB: take the largest
+        # batch whose compiled step leaves headroom
         for batch in (8, 6, 4, 2, 1):
             tokens = jax.device_put(jax.random.randint(
                 jax.random.PRNGKey(config["seed"] + 1),

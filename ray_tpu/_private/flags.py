@@ -252,7 +252,7 @@ _SPECS = [
        "node scheduler (goodput_push lane; close() always pushes)"),
     _f("RTPU_GOODPUT_PEAK_TFLOPS", 197.0,
        "Per-chip peak TFLOP/s used as the MFU denominator (default: "
-       "v5e bf16 peak, matching bench.py's mfu_vs_v5e_peak)"),
+       "the v5e's bf16 peak)"),
     _i("RTPU_GOODPUT_WARMUP", 1,
        "Steps excluded from steady-state throughput/MFU accounting "
        "(step 0 pays compile/dispatch warmup)"),

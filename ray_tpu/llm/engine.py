@@ -12,7 +12,6 @@ queues.  Static shapes throughout: no recompiles after warmup.
 
 from __future__ import annotations
 
-import os
 import queue as queue_mod
 import threading
 import time
@@ -24,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import flags
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
@@ -349,7 +349,14 @@ class _Slot:
 
 
 class LLMEngine:
-    """Single-process engine; wrap in an actor for serving (server.py)."""
+    """Single-process engine; wrap in an actor for serving (server.py).
+
+    Of ``model_cfg`` the engine reads ``n_layers``, ``n_kv_heads``,
+    ``head_dim`` and ``dtype`` (the pools' shape; ``_tier_expect``) and hands
+    it, with ``params``, to the five programs of llm/model.py it calls:
+    ``prefill``, ``prefill_with_prefix``, ``decode_step``,
+    ``decode_step_greedy``, ``copy_page``.
+    """
 
     def __init__(self, params, model_cfg: LlamaConfig,
                  cfg: Optional[EngineConfig] = None, kv_tier=None):
@@ -369,8 +376,7 @@ class LLMEngine:
         # the allocator (tests do) starts from an empty, consistent state.
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.cfg.page_size)
-            if os.environ.get("RTPU_PREFIX_CACHE", "1").lower()
-            not in ("0", "false") else None)
+            if flags.get("RTPU_PREFIX_CACHE") else None)
         self.max_pages_per_seq = -(-self.cfg.max_seq_len
                                    // self.cfg.page_size)
         # Store-backed KV tier (ISSUE 16): hot family spines seal into
@@ -402,8 +408,7 @@ class LLMEngine:
         # waiting request whose prefix is resident, but never once the
         # head of the queue has waited longer than this cap (seconds) —
         # bounded unfairness, misses can't starve.
-        self._admit_age_cap_s = float(
-            os.environ.get("RTPU_ADMIT_AGE_CAP_S", "0.25") or 0.25)
+        self._admit_age_cap_s = flags.get("RTPU_ADMIT_AGE_CAP_S")
         self._m = _engine_metrics()
         # what the scheduler thread is doing, phase by phase (ISSUE 24)
         self._ph = _LoopPhases(self._slots)
@@ -413,8 +418,6 @@ class LLMEngine:
 
     def start(self):
         if self._thread is None:
-            from ray_tpu._private import flags
-
             # the loop is sampled iff requests are: read once, here
             self._ph.sampled = float(flags.get("RTPU_TRACE_SAMPLE")) > 0
             self._thread = threading.Thread(target=self._loop, daemon=True)
@@ -917,43 +920,29 @@ class LLMEngine:
         ph = self._ph
         ph.begin(P_PREFILL_HOST, req)
         t0 = time.monotonic()
+        # pages[:prefix_len // ps] already hold a cached prefix's KV (none
+        # without a hit): compute only what follows it
+        suffix = req.prompt_tokens[prefix_len:]
+        bucket = self.cfg.bucket_for(len(suffix))
+        tokens = np.zeros(bucket, np.int32)
+        tokens[:len(suffix)] = suffix
+        positions = prefix_len + np.arange(bucket, dtype=np.int32)
+        # map each padded position to (page, slot); positions beyond the
+        # allocated pages land in the null page (masked out of attention)
+        page_rows = np.zeros(bucket, np.int32)
+        for i in range(bucket):
+            pi = (prefix_len + i) // ps
+            page_rows[i] = pages[pi] if pi < len(pages) else 0
+        program = lm.prefill
+        args = (jnp.asarray(page_rows), jnp.int32(len(suffix)),
+                jnp.asarray(positions % ps))
         if prefix_len > 0:
-            # prefix-cache hit: pages[:prefix_len//ps] already hold the
-            # prefix KV; compute only the suffix, attending through the
-            # full page table (suffix writes never touch shared pages —
-            # every write position is >= prefix_len)
-            suffix = req.prompt_tokens[prefix_len:]
-            ls = len(suffix)
-            bucket = self.cfg.bucket_for(ls)
-            tokens = np.zeros(bucket, np.int32)
-            tokens[:ls] = suffix
-            positions = prefix_len + np.arange(bucket, dtype=np.int32)
-            page_rows = np.zeros(bucket, np.int32)
-            for i in range(bucket):
-                pi = (prefix_len + i) // ps
-                page_rows[i] = pages[pi] if pi < len(pages) else 0
-            slot_positions = positions % ps
+            # attend through the full page table (suffix writes never
+            # touch shared pages: every write position is >= prefix_len)
             table = np.zeros(self.max_pages_per_seq, np.int32)
             table[:len(pages)] = pages
             program = lm.prefill_with_prefix
-            args = (jnp.asarray(page_rows), jnp.int32(ls),
-                    jnp.asarray(slot_positions), jnp.asarray(table),
-                    jnp.asarray(positions))
-        else:
-            bucket = self.cfg.bucket_for(n)
-            tokens = np.zeros(bucket, np.int32)
-            tokens[:n] = req.prompt_tokens
-            # map each padded position to (page, slot); positions beyond
-            # the allocated pages land in the null page (masked out of
-            # attention)
-            page_rows = np.zeros(bucket, np.int32)
-            for i in range(bucket):
-                pi = i // ps
-                page_rows[i] = pages[pi] if pi < len(pages) else 0
-            slot_positions = np.arange(bucket, dtype=np.int32) % ps
-            program = lm.prefill
-            args = (jnp.asarray(page_rows), jnp.int32(n),
-                    jnp.asarray(slot_positions))
+            args += (jnp.asarray(table), jnp.asarray(positions))
         tokens = jnp.asarray(tokens)
         ph.vals = (bucket, prefix_len)
         ph.begin(P_PREFILL_DISPATCH, req, (bucket,))

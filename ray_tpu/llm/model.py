@@ -6,48 +6,66 @@ for the whole engine (static [max_slots] batch) and one compiled prefill per
 length bucket.  Nothing that depends on a sequence's length is a Python
 branch or a program variant.
 
-The decode step carries the two page pools through its layer scan whole: a
-layer scatters its B new K/V rows into the pool in place, then
-``ops/paged_attention.paged_decode_attention`` walks each slot's page table
-as far as that slot's position and reads those pages out of the pool where
-it lies, at KV-head width (one page read serves every query head of a
-group), with a running float32 softmax; an inactive slot costs nothing.
-The donated pools are aliased to the outputs: nothing pool-sized is
-sliced, stacked or copied.  Prefill attends within the sequence it is
-given (a dense causal ``[L, L]`` score matrix); ``prefill_with_prefix``
-still gathers the whole page table and repeats K/V to the query heads, as
-the decode step did before the kernel (PERF.md section 7).
-The reference gets this from vLLM's CUDA kernels; here it is jax/XLA and
-Pallas native (SURVEY.md §7 step 8: "continuous-batching engine on TPU,
-paged attention, static-shape token buckets to avoid recompiles").
+Every program here is models/llama.py's parts (``embed``, ``layer``,
+``head``) around an ``attend(q, k, v, cache) -> (out, cache)`` of its own:
+the block is not written here, only the three ways it attends.
+
+- ``prefill``: within the padded sequence it is given, a dense causal
+  ``[L, L]`` score matrix over this call's own k and v.
+- ``prefill_with_prefix``: through the sequence's page table; it still
+  gathers the whole table and repeats K/V to the query heads, as the decode
+  step did before the kernel (PERF.md section 7).
+- ``_decode_impl`` (``decode_step``, ``decode_step_greedy``): through
+  ``ops/paged_attention.paged_decode_attention``, which walks each slot's
+  page table as far as that slot's position and reads those pages out of
+  the pool where it lies, at KV-head width (one page read serves every query
+  head of a group), with a running float32 softmax; an inactive slot costs
+  nothing.
+
+Each ``attend`` first writes its new K/V rows into the layer's pages.  The
+two prefills scan over the pools (a layer's step sees that layer's pages);
+the decode step carries both pools through its layer scan whole and scatters
+in place, so the donated pools are aliased to the outputs and nothing
+pool-sized is sliced, stacked or copied.  The reference gets this from
+vLLM's CUDA kernels; here it is jax/XLA and Pallas native (SURVEY.md §7
+step 8).
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import LlamaConfig, rms_norm, rope
+from ray_tpu.models.llama import LlamaConfig, embed, head, layer
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
-def _qkv(cfg: LlamaConfig, p, h):
-    q = (h @ p["attn"]["wq"].astype(h.dtype)).reshape(
-        *h.shape[:-1], cfg.n_heads, cfg.head_dim)
-    k = (h @ p["attn"]["wk"].astype(h.dtype)).reshape(
-        *h.shape[:-1], cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["attn"]["wv"].astype(h.dtype)).reshape(
-        *h.shape[:-1], cfg.n_kv_heads, cfg.head_dim)
-    return q, k, v
+def _masked_attention(cfg: LlamaConfig, q, keys, vals, mask):
+    """Dense softmax attention of q [L, H, d] over keys/vals [T, Hkv, d]
+    repeated to the query heads, where ``mask`` [L, T] allows."""
+    rep = cfg.n_heads // cfg.n_kv_heads
+    keys = jnp.repeat(keys, rep, axis=1)  # [T, H, d]
+    vals = jnp.repeat(vals, rep, axis=1)
+    scores = jnp.einsum("qhd,khd->hqk", q, keys) / (cfg.head_dim ** 0.5)
+    scores = jnp.where(mask[None], scores, -1e30)
+    attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
 
 
-def _mlp(p, h):
-    gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
-    up = h @ p["mlp"]["w_up"].astype(h.dtype)
-    return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
+def _prefill_layers(params, x, cache_k, cache_v, positions, true_len, attend,
+                    cfg: LlamaConfig):
+    """What the two prefills share: the layer scan over (layer params, that
+    layer's pages of each pool), and the last real token's logits.
+    ``attend(q, k, v, (ck_l, cv_l))`` writes and attends its own way."""
+    def body(x, per_layer):
+        p, ck_l, cv_l = per_layer
+        return layer(cfg, p, x, positions, attend, (ck_l, cv_l))
+
+    x, (cache_k, cache_v) = jax.lax.scan(
+        body, x, (params["layers"], cache_k, cache_v))
+    return head(params, x, cfg, true_len), cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -60,42 +78,23 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     Writes K/V for positions < true_len into the paged cache and returns
     (logits_at_last_token [V], cache_k, cache_v).
     """
-    L = tokens.shape[0]
-    x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [L, D]
-    positions = jnp.arange(L)
+    x = embed(params, tokens, cfg)  # [L, D]
+    positions = jnp.arange(tokens.shape[0])
     causal = positions[None, :] <= positions[:, None]  # [L, L]
     valid = positions[None, :] < true_len
     mask = causal & valid
 
-    def body(x, layer):
-        p, ck_l, cv_l = layer
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, h)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    def attend(q, k, v, pages):
+        ck_l, cv_l = pages
         # write k/v into this layer's pages (beyond true_len the rows
         # write into the sequence's own pages — masked out of attention)
         ck_l = ck_l.at[page_rows, slot_positions].set(k)
         cv_l = cv_l.at[page_rows, slot_positions].set(v)
-        # full-sequence causal attention (GQA: repeat kv heads)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        kf = jnp.repeat(k, rep, axis=1)
-        vf = jnp.repeat(v, rep, axis=1)
-        scores = jnp.einsum("qhd,khd->hqk", q, kf) / (cfg.head_dim ** 0.5)
-        scores = jnp.where(mask[None], scores, -1e30)
-        attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("hqk,khd->qhd", attn.astype(vf.dtype), vf)
-        x = x + out.reshape(L, -1) @ p["attn"]["wo"].astype(x.dtype)
-        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(p, h)
-        return x, (ck_l, cv_l)
+        # within the sequence: this call's own k and v, never the pool
+        return _masked_attention(cfg, q, k, v, mask), (ck_l, cv_l)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["layers"], cache_k, cache_v))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
-    logits = last.astype(jnp.float32) @ params["lm_head"]
-    return logits, cache_k, cache_v
+    return _prefill_layers(params, x, cache_k, cache_v, positions, true_len,
+                           attend, cfg)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -115,17 +114,12 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     null page, padded query rows, and future suffix columns all drop out.
     Returns (logits at the last suffix token [V], cache_k, cache_v).
     """
-    L = tokens.shape[0]
     P = page_table.shape[0]
     page_size = cache_k.shape[2]
-    x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [L, D]
+    x = embed(params, tokens, cfg)  # [L, D]
 
-    def body(x, layer):
-        p, ck_l, cv_l = layer
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, h)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    def attend(q, k, v, pages):
+        ck_l, cv_l = pages
         # suffix writes go to the sequence's own fresh pages only: matched
         # prefix pages cover positions < prefix_len and are never written
         ck_l = ck_l.at[page_rows, slot_positions].set(k)
@@ -134,26 +128,12 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                                         cfg.head_dim)
         vals = cv_l[page_table].reshape(P * page_size, cfg.n_kv_heads,
                                         cfg.head_dim)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        keys = jnp.repeat(keys, rep, axis=1)  # [T, H, d]
-        vals = jnp.repeat(vals, rep, axis=1)
-        scores = jnp.einsum("qhd,khd->hqk", q, keys) / (cfg.head_dim ** 0.5)
         tpos = jnp.arange(P * page_size)[None]  # [1, T]
         mask = tpos <= positions[:, None]  # [L, T] causal over absolutes
-        scores = jnp.where(mask[None], scores, -1e30)
-        attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
-        x = x + out.reshape(L, -1) @ p["attn"]["wo"].astype(x.dtype)
-        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(p, h)
-        return x, (ck_l, cv_l)
+        return _masked_attention(cfg, q, keys, vals, mask), (ck_l, cv_l)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["layers"], cache_k, cache_v))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
-    logits = last.astype(jnp.float32) @ params["lm_head"]
-    return logits, cache_k, cache_v
+    return _prefill_layers(params, x, cache_k, cache_v, positions, true_len,
+                           attend, cfg)
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
@@ -171,7 +151,7 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     """
     P = page_tables.shape[1]
     page_size = cache_k.shape[2]
-    x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [B, D]
+    x = embed(params, tokens, cfg)  # [B, D]
 
     # where this step's k/v lands: slot b writes page_tables[b, pos//ps].
     # Inactive slots, and a burst's overshoot past the table's last page,
@@ -185,29 +165,23 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     # attend up to and including the current token; 0 skips the slot
     lengths = jnp.where(active, positions + 1, 0)
 
-    def body(carry, layer):
-        x, ck, cv = carry
-        p, li = layer
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, h)  # q: [B, H, d]; k,v: [B, Hkv, d]
-        q = rope(q[:, None], positions[:, None],
-                 cfg.rope_theta)[:, 0]
-        k = rope(k[:, None], positions[:, None],
-                 cfg.rope_theta)[:, 0]
+    def attend(q, k, v, pools):  # q: [B, H, d]; k, v: [B, Hkv, d]
+        ck, cv, li = pools
         ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
         cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
-        out = paged_decode_attention(q, ck, cv, page_tables, lengths, li)
-        x = x + out.reshape(x.shape[0], -1) @ p["attn"]["wo"].astype(x.dtype)
-        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(p, h)
+        return (paged_decode_attention(q, ck, cv, page_tables, lengths, li),
+                (ck, cv))
+
+    def body(carry, per_layer):
+        x, ck, cv = carry
+        p, li = per_layer
+        x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li))
         return (x, ck, cv), None
 
     (x, cache_k, cache_v), _ = jax.lax.scan(
         body, (x, cache_k, cache_v),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x.astype(jnp.float32) @ params["lm_head"]
-    return logits, cache_k, cache_v
+    return head(params, x, cfg), cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))

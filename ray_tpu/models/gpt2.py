@@ -3,6 +3,10 @@
 Same functional conventions as models/llama.py: dict pytrees, scan-stacked
 layers, logical sharding specs.  Learned positional embeddings, pre-LN,
 GELU MLP, untied LM head off the tied embedding (GPT-2 ties them).
+
+The block shares no part with models/llama.py's: layer norm with a bias
+(not RMS norm), one fused biased QKV product (not three without), learned
+positions (not rotated q and k), GELU (not a gate).
 """
 
 from __future__ import annotations

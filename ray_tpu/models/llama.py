@@ -6,6 +6,13 @@ every parameter carries a *logical* sharding spec (parallel/sharding.py) so
 the same definition runs single-chip, FSDP, TP, or any mesh combination.
 The reference delegates this entire layer to torch/vLLM engines; here it is
 native (SURVEY.md §2.4, §7 step 7).
+
+The decoder block is written here once, as parts (``embed`` ... ``head``
+below), and every Llama-family forward pass composes them around the one
+thing that differs, how it attends: this file's training forward,
+``models/moe.py`` (its own feed-forward after ``attention_block``) and the
+engine's cache-aware programs (``llm/model.py``).  ``models/gpt2.py`` shares
+none of them, and says why.
 """
 
 from __future__ import annotations
@@ -68,8 +75,10 @@ class LlamaConfig:
                            max_seq_len=512, remat=True, loss_chunk=128)
 
 
-def param_logical_specs(cfg: LlamaConfig):
-    """Logical sharding spec tree, mirroring init()'s param tree."""
+def decoder_logical_specs(feed_forward: dict):
+    """Logical sharding spec tree of a Llama-family decoder around its
+    feed-forward's own entries (``{"mlp": ...}`` here, router and experts
+    in models/moe.py), mirroring init()'s param tree."""
     layer = {
         "attn": {
             "wq": L("layers", "embed", "heads"),
@@ -77,11 +86,7 @@ def param_logical_specs(cfg: LlamaConfig):
             "wv": L("layers", "embed", "kv_heads"),
             "wo": L("layers", "heads", "embed"),
         },
-        "mlp": {
-            "w_gate": L("layers", "embed", "mlp"),
-            "w_up": L("layers", "embed", "mlp"),
-            "w_down": L("layers", "mlp", "embed"),
-        },
+        **feed_forward,
         "attn_norm": L("layers", "norm"),
         "mlp_norm": L("layers", "norm"),
     }
@@ -91,6 +96,14 @@ def param_logical_specs(cfg: LlamaConfig):
         "final_norm": L("norm",),
         "lm_head": L("embed", "vocab"),
     }
+
+
+def param_logical_specs(cfg: LlamaConfig):
+    return decoder_logical_specs({"mlp": {
+        "w_gate": L("layers", "embed", "mlp"),
+        "w_up": L("layers", "embed", "mlp"),
+        "w_down": L("layers", "mlp", "embed"),
+    }})
 
 
 def init(cfg: LlamaConfig, key: jax.Array):
@@ -128,6 +141,13 @@ def init(cfg: LlamaConfig, key: jax.Array):
     }
 
 
+# ---------------------------------------------------------------------------
+# The block, as parts.  ``p`` is one layer's slice of params["layers"].
+
+def embed(params, tokens, cfg):
+    return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+
+
 def rms_norm(x, weight, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(
@@ -135,11 +155,12 @@ def rms_norm(x, weight, eps):
 
 
 def rope(x, positions, theta):
-    """Rotary embedding; x: (..., seq, heads, head_dim)."""
+    """Rotary embedding; x: (..., heads, head_dim), positions: (...) or
+    anything that broadcasts against x's leading axes."""
     head_dim = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
                       / (head_dim // 2))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (.., s, d/2)
+    angles = positions[..., None].astype(jnp.float32) * freqs  # (.., d/2)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -147,42 +168,78 @@ def rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-def _attention(q, k, v, attn_impl, mesh, rules=None):
-    """Dispatch dense flash vs sequence-parallel attention
-    (ring / zigzag-balanced ring / ulysses)."""
-    if attn_impl in ("ring", "zigzag", "ulysses"):
-        from ray_tpu.ops.ring_attention import sequence_parallel_attention
+def qkv_rope(cfg, p, h, positions):
+    """The normed stream h (..., d_model) projected and split into heads,
+    q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width."""
+    def heads(w, n):
+        return (h @ w.astype(h.dtype)).reshape(*h.shape[:-1], n, cfg.head_dim)
 
-        if mesh is None:
-            raise ValueError(f"attn_impl={attn_impl!r} requires a mesh")
-        return sequence_parallel_attention(q, k, v, mesh, impl=attn_impl,
-                                           causal=True, rules=rules)
-    return flash_attention(q, k, v, causal=True, impl=attn_impl, mesh=mesh,
-                           rules=rules)
+    q = heads(p["attn"]["wq"], cfg.n_heads)
+    k = heads(p["attn"]["wk"], cfg.n_kv_heads)
+    v = heads(p["attn"]["wv"], cfg.n_kv_heads)
+    return (rope(q, positions, cfg.rope_theta),
+            rope(k, positions, cfg.rope_theta), v)
+
+
+def attention_block(cfg, p, x, positions, attend, cache=None):
+    """x + attention(norm(x)).  ``attend(q, k, v, cache) -> (out, cache)``
+    is the one thing that differs between forward passes: training attends
+    within the batch and has no cache; the engine's programs write k and v
+    into the layer's pages and attend through them.  ``cache`` is whatever
+    the caller's layer scan hands its strategy, and comes back with x."""
+    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    out, cache = attend(*qkv_rope(cfg, p, h, positions), cache)
+    out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim)
+    return x + out @ p["attn"]["wo"].astype(x.dtype), cache
+
+
+def gated_mlp(p, h):
+    gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
+    up = h @ p["mlp"]["w_up"].astype(h.dtype)
+    return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
+
+
+def layer(cfg, p, x, positions, attend, cache=None):
+    """One dense decoder layer: (x, cache)."""
+    x, cache = attention_block(cfg, p, x, positions, attend, cache)
+    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    return x + gated_mlp(p, h), cache
+
+
+def head(params, x, cfg, true_len=None):
+    """Final norm, then ``lm_head`` in float32 (what sampling and the MoE
+    loss take).  ``true_len``: x [L, d_model] is one padded sequence of
+    which only the last real token's logits are wanted."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if true_len is not None:
+        x = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
+    return x.astype(jnp.float32) @ params["lm_head"]
+
+
+# ---------------------------------------------------------------------------
+# The training forward: attention within the batch, no cache.
+
+def batch_attend(attn_impl, mesh, rules=None):
+    """The training passes' ``attend``: dense flash or sequence-parallel
+    attention (ring / zigzag-balanced ring / ulysses); no cache."""
+    def attend(q, k, v, cache):
+        if attn_impl in ("ring", "zigzag", "ulysses"):
+            from ray_tpu.ops.ring_attention import sequence_parallel_attention
+
+            if mesh is None:
+                raise ValueError(f"attn_impl={attn_impl!r} requires a mesh")
+            return sequence_parallel_attention(
+                q, k, v, mesh, impl=attn_impl, causal=True,
+                rules=rules), cache
+        return flash_attention(q, k, v, causal=True, impl=attn_impl,
+                               mesh=mesh, rules=rules), cache
+    return attend
 
 
 def _layer(cfg: LlamaConfig, x, layer_params, positions, attn_impl, mesh,
            rules):
-    p = layer_params
-    b, s, d = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = (h @ p["attn"]["wq"].astype(h.dtype)).reshape(
-        b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["attn"]["wk"].astype(h.dtype)).reshape(
-        b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["attn"]["wv"].astype(h.dtype)).reshape(
-        b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    attn = _attention(q, k, v, attn_impl, mesh, rules)
-    attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
-    x = x + attn @ p["attn"]["wo"].astype(h.dtype)
-
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
-    up = h @ p["mlp"]["w_up"].astype(h.dtype)
-    x = x + (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
-    return x
+    return layer(cfg, layer_params, x, positions,
+                 batch_attend(attn_impl, mesh, rules))[0]
 
 
 def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
@@ -194,8 +251,7 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
     attn_impl "ring"/"ulysses" (with a mesh) enables sequence-parallel
     attention over the sp axis for long-context training.
     """
-    dtype = jnp.dtype(cfg.dtype)
-    x = params["embed"][tokens].astype(dtype)
+    x = embed(params, tokens, cfg)
     positions = jnp.arange(tokens.shape[1])[None, :]
 
     step = partial(_layer, cfg, positions=positions, attn_impl=attn_impl,

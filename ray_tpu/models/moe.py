@@ -8,7 +8,8 @@ tensor (GShard-style), so XLA emits the token all-to-all from the shardings
 alone.  Everything is static-shape: top-k routing, capacity dropping, and
 combine are MXU-friendly dense ops — no ragged gathers.
 
-Attention/norm/rope are shared with the Llama block (models/llama.py).
+The block's attention half, the embedding and the epilogue are the Llama
+family's parts (models/llama.py); only the feed-forward is this file's own.
 """
 
 from __future__ import annotations
@@ -19,7 +20,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _attention, rms_norm, rope
+from ray_tpu.models.llama import (attention_block, batch_attend,
+                                  decoder_logical_specs, embed, head,
+                                  rms_norm)
 from ray_tpu.parallel.sharding import logical_spec as L
 
 
@@ -57,28 +60,14 @@ class MoEConfig:
 
 
 def param_logical_specs(cfg: MoEConfig):
-    layer = {
-        "attn": {
-            "wq": L("layers", "embed", "heads"),
-            "wk": L("layers", "embed", "kv_heads"),
-            "wv": L("layers", "embed", "kv_heads"),
-            "wo": L("layers", "heads", "embed"),
-        },
+    return decoder_logical_specs({
         "router": L("layers", "embed", None),
         "experts": {
             "w_gate": L("layers", "experts", "embed", "expert_mlp"),
             "w_up": L("layers", "experts", "embed", "expert_mlp"),
             "w_down": L("layers", "experts", "expert_mlp", "embed"),
         },
-        "attn_norm": L("layers", "norm"),
-        "mlp_norm": L("layers", "norm"),
-    }
-    return {
-        "embed": L("vocab", "embed"),
-        "layers": layer,
-        "final_norm": L("norm",),
-        "lm_head": L("embed", "vocab"),
-    }
+    })
 
 
 def init(cfg: MoEConfig, key: jax.Array):
@@ -181,20 +170,8 @@ def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,
            rules):
     x, aux_sum = carry
     p = layer_params
-    b, s, d = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = (h @ p["attn"]["wq"].astype(h.dtype)).reshape(
-        b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["attn"]["wk"].astype(h.dtype)).reshape(
-        b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["attn"]["wv"].astype(h.dtype)).reshape(
-        b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    attn = _attention(q, k, v, attn_impl, mesh, rules)
-    attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
-    x = x + attn @ p["attn"]["wo"].astype(h.dtype)
-
+    x, _ = attention_block(cfg, p, x, positions,
+                           batch_attend(attn_impl, mesh, rules))
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     moe_out, aux = moe_mlp(cfg, h, p["router"], p["experts"])
     return (x + moe_out, aux_sum + aux)
@@ -203,8 +180,7 @@ def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,
 def apply(params, tokens, cfg: MoEConfig, attn_impl: str = "auto",
           mesh=None, rules=None, return_aux: bool = False):
     """Forward: tokens (B, S) -> logits (B, S, vocab) [, aux_loss]."""
-    dtype = jnp.dtype(cfg.dtype)
-    x = params["embed"][tokens].astype(dtype)
+    x = embed(params, tokens, cfg)
     positions = jnp.arange(tokens.shape[1])[None, :]
 
     step = partial(_layer, cfg, positions=positions, attn_impl=attn_impl,
@@ -217,8 +193,7 @@ def apply(params, tokens, cfg: MoEConfig, attn_impl: str = "auto",
 
     (x, aux), _ = jax.lax.scan(
         scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x.astype(jnp.float32) @ params["lm_head"]
+    logits = head(params, x, cfg)
     aux = aux / cfg.n_layers
     return (logits, aux) if return_aux else logits
 

@@ -494,7 +494,9 @@ def cmd_goodput(args):
         print(f"steady-state throughput: {tok:,.0f} tok/s "
               f"(post-warmup steps only)")
     if s.get("mfu") is not None:
-        print(f"mfu: {s['mfu']:.3f} (counted flops per MFU_PROFILE.md)")
+        print(f"mfu: {s['mfu']:.3f} (counted flops: 6*N*tokens or the "
+              f"compiled program's cost analysis, over "
+              f"RTPU_GOODPUT_PEAK_TFLOPS)")
     print("---- wall-time attribution (sums to elapsed) ----")
     for name in goodput_mod.BUCKETS:
         sec = s["buckets"].get(name, 0.0)

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 Every process that compiles calls ``enable()`` before it does: worker
-start-up, the test session, ``chip_smoke.py``, ``bench.py``.  A directory
+start-up, the test session, ``chip_smoke.py``.  A directory
 given from outside in ``JAX_COMPILATION_CACHE_DIR`` is JAX's own setting
 and is left alone.  Otherwise the cache goes to one fixed, git-ignored
 directory inside the checkout: the path is part of the cache key, so a

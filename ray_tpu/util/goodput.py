@@ -47,12 +47,11 @@ Records ride the existing push plane (``goodput_push`` — the same lane as
 by ``RTPU_GOODPUT_CAP``), and surface through ``state.get_goodput``, the
 dashboard's ``/api/goodput``, and ``rtpu goodput``.
 
-MFU accounting matches MFU_PROFILE.md / bench.py: counted FLOPs per step
-come from the compiled program's ``cost_analysis()`` when available, else
-the analytic dense-LM ``6 * n_params * tokens`` (attention inner products
-and non-matmul work are NOT counted as useful flops), divided by
-``RTPU_GOODPUT_PEAK_TFLOPS`` (default 197, the v5e bf16 peak — the same
-denominator as bench.py's ``mfu_vs_v5e_peak``).
+MFU accounting: counted FLOPs per step come from the compiled program's
+``cost_analysis()`` when available, else the analytic dense-LM
+``6 * n_params * tokens`` (attention inner products and non-matmul work are
+NOT counted as useful flops), divided by ``RTPU_GOODPUT_PEAK_TFLOPS``
+(default 197, the v5e's bf16 peak in TFLOP/s).
 """
 
 from __future__ import annotations
@@ -105,8 +104,8 @@ def _instruments() -> dict:
                 "mfu": Gauge(
                     "train_mfu",
                     "Model flops utilization vs RTPU_GOODPUT_PEAK_TFLOPS "
-                    "(counted flops per MFU_PROFILE.md: 6*N*tokens or "
-                    "compiled cost_analysis)", tag_keys=("run",)),
+                    "(counted flops: 6*N*tokens or compiled "
+                    "cost_analysis)", tag_keys=("run",)),
                 "tflops": Gauge(
                     "train_model_tflops_per_s",
                     "Counted model TFLOP/s over steady-state steps",
@@ -130,7 +129,7 @@ def _instruments() -> dict:
 
 def analytic_step_flops(n_params: int, tokens: int) -> float:
     """Dense-LM counted flops for one step: 6*N*tokens (fwd 2N + bwd 4N per
-    token; attention inner products excluded — MFU_PROFILE.md's rule)."""
+    token; attention inner products and non-matmul work excluded)."""
     return 6.0 * float(n_params) * float(tokens)
 
 

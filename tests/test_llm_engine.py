@@ -134,3 +134,66 @@ def test_temperature_sampling_seeded(tiny_model):
     assert a == b
     assert len(a) == 8
     assert a != c or True  # different seed usually differs; no hard assert
+
+
+# -- the seam: each program's logits against the training forward -----------
+
+@pytest.mark.parametrize("program",
+                         ["prefill", "prefill_with_prefix", "decode_step"])
+def test_program_logits_match_full_forward(program):
+    """The engine's programs called directly, on scattered pages: the
+    logits they return for the prompt's last token are ``llama.apply``'s
+    at that position, whichever way the program attends."""
+    import dataclasses
+
+    from ray_tpu.llm import model as lm
+    from ray_tpu.llm.paged_cache import CacheConfig, init_cache
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
+                              dtype="float32")
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    ps, table_width, n = 8, 4, 21
+    pages = np.asarray([3, 5, 2], np.int32)  # 24 positions; page 0 is null
+    prompt = np.random.default_rng(0).integers(1, 128, size=n).astype(
+        np.int32)
+    want = np.asarray(llama.apply(params, jnp.asarray(prompt[None]), cfg))[0]
+    cache = init_cache(CacheConfig(
+        n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, num_pages=8, page_size=ps, dtype=cfg.dtype))
+    table = np.zeros(table_width, np.int32)
+    table[:len(pages)] = pages
+
+    def prefill_args(start, stop, bucket):
+        """tokens[start:stop] padded to the bucket, and where they land."""
+        tokens = np.zeros(bucket, np.int32)
+        tokens[:stop - start] = prompt[start:stop]
+        positions = start + np.arange(bucket, dtype=np.int32)
+        page_rows = table[positions // ps]  # past the pages: the null page
+        return (jnp.asarray(tokens), jnp.asarray(page_rows),
+                jnp.int32(stop - start), jnp.asarray(positions % ps),
+                jnp.asarray(positions))
+
+    def prefill(stop, bucket):
+        tokens, rows, true_len, slots, _ = prefill_args(0, stop, bucket)
+        return lm.prefill(params, tokens, *cache, rows, true_len, slots, cfg)
+
+    if program == "prefill":
+        got, _, _ = prefill(n, 32)
+    elif program == "prefill_with_prefix":
+        _, *cache = prefill(2 * ps, 16)  # a 2-page resident prefix
+        tokens, rows, true_len, slots, positions = prefill_args(2 * ps, n, 16)
+        got, _, _ = lm.prefill_with_prefix(
+            params, tokens, *cache, rows, true_len, slots,
+            jnp.asarray(table), positions, cfg)
+    else:
+        _, *cache = prefill(n - 1, 32)
+        # slot 1 decodes the prompt's last token; slot 0 is empty
+        got, _, _ = lm.decode_step(
+            params, jnp.asarray([0, prompt[-1]], jnp.int32), *cache,
+            jnp.asarray(np.stack([np.zeros_like(table), table])),
+            jnp.asarray([0, n - 1], jnp.int32), jnp.asarray([False, True]),
+            cfg)
+        got = got[1]
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want[n - 1], atol=2e-5,
+                               rtol=2e-5)
