@@ -86,6 +86,10 @@ def _engine_metrics():
                 "page_evictions": Counter(
                     "llm_page_evictions_total", "Prefix-cache pages "
                     "reclaimed to satisfy allocations"),
+                "eviction_scans": Counter(
+                    "llm_eviction_scans_total", "Passes over the prefix "
+                    "cache's resident blocks (page evictions / scans = "
+                    "pages one pass reclaimed)"),
                 "prefill_saved": Counter(
                     "llm_prefill_tokens_saved_total", "Prompt tokens whose "
                     "prefill compute was skipped via resident prefix pages "
@@ -401,6 +405,7 @@ class LLMEngine:
                        "decode_pages_read": 0,
                        "tokens_generated": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
+                       "eviction_scans": 0,
                        "prefill_tokens_saved": 0, "cow_copies": 0,
                        "kv_seals": 0, "kv_pulls": 0, "kv_pull_pages": 0,
                        "kv_pull_fallbacks": 0}
@@ -985,23 +990,27 @@ class LLMEngine:
         return out
 
     def _reserve(self, n: int) -> bool:
-        """Make n pages allocatable, reclaiming LRU prefix-cache pages as
-        needed.  Returns False (leaving partial reclaims in place — they
-        were the coldest blocks anyway) if the pool can't cover it."""
-        if n <= 0:
+        """Make n pages allocatable, reclaiming prefix-cache pages as
+        needed: ONE `PrefixCache.evict` for all the pages that are short,
+        so a phase pays one pass over the resident blocks however many
+        pages it needs.  Returns False (leaving partial reclaims in place
+        — they were the coldest blocks anyway) if the pool can't cover
+        it."""
+        short = n - self.allocator.num_free()
+        if short <= 0:
             return True
         pc = self.prefix_cache
-        while self.allocator.num_free() < n:
-            hit = pc.evict_one(self.allocator.refcount) \
-                if pc is not None else None
-            if hit is None:
-                return False
-            page, klass = hit
+        if pc is None:
+            return False
+        hits = pc.evict(self.allocator.refcount, short)
+        self._stats["eviction_scans"] += 1
+        self._m["eviction_scans"].inc()
+        for page, klass in hits:
             self.allocator.reclaim(page)
             self._stats["page_evictions"] += 1
             self._m["page_evictions"].inc()
             self._m["cache_evictions"].inc(1, {"class": klass})
-        return True
+        return len(hits) == short
 
     def _register_blocks(self, tokens: List[int], pages: List[int]) -> None:
         if self.prefix_cache is None:
@@ -1275,14 +1284,23 @@ class LLMEngine:
         order = sorted(
             ((i, s) for i, s in enumerate(self._slots) if s is not None),
             key=lambda t: t[1].request.submitted_at)
+
+        def need_pages(s: _Slot) -> int:
+            sp = s.request.params
+            remaining = max(1, sp.max_tokens - s.request.produced)
+            k = min(steps, remaining)
+            need = min((s.num_tokens + k - 1) // ps + 1,
+                       self.max_pages_per_seq)
+            return need - len(s.pages)
+
+        # one pass over the prefix cache for the whole burst: when it
+        # covers the sum every slot below finds its pages free; when it
+        # cannot, everything reclaimable is already back and the loop
+        # fails at the slot, and preempts the victim, it always did
+        self._reserve(sum(max(0, need_pages(s)) for _, s in order))
         for i, s in order:
             while self._slots[i] is s:
-                sp = s.request.params
-                remaining = max(1, sp.max_tokens - s.request.produced)
-                k = min(steps, remaining)
-                need = min((s.num_tokens + k - 1) // ps + 1,
-                           self.max_pages_per_seq)
-                delta = need - len(s.pages)
+                delta = need_pages(s)
                 if delta <= 0:
                     break
                 if self._reserve(delta):

@@ -24,9 +24,12 @@ Converting locality into throughput (ISSUE 14) adds:
 
 - per-family heat: every block belongs to the family of its chain's root
   digest; families track hit count, resident-block count, and last-hit
-  time, and `evict_one` reclaims leaf-first inside the COLDEST family
+  time, and `evict` reclaims leaf-first inside the COLDEST family
   instead of walking a global LRU — a burst of unique traffic can no
-  longer shred a hot shared root that queued requests are about to hit;
+  longer shred a hot shared root that queued requests are about to hit.
+  `evict(refcount, n)` takes all the pages a phase of the engine loop
+  needs in ONE pass over the resident blocks (a heap of the evictable
+  leaves, fed by the parents each eviction bares), not a pass a page;
 - partial-block (copy-on-write) matching: blocks remember their token
   content, so a prompt that diverges INSIDE a cached block still reuses
   the shared slots — the engine copies that single page and prefills only
@@ -36,6 +39,7 @@ Converting locality into throughput (ISSUE 14) adds:
 from __future__ import annotations
 
 import hashlib
+import heapq
 import os
 import time
 from collections import OrderedDict
@@ -426,9 +430,11 @@ class PrefixCache:
     def _is_leaf(self, d: bytes) -> bool:
         return not self._children.get(d)
 
-    def evict_one(self, refcount: Callable[[int], int]
-                  ) -> Optional[Tuple[int, str]]:
-        """Reclaim one block: leaf-first within the COLDEST family.
+    def evict(self, refcount: Callable[[int], int], n: int
+              ) -> List[Tuple[int, str]]:
+        """Reclaim up to `n` blocks in ONE pass over the index; returns
+        (page, class) per block in eviction order, fewer than `n` when
+        every remaining block is pinned.
 
         Candidates are unreferenced blocks with no resident children;
         among them the family least recently hit loses a block (never-hit
@@ -441,31 +447,61 @@ class PrefixCache:
         every evictable block still has resident children (its leaves are
         all pinned) is a chain cut at an interior block, oldest first —
         class "hot_root_forced", the event the bench counts as throwing
-        locality away.  Returns (page, class) or None if every block is
-        pinned."""
-        for spine_ok in (False, True):
-            best: Optional[_Block] = None
-            best_heat: Optional[Tuple[float, int]] = None
-            for d, blk in self._blocks.items():  # oldest-first = LRU
-                if refcount(blk.page) > 0 or not self._is_leaf(d):
-                    continue
-                if blk.was_hit and not spine_ok:
-                    continue
-                fam = self._families.get(blk.root)
-                heat = ((fam.last_hit, fam.hits) if fam is not None
-                        else (0.0, 0))
-                if best_heat is None or heat < best_heat:
-                    best, best_heat = blk, heat
-            if best is not None:
-                self._remove(best)
+        locality away.
+
+        The scan keys every candidate (never-hit before reused, family
+        heat, LRU position) into a heap.  Evicting a block can turn its
+        parent into a leaf, which then joins the heap under its own key
+        (a chain drains from its tip), so the order is the one `n`
+        successive calls for one block give: no match happens inside a
+        call, hence neither heat, `was_hit`, refcounts nor the relative
+        LRU order of the survivors can change under it."""
+        out: List[Tuple[int, str]] = []
+        if n <= 0:
+            return out
+        # every unreferenced block's LRU position, oldest first (also the
+        # order of the forced cuts); the leaves among them start the heap
+        rank: Dict[bytes, int] = {}
+        heap: List[tuple] = []
+        for i, (d, blk) in enumerate(self._blocks.items()):
+            if refcount(blk.page) > 0:
+                continue
+            rank[d] = i
+            if self._is_leaf(d):
+                heap.append(self._evict_key(blk, i))
+        heapq.heapify(heap)
+        oldest = iter(rank)
+        while len(out) < n:
+            if heap:
+                blk = heapq.heappop(heap)[-1]
+                klass = "cold_family"
                 self.evictions_cold_family += 1
-                return best.page, "cold_family"
-        for d, blk in list(self._blocks.items()):
-            if refcount(blk.page) <= 0:
-                self._remove(blk)
+            else:
+                blk = next((b for d in oldest
+                            if (b := self._blocks.get(d)) is not None), None)
+                if blk is None:
+                    break
+                klass = "hot_root_forced"
                 self.evictions_hot_root_forced += 1
-                return blk.page, "hot_root_forced"
-        return None
+            self._remove(blk)
+            out.append((blk.page, klass))
+            if blk.parent in rank and blk.parent in self._blocks \
+                    and self._is_leaf(blk.parent):
+                heapq.heappush(heap, self._evict_key(
+                    self._blocks[blk.parent], rank[blk.parent]))
+        return out
+
+    def _evict_key(self, blk: _Block, rank: int) -> tuple:
+        fam = self._families.get(blk.root)
+        heat = (fam.last_hit, fam.hits) if fam is not None else (0.0, 0)
+        return (blk.was_hit, *heat, rank, blk)
+
+    def evict_one(self, refcount: Callable[[int], int]
+                  ) -> Optional[Tuple[int, str]]:
+        """`evict` for a single block: (page, class), or None if every
+        block is pinned."""
+        got = self.evict(refcount, 1)
+        return got[0] if got else None
 
     def digests(self, limit: Optional[int] = None) -> List[str]:
         """Most-recently-used block digests (hex) — the resident-prefix

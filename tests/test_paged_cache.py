@@ -3,11 +3,15 @@ matching, the digest-advertisement cap, and the allocator page-state
 invariant under a randomized admit/decode/preempt/evict storm
 (RTPU_DEBUG_ALLOCATOR asserts it after every op).
 
-Pure host-side structures — no jax, no engine — so these run in
+Host-side structures only — nothing is prefilled or decoded, the engine
+of the last section is driven by hand with no weights — so these run in
 milliseconds and pin the eviction-policy semantics the serving bench
-depends on.
+depends on.  The batched `PrefixCache.evict` is held, page for page,
+against the one-block-a-call walk it replaced, kept below as the plain
+reference.
 """
 
+import copy
 import random
 
 import pytest
@@ -230,3 +234,336 @@ def test_allocator_invariant_storm():
         alloc.reclaim(hit[0])
     assert alloc.num_free() == 31  # every page home again (0 is null)
     assert alloc.num_resident() == 0
+
+
+# ------------------------------- batched eviction against the reference
+
+
+def reference_evict_one(cache, refcount):
+    """The walk `PrefixCache.evict` replaced (PR 30), kept as the plain
+    reference: EVERY resident block is visited to give back ONE page."""
+    for spine_ok in (False, True):
+        best = None
+        best_heat = None
+        for d, blk in cache._blocks.items():  # oldest-first = LRU
+            if refcount(blk.page) > 0 or not cache._is_leaf(d):
+                continue
+            if blk.was_hit and not spine_ok:
+                continue
+            fam = cache._families.get(blk.root)
+            heat = ((fam.last_hit, fam.hits) if fam is not None
+                    else (0.0, 0))
+            if best_heat is None or heat < best_heat:
+                best, best_heat = blk, heat
+        if best is not None:
+            cache._remove(best)
+            cache.evictions_cold_family += 1
+            return best.page, "cold_family"
+    for d, blk in list(cache._blocks.items()):
+        if refcount(blk.page) <= 0:
+            cache._remove(blk)
+            cache.evictions_hot_root_forced += 1
+            return blk.page, "hot_root_forced"
+    return None
+
+
+def _fresh(rng, n):
+    return [rng.randrange(1000, 1_000_000) for _ in range(n)]
+
+
+def _unshared_chains(rng):
+    """What the benchmark's serving cells leave: never-hit chains, each a
+    family of its own, the pool full of them."""
+    alloc, cache = PageAllocator(512), PrefixCache(4)
+    for _ in range(rng.randrange(8, 20)):
+        _insert_chain(alloc, cache, _fresh(rng, 4 * rng.randrange(1, 12)))
+    return alloc, cache
+
+
+def _shared_spines(rng, pin=False):
+    """Families with a reused spine and unique tails; some families hit
+    again later (heat), so the spine pass has an order to get right."""
+    alloc, cache = PageAllocator(512), PrefixCache(4)
+    spines = [_fresh(rng, 4 * rng.randrange(1, 5))
+              for _ in range(rng.randrange(3, 7))]
+    tails = []
+    for _ in range(rng.randrange(10, 24)):
+        toks = rng.choice(spines) + _fresh(rng, 4 * rng.randrange(1, 5))
+        tails.append(_insert_chain(alloc, cache, toks))
+    for spine in rng.sample(spines, len(spines) // 2):
+        cache.match(spine + [7])
+    if pin:
+        # live sequences: some hold every block they registered (the
+        # whole chain, for a family's first request), some only the tip
+        for pages in rng.sample(tails, len(tails) // 2):
+            held = ([p for p in pages if alloc.is_cached(p)]
+                    if rng.random() < 0.5 else pages[-1:])
+            alloc.retain(held)
+    return alloc, cache
+
+
+def _family_empties(rng):
+    """One- and two-block families among long ones: a batch takes the
+    last block of several families (their heat rows go) on its way."""
+    alloc, cache = PageAllocator(512), PrefixCache(4)
+    for _ in range(rng.randrange(6, 12)):
+        _insert_chain(alloc, cache, _fresh(rng, 4 * rng.randrange(1, 3)))
+        _insert_chain(alloc, cache, _fresh(rng, 4 * rng.randrange(6, 10)))
+    return alloc, cache
+
+
+def _only_interior(rng):
+    """Every leaf is pinned, so chains are cut at interior blocks, oldest
+    first; shorter re-matches put some parents AFTER their children in
+    the LRU order, so a forced cut can free a parent as a leaf."""
+    alloc, cache = PageAllocator(512), PrefixCache(4)
+    for _ in range(rng.randrange(4, 9)):
+        toks = _fresh(rng, 4 * rng.randrange(3, 8))
+        pages = _insert_chain(alloc, cache, toks)
+        alloc.retain(pages[-1:])
+        if rng.random() < 0.6:
+            cache.match(toks[:4 * rng.randrange(1, len(pages))] + [7])
+    return alloc, cache
+
+
+def _never_hit_parent(rng):
+    """A reused block under a parent that was cut and came back never
+    hit: the parent jumps the spine queue the moment its child goes."""
+    alloc, cache = PageAllocator(512), PrefixCache(4)
+    for _ in range(rng.randrange(3, 7)):
+        toks = _fresh(rng, 12)
+        _, b, _ = _insert_chain(alloc, cache, toks)
+        cache.match(toks + [7])
+        # cut at b, as a pool whose every other block is pinned would
+        assert cache.evict(lambda p: int(p != b), 1) == [
+            (b, "hot_root_forced")]
+        alloc.reclaim(b)
+        _insert_chain(alloc, cache, toks)  # b's block is back, never hit
+        _insert_chain(alloc, cache, _fresh(rng, 8))
+    return alloc, cache
+
+
+def _storm(rng):
+    """Whatever a random admit/finish/hit/evict history leaves behind."""
+    alloc, cache = PageAllocator(96), PrefixCache(4)
+    live = []
+    for _ in range(rng.randrange(300, 900)):
+        op = rng.randrange(5)
+        if op == 0 and alloc.num_free() >= 4:
+            live.append(alloc.allocate(rng.randrange(1, 5)))
+        elif op == 1 and live:
+            pages = live.pop(rng.randrange(len(live)))
+            toks = [rng.randrange(3) for _ in range(len(pages) * 4)]
+            alloc.mark_cached(cache.insert(toks, pages))
+            alloc.free(pages)
+        elif op in (2, 3):
+            matched = cache.match([rng.randrange(3) for _ in range(17)])
+            if matched:
+                alloc.retain(matched)
+                live.append(matched)
+        elif alloc.num_free() < 8:
+            for page, _ in cache.evict(alloc.refcount, rng.randrange(1, 6)):
+                alloc.reclaim(page)
+    rng.shuffle(live)
+    for pages in live[len(live) // 3:]:  # most sequences end, some stay
+        alloc.free(pages)
+    return alloc, cache
+
+
+def _cache_state(cache):
+    return (list(cache._blocks), dict(cache._by_page),
+            {k: set(v) for k, v in cache._children.items()},
+            {r: (f.hits, f.blocks, f.last_hit)
+             for r, f in cache._families.items()},
+            cache.stats())
+
+
+SHAPES = {"unshared_chains": _unshared_chains,
+          "shared_spines": _shared_spines,
+          "pinned_leaves": lambda rng: _shared_spines(rng, pin=True),
+          "family_empties_mid_batch": _family_empties,
+          "only_interior_evictable": _only_interior,
+          "never_hit_parent": _never_hit_parent,
+          "storm": _storm}
+
+
+@pytest.mark.parametrize("seed", [11, 2147485001, 30303])
+@pytest.mark.parametrize("shape", [*SHAPES, "n_beyond_evictable"])
+def test_evict_n_is_n_reference_calls(shape, seed):
+    """`evict(refcount, n)` gives the pages, in the order, with the
+    classes and the counters of n calls of the one-block walk, and leaves
+    the index as they leave it — on every shape, also when n asks for more
+    than can go."""
+    rng = random.Random(f"{shape}/{seed}")
+    beyond = shape == "n_beyond_evictable"
+    build = rng.choice(list(SHAPES.values())) if beyond else SHAPES[shape]
+    alloc, cache = build(rng)
+    evictable = sum(1 for b in cache._blocks.values()
+                    if alloc.refcount(b.page) <= 0)
+    assert evictable >= 2
+    for n in ([evictable + 5] if beyond
+              else [1, rng.randrange(2, evictable), evictable]):
+        ref_alloc, ref_cache = copy.deepcopy((alloc, cache))
+        want = []
+        for _ in range(n):
+            hit = reference_evict_one(ref_cache, ref_alloc.refcount)
+            if hit is None:
+                break
+            want.append(hit)
+        got_alloc, got_cache = copy.deepcopy((alloc, cache))
+        got = got_cache.evict(got_alloc.refcount, n)
+        assert got == want
+        assert len(got) == min(n, evictable)
+        assert _cache_state(got_cache) == _cache_state(ref_cache)
+    assert cache.evict(alloc.refcount, 0) == []
+
+
+# ------------------------------- the engine's one pass (counts, not times)
+
+
+def _engine(num_pages, max_slots=32):
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models.llama import LlamaConfig
+
+    model = LlamaConfig(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
+                        n_kv_heads=1, d_ff=32, max_seq_len=512,
+                        dtype="float32", remat=False)
+    # no weights: nothing here prefills or decodes
+    return LLMEngine(None, model, EngineConfig(
+        max_slots=max_slots, num_pages=num_pages, page_size=4,
+        max_seq_len=512, prefill_buckets=(512,)))
+
+
+def _fill_pool(engine, rng):
+    """Finished requests' chains until the pool holds nothing else."""
+    alloc, cache = engine.allocator, engine.prefix_cache
+    while alloc.num_free():
+        n = min(rng.randrange(3, 12), alloc.num_free())
+        _insert_chain(alloc, cache, _fresh(rng, 4 * n))
+
+
+def _decoding_slots(engine, rng, n_slots):
+    """Slots one token short of a page boundary, as a burst finds them:
+    every one needs a page (some two) for its next 8 writes."""
+    from ray_tpu.llm.engine import SamplingParams, _Request, _Slot
+
+    for i in range(n_slots):
+        n_pages = rng.randrange(2, 6)
+        toks = _fresh(rng, 4 * n_pages - rng.randrange(0, 3))
+        req = _Request(request_id=f"r{i}", prompt_tokens=toks[:5],
+                       params=SamplingParams(max_tokens=256),
+                       submitted_at=float(i))
+        engine._slots[i] = _Slot(
+            request=req, pages=engine.allocator.allocate(n_pages),
+            num_tokens=len(toks), last_token=1, generated=toks[5:])
+
+
+def test_reserve_is_one_scan_for_all_its_pages():
+    engine = _engine(num_pages=400)
+    _fill_pool(engine, random.Random(5))
+    assert engine.allocator.num_free() == 0
+    assert engine._reserve(37)
+    st = engine.stats()
+    assert st["page_evictions"] == 37
+    assert st["eviction_scans"] <= 2
+    assert st["free_pages"] == 37
+    assert st["prefix_cache"]["evictions_cold_family"] == 37
+
+
+def test_reserve_keeps_partial_reclaims_when_the_pool_cannot_cover():
+    engine = _engine(num_pages=64)
+    engine.allocator.allocate(40)  # live sequences
+    _fill_pool(engine, random.Random(6))
+    assert not engine._reserve(37)
+    st = engine.stats()
+    assert st["page_evictions"] == st["free_pages"] == 63 - 40
+    assert st["eviction_scans"] == 1
+    assert len(engine.prefix_cache) == 0
+
+
+def test_a_burst_over_32_slots_is_one_scan():
+    rng = random.Random(8)
+    engine = _engine(num_pages=600)
+    _decoding_slots(engine, rng, 32)
+    _fill_pool(engine, rng)
+    before = [len(s.pages) for s in engine._slots]
+    engine._ensure_capacity(8)
+    grown = [len(s.pages) - b for s, b in zip(engine._slots, before)]
+    assert all(1 <= g <= 2 for g in grown)
+    st = engine.stats()
+    assert st["page_evictions"] == sum(grown) >= 32
+    assert st["eviction_scans"] <= 2
+    assert st["preempted"] == 0
+
+
+def _reference_ensure_capacity(engine, steps):
+    """`_ensure_capacity` as it was: every slot reserves for itself, one
+    reference walk a page."""
+    def reserve(n):
+        while engine.allocator.num_free() < n:
+            hit = reference_evict_one(engine.prefix_cache,
+                                      engine.allocator.refcount)
+            if hit is None:
+                return False
+            engine.allocator.reclaim(hit[0])
+        return True
+
+    ps = engine.cfg.page_size
+    order = sorted(
+        ((i, s) for i, s in enumerate(engine._slots) if s is not None),
+        key=lambda t: t[1].request.submitted_at)
+    for i, s in order:
+        while engine._slots[i] is s:
+            sp = s.request.params
+            remaining = max(1, sp.max_tokens - s.request.produced)
+            k = min(steps, remaining)
+            need = min((s.num_tokens + k - 1) // ps + 1,
+                       engine.max_pages_per_seq)
+            delta = need - len(s.pages)
+            if delta <= 0:
+                break
+            if reserve(delta):
+                s.pages.extend(engine.allocator.allocate(delta))
+                break
+            victim = min(
+                ((j, t) for j, t in enumerate(engine._slots)
+                 if t is not None),
+                key=lambda t: (engine._shared_pages(t[1]),
+                               -t[1].request.submitted_at))
+            engine._preempt(*victim)
+
+
+@pytest.mark.parametrize("cached_pages", [0, 5, 11, 200])
+def test_a_burst_preempts_as_the_per_slot_path_did(cached_pages):
+    """A pool that cannot cover the burst's sum (and one that can): the
+    same slots grow by the same pages, the same victims are preempted in
+    the same order, and the index and the free list end the same."""
+    def build():
+        rng = random.Random(9)
+        engine = _engine(num_pages=300, max_slots=16)
+        _decoding_slots(engine, rng, 16)
+        # what is not the slots' or cached is held by nobody we preempt
+        spare = engine.allocator.num_free() - cached_pages
+        if spare > 0:
+            engine.allocator.allocate(spare)
+        _fill_pool(engine, rng)
+        return engine
+
+    got = build()
+    got._ensure_capacity(8)
+    want = build()
+    _reference_ensure_capacity(want, 8)
+
+    def outcome(e):
+        return ([(s.request.request_id, s.pages) if s else None
+                 for s in e._slots],
+                [r.request_id for r in e._waiting.queue],
+                e.allocator._free, _cache_state(e.prefix_cache))
+
+    assert outcome(got) == outcome(want)
+    preempted = got.stats()["preempted"]
+    assert (preempted > 0) == (cached_pages < 16)
+    # one pass for the burst; past it only a reserve that fails, or that
+    # takes a preempted slot's pages, scans again: never one a page
+    scans = got.stats()["eviction_scans"]
+    assert scans == 1 if not preempted else scans <= 1 + 16 + preempted
