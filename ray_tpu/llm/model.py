@@ -22,13 +22,15 @@ the block is not written here, only the three ways it attends.
   head of a group), with a running float32 softmax; an inactive slot costs
   nothing.
 
-Each ``attend`` first writes its new K/V rows into the layer's pages.  The
-two prefills scan over the pools (a layer's step sees that layer's pages);
-the decode step carries both pools through its layer scan whole and scatters
-in place, so the donated pools are aliased to the outputs and nothing
-pool-sized is sliced, stacked or copied.  The reference gets this from
-vLLM's CUDA kernels; here it is jax/XLA and Pallas native (SURVEY.md §7
-step 8).
+Each ``attend`` first writes its new K/V rows into the layer's pages.
+Every program carries both pools through its layer scan whole, with the
+layer's index beside the layer's parameters, and scatters in place at
+``[li, page, slot]``: the donated pools are aliased to the outputs and
+nothing pool-sized, nor one layer of a pool, is sliced, stacked or copied
+(a scan OVER the pools makes XLA unstack and restack them: ~22 ms an
+admission over the serving cells' 3.2 GB pool, PERF.md section 6).  The
+reference gets this from vLLM's CUDA kernels; here it is jax/XLA and Pallas
+native (SURVEY.md §7 step 8).
 """
 
 from __future__ import annotations
@@ -54,18 +56,21 @@ def _masked_attention(cfg: LlamaConfig, q, keys, vals, mask):
     return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
 
 
-def _prefill_layers(params, x, cache_k, cache_v, positions, true_len, attend,
-                    cfg: LlamaConfig):
-    """What the two prefills share: the layer scan over (layer params, that
-    layer's pages of each pool), and the last real token's logits.
-    ``attend(q, k, v, (ck_l, cv_l))`` writes and attends its own way."""
-    def body(x, per_layer):
-        p, ck_l, cv_l = per_layer
-        return layer(cfg, p, x, positions, attend, (ck_l, cv_l))
+def _scan_layers(params, x, cache_k, cache_v, positions, attend,
+                 cfg: LlamaConfig):
+    """The layer scan of every program here: both pools ride in the carry
+    whole, never scanned over, and ``attend(q, k, v, (ck, cv, li))`` writes
+    layer ``li``'s rows into them in place and attends its own way."""
+    def body(carry, per_layer):
+        x, ck, cv = carry
+        p, li = per_layer
+        x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li))
+        return (x, ck, cv), None
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["layers"], cache_k, cache_v))
-    return head(params, x, cfg, true_len), cache_k, cache_v
+    (x, cache_k, cache_v), _ = jax.lax.scan(
+        body, (x, cache_k, cache_v),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return x, cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -84,17 +89,18 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     valid = positions[None, :] < true_len
     mask = causal & valid
 
-    def attend(q, k, v, pages):
-        ck_l, cv_l = pages
+    def attend(q, k, v, pools):
+        ck, cv, li = pools
         # write k/v into this layer's pages (beyond true_len the rows
         # write into the sequence's own pages — masked out of attention)
-        ck_l = ck_l.at[page_rows, slot_positions].set(k)
-        cv_l = cv_l.at[page_rows, slot_positions].set(v)
+        ck = ck.at[li, page_rows, slot_positions].set(k)
+        cv = cv.at[li, page_rows, slot_positions].set(v)
         # within the sequence: this call's own k and v, never the pool
-        return _masked_attention(cfg, q, k, v, mask), (ck_l, cv_l)
+        return _masked_attention(cfg, q, k, v, mask), (ck, cv)
 
-    return _prefill_layers(params, x, cache_k, cache_v, positions, true_len,
-                           attend, cfg)
+    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
+                                       positions, attend, cfg)
+    return head(params, x, cfg, true_len), cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -118,22 +124,23 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     page_size = cache_k.shape[2]
     x = embed(params, tokens, cfg)  # [L, D]
 
-    def attend(q, k, v, pages):
-        ck_l, cv_l = pages
+    def attend(q, k, v, pools):
+        ck, cv, li = pools
         # suffix writes go to the sequence's own fresh pages only: matched
         # prefix pages cover positions < prefix_len and are never written
-        ck_l = ck_l.at[page_rows, slot_positions].set(k)
-        cv_l = cv_l.at[page_rows, slot_positions].set(v)
-        keys = ck_l[page_table].reshape(P * page_size, cfg.n_kv_heads,
-                                        cfg.head_dim)
-        vals = cv_l[page_table].reshape(P * page_size, cfg.n_kv_heads,
-                                        cfg.head_dim)
+        ck = ck.at[li, page_rows, slot_positions].set(k)
+        cv = cv.at[li, page_rows, slot_positions].set(v)
+        keys = ck[li, page_table].reshape(P * page_size, cfg.n_kv_heads,
+                                          cfg.head_dim)
+        vals = cv[li, page_table].reshape(P * page_size, cfg.n_kv_heads,
+                                          cfg.head_dim)
         tpos = jnp.arange(P * page_size)[None]  # [1, T]
         mask = tpos <= positions[:, None]  # [L, T] causal over absolutes
-        return _masked_attention(cfg, q, keys, vals, mask), (ck_l, cv_l)
+        return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
 
-    return _prefill_layers(params, x, cache_k, cache_v, positions, true_len,
-                           attend, cfg)
+    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
+                                       positions, attend, cfg)
+    return head(params, x, cfg, true_len), cache_k, cache_v
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
@@ -144,10 +151,8 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     page_tables: [B, P] page ids (0 = null page); active: [B] bool.
     Returns (logits [B, V], cache_k, cache_v).
 
-    The pools are carried through the layer scan whole, not scanned over:
-    a layer writes its B new rows into the pool in place and the paged
-    kernel reads that layer's pages out of the same buffer, so the donated
-    pools are aliased to the outputs and never sliced, stacked or copied.
+    A layer writes its B new rows into the pool in place and the paged
+    kernel reads that layer's pages out of the same buffer.
     """
     P = page_tables.shape[1]
     page_size = cache_k.shape[2]
@@ -172,15 +177,8 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
         return (paged_decode_attention(q, ck, cv, page_tables, lengths, li),
                 (ck, cv))
 
-    def body(carry, per_layer):
-        x, ck, cv = carry
-        p, li = per_layer
-        x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li))
-        return (x, ck, cv), None
-
-    (x, cache_k, cache_v), _ = jax.lax.scan(
-        body, (x, cache_k, cache_v),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
+                                       positions, attend, cfg)
     return head(params, x, cfg), cache_k, cache_v
 
 

@@ -197,3 +197,78 @@ def test_program_logits_match_full_forward(program):
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), want[n - 1], atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill_with_prefix"])
+def test_prefill_writes_its_rows_and_nothing_else(program):
+    """Both prefills scatter into the whole pool in place: over a pool of
+    noise, every row of every layer that ``page_rows``/``slot_positions``
+    does not name comes back bit-identical, and the named rows hold the k
+    and v of a plain layer-by-layer forward (padding rows included; the
+    null page may hold scratch)."""
+    import dataclasses
+
+    from ray_tpu.llm import model as lm
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
+                              dtype="float32")
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    ps, n = 8, 21
+    table = np.asarray([3, 5, 2, 0], np.int32)  # 24 positions, then null
+    prompt = np.random.default_rng(1).integers(1, 128, size=n).astype(
+        np.int32)
+    shape = (cfg.n_layers, 8, ps, cfg.n_kv_heads, cfg.head_dim)
+    cache = [jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32)
+             for i in (1, 2)]
+
+    def plain_kv(tokens, mask):
+        """Per-layer k and v [n_layers, L, Hkv, d] of a dense forward."""
+        rep = cfg.n_heads // cfg.n_kv_heads
+        ks, vs = [], []
+
+        def attend(q, k, v, _):
+            ks.append(k)
+            vs.append(v)
+            scores = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, rep, 1))
+            scores = jnp.where(mask[None], scores / cfg.head_dim ** 0.5,
+                               -jnp.inf)
+            return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, -1),
+                              jnp.repeat(v, rep, 1)), None
+
+        x = params["embed"][tokens]
+        for li in range(cfg.n_layers):
+            p = jax.tree.map(lambda a: a[li], params["layers"])
+            x, _ = llama.layer(cfg, p, x, jnp.arange(len(tokens)), attend)
+        return np.stack(ks), np.stack(vs)
+
+    tokens = np.zeros(32, np.int32)
+    tokens[:n] = prompt
+    positions = np.arange(32)
+    causal = positions[None, :] <= positions[:, None]
+    if program == "prefill":
+        start = 0
+        want = plain_kv(tokens, causal & (positions[None, :] < n))
+    else:  # pages 3 and 5 resident, the suffix lands on page 2
+        start = 2 * ps
+        _, *cache = lm.prefill(
+            params, jnp.asarray(prompt[:start]), *cache,
+            jnp.asarray(table[positions[:start] // ps]), jnp.int32(start),
+            jnp.asarray(positions[:start] % ps), cfg)
+        want = plain_kv(tokens, causal)
+    before = [np.asarray(c) for c in cache]
+    written = positions[start:]
+    rows, slots = table[written // ps], written % ps
+    # the suffix program also takes the page table and absolute positions
+    through = (jnp.asarray(table), jnp.asarray(written)) if start else ()
+    _, *after = getattr(lm, program)(
+        params, jnp.asarray(tokens[start:]), *cache, jnp.asarray(rows),
+        jnp.int32(n - start), jnp.asarray(slots), *through, cfg)
+    named = np.zeros(shape[1:3], bool)
+    named[rows, slots] = True
+    real = rows != 0
+    for was, got, ref in zip(before, after, want):
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got[:, ~named], was[:, ~named])
+        np.testing.assert_allclose(got[:, rows[real], slots[real]],
+                                   ref[:, written[real]], atol=1e-5,
+                                   rtol=1e-5)

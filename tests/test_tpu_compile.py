@@ -148,6 +148,30 @@ def test_engine_prefill_and_decode_compile_at_llama_widths(topo, as_tpu):
 V5E_BYTES_LIMIT = 16.91e9  # memory_stats()["bytes_limit"] on the chip
 
 
+def _cell_llama(n_layers):
+    """The serving cells' configuration (Mistral-7B widths) at a depth."""
+    return llama.LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=2048, rope_theta=1e6,
+        dtype="bfloat16", remat=False)
+
+
+def _assert_holds_the_pool_once(compiled, n_layers):
+    """Over the cells' pool of 3,072 pages: both donated pools are aliased
+    to the outputs, less than a pool of temporaries is planned, and nothing
+    of a pool's shape is copied nor one layer of it sliced out."""
+    one_pool = n_layers * 3072 * 16 * 8 * 128 * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * one_pool
+    assert m.temp_size_in_bytes < one_pool
+    assert _footprint(compiled) < V5E_BYTES_LIMIT
+    results = [line.split(" = ")[1] for line in compiled.as_text().splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and r.startswith(
+        f"bf16[{n_layers},3072,16,8,128]")]
+    assert not [r for r in results if r.startswith("bf16[1,3072,16,8,128]")]
+
+
 @pytest.mark.parametrize("n_layers", [16, 20])
 def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers):
     """The benchmark's own decode program (Mistral-7B widths, 32 slots of
@@ -156,23 +180,33 @@ def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers):
     the pool was held twice, and at 20 layers the chip's compiler refused
     the program (17.06 GiB of 15.75)."""
     one = SingleDeviceSharding(topo.devices[0])
-    cfg = llama.LlamaConfig(
-        vocab_size=32768, d_model=4096, n_layers=n_layers, n_heads=32,
-        n_kv_heads=8, d_ff=14336, max_seq_len=2048, rope_theta=1e6,
-        dtype="bfloat16", remat=False)
-    decode = _decode_program(cfg, one, slots=32, pages_per_seq=128,
-                             num_pages=3072)
-    one_pool = n_layers * 3072 * 16 * 8 * 128 * 2
-    m = decode.memory_analysis()
-    assert m.alias_size_in_bytes >= 2 * one_pool
-    assert m.temp_size_in_bytes < one_pool
-    assert _footprint(decode) < V5E_BYTES_LIMIT
-    text = decode.as_text()
-    assert "paged_decode_attention" in text
-    pool_shape = f"bf16[{n_layers},3072,16,8,128]"
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and line.split(" = ")[1].startswith(
-                    pool_shape)]
+    decode = _decode_program(_cell_llama(n_layers), one, slots=32,
+                             pages_per_seq=128, num_pages=3072)
+    _assert_holds_the_pool_once(decode, n_layers)
+    assert "paged_decode_attention" in decode.as_text()
+
+
+@pytest.mark.parametrize("bucket", [256, 2048])
+@pytest.mark.parametrize("n_layers", [16, 20])
+@pytest.mark.parametrize("program", ["prefill", "prefill_with_prefix"])
+def test_prefill_holds_the_page_pool_once(topo, as_tpu, program, n_layers,
+                                          bucket):
+    """Both prefill programs at the serving cells' shapes: the pools ride in
+    the layer scan's carry, so a call writes its rows in place.  Scanned
+    over, every call copied both pools on entry and sliced each layer's
+    pages out and back (3.24 GB of temporaries, ~22 ms whatever the
+    prompt's length), and at 20 layers the chip's compiler refused the
+    2,048 bucket (16.40 G of 15.75)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = _cell_llama(n_layers)
+    params, cache = _on(one, _serving_shapes(cfg, num_pages=3072))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    # the suffix program also takes a page table of 128 and the positions
+    table = () if program == "prefill" else (i32(128), i32(bucket))
+    compiled = getattr(lm, program).lower(
+        params, i32(bucket), cache, cache, i32(bucket), i32(), i32(bucket),
+        *table, cfg).compile()
+    _assert_holds_the_pool_once(compiled, n_layers)
 
 
 @pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [
