@@ -8,6 +8,14 @@ step (llm/model.py).  The scheduler thread admits waiting requests into free
 slots whenever pages are available (prefill), then advances every active
 slot one token per iteration (decode), streaming tokens into per-request
 queues.  Static shapes throughout: no recompiles after warmup.
+
+A model configuration with a ``block_length`` (models/sdar_moe.py)
+generates by diffusion over blocks: a slot then holds an open BLOCK of
+positions, a decode step is one denoising pass over every slot's block
+(``lm.block_step``) that fills 0..block_length of its masks, a block's K/V
+is final only after a pass whose input held no mask, and a prefill emits
+nothing.  The loop, its phases, admission, the page pool, the prefix cache
+and preemption are the same code; what differs is marked "block" below.
 """
 
 from __future__ import annotations
@@ -29,7 +37,6 @@ from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
                                      init_cache)
-from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.util import tracing
 
 # Serving observability (ISSUE 8): the engine-local stats() dict stays the
@@ -65,7 +72,22 @@ def _engine_metrics():
                 "prefills": Counter(
                     "llm_prefills_total", "Prefill executions"),
                 "decode_steps": Counter(
-                    "llm_decode_steps_total", "Batched decode steps"),
+                    "llm_decode_steps_total", "Batched decode steps (for "
+                    "a block-diffusion model, denoising passes)"),
+                "block_slot_passes": Counter(
+                    "llm_block_slot_passes_total", "Denoising passes a "
+                    "live slot took part in (tokens / this = tokens a "
+                    "slot's pass yields)"),
+                "masks_filled": Counter(
+                    "llm_masks_filled_total", "Masked positions that "
+                    "denoising passes filled"),
+                "experts_read": Counter(
+                    "llm_experts_read_total", "Experts the routed layers "
+                    "of denoising passes and prefills read (x 3 matrices "
+                    "= the grouped product's weight traffic)"),
+                "blocks_final": Counter(
+                    "llm_blocks_final_total", "Blocks whose K/V a pass "
+                    "over their mask-free tokens made final"),
                 "decode_pages_read": Counter(
                     "llm_decode_pages_read_total", "KV pages the decode "
                     "kernel walked: per step and active slot, the pages "
@@ -173,7 +195,11 @@ _PHASE_ATTRS = {
     P_PREFILL_DISPATCH: ("bucket",), P_PREFILL_FETCH: (),
     P_PREFILL_EMIT: (), P_DECODE_HOST: ("active_slots", "burst"),
     P_DECODE_DISPATCH: ("burst",), P_DECODE_FETCH: ("burst",),
-    P_DECODE_EMIT: ("tokens", "slots_released"), P_GAUGES: (),
+    # a block-diffusion burst adds what its passes did
+    P_DECODE_EMIT: ("tokens", "slots_released", "slot_passes",
+                    "masks_filled", "blocks_final", "experts_read",
+                    "passes"),
+    P_GAUGES: (),
     S_COMPILE: ("seconds", "phase", "program"),
 }
 
@@ -350,6 +376,14 @@ class _Slot:
     last_token: int
     generated: List[int] = field(default_factory=list)
     rng: Optional[np.random.Generator] = None
+    # block diffusion: the open block at positions [num_tokens, num_tokens
+    # + B), as the last pass left it.  ``blk_masked`` is the state (an id
+    # drawn from the vocabulary may be the mask token's); the first
+    # ``blk_given`` positions are the prompt's tail and are never emitted
+    blk_tokens: List[int] = field(default_factory=list)
+    blk_masked: List[bool] = field(default_factory=list)
+    blk_given: int = 0
+    blk_step: int = 0  # passes this block has had
 
 
 class LLMEngine:
@@ -357,16 +391,26 @@ class LLMEngine:
 
     Of ``model_cfg`` the engine reads ``n_layers``, ``n_kv_heads``,
     ``head_dim`` and ``dtype`` (the pools' shape; ``_tier_expect``) and hands
-    it, with ``params``, to the five programs of llm/model.py it calls:
-    ``prefill``, ``prefill_with_prefix``, ``decode_step``,
-    ``decode_step_greedy``, ``copy_page``.
+    it, with ``params``, to the programs of llm/model.py it calls:
+    ``prefill``, ``prefill_with_prefix``, ``copy_page`` and, by whether the
+    configuration has a ``block_length``, ``decode_step`` /
+    ``decode_step_greedy`` or ``block_step`` (which also reads the
+    sampler's settings off it).
     """
 
-    def __init__(self, params, model_cfg: LlamaConfig,
-                 cfg: Optional[EngineConfig] = None, kv_tier=None):
+    def __init__(self, params, model_cfg, cfg: Optional[EngineConfig] = None,
+                 kv_tier=None):
         self.cfg = cfg or EngineConfig()
         self.model_cfg = model_cfg
         self.params = params
+        # block: positions a block (0: a token at a time)
+        self._block = int(getattr(model_cfg, "block_length", 0))
+        if self._block and (self.cfg.page_size % self._block
+                            or self.cfg.max_seq_len % self._block):
+            raise ValueError(
+                f"a block of {self._block} positions must divide page_size "
+                f"({self.cfg.page_size}) and max_seq_len "
+                f"({self.cfg.max_seq_len}): a block lies in one page")
         ccfg = CacheConfig(
             n_layers=model_cfg.n_layers, n_kv_heads=model_cfg.n_kv_heads,
             head_dim=model_cfg.head_dim, num_pages=self.cfg.num_pages,
@@ -402,7 +446,9 @@ class LLMEngine:
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
         self._stats = {"prefills": 0, "decode_steps": 0,
-                       "decode_pages_read": 0,
+                       "decode_pages_read": 0, "block_slot_passes": 0,
+                       "masks_filled": 0, "blocks_final": 0,
+                       "experts_read": 0,
                        "tokens_generated": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
                        "eviction_scans": 0,
@@ -436,6 +482,9 @@ class LLMEngine:
     def submit(self, prompt_tokens: List[int],
                params: Optional[SamplingParams] = None) -> _Request:
         params = params or SamplingParams()
+        if self._block and params.temperature > 0:
+            self._refuse_block(f"sampling at temperature "
+                               f"{params.temperature} (greedy only)")
         total = len(prompt_tokens) + params.max_tokens
         if total > self.cfg.max_seq_len:
             raise ValueError(
@@ -462,6 +511,10 @@ class LLMEngine:
         the first token, and return (first_token, kv_k, kv_v, n_tokens) —
         the KV page arrays a decode engine injects via submit_with_kv.
         Pages are freed here immediately; this engine keeps no state."""
+        if self._block:
+            self._refuse_block("prefill/decode disaggregation "
+                               "(prefill_extract): a prefill of this model "
+                               "yields no first token to ship")
         self.start()
         params = params or SamplingParams()
         req = _Request(request_id=uuid.uuid4().hex[:12],
@@ -485,6 +538,10 @@ class LLMEngine:
                        params: Optional[SamplingParams] = None) -> _Request:
         """P/D disaggregation, decode side: admit a sequence whose prompt
         KV was computed elsewhere. No prefill compute happens here."""
+        if self._block:
+            self._refuse_block("prefill/decode disaggregation "
+                               "(submit_with_kv): a slot of this model "
+                               "opens on a block, not on a shipped token")
         self.start()
         params = params or SamplingParams()
         total = len(prompt_tokens) + params.max_tokens
@@ -504,6 +561,12 @@ class LLMEngine:
         self._trace_init(req)
         self._waiting.put(req)
         return req
+
+    def _refuse_block(self, what: str):
+        raise ValueError(
+            f"{type(self.model_cfg).__name__} generates by diffusion over "
+            f"blocks of {self._block} positions, which this engine does not "
+            f"serve with {what}")
 
     def generate(self, prompt_tokens: List[int],
                  params: Optional[SamplingParams] = None,
@@ -811,6 +874,13 @@ class LLMEngine:
                                    outcome=outcome, pages=pulled)
                 matched, cow_src, cow_len = \
                     self.prefix_cache.match_cow(req.prompt_tokens)
+                if self._block:
+                    # block: a token's K/V depends on its whole block, so a
+                    # hit that ends inside one stops at the block's start
+                    # (whole pages are whole blocks)
+                    cow_len -= cow_len % self._block
+                    if cow_len == 0:
+                        cow_src = None
             need_total = n // self.cfg.page_size + 1
             # pin matched pages — and the COW source, which eviction in
             # _reserve would otherwise happily reclaim before the copy —
@@ -893,6 +963,12 @@ class LLMEngine:
             # every full prompt page — freshly computed or injected — is
             # now index-able for later prompts sharing the prefix
             self._register_blocks(req.prompt_tokens, pages)
+            if self._block:
+                # block: no token follows from a prefill; the slot opens on
+                # the prompt's tail and masks
+                self._slots[free_slot] = self._open_block(req, pages)
+                admitted = True
+                continue
             slot = _Slot(request=req, pages=pages,
                          num_tokens=len(req.prompt_tokens),
                          last_token=last, rng=rng)
@@ -919,7 +995,11 @@ class LLMEngine:
 
     def _prefill(self, req: _Request, pages: List[int],
                  rng: Optional[np.random.Generator],
-                 prefix_len: int = 0) -> int:
+                 prefix_len: int = 0) -> Optional[int]:
+        """Compute the prompt's K/V past ``prefix_len`` and sample the
+        token that follows the prompt.  Block: the prompt's whole blocks
+        only (the tail opens the slot's first block), nothing sampled, and
+        no program at all when the hit covers them."""
         n = len(req.prompt_tokens)
         ps = self.cfg.page_size
         ph = self._ph
@@ -927,8 +1007,9 @@ class LLMEngine:
         t0 = time.monotonic()
         # pages[:prefix_len // ps] already hold a cached prefix's KV (none
         # without a hit): compute only what follows it
-        suffix = req.prompt_tokens[prefix_len:]
-        bucket = self.cfg.bucket_for(len(suffix))
+        suffix = req.prompt_tokens[prefix_len:
+                                   n - n % self._block if self._block else n]
+        bucket = self.cfg.bucket_for(max(1, len(suffix)))
         tokens = np.zeros(bucket, np.int32)
         tokens[:len(suffix)] = suffix
         positions = prefix_len + np.arange(bucket, dtype=np.int32)
@@ -950,18 +1031,25 @@ class LLMEngine:
             args += (jnp.asarray(table), jnp.asarray(positions))
         tokens = jnp.asarray(tokens)
         ph.vals = (bucket, prefix_len)
-        ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
-        logits, self.cache_k, self.cache_v = program(
-            self.params, tokens, self.cache_k, self.cache_v, *args,
-            self.model_cfg)
-        ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
-        logits = np.asarray(logits)
-        ph.begin(P_PREFILL_EMIT, req)
-        out = self._sample_one(logits, req.params, rng)
-        self._stats["prefills"] += 1
+        out, block_attrs = None, {}
+        if suffix or not self._block:
+            ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
+            logits, self.cache_k, self.cache_v = program(
+                self.params, tokens, self.cache_k, self.cache_v, *args,
+                self.model_cfg)
+            ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
+            logits = np.asarray(logits)
+            ph.begin(P_PREFILL_EMIT, req)
+            if self._block:  # what came back is the experts it read
+                block_attrs = {"experts_read": int(logits)}
+                self._stats["experts_read"] += int(logits)
+                self._m["experts_read"].inc(int(logits))
+            else:
+                out = self._sample_one(logits, req.params, rng)
+            self._stats["prefills"] += 1
+            self._m["prefills"].inc()
         dt = time.monotonic() - t0
         self._stats["admitted"] += 1
-        self._m["prefills"].inc()
         self._m["admitted"].inc()
         tid = req.trace_ctx[0] if req.trace_ctx else None
         self._m["prefill_t"].observe(dt, exemplar=tid)
@@ -972,7 +1060,8 @@ class LLMEngine:
             self._span(req, "llm.queue", req.submitted_wall,
                        req.submitted_wall + qw, wait_s=round(qw, 6))
             self._span(req, "llm.prefill", w_end - dt, w_end, tokens=n,
-                       prefix_len=prefix_len, resumed=bool(req.preempts))
+                       prefix_len=prefix_len, resumed=bool(req.preempts),
+                       **block_attrs)
         if req.preempts:
             try:
                 from ray_tpu.util import events
@@ -1288,6 +1377,17 @@ class LLMEngine:
         def need_pages(s: _Slot) -> int:
             sp = s.request.params
             remaining = max(1, sp.max_tokens - s.request.produced)
+            if self._block:
+                # block: a block takes a filling pass and a final one at
+                # least, so `steps` passes reach 1 + steps // 2 blocks, and
+                # never one past the block of the request's last token
+                B = self._block
+                last = (len(s.request.prompt_tokens) + len(s.generated)
+                        + remaining)
+                end = min(s.num_tokens + B * (1 + steps // 2),
+                          -(-last // B) * B)
+                need = min(-(-end // ps), self.max_pages_per_seq)
+                return need - len(s.pages)
             k = min(steps, remaining)
             need = min((s.num_tokens + k - 1) // ps + 1,
                        self.max_pages_per_seq)
@@ -1357,6 +1457,9 @@ class LLMEngine:
         if not active_slots:
             ph.vals = (0, burst)
             return True  # everything preempted; _admit resumes them
+        if self._block:
+            self._block_burst(active_slots, burst)
+            return True
         B = self.cfg.max_slots
         P = self.max_pages_per_seq
         tokens = np.zeros(B, np.int32)
@@ -1421,6 +1524,114 @@ class LLMEngine:
             ph.vals = (self._stats["tokens_generated"] - emitted,
                        sum(self._slots[i] is not s for i, s in active_slots))
         return True
+
+    # ------------------------- block diffusion ----------------------------
+
+    def _open_block(self, req: _Request, pages: List[int]) -> _Slot:
+        """A slot whose K/V is final for the prompt's whole blocks and
+        whose first block is the prompt's tail followed by masks."""
+        B = self._block
+        n = len(req.prompt_tokens)
+        r = n % B
+        return _Slot(
+            request=req, pages=pages, num_tokens=n - r, last_token=-1,
+            blk_tokens=(req.prompt_tokens[n - r:]
+                        + [self.model_cfg.mask_token_id] * (B - r)),
+            blk_masked=[False] * r + [True] * (B - r), blk_given=r)
+
+    def _block_burst(self, active_slots, burst: int) -> None:
+        """``burst`` denoising passes over every slot's open block, chained
+        on the device (a pass's state feeds the next), one fetch for all of
+        their records.  Overshoot is safe as in greedy decoding: a slot that
+        finishes mid-burst keeps passing over its own (or the null) pages
+        and what those passes fill is not emitted."""
+        ph = self._ph
+        S, B, P = self.cfg.max_slots, self._block, self.max_pages_per_seq
+        tokens = np.full((S, B), self.model_cfg.mask_token_id, np.int32)
+        masked = np.ones((S, B), bool)
+        starts = np.zeros(S, np.int32)
+        step = np.zeros(S, np.int32)
+        tables = np.zeros((S, P), np.int32)
+        active = np.zeros(S, bool)
+        for i, s in active_slots:
+            tokens[i], masked[i] = s.blk_tokens, s.blk_masked
+            starts[i], step[i] = s.num_tokens, s.blk_step
+            tables[i, :len(s.pages)] = s.pages
+            active[i] = True
+        tables_dev, active_dev = jnp.asarray(tables), jnp.asarray(active)
+        state = (jnp.asarray(tokens), jnp.asarray(masked),
+                 jnp.asarray(starts), jnp.asarray(step))
+        counted = ("tokens_generated", "block_slot_passes", "masks_filled",
+                   "blocks_final", "experts_read", "decode_pages_read")
+        before = [self._stats[k] for k in counted]
+        ph.vals = (len(active_slots), burst)
+        ph.begin(P_DECODE_DISPATCH, vals=(burst,))
+        records = []
+        for _ in range(burst):
+            record, *state, self.cache_k, self.cache_v = lm.block_step(
+                self.params, self.cache_k, self.cache_v, tables_dev,
+                active_dev, *state, self.model_cfg)
+            records.append(record)
+        ph.begin(P_DECODE_FETCH, vals=(burst,))  # the host waits
+        # plain lists: the replay below reads every number of them
+        rows = (np.asarray(jnp.stack(records)).tolist() if burst > 1
+                else [np.asarray(records[0]).tolist()])
+        ph.begin(P_DECODE_EMIT)
+        self._stats["decode_steps"] += burst
+        self._m["decode_steps"].inc(burst)
+        for row in rows:
+            self._stats["experts_read"] += row[0][2 * B + 1]  # in every row
+            for i, s in active_slots:
+                if self._slots[i] is s:  # else finished earlier in the burst
+                    self._accept_pass(i, s, row[i])
+        did = {k: self._stats[k] - b for k, b in zip(counted, before)}
+        for k in counted[1:]:  # _emit counts the tokens itself
+            self._m[k].inc(did[k])
+        if ph.sampled:
+            ph.vals = (did["tokens_generated"],
+                       sum(self._slots[i] is not s for i, s in active_slots),
+                       did["block_slot_passes"], did["masks_filled"],
+                       did["blocks_final"], did["experts_read"], burst)
+
+    def _accept_pass(self, i: int, s: _Slot, record) -> None:
+        """Replay one pass's record (a list) for slot i: the block after
+        the pass [B], its masks after it [B], whether the pass made it
+        final."""
+        B = self._block
+        stats = self._stats
+        stats["block_slot_passes"] += 1
+        # the kernel walked the pages up to the block's end
+        stats["decode_pages_read"] += min(
+            (s.num_tokens + B - 1) // self.cfg.page_size + 1,
+            self.max_pages_per_seq)
+        if record[2 * B]:
+            # the input held no mask: the K/V this pass wrote is final and
+            # the next block opens, all masks
+            stats["blocks_final"] += 1
+            s.num_tokens += B
+            s.blk_tokens = [self.model_cfg.mask_token_id] * B
+            s.blk_masked = [True] * B
+            s.blk_given = s.blk_step = 0
+            return
+        still = [bool(m) for m in record[B:2 * B]]
+        stats["masks_filled"] += sum(s.blk_masked) - sum(still)
+        s.blk_tokens = record[:B]
+        s.blk_masked = still
+        s.blk_step += 1
+        if any(still):
+            return
+        # the pass that filled the block's last mask emits its new tokens,
+        # in position order; max_tokens and stop tokens cut in that order
+        sp = s.request.params
+        for tok in s.blk_tokens[s.blk_given:]:
+            if tok in sp.stop_token_ids:
+                self._release_slot(i, s)
+                return
+            s.generated.append(tok)
+            self._emit(s, tok)
+            if s.request.produced >= sp.max_tokens:
+                self._release_slot(i, s)
+                return
 
     def _accept_token(self, i: int, s: _Slot, tok: int):
         """Record one sampled token for slot i: emit, finish, or continue."""

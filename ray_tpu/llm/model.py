@@ -22,6 +22,14 @@ the block is not written here, only the three ways it attends.
   head of a group), with a running float32 softmax; an inactive slot costs
   nothing.
 
+A configuration with a ``block_length`` (models/sdar_moe.py) generates by
+diffusion over blocks, and takes a fourth program and another mask: both
+prefills are causal over blocks and bidirectional inside one, emit nothing
+(the model's logits are for the position itself, so no token follows from a
+prompt's last one), and ``block_step`` is one denoising pass over every
+slot's open block, its rows attending through the page table like a decode
+step's.  Its feed-forward, the routed experts, comes with the parameters.
+
 Each ``attend`` first writes its new K/V rows into the layer's pages.
 Every program carries both pools through its layer scan whole, with the
 layer's index beside the layer's parameters, and scatters in place at
@@ -40,6 +48,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import sdar_moe
 from ray_tpu.models.llama import LlamaConfig, embed, head, layer
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
@@ -60,7 +69,19 @@ def _scan_layers(params, x, cache_k, cache_v, positions, attend,
                  cfg: LlamaConfig):
     """The layer scan of every program here: both pools ride in the carry
     whole, never scanned over, and ``attend(q, k, v, (ck, cv, li))`` writes
-    layer ``li``'s rows into them in place and attends its own way."""
+    layer ``li``'s rows into them in place and attends its own way.
+    Returns (x, cache_k, cache_v, experts the routed layers read or None)."""
+    if "experts" in params["layers"]:  # routed: the experts are not scanned
+        def routed_body(carry, p, li, feed_forward):
+            x, ck, cv = carry
+            x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li),
+                                feed_forward)
+            return x, ck, cv
+
+        (x, cache_k, cache_v), hit = sdar_moe.scan_layers(
+            cfg, params, routed_body, (x, cache_k, cache_v))
+        return x, cache_k, cache_v, hit
+
     def body(carry, per_layer):
         x, ck, cv = carry
         p, li = per_layer
@@ -70,7 +91,31 @@ def _scan_layers(params, x, cache_k, cache_v, positions, attend,
     (x, cache_k, cache_v), _ = jax.lax.scan(
         body, (x, cache_k, cache_v),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    return x, cache_k, cache_v
+    return x, cache_k, cache_v, None
+
+
+def _block_length(cfg) -> int:
+    """Positions a block of a block-diffusion configuration; 0 for a model
+    that generates a token at a time."""
+    return getattr(cfg, "block_length", 0)
+
+
+def _visible(cfg, qpos, kpos):
+    """[q, k] bool: may the query at ``qpos`` see the key at ``kpos``?
+    Causal; for a block-diffusion configuration causal over blocks."""
+    if _block_length(cfg):
+        return sdar_moe.block_causal(qpos, kpos, cfg.block_length)
+    return kpos[None, :] <= qpos[:, None]
+
+
+def _prefill_result(params, x, cfg, true_len, experts_hit):
+    """What a prefill hands the engine: the last token's logits, or for a
+    block-diffusion configuration (no token follows from a prompt, so the
+    output head is not run) a number to wait for: the experts its routed
+    layers read."""
+    if _block_length(cfg):
+        return experts_hit
+    return head(params, x, cfg, true_len)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -85,7 +130,7 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     """
     x = embed(params, tokens, cfg)  # [L, D]
     positions = jnp.arange(tokens.shape[0])
-    causal = positions[None, :] <= positions[:, None]  # [L, L]
+    causal = _visible(cfg, positions, positions)  # [L, L]
     valid = positions[None, :] < true_len
     mask = causal & valid
 
@@ -98,9 +143,9 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         # within the sequence: this call's own k and v, never the pool
         return _masked_attention(cfg, q, k, v, mask), (ck, cv)
 
-    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
-                                       positions, attend, cfg)
-    return head(params, x, cfg, true_len), cache_k, cache_v
+    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
+                                            positions, attend, cfg)
+    return _prefill_result(params, x, cfg, true_len, hit), cache_k, cache_v
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -134,13 +179,13 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                                           cfg.head_dim)
         vals = cv[li, page_table].reshape(P * page_size, cfg.n_kv_heads,
                                           cfg.head_dim)
-        tpos = jnp.arange(P * page_size)[None]  # [1, T]
-        mask = tpos <= positions[:, None]  # [L, T] causal over absolutes
+        # [L, T] causal over absolutes
+        mask = _visible(cfg, positions, jnp.arange(P * page_size))
         return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
 
-    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
-                                       positions, attend, cfg)
-    return head(params, x, cfg, true_len), cache_k, cache_v
+    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
+                                            positions, attend, cfg)
+    return _prefill_result(params, x, cfg, true_len, hit), cache_k, cache_v
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
@@ -177,8 +222,8 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
         return (paged_decode_attention(q, ck, cv, page_tables, lengths, li),
                 (ck, cv))
 
-    x, cache_k, cache_v = _scan_layers(params, x, cache_k, cache_v,
-                                       positions, attend, cfg)
+    x, cache_k, cache_v, _ = _scan_layers(params, x, cache_k, cache_v,
+                                          positions, attend, cfg)
     return head(params, x, cfg), cache_k, cache_v
 
 
@@ -199,6 +244,103 @@ def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
         params, tokens, cache_k, cache_v, page_tables, positions, active,
         cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_k, cache_v
+
+
+def _fill(cfg, logits, masked, step):
+    """Which of a block's masked positions this pass fills, and with what:
+    ``generate.py``'s three strategies.  logits [S, B, V] float32; masked
+    [S, B]; step [S] passes this block has had.  Returns (x0 [S, B] int32,
+    fill [S, B] bool).  Only a masked position is ever filled (the
+    published top-k can name an unmasked one in a block a prompt's tail
+    opened; a filled position never changes here)."""
+    B, T = cfg.block_length, cfg.denoising_steps
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    n_t = jnp.asarray(sdar_moe.num_transfer_tokens(B, T), jnp.int32)[
+        jnp.minimum(step, T - 1)][:, None]  # [S, 1]
+    if cfg.remasking_strategy == "sequential":  # the leftmost masked
+        return x0, masked & (jnp.cumsum(masked, axis=1) <= n_t)
+    # softmax probability of x0, masked positions only
+    conf = jnp.exp(jnp.max(logits, axis=-1)
+                   - jax.scipy.special.logsumexp(logits, axis=-1))
+    conf = jnp.where(masked, conf, -jnp.inf)
+    # rank 0 is the most confident; ties go to the leftmost
+    order = jnp.argsort(-conf, axis=1, stable=True)
+    rank = jnp.argsort(order, axis=1, stable=True)
+    static = masked & (rank < n_t)
+    if cfg.remasking_strategy == "low_confidence_static":
+        return x0, static
+    high = conf > cfg.confidence_threshold
+    enough = jnp.sum(high, axis=1, keepdims=True) >= n_t
+    return x0, jnp.where(enough, high, static)
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1, 2))
+def block_step(params, cache_k, cache_v, page_tables, active, tokens,
+               masked, starts, step, cfg):
+    """One denoising pass over the open block of EVERY slot.
+
+    tokens: [S, B] int32 the block as it stands, the mask token at masked
+    positions; masked: [S, B] bool (the state, not ``tokens == mask``: a
+    drawn id may be the mask token's); starts: [S] the block's first
+    position, a multiple of B; step: [S] passes this block has had;
+    page_tables: [S, P]; active: [S] bool.
+
+    Every row attends to pages [0, start) and to its own block whole
+    (bidirectional inside it): the S x B rows go through
+    ``paged_decode_attention`` as S slots of B x H query heads, each KV
+    group's B x H/Hkv rows together, with the block's END as the length, so
+    a page is read once for the B rows.  The pass writes the block's K/V
+    rows in place; a later pass over the same block overwrites them, and
+    the rows are final when the pass's input held no mask.  Such a pass
+    fills nothing and opens the next block (all masks); any other fills
+    masks by ``cfg.remasking_strategy``.
+
+    Returns (record [S, 2B + 2] int32: the block after the pass, its masks
+    after the pass, whether the pass made it final, and in every row the
+    experts the pass's routed layers read; then what the next pass takes:
+    tokens, masked, starts, step; cache_k, cache_v).
+    """
+    S, B = tokens.shape
+    P = page_tables.shape[1]
+    page_size = cache_k.shape[2]
+    n_kv, rep, d = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
+    x = embed(params, tokens.reshape(S * B), cfg)  # [S * B, D]
+    offsets = jnp.arange(B, dtype=starts.dtype)
+    positions = (starts[:, None] + offsets).reshape(S * B)
+    # a block lies in one page (page_size is a multiple of B); inactive
+    # slots and a burst's overshoot write into the null page, as decoding
+    in_table = active & (starts < P * page_size)
+    write_page = jnp.take_along_axis(
+        page_tables, jnp.minimum(starts // page_size, P - 1)[:, None],
+        axis=1)[:, 0]
+    write_page = jnp.repeat(jnp.where(in_table, write_page, 0), B)
+    write_slot = ((starts % page_size)[:, None] + offsets).reshape(S * B)
+    lengths = jnp.where(active, starts + B, 0)
+
+    def attend(q, k, v, pools):  # q: [S * B, H, d]; k, v: [S * B, Hkv, d]
+        ck, cv, li = pools
+        ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
+        cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+        q = q.reshape(S, B, n_kv, rep, d).transpose(0, 2, 1, 3, 4)
+        out = paged_decode_attention(q.reshape(S, n_kv * B * rep, d), ck, cv,
+                                     page_tables, lengths, li)
+        out = out.reshape(S, n_kv, B, rep, d).transpose(0, 2, 1, 3, 4)
+        return out.reshape(S * B, n_kv * rep, d), (ck, cv)
+
+    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
+                                            positions, attend, cfg)
+    x0, fill = _fill(cfg, head(params, x, cfg).reshape(S, B, -1), masked,
+                     step)
+    final = active & ~jnp.any(masked, axis=1)
+    tokens = jnp.where(fill, x0, tokens)
+    masked = masked & ~fill
+    record = jnp.concatenate(
+        [tokens, masked.astype(jnp.int32), final[:, None].astype(jnp.int32),
+         jnp.broadcast_to(hit, (S, 1))], axis=1)
+    nxt = final[:, None]
+    return (record, jnp.where(nxt, jnp.int32(cfg.mask_token_id), tokens),
+            masked | nxt, jnp.where(final, starts + B, starts),
+            jnp.where(final, 0, step + 1), cache_k, cache_v)
 
 
 @partial(jax.jit, donate_argnums=(0, 1))

@@ -28,7 +28,9 @@ class LLMConfig:
     (model_loading_config + engine_kwargs + deployment_config)."""
 
     model_id: str = "llama-tiny"
-    # callable returning (params, LlamaConfig) — checkpoint loading hook
+    # callable returning (params, model config) — checkpoint loading hook;
+    # the engine dispatches on the configuration (models/llama.py,
+    # models/sdar_moe.py)
     model_loader: Optional[Callable] = None
     tokenizer: Optional[str] = None  # None/"byte" or HF name
     engine_config: EngineConfig = field(default_factory=EngineConfig)

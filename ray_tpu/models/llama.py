@@ -177,6 +177,9 @@ def qkv_rope(cfg, p, h, positions):
     q = heads(p["attn"]["wq"], cfg.n_heads)
     k = heads(p["attn"]["wk"], cfg.n_kv_heads)
     v = heads(p["attn"]["wv"], cfg.n_kv_heads)
+    if "q_norm" in p["attn"]:  # Qwen3 family: RMS norm a head, before rope
+        q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["attn"]["k_norm"], cfg.norm_eps)
     return (rope(q, positions, cfg.rope_theta),
             rope(k, positions, cfg.rope_theta), v)
 
@@ -199,11 +202,13 @@ def gated_mlp(p, h):
     return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
 
 
-def layer(cfg, p, x, positions, attend, cache=None):
-    """One dense decoder layer: (x, cache)."""
+def layer(cfg, p, x, positions, attend, cache=None, feed_forward=gated_mlp):
+    """One decoder layer: (x, cache).  ``feed_forward(p, h)`` is the dense
+    gated MLP unless the caller hands another (models/moe.py's routed
+    experts)."""
     x, cache = attention_block(cfg, p, x, positions, attend, cache)
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    return x + gated_mlp(p, h), cache
+    return x + feed_forward(p, h), cache
 
 
 def head(params, x, cfg, true_len=None):

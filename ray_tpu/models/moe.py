@@ -10,6 +10,12 @@ combine are MXU-friendly dense ops — no ragged gathers.
 
 The block's attention half, the embedding and the epilogue are the Llama
 family's parts (models/llama.py); only the feed-forward is this file's own.
+
+Two routed layers live here.  ``moe_mlp`` is the training model's: a
+static capacity, tokens over it dropped, dispatch as einsums whose
+shardings give XLA the all-to-all.  ``routed_mlp`` is the serving one
+(models/sdar_moe.py through llm/model.py): dropless, assignments sorted by
+expert into ``ops/grouped_matmul``, no (tokens, experts, capacity) tensor.
 """
 
 from __future__ import annotations
@@ -164,6 +170,85 @@ def moe_mlp(cfg: MoEConfig, x, router_w, experts):
     p = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(f * p)
     return out.reshape(b, s, d), aux
+
+
+def route(h, router_w, top_k: int, renormalise: bool = True):
+    """Softmax over the experts in float32 (a float32 product: on the TPU a
+    float32 matmul runs in bf16 passes unless told otherwise), the top_k of
+    it and, ``renormalise``, their weights made to sum to one.  h: (N, D).
+    Returns (weights (N, k) float32, experts (N, k) int32)."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if renormalise:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_idx.astype(jnp.int32)
+
+
+def row_tile(assignments: int, n_experts: int) -> int:
+    """Rows a tile of the grouped product: the rows an expert gets on
+    average, as a power of two between bf16's 16 sublanes and the MXU's
+    128 (a larger tile pads every expert's run further)."""
+    per_expert = max(1, assignments // n_experts)
+    return min(128, max(16, 1 << (per_expert.bit_length() - 1)))
+
+
+def routed_mlp(h, router_w, experts, layer, *, top_k: int,
+               renormalise: bool = True):
+    """Dropless top-k routed gated MLP.  h: (..., D) -> (..., D).
+
+    ``experts``: w_gate / w_up [layers, E, D, F] and w_down [layers, E, F, D]
+    stacked over layers, of which ``layer`` is read (ops/grouped_matmul says
+    why they come whole); router_w: (D, E), this layer's.  Every (token,
+    expert) assignment is computed: no capacity, no dropped token.
+    Returns (out, experts_hit): how many experts some token reached, which
+    is how many the grouped product read.
+    """
+    hf = h.reshape(-1, h.shape[-1])
+    weights, chosen = route(hf, router_w, top_k, renormalise)
+    out, experts_hit = dispatch(hf, weights, chosen, experts, layer)
+    return out.reshape(h.shape), experts_hit
+
+
+def dispatch(hf, weights, chosen, experts, layer):
+    """sum_k weights[n, k] x expert chosen[n, k] of hf[n]: (N, D) -> ((N, D),
+    experts with a row at all, int32).
+
+    The N x k assignments are sorted by expert; each expert's run is padded
+    to whole row tiles and the padded layout is gathered from hf,
+    multiplied tile by tile with the tile's expert (``grouped_mlp``), and
+    gathered back with the weights.  The padded layout is sized for the
+    worst split (every expert's run ending one row into a tile).
+    """
+    from ray_tpu.ops.grouped_matmul import grouped_mlp
+
+    (n, d), top_k = hf.shape, chosen.shape[1]
+    e = experts["w_gate"].shape[1]
+    m = n * top_k
+    tile = row_tile(m, e)
+    n_tiles = min(m, -(-(m + e * (tile - 1)) // tile))
+    flat = chosen.reshape(m)  # assignment a = token * k + choice
+    order = jnp.argsort(flat, stable=True)  # by expert, then by token
+    sizes = jnp.bincount(flat, length=e)
+    padded = -(-sizes // tile) * tile
+    run_end = jnp.cumsum(padded)
+    sorted_e = flat[order]
+    rank = jnp.arange(m) - (jnp.cumsum(sizes) - sizes)[sorted_e]
+    row_sorted = (run_end - padded)[sorted_e] + rank  # its padded row
+    # padded rows with no assignment read token 0; nothing reads them back
+    src = jnp.zeros(n_tiles * tile, jnp.int32).at[row_sorted].set(
+        (order // top_k).astype(jnp.int32))
+    tile_expert = jnp.searchsorted(
+        run_end, jnp.arange(n_tiles) * tile, side="right")
+    # the tiles past the last run keep its expert: no block is fetched
+    tile_expert = jnp.minimum(tile_expert, sorted_e[-1])
+    out = grouped_mlp(hf[src], experts["w_gate"], experts["w_up"],
+                      experts["w_down"], tile_expert, run_end[-1] // tile,
+                      layer, tile=tile)
+    row = jnp.zeros(m, jnp.int32).at[order].set(row_sorted.astype(jnp.int32))
+    out = out[row].reshape(n, top_k, d).astype(jnp.float32)
+    return (jnp.einsum("nk,nkd->nd", weights, out).astype(hf.dtype),
+            jnp.sum(sizes > 0).astype(jnp.int32))
 
 
 def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,

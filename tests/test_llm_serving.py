@@ -34,6 +34,17 @@ def tiny_loader():
     return llama.init(cfg, jax.random.PRNGKey(7)), cfg
 
 
+def tiny_sdar_loader():
+    """A block-diffusion MoE (models/sdar_moe.py) over the byte
+    tokenizer's vocabulary."""
+    import jax
+
+    from ray_tpu.models import sdar_moe
+
+    cfg = sdar_moe.SDARMoEConfig.tiny(259, max_seq_len=512)
+    return sdar_moe.init(cfg, jax.random.PRNGKey(7)), cfg
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _cluster(ray_cluster):
     # join the session cluster (conftest.ray_cluster owns the
@@ -75,11 +86,47 @@ def test_openai_endpoints():
     serve.delete("llm")
 
 
-def test_batch_processor_over_dataset():
+def test_block_diffusion_model_streams_over_http():
+    """The same path, chosen by the model configuration's type alone:
+    build_openai_app -> serve.run -> HTTP -> LLMServer -> LLMEngine, whose
+    passes fill a block of 4 at a time; the stream carries every token, a
+    sampled request is refused by name."""
+    import json
+
+    app = build_openai_app(LLMConfig(
+        model_id="tiny-sdar", model_loader=tiny_sdar_loader,
+        engine_config=EngineConfig(max_slots=4, num_pages=128, page_size=8,
+                                   max_seq_len=256,
+                                   prefill_buckets=(32, 64, 128)),
+        default_max_tokens=8))
+    serve.run(app, name="sdar", route_prefix="/sdar",
+              _blocking_timeout_s=120)
+    base = f"http://127.0.0.1:{serve.http_port()}/sdar/v1"
+    body = {"prompt": [7, 8, 9, 10, 11, 12, 13], "max_tokens": 10,
+            "ignore_eos": True}
+    whole = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert whole["usage"]["completion_tokens"] == 10, whole
+    events = []
+    with requests.post(f"{base}/completions", json={**body, "stream": True},
+                       stream=True, timeout=300) as r:
+        for line in r.iter_lines():
+            if line.startswith(b"data: ") and line != b"data: [DONE]":
+                events.append(json.loads(line[6:]))
+    text = "".join(e["choices"][0]["text"] for e in events)
+    assert text == whole["choices"][0]["text"]
+    r = requests.post(f"{base}/completions",
+                      json={**body, "temperature": 0.7}, timeout=300)
+    assert "diffusion over blocks" in r.text
+    serve.delete("sdar")
+
+
+@pytest.mark.parametrize("loader", [tiny_loader, tiny_sdar_loader])
+def test_batch_processor_over_dataset(loader):
     from ray_tpu import data as rd
 
     processor = build_llm_processor(ProcessorConfig(
-        model_loader=tiny_loader,
+        model_loader=loader,
         engine_config=EngineConfig(max_slots=4, num_pages=128, page_size=8,
                                    max_seq_len=256,
                                    prefill_buckets=(32, 64)),

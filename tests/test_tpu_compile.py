@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models import llama
+from ray_tpu.models import llama, sdar_moe
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
@@ -207,6 +207,66 @@ def test_prefill_holds_the_page_pool_once(topo, as_tpu, program, n_layers,
         params, i32(bucket), cache, cache, i32(bucket), i32(), i32(bucket),
         *table, cfg).compile()
     _assert_holds_the_pool_once(compiled, n_layers)
+
+
+def _sdar_cell():
+    """The block-diffusion cell's configuration (SDAR-30B-A3B widths, 8 of
+    48 layers, every one of the 128 experts, the whole vocabulary).  The
+    chip's-share rule does not apply: nothing of a layer is held
+    elsewhere."""
+    return sdar_moe.SDARMoEConfig(n_layers=8, max_seq_len=1024,
+                                  denoising_steps=2,
+                                  remasking_strategy="sequential")
+
+
+# planned bytes a program of the cell, compiled for the described v5e here
+# (PERF.md section 4): the weights and the pools are 11.75 GB of each
+SDAR_PLANNED_GB = {"block_step": 11.830, 64: 11.130, 128: 11.130,
+                   256: 11.130, 512: 11.133}
+
+
+@pytest.mark.parametrize("program", ["block_step", 64, 128, 256, 512])
+def test_block_diffusion_programs_compile_at_sdar_widths(topo, as_tpu,
+                                                         program):
+    """``block_step`` (32 slots of 4 rows through 64-page tables) and the
+    four prefill buckets of configuration ``sdar30b_a3b_serve_1chip``,
+    over its pool of 2,048 pages: each plans under 0.9 of the chip's bytes_limit beside
+    11.2 GB of weights, the pools are aliased to the outputs, the routed
+    experts go through the grouped kernel and stay where they lie (no copy
+    of a layer's 1.2 GB of them), and a pass attends through the paged
+    kernel."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = _sdar_cell()
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: sdar_moe.init(cfg, k), jax.random.PRNGKey(0)))
+    cache = sds((cfg.n_layers, 2048, 16, cfg.n_kv_heads, cfg.head_dim),
+                jnp.bfloat16)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "block_step":
+        S, B = 32, cfg.block_length
+        compiled = lm.block_step.lower(
+            params, cache, cache, i32(S, 64), sds((S,), jnp.bool_), i32(S, B),
+            sds((S, B), jnp.bool_), i32(S), i32(S), cfg).compile()
+        assert "paged_decode_attention" in compiled.as_text()
+    else:
+        compiled = lm.prefill.lower(
+            params, i32(program), cache, cache, i32(program), i32(),
+            i32(program), cfg).compile()
+    text = compiled.as_text()
+    assert "moe_grouped_mlp" in text
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and (
+        r.startswith("bf16[8,128,") or r.startswith("bf16[128,2048,768]")
+        or r.startswith("bf16[128,768,2048]")
+        or r.startswith("bf16[8,2048,16,4,128]"))]
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * 8 * 2048 * 16 * 4 * 128 * 2
+    planned = _footprint(compiled)
+    assert planned < 0.9 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - SDAR_PLANNED_GB[program]) < 0.05
 
 
 @pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [
