@@ -94,6 +94,14 @@ def _engine_metrics():
                     "its position reaches"),
                 "tokens": Counter(
                     "llm_tokens_total", "Tokens emitted to callers"),
+                "deliveries": Counter(
+                    "llm_deliveries_total", "Times the engine loop handed "
+                    "the streams what its replays had left for them "
+                    "(tokens / this = what one delivery carries)"),
+                "deliveries_behind_dispatch": Counter(
+                    "llm_deliveries_behind_dispatch_total", "Deliveries "
+                    "made right after a device program was dispatched, "
+                    "not at the end of an iteration that dispatched none"),
                 "admitted": Counter(
                     "llm_admitted_total", "Requests admitted to slots"),
                 "preempted": Counter(
@@ -171,7 +179,10 @@ _inject_kv_pages = jax.jit(_inject_kv_pages_impl, donate_argnums=(0, 1))
 # Engine-loop anatomy (ISSUE 24): what the scheduler THREAD was doing, next
 # to the per-request spans that say who was waiting.  Each iteration of
 # _loop is cut into phases that do not overlap and together cover it.  The
-# names are read by PERF.md and benchmarks/trace/host_phases.py.
+# names are read by PERF.md and benchmarks/trace/host_phases.py.  A name
+# may occur more than once an iteration: decode_emit is the replay of a
+# burst and, with ``delivered``, a delivery (which may cut a dispatch phase
+# in two).
 
 P_HYDRATE = "llm.loop.hydrate"
 P_ADMIT = "llm.loop.admit"
@@ -189,6 +200,7 @@ S_LOOP = "llm.loop"  # an iteration's umbrella span
 S_COMPILE = "xla.compile"
 
 # what the values a call site leaves in ``vals`` are called in the record
+# (a ``vals`` that is a dict names its own: a delivery's decode_emit)
 _PHASE_ATTRS = {
     P_HYDRATE: ("pages",), P_ADMIT: ("outcome",),
     P_PREFILL_HOST: ("bucket", "prefix_len"),
@@ -248,7 +260,7 @@ class _LoopPhases:
         self.name: Optional[str] = None
         self.t0 = 0.0
         self.req: Optional[_Request] = None  # whom the open phase serves
-        self.vals: tuple = ()  # its attributes, by _PHASE_ATTRS[name]
+        self.vals = ()  # its attributes, by _PHASE_ATTRS[name] or a dict
         self._ann = None
         self._done: list = []  # (name, t0, t1, req, vals), sampled only
         self._trace = tracing.LoopTrace()
@@ -299,7 +311,8 @@ class _LoopPhases:
                 tid, S_LOOP, start + wall, self._done[-1][2] + wall,
                 kind="engine", attrs={"it": self.it})
             for name, t0, t1, req, vals in self._done:
-                attrs = dict(zip(_PHASE_ATTRS[name], vals), it=self.it)
+                attrs = dict(vals if type(vals) is dict
+                             else zip(_PHASE_ATTRS[name], vals), it=self.it)
                 if req is not None:
                     attrs["request_id"] = req.request_id
                     if req.trace_ctx is not None:
@@ -442,6 +455,9 @@ class LLMEngine:
         # via stats(), whose individual reads are GIL-atomic.  Do not add
         # cross-thread mutation without introducing a real lock.
         self._slots: List[Optional[_Slot]] = [None] * self.cfg.max_slots
+        # (out_queue, items) a replay left for the streams, in order: put
+        # by _deliver once the next device program is dispatched
+        self._undelivered: List[tuple] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
@@ -449,7 +465,8 @@ class LLMEngine:
                        "decode_pages_read": 0, "block_slot_passes": 0,
                        "masks_filled": 0, "blocks_final": 0,
                        "experts_read": 0,
-                       "tokens_generated": 0, "preempted": 0,
+                       "tokens_generated": 0, "deliveries": 0,
+                       "deliveries_behind_dispatch": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
                        "eviction_scans": 0,
                        "prefill_tokens_saved": 0, "cow_copies": 0,
@@ -623,6 +640,7 @@ class LLMEngine:
         try:
             self._run_loop(ph)
         finally:
+            self._deliver(False)  # stop(): what the last replay left
             ph.end()
             if ph.sampled:
                 _sampled_loops.pop(threading.get_ident(), None)
@@ -654,11 +672,11 @@ class LLMEngine:
                 import traceback
 
                 traceback.print_exc()
+                self._deliver(False)  # replayed tokens, then the error
                 ph.finish_iteration(True)
                 for i, s in enumerate(self._slots):
                     if s is not None:
-                        s.request.out_queue.put(e)
-                        s.request.out_queue.put(None)
+                        self._fail(s.request, e)
                         self.allocator.free(s.pages)
                         self._slots[i] = None
                 while True:
@@ -666,10 +684,12 @@ class LLMEngine:
                         req = self._waiting.get_nowait()
                     except queue_mod.Empty:
                         break
-                    req.out_queue.put(e)
-                    req.out_queue.put(None)
+                    self._fail(req, e)
                 continue
-            worked = admitted or stepped or hydrated
+            # an iteration that gave the device no program delivers here:
+            # nothing is stranded behind an idle loop
+            delivered = not (admitted or stepped) and self._deliver(False)
+            worked = admitted or stepped or hydrated or delivered
             now = time.monotonic()
             if now - self._gauges_at >= 0.25:
                 self._gauges_at = now
@@ -833,8 +853,9 @@ class LLMEngine:
                     idx = np.asarray(pages)
                     kv_k = np.asarray(self.cache_k[:, idx])
                     kv_v = np.asarray(self.cache_v[:, idx])
-                    req.out_queue.put(("prefill_done", last, kv_k, kv_v))
-                    req.out_queue.put(None)
+                    self._undelivered.append(
+                        (req.out_queue,
+                         (("prefill_done", last, kv_k, kv_v), None)))
                     self._register_blocks(req.prompt_tokens, pages)
                     # P/D tier handoff: seal regardless of family heat —
                     # the sealed spine IS the page transfer the decode
@@ -842,8 +863,7 @@ class LLMEngine:
                     self._maybe_seal(req.prompt_tokens, force=True)
                     self._close_request_span(req)
                 except Exception as e:  # noqa: BLE001
-                    req.out_queue.put(e)
-                    req.out_queue.put(None)
+                    self._fail(req, e)
                     self._close_request_span(req, ok=False)
                 finally:
                     self.allocator.free(pages)
@@ -944,8 +964,7 @@ class LLMEngine:
                     last = self._prefill(req, pages, rng, prefix_len)
             except Exception as e:  # noqa: BLE001 — surface to caller
                 self.allocator.free(pages)
-                req.out_queue.put(e)
-                req.out_queue.put(None)
+                self._fail(req, e)
                 self._close_request_span(req, ok=False, error=repr(e))
                 continue
             finally:
@@ -973,8 +992,7 @@ class LLMEngine:
                          num_tokens=len(req.prompt_tokens),
                          last_token=last, rng=rng)
             if last in req.params.stop_token_ids:
-                self._finish_request(req)
-                req.out_queue.put(None)
+                self._end_stream(req)
                 self.allocator.free(pages)
             else:
                 slot.generated.append(last)
@@ -984,10 +1002,9 @@ class LLMEngine:
                     self._stats["tokens_generated"] += 1
                     req.produced += 1
                 else:
-                    self._emit(slot, last)
+                    self._emit(slot, [last])
                 if req.produced >= req.params.max_tokens:
-                    self._finish_request(req)
-                    req.out_queue.put(None)
+                    self._end_stream(req)
                     self.allocator.free(pages)
                 else:
                     self._slots[free_slot] = slot
@@ -1037,6 +1054,7 @@ class LLMEngine:
             logits, self.cache_k, self.cache_v = program(
                 self.params, tokens, self.cache_k, self.cache_v, *args,
                 self.model_cfg)
+            self._deliver(True)
             ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
             logits = np.asarray(logits)
             ph.begin(P_PREFILL_EMIT, req)
@@ -1494,32 +1512,29 @@ class LLMEngine:
                         tables_dev, pos_dev + j, active_dev,
                         self.model_cfg)
                 steps.append(toks_dev)
+                if j == 0 and self._deliver(True) and burst > 1:
+                    ph.begin(P_DECODE_DISPATCH, vals=(burst,))
             # the host waits for the device
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             # ONE host round trip for the whole burst (stack on device)
-            rows = np.asarray(jnp.stack(steps)) if burst > 1 else [
-                np.asarray(steps[0])]
+            rows = (np.asarray(jnp.stack(steps)) if burst > 1
+                    else np.asarray(steps[0])[None])
             ph.begin(P_DECODE_EMIT)
-            self._stats["decode_steps"] += burst
-            self._m["decode_steps"].inc(burst)
-            for row in rows:
-                for i, s in active_slots:
-                    if self._slots[i] is not s:
-                        continue  # finished earlier in this burst
-                    self._accept_token(i, s, int(row[i]))
         else:
             logits, self.cache_k, self.cache_v = lm.decode_step(
                 self.params, toks_dev, self.cache_k, self.cache_v,
                 tables_dev, pos_dev, active_dev, self.model_cfg)
+            self._deliver(True)
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             logits_np = np.asarray(logits)
             ph.begin(P_DECODE_EMIT)
-            self._stats["decode_steps"] += 1
-            self._m["decode_steps"].inc()
+            rows = np.zeros((1, B), np.int32)
             for i, s in active_slots:
-                tok = self._sample_one(logits_np[i], s.request.params,
-                                       s.rng)
-                self._accept_token(i, s, tok)
+                rows[0, i] = self._sample_one(
+                    logits_np[i], s.request.params, s.rng)
+        self._stats["decode_steps"] += burst
+        self._m["decode_steps"].inc(burst)
+        self._accept_burst(active_slots, rows)
         if ph.sampled:
             ph.vals = (self._stats["tokens_generated"] - emitted,
                        sum(self._slots[i] is not s for i, s in active_slots))
@@ -1567,11 +1582,13 @@ class LLMEngine:
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))
         records = []
-        for _ in range(burst):
+        for j in range(burst):
             record, *state, self.cache_k, self.cache_v = lm.block_step(
                 self.params, self.cache_k, self.cache_v, tables_dev,
                 active_dev, *state, self.model_cfg)
             records.append(record)
+            if j == 0 and self._deliver(True) and burst > 1:
+                ph.begin(P_DECODE_DISPATCH, vals=(burst,))
         ph.begin(P_DECODE_FETCH, vals=(burst,))  # the host waits
         # plain lists: the replay below reads every number of them
         rows = (np.asarray(jnp.stack(records)).tolist() if burst > 1
@@ -1622,54 +1639,119 @@ class LLMEngine:
             return
         # the pass that filled the block's last mask emits its new tokens,
         # in position order; max_tokens and stop tokens cut in that order
-        sp = s.request.params
-        for tok in s.blk_tokens[s.blk_given:]:
-            if tok in sp.stop_token_ids:
-                self._release_slot(i, s)
-                return
-            s.generated.append(tok)
-            self._emit(s, tok)
-            if s.request.produced >= sp.max_tokens:
-                self._release_slot(i, s)
-                return
+        new, _, ends = self._cut(s.request, s.blk_tokens[s.blk_given:])
+        if new:
+            s.generated.extend(new)
+            self._emit(s, new)
+        if ends:
+            self._release_slot(i, s)
 
-    def _accept_token(self, i: int, s: _Slot, tok: int):
-        """Record one sampled token for slot i: emit, finish, or continue."""
-        s.num_tokens += 1  # last_token's KV is now in the cache
-        sp = s.request.params
-        if tok in sp.stop_token_ids:
+    @staticmethod
+    def _cut(req: _Request, toks: List[int]):
+        """What a request makes of ``toks``, in order: the tokens it takes
+        (those before the first stop token, up to the one that reaches
+        ``max_tokens``), how many of ``toks`` that used up (a stop token
+        is used and not taken), and whether the request ends there."""
+        sp = req.params
+        n = len(toks)
+        room = max(1, sp.max_tokens - req.produced)
+        stop = n
+        if sp.stop_token_ids:
+            stop = next((j for j, t in enumerate(toks)
+                         if t in sp.stop_token_ids), n)
+        stopped = stop < min(n, room)
+        taken = min(stop, room)
+        return toks[:taken], taken + stopped, stopped or room <= n
+
+    def _accept_burst(self, active_slots, rows: np.ndarray) -> None:
+        """Replay a burst's sampled tokens ``rows`` [steps, slots], a slot
+        at a time: a slot takes its column as far as its request goes (what
+        later steps wrote and sampled for it is overshoot), and the slots
+        that ended are released in the order the steps ended them."""
+        ended = []
+        cols = rows.T.tolist()
+        for i, s in active_slots:
+            new, used, ends = self._cut(s.request, cols[i])
+            s.num_tokens += used  # a step put its input's K/V in the cache
+            if new:
+                s.generated.extend(new)
+                self._emit(s, new)
+            if ends:
+                ended.append((used, i, s))
+            else:
+                s.last_token = new[-1]
+        for _, i, s in sorted(ended, key=lambda e: e[:2]):
             self._release_slot(i, s)
-            return
-        s.generated.append(tok)
-        self._emit(s, tok)
-        if s.request.produced >= sp.max_tokens:
-            self._release_slot(i, s)
-        else:
-            s.last_token = tok
 
     def _release_slot(self, i: int, s: _Slot) -> None:
         """Finish a sequence: register its full pages (prompt AND generated
         KV — a follow-up turn extending this conversation hits them) and
         release; cached pages stay resident until the pool reclaims them."""
-        self._finish_request(s.request)
-        s.request.out_queue.put(None)
+        self._end_stream(s.request)
         seq = s.request.prompt_tokens + s.generated
         self._register_blocks(seq[:s.num_tokens], s.pages)
         self.allocator.free(s.pages)
         self._slots[i] = None
 
-    def _emit(self, slot: _Slot, token: int):
-        self._stats["tokens_generated"] += 1
+    # ------------------------- delivery ------------------------------------
+    # A replay changes STATE (slots, requests, counters, pages), which the
+    # next device program is built from; what the streams are to receive it
+    # leaves in _undelivered.  _deliver puts that once the next program is
+    # on the device, so the pull threads it wakes (32 of them, each with
+    # JSON to write under the interpreter lock) run while the chip is busy
+    # and not while it waits for this thread.
+
+    def _emit(self, slot: _Slot, tokens: List[int]) -> None:
+        """Count ``tokens`` (ints, in order) as generated by the slot's
+        request and leave them for the next delivery."""
+        n = len(tokens)
+        self._stats["tokens_generated"] += n
         req = slot.request
-        req.emitted += 1
-        req.produced += 1  # survives preemption (len(generated) does not)
+        req.emitted += n
+        req.produced += n  # survives preemption (len(generated) does not)
         if req.first_token_at is None:
             req.first_token_at = time.monotonic()
             self._m["ttft"].observe(
                 req.first_token_at - req.submitted_at,
                 exemplar=req.trace_ctx[0] if req.trace_ctx else None)
-        self._m["tokens"].inc()
-        req.out_queue.put(int(token))
+        self._m["tokens"].inc(n)
+        self._undelivered.append((req.out_queue, tokens))
+
+    def _end_stream(self, req: _Request) -> None:
+        """A request finished: its latencies, and the terminator behind
+        whatever of it is still to be delivered."""
+        self._finish_request(req)
+        self._undelivered.append((req.out_queue, (None,)))
+
+    def _deliver(self, behind_dispatch: bool) -> bool:
+        """Put what the replays left, in their order; every put of the
+        decode and prefill side is made here.  Banked as a decode_emit
+        phase of its own (``delivered``: the items put); the caller opens
+        the phase that follows.  False where nothing was left."""
+        left = self._undelivered
+        if not left:
+            return False
+        ph = self._ph
+        ph.begin(P_DECODE_EMIT)
+        for k in (("deliveries", "deliveries_behind_dispatch")
+                  if behind_dispatch else ("deliveries",)):
+            self._stats[k] += 1
+            self._m[k].inc()
+        n = 0
+        for out_queue, items in left:
+            for item in items:
+                out_queue.put(item)
+            n += len(items)
+        left.clear()
+        ph.vals = {"delivered": n}
+        return True
+
+    def _fail(self, req: _Request, e: Exception) -> None:
+        """The error, then the terminator, behind everything the request
+        was still to be delivered."""
+        self._deliver(False)
+        req.out_queue.put(e)
+        req.out_queue.put(None)
 
     def _sample_one(self, logits: np.ndarray, params: SamplingParams,
                     rng: Optional[np.random.Generator]) -> int:

@@ -89,20 +89,40 @@ def test_phases_are_ordered_disjoint_and_cover_their_iteration(sampled_run):
         assert abs(covered - (loop["end_ts"] - loop["start_ts"])) < 1e-3
         assert phases[0]["start_ts"] == loop["start_ts"]
         assert phases[-1]["end_ts"] == loop["end_ts"]
-    # a decode iteration runs host -> dispatch -> fetch -> emit
-    names = [p["name"].rsplit(".", 1)[1] for p in its[max(its)]]
-    assert names[-4:] == ["decode_host", "decode_dispatch", "decode_fetch",
-                          "decode_emit"]
-    emit = its[max(its)][-1]["args"]
-    assert emit["slots_released"] == 1 and emit["tokens"] >= 1
+    # a decode iteration runs host -> dispatch -> fetch -> emit, and what
+    # the burst before it replayed is delivered (a decode_emit of its own)
+    # once the burst's first step is dispatched
+    last_burst, after = sorted(its)[-2:]
+    names = [p["name"].rsplit(".", 1)[1] for p in its[last_burst]]
+    assert names[-6:] == ["decode_host", "decode_dispatch", "decode_emit",
+                          "decode_dispatch", "decode_fetch", "decode_emit"]
+    delivery, replay = (its[last_burst][k]["args"] for k in (-4, -1))
+    assert set(delivery) == {"delivered", "it"} and delivery["delivered"] == 8
+    assert replay["slots_released"] == 1 and replay["tokens"] >= 1
+    assert "delivered" not in replay
+    # the request's last tokens and its terminator leave in the iteration
+    # that finds nothing to dispatch, before it ends
+    names = [p["name"].rsplit(".", 1)[1] for p in its[after]]
+    assert names[:2] == ["admit", "decode_emit"]
+    assert its[after][1]["args"] == {
+        "delivered": replay["tokens"] + 1, "it": after}
+    # every token reached a stream through a delivery, with a terminator a
+    # request
+    delivered = sum(p["args"].get("delivered", 0)
+                    for ps in its.values() for p in ps)
+    assert delivered == sum(len(o) for o in sampled_run[1]) + 2
 
 
 def test_an_idle_stretch_is_one_span(sampled_run):
     recs, _, _ = sampled_run
     first_end = max(r["end_ts"] for r in recs if r["args"].get(
         "request_id") and r["args"]["it"] == min(_by_iteration(recs)))
+    # the stretch between the two requests (stop() may cut another, after
+    # the last working iteration)
+    last_work = max(r["start_ts"] for r in recs
+                    if r["name"] == engine_mod.S_LOOP)
     idle = [r for r in recs if r["name"] == engine_mod.P_IDLE
-            and r["start_ts"] >= first_end]
+            and first_end <= r["start_ts"] < last_work]
     assert len(idle) == 1, idle
     assert 0.29 <= idle[0]["end_ts"] - idle[0]["start_ts"] < 2.0
     # one span for ~150 sleeps of 2 ms, and it says so
