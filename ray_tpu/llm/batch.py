@@ -19,7 +19,10 @@ from ray_tpu.llm.tokenizer import get_tokenizer
 class ProcessorConfig:
     """Reference: batch/processor/__init__.py ProcessorConfig lineage."""
 
-    model_loader: Callable = None  # () -> (params, LlamaConfig)
+    # () -> (params, the model's configuration): whatever the programs of
+    # llm/model.py serve, a LlamaConfig or an SDARMoEConfig (their docstring
+    # says what they read of it)
+    model_loader: Callable = None
     tokenizer: Optional[str] = None
     engine_config: EngineConfig = field(default_factory=EngineConfig)
     concurrency: int = 1  # engine actors

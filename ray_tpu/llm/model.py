@@ -30,6 +30,18 @@ prompt's last one), and ``block_step`` is one denoising pass over every
 slot's open block, its rows attending through the page table like a decode
 step's.  Its feed-forward, the routed experts, comes with the parameters.
 
+``cfg`` is the model's configuration, hashable (a static argument), of
+whichever family the parameters are: a ``LlamaConfig`` or an
+``SDARMoEConfig`` today.  The programs read of it ``n_layers``,
+``n_heads``, ``n_kv_heads`` and ``head_dim`` here and, through
+models/llama.py's parts, ``dtype``, ``norm_eps`` and ``rope_theta``; of a
+block-diffusion configuration also ``block_length``, ``mask_token_id``,
+``denoising_steps``, ``remasking_strategy`` and ``confidence_threshold``
+(the sampler) and, through ``sdar_moe.scan_layers``, ``experts_per_token``
+and ``norm_topk_prob``.  Which feed-forward and which head norms a layer
+has is read off the parameters (``"experts" in params["layers"]``,
+``"q_norm" in p["attn"]``), not off a type.
+
 Each ``attend`` first writes its new K/V rows into the layer's pages.
 Every program carries both pools through its layer scan whole, with the
 layer's index beside the layer's parameters, and scatters in place at
@@ -49,24 +61,25 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import sdar_moe
-from ray_tpu.models.llama import LlamaConfig, embed, head, layer
+from ray_tpu.models.llama import embed, head, layer
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
-def _masked_attention(cfg: LlamaConfig, q, keys, vals, mask):
+def _masked_attention(cfg, q, keys, vals, mask):
     """Dense softmax attention of q [L, H, d] over keys/vals [T, Hkv, d]
     repeated to the query heads, where ``mask`` [L, T] allows."""
     rep = cfg.n_heads // cfg.n_kv_heads
-    keys = jnp.repeat(keys, rep, axis=1)  # [T, H, d]
-    vals = jnp.repeat(vals, rep, axis=1)
-    scores = jnp.einsum("qhd,khd->hqk", q, keys) / (cfg.head_dim ** 0.5)
-    scores = jnp.where(mask[None], scores, -1e30)
-    attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
+    with jax.named_scope("attn/attend"):
+        with jax.named_scope("repeat_kv"):
+            keys = jnp.repeat(keys, rep, axis=1)  # [T, H, d]
+            vals = jnp.repeat(vals, rep, axis=1)
+        scores = jnp.einsum("qhd,khd->hqk", q, keys) / (cfg.head_dim ** 0.5)
+        scores = jnp.where(mask[None], scores, -1e30)
+        attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
 
 
-def _scan_layers(params, x, cache_k, cache_v, positions, attend,
-                 cfg: LlamaConfig):
+def _scan_layers(params, x, cache_k, cache_v, positions, attend, cfg):
     """The layer scan of every program here: both pools ride in the carry
     whole, never scanned over, and ``attend(q, k, v, (ck, cv, li))`` writes
     layer ``li``'s rows into them in place and attends its own way.
@@ -88,9 +101,10 @@ def _scan_layers(params, x, cache_k, cache_v, positions, attend,
         x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li))
         return (x, ck, cv), None
 
-    (x, cache_k, cache_v), _ = jax.lax.scan(
-        body, (x, cache_k, cache_v),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    with jax.named_scope("layers"):
+        (x, cache_k, cache_v), _ = jax.lax.scan(
+            body, (x, cache_k, cache_v),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     return x, cache_k, cache_v, None
 
 
@@ -120,7 +134,7 @@ def _prefill_result(params, x, cfg, true_len, experts_hit):
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
 def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
-            slot_positions, cfg: LlamaConfig):
+            slot_positions, cfg):
     """Prefill ONE sequence padded to a length bucket.
 
     tokens: [L] int32 (padded); page_rows: [L] page id per token position;
@@ -138,8 +152,9 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         ck, cv, li = pools
         # write k/v into this layer's pages (beyond true_len the rows
         # write into the sequence's own pages — masked out of attention)
-        ck = ck.at[li, page_rows, slot_positions].set(k)
-        cv = cv.at[li, page_rows, slot_positions].set(v)
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, page_rows, slot_positions].set(k)
+            cv = cv.at[li, page_rows, slot_positions].set(v)
         # within the sequence: this call's own k and v, never the pool
         return _masked_attention(cfg, q, k, v, mask), (ck, cv)
 
@@ -151,7 +166,7 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
 def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                         true_len, slot_positions, page_table, positions,
-                        cfg: LlamaConfig):
+                        cfg):
     """Prefill the SUFFIX of one sequence whose leading pages are already
     resident (prefix-cache hit).
 
@@ -173,14 +188,16 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
         ck, cv, li = pools
         # suffix writes go to the sequence's own fresh pages only: matched
         # prefix pages cover positions < prefix_len and are never written
-        ck = ck.at[li, page_rows, slot_positions].set(k)
-        cv = cv.at[li, page_rows, slot_positions].set(v)
-        keys = ck[li, page_table].reshape(P * page_size, cfg.n_kv_heads,
-                                          cfg.head_dim)
-        vals = cv[li, page_table].reshape(P * page_size, cfg.n_kv_heads,
-                                          cfg.head_dim)
-        # [L, T] causal over absolutes
-        mask = _visible(cfg, positions, jnp.arange(P * page_size))
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, page_rows, slot_positions].set(k)
+            cv = cv.at[li, page_rows, slot_positions].set(v)
+        with jax.named_scope("attn/attend"):  # the gather is attending
+            keys = ck[li, page_table].reshape(
+                P * page_size, cfg.n_kv_heads, cfg.head_dim)
+            vals = cv[li, page_table].reshape(
+                P * page_size, cfg.n_kv_heads, cfg.head_dim)
+            # [L, T] causal over absolutes
+            mask = _visible(cfg, positions, jnp.arange(P * page_size))
         return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
 
     x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
@@ -189,7 +206,7 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
-                 active, cfg: LlamaConfig):
+                 active, cfg):
     """One token for EVERY slot (the continuous-batching hot loop).
 
     tokens: [B] int32 current token per slot; positions: [B] its position;
@@ -217,10 +234,12 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
 
     def attend(q, k, v, pools):  # q: [B, H, d]; k, v: [B, Hkv, d]
         ck, cv, li = pools
-        ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
-        cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
-        return (paged_decode_attention(q, ck, cv, page_tables, lengths, li),
-                (ck, cv))
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
+            cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+        with jax.named_scope("attn/attend"):
+            return (paged_decode_attention(q, ck, cv, page_tables, lengths,
+                                           li), (ck, cv))
 
     x, cache_k, cache_v, _ = _scan_layers(params, x, cache_k, cache_v,
                                           positions, attend, cfg)
@@ -229,21 +248,22 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
 def decode_step(params, tokens, cache_k, cache_v, page_tables, positions,
-                active, cfg: LlamaConfig):
+                active, cfg):
     return _decode_impl(params, tokens, cache_k, cache_v, page_tables,
                         positions, active, cfg)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
 def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
-                       positions, active, cfg: LlamaConfig):
+                       positions, active, cfg):
     """Greedy decode: argmax ON DEVICE, so the host fetches [B] int32
     instead of [B, vocab] fp32 logits — the device-to-host round trip is the
     decode loop's fixed cost when every active request samples greedily."""
     logits, cache_k, cache_v = _decode_impl(
         params, tokens, cache_k, cache_v, page_tables, positions, active,
         cfg)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_k, cache_v
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_k, cache_v
 
 
 def _fill(cfg, logits, masked, step):
@@ -253,25 +273,26 @@ def _fill(cfg, logits, masked, step):
     fill [S, B] bool).  Only a masked position is ever filled (the
     published top-k can name an unmasked one in a block a prompt's tail
     opened; a filled position never changes here)."""
-    B, T = cfg.block_length, cfg.denoising_steps
-    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    n_t = jnp.asarray(sdar_moe.num_transfer_tokens(B, T), jnp.int32)[
-        jnp.minimum(step, T - 1)][:, None]  # [S, 1]
-    if cfg.remasking_strategy == "sequential":  # the leftmost masked
-        return x0, masked & (jnp.cumsum(masked, axis=1) <= n_t)
-    # softmax probability of x0, masked positions only
-    conf = jnp.exp(jnp.max(logits, axis=-1)
-                   - jax.scipy.special.logsumexp(logits, axis=-1))
-    conf = jnp.where(masked, conf, -jnp.inf)
-    # rank 0 is the most confident; ties go to the leftmost
-    order = jnp.argsort(-conf, axis=1, stable=True)
-    rank = jnp.argsort(order, axis=1, stable=True)
-    static = masked & (rank < n_t)
-    if cfg.remasking_strategy == "low_confidence_static":
-        return x0, static
-    high = conf > cfg.confidence_threshold
-    enough = jnp.sum(high, axis=1, keepdims=True) >= n_t
-    return x0, jnp.where(enough, high, static)
+    with jax.named_scope("sample"):
+        B, T = cfg.block_length, cfg.denoising_steps
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        n_t = jnp.asarray(sdar_moe.num_transfer_tokens(B, T), jnp.int32)[
+            jnp.minimum(step, T - 1)][:, None]  # [S, 1]
+        if cfg.remasking_strategy == "sequential":  # the leftmost masked
+            return x0, masked & (jnp.cumsum(masked, axis=1) <= n_t)
+        # softmax probability of x0, masked positions only
+        conf = jnp.exp(jnp.max(logits, axis=-1)
+                       - jax.scipy.special.logsumexp(logits, axis=-1))
+        conf = jnp.where(masked, conf, -jnp.inf)
+        # rank 0 is the most confident; ties go to the leftmost
+        order = jnp.argsort(-conf, axis=1, stable=True)
+        rank = jnp.argsort(order, axis=1, stable=True)
+        static = masked & (rank < n_t)
+        if cfg.remasking_strategy == "low_confidence_static":
+            return x0, static
+        high = conf > cfg.confidence_threshold
+        enough = jnp.sum(high, axis=1, keepdims=True) >= n_t
+        return x0, jnp.where(enough, high, static)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1, 2))
@@ -319,13 +340,15 @@ def block_step(params, cache_k, cache_v, page_tables, active, tokens,
 
     def attend(q, k, v, pools):  # q: [S * B, H, d]; k, v: [S * B, Hkv, d]
         ck, cv, li = pools
-        ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
-        cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
-        q = q.reshape(S, B, n_kv, rep, d).transpose(0, 2, 1, 3, 4)
-        out = paged_decode_attention(q.reshape(S, n_kv * B * rep, d), ck, cv,
-                                     page_tables, lengths, li)
-        out = out.reshape(S, n_kv, B, rep, d).transpose(0, 2, 1, 3, 4)
-        return out.reshape(S * B, n_kv * rep, d), (ck, cv)
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
+            cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+        with jax.named_scope("attn/attend"):
+            q = q.reshape(S, B, n_kv, rep, d).transpose(0, 2, 1, 3, 4)
+            out = paged_decode_attention(q.reshape(S, n_kv * B * rep, d), ck,
+                                         cv, page_tables, lengths, li)
+            out = out.reshape(S, n_kv, B, rep, d).transpose(0, 2, 1, 3, 4)
+            return out.reshape(S * B, n_kv * rep, d), (ck, cv)
 
     x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
                                             positions, attend, cfg)

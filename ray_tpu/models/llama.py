@@ -144,8 +144,31 @@ def init(cfg: LlamaConfig, key: jax.Array):
 # ---------------------------------------------------------------------------
 # The block, as parts.  ``p`` is one layer's slice of params["layers"].
 
+# The names the parts give their operations (``jax.named_scope``; metadata
+# on the traced operations, nothing at run time).  A part opens its scope
+# where it is written, below and in models/moe.py, models/losses.py,
+# llm/model.py (the ``attend`` strategies, token selection) and
+# train/step.py, so every composition inherits them and a device profile
+# of any program reads ``.../attn/qkv/dot_general`` where it read
+# ``fusion.121``.  ``layers`` is the layer scan itself: what lies under it
+# and under no deeper part is the scan's own work, the slices of the stacked
+# weights (and the transposes XLA makes of them) and the loop's counter.
+# Whoever reads a profile by part (benchmarks/trace/device_parts.py) takes
+# the names from here, the innermost that a path holds; none is a name a
+# JAX primitive or transform uses.
+PARTS = (
+    "embed", "layers",
+    "attn/norm", "attn/qkv", "attn/rope", "attn/kv_write", "attn/attend",
+    "attn/attend/repeat_kv", "attn/out",
+    "mlp/norm", "mlp/gate_up", "mlp/down",
+    "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+    "head", "sample", "loss", "optim",
+)
+
+
 def embed(params, tokens, cfg):
-    return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("embed"):
+        return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
 
 
 def rms_norm(x, weight, eps):
@@ -158,14 +181,16 @@ def rope(x, positions, theta):
     """Rotary embedding; x: (..., heads, head_dim), positions: (...) or
     anything that broadcasts against x's leading axes."""
     head_dim = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
-                      / (head_dim // 2))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (.., d/2)
-    cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    with jax.named_scope("attn/rope"):
+        freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
+                          / (head_dim // 2))
+        angles = positions[..., None].astype(jnp.float32) * freqs  # (.., d/2)
+        cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
+        sin = jnp.sin(angles)[..., None, :]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+        return out.astype(x.dtype)
 
 
 def qkv_rope(cfg, p, h, positions):
@@ -174,12 +199,13 @@ def qkv_rope(cfg, p, h, positions):
     def heads(w, n):
         return (h @ w.astype(h.dtype)).reshape(*h.shape[:-1], n, cfg.head_dim)
 
-    q = heads(p["attn"]["wq"], cfg.n_heads)
-    k = heads(p["attn"]["wk"], cfg.n_kv_heads)
-    v = heads(p["attn"]["wv"], cfg.n_kv_heads)
-    if "q_norm" in p["attn"]:  # Qwen3 family: RMS norm a head, before rope
-        q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["attn"]["k_norm"], cfg.norm_eps)
+    with jax.named_scope("attn/qkv"):
+        q = heads(p["attn"]["wq"], cfg.n_heads)
+        k = heads(p["attn"]["wk"], cfg.n_kv_heads)
+        v = heads(p["attn"]["wv"], cfg.n_kv_heads)
+        if "q_norm" in p["attn"]:  # Qwen3 family: RMS norm a head, pre-rope
+            q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["attn"]["k_norm"], cfg.norm_eps)
     return (rope(q, positions, cfg.rope_theta),
             rope(k, positions, cfg.rope_theta), v)
 
@@ -189,17 +215,23 @@ def attention_block(cfg, p, x, positions, attend, cache=None):
     is the one thing that differs between forward passes: training attends
     within the batch and has no cache; the engine's programs write k and v
     into the layer's pages and attend through them.  ``cache`` is whatever
-    the caller's layer scan hands its strategy, and comes back with x."""
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    the caller's layer scan hands its strategy, and comes back with x.
+    The strategy names its own parts (``attn/attend``, and ``attn/kv_write``
+    where it has a cache to write)."""
+    with jax.named_scope("attn/norm"):
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
     out, cache = attend(*qkv_rope(cfg, p, h, positions), cache)
-    out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim)
-    return x + out @ p["attn"]["wo"].astype(x.dtype), cache
+    with jax.named_scope("attn/out"):
+        out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim)
+        return x + out @ p["attn"]["wo"].astype(x.dtype), cache
 
 
 def gated_mlp(p, h):
-    gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
-    up = h @ p["mlp"]["w_up"].astype(h.dtype)
-    return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
+    with jax.named_scope("mlp/gate_up"):
+        gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
+        up = h @ p["mlp"]["w_up"].astype(h.dtype)
+    with jax.named_scope("mlp/down"):
+        return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
 
 
 def layer(cfg, p, x, positions, attend, cache=None, feed_forward=gated_mlp):
@@ -207,7 +239,8 @@ def layer(cfg, p, x, positions, attend, cache=None, feed_forward=gated_mlp):
     gated MLP unless the caller hands another (models/moe.py's routed
     experts)."""
     x, cache = attention_block(cfg, p, x, positions, attend, cache)
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp/norm"):
+        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     return x + feed_forward(p, h), cache
 
 
@@ -215,10 +248,11 @@ def head(params, x, cfg, true_len=None):
     """Final norm, then ``lm_head`` in float32 (what sampling and the MoE
     loss take).  ``true_len``: x [L, d_model] is one padded sequence of
     which only the last real token's logits are wanted."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if true_len is not None:
-        x = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
-    return x.astype(jnp.float32) @ params["lm_head"]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if true_len is not None:
+            x = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
+        return x.astype(jnp.float32) @ params["lm_head"]
 
 
 # ---------------------------------------------------------------------------
@@ -228,16 +262,19 @@ def batch_attend(attn_impl, mesh, rules=None):
     """The training passes' ``attend``: dense flash or sequence-parallel
     attention (ring / zigzag-balanced ring / ulysses); no cache."""
     def attend(q, k, v, cache):
-        if attn_impl in ("ring", "zigzag", "ulysses"):
-            from ray_tpu.ops.ring_attention import sequence_parallel_attention
+        with jax.named_scope("attn/attend"):
+            if attn_impl in ("ring", "zigzag", "ulysses"):
+                from ray_tpu.ops.ring_attention import (
+                    sequence_parallel_attention)
 
-            if mesh is None:
-                raise ValueError(f"attn_impl={attn_impl!r} requires a mesh")
-            return sequence_parallel_attention(
-                q, k, v, mesh, impl=attn_impl, causal=True,
-                rules=rules), cache
-        return flash_attention(q, k, v, causal=True, impl=attn_impl,
-                               mesh=mesh, rules=rules), cache
+                if mesh is None:
+                    raise ValueError(
+                        f"attn_impl={attn_impl!r} requires a mesh")
+                return sequence_parallel_attention(
+                    q, k, v, mesh, impl=attn_impl, causal=True,
+                    rules=rules), cache
+            return flash_attention(q, k, v, causal=True, impl=attn_impl,
+                                   mesh=mesh, rules=rules), cache
     return attend
 
 
@@ -267,8 +304,10 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
     def scan_body(x, layer_params):
         return step(x, layer_params), None
 
-    x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    with jax.named_scope("head"):  # the final norm is the head's
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def apply(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
@@ -277,8 +316,9 @@ def apply(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
     x = trunk(params, tokens, cfg, attn_impl, mesh=mesh, rules=rules)
     # bf16 operands, fp32 accumulation (preferred_element_type) — the
     # MXU's native mode; logits come out fp32 for a stable softmax.
-    return jnp.dot(x, params["lm_head"].astype(x.dtype),
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        return jnp.dot(x, params["lm_head"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
 
 
 def loss_fn(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",

@@ -31,6 +31,11 @@ def chunked_softmax_xent(x: jax.Array, head: jax.Array, targets: jax.Array,
              ``wte.T`` — XLA folds the transpose into the dot)
     targets: (batch, seq) int32 gold next tokens
     """
+    with jax.named_scope("loss"):  # models/llama.py PARTS
+        return _chunked_softmax_xent(x, head, targets, chunk)
+
+
+def _chunked_softmax_xent(x, head, targets, chunk):
     b, s, _ = x.shape
 
     def nll(xch, tch, mch):

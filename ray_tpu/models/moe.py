@@ -131,44 +131,54 @@ def moe_mlp(cfg: MoEConfig, x, router_w, experts):
     cap = expert_capacity(cfg, n)
     xf = x.reshape(n, d)
 
-    logits = (xf.astype(jnp.float32) @ router_w.astype(jnp.float32))  # (N, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, k)  # (N, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # Mixtral renorm
-
-    # Position of each (token, choice) in its expert's buffer.  Priority is
-    # choice-major (all first choices before any second choice) so a token's
-    # primary expert wins capacity contention.
-    choice_onehot = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)  # (N, k, E)
-    flat = choice_onehot.transpose(1, 0, 2).reshape(k * n, e)
-    pos_flat = jnp.cumsum(flat, axis=0) - flat  # (k*N, E) position per slot
-    pos = pos_flat.reshape(k, n, e).transpose(1, 0, 2)  # (N, k, E)
-    pos_in_expert = jnp.sum(pos * choice_onehot, axis=-1)  # (N, k)
-    keep = pos_in_expert < cap  # capacity drop mask
-
-    # (N, k, E, C) collapsed over k -> dispatch (N, E, C)
-    cap_onehot = jax.nn.one_hot(pos_in_expert.astype(jnp.int32), cap,
-                                dtype=jnp.float32)
-    dispatch = jnp.einsum("nke,nkc,nk->nec", choice_onehot, cap_onehot,
-                          keep.astype(jnp.float32))
-    combine = jnp.einsum("nec,nke,nk->nec", dispatch, choice_onehot, top_p)
+    with jax.named_scope("moe/route"):
+        logits = (xf.astype(jnp.float32)
+                  @ router_w.astype(jnp.float32))  # (N, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_idx = jax.lax.top_k(probs, k)  # (N, k)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # Mixtral
 
     compute_dtype = x.dtype
-    expert_in = jnp.einsum("nec,nd->ecd", dispatch.astype(compute_dtype), xf)
-    gate = jax.nn.silu(jnp.einsum(
-        "ecd,edf->ecf", expert_in, experts["w_gate"].astype(compute_dtype)))
-    up = jnp.einsum("ecd,edf->ecf", expert_in,
-                    experts["w_up"].astype(compute_dtype))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up,
-                            experts["w_down"].astype(compute_dtype))
-    out = jnp.einsum("nec,ecd->nd", combine.astype(compute_dtype), expert_out)
+    with jax.named_scope("moe/dispatch"):
+        # Position of each (token, choice) in its expert's buffer.  Priority
+        # is choice-major (all first choices before any second choice) so a
+        # token's primary expert wins capacity contention.
+        choice_onehot = jax.nn.one_hot(top_idx, e,
+                                       dtype=jnp.float32)  # (N, k, E)
+        flat = choice_onehot.transpose(1, 0, 2).reshape(k * n, e)
+        pos_flat = jnp.cumsum(flat, axis=0) - flat  # (k*N, E) slot position
+        pos = pos_flat.reshape(k, n, e).transpose(1, 0, 2)  # (N, k, E)
+        pos_in_expert = jnp.sum(pos * choice_onehot, axis=-1)  # (N, k)
+        keep = pos_in_expert < cap  # capacity drop mask
+
+        # (N, k, E, C) collapsed over k -> dispatch (N, E, C)
+        cap_onehot = jax.nn.one_hot(pos_in_expert.astype(jnp.int32), cap,
+                                    dtype=jnp.float32)
+        dispatch = jnp.einsum("nke,nkc,nk->nec", choice_onehot, cap_onehot,
+                              keep.astype(jnp.float32))
+        combine = jnp.einsum("nec,nke,nk->nec", dispatch, choice_onehot,
+                             top_p)
+        expert_in = jnp.einsum("nec,nd->ecd", dispatch.astype(compute_dtype),
+                               xf)
+    with jax.named_scope("moe/experts"):
+        gate = jax.nn.silu(jnp.einsum(
+            "ecd,edf->ecf", expert_in,
+            experts["w_gate"].astype(compute_dtype)))
+        up = jnp.einsum("ecd,edf->ecf", expert_in,
+                        experts["w_up"].astype(compute_dtype))
+        expert_out = jnp.einsum("ecf,efd->ecd", gate * up,
+                                experts["w_down"].astype(compute_dtype))
+    with jax.named_scope("moe/combine"):
+        out = jnp.einsum("nec,ecd->nd", combine.astype(compute_dtype),
+                         expert_out)
 
     # Switch-style load-balancing auxiliary loss: E * sum_e f_e * p_e where
     # f_e = fraction of tokens whose TOP choice is e, p_e = mean router prob.
-    top1 = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)
-    f = jnp.mean(top1, axis=0)
-    p = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(f * p)
+    with jax.named_scope("moe/route"):
+        top1 = jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32)
+        f = jnp.mean(top1, axis=0)
+        p = jnp.mean(probs, axis=0)
+        aux = e * jnp.sum(f * p)
     return out.reshape(b, s, d), aux
 
 
@@ -177,12 +187,14 @@ def route(h, router_w, top_k: int, renormalise: bool = True):
     float32 matmul runs in bf16 passes unless told otherwise), the top_k of
     it and, ``renormalise``, their weights made to sum to one.  h: (N, D).
     Returns (weights (N, k) float32, experts (N, k) int32)."""
-    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    if renormalise:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    return top_p, top_idx.astype(jnp.int32)
+    with jax.named_scope("moe/route"):
+        logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       top_k)
+        if renormalise:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return top_p, top_idx.astype(jnp.int32)
 
 
 def row_tile(assignments: int, n_experts: int) -> int:
@@ -227,28 +239,34 @@ def dispatch(hf, weights, chosen, experts, layer):
     m = n * top_k
     tile = row_tile(m, e)
     n_tiles = min(m, -(-(m + e * (tile - 1)) // tile))
-    flat = chosen.reshape(m)  # assignment a = token * k + choice
-    order = jnp.argsort(flat, stable=True)  # by expert, then by token
-    sizes = jnp.bincount(flat, length=e)
-    padded = -(-sizes // tile) * tile
-    run_end = jnp.cumsum(padded)
-    sorted_e = flat[order]
-    rank = jnp.arange(m) - (jnp.cumsum(sizes) - sizes)[sorted_e]
-    row_sorted = (run_end - padded)[sorted_e] + rank  # its padded row
-    # padded rows with no assignment read token 0; nothing reads them back
-    src = jnp.zeros(n_tiles * tile, jnp.int32).at[row_sorted].set(
-        (order // top_k).astype(jnp.int32))
-    tile_expert = jnp.searchsorted(
-        run_end, jnp.arange(n_tiles) * tile, side="right")
-    # the tiles past the last run keep its expert: no block is fetched
-    tile_expert = jnp.minimum(tile_expert, sorted_e[-1])
-    out = grouped_mlp(hf[src], experts["w_gate"], experts["w_up"],
-                      experts["w_down"], tile_expert, run_end[-1] // tile,
-                      layer, tile=tile)
-    row = jnp.zeros(m, jnp.int32).at[order].set(row_sorted.astype(jnp.int32))
-    out = out[row].reshape(n, top_k, d).astype(jnp.float32)
-    return (jnp.einsum("nk,nkd->nd", weights, out).astype(hf.dtype),
-            jnp.sum(sizes > 0).astype(jnp.int32))
+    with jax.named_scope("moe/dispatch"):
+        flat = chosen.reshape(m)  # assignment a = token * k + choice
+        order = jnp.argsort(flat, stable=True)  # by expert, then by token
+        sizes = jnp.bincount(flat, length=e)
+        padded = -(-sizes // tile) * tile
+        run_end = jnp.cumsum(padded)
+        sorted_e = flat[order]
+        rank = jnp.arange(m) - (jnp.cumsum(sizes) - sizes)[sorted_e]
+        row_sorted = (run_end - padded)[sorted_e] + rank  # its padded row
+        # padded rows with no assignment read token 0; nothing reads them
+        # back
+        src = jnp.zeros(n_tiles * tile, jnp.int32).at[row_sorted].set(
+            (order // top_k).astype(jnp.int32))
+        tile_expert = jnp.searchsorted(
+            run_end, jnp.arange(n_tiles) * tile, side="right")
+        # the tiles past the last run keep its expert: no block is fetched
+        tile_expert = jnp.minimum(tile_expert, sorted_e[-1])
+        rows = hf[src]
+    with jax.named_scope("moe/experts"):
+        out = grouped_mlp(rows, experts["w_gate"], experts["w_up"],
+                          experts["w_down"], tile_expert,
+                          run_end[-1] // tile, layer, tile=tile)
+    with jax.named_scope("moe/combine"):
+        row = jnp.zeros(m, jnp.int32).at[order].set(
+            row_sorted.astype(jnp.int32))
+        out = out[row].reshape(n, top_k, d).astype(jnp.float32)
+        return (jnp.einsum("nk,nkd->nd", weights, out).astype(hf.dtype),
+                jnp.sum(sizes > 0).astype(jnp.int32))
 
 
 def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,
@@ -257,7 +275,8 @@ def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,
     p = layer_params
     x, _ = attention_block(cfg, p, x, positions,
                            batch_attend(attn_impl, mesh, rules))
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp/norm"):
+        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     moe_out, aux = moe_mlp(cfg, h, p["router"], p["experts"])
     return (x + moe_out, aux_sum + aux)
 
@@ -276,8 +295,9 @@ def apply(params, tokens, cfg: MoEConfig, attn_impl: str = "auto",
     def scan_body(carry, layer_params):
         return step(carry, layer_params), None
 
-    (x, aux), _ = jax.lax.scan(
-        scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(
+            scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
     logits = head(params, x, cfg)
     aux = aux / cfg.n_layers
     return (logits, aux) if return_aux else logits
@@ -289,6 +309,8 @@ def loss_fn(params, tokens, cfg: MoEConfig, attn_impl: str = "auto",
     logits, aux = apply(params, tokens[:, :-1], cfg, attn_impl, mesh=mesh,
                         rules=rules, return_aux=True)
     targets = tokens[:, 1:]
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold) + cfg.aux_loss_weight * aux
+    with jax.named_scope("loss"):
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1)[..., 0]
+        return jnp.mean(logz - gold) + cfg.aux_loss_weight * aux

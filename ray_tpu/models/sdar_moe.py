@@ -154,9 +154,10 @@ def scan_layers(cfg, params, body, carry):
         carry = body(carry, p, li, routed)
         return (carry, hit + sum(hits)), None
 
-    (carry, hit), _ = jax.lax.scan(
-        step, (carry, jnp.int32(0)),
-        (stacked, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    with jax.named_scope("layers"):
+        (carry, hit), _ = jax.lax.scan(
+            step, (carry, jnp.int32(0)),
+            (stacked, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     return carry, hit
 
 
@@ -174,11 +175,14 @@ def apply(params, tokens, cfg: SDARMoEConfig):
     rep = cfg.n_heads // cfg.n_kv_heads
 
     def attend(q, k, v, cache):  # (b, s, heads, d)
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (cfg.head_dim ** 0.5)
-        attn = jax.nn.softmax(jnp.where(mask, scores, -1e30).astype(
-            jnp.float32), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", attn.astype(v.dtype), v), cache
+        with jax.named_scope("attn/attend"):
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+            scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                      / (cfg.head_dim ** 0.5))
+            attn = jax.nn.softmax(jnp.where(mask, scores, -1e30).astype(
+                jnp.float32), axis=-1)
+            return (jnp.einsum("bhqk,bkhd->bqhd", attn.astype(v.dtype), v),
+                    cache)
 
     def body(x, p, li, ffn):
         return layer(cfg, p, x, positions[None, :], attend, None, ffn)[0]

@@ -58,8 +58,10 @@ def repeat_kv_heads(k, v, num_heads):
     kv_heads = k.shape[2]
     if kv_heads != num_heads:
         reps = num_heads // kv_heads
-        k = jnp.repeat(k, reps, axis=2)
-        v = jnp.repeat(v, reps, axis=2)
+        # inside the caller's ``attn/attend`` (models/llama.py PARTS)
+        with jax.named_scope("repeat_kv"):
+            k = jnp.repeat(k, reps, axis=2)
+            v = jnp.repeat(v, reps, axis=2)
     return k, v
 
 

@@ -107,10 +107,11 @@ def make_train_step(
     def step_fn(state, batch):
         batch = jax.lax.with_sharding_constraint(batch, batch_sharding)
         loss_val, grads = jax.value_and_grad(loss)(state["params"], batch)
-        updates, new_opt_state = optimizer.update(
-            grads, state["opt_state"], state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optim"):  # models/llama.py PARTS
+            updates, new_opt_state = optimizer.update(
+                grads, state["opt_state"], state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            grad_norm = optax.global_norm(grads)
         new_state = {
             "params": new_params,
             "opt_state": new_opt_state,

@@ -1,0 +1,263 @@
+"""The programs name their parts (``models/llama.py`` ``PARTS``).
+
+At tiny sizes on the CPU, each program's COMPILED text is parsed for
+``op_name``: every product (``dot_general``) and every kernel lies under
+exactly one part, every part the program is made of occurs in it, a
+gradient under ``remat`` recomputes under ``attn/*`` and ``mlp/*`` and one
+without recomputes nothing, and a program's outputs equal, bit for bit,
+those of the same function traced with ``jax.named_scope`` a no-op.  The
+paths are split by the benchmark's own reader
+(``benchmarks/trace/device_parts.py``), so what is checked here is what a
+traced run on the chip is read with.
+"""
+
+import contextlib
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+from benchmarks.trace.device_parts import part_runs, split_path
+from ray_tpu.llm import model as lm
+from ray_tpu.models import llama, moe, sdar_moe
+from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+from ray_tpu.train.step import (create_train_state, default_optimizer,
+                                make_train_step)
+
+PARTS = frozenset(llama.PARTS)
+FLASH = ("flash_attention_fwd", "flash_attention_bwd_dkv",
+         "flash_attention_bwd_dq")
+KERNELS = {**dict.fromkeys(FLASH, "attn/attend"),
+           "paged_decode_attention": "attn/attend",
+           "moe_grouped_mlp": "moe/experts"}
+BLOCK = ("embed", "layers", "attn/norm", "attn/qkv", "attn/rope",
+         "attn/attend", "attn/out", "mlp/norm")
+DENSE = BLOCK + ("mlp/gate_up", "mlp/down")
+ROUTED = BLOCK + ("moe/route", "moe/dispatch", "moe/experts", "moe/combine")
+PS, PAGES = 8, 16  # page size, pages in the pool
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """Metadata is not in the persistent cache's key: an entry written by
+    a tree without scopes would hand back an executable without them."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _pools(cfg):
+    shape = (cfg.n_layers, PAGES, PS, cfg.n_kv_heads, cfg.head_dim)
+    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+
+def _dense():
+    cfg = llama.LlamaConfig.tiny()
+    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+
+
+def _prefill():
+    cfg, params = _dense()
+    L = 16
+    ck, cv = _pools(cfg)
+    pos = np.arange(L)
+    return lm.prefill, (params, jnp.arange(L, dtype=jnp.int32) % 7, ck, cv,
+                        jnp.asarray(pos // PS + 1, jnp.int32), jnp.int32(13),
+                        jnp.asarray(pos % PS, jnp.int32)), cfg
+
+
+def _prefill_with_prefix():
+    cfg, params = _dense()
+    L, P = 16, 4
+    ck, cv = _pools(cfg)
+    pos = np.arange(L) + PS  # one resident page before the suffix
+    return lm.prefill_with_prefix, (
+        params, jnp.arange(L, dtype=jnp.int32) % 7, ck, cv,
+        jnp.asarray(pos // PS + 1, jnp.int32), jnp.int32(13),
+        jnp.asarray(pos % PS, jnp.int32), jnp.arange(1, P + 1, dtype=jnp.int32),
+        jnp.asarray(pos, jnp.int32)), cfg
+
+
+def _decode():
+    cfg, params = _dense()
+    B, P = 4, 4
+    ck, cv = _pools(cfg)
+    tables = jnp.asarray(np.arange(1, 1 + B * 2).reshape(B, 2).repeat(2, 1),
+                         jnp.int32)[:, :P]
+    return lm.decode_step_greedy, (
+        params, jnp.arange(B, dtype=jnp.int32), ck, cv, tables,
+        jnp.full((B,), 5, jnp.int32), jnp.ones((B,), bool)), cfg
+
+
+def _block_step():
+    cfg = sdar_moe.SDARMoEConfig.tiny()
+    params = sdar_moe.init(cfg, jax.random.PRNGKey(0))
+    S, P, B = 4, 4, cfg.block_length
+    ck, cv = _pools(cfg)
+    tables = jnp.asarray(np.arange(1, 1 + S * 2).reshape(S, 2).repeat(2, 1),
+                         jnp.int32)[:, :P]
+    return lm.block_step, (
+        params, ck, cv, tables, jnp.ones((S,), bool),
+        jnp.full((S, B), 7, jnp.int32), jnp.ones((S, B), bool),
+        jnp.full((S,), 8, jnp.int32), jnp.zeros((S,), jnp.int32)), cfg
+
+
+def _grad(model, cfg, **kw):
+    """(function of (params, tokens), its arguments)."""
+    params = model.init(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) % 11
+
+    def grad(params, tokens):
+        return jax.grad(lambda p: model.loss_fn(p, tokens, cfg, **kw))(params)
+    return grad, (params, tokens)
+
+
+def _llama_grad(remat):
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), remat=remat,
+                              loss_chunk=16)
+    return _grad(llama, cfg, attn_impl="pallas")
+
+
+def _moe_grad():
+    return _grad(moe, moe.MoEConfig.tiny())
+
+
+def _train_step():
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), remat=True,
+                              loss_chunk=16)
+    mesh = create_mesh(MeshConfig(fsdp=1), devices=jax.devices()[:1])
+    opt = default_optimizer()
+    with mesh:
+        state = create_train_state(llama, cfg, mesh, opt,
+                                   jax.random.PRNGKey(0))
+        step = make_train_step(llama, cfg, mesh, opt, attn_impl="pallas",
+                               donate=False)
+    return step, (state, jnp.zeros((2, 33), jnp.int32)), mesh
+
+
+def _compiled_text(name):
+    """The compiled text of one of the programs below."""
+    if name in ENGINE:
+        fn, args, cfg = ENGINE[name]()
+        return fn.lower(*args, cfg=cfg).compile().as_text()
+    if name == "train_step":
+        step, args, mesh = _train_step()
+        with mesh:
+            return step.lower(*args).compile().as_text()
+    fn, args = GRADS[name]()
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
+          "decode_step_greedy": _decode, "block_step": _block_step}
+GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
+         "llama_grad": lambda: _llama_grad(False),
+         "moe_grad": _moe_grad}
+# the parts each program is made of
+EXPECTED = {
+    "prefill": DENSE + ("attn/kv_write", "attn/attend/repeat_kv", "head"),
+    "prefill_with_prefix": DENSE + ("attn/kv_write", "attn/attend/repeat_kv",
+                                    "head"),
+    "decode_step_greedy": DENSE + ("attn/kv_write", "head", "sample"),
+    "block_step": ROUTED + ("attn/kv_write", "head", "sample"),
+    "llama_grad_remat": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
+    "llama_grad": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
+    "moe_grad": ROUTED + ("attn/attend/repeat_kv", "head", "loss"),
+    "train_step": DENSE + ("attn/attend/repeat_kv", "head", "loss", "optim"),
+}
+_TEXTS = {}
+
+
+def _op_names(name):
+    if name not in _TEXTS:
+        # whole paths only: the body of a reduction or a scatter carries
+        # the tail of its caller's
+        _TEXTS[name] = sorted(n for n in set(re.findall(
+            r'op_name="([^"]*)"', _compiled_text(name)))
+            if n.startswith("jit("))
+    return _TEXTS[name]
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_products_and_kernels_lie_under_exactly_one_part(name):
+    names = _op_names(name)
+    products = [n for n in names if n.rstrip(":").endswith("dot_general")]
+    assert products, "no product in the compiled text"
+    for n in products:
+        runs = [r for r in part_runs(n, PARTS) if r != "layers"]
+        assert len(runs) == 1, f"{n}: parts {runs}"
+    seen = set()
+    for n in names:
+        for kernel, part in KERNELS.items():
+            if kernel in re.split(r"[/()]", n):
+                seen.add(kernel)
+                assert split_path(n, PARTS)[0] == part, n
+    wanted = {"decode_step_greedy": {"paged_decode_attention"},
+              "block_step": {"paged_decode_attention", "moe_grouped_mlp"},
+              "llama_grad": set(FLASH),
+              "train_step": set(FLASH)}.get(name, set())
+    assert wanted <= seen
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_every_part_of_a_program_occurs_in_it(name):
+    found = {split_path(n, PARTS)[0] for n in _op_names(name)}
+    assert set(EXPECTED[name]) <= found, set(EXPECTED[name]) - found
+    # and nothing the table does not give it (None: outside every part)
+    assert found - {None} <= set(EXPECTED[name]), found - set(EXPECTED[name])
+
+
+def test_every_part_is_some_programs():
+    assert set().union(*EXPECTED.values()) == PARTS
+
+
+@pytest.mark.parametrize("name, recomputes", [
+    ("llama_grad_remat", True), ("llama_grad", False), ("train_step", True)])
+def test_remat_recomputes_under_the_layers_parts(name, recomputes):
+    phases = {}
+    for n in _op_names(name):
+        part, phase = split_path(n, PARTS)
+        phases.setdefault(phase, set()).add(part)
+    assert {"fwd", "bwd"} <= set(phases)
+    again = phases.get("recompute", set())
+    if not recomputes:
+        # the chunked loss checkpoints its own chunk: that is not the
+        # layers' remat
+        assert again <= {"loss"}, again
+        return
+    assert {"attn/qkv", "attn/attend", "mlp/gate_up"} <= again, again
+    assert "optim" not in again and "embed" not in again
+
+
+@pytest.mark.parametrize("name", sorted(ENGINE) + ["llama_grad_remat"])
+def test_outputs_are_bit_for_bit_those_without_scopes(name, monkeypatch):
+    def build():  # a new function each time: a trace is cached by it
+        if name in ENGINE:
+            fn, args, cfg = ENGINE[name]()
+            return (lambda *a: fn.__wrapped__(*a, cfg=cfg)), args
+        return GRADS[name]()
+
+    plain, args = build()
+    with_scopes = jax.jit(plain).lower(*args).compile()
+    assert _scoped(with_scopes)
+    got = with_scopes(*args)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        plain, args = build()
+        bare = jax.jit(plain).lower(*args).compile()
+        assert not _scoped(bare)
+        want = bare(*args)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and np.array_equal(np.asarray(a),
+                                                     np.asarray(b))
+
+
+def _scoped(compiled) -> bool:
+    return any(part_runs(n, PARTS) for n in re.findall(
+        r'op_name="(jit\([^"]*)"', compiled.as_text()))
