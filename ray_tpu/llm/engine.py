@@ -37,6 +37,7 @@ from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
                                      init_cache)
+from ray_tpu.models.llama import serving_layout
 from ray_tpu.util import tracing
 
 # Serving observability (ISSUE 8): the engine-local stats() dict stays the
@@ -409,13 +410,18 @@ class LLMEngine:
     configuration has a ``block_length``, ``decode_step`` /
     ``decode_step_greedy`` or ``block_step`` (which also reads the
     sampler's settings off it).
+
+    The engine holds the parameters in the SERVING layout
+    (``models.llama.serving_layout``: a layer's ``wq``, ``wk``, ``wv`` as one
+    stacked ``wqkv``), made once here from whichever tree it is handed, and
+    keeps no reference to the three: they are freed when the caller lets go.
     """
 
     def __init__(self, params, model_cfg, cfg: Optional[EngineConfig] = None,
                  kv_tier=None):
         self.cfg = cfg or EngineConfig()
         self.model_cfg = model_cfg
-        self.params = params
+        self.params = serving_layout(params)
         # block: positions a block (0: a token at a time)
         self._block = int(getattr(model_cfg, "block_length", 0))
         if self._block and (self.cfg.page_size % self._block

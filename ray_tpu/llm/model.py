@@ -40,7 +40,15 @@ block-diffusion configuration also ``block_length``, ``mask_token_id``,
 (the sampler) and, through ``sdar_moe.scan_layers``, ``experts_per_token``
 and ``norm_topk_prob``.  Which feed-forward and which head norms a layer
 has is read off the parameters (``"experts" in params["layers"]``,
-``"q_norm" in p["attn"]``), not off a type.
+``"q_norm" in p["attn"]``), not off a type; so is how it projects q, k and
+v.  ``LLMEngine`` hands these programs the SERVING layout
+(``models.llama.serving_layout``): a layer's ``wq``, ``wk`` and ``wv`` as
+one stacked ``wqkv``, of which ``qkv_rope`` makes ONE product that XLA
+reads out of the stacked parameter inside the product's own fusion, as it
+reads the MLP's.  A tree with the three weights (training's, a test's)
+runs through every program too: XLA then slices each weight of a layer
+into fast memory and transposes it there before its product, 9 % of a
+decode step at the serving cells' shapes (PERF.md section 6, PR 37).
 
 Each ``attend`` first writes its new K/V rows into the layer's pages.
 Every program carries both pools through its layer scan whole, with the

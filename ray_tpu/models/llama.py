@@ -152,7 +152,9 @@ def init(cfg: LlamaConfig, key: jax.Array):
 # of any program reads ``.../attn/qkv/dot_general`` where it read
 # ``fusion.121``.  ``layers`` is the layer scan itself: what lies under it
 # and under no deeper part is the scan's own work, the slices of the stacked
-# weights (and the transposes XLA makes of them) and the loop's counter.
+# norm weights, the loop's counter and, for a tree with ``wq``, ``wk``,
+# ``wv``, their slices into fast memory and the transposes XLA makes of
+# them (``serving_layout`` below is the cure where it cost most).
 # Whoever reads a profile by part (benchmarks/trace/device_parts.py) takes
 # the names from here, the innermost that a path holds; none is a name a
 # JAX primitive or transform uses.
@@ -193,16 +195,46 @@ def rope(x, positions, theta):
         return out.astype(x.dtype)
 
 
+def serving_layout(params):
+    """The parameter tree as the served programs (llm/model.py) hold it:
+    every layer's ``wq``, ``wk`` and ``wv`` side by side in ONE stacked
+    ``wqkv`` [n_layers, d_model, (n_heads + 2 n_kv_heads) head_dim], the
+    three dropped, every other leaf as it was.  ``qkv_rope`` then makes one
+    product a layer, and XLA reads the layer's weight out of the stacked
+    parameter inside that product's fusion, as it reads the MLP's; the three
+    products' reshape to heads it folds into a convolution that wants each
+    weight sliced out and transposed first (PERF.md section 6, PR 37).  A
+    tree that already has ``wqkv`` comes back as it is.  Training keeps the
+    three: their logical specs shard ``heads`` and ``kv_heads`` apart."""
+    attn = params["layers"]["attn"]
+    if "wqkv" in attn:
+        return params
+    attn = dict(attn)
+    attn["wqkv"] = jnp.concatenate(
+        [attn.pop("wq"), attn.pop("wk"), attn.pop("wv")], axis=-1)
+    return {**params, "layers": {**params["layers"], "attn": attn}}
+
+
 def qkv_rope(cfg, p, h, positions):
     """The normed stream h (..., d_model) projected and split into heads,
-    q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width."""
+    q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width.
+    The layer's parameters say how: three weights (training), or the one
+    ``wqkv`` of ``serving_layout``, whose product is split after."""
     def heads(w, n):
         return (h @ w.astype(h.dtype)).reshape(*h.shape[:-1], n, cfg.head_dim)
 
     with jax.named_scope("attn/qkv"):
-        q = heads(p["attn"]["wq"], cfg.n_heads)
-        k = heads(p["attn"]["wk"], cfg.n_kv_heads)
-        v = heads(p["attn"]["wv"], cfg.n_kv_heads)
+        if "wqkv" in p["attn"]:  # serving_layout: one product, then split
+            nq = cfg.n_heads * cfg.head_dim
+            nkv = cfg.n_kv_heads * cfg.head_dim
+            q, k, v = (y.reshape(*h.shape[:-1], -1, cfg.head_dim)
+                       for y in jnp.split(
+                           h @ p["attn"]["wqkv"].astype(h.dtype),
+                           (nq, nq + nkv), axis=-1))
+        else:
+            q = heads(p["attn"]["wq"], cfg.n_heads)
+            k = heads(p["attn"]["wk"], cfg.n_kv_heads)
+            v = heads(p["attn"]["wv"], cfg.n_kv_heads)
         if "q_norm" in p["attn"]:  # Qwen3 family: RMS norm a head, pre-rope
             q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
             k = rms_norm(k, p["attn"]["k_norm"], cfg.norm_eps)

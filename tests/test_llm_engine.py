@@ -272,3 +272,152 @@ def test_prefill_writes_its_rows_and_nothing_else(program):
         np.testing.assert_allclose(got[:, rows[real], slots[real]],
                                    ref[:, written[real]], atol=1e-5,
                                    rtol=1e-5)
+
+
+# -- the serving layout: one stacked wqkv a layer ----------------------------
+
+def _family(name):
+    """(float32 configuration, three-weight parameters) of a family."""
+    import dataclasses
+
+    from ray_tpu.models import sdar_moe
+
+    if name == "llama":
+        cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
+                                  dtype="float32")
+        return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+    cfg = sdar_moe.SDARMoEConfig.tiny(512, remasking_strategy="sequential")
+    return cfg, sdar_moe.init(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("family", ["llama", "sdar_moe"])
+def test_serving_layout_stacks_qkv_and_touches_nothing_else(family):
+    cfg, params = _family(family)
+    served = llama.serving_layout(params)
+    attn = served["layers"]["attn"]
+    assert not {"wq", "wk", "wv"} & set(attn)
+    nq, nkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    assert attn["wqkv"].shape == (cfg.n_layers, cfg.d_model, nq + 2 * nkv)
+    was = params["layers"]["attn"]
+    for name, cols in (("wq", slice(0, nq)), ("wk", slice(nq, nq + nkv)),
+                       ("wv", slice(nq + nkv, None))):
+        np.testing.assert_array_equal(np.asarray(attn["wqkv"][..., cols]),
+                                      np.asarray(was[name]))
+    # every other leaf is the array it was, not a copy of it
+    def others(tree):
+        return {**tree, "layers": {**tree["layers"], "attn": {
+            k: v for k, v in tree["layers"]["attn"].items()
+            if k not in ("wq", "wk", "wv", "wqkv")}}}
+
+    rest, want = others(served), others(params)
+    assert jax.tree.structure(rest) == jax.tree.structure(want)
+    assert all(a is b for a, b in zip(jax.tree.leaves(rest),
+                                      jax.tree.leaves(want)))
+    assert set(was) >= {"wq", "wk", "wv"}  # the caller's tree is not edited
+    assert llama.serving_layout(served) is served
+
+
+@pytest.mark.parametrize("family,program", [
+    ("llama", "prefill"), ("llama", "prefill_with_prefix"),
+    ("llama", "decode_step"), ("llama", "decode_step_greedy"),
+    ("sdar_moe", "prefill"), ("sdar_moe", "prefill_with_prefix"),
+    ("sdar_moe", "block_step")])
+def test_programs_on_the_serving_layout_equal_the_three_weight_tree(
+        family, program):
+    """Every served program over scattered pages, once with ``wq``, ``wk``,
+    ``wv`` and once with the stacked ``wqkv``: the same logits (tokens,
+    records) and the same K/V rows in the pools, in float32 to 1e-6."""
+    from ray_tpu.llm import model as lm
+    from ray_tpu.llm.paged_cache import CacheConfig, init_cache
+
+    cfg, params = _family(family)
+    ps, n = 8, 20  # five whole blocks of 4
+    table = np.asarray([3, 5, 2, 0], np.int32)
+    prompt = np.random.default_rng(2).integers(1, 100, size=n).astype(
+        np.int32)
+
+    def run(params):
+        cache = init_cache(CacheConfig(
+            n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.head_dim, num_pages=8, page_size=ps,
+            dtype=cfg.dtype))
+
+        def prefill(start, stop, bucket, through_table):
+            tokens = np.zeros(bucket, np.int32)
+            tokens[:stop - start] = prompt[start:stop]
+            pos = start + np.arange(bucket, dtype=np.int32)
+            rest = ((jnp.asarray(table), jnp.asarray(pos))
+                    if through_table else ())
+            return getattr(
+                lm, "prefill_with_prefix" if through_table else "prefill")(
+                params, jnp.asarray(tokens), *cache,
+                jnp.asarray(table[pos // ps]), jnp.int32(stop - start),
+                jnp.asarray(pos % ps), *rest, cfg)
+
+        if program == "prefill":
+            return prefill(0, n, 32, False)
+        _, *cache = prefill(0, 2 * ps, 16, False)
+        if program == "prefill_with_prefix":
+            return prefill(2 * ps, n, 16, True)
+        tables = jnp.asarray(np.stack([np.zeros_like(table), table]))
+        active = jnp.asarray([False, True])
+        if program == "block_step":  # the block at 16..19, two masks left
+            B = cfg.block_length
+            tokens = np.full((2, B), cfg.mask_token_id, np.int32)
+            tokens[1, :2] = prompt[16:18]
+            masked = np.ones((2, B), bool)
+            masked[1, :2] = False
+            return lm.block_step(
+                params, *cache, tables, active, jnp.asarray(tokens),
+                jnp.asarray(masked), jnp.asarray([0, 16], jnp.int32),
+                jnp.zeros(2, jnp.int32), cfg)
+        return getattr(lm, program)(
+            params, jnp.asarray([0, prompt[16]], jnp.int32), *cache, tables,
+            jnp.asarray([0, 16], jnp.int32), active, cfg)
+
+    want, got = run(params), run(llama.serving_layout(params))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        if jnp.issubdtype(g.dtype, jnp.floating):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-6, rtol=1e-6)
+        else:  # greedy tokens, a pass's record and state, experts read
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("handed", ["three_weights", "serving_layout"])
+def test_engine_holds_the_serving_layout(tiny_model, handed):
+    """Whichever tree it is handed, the engine keeps the stacked one and no
+    ``wq``, ``wk``, ``wv``, and generates the full forward's tokens."""
+    params, cfg = tiny_model
+    tree = params if handed == "three_weights" else llama.serving_layout(
+        params)
+    engine = make_engine((tree, cfg))
+    attn = engine.params["layers"]["attn"]
+    assert "wqkv" in attn and not {"wq", "wk", "wv"} & set(attn)
+    if handed == "serving_layout":
+        assert engine.params is tree
+    prompt = [9, 2, 77, 31, 5, 64, 12, 3, 101]
+    got = engine.generate(prompt, SamplingParams(max_tokens=10))
+    engine.stop()
+    assert got == reference_greedy(params, cfg, prompt, 10)
+
+
+def test_engine_lets_go_of_the_three_weights(tiny_model):
+    """Once the caller drops its tree, ``wq``, ``wk`` and ``wv`` are freed:
+    the engine's resident weights are the tree's own size, not that plus a
+    second copy of the projections."""
+    import gc
+    import weakref
+
+    _, cfg = tiny_model
+    params = llama.init(cfg, jax.random.PRNGKey(1))
+    attn = params["layers"]["attn"]
+    gone = [weakref.ref(attn[k]) for k in ("wq", "wk", "wv")]
+    kept = weakref.ref(attn["wo"])
+    engine = make_engine((params, cfg))
+    del params, attn
+    gc.collect()
+    assert all(r() is None for r in gone) and kept() is not None
+    assert kept() is engine.params["layers"]["attn"]["wo"]

@@ -58,8 +58,10 @@ def _pools(cfg):
 
 
 def _dense():
+    """The engine's programs run over the serving layout (one ``wqkv``);
+    the gradients below keep training's three weights."""
     cfg = llama.LlamaConfig.tiny()
-    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+    return cfg, llama.serving_layout(llama.init(cfg, jax.random.PRNGKey(0)))
 
 
 def _prefill():
@@ -97,7 +99,7 @@ def _decode():
 
 def _block_step():
     cfg = sdar_moe.SDARMoEConfig.tiny()
-    params = sdar_moe.init(cfg, jax.random.PRNGKey(0))
+    params = llama.serving_layout(sdar_moe.init(cfg, jax.random.PRNGKey(0)))
     S, P, B = 4, 4, cfg.block_length
     ck, cv = _pools(cfg)
     tables = jnp.asarray(np.arange(1, 1 + S * 2).reshape(S, 2).repeat(2, 1),

@@ -422,16 +422,20 @@ def test_evict_n_is_n_reference_calls(shape, seed):
 
 
 def _engine(num_pages, max_slots=32):
-    from ray_tpu.llm.engine import EngineConfig, LLMEngine
-    from ray_tpu.models.llama import LlamaConfig
+    import jax
 
-    model = LlamaConfig(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
-                        n_kv_heads=1, d_ff=32, max_seq_len=512,
-                        dtype="float32", remat=False)
-    # no weights: nothing here prefills or decodes
-    return LLMEngine(None, model, EngineConfig(
-        max_slots=max_slots, num_pages=num_pages, page_size=4,
-        max_seq_len=512, prefill_buckets=(512,)))
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models import llama
+
+    model = llama.LlamaConfig(vocab_size=64, d_model=16, n_layers=1,
+                              n_heads=2, n_kv_heads=1, d_ff=32,
+                              max_seq_len=512, dtype="float32", remat=False)
+    # nothing here prefills or decodes; the engine lays its weights out for
+    # serving as it is built, so it is handed some
+    return LLMEngine(
+        llama.init(model, jax.random.PRNGKey(0)), model,
+        EngineConfig(max_slots=max_slots, num_pages=num_pages, page_size=4,
+                     max_seq_len=512, prefill_buckets=(512,)))
 
 
 def _fill_pool(engine, rng):

@@ -103,10 +103,23 @@ def _smoke_llama(n_layers):
                                n_layers=n_layers, remat=False)
 
 
-def _serving_shapes(cfg, num_pages=1024, page_size=16):
-    params = jax.eval_shape(
-        lambda k: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
-                               llama.init(cfg, k)), jax.random.PRNGKey(0))
+LAYOUTS = ["serving", "three_weights"]
+
+
+def _bf16_params(model, cfg, layout="serving"):
+    """Shapes of the family's bf16 parameters as the engine holds them
+    (``llama.serving_layout``: one stacked ``wqkv`` a layer) or as
+    ``init`` makes them and training keeps them."""
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: model.init(cfg, k), jax.random.PRNGKey(0)))
+    if layout == "serving":
+        return jax.eval_shape(llama.serving_layout, shapes)
+    return shapes
+
+
+def _serving_shapes(cfg, num_pages=1024, page_size=16, layout="serving"):
+    params = _bf16_params(llama, cfg, layout)
     cache = jax.ShapeDtypeStruct(
         (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim),
         jnp.bfloat16)
@@ -119,8 +132,10 @@ def _footprint(compiled):
             + m.output_size_in_bytes - m.alias_size_in_bytes)
 
 
-def _decode_program(cfg, one, slots, pages_per_seq, num_pages=1024):
-    params, cache = _on(one, _serving_shapes(cfg, num_pages=num_pages))
+def _decode_program(cfg, one, slots, pages_per_seq, num_pages=1024,
+                    layout="serving"):
+    params, cache = _on(one, _serving_shapes(cfg, num_pages=num_pages,
+                                             layout=layout))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
     return lm.decode_step_greedy.lower(
         params, i32(slots), cache, cache, i32(slots, pages_per_seq),
@@ -172,8 +187,48 @@ def _assert_holds_the_pool_once(compiled, n_layers):
     assert not [r for r in results if r.startswith("bf16[1,3072,16,8,128]")]
 
 
+def _materialised(compiled, shapes):
+    """Operations outside a product's fusion whose result has one of
+    ``shapes``: a fusion that only slices (its result IS the slice, into
+    fast memory) and a ``copy`` of one (the transpose).  A ``dynamic-slice``
+    INSIDE the fusion of the product that reads it is the weight read where
+    it lies, and is not counted."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        head, _, result = line.strip().partition(" = ")
+        if result.startswith(shapes) and (
+                " copy(" in result or " fusion(" in result
+                or head.startswith("ROOT")):
+            out.append(line.strip()[:160])
+    return out
+
+
+# one layer of a projection weight (wq, wk / wv, the stacked wqkv):
+# Mistral-7B, SDAR-30B-A3B
+MISTRAL_QKV = ("bf16[1,4096,4096]", "bf16[1,4096,1024]", "bf16[1,4096,6144]")
+SDAR_QKV = ("bf16[1,2048,4096]", "bf16[1,2048,512]", "bf16[1,2048,5120]")
+
+
+def _assert_projects_by_layout(compiled, layout, shapes):
+    """On the serving layout q, k and v come of ONE product that reads the
+    layer's ``wqkv`` out of the stacked parameter inside its own fusion:
+    no one-layer slice of a projection weight is an operation's result,
+    neither the slice into fast memory nor XLA's transpose of it.  The
+    three-weight tree (training's path through ``qkv_rope``) compiles, and
+    shows what the layout is for: ``(h @ w).reshape(heads)`` becomes a
+    convolution that wants each weight sliced out and transposed first
+    (should that assertion fail, the compiler has learnt to read the three
+    in place and the layout has lost its reason)."""
+    found = _materialised(compiled, shapes)
+    if layout == "serving":
+        assert not found, found
+    else:
+        assert any(" copy(" in f for f in found)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("n_layers", [16, 20])
-def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers):
+def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers, layout):
     """The benchmark's own decode program (Mistral-7B widths, 32 slots of
     128 pages over a pool of 3,072): both donated pools are aliased to the
     outputs and nothing pool-sized is planned beside them.  Scanned over,
@@ -181,16 +236,18 @@ def test_decode_step_holds_the_page_pool_once(topo, as_tpu, n_layers):
     the program (17.06 GiB of 15.75)."""
     one = SingleDeviceSharding(topo.devices[0])
     decode = _decode_program(_cell_llama(n_layers), one, slots=32,
-                             pages_per_seq=128, num_pages=3072)
+                             pages_per_seq=128, num_pages=3072, layout=layout)
     _assert_holds_the_pool_once(decode, n_layers)
+    _assert_projects_by_layout(decode, layout, MISTRAL_QKV)
     assert "paged_decode_attention" in decode.as_text()
 
 
+@pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("bucket", [256, 2048])
 @pytest.mark.parametrize("n_layers", [16, 20])
 @pytest.mark.parametrize("program", ["prefill", "prefill_with_prefix"])
 def test_prefill_holds_the_page_pool_once(topo, as_tpu, program, n_layers,
-                                          bucket):
+                                          bucket, layout):
     """Both prefill programs at the serving cells' shapes: the pools ride in
     the layer scan's carry, so a call writes its rows in place.  Scanned
     over, every call copied both pools on entry and sliced each layer's
@@ -199,7 +256,8 @@ def test_prefill_holds_the_page_pool_once(topo, as_tpu, program, n_layers,
     2,048 bucket (16.40 G of 15.75)."""
     one = SingleDeviceSharding(topo.devices[0])
     cfg = _cell_llama(n_layers)
-    params, cache = _on(one, _serving_shapes(cfg, num_pages=3072))
+    params, cache = _on(one, _serving_shapes(cfg, num_pages=3072,
+                                             layout=layout))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
     # the suffix program also takes a page table of 128 and the positions
     table = () if program == "prefill" else (i32(128), i32(bucket))
@@ -207,6 +265,7 @@ def test_prefill_holds_the_page_pool_once(topo, as_tpu, program, n_layers,
         params, i32(bucket), cache, cache, i32(bucket), i32(), i32(bucket),
         *table, cfg).compile()
     _assert_holds_the_pool_once(compiled, n_layers)
+    _assert_projects_by_layout(compiled, layout, MISTRAL_QKV)
 
 
 def _sdar_cell():
@@ -225,9 +284,10 @@ SDAR_PLANNED_GB = {"block_step": 11.830, 64: 11.130, 128: 11.130,
                    256: 11.130, 512: 11.133}
 
 
+@pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("program", ["block_step", 64, 128, 256, 512])
 def test_block_diffusion_programs_compile_at_sdar_widths(topo, as_tpu,
-                                                         program):
+                                                         program, layout):
     """``block_step`` (32 slots of 4 rows through 64-page tables) and the
     four prefill buckets of configuration ``sdar30b_a3b_serve_1chip``,
     over its pool of 2,048 pages: each plans under 0.9 of the chip's bytes_limit beside
@@ -238,9 +298,7 @@ def test_block_diffusion_programs_compile_at_sdar_widths(topo, as_tpu,
     one = SingleDeviceSharding(topo.devices[0])
     cfg = _sdar_cell()
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
-    params = jax.tree.map(
-        lambda x: sds(x.shape, jnp.bfloat16),
-        jax.eval_shape(lambda k: sdar_moe.init(cfg, k), jax.random.PRNGKey(0)))
+    params = _on(one, _bf16_params(sdar_moe, cfg, layout))
     cache = sds((cfg.n_layers, 2048, 16, cfg.n_kv_heads, cfg.head_dim),
                 jnp.bfloat16)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -255,6 +313,7 @@ def test_block_diffusion_programs_compile_at_sdar_widths(topo, as_tpu,
             params, i32(program), cache, cache, i32(program), i32(),
             i32(program), cfg).compile()
     text = compiled.as_text()
+    _assert_projects_by_layout(compiled, layout, SDAR_QKV)
     assert "moe_grouped_mlp" in text
     results = [line.split(" = ")[1] for line in text.splitlines()
                if " = " in line]
