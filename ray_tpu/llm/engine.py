@@ -36,8 +36,8 @@ from ray_tpu.llm import model as lm
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
-                                     init_cache)
-from ray_tpu.models.llama import serving_layout
+                                     init_cache, init_state)
+from ray_tpu.ops.gated_delta import CHUNK as SCAN_CHUNK
 from ray_tpu.util import tracing
 
 # Serving observability (ISSUE 8): the engine-local stats() dict stays the
@@ -89,6 +89,20 @@ def _engine_metrics():
                 "blocks_final": Counter(
                     "llm_blocks_final_total", "Blocks whose K/V a pass "
                     "over their mask-free tokens made final"),
+                "state_slot_steps": Counter(
+                    "llm_state_slot_steps_total", "Recurrent state: live "
+                    "slots whose rows a decode step updated, summed over "
+                    "the steps (x the bytes of a slot's rows = what the "
+                    "update has to move)"),
+                "state_resets": Counter(
+                    "llm_state_resets_total", "Recurrent state: "
+                    "admissions whose prefill began a slot's rows anew "
+                    "from a zero state"),
+                "scan_chunks": Counter(
+                    "llm_scan_chunks_total", "Recurrent state: chunks the "
+                    "prefills' chunked recurrence went through (a "
+                    "bucket's, its padding included: the program scans "
+                    "them all), summed over the recurrent layers"),
                 "decode_pages_read": Counter(
                     "llm_decode_pages_read_total", "KV pages the decode "
                     "kernel walked: per step and active slot, the pages "
@@ -209,6 +223,9 @@ _PHASE_ATTRS = {
     P_PREFILL_EMIT: (), P_DECODE_HOST: ("active_slots", "burst"),
     P_DECODE_DISPATCH: ("burst",), P_DECODE_FETCH: ("burst",),
     # a block-diffusion burst adds what its passes did
+    # ... and a model with recurrent layers names its own (a dict): also
+    # ``steps`` and ``state_slots``, the live slots whose state rows the
+    # burst's steps updated, summed over them
     P_DECODE_EMIT: ("tokens", "slots_released", "slot_passes",
                     "masks_filled", "blocks_final", "experts_read",
                     "passes"),
@@ -403,25 +420,37 @@ class _Slot:
 class LLMEngine:
     """Single-process engine; wrap in an actor for serving (server.py).
 
-    Of ``model_cfg`` the engine reads ``n_layers``, ``n_kv_heads``,
-    ``head_dim`` and ``dtype`` (the pools' shape; ``_tier_expect``) and hands
-    it, with ``params``, to the programs of llm/model.py it calls:
-    ``prefill``, ``prefill_with_prefix``, ``copy_page`` and, by whether the
+    The engine asks llm/model.py what a model of ``model_cfg`` caches
+    (``lm.cache_layout``) and allocates that: page pools for the layers
+    that attend and, for a model with recurrent layers, state rows a slot
+    beside them.  Of ``model_cfg`` itself it reads ``dtype`` and hands it,
+    with ``params``, to the programs of llm/model.py it calls: ``prefill``,
+    ``prefill_with_prefix``, ``copy_page`` and, by whether the
     configuration has a ``block_length``, ``decode_step`` /
     ``decode_step_greedy`` or ``block_step`` (which also reads the
     sampler's settings off it).
 
+    A slot's state rows are begun anew by the prefill that admits a
+    sequence to it (from a zero state, whatever the last tenant left) and
+    mean nothing once it is released.  Pages say nothing of the state at a
+    prefix's end, so for such a model what would need that is refused by
+    name or not built: no ``PrefixCache`` (a preempted sequence's resume
+    prefill recomputes from position 0), no P/D (``prefill_extract``,
+    ``submit_with_kv``), no KV tier.
+
     The engine holds the parameters in the SERVING layout
-    (``models.llama.serving_layout``: a layer's ``wq``, ``wk``, ``wv`` as one
-    stacked ``wqkv``), made once here from whichever tree it is handed, and
-    keeps no reference to the three: they are freed when the caller lets go.
+    (``lm.serving_layout``: a layer's ``wq``, ``wk``, ``wv`` as one stacked
+    ``wqkv``; a linear-attention layer's six input projections as one
+    ``w_in``), made once here from whichever tree it is handed, and keeps
+    no reference to the unstacked weights: they are freed when the caller
+    lets go.
     """
 
     def __init__(self, params, model_cfg, cfg: Optional[EngineConfig] = None,
                  kv_tier=None):
         self.cfg = cfg or EngineConfig()
         self.model_cfg = model_cfg
-        self.params = serving_layout(params)
+        self.params = lm.serving_layout(params)
         # block: positions a block (0: a token at a time)
         self._block = int(getattr(model_cfg, "block_length", 0))
         if self._block and (self.cfg.page_size % self._block
@@ -431,19 +460,25 @@ class LLMEngine:
                 f"({self.cfg.page_size}) and max_seq_len "
                 f"({self.cfg.max_seq_len}): a block lies in one page")
         ccfg = CacheConfig(
-            n_layers=model_cfg.n_layers, n_kv_heads=model_cfg.n_kv_heads,
-            head_dim=model_cfg.head_dim, num_pages=self.cfg.num_pages,
-            page_size=self.cfg.page_size, dtype=model_cfg.dtype)
+            **lm.cache_layout(model_cfg), num_pages=self.cfg.num_pages,
+            page_size=self.cfg.page_size, dtype=model_cfg.dtype,
+            max_slots=self.cfg.max_slots)
         self.cache_k, self.cache_v = init_cache(ccfg)
+        # recurrent layers' rows, a slot each (None: the model has none)
+        self.state = init_state(ccfg)
+        self._state_layers = ccfg.state_layers
         self.allocator = PageAllocator(self.cfg.num_pages)
         # Prefix caching (ISSUE 10): finished sequences leave their full
         # prompt pages resident; later prompts sharing a page-aligned
         # prefix skip that prefill compute.  A pure index over pages — all
         # page ownership still flows through self.allocator, so swapping
         # the allocator (tests do) starts from an empty, consistent state.
+        # Recurrent state: a prefix's pages hold nothing of the state at
+        # its end, so no index is built and every prompt is computed whole.
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.cfg.page_size)
-            if flags.get("RTPU_PREFIX_CACHE") else None)
+            if flags.get("RTPU_PREFIX_CACHE") and self.state is None
+            else None)
         self.max_pages_per_seq = -(-self.cfg.max_seq_len
                                    // self.cfg.page_size)
         # Store-backed KV tier (ISSUE 16): hot family spines seal into
@@ -452,7 +487,9 @@ class LLMEngine:
         # hydration) runs on the scheduler thread — the single-writer
         # contract below covers it; kv_prehydrate() crosses threads only
         # through the thread-safe _hydrate_q.
-        self.kv_tier = kv_tier
+        # (a server hands every engine its worker's tier unasked: one with
+        # recurrent layers has no use for it, and refuses what asks for it)
+        self.kv_tier = kv_tier if self.state is None else None
         self._hydrate_q: queue_mod.Queue = queue_mod.Queue()
         self._waiting: queue_mod.Queue = queue_mod.Queue()
         # Single-writer design: _slots, the allocator, and _stats are
@@ -470,7 +507,8 @@ class LLMEngine:
         self._stats = {"prefills": 0, "decode_steps": 0,
                        "decode_pages_read": 0, "block_slot_passes": 0,
                        "masks_filled": 0, "blocks_final": 0,
-                       "experts_read": 0,
+                       "experts_read": 0, "state_slot_steps": 0,
+                       "state_resets": 0, "scan_chunks": 0,
                        "tokens_generated": 0, "deliveries": 0,
                        "deliveries_behind_dispatch": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
@@ -538,6 +576,8 @@ class LLMEngine:
             self._refuse_block("prefill/decode disaggregation "
                                "(prefill_extract): a prefill of this model "
                                "yields no first token to ship")
+        self._refuse_recurrent("prefill/decode disaggregation "
+                               "(prefill_extract)")
         self.start()
         params = params or SamplingParams()
         req = _Request(request_id=uuid.uuid4().hex[:12],
@@ -565,6 +605,8 @@ class LLMEngine:
             self._refuse_block("prefill/decode disaggregation "
                                "(submit_with_kv): a slot of this model "
                                "opens on a block, not on a shipped token")
+        self._refuse_recurrent("prefill/decode disaggregation "
+                               "(submit_with_kv)")
         self.start()
         params = params or SamplingParams()
         total = len(prompt_tokens) + params.max_tokens
@@ -590,6 +632,14 @@ class LLMEngine:
             f"{type(self.model_cfg).__name__} generates by diffusion over "
             f"blocks of {self._block} positions, which this engine does not "
             f"serve with {what}")
+
+    def _refuse_recurrent(self, what: str):
+        if self.state is not None:
+            raise ValueError(
+                f"{type(self.model_cfg).__name__} has recurrent layers whose "
+                f"state is a row a slot beside the pages, which this engine "
+                f"does not serve with {what}: pages alone carry nothing of "
+                f"the state at their end")
 
     def generate(self, prompt_tokens: List[int],
                  params: Optional[SamplingParams] = None,
@@ -967,7 +1017,8 @@ class LLMEngine:
                         prefix_len += cow_len
                         self._stats["cow_copies"] += 1
                         self._m["cow_copies"].inc()
-                    last = self._prefill(req, pages, rng, prefix_len)
+                    last = self._prefill(req, pages, rng, prefix_len,
+                                         free_slot)
             except Exception as e:  # noqa: BLE001 — surface to caller
                 self.allocator.free(pages)
                 self._fail(req, e)
@@ -1018,11 +1069,16 @@ class LLMEngine:
 
     def _prefill(self, req: _Request, pages: List[int],
                  rng: Optional[np.random.Generator],
-                 prefix_len: int = 0) -> Optional[int]:
+                 prefix_len: int = 0,
+                 slot: Optional[int] = None) -> Optional[int]:
         """Compute the prompt's K/V past ``prefix_len`` and sample the
         token that follows the prompt.  Block: the prompt's whole blocks
         only (the tail opens the slot's first block), nothing sampled, and
-        no program at all when the hit covers them."""
+        no program at all when the hit covers them.  Recurrent layers: the
+        same program also begins ``slot``'s state rows anew."""
+        if self.state is not None and slot is None:
+            raise ValueError("a prefill over recurrent layers names the "
+                             "slot whose state rows it begins")
         n = len(req.prompt_tokens)
         ps = self.cfg.page_size
         ph = self._ph
@@ -1057,9 +1113,14 @@ class LLMEngine:
         out, block_attrs = None, {}
         if suffix or not self._block:
             ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
-            logits, self.cache_k, self.cache_v = program(
-                self.params, tokens, self.cache_k, self.cache_v, *args,
-                self.model_cfg)
+            logits = self._run(program, tokens, *args, slot=slot)
+            if self.state is not None:
+                chunks = -(-bucket // SCAN_CHUNK) * self._state_layers
+                block_attrs = {"scan_chunks": chunks}
+                self._stats["scan_chunks"] += chunks
+                self._stats["state_resets"] += 1
+                self._m["scan_chunks"].inc(chunks)
+                self._m["state_resets"].inc()
             self._deliver(True)
             ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
             logits = np.asarray(logits)
@@ -1139,15 +1200,15 @@ class LLMEngine:
         (controller replication fan-out / warm restart).  Thread-safe:
         roots queue through _hydrate_q and the scheduler thread performs
         the actual pool mutation in _drain_hydrations."""
+        self._refuse_recurrent("the KV tier (kv_prehydrate)")
         self.start()
         for r in roots or ():
             self._hydrate_q.put(str(r))
 
     def _tier_expect(self) -> dict:
-        return {"page_size": self.cfg.page_size,
-                "layers": self.model_cfg.n_layers,
-                "kv_heads": self.model_cfg.n_kv_heads,
-                "head_dim": self.model_cfg.head_dim,
+        layers, _, _, kv_heads, head_dim = self.cache_k.shape
+        return {"page_size": self.cfg.page_size, "layers": layers,
+                "kv_heads": kv_heads, "head_dim": head_dim,
                 "dtype": str(np.dtype(self.cache_k.dtype))}
 
     def _kv_fallback(self, reason: str,
@@ -1512,11 +1573,9 @@ class LLMEngine:
         if all_greedy:
             steps = []
             for j in range(burst):
-                toks_dev, self.cache_k, self.cache_v = \
-                    lm.decode_step_greedy(
-                        self.params, toks_dev, self.cache_k, self.cache_v,
-                        tables_dev, pos_dev + j, active_dev,
-                        self.model_cfg)
+                toks_dev = self._run(
+                    lm.decode_step_greedy, toks_dev, tables_dev,
+                    pos_dev + j, active_dev)
                 steps.append(toks_dev)
                 if j == 0 and self._deliver(True) and burst > 1:
                     ph.begin(P_DECODE_DISPATCH, vals=(burst,))
@@ -1527,9 +1586,8 @@ class LLMEngine:
                     else np.asarray(steps[0])[None])
             ph.begin(P_DECODE_EMIT)
         else:
-            logits, self.cache_k, self.cache_v = lm.decode_step(
-                self.params, toks_dev, self.cache_k, self.cache_v,
-                tables_dev, pos_dev, active_dev, self.model_cfg)
+            logits = self._run(lm.decode_step, toks_dev, tables_dev,
+                               pos_dev, active_dev)
             self._deliver(True)
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             logits_np = np.asarray(logits)
@@ -1540,11 +1598,33 @@ class LLMEngine:
                     logits_np[i], s.request.params, s.rng)
         self._stats["decode_steps"] += burst
         self._m["decode_steps"].inc(burst)
+        if self.state is not None:
+            state_slots = burst * len(active_slots)
+            self._stats["state_slot_steps"] += state_slots
+            self._m["state_slot_steps"].inc(state_slots)
         self._accept_burst(active_slots, rows)
         if ph.sampled:
             ph.vals = (self._stats["tokens_generated"] - emitted,
                        sum(self._slots[i] is not s for i, s in active_slots))
+            if self.state is not None:
+                ph.vals = dict(zip(_PHASE_ATTRS[P_DECODE_EMIT], ph.vals),
+                               steps=burst, state_slots=state_slots)
         return True
+
+    def _run(self, program, tokens, *args, slot=None):
+        """One of llm/model.py's token-at-a-time programs over the pools
+        and, for a model with recurrent layers, the state rows (a prefill
+        names the ``slot`` it admits to): what it yields; pools and rows
+        come back in place."""
+        rows = {} if self.state is None else {"state": self.state}
+        if rows and slot is not None:
+            rows["slot"] = jnp.int32(slot)
+        out, self.cache_k, self.cache_v, *state = program(
+            self.params, tokens, self.cache_k, self.cache_v, *args,
+            self.model_cfg, **rows)
+        if state:
+            (self.state,) = state
+        return out
 
     # ------------------------- block diffusion ----------------------------
 

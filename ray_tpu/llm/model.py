@@ -30,22 +30,47 @@ prompt's last one), and ``block_step`` is one denoising pass over every
 slot's open block, its rows attending through the page table like a decode
 step's.  Its feed-forward, the routed experts, comes with the parameters.
 
+A model with RECURRENT layers (models/olmo_hybrid.py: gated delta-rule
+linear attention, three such layers to every full-attention one) caches
+more than pages, and says so (``cache_layout``): page pools for the layers
+that attend only, and beside them ``state``, named rows ``[rows,
+max_slots, ...]``: a slot's recurrent state (float32) and its
+convolution's last inputs.  The same ``prefill`` and ``decode_step*``
+serve it, taking ``state`` (donated, aliased to the output like the pools)
+and, the prefill, the ``slot`` it admits to.  ``_scan_layers`` then scans
+PERIODS (the recurrent layers of a period unrolled in the body, their
+weights indexed where they are read, then its full layer) with a third
+thing in the carry beside the pools: the state rows, whole.  As ``attend``
+is what the full layer's callers differ in, ``recur(mix, qkv, b, a, rows)``
+is the recurrent layer's: ``prefill`` runs ``ops/gated_delta.chunked`` from
+a ZERO state over the real tokens and writes the slot's rows ONCE after
+the scan (a row-sized write inside the loop made XLA copy all of the state
+to another layout and back); ``_decode_impl`` updates every live slot's
+row in place (``ops/gated_delta.decode_update``, a Pallas kernel).  A pool
+may hold more KV heads than the model has (whole tiles for the paged
+kernel: ``_pad_heads`` puts zero heads behind k, v and the decode step's
+q).  Pages hold nothing of the state at a prefix's end, so
+``prefill_with_prefix`` refuses such a tree by name, and the engine builds
+no prefix index and refuses P/D and the KV tier for it.
+
 ``cfg`` is the model's configuration, hashable (a static argument), of
-whichever family the parameters are: a ``LlamaConfig`` or an
-``SDARMoEConfig`` today.  The programs read of it ``n_layers``,
-``n_heads``, ``n_kv_heads`` and ``head_dim`` here and, through
+whichever family the parameters are: a ``LlamaConfig``, an
+``SDARMoEConfig`` or an ``OlmoHybridConfig`` today.  The programs read of
+it ``n_layers``, ``n_heads``, ``n_kv_heads`` and ``head_dim`` here and, through
 models/llama.py's parts, ``dtype``, ``norm_eps`` and ``rope_theta``; of a
 block-diffusion configuration also ``block_length``, ``mask_token_id``,
 ``denoising_steps``, ``remasking_strategy`` and ``confidence_threshold``
 (the sampler) and, through ``sdar_moe.scan_layers``, ``experts_per_token``
 and ``norm_topk_prob``.  Which feed-forward and which head norms a layer
 has is read off the parameters (``"experts" in params["layers"]``,
-``"q_norm" in p["attn"]``), not off a type; so is how it projects q, k and
+``"lin" in params["layers"]``, ``"q_norm" in p["attn"]`` and that weight's
+width), not off a type; so is how it projects q, k and
 v.  ``LLMEngine`` hands these programs the SERVING layout
-(``models.llama.serving_layout``): a layer's ``wq``, ``wk`` and ``wv`` as
-one stacked ``wqkv``, of which ``qkv_rope`` makes ONE product that XLA
-reads out of the stacked parameter inside the product's own fusion, as it
-reads the MLP's.  A tree with the three weights (training's, a test's)
+(``serving_layout`` below, by the family the tree is of): a layer's ``wq``,
+``wk`` and ``wv`` as one stacked ``wqkv`` (and a linear-attention layer's
+six input projections as one ``w_in``), of which ``qkv_rope`` makes ONE
+product that XLA reads out of the stacked parameter inside its own fusion,
+as it reads the MLP's.  A tree with the three weights (training's, a test's)
 runs through every program too: XLA then slices each weight of a layer
 into fast memory and transposes it there before its product, 9 % of a
 decode step at the serving cells' shapes (PERF.md section 6, PR 37).
@@ -68,9 +93,41 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import sdar_moe
+from ray_tpu.models import llama, olmo_hybrid, sdar_moe
 from ray_tpu.models.llama import embed, head, layer
+from ray_tpu.ops import gated_delta
 from ray_tpu.ops.paged_attention import paged_decode_attention
+
+
+def cache_layout(cfg) -> dict:
+    """What these programs cache for a model of configuration ``cfg``, as
+    ``paged_cache.CacheConfig`` takes it: ``n_layers`` page pools of
+    ``n_kv_heads`` x ``head_dim`` (the heads as the POOL holds them) and,
+    for a model with recurrent layers, ``state_layers`` times the
+    ``state_rows`` a slot.  A configuration that caches anything but one
+    K/V pool a layer says so itself (``cfg.cache_layout()``)."""
+    declared = getattr(cfg, "cache_layout", None)
+    if declared is not None:
+        return declared()
+    return {"n_layers": cfg.n_layers, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim}
+
+
+def serving_layout(params):
+    """The tree as these programs hold it, by the family the tree is of
+    (``models.llama.serving_layout``, ``models.olmo_hybrid``'s)."""
+    if "lin" in params["layers"]:
+        return olmo_hybrid.serving_layout(params)
+    return llama.serving_layout(params)
+
+
+def _pad_heads(x, n: int):
+    """x [..., heads, d] with zero heads behind up to ``n``: a pool whose
+    pages must be whole tiles may hold more heads than the model has."""
+    short = n - x.shape[-2]
+    if short == 0:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, short), (0, 0)))
 
 
 def _masked_attention(cfg, q, keys, vals, mask):
@@ -87,11 +144,38 @@ def _masked_attention(cfg, q, keys, vals, mask):
         return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
 
 
-def _scan_layers(params, x, cache_k, cache_v, positions, attend, cfg):
-    """The layer scan of every program here: both pools ride in the carry
-    whole, never scanned over, and ``attend(q, k, v, (ck, cv, li))`` writes
-    layer ``li``'s rows into them in place and attends its own way.
-    Returns (x, cache_k, cache_v, experts the routed layers read or None)."""
+def _scan_layers(params, x, caches, positions, attend, cfg, recur=None):
+    """The layer scan of every program here.  ``caches`` = (cache_k,
+    cache_v, state): both pools, and the state rows of a model with
+    recurrent layers (else None), ride in the carry whole, never scanned
+    over; ``attend(q, k, v, (ck, cv, li))`` writes pool layer ``li``'s rows
+    into them in place and attends its own way.  A recurrent layer's
+    ``recur(mix, qkv, b, a, (state, li)) -> (o, (state, left))`` updates
+    its rows in place likewise, or leaves them be and hands back as ``left``
+    what the caller is to write once the scan is over.  Returns (x, caches,
+    what the scan left: the experts the routed layers read, the recurrent
+    layers' ``left`` [periods, ...] in a list by place in the period, or
+    None)."""
+    cache_k, cache_v, state = caches
+    if "lin" in params["layers"]:  # periods: recurrent layers, then a full
+        n = cfg.lin_per_period
+
+        def period_body(carry, lin, full, period):
+            x, ck, cv, st = carry
+            left = []
+            for j in range(n):
+                li = period * n + j
+                x, (st, out) = olmo_hybrid.linear_layer(
+                    cfg, lin(li), x, recur, (st, li))
+                left.append(out)
+            x, (ck, cv) = olmo_hybrid.full_layer(
+                cfg, full, x, positions, attend, (ck, cv, period))
+            return (x, ck, cv, st), left
+
+        (x, *caches), left = olmo_hybrid.scan_periods(
+            cfg, params, period_body, (x, cache_k, cache_v, state))
+        return x, tuple(caches), left
+
     if "experts" in params["layers"]:  # routed: the experts are not scanned
         def routed_body(carry, p, li, feed_forward):
             x, ck, cv = carry
@@ -101,7 +185,7 @@ def _scan_layers(params, x, cache_k, cache_v, positions, attend, cfg):
 
         (x, cache_k, cache_v), hit = sdar_moe.scan_layers(
             cfg, params, routed_body, (x, cache_k, cache_v))
-        return x, cache_k, cache_v, hit
+        return x, (cache_k, cache_v, state), hit
 
     def body(carry, per_layer):
         x, ck, cv = carry
@@ -113,7 +197,20 @@ def _scan_layers(params, x, cache_k, cache_v, positions, attend, cfg):
         (x, cache_k, cache_v), _ = jax.lax.scan(
             body, (x, cache_k, cache_v),
             (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    return x, cache_k, cache_v, None
+    return x, (cache_k, cache_v, state), None
+
+
+def _cached(out, caches):
+    """What a program hands back: its result, both pools and, for a model
+    with recurrent layers, the state rows."""
+    return (out, *caches) if caches[2] is not None else (out, *caches[:2])
+
+
+def _conv_and_gates(cfg, mix, qkv, before, b, a):
+    """A recurrent layer's rows through the short convolution and the
+    norms and gates: (q, k, v, g, beta, the convolution's rows)."""
+    y, rows = olmo_hybrid.short_conv(mix["conv"], qkv, before)
+    return (*olmo_hybrid.delta_inputs(cfg, mix, y, b, a), rows)
 
 
 def _block_length(cfg) -> int:
@@ -140,15 +237,22 @@ def _prefill_result(params, x, cfg, true_len, experts_hit):
     return head(params, x, cfg, true_len)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
+@partial(jax.jit, static_argnames=("cfg",),
+         donate_argnames=("cache_k", "cache_v", "state"))
 def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
-            slot_positions, cfg):
+            slot_positions, cfg, state=None, slot=None):
     """Prefill ONE sequence padded to a length bucket.
 
     tokens: [L] int32 (padded); page_rows: [L] page id per token position;
     slot_positions: [L] slot inside the page; true_len: scalar.
     Writes K/V for positions < true_len into the paged cache and returns
     (logits_at_last_token [V], cache_k, cache_v).
+
+    A model with recurrent layers also takes ``state`` (its rows, donated)
+    and ``slot`` (the row this sequence is admitted to): each such layer
+    runs the chunked recurrence from a ZERO state over the true_len tokens
+    and leaves the final state, and its convolution's last inputs, in the
+    slot's row, whatever the row held; ``state`` comes back last.
     """
     x = embed(params, tokens, cfg)  # [L, D]
     positions = jnp.arange(tokens.shape[0])
@@ -161,14 +265,47 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         # write k/v into this layer's pages (beyond true_len the rows
         # write into the sequence's own pages — masked out of attention)
         with jax.named_scope("attn/kv_write"):
-            ck = ck.at[li, page_rows, slot_positions].set(k)
-            cv = cv.at[li, page_rows, slot_positions].set(v)
+            n_pool = ck.shape[3]
+            ck = ck.at[li, page_rows, slot_positions].set(
+                _pad_heads(k, n_pool))
+            cv = cv.at[li, page_rows, slot_positions].set(
+                _pad_heads(v, n_pool))
         # within the sequence: this call's own k and v, never the pool
         return _masked_attention(cfg, q, k, v, mask), (ck, cv)
 
-    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
-                                            positions, attend, cfg)
-    return _prefill_result(params, x, cfg, true_len, hit), cache_k, cache_v
+    def recur(mix, qkv, b, a, rows):  # qkv: [L, channels]
+        taps = mix["conv"].shape[0] - 1  # inputs the convolution keeps
+        q, k, v, g, beta, conv = _conv_and_gates(
+            cfg, mix, qkv, jnp.zeros((taps, qkv.shape[-1]), qkv.dtype), b, a)
+        with jax.named_scope("lin_attn/state"):
+            # a padded position changes nothing: no decay, no write
+            real = (positions < true_len)[:, None]
+            o, S = gated_delta.chunked(
+                q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0),
+                jnp.zeros((q.shape[1], v.shape[2], q.shape[2]), jnp.float32))
+            left = (gated_delta.pack_state(S, cfg.state_pack),
+                    jax.lax.dynamic_slice_in_dim(conv, true_len, taps, 0))
+        return o, (None, left)
+
+    # the scan carries no state: a prefill begins its slot's rows anew
+    x, (cache_k, cache_v, _), left = _scan_layers(
+        params, x, (cache_k, cache_v, None), positions, attend, cfg, recur)
+    if state is not None:
+        # The slot's rows are written HERE, once, and not in the scan: a
+        # row-sized update inside the loop lets XLA choose the carried
+        # state's layout to suit the update, and copy all of the state
+        # (0.85 GB) to that layout and back around the loop.
+        with jax.named_scope("lin_attn/state"):
+            S, tail = (jnp.stack(rows, axis=1) for rows in zip(*left))
+            S = S.reshape(-1, 1, *S.shape[2:])  # [layers, 1, ...]
+            tail = tail.reshape(-1, 1, tail.shape[-1])
+            state = {"S": jax.lax.dynamic_update_slice(
+                         state["S"], S, (0, slot, 0, 0, 0)),
+                     "conv": jax.lax.dynamic_update_slice(
+                         state["conv"], tail.astype(state["conv"].dtype),
+                         (0, slot, 0))}
+    return _cached(_prefill_result(params, x, cfg, true_len, left),
+                   (cache_k, cache_v, state))
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -188,6 +325,10 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     null page, padded query rows, and future suffix columns all drop out.
     Returns (logits at the last suffix token [V], cache_k, cache_v).
     """
+    if "lin" in params["layers"]:
+        raise ValueError(
+            "prefill_with_prefix serves no model with recurrent layers: "
+            "the pages of a prefix hold nothing of their state at its end")
     P = page_table.shape[0]
     page_size = cache_k.shape[2]
     x = embed(params, tokens, cfg)  # [L, D]
@@ -208,13 +349,13 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
             mask = _visible(cfg, positions, jnp.arange(P * page_size))
         return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
 
-    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
-                                            positions, attend, cfg)
-    return _prefill_result(params, x, cfg, true_len, hit), cache_k, cache_v
+    x, caches, hit = _scan_layers(params, x, (cache_k, cache_v, None),
+                                  positions, attend, cfg)
+    return _cached(_prefill_result(params, x, cfg, true_len, hit), caches)
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
-                 active, cfg):
+                 active, cfg, state=None):
     """One token for EVERY slot (the continuous-batching hot loop).
 
     tokens: [B] int32 current token per slot; positions: [B] its position;
@@ -222,7 +363,11 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     Returns (logits [B, V], cache_k, cache_v).
 
     A layer writes its B new rows into the pool in place and the paged
-    kernel reads that layer's pages out of the same buffer.
+    kernel reads that layer's pages out of the same buffer.  A recurrent
+    layer updates the row of every ACTIVE slot in ``state`` in place
+    (``ops/gated_delta.decode_update``: a live slot's state is read once
+    and written once, the others' not at all); ``state`` then comes back
+    last.
     """
     P = page_tables.shape[1]
     page_size = cache_k.shape[2]
@@ -242,36 +387,59 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
 
     def attend(q, k, v, pools):  # q: [B, H, d]; k, v: [B, Hkv, d]
         ck, cv, li = pools
+        n_pool = ck.shape[3]
         with jax.named_scope("attn/kv_write"):
-            ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
-            cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+            ck = ck.at[li, write_page, write_slot].set(
+                _pad_heads(k, n_pool).astype(ck.dtype))
+            cv = cv.at[li, write_page, write_slot].set(
+                _pad_heads(v, n_pool).astype(cv.dtype))
         with jax.named_scope("attn/attend"):
+            if n_pool != k.shape[1]:  # a padded pool: one query head each
+                out = paged_decode_attention(
+                    _pad_heads(q, n_pool), ck, cv, page_tables, lengths, li)
+                return out[:, :q.shape[1]], (ck, cv)
             return (paged_decode_attention(q, ck, cv, page_tables, lengths,
                                            li), (ck, cv))
 
-    x, cache_k, cache_v, _ = _scan_layers(params, x, cache_k, cache_v,
-                                          positions, attend, cfg)
-    return head(params, x, cfg), cache_k, cache_v
+    def recur(mix, qkv, b, a, rows):  # qkv: [B, channels]
+        st, li = rows
+        taps = mix["conv"].shape[0] - 1  # rows a layer, every slot's
+        before = jax.lax.dynamic_slice_in_dim(st["conv"], li * taps, taps)
+        q, k, v, g, beta, conv = _conv_and_gates(
+            cfg, mix, qkv[None], before, b[None], a[None])
+        with jax.named_scope("lin_attn/state"):
+            o, S = gated_delta.decode_update(
+                st["S"], li, q[0], k[0], v[0], g[0], beta[0], active,
+                pack=cfg.state_pack)
+            st = {"S": S, "conv": jax.lax.dynamic_update_slice_in_dim(
+                st["conv"], conv[1:], li * taps, axis=0)}
+        return o, (st, None)
+
+    x, caches, _ = _scan_layers(params, x, (cache_k, cache_v, state),
+                                positions, attend, cfg, recur)
+    return _cached(head(params, x, cfg), caches)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
+@partial(jax.jit, static_argnames=("cfg",),
+         donate_argnames=("cache_k", "cache_v", "state"))
 def decode_step(params, tokens, cache_k, cache_v, page_tables, positions,
-                active, cfg):
+                active, cfg, state=None):
     return _decode_impl(params, tokens, cache_k, cache_v, page_tables,
-                        positions, active, cfg)
+                        positions, active, cfg, state)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
+@partial(jax.jit, static_argnames=("cfg",),
+         donate_argnames=("cache_k", "cache_v", "state"))
 def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
-                       positions, active, cfg):
+                       positions, active, cfg, state=None):
     """Greedy decode: argmax ON DEVICE, so the host fetches [B] int32
     instead of [B, vocab] fp32 logits — the device-to-host round trip is the
     decode loop's fixed cost when every active request samples greedily."""
-    logits, cache_k, cache_v = _decode_impl(
+    logits, *caches = _decode_impl(
         params, tokens, cache_k, cache_v, page_tables, positions, active,
-        cfg)
+        cfg, state)
     with jax.named_scope("sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache_k, cache_v
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *caches)
 
 
 def _fill(cfg, logits, masked, step):
@@ -358,8 +526,8 @@ def block_step(params, cache_k, cache_v, page_tables, active, tokens,
             out = out.reshape(S, n_kv, B, rep, d).transpose(0, 2, 1, 3, 4)
             return out.reshape(S * B, n_kv * rep, d), (ck, cv)
 
-    x, cache_k, cache_v, hit = _scan_layers(params, x, cache_k, cache_v,
-                                            positions, attend, cfg)
+    x, (cache_k, cache_v, _), hit = _scan_layers(
+        params, x, (cache_k, cache_v, None), positions, attend, cfg)
     x0, fill = _fill(cfg, head(params, x, cfg).reshape(S, B, -1), masked,
                      step)
     final = active & ~jnp.any(masked, axis=1)

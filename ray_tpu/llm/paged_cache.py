@@ -55,12 +55,24 @@ _FALSY = ("", "0", "false", "no", "off")
 
 @dataclass
 class CacheConfig:
+    """What a model caches, as it declares it (``llm/model.py
+    cache_layout``), at an engine's sizes.  Two kinds: pages of K/V,
+    ``n_layers`` pools of ``n_kv_heads`` x ``head_dim`` (the layers that
+    attend, the heads as the pool holds them), and ``state_rows``, by name
+    the (count, shape, dtype) of the rows that each of ``max_slots`` slots
+    holds for the ``state_layers`` recurrent layers between them: fixed in
+    size, beside the pages and not in them, meaningless once the slot is
+    released."""
+
     n_layers: int
     n_kv_heads: int
     head_dim: int
     num_pages: int = 256
     page_size: int = 16
     dtype: str = "bfloat16"
+    state_layers: int = 0
+    state_rows: Optional[dict] = None
+    max_slots: int = 0
 
     @property
     def tokens_capacity(self) -> int:
@@ -72,6 +84,15 @@ def init_cache(cfg: CacheConfig):
              cfg.n_kv_heads, cfg.head_dim)
     dt = jnp.dtype(cfg.dtype)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+
+def init_state(cfg: CacheConfig):
+    """The state rows [count, max_slots, *row] by name, zeros; None for a
+    model that declares none."""
+    if not cfg.state_rows:
+        return None
+    return {name: jnp.zeros((count, cfg.max_slots, *shape), dt)
+            for name, (count, shape, dt) in cfg.state_rows.items()}
 
 
 class PageAllocator:

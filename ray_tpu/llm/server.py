@@ -99,7 +99,9 @@ class LLMServer:
                 roots = self._tier.hottest(8)
             except Exception:  # noqa: BLE001
                 roots = []
-            if roots:
+            # (an engine over recurrent layers took no tier: it has no
+            # pages a spine could be pulled into, and refuses by name)
+            if roots and self._engine.kv_tier is not None:
                 self._engine.kv_prehydrate(roots)
 
     def _params_from(self, body: dict) -> SamplingParams:

@@ -165,6 +165,11 @@ PARTS = (
     "mlp/norm", "mlp/gate_up", "mlp/down",
     "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
     "head", "sample", "loss", "optim",
+    # models/olmo_hybrid.py's linear-attention layer: the stacked input
+    # product, the short convolution, norms and gates, the recurrence (a
+    # decode step's update, a prefill's chunked scan), gated norm and W_o
+    "lin_attn/proj", "lin_attn/conv", "lin_attn/gates", "lin_attn/state",
+    "lin_attn/out",
 )
 
 
@@ -215,6 +220,16 @@ def serving_layout(params):
     return {**params, "layers": {**params["layers"], "attn": attn}}
 
 
+def _qk_norm(cfg, x, weight):
+    """RMS norm of q or k, x (..., heads, head_dim): a [head_dim] weight
+    norms each head (Qwen3 family), a wider one the whole row of heads
+    before they are split (OLMo 2 / 3)."""
+    if weight.shape[-1] == cfg.head_dim:
+        return rms_norm(x, weight, cfg.norm_eps)
+    flat = x.reshape(*x.shape[:-2], -1)
+    return rms_norm(flat, weight, cfg.norm_eps).reshape(x.shape)
+
+
 def qkv_rope(cfg, p, h, positions):
     """The normed stream h (..., d_model) projected and split into heads,
     q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width.
@@ -235,9 +250,9 @@ def qkv_rope(cfg, p, h, positions):
             q = heads(p["attn"]["wq"], cfg.n_heads)
             k = heads(p["attn"]["wk"], cfg.n_kv_heads)
             v = heads(p["attn"]["wv"], cfg.n_kv_heads)
-        if "q_norm" in p["attn"]:  # Qwen3 family: RMS norm a head, pre-rope
-            q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, p["attn"]["k_norm"], cfg.norm_eps)
+        if "q_norm" in p["attn"]:  # RMS norm pre-rope, by the weight's width
+            q = _qk_norm(cfg, q, p["attn"]["q_norm"])
+            k = _qk_norm(cfg, k, p["attn"]["k_norm"])
     return (rope(q, positions, cfg.rope_theta),
             rope(k, positions, cfg.rope_theta), v)
 

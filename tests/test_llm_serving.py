@@ -45,6 +45,17 @@ def tiny_sdar_loader():
     return sdar_moe.init(cfg, jax.random.PRNGKey(7)), cfg
 
 
+def tiny_hybrid_loader():
+    """Linear-attention layers, three to every full one
+    (models/olmo_hybrid.py), over the byte tokenizer's vocabulary."""
+    import jax
+
+    from ray_tpu.models import olmo_hybrid
+
+    cfg = olmo_hybrid.OlmoHybridConfig.tiny(259)
+    return olmo_hybrid.init(cfg, jax.random.PRNGKey(7)), cfg
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _cluster(ray_cluster):
     # join the session cluster (conftest.ray_cluster owns the
@@ -121,7 +132,49 @@ def test_block_diffusion_model_streams_over_http():
     serve.delete("sdar")
 
 
-@pytest.mark.parametrize("loader", [tiny_loader, tiny_sdar_loader])
+def test_hybrid_model_answers_over_http():
+    """The same path again, chosen by what the model says it caches:
+    build_openai_app -> serve.run -> HTTP -> LLMServer -> LLMEngine, pages
+    for the full layers and a state row a slot for the linear ones; the
+    stream carries what the whole answer holds, the same prompt asked
+    again is computed again and answers the same, and the engine's
+    counters say whose state was updated."""
+    import json
+
+    app = build_openai_app(LLMConfig(
+        model_id="tiny-hybrid", model_loader=tiny_hybrid_loader,
+        engine_config=EngineConfig(max_slots=4, num_pages=128, page_size=16,
+                                   max_seq_len=512,
+                                   prefill_buckets=(64, 128, 256)),
+        default_max_tokens=8))
+    serve.run(app, name="hybrid", route_prefix="/hybrid",
+              _blocking_timeout_s=120)
+    base = f"http://127.0.0.1:{serve.http_port()}/hybrid/v1"
+    body = {"prompt": list(range(7, 157)), "max_tokens": 12,
+            "ignore_eos": True}
+    whole = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert whole["usage"]["completion_tokens"] == 12, whole
+    events = []
+    with requests.post(f"{base}/completions", json={**body, "stream": True},
+                       stream=True, timeout=300) as r:
+        for line in r.iter_lines():
+            if line.startswith(b"data: ") and line != b"data: [DONE]":
+                events.append(json.loads(line[6:]))
+    text = "".join(e["choices"][0]["text"] for e in events)
+    assert text == whole["choices"][0]["text"]
+    from ray_tpu.serve.handle import DeploymentHandle
+
+    stats = DeploymentHandle(
+        "hybrid", "LLMServer:tiny-hybrid").engine_stats.remote().result(
+            timeout_s=60)
+    assert stats["state_resets"] == 2 and stats["prefill_tokens_saved"] == 0
+    assert stats["state_slot_steps"] >= 2 * 11 and stats["scan_chunks"] > 0
+    serve.delete("hybrid")
+
+
+@pytest.mark.parametrize("loader", [tiny_loader, tiny_sdar_loader,
+                                    tiny_hybrid_loader])
 def test_batch_processor_over_dataset(loader):
     from ray_tpu import data as rd
 

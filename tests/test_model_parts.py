@@ -23,7 +23,8 @@ from jax.experimental.compilation_cache import compilation_cache
 
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
-from ray_tpu.models import llama, moe, sdar_moe
+from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
+from ray_tpu.models import llama, moe, olmo_hybrid, sdar_moe
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -33,11 +34,14 @@ FLASH = ("flash_attention_fwd", "flash_attention_bwd_dkv",
          "flash_attention_bwd_dq")
 KERNELS = {**dict.fromkeys(FLASH, "attn/attend"),
            "paged_decode_attention": "attn/attend",
-           "moe_grouped_mlp": "moe/experts"}
+           "moe_grouped_mlp": "moe/experts",
+           "gated_delta_update": "lin_attn/state"}
 BLOCK = ("embed", "layers", "attn/norm", "attn/qkv", "attn/rope",
          "attn/attend", "attn/out", "mlp/norm")
 DENSE = BLOCK + ("mlp/gate_up", "mlp/down")
 ROUTED = BLOCK + ("moe/route", "moe/dispatch", "moe/experts", "moe/combine")
+HYBRID = DENSE + ("lin_attn/proj", "lin_attn/conv", "lin_attn/gates",
+                  "lin_attn/state", "lin_attn/out")
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -110,6 +114,29 @@ def _block_step():
         jnp.full((S,), 8, jnp.int32), jnp.zeros((S,), jnp.int32)), cfg
 
 
+def _hybrid():
+    """(configuration, serving tree, pools over the full layers, state
+    rows of 4 slots)."""
+    cfg = olmo_hybrid.OlmoHybridConfig.tiny()
+    params = lm.serving_layout(olmo_hybrid.init(cfg, jax.random.PRNGKey(0)))
+    cc = CacheConfig(**lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+                     dtype="float32", max_slots=4)
+    return cfg, params, init_cache(cc), init_state(cc)
+
+
+def _hybrid_prefill():
+    cfg, params, (ck, cv), state = _hybrid()
+    fn, (_, tokens, _, _, *rest), _ = _prefill()
+    return fn, (params, tokens, ck, cv, *rest), cfg, {
+        "state": state, "slot": jnp.int32(1)}
+
+
+def _hybrid_decode():
+    cfg, params, (ck, cv), state = _hybrid()
+    fn, (_, tokens, _, _, *rest), _ = _decode()
+    return fn, (params, tokens, ck, cv, *rest), cfg, {"state": state}
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -146,8 +173,8 @@ def _train_step():
 def _compiled_text(name):
     """The compiled text of one of the programs below."""
     if name in ENGINE:
-        fn, args, cfg = ENGINE[name]()
-        return fn.lower(*args, cfg=cfg).compile().as_text()
+        fn, args, cfg, *rows = ENGINE[name]()  # rows: a hybrid's state
+        return fn.lower(*args, cfg=cfg, **dict(*rows)).compile().as_text()
     if name == "train_step":
         step, args, mesh = _train_step()
         with mesh:
@@ -157,7 +184,9 @@ def _compiled_text(name):
 
 
 ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
-          "decode_step_greedy": _decode, "block_step": _block_step}
+          "decode_step_greedy": _decode, "block_step": _block_step,
+          "hybrid_prefill": _hybrid_prefill,
+          "hybrid_decode_step_greedy": _hybrid_decode}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
          "llama_grad": lambda: _llama_grad(False),
          "moe_grad": _moe_grad}
@@ -168,6 +197,9 @@ EXPECTED = {
                                     "head"),
     "decode_step_greedy": DENSE + ("attn/kv_write", "head", "sample"),
     "block_step": ROUTED + ("attn/kv_write", "head", "sample"),
+    "hybrid_prefill": HYBRID + ("attn/kv_write", "attn/attend/repeat_kv",
+                                "head"),
+    "hybrid_decode_step_greedy": HYBRID + ("attn/kv_write", "head", "sample"),
     "llama_grad_remat": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "llama_grad": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "moe_grad": ROUTED + ("attn/attend/repeat_kv", "head", "loss"),
@@ -202,6 +234,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
                 assert split_path(n, PARTS)[0] == part, n
     wanted = {"decode_step_greedy": {"paged_decode_attention"},
               "block_step": {"paged_decode_attention", "moe_grouped_mlp"},
+              "hybrid_decode_step_greedy": {"paged_decode_attention",
+                                            "gated_delta_update"},
               "llama_grad": set(FLASH),
               "train_step": set(FLASH)}.get(name, set())
     assert wanted <= seen
@@ -241,8 +275,9 @@ def test_remat_recomputes_under_the_layers_parts(name, recomputes):
 def test_outputs_are_bit_for_bit_those_without_scopes(name, monkeypatch):
     def build():  # a new function each time: a trace is cached by it
         if name in ENGINE:
-            fn, args, cfg = ENGINE[name]()
-            return (lambda *a: fn.__wrapped__(*a, cfg=cfg)), args
+            fn, args, cfg, *rows = ENGINE[name]()
+            return (lambda *a: fn.__wrapped__(*a, cfg=cfg,
+                                              **dict(*rows))), args
         return GRADS[name]()
 
     plain, args = build()

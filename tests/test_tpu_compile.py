@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models import llama, sdar_moe
+from ray_tpu.models import llama, olmo_hybrid, sdar_moe
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
@@ -326,6 +326,93 @@ def test_block_diffusion_programs_compile_at_sdar_widths(topo, as_tpu,
     planned = _footprint(compiled)
     assert planned < 0.9 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - SDAR_PLANNED_GB[program]) < 0.05
+
+
+# planned bytes a program of configuration ``olmo_hybrid7b_serve_1chip``,
+# compiled for the described v5e here (PERF.md section 4): weights 8.22 GB
+# (serving layout), both pools 3.22 GB, the state rows 0.88 GB
+HYBRID_PLANNED_GB = {"decode_step_greedy": 12.320, 64: 12.386, 1024: 12.721,
+                     2048: 13.099}
+
+
+@pytest.mark.parametrize("program", ["decode_step_greedy", 64, 1024, 2048])
+def test_hybrid_programs_compile_at_olmo_widths(topo, as_tpu, program):
+    """``decode_step_greedy`` (32 slots, 128-page tables) and three prefill
+    buckets of Olmo-Hybrid-7B at published widths and 16 layers, over the
+    cell's 3,072 pages (4 pools of 32-head pages) and 32 slots' state rows:
+    each plans at or under 0.85 of the chip's bytes_limit; pools AND state
+    rows are aliased to the outputs and held once (no copy of either, and
+    fewer temporaries than one of them: scanned over, or updated a row at a
+    time inside the scan, the 0.85 GB state was copied to another layout
+    and back); every weight is read where it lies (two slices deep, a
+    period then a layer, each was copied out and transposed: 1.2 GB of
+    temporaries a prefill); the decode step updates the state through the
+    ``gated_delta_update`` kernel and attends through the paged one."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = olmo_hybrid.OlmoHybridConfig(n_layers=16, max_seq_len=2048)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shapes = jax.eval_shape(
+        lambda k: olmo_hybrid.init(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0))
+    params = _on(one, jax.eval_shape(lm.serving_layout, shapes))
+    layout = lm.cache_layout(cfg)
+    cache = sds((layout["n_layers"], 3072, 16, layout["n_kv_heads"],
+                 layout["head_dim"]), jnp.bfloat16)
+    state = {name: sds((rows, 32, *shape), dt)
+             for name, (rows, shape, dt) in layout["state_rows"].items()}
+    assert cache.shape == (4, 3072, 16, 32, 128)
+    assert state["S"].shape == (12, 32, 15, 96, 384)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step_greedy":
+        compiled = lm.decode_step_greedy.lower(
+            params, i32(32), cache, cache, i32(32, 128), i32(32),
+            sds((32,), jnp.bool_), cfg, state).compile()
+        text = compiled.as_text()
+        assert "gated_delta_update" in text
+        assert "paged_decode_attention" in text
+    else:
+        compiled = lm.prefill.lower(
+            params, i32(program), cache, cache, i32(program), i32(),
+            i32(program), cfg, state, i32()).compile()
+        text = compiled.as_text()
+    pools = 2 * 4 * 3072 * 16 * 32 * 128 * 2
+    rows = 12 * 32 * 15 * 96 * 384 * 4 + 36 * 32 * 11520 * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pools + rows
+    # a decode step plans 3 MB of temporaries, a 2,048 prefill 0.78 GB
+    assert m.temp_size_in_bytes < (64e6 if program == "decode_step_greedy"
+                                   else 0.85e9)
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and r.startswith((
+        "f32[12,32,15,96,384]", "bf16[36,32,11520]",
+        "bf16[4,3072,16,32,128]"))]
+    planned = _footprint(compiled)
+    assert planned <= 0.85 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - HYBRID_PLANNED_GB[program]) < 0.05
+
+
+def test_state_update_kernel_compiles_and_writes_in_place(topo, as_tpu):
+    """``ops/gated_delta.decode_update`` alone at the cell's shape: the
+    state is aliased to the kernel's output, and nothing state-sized is
+    planned beside it."""
+    from ray_tpu.ops import gated_delta
+
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+
+    def update(state, layer, q, k, v, g, beta, active):
+        return gated_delta.decode_update(state, layer, q, k, v, g, beta,
+                                         active, pack=2)
+
+    compiled = jax.jit(update, donate_argnums=0).lower(
+        f32(12, 32, 15, 96, 384),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one), f32(32, 30, 96),
+        f32(32, 30, 96), f32(32, 30, 192), f32(32, 30), f32(32, 30),
+        jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 12 * 32 * 15 * 96 * 384 * 4
+    assert m.temp_size_in_bytes < 16e6
+    assert "gated_delta_update" in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [
