@@ -182,4 +182,4 @@ def loss_fn(params, tokens, cfg: GPT2Config, attn_impl: str = "auto",
     x = trunk(params, tokens[:, :-1], cfg, attn_impl, mesh=mesh,
               rules=rules)
     return chunked_softmax_xent(x, params["wte"].T, tokens[:, 1:],
-                                chunk=cfg.loss_chunk)
+                                chunk=cfg.loss_chunk, mesh=mesh, rules=rules)

@@ -375,4 +375,4 @@ def loss_fn(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
 
     x = trunk(params, tokens[:, :-1], cfg, attn_impl, mesh=mesh, rules=rules)
     return chunked_softmax_xent(x, params["lm_head"], tokens[:, 1:],
-                                chunk=cfg.loss_chunk)
+                                chunk=cfg.loss_chunk, mesh=mesh, rules=rules)

@@ -11,6 +11,7 @@ would turn down.  Skipped where the topology cannot be described.
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -450,14 +451,18 @@ def _train_step(model, cfg, mesh, batch, seq, attn_impl=None):
         return step.lower(state, tokens).compile()
 
 
+def _fsdp2_tp2(devices):
+    shape = {"fsdp": 2, "tp": 2}
+    return Mesh(np.array(devices).reshape(
+        tuple(shape.get(a, 1) for a in AXIS_ORDER)), AXIS_ORDER)
+
+
 def test_fsdp_tp_flash_step_compiles_on_four_chips(topo, as_tpu):
     """The north-star recipe's layout (fsdp x tp, ``attn_impl="flash"``):
     GSPMD cannot partition a Mosaic kernel, so without the shard_map around
     it this is ``NotImplementedError: Mosaic kernels cannot be
     automatically partitioned``."""
-    shape = {"fsdp": 2, "tp": 2}
-    mesh = Mesh(np.array(topo.devices).reshape(
-        tuple(shape.get(a, 1) for a in AXIS_ORDER)), AXIS_ORDER)
+    mesh = _fsdp2_tp2(topo.devices)
     # the recipe's own small stand-in (same GQA ratio and sharding
     # structure): at full widths this compile takes 19 s, and the chip run
     # (chip_smoke.py --chips 4) is what checks those
@@ -467,6 +472,65 @@ def test_fsdp_tp_flash_step_compiles_on_four_chips(topo, as_tpu):
     assert "tpu_custom_call" in text
     assert "all-gather" in text  # fsdp parameters
     assert "all-reduce" in text or "reduce-scatter" in text  # grads, tp
+
+
+_COLLECTIVE = re.compile(
+    r" = .*? (all-gather|all-reduce|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
+_UNDER_LOSS = re.compile(r"[/(]loss[/)](.*)")
+
+
+def _assert_loss_keeps_the_head_still(text, d_model, vocab, fsdp=2, tp=2):
+    """The loss's layout, read off a compiled step: inside the scans under
+    ``loss`` no collective has an operand or a result with a vocabulary
+    axis (whole or a device's ``vocab / tp``: the logits, their gradient,
+    the head or its gradient); outside them the bf16 head is gathered over
+    ``fsdp`` once and its float32 gradient reduced once, each a step."""
+    columns = {vocab, vocab // tp}
+    in_scan, gathers, reductions = [], 0, 0
+    for line in text.splitlines():
+        found = _COLLECTIVE.search(line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        under = op_name and _UNDER_LOSS.search(op_name.group(1))
+        if not (found and under):
+            continue
+        shapes = [tuple(int(n) for n in dims.split(",") if n)
+                  for dims in re.findall(
+                      r"\b(?:bf16|f32|f16|s32|u32|pred)\[([\d,]*)\]",
+                      line.split(", metadata=")[0])]
+        if "while/body" in under.group(1):
+            in_scan.append(line.strip()[:200])
+            assert not any(columns & set(shape) for shape in shapes), line
+        elif found.group(1) == "all-gather":
+            gathers += (d_model, vocab // tp) in shapes
+        else:
+            reductions += (d_model // fsdp, vocab // tp) in shapes
+    assert in_scan, "no collective under the loss's scan: is it a scan?"
+    assert (gathers, reductions) == (1, 1), (gathers, reductions)
+
+
+def test_cell_step_fits_and_its_loss_keeps_the_head_still(topo, as_tpu):
+    """``train_fsdp2_tp2``'s step (``mistral7b_train_4chip.json``: 7 layers
+    at Mistral-7B's widths, 8 x 4,096 tokens, fsdp=2 x tp=2, flash kernel):
+    plans at most 0.85 of the chip's bytes_limit, as the runner demands,
+    and the loss ships no logits, no gradient of them and no head inside
+    its scan (PR 40: the partitioner's own layout gathered the head twice
+    a chunk and reduce-scattered a float32 head gradient a chunk)."""
+    cfg = dataclasses.replace(_cell_llama(7), max_seq_len=32768, remat=True,
+                              loss_chunk=256)
+    compiled = _train_step(llama, cfg, _fsdp2_tp2(topo.devices), batch=8,
+                           seq=4095, attn_impl="flash")
+    assert _footprint(compiled) <= 0.85 * V5E_BYTES_LIMIT
+    _assert_loss_keeps_the_head_still(compiled.as_text(), 4096, 32768)
+
+
+def test_loss_keeps_the_head_still_on_four_host_devices():
+    """The same reading where no chip's compiler is: the recipe's small
+    stand-in partitioned for four CPU devices."""
+    cfg = llama.LlamaConfig.llama3_8b_dry(vocab_size=768)
+    compiled = _train_step(llama, cfg, _fsdp2_tp2(jax.devices()[:4]),
+                           batch=2, seq=511)
+    _assert_loss_keeps_the_head_still(compiled.as_text(), cfg.d_model, 768)
 
 
 @pytest.mark.parametrize("q_shape,kv_heads,match", [
