@@ -84,8 +84,9 @@ def _engine_metrics():
                     "denoising passes filled"),
                 "experts_read": Counter(
                     "llm_experts_read_total", "Experts the routed layers "
-                    "of denoising passes and prefills read (x 3 matrices "
-                    "= the grouped product's weight traffic)"),
+                    "of denoising passes, decode steps and prefills read "
+                    "(x 3 matrices = the grouped product's weight "
+                    "traffic)"),
                 "blocks_final": Counter(
                     "llm_blocks_final_total", "Blocks whose K/V a pass "
                     "over their mask-free tokens made final"),
@@ -107,6 +108,11 @@ def _engine_metrics():
                     "llm_decode_pages_read_total", "KV pages the decode "
                     "kernel walked: per step and active slot, the pages "
                     "its position reaches"),
+                "latent_pages_read": Counter(
+                    "llm_latent_pages_read_total", "Of those, pages of "
+                    "LATENT rows (one row a token a layer, key and value "
+                    "both): x page_size x the row's bytes x layers = what "
+                    "the latent decode kernel has to read"),
                 "tokens": Counter(
                     "llm_tokens_total", "Tokens emitted to callers"),
                 "deliveries": Counter(
@@ -225,7 +231,9 @@ _PHASE_ATTRS = {
     # a block-diffusion burst adds what its passes did
     # ... and a model with recurrent layers names its own (a dict): also
     # ``steps`` and ``state_slots``, the live slots whose state rows the
-    # burst's steps updated, summed over them
+    # burst's steps updated, summed over them; so does a token-at-a-time
+    # model with routed experts or latent pages: ``steps``, the
+    # ``experts_read`` by the burst's steps and the ``latent_pages_read``
     P_DECODE_EMIT: ("tokens", "slots_released", "slot_passes",
                     "masks_filled", "blocks_final", "experts_read",
                     "passes"),
@@ -463,7 +471,13 @@ class LLMEngine:
             **lm.cache_layout(model_cfg), num_pages=self.cfg.num_pages,
             page_size=self.cfg.page_size, dtype=model_cfg.dtype,
             max_slots=self.cfg.max_slots)
+        # (for latent pages cache_k is the one pool and cache_v None)
         self.cache_k, self.cache_v = init_cache(ccfg)
+        self._latent = bool(ccfg.latent_dim)
+        # a token-at-a-time model with routed experts: its programs hand
+        # back, beside their result, the experts they read
+        self._routed = (not self._block
+                        and "experts" in self.params["layers"])
         # recurrent layers' rows, a slot each (None: the model has none)
         self.state = init_state(ccfg)
         self._state_layers = ccfg.state_layers
@@ -489,7 +503,9 @@ class LLMEngine:
         # through the thread-safe _hydrate_q.
         # (a server hands every engine its worker's tier unasked: one with
         # recurrent layers has no use for it, and refuses what asks for it)
-        self.kv_tier = kv_tier if self.state is None else None
+        # (nor has one whose pages are latent rows: the tier moves K and V)
+        self.kv_tier = (kv_tier if self.state is None and not self._latent
+                        else None)
         self._hydrate_q: queue_mod.Queue = queue_mod.Queue()
         self._waiting: queue_mod.Queue = queue_mod.Queue()
         # Single-writer design: _slots, the allocator, and _stats are
@@ -505,7 +521,8 @@ class LLMEngine:
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
         self._stats = {"prefills": 0, "decode_steps": 0,
-                       "decode_pages_read": 0, "block_slot_passes": 0,
+                       "decode_pages_read": 0, "latent_pages_read": 0,
+                       "block_slot_passes": 0,
                        "masks_filled": 0, "blocks_final": 0,
                        "experts_read": 0, "state_slot_steps": 0,
                        "state_resets": 0, "scan_chunks": 0,
@@ -578,6 +595,8 @@ class LLMEngine:
                                "yields no first token to ship")
         self._refuse_recurrent("prefill/decode disaggregation "
                                "(prefill_extract)")
+        self._refuse_latent("prefill/decode disaggregation "
+                            "(prefill_extract)")
         self.start()
         params = params or SamplingParams()
         req = _Request(request_id=uuid.uuid4().hex[:12],
@@ -607,6 +626,8 @@ class LLMEngine:
                                "opens on a block, not on a shipped token")
         self._refuse_recurrent("prefill/decode disaggregation "
                                "(submit_with_kv)")
+        self._refuse_latent("prefill/decode disaggregation "
+                            "(submit_with_kv)")
         self.start()
         params = params or SamplingParams()
         total = len(prompt_tokens) + params.max_tokens
@@ -640,6 +661,14 @@ class LLMEngine:
                 f"state is a row a slot beside the pages, which this engine "
                 f"does not serve with {what}: pages alone carry nothing of "
                 f"the state at their end")
+
+    def _refuse_latent(self, what: str):
+        if self._latent:
+            raise ValueError(
+                f"{type(self.model_cfg).__name__} caches latent rows, one "
+                f"pool of {self.cache_k.shape[-1]} values a token a layer "
+                f"and no V pool, which this engine does not serve with "
+                f"{what}: that ships K and V pages")
 
     def generate(self, prompt_tokens: List[int],
                  params: Optional[SamplingParams] = None,
@@ -1123,13 +1152,17 @@ class LLMEngine:
                 self._m["state_resets"].inc()
             self._deliver(True)
             ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
+            if self._routed:  # the logits, then the experts it read
+                logits, hit = logits
             logits = np.asarray(logits)
             ph.begin(P_PREFILL_EMIT, req)
             if self._block:  # what came back is the experts it read
-                block_attrs = {"experts_read": int(logits)}
-                self._stats["experts_read"] += int(logits)
-                self._m["experts_read"].inc(int(logits))
-            else:
+                hit = logits
+            if self._block or self._routed:
+                block_attrs = {"experts_read": int(hit)}
+                self._stats["experts_read"] += int(hit)
+                self._m["experts_read"].inc(int(hit))
+            if not self._block:
                 out = self._sample_one(logits, req.params, rng)
             self._stats["prefills"] += 1
             self._m["prefills"].inc()
@@ -1201,6 +1234,7 @@ class LLMEngine:
         roots queue through _hydrate_q and the scheduler thread performs
         the actual pool mutation in _drain_hydrations."""
         self._refuse_recurrent("the KV tier (kv_prehydrate)")
+        self._refuse_latent("the KV tier (kv_prehydrate)")
         self.start()
         for r in roots or ():
             self._hydrate_q.put(str(r))
@@ -1567,6 +1601,10 @@ class LLMEngine:
             // self.cfg.page_size + 1, P).sum())
         self._stats["decode_pages_read"] += pages_read
         self._m["decode_pages_read"].inc(pages_read)
+        if self._latent:
+            self._stats["latent_pages_read"] += pages_read
+            self._m["latent_pages_read"].inc(pages_read)
+        hits = []  # a routed model: the experts each step read, on device
         emitted = self._stats["tokens_generated"]
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))
@@ -1576,6 +1614,9 @@ class LLMEngine:
                 toks_dev = self._run(
                     lm.decode_step_greedy, toks_dev, tables_dev,
                     pos_dev + j, active_dev)
+                if self._routed:
+                    toks_dev, hit = toks_dev
+                    hits.append(hit)
                 steps.append(toks_dev)
                 if j == 0 and self._deliver(True) and burst > 1:
                     ph.begin(P_DECODE_DISPATCH, vals=(burst,))
@@ -1588,6 +1629,9 @@ class LLMEngine:
         else:
             logits = self._run(lm.decode_step, toks_dev, tables_dev,
                                pos_dev, active_dev)
+            if self._routed:
+                logits, hit = logits
+                hits.append(hit)
             self._deliver(True)
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             logits_np = np.asarray(logits)
@@ -1602,13 +1646,25 @@ class LLMEngine:
             state_slots = burst * len(active_slots)
             self._stats["state_slot_steps"] += state_slots
             self._m["state_slot_steps"].inc(state_slots)
+        experts_read = 0
+        if hits:  # computed with the tokens that were just fetched
+            experts_read = int(sum(int(h) for h in jax.device_get(hits)))
+            self._stats["experts_read"] += experts_read
+            self._m["experts_read"].inc(experts_read)
         self._accept_burst(active_slots, rows)
         if ph.sampled:
             ph.vals = (self._stats["tokens_generated"] - emitted,
                        sum(self._slots[i] is not s for i, s in active_slots))
+            named = {}  # what such a model's steps did, by name
             if self.state is not None:
+                named["state_slots"] = state_slots
+            if self._routed:
+                named["experts_read"] = experts_read
+            if self._latent:
+                named["latent_pages_read"] = pages_read
+            if named:
                 ph.vals = dict(zip(_PHASE_ATTRS[P_DECODE_EMIT], ph.vals),
-                               steps=burst, state_slots=state_slots)
+                               steps=burst, **named)
         return True
 
     def _run(self, program, tokens, *args, slot=None):

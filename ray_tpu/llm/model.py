@@ -53,18 +53,47 @@ q).  Pages hold nothing of the state at a prefix's end, so
 ``prefill_with_prefix`` refuses such a tree by name, and the engine builds
 no prefix index and refuses P/D and the KV tier for it.
 
+A model that attends over LATENT rows (models/glm_moe_lite.py: latent
+attention in every layer, a leading dense layer, then routed experts beside
+a shared one) caches neither K nor V.  Its ``cache_layout`` declares a
+LATENT POOL: ``cache_k`` is ``[n_layers, pages, page_size, latent_width]``,
+one row a token a layer (``c_kv`` after its norm, then the ONE rotary key
+after rotation, then zeros to the lane tile's end: 512 + 64 + 64 = 640
+lanes, 1,280 bytes in bf16), key and value both, and ``cache_v`` is None;
+page tables, allocator, prefix index and ``copy_page`` do not notice.  The
+same programs serve it, each with a second ``attend`` of its own
+(``attend_latent(q_nope, q_rope, row, a, pools)``, picked where the tree
+is latent: ``_latent``), and the two FORMS of latent attention, the same
+function, go by program: ``prefill`` and ``prefill_with_prefix`` write
+their rows and attend REBUILT (per-head K and V made from latent rows by
+``glm_moe_lite.rebuild_kv``: this call's own rows, or the rows the page
+table reaches, then the dense mask of ``_masked_attention``, a head a
+head); ``_decode_impl`` writes its B rows and attends ABSORBED (``W_uk``
+folded into the query, ``ops/paged_attention.paged_latent_decode_attention``
+walking each slot's rows ONCE and using each twice, ``W_uv`` applied to
+its output): K and V are never made for a decode step.  ``_scan_layers``
+runs the leading dense layers before the scanned sparse ones, the pool's
+layer index running on (``glm_moe_lite.scan_layers``).  A token-at-a-time
+model with routed experts hands back, beside its logits or tokens, the
+experts its routed layers read (``_routed``).
+
 ``cfg`` is the model's configuration, hashable (a static argument), of
 whichever family the parameters are: a ``LlamaConfig``, an
-``SDARMoEConfig`` or an ``OlmoHybridConfig`` today.  The programs read of
+``SDARMoEConfig``, an ``OlmoHybridConfig`` or a ``GLMMoELiteConfig``
+today.  The programs read of
 it ``n_layers``, ``n_heads``, ``n_kv_heads`` and ``head_dim`` here and, through
 models/llama.py's parts, ``dtype``, ``norm_eps`` and ``rope_theta``; of a
 block-diffusion configuration also ``block_length``, ``mask_token_id``,
 ``denoising_steps``, ``remasking_strategy`` and ``confidence_threshold``
-(the sampler) and, through ``sdar_moe.scan_layers``, ``experts_per_token``
-and ``norm_topk_prob``.  Which feed-forward and which head norms a layer
+(the sampler) and, through ``moe.scan_routed_layers``,
+``experts_per_token``, ``norm_topk_prob`` and (where it has one)
+``routed_scaling_factor``; of a latent configuration ``kv_lora_rank``,
+``latent_dim`` and the head widths.  Which feed-forward and which head norms a layer
 has is read off the parameters (``"experts" in params["layers"]``,
-``"lin" in params["layers"]``, ``"q_norm" in p["attn"]`` and that weight's
-width), not off a type; so is how it projects q, k and
+``"lin" in params["layers"]``, ``"dense" in params``, ``"w_uk"`` or
+``"wkv_b"`` in a layer's ``attn``, ``"q_norm" in p["attn"]`` and that
+weight's width, ``"router_bias"`` and ``"shared"`` in a routed layer), not
+off a type; so is how it projects q, k and
 v.  ``LLMEngine`` hands these programs the SERVING layout
 (``serving_layout`` below, by the family the tree is of): a layer's ``wq``,
 ``wk`` and ``wv`` as one stacked ``wqkv`` (and a linear-attention layer's
@@ -93,10 +122,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
 from ray_tpu.models.llama import embed, head, layer
 from ray_tpu.ops import gated_delta
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                         paged_latent_decode_attention)
 
 
 def cache_layout(cfg) -> dict:
@@ -118,7 +148,17 @@ def serving_layout(params):
     (``models.llama.serving_layout``, ``models.olmo_hybrid``'s)."""
     if "lin" in params["layers"]:
         return olmo_hybrid.serving_layout(params)
+    if _latent(params):
+        return glm_moe_lite.serving_layout(params)
     return llama.serving_layout(params)
+
+
+def _latent(params) -> bool:
+    """Does the tree attend over latent rows (models/glm_moe_lite.py)?  Then
+    ``cache_k`` is the latent pool, ``cache_v`` None, and a program's
+    ``attend`` is its latent one."""
+    attn = params["layers"].get("attn", ())
+    return "wkv_b" in attn or "w_uk" in attn
 
 
 def _pad_heads(x, n: int):
@@ -142,6 +182,23 @@ def _masked_attention(cfg, q, keys, vals, mask):
         scores = jnp.where(mask[None], scores, -1e30)
         attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
         return jnp.einsum("hqk,khd->qhd", attn.astype(vals.dtype), vals)
+
+
+def _write_rows(pool, li, pages, slots, row):
+    """Latent rows [n, latent_dim] into pool layer ``li`` at (pages, slots),
+    zeros behind them to the pool's width."""
+    with jax.named_scope("attn/kv_write"):
+        short = pool.shape[-1] - row.shape[-1]
+        return pool.at[li, pages, slots].set(
+            jnp.pad(row, ((0, 0), (0, short))).astype(pool.dtype))
+
+
+def _rebuilt_attention(cfg, a, q_nope, q_rope, rows, mask):
+    """Latent attention in the REBUILT form: per-head K and V made from
+    the latent ``rows`` [T, >= latent_dim], then dense masked attention."""
+    keys, vals = glm_moe_lite.rebuild_kv(cfg, a, rows)
+    return _masked_attention(
+        cfg, jnp.concatenate([q_nope, q_rope], axis=-1), keys, vals, mask)
 
 
 def _scan_layers(params, x, caches, positions, attend, cfg, recur=None):
@@ -175,6 +232,18 @@ def _scan_layers(params, x, caches, positions, attend, cfg, recur=None):
         (x, *caches), left = olmo_hybrid.scan_periods(
             cfg, params, period_body, (x, cache_k, cache_v, state))
         return x, tuple(caches), left
+
+    if _latent(params):  # leading dense layers, then routed ones
+        def latent_body(carry, p, li, feed_forward):
+            x, ck, cv = carry
+            x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li),
+                                feed_forward,
+                                glm_moe_lite.latent_attention_block)
+            return x, ck, cv
+
+        (x, cache_k, cache_v), hit = glm_moe_lite.scan_layers(
+            cfg, params, latent_body, (x, cache_k, cache_v))
+        return x, (cache_k, cache_v, state), hit
 
     if "experts" in params["layers"]:  # routed: the experts are not scanned
         def routed_body(carry, p, li, feed_forward):
@@ -227,14 +296,20 @@ def _visible(cfg, qpos, kpos):
     return kpos[None, :] <= qpos[:, None]
 
 
+def _routed(params) -> bool:
+    return "experts" in params["layers"]
+
+
 def _prefill_result(params, x, cfg, true_len, experts_hit):
-    """What a prefill hands the engine: the last token's logits, or for a
-    block-diffusion configuration (no token follows from a prompt, so the
-    output head is not run) a number to wait for: the experts its routed
-    layers read."""
+    """What a prefill hands the engine: the last token's logits (with the
+    experts its routed layers read, where a token-at-a-time model has
+    such), or for a block-diffusion configuration (no token follows from a
+    prompt, so the output head is not run) a number to wait for: those
+    experts."""
     if _block_length(cfg):
         return experts_hit
-    return head(params, x, cfg, true_len)
+    logits = head(params, x, cfg, true_len)
+    return (logits, experts_hit) if _routed(params) else logits
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -273,6 +348,12 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         # within the sequence: this call's own k and v, never the pool
         return _masked_attention(cfg, q, k, v, mask), (ck, cv)
 
+    def attend_latent(q_nope, q_rope, row, a, pools):
+        pool, _, li = pools  # rebuilt from this call's own rows
+        pool = _write_rows(pool, li, page_rows, slot_positions, row)
+        return (_rebuilt_attention(cfg, a, q_nope, q_rope, row, mask),
+                (pool, None))
+
     def recur(mix, qkv, b, a, rows):  # qkv: [L, channels]
         taps = mix["conv"].shape[0] - 1  # inputs the convolution keeps
         q, k, v, g, beta, conv = _conv_and_gates(
@@ -289,7 +370,8 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
 
     # the scan carries no state: a prefill begins its slot's rows anew
     x, (cache_k, cache_v, _), left = _scan_layers(
-        params, x, (cache_k, cache_v, None), positions, attend, cfg, recur)
+        params, x, (cache_k, cache_v, None), positions,
+        attend_latent if _latent(params) else attend, cfg, recur)
     if state is not None:
         # The slot's rows are written HERE, once, and not in the scan: a
         # row-sized update inside the loop lets XLA choose the carried
@@ -349,8 +431,18 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
             mask = _visible(cfg, positions, jnp.arange(P * page_size))
         return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
 
-    x, caches, hit = _scan_layers(params, x, (cache_k, cache_v, None),
-                                  positions, attend, cfg)
+    def attend_latent(q_nope, q_rope, row, a, pools):
+        pool, _, li = pools  # rebuilt from the rows the page table reaches
+        pool = _write_rows(pool, li, page_rows, slot_positions, row)
+        with jax.named_scope("attn/attend"):  # the gather is attending
+            rows = pool[li, page_table].reshape(P * page_size, -1)
+            mask = _visible(cfg, positions, jnp.arange(P * page_size))
+        return (_rebuilt_attention(cfg, a, q_nope, q_rope, rows, mask),
+                (pool, None))
+
+    x, caches, hit = _scan_layers(
+        params, x, (cache_k, cache_v, None), positions,
+        attend_latent if _latent(params) else attend, cfg)
     return _cached(_prefill_result(params, x, cfg, true_len, hit), caches)
 
 
@@ -401,6 +493,16 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
             return (paged_decode_attention(q, ck, cv, page_tables, lengths,
                                            li), (ck, cv))
 
+    def attend_latent(q_nope, q_rope, row, a, pools):
+        pool, _, li = pools  # absorbed: K and V are never made
+        pool = _write_rows(pool, li, write_page, write_slot, row)
+        q = glm_moe_lite.absorb(cfg, a, q_nope, q_rope, pool.shape[-1])
+        with jax.named_scope("mla/attend"):
+            out = paged_latent_decode_attention(
+                q, pool, page_tables, lengths, li,
+                value_dim=cfg.kv_lora_rank, sm_scale=cfg.head_dim ** -0.5)
+        return glm_moe_lite.unabsorb(cfg, a, out), (pool, None)
+
     def recur(mix, qkv, b, a, rows):  # qkv: [B, channels]
         st, li = rows
         taps = mix["conv"].shape[0] - 1  # rows a layer, every slot's
@@ -415,9 +517,11 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
                 st["conv"], conv[1:], li * taps, axis=0)}
         return o, (st, None)
 
-    x, caches, _ = _scan_layers(params, x, (cache_k, cache_v, state),
-                                positions, attend, cfg, recur)
-    return _cached(head(params, x, cfg), caches)
+    x, caches, hit = _scan_layers(
+        params, x, (cache_k, cache_v, state), positions,
+        attend_latent if _latent(params) else attend, cfg, recur)
+    logits = head(params, x, cfg)
+    return _cached((logits, hit) if _routed(params) else logits, caches)
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -438,8 +542,11 @@ def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
     logits, *caches = _decode_impl(
         params, tokens, cache_k, cache_v, page_tables, positions, active,
         cfg, state)
+    # a routed model: the step's tokens, then the experts it read
+    logits, *hit = logits if _routed(params) else (logits,)
     with jax.named_scope("sample"):
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *caches)
+        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return ((tokens, *hit) if hit else tokens, *caches)
 
 
 def _fill(cfg, logits, masked, step):
@@ -552,4 +659,5 @@ def copy_page(cache_k, cache_v, src, dst):
     attention reads it, the same invariant that makes null-page garbage
     safe."""
     return (cache_k.at[:, dst].set(cache_k[:, src]),
-            cache_v.at[:, dst].set(cache_v[:, src]))
+            None if cache_v is None  # a latent pool is the one pool
+            else cache_v.at[:, dst].set(cache_v[:, src]))

@@ -56,33 +56,56 @@ _FALSY = ("", "0", "false", "no", "off")
 @dataclass
 class CacheConfig:
     """What a model caches, as it declares it (``llm/model.py
-    cache_layout``), at an engine's sizes.  Two kinds: pages of K/V,
+    cache_layout``), at an engine's sizes.  Three kinds.  Pages of K/V:
     ``n_layers`` pools of ``n_kv_heads`` x ``head_dim`` (the layers that
-    attend, the heads as the pool holds them), and ``state_rows``, by name
-    the (count, shape, dtype) of the rows that each of ``max_slots`` slots
-    holds for the ``state_layers`` recurrent layers between them: fixed in
-    size, beside the pages and not in them, meaningless once the slot is
-    released."""
+    attend, the heads as the pool holds them), K and V one shape.  LATENT
+    pages (``latent_dim`` > 0, then no heads): ``n_layers`` pools of ONE
+    row of ``latent_dim`` a token, key and value both, and no V pool
+    (models/glm_moe_lite.py ``cache_layout`` says what lies in the row).
+    The page table, ``PageAllocator`` and ``PrefixCache`` are the same for
+    both: a page is ``page_size`` tokens of every layer.  And
+    ``state_rows``, by name the (count, shape, dtype) of the rows that each
+    of ``max_slots`` slots holds for the ``state_layers`` recurrent layers
+    between them: fixed in size, beside the pages and not in them,
+    meaningless once the slot is released."""
 
     n_layers: int
-    n_kv_heads: int
-    head_dim: int
+    n_kv_heads: int = 0
+    head_dim: int = 0
     num_pages: int = 256
     page_size: int = 16
     dtype: str = "bfloat16"
     state_layers: int = 0
     state_rows: Optional[dict] = None
     max_slots: int = 0
+    latent_dim: int = 0
+
+    def __post_init__(self):
+        if bool(self.latent_dim) == bool(self.n_kv_heads * self.head_dim):
+            raise ValueError(
+                f"a page holds K and V of n_kv_heads x head_dim "
+                f"({self.n_kv_heads} x {self.head_dim}) or one latent row "
+                f"of latent_dim ({self.latent_dim}), one of the two")
 
     @property
     def tokens_capacity(self) -> int:
         return self.num_pages * self.page_size
 
+    @property
+    def bytes_per_token(self) -> int:
+        """Page bytes a cached token takes, all layers."""
+        row = self.latent_dim or 2 * self.n_kv_heads * self.head_dim
+        return self.n_layers * row * jnp.dtype(self.dtype).itemsize
+
 
 def init_cache(cfg: CacheConfig):
+    """(cache_k, cache_v) zeros; for latent pages (the one pool, None)."""
+    dt = jnp.dtype(cfg.dtype)
+    if cfg.latent_dim:
+        return jnp.zeros((cfg.n_layers, cfg.num_pages, cfg.page_size,
+                          cfg.latent_dim), dt), None
     shape = (cfg.n_layers, cfg.num_pages, cfg.page_size,
              cfg.n_kv_heads, cfg.head_dim)
-    dt = jnp.dtype(cfg.dtype)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 

@@ -170,6 +170,13 @@ PARTS = (
     # decode step's update, a prefill's chunked scan), gated norm and W_o
     "lin_attn/proj", "lin_attn/conv", "lin_attn/gates", "lin_attn/state",
     "lin_attn/out",
+    # models/glm_moe_lite.py's latent attention: the stacked down-projections
+    # and the latent norm, the query's norm and up-projection, K and V
+    # rebuilt from latent rows (prefills), the decode step's absorption,
+    # its paged kernel over the rows and its un-absorption; the routed
+    # layers' shared expert (models/moe.py)
+    "mla/kv_down", "mla/q_proj", "mla/kv_up", "mla/absorb", "mla/attend",
+    "mla/unabsorb", "moe/shared",
 )
 
 
@@ -281,11 +288,14 @@ def gated_mlp(p, h):
         return (gate * up) @ p["mlp"]["w_down"].astype(h.dtype)
 
 
-def layer(cfg, p, x, positions, attend, cache=None, feed_forward=gated_mlp):
+def layer(cfg, p, x, positions, attend, cache=None, feed_forward=gated_mlp,
+          attention=attention_block):
     """One decoder layer: (x, cache).  ``feed_forward(p, h)`` is the dense
     gated MLP unless the caller hands another (models/moe.py's routed
-    experts)."""
-    x, cache = attention_block(cfg, p, x, positions, attend, cache)
+    experts), ``attention`` this file's block unless the layer attends over
+    latent rows (models/glm_moe_lite.py ``latent_attention_block``, whose
+    ``attend`` takes other things)."""
+    x, cache = attention(cfg, p, x, positions, attend, cache)
     with jax.named_scope("mlp/norm"):
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     return x + feed_forward(p, h), cache

@@ -182,18 +182,33 @@ def moe_mlp(cfg: MoEConfig, x, router_w, experts):
     return out.reshape(b, s, d), aux
 
 
-def route(h, router_w, top_k: int, renormalise: bool = True):
-    """Softmax over the experts in float32 (a float32 product: on the TPU a
-    float32 matmul runs in bf16 passes unless told otherwise), the top_k of
-    it and, ``renormalise``, their weights made to sum to one.  h: (N, D).
-    Returns (weights (N, k) float32, experts (N, k) int32)."""
+def route(h, router_w, top_k: int, renormalise: bool = True, bias=None,
+          scale=None):
+    """The top_k experts of every row and their weights.  h: (N, D).
+    Returns (weights (N, k) float32, experts (N, k) int32).
+
+    The scores are a float32 product (on the TPU a float32 matmul runs in
+    bf16 passes unless told otherwise).  Without ``bias``: softmax over the
+    experts, the top_k of it and, ``renormalise``, their weights made to sum
+    to one (Qwen3-MoE, Mixtral).  With ``bias`` (E,), a layer's
+    ``e_score_correction_bias`` (DeepSeek-V3's ``noaux_tc``, one group):
+    s = sigmoid(scores); the experts are the top_k of s + bias, the bias
+    steering the CHOICE only; their weights are s of the chosen, without it,
+    ``renormalise`` divided by their sum, then times ``scale``."""
     with jax.named_scope("moe/route"):
         logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                       top_k)
+        if bias is None:
+            top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                           top_k)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, top_idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+            top_p = jnp.take_along_axis(s, top_idx, axis=-1)
         if renormalise:
             top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if scale is not None:
+            top_p = top_p * scale
         return top_p, top_idx.astype(jnp.int32)
 
 
@@ -206,20 +221,73 @@ def row_tile(assignments: int, n_experts: int) -> int:
 
 
 def routed_mlp(h, router_w, experts, layer, *, top_k: int,
-               renormalise: bool = True):
+               renormalise: bool = True, bias=None, scale=None, shared=None):
     """Dropless top-k routed gated MLP.  h: (..., D) -> (..., D).
 
     ``experts``: w_gate / w_up [layers, E, D, F] and w_down [layers, E, F, D]
     stacked over layers, of which ``layer`` is read (ops/grouped_matmul says
-    why they come whole); router_w: (D, E), this layer's.  Every (token,
-    expert) assignment is computed: no capacity, no dropped token.
+    why they come whole); router_w: (D, E), this layer's; ``bias`` and
+    ``scale`` as ``route`` takes them.  Every (token, expert) assignment is
+    computed: no capacity, no dropped token.  ``shared``: this layer's
+    shared expert (w_gate, w_up (D, Fs), w_down (Fs, D)), which every token
+    passes through once, beside the routed ones and unweighted.
     Returns (out, experts_hit): how many experts some token reached, which
     is how many the grouped product read.
     """
     hf = h.reshape(-1, h.shape[-1])
-    weights, chosen = route(hf, router_w, top_k, renormalise)
+    weights, chosen = route(hf, router_w, top_k, renormalise, bias, scale)
     out, experts_hit = dispatch(hf, weights, chosen, experts, layer)
+    if shared is not None:
+        out = out + shared_mlp(shared, hf)
     return out.reshape(h.shape), experts_hit
+
+
+def shared_mlp(shared, hf):
+    """The shared expert: a gated MLP over every row, one part."""
+    with jax.named_scope("moe/shared"):
+        gate = jax.nn.silu(hf @ shared["w_gate"].astype(hf.dtype))
+        up = hf @ shared["w_up"].astype(hf.dtype)
+        return (gate * up) @ shared["w_down"].astype(hf.dtype)
+
+
+def scan_routed_layers(cfg, layers, body, carry, first: int = 0):
+    """``lax.scan`` of ``body(carry, layer_params, li, feed_forward)`` over
+    the routed layers ``layers`` (leaves stacked on a leading axis), the
+    experts held out of what the scan slices: they stay stacked over
+    layers and are indexed where they are read.  ``first`` layers of
+    another kind precede them in the model: ``li`` is the layer's place in
+    the MODEL (its page pool's), the experts are indexed by its place among
+    the routed.  ``feed_forward(p, h)`` is the layer's, for ``llama.layer``:
+    ``routed_mlp`` as the parameters and ``cfg`` have it (a layer with a
+    ``router_bias`` scores by sigmoid and chooses by score + bias; one with
+    ``shared`` adds its shared expert).  Returns (carry, experts read,
+    summed over the layers)."""
+    stacked = dict(layers)
+    experts = stacked.pop("experts")
+    n_routed = experts["w_gate"].shape[0]
+
+    def step(carry_hit, per_layer):
+        carry, hit = carry_hit
+        p, i = per_layer
+        hits = []  # what this layer's feed-forward read, once it has run
+
+        def routed(p, h):
+            out, n = routed_mlp(
+                h, p["router"], experts, i, top_k=cfg.experts_per_token,
+                renormalise=cfg.norm_topk_prob, bias=p.get("router_bias"),
+                scale=getattr(cfg, "routed_scaling_factor", None),
+                shared=p.get("shared"))
+            hits.append(n)
+            return out
+
+        carry = body(carry, p, first + i if first else i, routed)
+        return (carry, hit + sum(hits)), None
+
+    with jax.named_scope("layers"):
+        (carry, hit), _ = jax.lax.scan(
+            step, (carry, jnp.int32(0)),
+            (stacked, jnp.arange(n_routed, dtype=jnp.int32)))
+    return carry, hit
 
 
 def dispatch(hf, weights, chosen, experts, layer):

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import embed, head, layer
-from ray_tpu.models.moe import routed_mlp
+from ray_tpu.models.moe import scan_routed_layers
 
 STRATEGIES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
@@ -132,33 +132,10 @@ def init(cfg: SDARMoEConfig, key: jax.Array, dtype=jnp.float32):
 
 def scan_layers(cfg, params, body, carry):
     """``lax.scan`` of ``body(carry, layer_params, li, feed_forward)`` over
-    the layers, the experts held out of what the scan slices: they stay
-    stacked over layers and are indexed where they are read.
-    ``feed_forward(p, h)`` is the layer's, for ``llama.layer``.  Returns
-    (carry, experts read, summed over the layers)."""
-    stacked = dict(params["layers"])
-    experts = stacked.pop("experts")
-
-    def step(carry_hit, per_layer):
-        carry, hit = carry_hit
-        p, li = per_layer
-        hits = []  # what this layer's feed-forward read, once it has run
-
-        def routed(p, h):
-            out, n = routed_mlp(h, p["router"], experts, li,
-                                top_k=cfg.experts_per_token,
-                                renormalise=cfg.norm_topk_prob)
-            hits.append(n)
-            return out
-
-        carry = body(carry, p, li, routed)
-        return (carry, hit + sum(hits)), None
-
-    with jax.named_scope("layers"):
-        (carry, hit), _ = jax.lax.scan(
-            step, (carry, jnp.int32(0)),
-            (stacked, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    return carry, hit
+    the layers, every one routed (``moe.scan_routed_layers``: the experts
+    held out of what the scan slices).  Returns (carry, experts read,
+    summed over the layers)."""
+    return scan_routed_layers(cfg, params["layers"], body, carry)
 
 
 def block_causal(qpos, kpos, block_length: int):

@@ -1,5 +1,8 @@
 """Paged decode attention for TPU: one query token a slot, read from the
-page pool where it lies.
+page pool where it lies.  Two kernels: ``paged_decode_attention`` over K and
+V pools (the first part of this file and of this text) and, at the end,
+``paged_latent_decode_attention`` over ONE pool of latent rows that are key
+and value both (models/glm_moe_lite.py), the same walk with one copy a page.
 
 The decode step's attention used to gather every slot's whole page table
 into a dense ``[B, P * page_size, n_kv_heads, d]`` K and V, repeat both to
@@ -220,3 +223,180 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     return _paged_decode(q.astype(k_pool.dtype), k_pool, v_pool, page_tables,
                          lengths, layer, pages_per_block=pages_per_block,
                          interpret=not on_tpu).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent pages (models/glm_moe_lite.py): one pool of rows, each read twice.
+
+# tokens a compute block of the latent kernel holds: a latent page is one
+# contiguous page_size x width run (20 KB at 16 x 640 bf16), a fifth of a
+# K/V block's bytes a token, so a block takes twice the tokens
+LATENT_BLOCK_TOKENS = 512
+
+
+def _paged_latent_kernel(lengths_ref, tables_ref, layer_ref, q_ref, pool,
+                         o_ref, buf, sems, *, sm_scale: float):
+    """``_paged_decode_kernel`` over ONE pool whose row is key and value
+    both: every query head scores against the whole row (its zero tail
+    meets the query's), and the weighted sum is taken over the row's first
+    ``o_ref.shape[-1]`` values.  No KV heads, so no column of a block
+    belongs to another head and nothing but the length is masked."""
+    B, H, _ = q_ref.shape
+    _, ppb, ps, W = buf.shape
+    V = o_ref.shape[-1]
+    P = tables_ref.shape[0] // B
+    block_tokens = ppb * ps
+    layer = layer_ref[0]
+
+    def block_copies(b, blk, which, wait: bool):
+        n_pages = (lengths_ref[b] + ps - 1) // ps
+        for i in range(ppb):
+            pg = blk * ppb + i
+
+            @pl.when(pg < n_pages)
+            def _():
+                page = 0 if wait else tables_ref[b * P + pg]
+                copy = pltpu.make_async_copy(
+                    pool.at[layer, page], buf.at[which, i], sems.at[which])
+                copy.wait() if wait else copy.start()
+
+    def next_active(b):
+        return jax.lax.while_loop(
+            lambda n: (n < B) & (lengths_ref[jnp.minimum(n, B - 1)] == 0),
+            lambda n: n + 1, b + 1)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, block_tokens), 1)
+    o_ref[...] = jnp.zeros_like(o_ref)
+    # rows past a block's last copied page are masked out of the scores but
+    # still multiplied by p == 0: they have to be finite
+    buf[...] = jnp.zeros_like(buf)
+    first = next_active(-1)
+
+    @pl.when(first < B)
+    def _():
+        block_copies(first, 0, 0, wait=False)
+
+    def slot(carry):
+        b, which = carry
+        length = lengths_ref[b]
+        n_blocks = (length + block_tokens - 1) // block_tokens
+        nxt = next_active(b)
+        q = q_ref[b]
+
+        def block(i, carry):
+            m, l, acc, which = carry
+            more = i + 1 < n_blocks
+
+            @pl.when(more | (nxt < B))
+            def _():
+                block_copies(jnp.where(more, b, jnp.minimum(nxt, B - 1)),
+                             jnp.where(more, i + 1, 0), 1 - which,
+                             wait=False)
+
+            block_copies(b, i, which, wait=True)
+            rows = buf[which].reshape(block_tokens, W)
+            s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(col < length - i * block_tokens, s * sm_scale,
+                          NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)  # masked columns: exactly 0
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :V], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - which
+
+        _, l, acc, which = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.full((H, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, V), jnp.float32), which))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return nxt, which
+
+    jax.lax.while_loop(lambda c: c[0] < B, slot, (first, 0))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_dim", "sm_scale", "pages_per_block", "interpret"))
+def _paged_latent(q, pool, page_tables, lengths, layer, *, value_dim: int,
+                  sm_scale: float, pages_per_block: int, interpret: bool):
+    B, H, _ = q.shape
+    _, _, ps, W = pool.shape
+    P = page_tables.shape[1]
+    ppb = min(pages_per_block, P)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),
+            in_specs=[vmem, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, ps, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),  # a buffer each
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
+        interpret=interpret,
+        name="paged_latent_decode_attention",
+    )(jnp.minimum(lengths, P * ps).astype(jnp.int32),
+      page_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+
+
+def paged_latent_decode_attention(
+        q: jax.Array, pool: jax.Array, page_tables: jax.Array,
+        lengths: jax.Array, layer, *, value_dim: int, sm_scale: float,
+        pages_per_block: int | None = None) -> jax.Array:
+    """Absorbed latent attention of one query token a slot over that slot's
+    cached LATENT rows: each row is walked once and used twice.
+
+    q: [B, H, W], the new token's absorbed query heads (``[q_nope W_uk |
+    q_rope]``, zeros to the pool's width).  pool: [n_layers, num_pages,
+    page_size, W], the whole latent pool (rows ``[c_kv | k_rope | 0]``);
+    only ``layer`` is read.  page_tables [B, P], lengths [B] and ``layer``
+    as ``paged_decode_attention`` takes them; a slot of length 0 is skipped
+    and its output row is zeros.  The new token's own row must already be
+    in the pool.  Scores are ``sm_scale * q . row`` over the whole width;
+    the value of a row is its first ``value_dim`` entries.  Returns [B, H,
+    value_dim] in q's dtype (attention's weighted sum of ``c_kv``, still to
+    go through ``W_uv``); operands go to the MXU in the pool's dtype, scores
+    and the softmax state are float32.  The heads are padded here to whole
+    sublane tiles of the pool's dtype (20 -> 32 in bf16).
+    """
+    if q.ndim != 3 or pool.ndim != 4 or q.shape[2] != pool.shape[3]:
+        raise ValueError(
+            f"paged latent decode attention takes q [B, H, W] and one pool "
+            f"[layers, pages, page_size, W] of the same width; got "
+            f"{q.shape}, {pool.shape}")
+    B, H, W = q.shape
+    ps = pool.shape[2]
+    if not 0 < value_dim <= W:
+        raise ValueError(
+            f"a row's value is its first value_dim entries: {value_dim} is "
+            f"not in 1..{W}")
+    if page_tables.shape[0] != B or lengths.shape != (B,):
+        raise ValueError(
+            f"page_tables {page_tables.shape} and lengths {lengths.shape} "
+            f"must lead with q's batch ({B})")
+    on_tpu = jax.default_backend() == "tpu"
+    sublanes = 32 // jnp.dtype(pool.dtype).itemsize
+    if on_tpu and (W % 128 or value_dim % 128 or ps % sublanes):
+        raise ValueError(
+            f"on the TPU the paged latent kernel copies whole pages and "
+            f"slices a row's value on a lane tile: a [{ps}, {W}] "
+            f"{jnp.dtype(pool.dtype).name} page whose value is {value_dim} "
+            f"wide is not made of whole tiles (width and value_dim "
+            f"multiples of 128, page_size of {sublanes})")
+    if pages_per_block is None:
+        pages_per_block = max(1, LATENT_BLOCK_TOKENS // ps)
+    padded = jnp.pad(q.astype(pool.dtype),
+                     ((0, 0), (0, -H % sublanes), (0, 0)))
+    out = _paged_latent(padded, pool, page_tables, lengths, layer,
+                        value_dim=value_dim, sm_scale=float(sm_scale),
+                        pages_per_block=pages_per_block,
+                        interpret=not on_tpu)
+    return out[:, :H].astype(q.dtype)

@@ -56,6 +56,18 @@ def tiny_hybrid_loader():
     return olmo_hybrid.init(cfg, jax.random.PRNGKey(7)), cfg
 
 
+def tiny_latent_loader():
+    """Latent attention over a latent page pool, routed experts beside a
+    shared one after a leading dense layer (models/glm_moe_lite.py), over
+    the byte tokenizer's vocabulary."""
+    import jax
+
+    from ray_tpu.models import glm_moe_lite
+
+    cfg = glm_moe_lite.GLMMoELiteConfig.tiny(259)
+    return glm_moe_lite.init(cfg, jax.random.PRNGKey(7)), cfg
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _cluster(ray_cluster):
     # join the session cluster (conftest.ray_cluster owns the
@@ -173,8 +185,58 @@ def test_hybrid_model_answers_over_http():
     serve.delete("hybrid")
 
 
+def test_latent_model_answers_over_http():
+    """The same path once more, chosen by what the model says it caches:
+    build_openai_app -> serve.run -> HTTP -> LLMServer -> LLMEngine over ONE
+    pool of latent rows; the answer streams, the same prompt asked again
+    (twice more) is a PREFIX HIT (latent pages are pages)
+    and answers the same, sampling works as for any token-at-a-time model,
+    and the engine's counters say what its steps read."""
+    import json
+
+    app = build_openai_app(LLMConfig(
+        model_id="tiny-latent", model_loader=tiny_latent_loader,
+        engine_config=EngineConfig(max_slots=4, num_pages=128, page_size=8,
+                                   max_seq_len=256,
+                                   prefill_buckets=(32, 64, 128)),
+        default_max_tokens=8))
+    serve.run(app, name="latent", route_prefix="/latent",
+              _blocking_timeout_s=120)
+    base = f"http://127.0.0.1:{serve.http_port()}/latent/v1"
+    body = {"prompt": list(range(7, 57)), "max_tokens": 12,
+            "ignore_eos": True}
+    whole = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert whole["usage"]["completion_tokens"] == 12, whole
+    events = []
+    with requests.post(f"{base}/completions", json={**body, "stream": True},
+                       stream=True, timeout=300) as r:
+        for line in r.iter_lines():
+            if line.startswith(b"data: ") and line != b"data: [DONE]":
+                events.append(json.loads(line[6:]))
+    # (not compared as text: the byte tokenizer's multi-byte sequences that
+    # a chunk's end splits decode to other characters than the whole does)
+    assert events and all(e["choices"][0]["text"] is not None for e in events)
+    again = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert again["choices"][0]["text"] == whole["choices"][0]["text"]
+    sampled = requests.post(f"{base}/completions",
+                            json={**body, "temperature": 0.7},
+                            timeout=300).json()
+    assert sampled["usage"]["completion_tokens"] == 12, sampled
+    from ray_tpu.serve.handle import DeploymentHandle
+
+    stats = DeploymentHandle(
+        "latent", "LLMServer:tiny-latent").engine_stats.remote().result(
+            timeout_s=60)
+    assert stats["prefill_tokens_saved"] >= 3 * 48  # six pages of eight
+    assert stats["latent_pages_read"] == stats["decode_pages_read"] > 0
+    assert stats["experts_read"] > 0
+    serve.delete("latent")
+
+
 @pytest.mark.parametrize("loader", [tiny_loader, tiny_sdar_loader,
-                                    tiny_hybrid_loader])
+                                    tiny_hybrid_loader, tiny_latent_loader])
 def test_batch_processor_over_dataset(loader):
     from ray_tpu import data as rd
 

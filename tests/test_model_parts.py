@@ -24,7 +24,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
-from ray_tpu.models import llama, moe, olmo_hybrid, sdar_moe
+from ray_tpu.models import glm_moe_lite, llama, moe, olmo_hybrid, sdar_moe
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -34,6 +34,7 @@ FLASH = ("flash_attention_fwd", "flash_attention_bwd_dkv",
          "flash_attention_bwd_dq")
 KERNELS = {**dict.fromkeys(FLASH, "attn/attend"),
            "paged_decode_attention": "attn/attend",
+           "paged_latent_decode_attention": "mla/attend",
            "moe_grouped_mlp": "moe/experts",
            "gated_delta_update": "lin_attn/state"}
 BLOCK = ("embed", "layers", "attn/norm", "attn/qkv", "attn/rope",
@@ -42,6 +43,14 @@ DENSE = BLOCK + ("mlp/gate_up", "mlp/down")
 ROUTED = BLOCK + ("moe/route", "moe/dispatch", "moe/experts", "moe/combine")
 HYBRID = DENSE + ("lin_attn/proj", "lin_attn/conv", "lin_attn/gates",
                   "lin_attn/state", "lin_attn/out")
+# latent attention: a leading dense layer, then routed ones with a shared
+# expert; no ``attn/qkv`` (the projections are ``mla/*``); both prefills
+# rebuild K and V (``mla/kv_up``) and attend densely, the decode step absorbs
+LATENT = (("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
+           "attn/out", "mlp/norm", "mlp/gate_up", "mlp/down", "mla/kv_down",
+           "mla/q_proj", "head") + ROUTED[-4:] + ("moe/shared",))
+LATENT_PREFILL = LATENT + ("mla/kv_up", "attn/attend")  # a head a head:
+# the repeat of K and V to the query heads is by one and leaves no operation
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -137,6 +146,18 @@ def _hybrid_decode():
     return fn, (params, tokens, ck, cv, *rest), cfg, {"state": state}
 
 
+def _latent(program):
+    """``program`` of the dense tree over a latent pool and no V pool."""
+    cfg = glm_moe_lite.GLMMoELiteConfig.tiny()
+    params = lm.serving_layout(glm_moe_lite.init(cfg, jax.random.PRNGKey(0)))
+    pool, none = init_cache(CacheConfig(
+        **lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+        dtype="float32"))
+    assert none is None
+    fn, (_, tokens, _, _, *rest), _ = program()
+    return fn, (params, tokens, pool, None, *rest), cfg
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -186,7 +207,10 @@ def _compiled_text(name):
 ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "decode_step_greedy": _decode, "block_step": _block_step,
           "hybrid_prefill": _hybrid_prefill,
-          "hybrid_decode_step_greedy": _hybrid_decode}
+          "hybrid_decode_step_greedy": _hybrid_decode,
+          "latent_prefill": lambda: _latent(_prefill),
+          "latent_prefill_with_prefix": lambda: _latent(_prefill_with_prefix),
+          "latent_decode_step_greedy": lambda: _latent(_decode)}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
          "llama_grad": lambda: _llama_grad(False),
          "moe_grad": _moe_grad}
@@ -200,6 +224,10 @@ EXPECTED = {
     "hybrid_prefill": HYBRID + ("attn/kv_write", "attn/attend/repeat_kv",
                                 "head"),
     "hybrid_decode_step_greedy": HYBRID + ("attn/kv_write", "head", "sample"),
+    "latent_prefill": LATENT_PREFILL,
+    "latent_prefill_with_prefix": LATENT_PREFILL,
+    "latent_decode_step_greedy": LATENT + ("mla/absorb", "mla/attend",
+                                           "mla/unabsorb", "sample"),
     "llama_grad_remat": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "llama_grad": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "moe_grad": ROUTED + ("attn/attend/repeat_kv", "head", "loss"),
@@ -236,6 +264,9 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
               "block_step": {"paged_decode_attention", "moe_grouped_mlp"},
               "hybrid_decode_step_greedy": {"paged_decode_attention",
                                             "gated_delta_update"},
+              "latent_prefill": {"moe_grouped_mlp"},
+              "latent_decode_step_greedy": {"paged_latent_decode_attention",
+                                            "moe_grouped_mlp"},
               "llama_grad": set(FLASH),
               "train_step": set(FLASH)}.get(name, set())
     assert wanted <= seen

@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models import llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
@@ -390,6 +390,88 @@ def test_hybrid_programs_compile_at_olmo_widths(topo, as_tpu, program):
     planned = _footprint(compiled)
     assert planned <= 0.85 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - HYBRID_PLANNED_GB[program]) < 0.05
+
+
+# planned bytes a program of configuration ``glm47_flash_serve_1chip``,
+# compiled for the described v5e here (PERF.md section 4): weights 10.33 GB
+# (serving layout), the ONE latent pool 2.01 GB
+LATENT_PLANNED_GB = {"decode_step_greedy": 12.347, 64: 12.348, 2048: 12.541,
+                     "prefix_64": 12.348, "prefix_2048": 12.731}
+
+
+@pytest.mark.parametrize("program", ["decode_step_greedy", 64, 2048,
+                                     "prefix_64", "prefix_2048"])
+def test_latent_programs_compile_at_glm_widths(topo, as_tpu, program):
+    """``decode_step_greedy`` (64 slots, 256-page tables), two prefill
+    buckets and two of ``prefill_with_prefix`` (4,096-token tables) of
+    GLM-4.7-Flash at published widths and 1 dense + 7 sparse layers, over
+    the cell's 12,288 pages of latent rows: each plans between 0.60 and 0.85
+    of the chip's bytes_limit; the ONE pool is aliased to the output and
+    held once, there is no V pool of any size; the routed experts go through
+    the grouped kernel and stay where they lie; the decode step attends
+    through the latent kernel (its pages of 16 x 640 bf16 are whole tiles)
+    and never rebuilds K or V, the prefills never call it."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = glm_moe_lite.GLMMoELiteConfig(n_layers=8, max_seq_len=4096)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shapes = jax.eval_shape(
+        lambda k: glm_moe_lite.init(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    params = _on(one, jax.eval_shape(lm.serving_layout, shapes))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert abs(weights / 1e9 - 10.332) < 0.001
+    layout = lm.cache_layout(cfg)
+    pool = sds((layout["n_layers"], 12288, 16, layout["latent_dim"]),
+               jnp.bfloat16)
+    assert pool.shape == (8, 12288, 16, 640)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step_greedy":
+        compiled = lm.decode_step_greedy.lower(
+            params, i32(64), pool, None, i32(64, 256), i32(64),
+            sds((64,), jnp.bool_), cfg).compile()
+    elif isinstance(program, int):
+        compiled = lm.prefill.lower(
+            params, i32(program), pool, None, i32(program), i32(),
+            i32(program), cfg).compile()
+    else:
+        L = int(program.split("_")[1])
+        compiled = lm.prefill_with_prefix.lower(
+            params, i32(L), pool, None, i32(L), i32(), i32(L), i32(256),
+            i32(L), cfg).compile()
+    text = compiled.as_text()
+    assert ("paged_latent_decode_attention" in text) == (
+        program == "decode_step_greedy")
+    assert "paged_decode_attention" not in text
+    assert "moe_grouped_mlp" in text
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and r.startswith((
+        "bf16[8,12288,16,640]", "bf16[7,64,", "bf16[64,2048,1536]",
+        "bf16[64,1536,2048]"))]
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 8 * 12288 * 16 * 640 * 2
+    assert m.temp_size_in_bytes < 0.45e9  # prefix_2048 plans 0.38 GB
+    planned = _footprint(compiled)
+    assert 0.60 * V5E_BYTES_LIMIT <= planned <= 0.85 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - LATENT_PLANNED_GB[program]) < 0.05
+
+
+def test_latent_kernel_refuses_pages_that_are_no_whole_tiles(as_tpu):
+    """What the chip's compiler would turn down is refused by name before
+    it: a 576-wide row, a value that ends inside a lane tile, 8-token pages
+    of bf16."""
+    from ray_tpu.ops.paged_attention import paged_latent_decode_attention
+
+    def call(width, value_dim, page_size):
+        return paged_latent_decode_attention(
+            jnp.zeros((4, 20, width), jnp.bfloat16),
+            jnp.zeros((2, 8, page_size, width), jnp.bfloat16),
+            jnp.zeros((4, 4), jnp.int32), jnp.zeros((4,), jnp.int32), 0,
+            value_dim=value_dim, sm_scale=1.0)
+
+    for shape in ((576, 512, 16), (640, 500, 16), (640, 512, 8)):
+        with pytest.raises(ValueError, match="whole tiles"):
+            call(*shape)
 
 
 def test_state_update_kernel_compiles_and_writes_in_place(topo, as_tpu):
