@@ -177,6 +177,14 @@ PARTS = (
     # layers' shared expert (models/moe.py)
     "mla/kv_down", "mla/q_proj", "mla/kv_up", "mla/absorb", "mla/attend",
     "mla/unabsorb", "moe/shared",
+    # parallel/tp_stream.py: the links of a training trunk whose stream is
+    # split over ``tp`` between the products: a group's rows passed round
+    # the ring into ``attn/qkv`` and ``mlp/gate_up``, the partial sums of
+    # ``attn/out`` and ``mlp/down`` passed home (and backward the reverse:
+    # a scatter inside ``attn/qkv``, a gather inside ``attn/out``); the
+    # slice that splits the stream before the first layer and the gather
+    # that makes it whole after the last
+    "tp/gather", "tp/scatter",
 )
 
 
@@ -237,11 +245,14 @@ def _qk_norm(cfg, x, weight):
     return rms_norm(flat, weight, cfg.norm_eps).reshape(x.shape)
 
 
-def qkv_rope(cfg, p, h, positions):
+def qkv_rope(cfg, p, h, positions, ring=None):
     """The normed stream h (..., d_model) projected and split into heads,
     q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width.
     The layer's parameters say how: three weights (training), or the one
-    ``wqkv`` of ``serving_layout``, whose product is split after."""
+    ``wqkv`` of ``serving_layout``, whose product is split after.  ``ring``
+    (parallel/tp_stream.py): h is a device's rows of a stream split over
+    ``tp``, and q, k, v come back for the whole group's rows at the
+    device's heads."""
     def heads(w, n):
         return (h @ w.astype(h.dtype)).reshape(*h.shape[:-1], n, cfg.head_dim)
 
@@ -253,6 +264,10 @@ def qkv_rope(cfg, p, h, positions):
                        for y in jnp.split(
                            h @ p["attn"]["wqkv"].astype(h.dtype),
                            (nq, nq + nkv), axis=-1))
+        elif ring is not None:
+            q, k, v = (y.reshape(*y.shape[:-1], -1, cfg.head_dim)
+                       for y in ring.into(h, p["attn"]["wq"], p["attn"]["wk"],
+                                          p["attn"]["wv"]))
         else:
             q = heads(p["attn"]["wq"], cfg.n_heads)
             k = heads(p["attn"]["wk"], cfg.n_kv_heads)
@@ -264,23 +279,36 @@ def qkv_rope(cfg, p, h, positions):
             rope(k, positions, cfg.rope_theta), v)
 
 
-def attention_block(cfg, p, x, positions, attend, cache=None):
+def attention_block(cfg, p, x, positions, attend, cache=None, ring=None):
     """x + attention(norm(x)).  ``attend(q, k, v, cache) -> (out, cache)``
     is the one thing that differs between forward passes: training attends
     within the batch and has no cache; the engine's programs write k and v
     into the layer's pages and attend through them.  ``cache`` is whatever
     the caller's layer scan hands its strategy, and comes back with x.
     The strategy names its own parts (``attn/attend``, and ``attn/kv_write``
-    where it has a cache to write)."""
+    where it has a cache to write).  ``ring``: x is a device's rows of a
+    stream split over ``tp`` (``qkv_rope``); the strategy attends over the
+    group's rows at the device's heads."""
     with jax.named_scope("attn/norm"):
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    out, cache = attend(*qkv_rope(cfg, p, h, positions), cache)
+    out, cache = attend(*qkv_rope(cfg, p, h, positions, ring), cache)
     with jax.named_scope("attn/out"):
-        out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim)
+        out = out.reshape(*out.shape[:-2], -1)
+        if ring is not None:
+            return x + ring.back(out, p["attn"]["wo"]), cache
         return x + out @ p["attn"]["wo"].astype(x.dtype), cache
 
 
-def gated_mlp(p, h):
+def gated_mlp(p, h, ring=None):
+    """``ring``: h is a device's rows of a stream split over ``tp``, and so
+    is what comes back; between them the group's rows at the device's
+    columns."""
+    if ring is not None:
+        with jax.named_scope("mlp/gate_up"):
+            gate, up = ring.into(h, p["mlp"]["w_gate"], p["mlp"]["w_up"])
+            gate = jax.nn.silu(gate)
+        with jax.named_scope("mlp/down"):
+            return ring.back(gate * up, p["mlp"]["w_down"])
     with jax.named_scope("mlp/gate_up"):
         gate = jax.nn.silu(h @ p["mlp"]["w_gate"].astype(h.dtype))
         up = h @ p["mlp"]["w_up"].astype(h.dtype)
@@ -315,12 +343,15 @@ def head(params, x, cfg, true_len=None):
 # ---------------------------------------------------------------------------
 # The training forward: attention within the batch, no cache.
 
+_SEQUENCE_PARALLEL = ("ring", "zigzag", "ulysses")
+
+
 def batch_attend(attn_impl, mesh, rules=None):
     """The training passes' ``attend``: dense flash or sequence-parallel
     attention (ring / zigzag-balanced ring / ulysses); no cache."""
     def attend(q, k, v, cache):
         with jax.named_scope("attn/attend"):
-            if attn_impl in ("ring", "zigzag", "ulysses"):
+            if attn_impl in _SEQUENCE_PARALLEL:
                 from ray_tpu.ops.ring_attention import (
                     sequence_parallel_attention)
 
@@ -336,9 +367,27 @@ def batch_attend(attn_impl, mesh, rules=None):
 
 
 def _layer(cfg: LlamaConfig, x, layer_params, positions, attn_impl, mesh,
-           rules):
+           rules, ring=None):
     return layer(cfg, layer_params, x, positions,
-                 batch_attend(attn_impl, mesh, rules))[0]
+                 batch_attend(attn_impl, mesh, rules),
+                 feed_forward=partial(gated_mlp, ring=ring),
+                 attention=partial(attention_block, ring=ring))[0]
+
+
+def _tp_split(cfg: LlamaConfig, batch: int, attn_impl, mesh, rules):
+    """How to split the stream over ``tp`` between a layer's products
+    (parallel/tp_stream.py), or None: no mesh, no ``tp`` axis on it, a
+    sequence-parallel attention, or rows that do not divide."""
+    if mesh is None or attn_impl in _SEQUENCE_PARALLEL:
+        return None
+    from ray_tpu.parallel.tp_stream import stream_split
+
+    return stream_split(
+        mesh, rules, jax.tree.map(lambda spec: spec[1:],
+                                  param_logical_specs(cfg)["layers"],
+                                  is_leaf=lambda s: isinstance(s, tuple)),
+        {"batch": batch, "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
+         "mlp": cfg.d_ff})
 
 
 def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
@@ -349,12 +398,29 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
     optionally rematerialized (jax.checkpoint) to trade FLOPs for HBM.
     attn_impl "ring"/"ulysses" (with a mesh) enables sequence-parallel
     attention over the sp axis for long-context training.
+
+    On a mesh with a ``tp`` axis the stream is split over it between the
+    layers' products (``_tp_split``): each layer runs under one shard_map on
+    a ``tp``-th of a group's rows, and the rows are gathered once, after
+    the final norm.
     """
     x = embed(params, tokens, cfg)
     positions = jnp.arange(tokens.shape[1])[None, :]
 
-    step = partial(_layer, cfg, positions=positions, attn_impl=attn_impl,
-                   mesh=mesh, rules=rules)
+    split = _tp_split(cfg, tokens.shape[0], attn_impl, mesh, rules)
+    if split is None:
+        step = partial(_layer, cfg, positions=positions, attn_impl=attn_impl,
+                       mesh=mesh, rules=rules)
+    else:
+        # the lookup and its gradient's scatter-add keep the layout they
+        # had (a group's rows whole): the split is a slice of it, and its
+        # backward one gather a step
+        with jax.named_scope("tp/scatter"):
+            x = split.split_rows(split.whole_rows(x))
+        step = split.layer(
+            lambda ring, x, p, positions: _layer(
+                cfg, x, p, positions, attn_impl, None, None, ring),
+            jnp.dtype(cfg.dtype), positions)
     if cfg.remat:
         step = jax.checkpoint(step)
 
@@ -364,7 +430,11 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
     with jax.named_scope("layers"):
         x, _ = jax.lax.scan(scan_body, x, params["layers"])
     with jax.named_scope("head"):  # the final norm is the head's
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if split is None:
+        return x
+    with jax.named_scope("tp/gather"):
+        return split.whole_rows(x)
 
 
 def apply(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",

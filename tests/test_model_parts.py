@@ -178,17 +178,22 @@ def _moe_grad():
     return _grad(moe, moe.MoEConfig.tiny())
 
 
-def _train_step():
+def _train_step(batch=2, **axes):
+    """The step on one device, or on the mesh ``axes`` name: with a ``tp``
+    axis that divides the rows the stream is split over it between the
+    products (parallel/tp_stream.py) and the links have names."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(), remat=True,
                               loss_chunk=16)
-    mesh = create_mesh(MeshConfig(fsdp=1), devices=jax.devices()[:1])
+    mesh = create_mesh(MeshConfig(**{"fsdp": 1, **axes}),
+                       devices=jax.devices()[:int(np.prod([*axes.values(),
+                                                           1]))])
     opt = default_optimizer()
     with mesh:
         state = create_train_state(llama, cfg, mesh, opt,
                                    jax.random.PRNGKey(0))
         step = make_train_step(llama, cfg, mesh, opt, attn_impl="pallas",
                                donate=False)
-    return step, (state, jnp.zeros((2, 33), jnp.int32)), mesh
+    return step, (state, jnp.zeros((batch, 33), jnp.int32)), mesh
 
 
 def _compiled_text(name):
@@ -196,8 +201,8 @@ def _compiled_text(name):
     if name in ENGINE:
         fn, args, cfg, *rows = ENGINE[name]()  # rows: a hybrid's state
         return fn.lower(*args, cfg=cfg, **dict(*rows)).compile().as_text()
-    if name == "train_step":
-        step, args, mesh = _train_step()
+    if name in TRAIN:
+        step, args, mesh = _train_step(**TRAIN[name])
         with mesh:
             return step.lower(*args).compile().as_text()
     fn, args = GRADS[name]()
@@ -211,6 +216,8 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "latent_prefill": lambda: _latent(_prefill),
           "latent_prefill_with_prefix": lambda: _latent(_prefill_with_prefix),
           "latent_decode_step_greedy": lambda: _latent(_decode)}
+TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
+    "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
          "llama_grad": lambda: _llama_grad(False),
          "moe_grad": _moe_grad}
@@ -232,6 +239,8 @@ EXPECTED = {
     "llama_grad": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "moe_grad": ROUTED + ("attn/attend/repeat_kv", "head", "loss"),
     "train_step": DENSE + ("attn/attend/repeat_kv", "head", "loss", "optim"),
+    "train_step_fsdp2_tp2": DENSE + ("attn/attend/repeat_kv", "head", "loss",
+                                     "optim", "tp/gather", "tp/scatter"),
 }
 _TEXTS = {}
 
@@ -268,7 +277,7 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
               "latent_decode_step_greedy": {"paged_latent_decode_attention",
                                             "moe_grouped_mlp"},
               "llama_grad": set(FLASH),
-              "train_step": set(FLASH)}.get(name, set())
+              **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
     assert wanted <= seen
 
 
@@ -285,7 +294,8 @@ def test_every_part_is_some_programs():
 
 
 @pytest.mark.parametrize("name, recomputes", [
-    ("llama_grad_remat", True), ("llama_grad", False), ("train_step", True)])
+    ("llama_grad_remat", True), ("llama_grad", False), ("train_step", True),
+    ("train_step_fsdp2_tp2", True)])
 def test_remat_recomputes_under_the_layers_parts(name, recomputes):
     phases = {}
     for n in _op_names(name):

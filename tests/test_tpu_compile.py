@@ -591,19 +591,85 @@ def _assert_loss_keeps_the_head_still(text, d_model, vocab, fsdp=2, tp=2):
     assert (gathers, reductions) == (1, 1), (gathers, reductions)
 
 
-def test_cell_step_fits_and_its_loss_keeps_the_head_still(topo, as_tpu):
+_CELL_STEP = []  # compiled once (half a minute) for the two tests below
+
+
+def _cell_step(topo):
     """``train_fsdp2_tp2``'s step (``mistral7b_train_4chip.json``: 7 layers
-    at Mistral-7B's widths, 8 x 4,096 tokens, fsdp=2 x tp=2, flash kernel):
-    plans at most 0.85 of the chip's bytes_limit, as the runner demands,
-    and the loss ships no logits, no gradient of them and no head inside
-    its scan (PR 40: the partitioner's own layout gathered the head twice
-    a chunk and reduce-scattered a float32 head gradient a chunk)."""
-    cfg = dataclasses.replace(_cell_llama(7), max_seq_len=32768, remat=True,
-                              loss_chunk=256)
-    compiled = _train_step(llama, cfg, _fsdp2_tp2(topo.devices), batch=8,
-                           seq=4095, attn_impl="flash")
+    at Mistral-7B's widths, 8 x 4,096 tokens, fsdp=2 x tp=2, flash
+    kernel), compiled for the described chips."""
+    if not _CELL_STEP:
+        cfg = dataclasses.replace(_cell_llama(7), max_seq_len=32768,
+                                  remat=True, loss_chunk=256)
+        _CELL_STEP.append(_train_step(llama, cfg, _fsdp2_tp2(topo.devices),
+                                      batch=8, seq=4095, attn_impl="flash"))
+    return _CELL_STEP[0]
+
+
+def test_cell_step_fits_and_its_loss_keeps_the_head_still(topo, as_tpu):
+    """The cell's step plans at most 0.85 of the chip's bytes_limit, as the
+    runner demands, and the loss ships no logits, no gradient of them and
+    no head inside its scan (PR 40: the partitioner's own layout gathered
+    the head twice a chunk and reduce-scattered a float32 head gradient a
+    chunk)."""
+    compiled = _cell_step(topo)
     assert _footprint(compiled) <= 0.85 * V5E_BYTES_LIMIT
     _assert_loss_keeps_the_head_still(compiled.as_text(), 4096, 32768)
+
+
+def test_cell_step_passes_its_stream_round_the_ring_beside_products(
+        topo, as_tpu):
+    """The layers' links in the cell's step as the chip's compiler
+    SCHEDULES them (PR 43: five all-reduces of the whole stream a layer,
+    each with nothing beside it, were 12 % of the step).  Inside the layer
+    scans no all-reduce carries the stream; a layer passes a device's rows
+    [2, 4095, 4096] round the ``tp`` ring eleven times (forward 2 gathers +
+    2 scatters, recompute 2 + 1, backward 2 + 2), and between every
+    pass's start and its done lies a product of the layer: no link waits
+    alone."""
+    rows, stream = "bf16[2,4095,4096]", re.compile(r"\[[24],4095,4096\]")
+    passes, open_ = {}, {}
+    for line in _scheduled_lines(_cell_step(topo).as_text()):
+        name = re.search(r'op_name="([^"]*)"', line)
+        path = name.group(1) if name else ""
+        if "layers" not in path or "while/body" not in path:
+            continue
+        found = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
+                         line)
+        if not found:
+            continue
+        result, shape, op = found.groups()
+        if op in ("all-reduce", "all-reduce-start"):
+            assert not stream.search(shape), line[:300]
+        elif op == "collective-permute-start" and rows in shape:
+            part = next(p for p in ("tp/gather", "tp/scatter") if p in path)
+            phase = ("recompute" if "rematted_computation" in path else
+                     "bwd" if "transpose(" in path else "fwd")
+            passes[part, phase] = passes.get((part, phase), 0) + 1
+            open_[result.replace("start", "done")] = []
+        elif op == "collective-permute-done":
+            beside = open_.pop(result, None)
+            assert beside is None or beside, (
+                f"{result}: no product between start and done")
+        elif "dot_general" in path and op in ("fusion", "convolution"):
+            for beside in open_.values():
+                beside.append(result)
+    assert not open_
+    assert passes == {("tp/gather", "fwd"): 2, ("tp/scatter", "fwd"): 2,
+                      ("tp/gather", "recompute"): 2,
+                      ("tp/scatter", "recompute"): 1,
+                      ("tp/gather", "bwd"): 2, ("tp/scatter", "bwd"): 2}
+
+
+def _scheduled_lines(text):
+    """The instructions of a compiled module's NON-fused computations, in
+    schedule order (a fusion's body has no schedule of its own)."""
+    keep = True
+    for line in text.splitlines():
+        if line and not line.startswith(" "):  # a computation's header
+            keep = not line.lstrip("%").startswith(("fused_", "async_"))
+        elif keep:
+            yield line
 
 
 def test_loss_keeps_the_head_still_on_four_host_devices():
