@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import FLASH_RESIDUALS, flash_attention
 from ray_tpu.parallel.sharding import logical_spec as L
 
 
@@ -39,7 +39,10 @@ class LlamaConfig:
     rope_theta: float = 500_000.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
-    remat: bool = True  # checkpoint each layer: recompute activations in bwd
+    # checkpoint each layer: the backward pass makes a layer's activations
+    # again from its input, but for the flash kernel's output and row
+    # statistics, which are kept (``REMAT_KEEPS``)
+    remat: bool = True
     # sequence-chunked cross-entropy (models/losses.py): avoids the
     # (batch, seq, vocab) fp32 logits tensor; 0 disables chunking
     loss_chunk: int = 256
@@ -343,6 +346,20 @@ def head(params, x, cfg, true_len=None):
 # ---------------------------------------------------------------------------
 # The training forward: attention within the batch, no cache.
 
+# What a remat'd layer keeps for its backward pass BESIDE the carry, by
+# ``checkpoint_name``; everything else is made again from the carry.  The
+# flash kernel's output and row statistics (68 MB a layer a device in the
+# benchmark's train cell, against the carry's 67): its second run was the
+# dearest recompute a kept byte (22 ms of an 836 ms step for 0.48 GB; the
+# MLP's ``gate`` and ``up`` cost 72 ms for 3.3 GB).  The set is fixed, one
+# for every mesh: it grows with what was kept already, and a trunk whose
+# attention is not the kernel (``impl="xla"``, the sequence-parallel
+# forms) names nothing and keeps the carry alone, as before.  q, k, v
+# after rope and the stream after attention were tried beside these and
+# dropped: the cell's step then plans over 0.85 of a v5e's memory
+# (PERF.md section 6, PR 44).
+REMAT_KEEPS = FLASH_RESIDUALS
+
 _SEQUENCE_PARALLEL = ("ring", "zigzag", "ulysses")
 
 
@@ -394,8 +411,12 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
           mesh=None, rules=None):
     """Embeddings -> final RMS norm, WITHOUT the LM head: (b, s, d).
 
-    Layers run under lax.scan over the stacked layer params; each step is
-    optionally rematerialized (jax.checkpoint) to trade FLOPs for HBM.
+    Layers run under lax.scan over the stacked layer params.  With
+    ``cfg.remat`` each step is a ``jax.checkpoint`` whose policy keeps the
+    layer's input (the carry) and ``REMAT_KEEPS``, the flash kernel's output
+    and row statistics, and makes everything else again in the backward
+    pass: the products and the norms are cheap for their bytes, the kernel
+    is not, so it runs once a layer a step.
     attn_impl "ring"/"ulysses" (with a mesh) enables sequence-parallel
     attention over the sp axis for long-context training.
 
@@ -422,7 +443,9 @@ def trunk(params, tokens, cfg: LlamaConfig, attn_impl: str = "auto",
                 cfg, x, p, positions, attn_impl, None, None, ring),
             jnp.dtype(cfg.dtype), positions)
     if cfg.remat:
-        step = jax.checkpoint(step)
+        step = jax.checkpoint(
+            step, policy=jax.checkpoint_policies.save_only_these_names(
+                *REMAT_KEEPS))
 
     def scan_body(x, layer_params):
         return step(x, layer_params), None

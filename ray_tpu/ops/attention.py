@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
@@ -409,10 +410,23 @@ def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
     return out
 
 
+# What the forward kernel alone can make, by name (``checkpoint_name``:
+# nothing in the lowered program).  A ``jax.checkpoint`` whose policy saves
+# these never runs the kernel a second time; q, k and v, packed, padded and
+# repeated to the query heads, are cheap to make again from what the caller
+# keeps (models/llama.py ``REMAT_KEEPS``).
+FLASH_RESIDUALS = ("flash/out", "flash/lse")
+
+
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, interpret):
     out, lse = _flash_forward(q, k, v, causal=causal, sm_scale=sm_scale,
                               block_q=block_q, block_k=block_k,
                               kv_len=kv_len, interpret=interpret)
+    # named HERE, so that the primal output and the residual are the one
+    # value: a name on ``attention``'s result would keep a copy and still
+    # run the kernel again for ``lse``
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 

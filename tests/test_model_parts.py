@@ -3,7 +3,8 @@
 At tiny sizes on the CPU, each program's COMPILED text is parsed for
 ``op_name``: every product (``dot_general``) and every kernel lies under
 exactly one part, every part the program is made of occurs in it, a
-gradient under ``remat`` recomputes under ``attn/*`` and ``mlp/*`` and one
+gradient under ``remat`` recomputes under ``attn/*`` and ``mlp/*`` (all of a
+layer but the flash kernel's forward run, whose results are kept) and one
 without recomputes nothing, and a program's outputs equal, bit for bit,
 those of the same function traced with ``jax.named_scope`` a no-op.  The
 paths are split by the benchmark's own reader
@@ -308,8 +309,18 @@ def test_remat_recomputes_under_the_layers_parts(name, recomputes):
         # layers' remat
         assert again <= {"loss"}, again
         return
-    assert {"attn/qkv", "attn/attend", "mlp/gate_up"} <= again, again
+    # what is still made again from a layer's input: the norms, q, k, v and
+    # their rotation, K and V repeated, packed and padded for the backward
+    # kernels (``attn/attend``: no product and no kernel of it), the
+    # attention output and the MLP's first two products
+    assert {"attn/norm", "attn/qkv", "attn/rope", "attn/attend", "attn/out",
+            "mlp/norm", "mlp/gate_up"} <= again, again
     assert "optim" not in again and "embed" not in again
+    # the forward kernel's output and row statistics are KEPT
+    # (``llama.REMAT_KEEPS``), so it runs once a layer, in the forward scan
+    kernel_runs = {split_path(n, PARTS)[1] for n in _op_names(name)
+                   if "flash_attention_fwd" in re.split(r"[/()]", n)}
+    assert kernel_runs == {"fwd"}, kernel_runs
 
 
 @pytest.mark.parametrize("name", sorted(ENGINE) + ["llama_grad_remat"])
