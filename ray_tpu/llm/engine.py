@@ -428,56 +428,49 @@ class _Slot:
 class LLMEngine:
     """Single-process engine; wrap in an actor for serving (server.py).
 
-    The engine asks llm/model.py what a model of ``model_cfg`` caches
-    (``lm.cache_layout``) and allocates that: page pools for the layers
-    that attend and, for a model with recurrent layers, state rows a slot
-    beside them.  Of ``model_cfg`` itself it reads ``dtype`` and hands it,
-    with ``params``, to the programs of llm/model.py it calls: ``prefill``,
-    ``prefill_with_prefix``, ``copy_page`` and, by whether the
-    configuration has a ``block_length``, ``decode_step`` /
-    ``decode_step_greedy`` or ``block_step`` (which also reads the
-    sampler's settings off it).
+    What the family of ``model_cfg`` is, the engine reads off the
+    configuration's own declaration (llm/model.py says who owns which
+    decision) and nowhere else: ``cache_layout()`` is what it allocates
+    (page pools for the layers that attend, a latent pool, state rows a
+    slot beside them), ``serving_layout`` how it holds the tree,
+    ``block_length`` whether a decode step is ``decode_step`` /
+    ``decode_step_greedy`` or ``block_step``, and ``refuses`` what it
+    cannot be served with: a feature listed there is refused by the
+    family's own sentence (``_refuse``) or not built (``prefix_cache``,
+    ``kv_tier``).  What the programs counted comes back by name and goes
+    to ``stats()``, the metrics and the spans under that name.
 
     A slot's state rows are begun anew by the prefill that admits a
     sequence to it (from a zero state, whatever the last tenant left) and
-    mean nothing once it is released.  Pages say nothing of the state at a
-    prefix's end, so for such a model what would need that is refused by
-    name or not built: no ``PrefixCache`` (a preempted sequence's resume
-    prefill recomputes from position 0), no P/D (``prefill_extract``,
-    ``submit_with_kv``), no KV tier.
+    mean nothing once it is released; without a ``PrefixCache`` a
+    preempted sequence's resume prefill recomputes from position 0.
 
-    The engine holds the parameters in the SERVING layout
-    (``lm.serving_layout``: a layer's ``wq``, ``wk``, ``wv`` as one stacked
-    ``wqkv``; a linear-attention layer's six input projections as one
-    ``w_in``), made once here from whichever tree it is handed, and keeps
-    no reference to the unstacked weights: they are freed when the caller
-    lets go.
+    The engine holds the parameters in the SERVING layout, made once here
+    from whichever tree it is handed, and keeps no reference to the
+    unstacked weights: they are freed when the caller lets go.
     """
 
     def __init__(self, params, model_cfg, cfg: Optional[EngineConfig] = None,
                  kv_tier=None):
         self.cfg = cfg or EngineConfig()
         self.model_cfg = model_cfg
-        self.params = lm.serving_layout(params)
+        self.params = model_cfg.serving_layout(params)
         # block: positions a block (0: a token at a time)
-        self._block = int(getattr(model_cfg, "block_length", 0))
+        self._block = int(model_cfg.block_length)
         if self._block and (self.cfg.page_size % self._block
                             or self.cfg.max_seq_len % self._block):
             raise ValueError(
                 f"a block of {self._block} positions must divide page_size "
                 f"({self.cfg.page_size}) and max_seq_len "
                 f"({self.cfg.max_seq_len}): a block lies in one page")
+        layout = model_cfg.cache_layout()
         ccfg = CacheConfig(
-            **lm.cache_layout(model_cfg), num_pages=self.cfg.num_pages,
+            **layout, num_pages=self.cfg.num_pages,
             page_size=self.cfg.page_size, dtype=model_cfg.dtype,
             max_slots=self.cfg.max_slots)
         # (for latent pages cache_k is the one pool and cache_v None)
         self.cache_k, self.cache_v = init_cache(ccfg)
-        self._latent = bool(ccfg.latent_dim)
-        # a token-at-a-time model with routed experts: its programs hand
-        # back, beside their result, the experts they read
-        self._routed = (not self._block
-                        and "experts" in self.params["layers"])
+        self._latent = "latent_dim" in layout
         # recurrent layers' rows, a slot each (None: the model has none)
         self.state = init_state(ccfg)
         self._state_layers = ccfg.state_layers
@@ -487,12 +480,11 @@ class LLMEngine:
         # prefix skip that prefill compute.  A pure index over pages — all
         # page ownership still flows through self.allocator, so swapping
         # the allocator (tests do) starts from an empty, consistent state.
-        # Recurrent state: a prefix's pages hold nothing of the state at
-        # its end, so no index is built and every prompt is computed whole.
+        # A family that refuses it has every prompt computed whole.
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.cfg.page_size)
-            if flags.get("RTPU_PREFIX_CACHE") and self.state is None
-            else None)
+            if flags.get("RTPU_PREFIX_CACHE")
+            and "prefix_cache" not in model_cfg.refuses else None)
         self.max_pages_per_seq = -(-self.cfg.max_seq_len
                                    // self.cfg.page_size)
         # Store-backed KV tier (ISSUE 16): hot family spines seal into
@@ -501,11 +493,9 @@ class LLMEngine:
         # hydration) runs on the scheduler thread — the single-writer
         # contract below covers it; kv_prehydrate() crosses threads only
         # through the thread-safe _hydrate_q.
-        # (a server hands every engine its worker's tier unasked: one with
-        # recurrent layers has no use for it, and refuses what asks for it)
-        # (nor has one whose pages are latent rows: the tier moves K and V)
-        self.kv_tier = (kv_tier if self.state is None and not self._latent
-                        else None)
+        # (a server hands every engine its worker's tier unasked: a family
+        # that refuses it lets it go, and refuses what asks for it)
+        self.kv_tier = None if "kv_tier" in model_cfg.refuses else kv_tier
         self._hydrate_q: queue_mod.Queue = queue_mod.Queue()
         self._waiting: queue_mod.Queue = queue_mod.Queue()
         # Single-writer design: _slots, the allocator, and _stats are
@@ -560,9 +550,8 @@ class LLMEngine:
     def submit(self, prompt_tokens: List[int],
                params: Optional[SamplingParams] = None) -> _Request:
         params = params or SamplingParams()
-        if self._block and params.temperature > 0:
-            self._refuse_block(f"sampling at temperature "
-                               f"{params.temperature} (greedy only)")
+        if params.temperature > 0:
+            self._refuse("sampling", f"temperature {params.temperature}")
         total = len(prompt_tokens) + params.max_tokens
         if total > self.cfg.max_seq_len:
             raise ValueError(
@@ -589,14 +578,7 @@ class LLMEngine:
         the first token, and return (first_token, kv_k, kv_v, n_tokens) —
         the KV page arrays a decode engine injects via submit_with_kv.
         Pages are freed here immediately; this engine keeps no state."""
-        if self._block:
-            self._refuse_block("prefill/decode disaggregation "
-                               "(prefill_extract): a prefill of this model "
-                               "yields no first token to ship")
-        self._refuse_recurrent("prefill/decode disaggregation "
-                               "(prefill_extract)")
-        self._refuse_latent("prefill/decode disaggregation "
-                            "(prefill_extract)")
+        self._refuse("pd", "prefill_extract")
         self.start()
         params = params or SamplingParams()
         req = _Request(request_id=uuid.uuid4().hex[:12],
@@ -620,14 +602,7 @@ class LLMEngine:
                        params: Optional[SamplingParams] = None) -> _Request:
         """P/D disaggregation, decode side: admit a sequence whose prompt
         KV was computed elsewhere. No prefill compute happens here."""
-        if self._block:
-            self._refuse_block("prefill/decode disaggregation "
-                               "(submit_with_kv): a slot of this model "
-                               "opens on a block, not on a shipped token")
-        self._refuse_recurrent("prefill/decode disaggregation "
-                               "(submit_with_kv)")
-        self._refuse_latent("prefill/decode disaggregation "
-                            "(submit_with_kv)")
+        self._refuse("pd", "submit_with_kv")
         self.start()
         params = params or SamplingParams()
         total = len(prompt_tokens) + params.max_tokens
@@ -648,27 +623,10 @@ class LLMEngine:
         self._waiting.put(req)
         return req
 
-    def _refuse_block(self, what: str):
-        raise ValueError(
-            f"{type(self.model_cfg).__name__} generates by diffusion over "
-            f"blocks of {self._block} positions, which this engine does not "
-            f"serve with {what}")
-
-    def _refuse_recurrent(self, what: str):
-        if self.state is not None:
-            raise ValueError(
-                f"{type(self.model_cfg).__name__} has recurrent layers whose "
-                f"state is a row a slot beside the pages, which this engine "
-                f"does not serve with {what}: pages alone carry nothing of "
-                f"the state at their end")
-
-    def _refuse_latent(self, what: str):
-        if self._latent:
-            raise ValueError(
-                f"{type(self.model_cfg).__name__} caches latent rows, one "
-                f"pool of {self.cache_k.shape[-1]} values a token a layer "
-                f"and no V pool, which this engine does not serve with "
-                f"{what}: that ships K and V pages")
+    def _refuse(self, feature: str, where: str) -> None:
+        """Raise, in the family's own words, if ``model_cfg`` declares that
+        it cannot be served with ``feature`` (``where``: the call)."""
+        lm.refuse(self.model_cfg, feature, where)
 
     def generate(self, prompt_tokens: List[int],
                  params: Optional[SamplingParams] = None,
@@ -1105,9 +1063,6 @@ class LLMEngine:
         only (the tail opens the slot's first block), nothing sampled, and
         no program at all when the hit covers them.  Recurrent layers: the
         same program also begins ``slot``'s state rows anew."""
-        if self.state is not None and slot is None:
-            raise ValueError("a prefill over recurrent layers names the "
-                             "slot whose state rows it begins")
         n = len(req.prompt_tokens)
         ps = self.cfg.page_size
         ph = self._ph
@@ -1139,33 +1094,23 @@ class LLMEngine:
             args += (jnp.asarray(table), jnp.asarray(positions))
         tokens = jnp.asarray(tokens)
         ph.vals = (bucket, prefix_len)
-        out, block_attrs = None, {}
+        out, did = None, {}  # what the prefill did, by name, for its span
         if suffix or not self._block:
             ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
-            logits = self._run(program, tokens, *args, slot=slot)
+            logits, counted = self._run(program, tokens, *args, slot=slot)
             if self.state is not None:
-                chunks = -(-bucket // SCAN_CHUNK) * self._state_layers
-                block_attrs = {"scan_chunks": chunks}
-                self._stats["scan_chunks"] += chunks
-                self._stats["state_resets"] += 1
-                self._m["scan_chunks"].inc(chunks)
-                self._m["state_resets"].inc()
+                did["scan_chunks"] = (-(-bucket // SCAN_CHUNK)
+                                      * self._state_layers)
+                self._count({**did, "state_resets": 1})
             self._deliver(True)
             ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
-            if self._routed:  # the logits, then the experts it read
-                logits, hit = logits
-            logits = np.asarray(logits)
+            logits, counted = jax.device_get((logits, counted))
             ph.begin(P_PREFILL_EMIT, req)
-            if self._block:  # what came back is the experts it read
-                hit = logits
-            if self._block or self._routed:
-                block_attrs = {"experts_read": int(hit)}
-                self._stats["experts_read"] += int(hit)
-                self._m["experts_read"].inc(int(hit))
-            if not self._block:
+            counted = {name: int(n) for name, n in counted.items()}
+            did.update(counted)
+            self._count({**counted, "prefills": 1})
+            if logits is not None:  # else no token follows from a prompt
                 out = self._sample_one(logits, req.params, rng)
-            self._stats["prefills"] += 1
-            self._m["prefills"].inc()
         dt = time.monotonic() - t0
         self._stats["admitted"] += 1
         self._m["admitted"].inc()
@@ -1179,7 +1124,7 @@ class LLMEngine:
                        req.submitted_wall + qw, wait_s=round(qw, 6))
             self._span(req, "llm.prefill", w_end - dt, w_end, tokens=n,
                        prefix_len=prefix_len, resumed=bool(req.preempts),
-                       **block_attrs)
+                       **did)
         if req.preempts:
             try:
                 from ray_tpu.util import events
@@ -1233,8 +1178,7 @@ class LLMEngine:
         (controller replication fan-out / warm restart).  Thread-safe:
         roots queue through _hydrate_q and the scheduler thread performs
         the actual pool mutation in _drain_hydrations."""
-        self._refuse_recurrent("the KV tier (kv_prehydrate)")
-        self._refuse_latent("the KV tier (kv_prehydrate)")
+        self._refuse("kv_tier", "kv_prehydrate")
         self.start()
         for r in roots or ():
             self._hydrate_q.put(str(r))
@@ -1599,24 +1543,24 @@ class LLMEngine:
         pages_read = int(np.minimum(
             (positions[active][:, None] + np.arange(burst))
             // self.cfg.page_size + 1, P).sum())
-        self._stats["decode_pages_read"] += pages_read
-        self._m["decode_pages_read"].inc(pages_read)
+        # what the family adds to a burst's counts, by name: by the kind of
+        # its cache here, and below what its steps' programs counted
+        named = {}
         if self._latent:
-            self._stats["latent_pages_read"] += pages_read
-            self._m["latent_pages_read"].inc(pages_read)
-        hits = []  # a routed model: the experts each step read, on device
+            named["latent_pages_read"] = pages_read
+        if self.state is not None:
+            named["state_slot_steps"] = burst * len(active_slots)
+        counts = []  # a step's ``counted``, on the device
         emitted = self._stats["tokens_generated"]
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))
         if all_greedy:
             steps = []
             for j in range(burst):
-                toks_dev = self._run(
+                toks_dev, counted = self._run(
                     lm.decode_step_greedy, toks_dev, tables_dev,
                     pos_dev + j, active_dev)
-                if self._routed:
-                    toks_dev, hit = toks_dev
-                    hits.append(hit)
+                counts.append(counted)
                 steps.append(toks_dev)
                 if j == 0 and self._deliver(True) and burst > 1:
                     ph.begin(P_DECODE_DISPATCH, vals=(burst,))
@@ -1627,11 +1571,9 @@ class LLMEngine:
                     else np.asarray(steps[0])[None])
             ph.begin(P_DECODE_EMIT)
         else:
-            logits = self._run(lm.decode_step, toks_dev, tables_dev,
-                               pos_dev, active_dev)
-            if self._routed:
-                logits, hit = logits
-                hits.append(hit)
+            logits, counted = self._run(lm.decode_step, toks_dev,
+                                        tables_dev, pos_dev, active_dev)
+            counts.append(counted)
             self._deliver(True)
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             logits_np = np.asarray(logits)
@@ -1640,47 +1582,41 @@ class LLMEngine:
             for i, s in active_slots:
                 rows[0, i] = self._sample_one(
                     logits_np[i], s.request.params, s.rng)
-        self._stats["decode_steps"] += burst
-        self._m["decode_steps"].inc(burst)
-        if self.state is not None:
-            state_slots = burst * len(active_slots)
-            self._stats["state_slot_steps"] += state_slots
-            self._m["state_slot_steps"].inc(state_slots)
-        experts_read = 0
-        if hits:  # computed with the tokens that were just fetched
-            experts_read = int(sum(int(h) for h in jax.device_get(hits)))
-            self._stats["experts_read"] += experts_read
-            self._m["experts_read"].inc(experts_read)
+        if counts[0]:  # computed with the tokens that were just fetched
+            counts = jax.device_get(counts)
+            for name in counts[0]:
+                named[name] = sum(int(c[name]) for c in counts)
+        self._count({"decode_steps": burst, "decode_pages_read": pages_read,
+                     **named})
         self._accept_burst(active_slots, rows)
         if ph.sampled:
             ph.vals = (self._stats["tokens_generated"] - emitted,
                        sum(self._slots[i] is not s for i, s in active_slots))
-            named = {}  # what such a model's steps did, by name
-            if self.state is not None:
-                named["state_slots"] = state_slots
-            if self._routed:
-                named["experts_read"] = experts_read
-            if self._latent:
-                named["latent_pages_read"] = pages_read
-            if named:
+            if named:  # the span names them too, and the steps
+                if "state_slot_steps" in named:  # (under the span's name)
+                    named["state_slots"] = named.pop("state_slot_steps")
                 ph.vals = dict(zip(_PHASE_ATTRS[P_DECODE_EMIT], ph.vals),
                                steps=burst, **named)
         return True
 
+    def _count(self, did: dict) -> None:
+        """Add what was done, by name, to ``stats()`` and the metrics."""
+        for name, n in did.items():
+            self._stats[name] += n
+            self._m[name].inc(n)
+
     def _run(self, program, tokens, *args, slot=None):
         """One of llm/model.py's token-at-a-time programs over the pools
         and, for a model with recurrent layers, the state rows (a prefill
-        names the ``slot`` it admits to): what it yields; pools and rows
-        come back in place."""
+        names the ``slot`` it admits to): what it yields and what it
+        counted by name; pools and rows come back in place."""
         rows = {} if self.state is None else {"state": self.state}
         if rows and slot is not None:
             rows["slot"] = jnp.int32(slot)
-        out, self.cache_k, self.cache_v, *state = program(
+        out, counted, self.cache_k, self.cache_v, self.state = program(
             self.params, tokens, self.cache_k, self.cache_v, *args,
             self.model_cfg, **rows)
-        if state:
-            (self.state,) = state
-        return out
+        return out, counted
 
     # ------------------------- block diffusion ----------------------------
 

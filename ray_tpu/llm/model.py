@@ -1,118 +1,41 @@
-"""Cache-aware Llama forward passes: bucketed prefill + batched paged decode.
+"""The served programs: bucketed prefill, suffix prefill through a prefix's
+pages, batched paged decode, block diffusion's denoising pass.  ONE compiled
+decode step for the whole engine (a static [max_slots] batch), one compiled
+prefill a length bucket: nothing that depends on a sequence's length is a
+Python branch or a program variant.  ``cfg`` is the model's configuration,
+hashable (a static argument), of whichever family the parameters are.
 
-The training-side model (models/llama.py) has no KV cache; these are the
-inference twins, built for XLA's compilation model: ONE compiled decode step
-for the whole engine (static [max_slots] batch) and one compiled prefill per
-length bucket.  Nothing that depends on a sequence's length is a Python
-branch or a program variant.
+Who owns which decision.  A PROGRAM here owns WHERE rows are written and
+WHAT is visible: its write coordinates (page and slot a position, or the
+slot's state row), its mask, and the closures that cache and attend its
+way, which it hands the family's walk as one bundle ``via``:
 
-Every program here is models/llama.py's parts (``embed``, ``layer``,
-``head``) around an ``attend(q, k, v, cache) -> (out, cache)`` of its own:
-the block is not written here, only the three ways it attends.
+- ``attend(q, k, v, (ck, cv, li))``: K/V rows into pool layer ``li``, then
+  ``prefill`` a dense mask over this call's own k and v, the suffix prefill
+  a gather through the page table, the decode step and ``block_step``
+  ``ops/paged_attention`` (pages read where they lie, at KV-head width; a
+  pool may hold more heads than the model, ``_pad_heads``);
+- ``attend_latent(q_nope, q_rope, row, a, (pool, None, li))``: latent rows
+  into the ONE pool, then the prefills REBUILT (K and V made from the rows
+  this call wrote or the page table reaches), the decode step ABSORBED
+  (``paged_latent_decode_attention``: K and V are never made);
+- ``recur(mix, qkv, b, a, (state, li))``: ``prefill`` runs the chunked
+  recurrence from a ZERO state and writes the slot's rows ONCE after the
+  scan, the decode step updates every live slot's row in place.
 
-- ``prefill``: within the padded sequence it is given, a dense causal
-  ``[L, L]`` score matrix over this call's own k and v.
-- ``prefill_with_prefix``: through the sequence's page table; it still
-  gathers the whole table and repeats K/V to the query heads, as the decode
-  step did before the kernel (PERF.md section 7).
-- ``_decode_impl`` (``decode_step``, ``decode_step_greedy``): through
-  ``ops/paged_attention.paged_decode_attention``, which walks each slot's
-  page table as far as that slot's position and reads those pages out of
-  the pool where it lies, at KV-head width (one page read serves every query
-  head of a group), with a running float32 softmax; an inactive slot costs
-  nothing.
+A FAMILY (its configuration class in ``models/``) owns WHAT a row is, how
+its layers are walked and what it refuses, and says so once:
+``cache_layout()``, ``serving_layout(params)``, ``served_walk(params, x,
+caches, positions, via)`` (it takes of ``via`` what it uses and hands back
+what it counted BY NAME and the rows to write once), ``refuses`` (feature
+-> why), ``block_length`` (0: a token at a time) and ``mask_token_id``.
+No program finds a family out from the tree's keys.
 
-A configuration with a ``block_length`` (models/sdar_moe.py) generates by
-diffusion over blocks, and takes a fourth program and another mask: both
-prefills are causal over blocks and bidirectional inside one, emit nothing
-(the model's logits are for the position itself, so no token follows from a
-prompt's last one), and ``block_step`` is one denoising pass over every
-slot's open block, its rows attending through the page table like a decode
-step's.  Its feed-forward, the routed experts, comes with the parameters.
-
-A model with RECURRENT layers (models/olmo_hybrid.py: gated delta-rule
-linear attention, three such layers to every full-attention one) caches
-more than pages, and says so (``cache_layout``): page pools for the layers
-that attend only, and beside them ``state``, named rows ``[rows,
-max_slots, ...]``: a slot's recurrent state (float32) and its
-convolution's last inputs.  The same ``prefill`` and ``decode_step*``
-serve it, taking ``state`` (donated, aliased to the output like the pools)
-and, the prefill, the ``slot`` it admits to.  ``_scan_layers`` then scans
-PERIODS (the recurrent layers of a period unrolled in the body, their
-weights indexed where they are read, then its full layer) with a third
-thing in the carry beside the pools: the state rows, whole.  As ``attend``
-is what the full layer's callers differ in, ``recur(mix, qkv, b, a, rows)``
-is the recurrent layer's: ``prefill`` runs ``ops/gated_delta.chunked`` from
-a ZERO state over the real tokens and writes the slot's rows ONCE after
-the scan (a row-sized write inside the loop made XLA copy all of the state
-to another layout and back); ``_decode_impl`` updates every live slot's
-row in place (``ops/gated_delta.decode_update``, a Pallas kernel).  A pool
-may hold more KV heads than the model has (whole tiles for the paged
-kernel: ``_pad_heads`` puts zero heads behind k, v and the decode step's
-q).  Pages hold nothing of the state at a prefix's end, so
-``prefill_with_prefix`` refuses such a tree by name, and the engine builds
-no prefix index and refuses P/D and the KV tier for it.
-
-A model that attends over LATENT rows (models/glm_moe_lite.py: latent
-attention in every layer, a leading dense layer, then routed experts beside
-a shared one) caches neither K nor V.  Its ``cache_layout`` declares a
-LATENT POOL: ``cache_k`` is ``[n_layers, pages, page_size, latent_width]``,
-one row a token a layer (``c_kv`` after its norm, then the ONE rotary key
-after rotation, then zeros to the lane tile's end: 512 + 64 + 64 = 640
-lanes, 1,280 bytes in bf16), key and value both, and ``cache_v`` is None;
-page tables, allocator, prefix index and ``copy_page`` do not notice.  The
-same programs serve it, each with a second ``attend`` of its own
-(``attend_latent(q_nope, q_rope, row, a, pools)``, picked where the tree
-is latent: ``_latent``), and the two FORMS of latent attention, the same
-function, go by program: ``prefill`` and ``prefill_with_prefix`` write
-their rows and attend REBUILT (per-head K and V made from latent rows by
-``glm_moe_lite.rebuild_kv``: this call's own rows, or the rows the page
-table reaches, then the dense mask of ``_masked_attention``, a head a
-head); ``_decode_impl`` writes its B rows and attends ABSORBED (``W_uk``
-folded into the query, ``ops/paged_attention.paged_latent_decode_attention``
-walking each slot's rows ONCE and using each twice, ``W_uv`` applied to
-its output): K and V are never made for a decode step.  ``_scan_layers``
-runs the leading dense layers before the scanned sparse ones, the pool's
-layer index running on (``glm_moe_lite.scan_layers``).  A token-at-a-time
-model with routed experts hands back, beside its logits or tokens, the
-experts its routed layers read (``_routed``).
-
-``cfg`` is the model's configuration, hashable (a static argument), of
-whichever family the parameters are: a ``LlamaConfig``, an
-``SDARMoEConfig``, an ``OlmoHybridConfig`` or a ``GLMMoELiteConfig``
-today.  The programs read of
-it ``n_layers``, ``n_heads``, ``n_kv_heads`` and ``head_dim`` here and, through
-models/llama.py's parts, ``dtype``, ``norm_eps`` and ``rope_theta``; of a
-block-diffusion configuration also ``block_length``, ``mask_token_id``,
-``denoising_steps``, ``remasking_strategy`` and ``confidence_threshold``
-(the sampler) and, through ``moe.scan_routed_layers``,
-``experts_per_token``, ``norm_topk_prob`` and (where it has one)
-``routed_scaling_factor``; of a latent configuration ``kv_lora_rank``,
-``latent_dim`` and the head widths.  Which feed-forward and which head norms a layer
-has is read off the parameters (``"experts" in params["layers"]``,
-``"lin" in params["layers"]``, ``"dense" in params``, ``"w_uk"`` or
-``"wkv_b"`` in a layer's ``attn``, ``"q_norm" in p["attn"]`` and that
-weight's width, ``"router_bias"`` and ``"shared"`` in a routed layer), not
-off a type; so is how it projects q, k and
-v.  ``LLMEngine`` hands these programs the SERVING layout
-(``serving_layout`` below, by the family the tree is of): a layer's ``wq``,
-``wk`` and ``wv`` as one stacked ``wqkv`` (and a linear-attention layer's
-six input projections as one ``w_in``), of which ``qkv_rope`` makes ONE
-product that XLA reads out of the stacked parameter inside its own fusion,
-as it reads the MLP's.  A tree with the three weights (training's, a test's)
-runs through every program too: XLA then slices each weight of a layer
-into fast memory and transposes it there before its product, 9 % of a
-decode step at the serving cells' shapes (PERF.md section 6, PR 37).
-
-Each ``attend`` first writes its new K/V rows into the layer's pages.
-Every program carries both pools through its layer scan whole, with the
-layer's index beside the layer's parameters, and scatters in place at
-``[li, page, slot]``: the donated pools are aliased to the outputs and
-nothing pool-sized, nor one layer of a pool, is sliced, stacked or copied
-(a scan OVER the pools makes XLA unstack and restack them: ~22 ms an
-admission over the serving cells' 3.2 GB pool, PERF.md section 6).  The
-reference gets this from vLLM's CUDA kernels; here it is jax/XLA and Pallas
-native (SURVEY.md §7 step 8).
+Every token program hands back ONE shape: (result, counted, cache_k,
+cache_v, state); ``counted`` is a mapping (empty for a dense model),
+``state`` and a latent model's ``cache_v`` None.  Pools and state are
+donated and ride in the layer scan's carry whole, scattered in place at
+``[li, page, slot]``: nothing pool-sized is sliced, stacked or copied.
 """
 
 from __future__ import annotations
@@ -123,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
-from ray_tpu.models.llama import embed, head, layer
+from ray_tpu.models.llama import embed, head
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
                                          paged_latent_decode_attention)
@@ -131,34 +54,28 @@ from ray_tpu.ops.paged_attention import (paged_decode_attention,
 
 def cache_layout(cfg) -> dict:
     """What these programs cache for a model of configuration ``cfg``, as
-    ``paged_cache.CacheConfig`` takes it: ``n_layers`` page pools of
-    ``n_kv_heads`` x ``head_dim`` (the heads as the POOL holds them) and,
-    for a model with recurrent layers, ``state_layers`` times the
-    ``state_rows`` a slot.  A configuration that caches anything but one
-    K/V pool a layer says so itself (``cfg.cache_layout()``)."""
-    declared = getattr(cfg, "cache_layout", None)
-    if declared is not None:
-        return declared()
-    return {"n_layers": cfg.n_layers, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim}
+    ``paged_cache.CacheConfig`` takes it: the family's own declaration."""
+    return cfg.cache_layout()
 
 
 def serving_layout(params):
-    """The tree as these programs hold it, by the family the tree is of
-    (``models.llama.serving_layout``, ``models.olmo_hybrid``'s)."""
+    """The tree as these programs hold it, for a caller with a tree and no
+    configuration (the engine asks ``cfg.serving_layout``).  The ONE place
+    in ``ray_tpu/llm/`` that recognises a family by its tree's keys."""
+    attn = params["layers"].get("attn", ())
     if "lin" in params["layers"]:
         return olmo_hybrid.serving_layout(params)
-    if _latent(params):
+    if "wkv_b" in attn or "w_uk" in attn:
         return glm_moe_lite.serving_layout(params)
     return llama.serving_layout(params)
 
 
-def _latent(params) -> bool:
-    """Does the tree attend over latent rows (models/glm_moe_lite.py)?  Then
-    ``cache_k`` is the latent pool, ``cache_v`` None, and a program's
-    ``attend`` is its latent one."""
-    attn = params["layers"].get("attn", ())
-    return "wkv_b" in attn or "w_uk" in attn
+def refuse(cfg, feature: str, where: str) -> None:
+    """Raise the family's own sentence if it declares (``cfg.refuses``) that
+    it cannot be served with ``feature``; ``where`` names the call."""
+    why = cfg.refuses.get(feature)
+    if why is not None:
+        raise ValueError(why.format(cfg=cfg, where=where))
 
 
 def _pad_heads(x, n: int):
@@ -201,80 +118,6 @@ def _rebuilt_attention(cfg, a, q_nope, q_rope, rows, mask):
         cfg, jnp.concatenate([q_nope, q_rope], axis=-1), keys, vals, mask)
 
 
-def _scan_layers(params, x, caches, positions, attend, cfg, recur=None):
-    """The layer scan of every program here.  ``caches`` = (cache_k,
-    cache_v, state): both pools, and the state rows of a model with
-    recurrent layers (else None), ride in the carry whole, never scanned
-    over; ``attend(q, k, v, (ck, cv, li))`` writes pool layer ``li``'s rows
-    into them in place and attends its own way.  A recurrent layer's
-    ``recur(mix, qkv, b, a, (state, li)) -> (o, (state, left))`` updates
-    its rows in place likewise, or leaves them be and hands back as ``left``
-    what the caller is to write once the scan is over.  Returns (x, caches,
-    what the scan left: the experts the routed layers read, the recurrent
-    layers' ``left`` [periods, ...] in a list by place in the period, or
-    None)."""
-    cache_k, cache_v, state = caches
-    if "lin" in params["layers"]:  # periods: recurrent layers, then a full
-        n = cfg.lin_per_period
-
-        def period_body(carry, lin, full, period):
-            x, ck, cv, st = carry
-            left = []
-            for j in range(n):
-                li = period * n + j
-                x, (st, out) = olmo_hybrid.linear_layer(
-                    cfg, lin(li), x, recur, (st, li))
-                left.append(out)
-            x, (ck, cv) = olmo_hybrid.full_layer(
-                cfg, full, x, positions, attend, (ck, cv, period))
-            return (x, ck, cv, st), left
-
-        (x, *caches), left = olmo_hybrid.scan_periods(
-            cfg, params, period_body, (x, cache_k, cache_v, state))
-        return x, tuple(caches), left
-
-    if _latent(params):  # leading dense layers, then routed ones
-        def latent_body(carry, p, li, feed_forward):
-            x, ck, cv = carry
-            x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li),
-                                feed_forward,
-                                glm_moe_lite.latent_attention_block)
-            return x, ck, cv
-
-        (x, cache_k, cache_v), hit = glm_moe_lite.scan_layers(
-            cfg, params, latent_body, (x, cache_k, cache_v))
-        return x, (cache_k, cache_v, state), hit
-
-    if "experts" in params["layers"]:  # routed: the experts are not scanned
-        def routed_body(carry, p, li, feed_forward):
-            x, ck, cv = carry
-            x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li),
-                                feed_forward)
-            return x, ck, cv
-
-        (x, cache_k, cache_v), hit = sdar_moe.scan_layers(
-            cfg, params, routed_body, (x, cache_k, cache_v))
-        return x, (cache_k, cache_v, state), hit
-
-    def body(carry, per_layer):
-        x, ck, cv = carry
-        p, li = per_layer
-        x, (ck, cv) = layer(cfg, p, x, positions, attend, (ck, cv, li))
-        return (x, ck, cv), None
-
-    with jax.named_scope("layers"):
-        (x, cache_k, cache_v), _ = jax.lax.scan(
-            body, (x, cache_k, cache_v),
-            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    return x, (cache_k, cache_v, state), None
-
-
-def _cached(out, caches):
-    """What a program hands back: its result, both pools and, for a model
-    with recurrent layers, the state rows."""
-    return (out, *caches) if caches[2] is not None else (out, *caches[:2])
-
-
 def _conv_and_gates(cfg, mix, qkv, before, b, a):
     """A recurrent layer's rows through the short convolution and the
     norms and gates: (q, k, v, g, beta, the convolution's rows)."""
@@ -282,34 +125,19 @@ def _conv_and_gates(cfg, mix, qkv, before, b, a):
     return (*olmo_hybrid.delta_inputs(cfg, mix, y, b, a), rows)
 
 
-def _block_length(cfg) -> int:
-    """Positions a block of a block-diffusion configuration; 0 for a model
-    that generates a token at a time."""
-    return getattr(cfg, "block_length", 0)
-
-
 def _visible(cfg, qpos, kpos):
     """[q, k] bool: may the query at ``qpos`` see the key at ``kpos``?
     Causal; for a block-diffusion configuration causal over blocks."""
-    if _block_length(cfg):
+    if cfg.block_length:
         return sdar_moe.block_causal(qpos, kpos, cfg.block_length)
     return kpos[None, :] <= qpos[:, None]
 
 
-def _routed(params) -> bool:
-    return "experts" in params["layers"]
-
-
-def _prefill_result(params, x, cfg, true_len, experts_hit):
-    """What a prefill hands the engine: the last token's logits (with the
-    experts its routed layers read, where a token-at-a-time model has
-    such), or for a block-diffusion configuration (no token follows from a
-    prompt, so the output head is not run) a number to wait for: those
-    experts."""
-    if _block_length(cfg):
-        return experts_hit
-    logits = head(params, x, cfg, true_len)
-    return (logits, experts_hit) if _routed(params) else logits
+def _last_logits(params, x, cfg, true_len):
+    """A prefill's result: the last token's logits, or None for a
+    block-diffusion configuration (no token follows from a prompt, so the
+    output head is not run; the engine waits for what was counted)."""
+    return None if cfg.block_length else head(params, x, cfg, true_len)
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -321,13 +149,13 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     tokens: [L] int32 (padded); page_rows: [L] page id per token position;
     slot_positions: [L] slot inside the page; true_len: scalar.
     Writes K/V for positions < true_len into the paged cache and returns
-    (logits_at_last_token [V], cache_k, cache_v).
+    (logits_at_last_token [V], counted, cache_k, cache_v, state).
 
     A model with recurrent layers also takes ``state`` (its rows, donated)
     and ``slot`` (the row this sequence is admitted to): each such layer
     runs the chunked recurrence from a ZERO state over the true_len tokens
     and leaves the final state, and its convolution's last inputs, in the
-    slot's row, whatever the row held; ``state`` comes back last.
+    slot's row, whatever the row held.
     """
     x = embed(params, tokens, cfg)  # [L, D]
     positions = jnp.arange(tokens.shape[0])
@@ -369,9 +197,9 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         return o, (None, left)
 
     # the scan carries no state: a prefill begins its slot's rows anew
-    x, (cache_k, cache_v, _), left = _scan_layers(
+    x, (cache_k, cache_v, _), counted, left = cfg.served_walk(
         params, x, (cache_k, cache_v, None), positions,
-        attend_latent if _latent(params) else attend, cfg, recur)
+        {"attend": attend, "attend_latent": attend_latent, "recur": recur})
     if state is not None:
         # The slot's rows are written HERE, once, and not in the scan: a
         # row-sized update inside the loop lets XLA choose the carried
@@ -386,8 +214,8 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
                      "conv": jax.lax.dynamic_update_slice(
                          state["conv"], tail.astype(state["conv"].dtype),
                          (0, slot, 0))}
-    return _cached(_prefill_result(params, x, cfg, true_len, left),
-                   (cache_k, cache_v, state))
+    return (_last_logits(params, x, cfg, true_len), counted, cache_k,
+            cache_v, state)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
@@ -405,12 +233,10 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     decode step — cached prefix columns come straight from the pool, suffix
     columns from this call's writes — masked at tpos <= position, so the
     null page, padded query rows, and future suffix columns all drop out.
-    Returns (logits at the last suffix token [V], cache_k, cache_v).
+    Returns (logits at the last suffix token [V], counted, cache_k,
+    cache_v, None).
     """
-    if "lin" in params["layers"]:
-        raise ValueError(
-            "prefill_with_prefix serves no model with recurrent layers: "
-            "the pages of a prefix hold nothing of their state at its end")
+    refuse(cfg, "prefix_cache", "prefill_with_prefix")
     P = page_table.shape[0]
     page_size = cache_k.shape[2]
     x = embed(params, tokens, cfg)  # [L, D]
@@ -440,10 +266,10 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
         return (_rebuilt_attention(cfg, a, q_nope, q_rope, rows, mask),
                 (pool, None))
 
-    x, caches, hit = _scan_layers(
+    x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, None), positions,
-        attend_latent if _latent(params) else attend, cfg)
-    return _cached(_prefill_result(params, x, cfg, true_len, hit), caches)
+        {"attend": attend, "attend_latent": attend_latent})
+    return (_last_logits(params, x, cfg, true_len), counted, *caches)
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
@@ -452,14 +278,13 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
 
     tokens: [B] int32 current token per slot; positions: [B] its position;
     page_tables: [B, P] page ids (0 = null page); active: [B] bool.
-    Returns (logits [B, V], cache_k, cache_v).
+    Returns (logits [B, V], counted, cache_k, cache_v, state).
 
     A layer writes its B new rows into the pool in place and the paged
     kernel reads that layer's pages out of the same buffer.  A recurrent
     layer updates the row of every ACTIVE slot in ``state`` in place
     (``ops/gated_delta.decode_update``: a live slot's state is read once
-    and written once, the others' not at all); ``state`` then comes back
-    last.
+    and written once, the others' not at all).
     """
     P = page_tables.shape[1]
     page_size = cache_k.shape[2]
@@ -517,11 +342,10 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
                 st["conv"], conv[1:], li * taps, axis=0)}
         return o, (st, None)
 
-    x, caches, hit = _scan_layers(
+    x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, state), positions,
-        attend_latent if _latent(params) else attend, cfg, recur)
-    logits = head(params, x, cfg)
-    return _cached((logits, hit) if _routed(params) else logits, caches)
+        {"attend": attend, "attend_latent": attend_latent, "recur": recur})
+    return (head(params, x, cfg), counted, *caches)
 
 
 @partial(jax.jit, static_argnames=("cfg",),
@@ -539,14 +363,11 @@ def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
     """Greedy decode: argmax ON DEVICE, so the host fetches [B] int32
     instead of [B, vocab] fp32 logits — the device-to-host round trip is the
     decode loop's fixed cost when every active request samples greedily."""
-    logits, *caches = _decode_impl(
+    logits, *rest = _decode_impl(
         params, tokens, cache_k, cache_v, page_tables, positions, active,
         cfg, state)
-    # a routed model: the step's tokens, then the experts it read
-    logits, *hit = logits if _routed(params) else (logits,)
     with jax.named_scope("sample"):
-        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return ((tokens, *hit) if hit else tokens, *caches)
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
 
 
 def _fill(cfg, logits, masked, step):
@@ -633,8 +454,8 @@ def block_step(params, cache_k, cache_v, page_tables, active, tokens,
             out = out.reshape(S, n_kv, B, rep, d).transpose(0, 2, 1, 3, 4)
             return out.reshape(S * B, n_kv * rep, d), (ck, cv)
 
-    x, (cache_k, cache_v, _), hit = _scan_layers(
-        params, x, (cache_k, cache_v, None), positions, attend, cfg)
+    x, (cache_k, cache_v, _), counted, _ = cfg.served_walk(
+        params, x, (cache_k, cache_v, None), positions, {"attend": attend})
     x0, fill = _fill(cfg, head(params, x, cfg).reshape(S, B, -1), masked,
                      step)
     final = active & ~jnp.any(masked, axis=1)
@@ -642,7 +463,7 @@ def block_step(params, cache_k, cache_v, page_tables, active, tokens,
     masked = masked & ~fill
     record = jnp.concatenate(
         [tokens, masked.astype(jnp.int32), final[:, None].astype(jnp.int32),
-         jnp.broadcast_to(hit, (S, 1))], axis=1)
+         jnp.broadcast_to(counted["experts_read"], (S, 1))], axis=1)
     nxt = final[:, None]
     return (record, jnp.where(nxt, jnp.int32(cfg.mask_token_id), tokens),
             masked | nxt, jnp.where(final, starts + B, starts),

@@ -55,8 +55,8 @@ _FALSY = ("", "0", "false", "no", "off")
 
 @dataclass
 class CacheConfig:
-    """What a model caches, as it declares it (``llm/model.py
-    cache_layout``), at an engine's sizes.  Three kinds.  Pages of K/V:
+    """What a model caches, as its configuration declares it
+    (``cfg.cache_layout()``), at an engine's sizes.  Three kinds.  Pages of K/V:
     ``n_layers`` pools of ``n_kv_heads`` x ``head_dim`` (the layers that
     attend, the heads as the pool holds them), K and V one shape.  LATENT
     pages (``latent_dim`` > 0, then no heads): ``n_layers`` pools of ONE

@@ -78,9 +78,14 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import (embed, gated_mlp, head, layer, rms_norm,
                                   rope)
-from ray_tpu.models.moe import scan_routed_layers
+from ray_tpu.models.moe import scan_routed_layers, served_routed_walk
 
 LANES = 128  # a pool's rows are whole lane tiles
+
+_LATENT_ROWS = ("{cfg.__class__.__name__} caches latent rows, one pool of "
+                "{cfg.latent_width} values a token a layer and no V pool, "
+                "which this engine does not serve with %s ({where}): that "
+                "ships K and V pages")
 
 
 @dataclass(frozen=True)
@@ -134,6 +139,20 @@ class GLMMoELiteConfig:
         """Lanes a row takes in the pool: ``latent_dim`` up to whole lane
         tiles (576 -> 640), the tail zeros."""
         return -(-self.latent_dim // LANES) * LANES
+
+    # What the engine and the served programs ask of a family (llm/model.py
+    # says who owns which decision), beside ``cache_layout`` below.
+    block_length = 0  # it generates a token at a time
+    refuses = {"pd": _LATENT_ROWS % "prefill/decode disaggregation",
+               "kv_tier": _LATENT_ROWS % "the KV tier"}
+
+    def serving_layout(self, params):
+        return serving_layout(params)
+
+    def served_walk(self, params, x, caches, positions, via):
+        return served_routed_walk(scan_layers, self, params, x, caches,
+                                  positions, via["attend_latent"],
+                                  latent_attention_block)
 
     def cache_layout(self) -> dict:
         """What the served programs cache (``llm/model.py cache_layout``):

@@ -51,6 +51,24 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    # What the engine and the served programs ask of a family (llm/model.py
+    # says who owns which decision).  Class members, not fields: a
+    # configuration hashes and compares as before.
+    block_length = 0  # positions a block; 0: it generates a token at a time
+    refuses = {}  # feature -> why the engine cannot serve the model with it
+
+    def cache_layout(self) -> dict:
+        """What is cached, as ``paged_cache.CacheConfig`` takes it: one K/V
+        page pool a layer."""
+        return {"n_layers": self.n_layers, "n_kv_heads": self.n_kv_heads,
+                "head_dim": self.head_dim}
+
+    def serving_layout(self, params):
+        return serving_layout(params)
+
+    def served_walk(self, params, x, caches, positions, via):
+        return served_walk(self, params, x, caches, positions, via)
+
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
         return LlamaConfig()
@@ -341,6 +359,29 @@ def head(params, x, cfg, true_len=None):
         if true_len is not None:
             x = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
         return x.astype(jnp.float32) @ params["lm_head"]
+
+
+def served_walk(cfg, params, x, caches, positions, via):
+    """The layers as the served programs (llm/model.py) walk them, one
+    signature a family: ``caches`` = (cache_k, cache_v, state) ride in the
+    scan's carry whole, ``via`` is what the program attends with
+    (``attend``, ``attend_latent``, ``recur``: a family takes what it
+    uses).  Returns (x, caches, what the walk counted by name, the rows a
+    program is to write once the scan is over).  Here: a plain scan, K/V
+    ``attend``, nothing counted."""
+    cache_k, cache_v, state = caches
+
+    def body(carry, per_layer):
+        x, ck, cv = carry
+        p, li = per_layer
+        x, (ck, cv) = layer(cfg, p, x, positions, via["attend"], (ck, cv, li))
+        return (x, ck, cv), None
+
+    with jax.named_scope("layers"):
+        (x, cache_k, cache_v), _ = jax.lax.scan(
+            body, (x, cache_k, cache_v),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return x, (cache_k, cache_v, state), {}, None
 
 
 # ---------------------------------------------------------------------------

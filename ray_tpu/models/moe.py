@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ray_tpu.models.llama import (attention_block, batch_attend,
                                   decoder_logical_specs, embed, head,
                                   rms_norm)
+from ray_tpu.models.llama import layer as decoder_layer
 from ray_tpu.parallel.sharding import logical_spec as L
 
 
@@ -275,7 +276,7 @@ def scan_routed_layers(cfg, layers, body, carry, first: int = 0):
             out, n = routed_mlp(
                 h, p["router"], experts, i, top_k=cfg.experts_per_token,
                 renormalise=cfg.norm_topk_prob, bias=p.get("router_bias"),
-                scale=getattr(cfg, "routed_scaling_factor", None),
+                scale=cfg.routed_scaling_factor,
                 shared=p.get("shared"))
             hits.append(n)
             return out
@@ -288,6 +289,25 @@ def scan_routed_layers(cfg, layers, body, carry, first: int = 0):
             step, (carry, jnp.int32(0)),
             (stacked, jnp.arange(n_routed, dtype=jnp.int32)))
     return carry, hit
+
+
+def served_routed_walk(scan_layers, cfg, params, x, caches, positions, attend,
+                       attention=attention_block):
+    """``llama.served_walk`` for a family whose layers ``scan_layers(cfg,
+    params, body, carry)`` walks with routed feed-forwards: the pools in
+    the carry, ``attend`` and ``attention`` the family's own, and the
+    experts its routed layers read counted as ``experts_read``."""
+    cache_k, cache_v, state = caches
+
+    def body(carry, p, li, feed_forward):
+        x, ck, cv = carry
+        x, (ck, cv) = decoder_layer(cfg, p, x, positions, attend,
+                                    (ck, cv, li), feed_forward, attention)
+        return x, ck, cv
+
+    (x, cache_k, cache_v), hit = scan_layers(cfg, params, body,
+                                             (x, cache_k, cache_v))
+    return x, (cache_k, cache_v, state), {"experts_read": hit}, None
 
 
 def dispatch(hf, weights, chosen, experts, layer):

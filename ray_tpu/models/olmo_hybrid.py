@@ -66,6 +66,11 @@ from ray_tpu.models import llama
 from ray_tpu.models.llama import embed, gated_mlp, head, qkv_rope, rms_norm
 from ray_tpu.ops import gated_delta
 
+_STATE_BESIDE = ("{cfg.__class__.__name__} has recurrent layers whose state is "
+                 "a row a slot beside the pages, which this engine does not "
+                 "serve with %s ({where}): pages alone carry nothing of the "
+                 "state at their end")
+
 
 @dataclass(frozen=True)
 class OlmoHybridConfig:
@@ -121,6 +126,23 @@ class OlmoHybridConfig:
             "conv": (layers * (self.conv_width - 1), (self.conv_channels,),
                      jnp.dtype(dtype or self.dtype)),
         }
+
+    # What the engine and the served programs ask of a family (llm/model.py
+    # says who owns which decision), beside ``cache_layout`` below.
+    block_length = 0  # it generates a token at a time
+    refuses = {
+        "pd": _STATE_BESIDE % "prefill/decode disaggregation",
+        "kv_tier": _STATE_BESIDE % "the KV tier",
+        "prefix_cache": "{where} serves no model with recurrent layers: the "
+                        "pages of a prefix hold nothing of their state at "
+                        "its end",
+    }
+
+    def serving_layout(self, params):
+        return serving_layout(params)
+
+    def served_walk(self, params, x, caches, positions, via):
+        return served_walk(self, params, x, caches, positions, via)
 
     def cache_layout(self) -> dict:
         """What the served programs cache (``llm/model.py cache_layout``):
@@ -367,6 +389,32 @@ def scan_periods(cfg, params, body, carry):
         return jax.lax.scan(
             step, carry, (params["layers"]["full"],
                           jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+
+
+def served_walk(cfg, params, x, caches, positions, via):
+    """``llama.served_walk`` over PERIODS: the recurrent layers of a period
+    unrolled in the body, then its full layer, with the state rows beside
+    the pools in the carry, whole.  ``via["recur"](mix, qkv, b, a, (state,
+    li)) -> (o, (state, left))`` updates a layer's rows in place, or leaves
+    them be and hands back as ``left`` what the program is to write once
+    the scan is over: the fourth thing returned, [periods, ...] in a list by
+    place in the period."""
+    n = cfg.lin_per_period
+
+    def body(carry, lin, full, period):
+        x, ck, cv, st = carry
+        left = []
+        for j in range(n):
+            li = period * n + j
+            x, (st, out) = linear_layer(cfg, lin(li), x, via["recur"],
+                                        (st, li))
+            left.append(out)
+        x, (ck, cv) = full_layer(cfg, full, x, positions, via["attend"],
+                                 (ck, cv, period))
+        return (x, ck, cv, st), left
+
+    (x, *caches), left = scan_periods(cfg, params, body, (x, *caches))
+    return x, tuple(caches), {}, left
 
 
 def apply(params, tokens, cfg: OlmoHybridConfig):

@@ -25,10 +25,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import embed, head, layer
-from ray_tpu.models.moe import scan_routed_layers
+from ray_tpu.models.llama import LlamaConfig, embed, head, layer
+from ray_tpu.models.moe import scan_routed_layers, served_routed_walk
 
 STRATEGIES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+_BY_BLOCKS = ("{cfg.__class__.__name__} generates by diffusion over blocks of "
+              "{cfg.block_length} positions, which this engine does not "
+              "serve with ")
 
 
 @dataclass(frozen=True)
@@ -63,6 +67,24 @@ class SDARMoEConfig:
             raise ValueError(
                 f"denoising_steps {self.denoising_steps} must lie in 1.."
                 f"block_length ({self.block_length})")
+
+    # What the engine and the served programs ask of a family (llm/model.py):
+    # ``block_length`` and ``mask_token_id`` are the fields above; the
+    # chosen experts' weights are not scaled; the tree and the pools are
+    # the Llama family's.
+    routed_scaling_factor = None
+    cache_layout = LlamaConfig.cache_layout
+    serving_layout = LlamaConfig.serving_layout
+    refuses = {
+        "sampling": _BY_BLOCKS + "sampling at {where} (greedy only)",
+        "pd": _BY_BLOCKS + "prefill/decode disaggregation ({where}): a "
+              "prefill of this model yields no first token to ship, and a "
+              "slot opens on a block, not on a shipped token",
+    }
+
+    def served_walk(self, params, x, caches, positions, via):
+        return served_routed_walk(scan_layers, self, params, x, caches,
+                                  positions, via["attend"])
 
     @staticmethod
     def sdar_30b_a3b() -> "SDARMoEConfig":

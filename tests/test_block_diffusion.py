@@ -259,9 +259,9 @@ def test_prefill_and_passes_through_pages_equal_the_cacheless_forward(
         pos = np.arange(L)
         rows = np.where(pos < P * ps, tables[i][np.minimum(pos // ps, P - 1)],
                         0)
-        _, ck, cv = lm.prefill(params, jnp.asarray(padded), ck, cv,
-                               jnp.asarray(rows), jnp.int32(full),
-                               jnp.asarray(pos % ps), cfg)
+        _, _, ck, cv, _ = lm.prefill(
+            params, jnp.asarray(padded), ck, cv, jnp.asarray(rows),
+            jnp.int32(full), jnp.asarray(pos % ps), cfg)
         tokens[i, :len(p) - full] = p[full:]
         masked[i, :len(p) - full] = False
         starts[i] = full
@@ -377,30 +377,6 @@ def test_preempted_requests_resume_to_the_same_tokens(params, monkeypatch):
     finally:
         engine.stop()
     assert got == _expected(cfg, params, prompts, 26)
-
-
-@pytest.mark.parametrize("path", ["temperature", "prefill_extract",
-                                  "submit_with_kv"])
-def test_paths_that_cannot_serve_block_diffusion_refuse_it_by_name(
-        params, path):
-    """Greedy only (sampled requests: ROADMAP M7), and no prefill/decode
-    disaggregation (``llm/pd_disagg.py`` reaches the engine through these
-    two calls alone): a prefill of this model yields no first token to ship.
-    The KV tier's seal and ``llm/batch.py`` need no refusal: the tier moves
-    whole pages, which the engine registers only when their K/V is final,
-    and the batch stage calls ``submit``."""
-    engine = _engine(params, _cfg())
-    try:
-        with pytest.raises(ValueError, match="SDARMoEConfig generates by "
-                                             "diffusion over blocks of 4"):
-            if path == "temperature":
-                engine.submit([5, 6, 7], SamplingParams(temperature=0.7))
-            elif path == "prefill_extract":
-                engine.prefill_extract([5, 6, 7, 8, 9])
-            else:
-                engine.submit_with_kv([5, 6, 7], 9, None, None)
-    finally:
-        engine.stop()
 
 
 def test_a_block_must_lie_in_one_page(params):

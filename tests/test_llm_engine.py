@@ -178,17 +178,17 @@ def test_program_logits_match_full_forward(program):
         return lm.prefill(params, tokens, *cache, rows, true_len, slots, cfg)
 
     if program == "prefill":
-        got, _, _ = prefill(n, 32)
+        got, *_ = prefill(n, 32)
     elif program == "prefill_with_prefix":
-        _, *cache = prefill(2 * ps, 16)  # a 2-page resident prefix
+        _, _, *cache, _ = prefill(2 * ps, 16)  # a 2-page resident prefix
         tokens, rows, true_len, slots, positions = prefill_args(2 * ps, n, 16)
-        got, _, _ = lm.prefill_with_prefix(
+        got, *_ = lm.prefill_with_prefix(
             params, tokens, *cache, rows, true_len, slots,
             jnp.asarray(table), positions, cfg)
     else:
-        _, *cache = prefill(n - 1, 32)
+        _, _, *cache, _ = prefill(n - 1, 32)
         # slot 1 decodes the prompt's last token; slot 0 is empty
-        got, _, _ = lm.decode_step(
+        got, *_ = lm.decode_step(
             params, jnp.asarray([0, prompt[-1]], jnp.int32), *cache,
             jnp.asarray(np.stack([np.zeros_like(table), table])),
             jnp.asarray([0, n - 1], jnp.int32), jnp.asarray([False, True]),
@@ -250,7 +250,7 @@ def test_prefill_writes_its_rows_and_nothing_else(program):
         want = plain_kv(tokens, causal & (positions[None, :] < n))
     else:  # pages 3 and 5 resident, the suffix lands on page 2
         start = 2 * ps
-        _, *cache = lm.prefill(
+        _, _, *cache, _ = lm.prefill(
             params, jnp.asarray(prompt[:start]), *cache,
             jnp.asarray(table[positions[:start] // ps]), jnp.int32(start),
             jnp.asarray(positions[:start] % ps), cfg)
@@ -260,7 +260,7 @@ def test_prefill_writes_its_rows_and_nothing_else(program):
     rows, slots = table[written // ps], written % ps
     # the suffix program also takes the page table and absolute positions
     through = (jnp.asarray(table), jnp.asarray(written)) if start else ()
-    _, *after = getattr(lm, program)(
+    _, _, *after, _ = getattr(lm, program)(
         params, jnp.asarray(tokens[start:]), *cache, jnp.asarray(rows),
         jnp.int32(n - start), jnp.asarray(slots), *through, cfg)
     named = np.zeros(shape[1:3], bool)
@@ -356,7 +356,7 @@ def test_programs_on_the_serving_layout_equal_the_three_weight_tree(
 
         if program == "prefill":
             return prefill(0, n, 32, False)
-        _, *cache = prefill(0, 2 * ps, 16, False)
+        _, _, *cache, _ = prefill(0, 2 * ps, 16, False)
         if program == "prefill_with_prefix":
             return prefill(2 * ps, n, 16, True)
         tables = jnp.asarray(np.stack([np.zeros_like(table), table]))

@@ -350,3 +350,68 @@ def test_outputs_are_bit_for_bit_those_without_scopes(name, monkeypatch):
 def _scoped(compiled) -> bool:
     return any(part_runs(n, PARTS) for n in re.findall(
         r'op_name="(jit\([^"]*)"', compiled.as_text()))
+
+
+# -- a family says what it is once -------------------------------------------
+
+# what llm/model.py and llm/engine.py ask of a configuration class
+ASKED = ("cache_layout", "serving_layout", "served_walk", "refuses",
+         "block_length")
+# ``lm.cache_layout(cfg)`` as PR 44 returned it, for the benchmark's
+# configurations (benchmarks/configs/*.json) at four layers
+CACHE_LAYOUTS = {
+    "mistral7b_serve_1chip": {"n_layers": 4, "n_kv_heads": 8,
+                              "head_dim": 128},
+    "sdar30b_a3b_serve_1chip": {"n_layers": 4, "n_kv_heads": 4,
+                                "head_dim": 128},
+    "olmo_hybrid7b_serve_1chip": {
+        "n_layers": 1, "n_kv_heads": 32, "head_dim": 128, "state_layers": 3,
+        "state_rows": {"S": (3, (15, 96, 384), jnp.float32),
+                       "conv": (9, (11520,), jnp.dtype("bfloat16"))}},
+    "glm47_flash_serve_1chip": {"n_layers": 4, "latent_dim": 640},
+}
+# how a program found a family out for itself before it was told
+SNIFFED = ('"lin" in', '"experts" in', '"wkv_b"', '"w_uk"',
+           '"dense" in params', 'getattr(cfg, "block_length"',
+           'getattr(cfg, "cache_layout"', "getattr(model_cfg",
+           "getattr(self.model_cfg")
+
+
+@pytest.mark.parametrize("config", list(CACHE_LAYOUTS))
+def test_a_configuration_class_answers_everything_it_is_asked(config):
+    """Each of the four served families: every question of the seam is
+    answered by the class itself (no ``getattr`` default is reached), by
+    class members that are no dataclass fields (``hash`` and ``==`` are
+    what they were), and what it says it caches is what it cached."""
+    from benchmarks import common
+
+    c = common.load_json("configs", config + ".json")
+    cfg = common.module("families", c["family"]).model_config(
+        {**c, "num_hidden_layers": 4})
+    for name in ASKED:
+        assert hasattr(type(cfg), name), name
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    assert not fields & (set(ASKED) - {"block_length"})
+    if cfg.block_length:  # a field where blocks are how it generates
+        assert "block_length" in fields and cfg.mask_token_id >= 0
+    assert cfg == dataclasses.replace(cfg)
+    assert hash(cfg) == hash(dataclasses.replace(cfg))
+    assert lm.cache_layout(cfg) == cfg.cache_layout() == CACHE_LAYOUTS[config]
+    CacheConfig(**cfg.cache_layout(), max_slots=2)  # the engine's own call
+    for feature, why in cfg.refuses.items():  # sentences ``lm.refuse`` fills
+        assert "{where}" in why and "{" not in why.format(cfg=cfg, where="x")
+
+
+def test_only_serving_layout_knows_a_family_by_its_tree():
+    """The engine and the programs hold none of the ways they once found a
+    family out, but for ``lm.serving_layout``'s fallback for a caller with
+    a tree and no configuration."""
+    import inspect
+
+    from ray_tpu.llm import engine
+
+    fallback = inspect.getsource(lm.serving_layout)
+    assert '"lin" in' in fallback
+    for module in (lm, engine):
+        source = inspect.getsource(module).replace(fallback, "")
+        assert not [s for s in SNIFFED if s in source]

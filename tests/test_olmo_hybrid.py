@@ -217,10 +217,11 @@ def test_prefill_and_decode_programs_equal_the_reference(params, n):
     padded[:n] = tokens[:n]
     rows = np.array([pages[i // ps] if i // ps < len(pages) else 0
                      for i in range(bucket)], np.int32)
-    lg, ck, cv, st = lm.prefill(
+    lg, counted, ck, cv, st = lm.prefill(
         tree, jnp.asarray(padded), ck, cv, jnp.asarray(rows), jnp.int32(n),
         jnp.asarray(np.arange(bucket) % ps), cfg, st, jnp.int32(slot))
     np.testing.assert_allclose(lg, want[n - 1], atol=TOL)
+    assert counted == {}  # a dense walk counts nothing
     assert float(jnp.abs(st["S"][:, 0] - 1).max()) == 0  # not its row
     tables = np.zeros((slots, 16), np.int32)
     tables[slot, :len(pages)] = pages
@@ -228,7 +229,7 @@ def test_prefill_and_decode_programs_equal_the_reference(params, n):
     for j in range(steps):
         tok = np.zeros(slots, np.int32)
         tok[slot] = tokens[n + j]
-        lg, ck, cv, st = lm.decode_step(
+        lg, _, ck, cv, st = lm.decode_step(
             tree, jnp.asarray(tok), ck, cv, jnp.asarray(tables),
             jnp.asarray(np.where(active, n + j, 0).astype(np.int32)),
             jnp.asarray(active), cfg, st)
@@ -400,25 +401,3 @@ def test_a_prefix_hit_is_not_taken(params):
     assert first == again
     assert st["prefill_tokens_saved"] == 0 and st["resident_pages"] == 0
     assert st["prefills"] == 2 and st["state_resets"] == 2
-
-
-@pytest.mark.parametrize("path", ["prefill_extract", "submit_with_kv",
-                                  "kv_prehydrate"])
-def test_paths_that_move_pages_alone_refuse_recurrent_state_by_name(
-        params, path):
-    """P/D ships pages and the KV tier seals and pulls them: neither
-    carries the state at the pages' end.  A tier handed to the engine (a
-    server hands every engine its worker's) is let go."""
-    engine = LLMEngine(params, _cfg(), EngineConfig(
-        max_slots=2, num_pages=16, max_seq_len=128,
-        prefill_buckets=(64, 128)), kv_tier=object())
-    assert engine.kv_tier is None
-    with pytest.raises(ValueError, match="OlmoHybridConfig has recurrent "
-                                         "layers .* does not serve with"):
-        if path == "prefill_extract":
-            engine.prefill_extract([5, 6, 7, 8, 9])
-        elif path == "submit_with_kv":
-            engine.submit_with_kv([5, 6, 7], 9, None, None)
-        else:
-            engine.kv_prehydrate(["00"])
-    assert engine._thread is None  # refused before anything started
