@@ -18,6 +18,11 @@ class EngineConfig:
     page_size: int = 16
     max_seq_len: int = 1024
     prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024)
+    # pages of EACH window layer's pool, for a model with window layers
+    # (paged_cache.py); 0: max_slots x (window // page_size + 4), a live
+    # slot's window and a burst, and at least what one prompt in chunks
+    # holds at once
+    window_pages: int = 0
 
     def bucket_for(self, n: int) -> int:
         for b in self.prefill_buckets:

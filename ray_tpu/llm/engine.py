@@ -16,6 +16,16 @@ positions, a decode step is one denoising pass over every slot's block
 is final only after a pass whose input held no mask, and a prefill emits
 nothing.  The loop, its phases, admission, the page pool, the prefix cache
 and preemption are the same code; what differs is marked "block" below.
+
+A prompt longer than the largest prefill bucket C is computed in CHUNKS of
+C: the first by ``lm.prefill``, the rest by ``lm.prefill_with_prefix`` over
+what the earlier chunks wrote (the program a prefix hit runs), ONE chunk an
+iteration of the loop, so a decode burst runs between two chunks whenever a
+slot is live.  The slot is taken at the first chunk and decodes after the
+last (``_Slot.prefill_at``); a ``prefill_only`` request (P/D) holds no slot
+and runs all its chunks inline before its pages ship.  A model with window layers (``cfg.window``,
+paged_cache.py) has a second allocator and a second page list a slot
+(``wpages``); what differs is marked "window" below.
 """
 
 from __future__ import annotations
@@ -113,6 +123,28 @@ def _engine_metrics():
                     "LATENT rows (one row a token a layer, key and value "
                     "both): x page_size x the row's bytes x layers = what "
                     "the latent decode kernel has to read"),
+                "prefill_chunks": Counter(
+                    "llm_prefill_chunks_total", "Prefill executions that "
+                    "computed one chunk of a prompt longer than the "
+                    "largest prefill bucket"),
+                "window_pages_freed": Counter(
+                    "llm_window_pages_freed_total", "Window layers: pages "
+                    "given back to their pool while the sequence lived, "
+                    "because they lay wholly behind its window"),
+                "window_pages_read": Counter(
+                    "llm_window_pages_read_total", "Window layers: pages a "
+                    "decode step's kernel walked a layer, from the page "
+                    "that holds length - window to the slot's length, "
+                    "summed over steps and active slots"),
+                "window_pages_skipped": Counter(
+                    "llm_window_pages_skipped_total", "Window layers: "
+                    "pages up to a slot's length that the bound left "
+                    "unread a layer (read + skipped = what a full layer "
+                    "walks)"),
+                "full_pages_read": Counter(
+                    "llm_full_pages_read_total", "Of a model with window "
+                    "layers, pages a decode step's kernel walked in a "
+                    "FULL layer"),
                 "tokens": Counter(
                     "llm_tokens_total", "Tokens emitted to callers"),
                 "deliveries": Counter(
@@ -180,6 +212,10 @@ def _engine_metrics():
                     "pages in use"),
                 "waiting": Gauge(
                     "llm_waiting", "Requests queued awaiting admission"),
+                "pages_in_use": Gauge(
+                    "llm_pages_in_use", "Pages live sequences hold, by "
+                    "kind of pool (full | window; a model with one kind "
+                    "of page: full)", tag_keys=("kind",)),
             }
         return _METRICS
 
@@ -233,7 +269,9 @@ _PHASE_ATTRS = {
     # ``steps`` and ``state_slots``, the live slots whose state rows the
     # burst's steps updated, summed over them; so does a token-at-a-time
     # model with routed experts or latent pages: ``steps``, the
-    # ``experts_read`` by the burst's steps and the ``latent_pages_read``
+    # ``experts_read`` by the burst's steps and the ``latent_pages_read``;
+    # a model with window layers ``window_pages_read``,
+    # ``window_pages_skipped`` and ``full_pages_read`` (a layer of a kind)
     P_DECODE_EMIT: ("tokens", "slots_released", "slot_passes",
                     "masks_filled", "blocks_final", "experts_read",
                     "passes"),
@@ -423,6 +461,12 @@ class _Slot:
     blk_masked: List[bool] = field(default_factory=list)
     blk_given: int = 0
     blk_step: int = 0  # passes this block has had
+    # a prompt in chunks: the position its next chunk begins at (the slot
+    # does not decode yet); None once the whole prompt is computed
+    prefill_at: Optional[int] = None
+    # window: the window layers' pages BY ABSOLUTE PAGE INDEX, the null
+    # page 0 where one was given back (paged_cache.py)
+    wpages: List[int] = field(default_factory=list)
 
 
 class LLMEngine:
@@ -464,11 +508,32 @@ class LLMEngine:
                 f"({self.cfg.page_size}) and max_seq_len "
                 f"({self.cfg.max_seq_len}): a block lies in one page")
         layout = model_cfg.cache_layout()
+        # window: positions a window layer sees (0: the model has none)
+        self._window = int(model_cfg.window)
+        ps = self.cfg.page_size
+        # a prompt past the largest bucket is computed in chunks of it
+        self._chunk = int(self.cfg.prefill_buckets[-1])
+        # window: what one sequence holds of a window layer's pool at most
+        # (CacheConfig.window_pages_per_seq), and the pool's default size
+        most = -(-(self._window + self._chunk) // ps) + 1
         ccfg = CacheConfig(
             **layout, num_pages=self.cfg.num_pages,
-            page_size=self.cfg.page_size, dtype=model_cfg.dtype,
-            max_slots=self.cfg.max_slots)
-        # (for latent pages cache_k is the one pool and cache_v None)
+            page_size=ps, dtype=model_cfg.dtype,
+            max_slots=self.cfg.max_slots,
+            window_pages=(self.cfg.window_pages or max(
+                self.cfg.max_slots * (self._window // ps + 4), most + 1))
+            if self._window else 0)
+        self.window_allocator: Optional[PageAllocator] = None
+        if self._window:
+            if most > ccfg.window_pages - 1:
+                raise ValueError(
+                    f"one sequence holds up to {most} pages of a window "
+                    f"layer (window {self._window} + a chunk of "
+                    f"{self._chunk}): window_pages {ccfg.window_pages} "
+                    f"cannot admit one")
+            self.window_allocator = PageAllocator(ccfg.window_pages)
+        # (for latent pages cache_k is the one pool and cache_v None; for
+        # pools by layer type each is a dict of a pool a kind)
         self.cache_k, self.cache_v = init_cache(ccfg)
         self._latent = "latent_dim" in layout
         # recurrent layers' rows, a slot each (None: the model has none)
@@ -522,7 +587,9 @@ class LLMEngine:
                        "eviction_scans": 0,
                        "prefill_tokens_saved": 0, "cow_copies": 0,
                        "kv_seals": 0, "kv_pulls": 0, "kv_pull_pages": 0,
-                       "kv_pull_fallbacks": 0}
+                       "kv_pull_fallbacks": 0, "prefill_chunks": 0,
+                       "window_pages_freed": 0, "window_pages_read": 0,
+                       "window_pages_skipped": 0, "full_pages_read": 0}
         # Hit-aware admission (ISSUE 14): under pool pressure prefer the
         # waiting request whose prefix is resident, but never once the
         # head of the queue has waited longer than this cap (seconds) —
@@ -557,6 +624,10 @@ class LLMEngine:
             raise ValueError(
                 f"prompt+max_tokens = {total} exceeds max_seq_len "
                 f"{self.cfg.max_seq_len}")
+        if len(prompt_tokens) > self._chunk:
+            self._refuse("chunked_prompt",
+                         f"a prompt of {len(prompt_tokens)} tokens, over "
+                         f"the largest prefill bucket {self._chunk},")
         # Page 0 is the reserved null page, so only num_pages-1 are ever
         # allocatable: an infeasible request would otherwise sit at the
         # queue head forever, wedging the engine for everyone behind it.
@@ -579,6 +650,10 @@ class LLMEngine:
         the KV page arrays a decode engine injects via submit_with_kv.
         Pages are freed here immediately; this engine keeps no state."""
         self._refuse("pd", "prefill_extract")
+        if len(prompt_tokens) > self._chunk:
+            self._refuse("chunked_prompt",
+                         f"a prompt of {len(prompt_tokens)} tokens, over "
+                         f"the largest prefill bucket {self._chunk},")
         self.start()
         params = params or SamplingParams()
         req = _Request(request_id=uuid.uuid4().hex[:12],
@@ -667,6 +742,7 @@ class LLMEngine:
                 "kv_tier": (self.kv_tier.stats()
                             if self.kv_tier is not None else None),
                 "free_pages": self.allocator.num_free(),
+                **self._pages_in_use(),
                 "waiting": self._waiting.qsize(),
                 # prefix-cache plane (ISSUE 10): hit/miss + resident pages
                 # + recent block digests — the router's KV-locality signal
@@ -720,7 +796,7 @@ class LLMEngine:
                 for i, s in enumerate(self._slots):
                     if s is not None:
                         self._fail(s.request, e)
-                        self.allocator.free(s.pages)
+                        self._free_pages(s)
                         self._slots[i] = None
                 while True:
                     try:
@@ -754,6 +830,21 @@ class LLMEngine:
             m["page_occupancy"].set(1.0 - free / allocatable)
         m["waiting"].set(self._waiting.qsize())
         m["prefix_resident"].set(self.allocator.num_resident())
+        for name, n in self._pages_in_use().items():
+            if name.endswith("_pages_in_use"):
+                m["pages_in_use"].set(n, {"kind": name.split("_")[0]})
+
+    def _pages_in_use(self) -> dict:
+        """Pages live sequences hold, by kind of pool, and (window) the
+        tokens those sequences hold: page bytes over them is what a
+        resident token costs."""
+        live = [s for s in self._slots if s is not None]
+        out = {"full_pages_in_use": sum(len(s.pages) for s in live)}
+        if self._window:
+            out["window_pages_in_use"] = sum(
+                sum(p != 0 for p in s.wpages) for s in live)
+            out["live_tokens"] = sum(s.num_tokens for s in live)
+        return out
 
     # -------------------- per-request trace anatomy (ISSUE 20) -------------
 
@@ -858,6 +949,12 @@ class LLMEngine:
         (vLLM analogue: Scheduler admitting to the running batch)."""
         admitted = False
         ph = self._ph
+        # a prompt in chunks goes on before anything new is admitted, one
+        # chunk an iteration: the decode burst of the live slots follows
+        for i, s in enumerate(self._slots):
+            if s is not None and s.prefill_at is not None:
+                self._next_chunk(i, s)
+                return True
         while True:
             # everything up to the prefill is the admit phase; the phase a
             # prefill leaves open (prefill_emit) lasts through the slot
@@ -892,7 +989,13 @@ class LLMEngine:
                 rng = (np.random.default_rng(req.params.seed)
                        if req.params.temperature > 0 else None)
                 try:
-                    last = self._prefill(req, pages, rng)
+                    # inline, so a prompt over the largest bucket runs all
+                    # its chunks here: the pages ship only when every row
+                    # behind them is written
+                    last, done = self._prefill(req, pages, rng)
+                    while done < self._prompt_end(req):
+                        last, done = self._prefill(req, pages, rng, done,
+                                                   later_chunk=True)
                     idx = np.asarray(pages)
                     kv_k = np.asarray(self.cache_k[:, idx])
                     kv_v = np.asarray(self.cache_v[:, idx])
@@ -950,7 +1053,11 @@ class LLMEngine:
             # BEFORE eviction can consider them
             pin = matched + ([cow_src] if cow_src is not None else [])
             self.allocator.retain(pin)
-            if not self._reserve(need_total - len(matched)):
+            # window: the first chunk's pages of the window layers (the
+            # full layers' are taken for the whole prompt here)
+            wneed = self._window_short([], 0, n)
+            if not (self._reserve(need_total - len(matched)) and (
+                    not wneed or self.window_allocator.can_allocate(wneed))):
                 self.allocator.free(pin)  # unpin; stays resident
                 self._waiting.queue.appendleft(req)  # type: ignore[attr-defined]
                 ph.vals = ("no_pages",)
@@ -958,6 +1065,7 @@ class LLMEngine:
             ph.vals = ("admitted",)
             pages = matched + self.allocator.allocate(
                 need_total - len(matched))
+            wpages = self.window_allocator.allocate(wneed) if wneed else []
             prefix_len = len(matched) * self.cfg.page_size
             rng = (np.random.default_rng(req.params.seed)
                    if req.params.temperature > 0 else None)
@@ -1004,10 +1112,12 @@ class LLMEngine:
                         prefix_len += cow_len
                         self._stats["cow_copies"] += 1
                         self._m["cow_copies"].inc()
-                    last = self._prefill(req, pages, rng, prefix_len,
-                                         free_slot)
+                    last, done = self._prefill(req, pages, rng, prefix_len,
+                                               free_slot, wpages)
             except Exception as e:  # noqa: BLE001 — surface to caller
                 self.allocator.free(pages)
+                if wpages:
+                    self.window_allocator.free(wpages)
                 self._fail(req, e)
                 self._close_request_span(req, ok=False, error=repr(e))
                 continue
@@ -1023,78 +1133,194 @@ class LLMEngine:
                 if prefix_len:
                     self._m["prefix_hit"].inc(prefix_len)
                     self._m["prefill_saved"].inc(prefix_len)
-            # every full prompt page — freshly computed or injected — is
-            # now index-able for later prompts sharing the prefix
-            self._register_blocks(req.prompt_tokens, pages)
-            if self._block:
-                # block: no token follows from a prefill; the slot opens on
-                # the prompt's tail and masks
-                self._slots[free_slot] = self._open_block(req, pages)
-                admitted = True
-                continue
-            slot = _Slot(request=req, pages=pages,
-                         num_tokens=len(req.prompt_tokens),
-                         last_token=last, rng=rng)
-            if last in req.params.stop_token_ids:
-                self._end_stream(req)
-                self.allocator.free(pages)
-            else:
-                slot.generated.append(last)
-                if req.kind == "decode_kv":
-                    # the prefill engine already delivered this token to
-                    # the caller; count it, don't re-emit
-                    self._stats["tokens_generated"] += 1
-                    req.produced += 1
-                else:
-                    self._emit(slot, [last])
-                if req.produced >= req.params.max_tokens:
-                    self._end_stream(req)
-                    self.allocator.free(pages)
-                else:
-                    self._slots[free_slot] = slot
+            slot = _Slot(request=req, pages=pages, num_tokens=n,
+                         last_token=-1, rng=rng, wpages=wpages)
+            if req.kind != "decode_kv" and done < self._prompt_end(req):
+                # a prompt in chunks: the slot is taken and decodes after
+                # its last chunk; this iteration goes on to the live
+                # slots' burst
+                slot.num_tokens = slot.prefill_at = done
+                self._trim_window(slot)
+                self._slots[free_slot] = slot
+                return True
+            self._seat(free_slot, slot, last)
             admitted = True
+
+    def _prompt_end(self, req: _Request) -> int:
+        """The position a request's prefill ends at: its prompt's end
+        (block: the end of the prompt's whole blocks)."""
+        n = len(req.prompt_tokens)
+        return n - n % self._block if self._block else n
+
+    def _seat(self, i: int, slot: _Slot, last: Optional[int]) -> None:
+        """What follows the prefill of a WHOLE prompt (its one program, or
+        its last chunk): the prompt's full pages are index-able, and the
+        sequence takes slot ``i`` on the token ``last`` that follows the
+        prompt unless that token ends it (block: on its open block)."""
+        req, pages = slot.request, slot.pages
+        # every full prompt page — freshly computed or injected — is
+        # now index-able for later prompts sharing the prefix
+        self._register_blocks(req.prompt_tokens, pages)
+        if self._block:
+            # block: no token follows from a prefill; the slot opens on
+            # the prompt's tail and masks
+            self._slots[i] = self._open_block(req, pages)
+            return
+        slot.last_token = last
+        self._slots[i] = None
+        if last in req.params.stop_token_ids:
+            self._end_stream(req)
+            self._free_pages(slot)
+            return
+        slot.generated.append(last)
+        if req.kind == "decode_kv":
+            # the prefill engine already delivered this token to
+            # the caller; count it, don't re-emit
+            self._stats["tokens_generated"] += 1
+            req.produced += 1
+        else:
+            self._emit(slot, [last])
+        if req.produced >= req.params.max_tokens:
+            self._end_stream(req)
+            self._free_pages(slot)
+        else:
+            self._slots[i] = slot
+
+    def _next_chunk(self, i: int, s: _Slot) -> None:
+        """The next chunk of the prompt that slot ``i`` is being admitted
+        on; after the last, the slot decodes."""
+        req = s.request
+        try:
+            # window: this chunk's pages of the window layers
+            short = self._window_short(s.wpages, s.prefill_at,
+                                       len(req.prompt_tokens))
+            if short:
+                if not self.window_allocator.can_allocate(short):
+                    self._preempt(i, s)  # resumes when the pool has room
+                    return
+                s.wpages.extend(self.window_allocator.allocate(short))
+            last, done = self._prefill(req, s.pages, s.rng, s.prefill_at, i,
+                                       s.wpages, later_chunk=True)
+        except Exception as e:  # noqa: BLE001 — surface to caller
+            self._free_pages(s)
+            self._slots[i] = None
+            self._fail(req, e)
+            self._close_request_span(req, ok=False, error=repr(e))
+            return
+        s.num_tokens = done
+        if done < self._prompt_end(req):
+            s.prefill_at = done
+            self._trim_window(s)
+            return
+        s.prefill_at = None
+        s.num_tokens = len(req.prompt_tokens)
+        self._seat(i, s, last)
+
+    def _window_short(self, wpages: List[int], start: int, n: int) -> int:
+        """window: pages of the window layers that the chunk from ``start``
+        of a prompt of ``n`` tokens still lacks (through the position after
+        it, which the first decode step writes); 0 without such layers."""
+        if not self._window:
+            return 0
+        return max(0, min(n, start + self._chunk) // self.cfg.page_size + 1
+                   - len(wpages))
+
+    def _free_pages(self, s: _Slot) -> None:
+        """Give back every page a sequence holds, of either kind."""
+        self.allocator.free(s.pages)
+        if s.wpages:
+            self.window_allocator.free(s.wpages)  # (skips the null entries)
+
+    def _trim_window(self, s: _Slot) -> int:
+        """window: give back the pages of the window layers that lie
+        wholly behind what the query at the slot's next position sees
+        (``num_tokens - window + 1`` on), leaving the null page in their
+        place; how many."""
+        if not self._window:
+            return 0
+        behind = min(max(0, s.num_tokens - self._window + 1)
+                     // self.cfg.page_size, len(s.wpages))
+        gone = [p for p in s.wpages[:behind] if p]
+        if gone:
+            self.window_allocator.free(gone)
+            s.wpages[:behind] = [0] * behind
+            self._count({"window_pages_freed": len(gone)})
+        return len(gone)
 
     def _prefill(self, req: _Request, pages: List[int],
                  rng: Optional[np.random.Generator],
-                 prefix_len: int = 0,
-                 slot: Optional[int] = None) -> Optional[int]:
-        """Compute the prompt's K/V past ``prefix_len`` and sample the
-        token that follows the prompt.  Block: the prompt's whole blocks
-        only (the tail opens the slot's first block), nothing sampled, and
-        no program at all when the hit covers them.  Recurrent layers: the
-        same program also begins ``slot``'s state rows anew."""
+                 prefix_len: int = 0, slot: Optional[int] = None,
+                 wpages: List[int] = (), later_chunk: bool = False) -> tuple:
+        """Compute the prompt's K/V past ``prefix_len``, ONE program's
+        worth: all of it, or where more than the largest bucket is left
+        the next chunk of that size.  Returns (the token that follows the
+        prompt, sampled, or None where this call did not reach the
+        prompt's end; the position it reached).  Block: the prompt's whole
+        blocks only (the tail opens the slot's first block), nothing
+        sampled, and no program at all when the hit covers them.
+        Recurrent layers: the same program also begins ``slot``'s state
+        rows anew.  window: ``wpages`` are the window layers' pages.
+        ``later_chunk``: what lies before ``prefix_len`` is this prompt's
+        own earlier chunks, not a prefix hit."""
         n = len(req.prompt_tokens)
         ps = self.cfg.page_size
         ph = self._ph
         ph.begin(P_PREFILL_HOST, req)
         t0 = time.monotonic()
         # pages[:prefix_len // ps] already hold a cached prefix's KV (none
-        # without a hit): compute only what follows it
-        suffix = req.prompt_tokens[prefix_len:
-                                   n - n % self._block if self._block else n]
+        # without a hit) or the earlier chunks': compute what follows it
+        end = self._prompt_end(req)
+        chunked = later_chunk or end - prefix_len > self._chunk
+        if end - prefix_len > self._chunk:
+            self._refuse("chunked_prompt",
+                         f"{end - prefix_len} tokens of a prompt left to "
+                         f"compute, over the largest prefill bucket "
+                         f"{self._chunk},")
+            if self._block and self._chunk % self._block:
+                raise ValueError(
+                    f"a chunk of {self._chunk} positions is no whole "
+                    f"number of blocks of {self._block}")
+            end = prefix_len + self._chunk
+        suffix = req.prompt_tokens[prefix_len:end]
         bucket = self.cfg.bucket_for(max(1, len(suffix)))
         tokens = np.zeros(bucket, np.int32)
         tokens[:len(suffix)] = suffix
         positions = prefix_len + np.arange(bucket, dtype=np.int32)
-        # map each padded position to (page, slot); positions beyond the
-        # allocated pages land in the null page (masked out of attention)
-        page_rows = np.zeros(bucket, np.int32)
-        for i in range(bucket):
-            pi = (prefix_len + i) // ps
-            page_rows[i] = pages[pi] if pi < len(pages) else 0
+
+        def rows_of(pages):
+            # map each padded position to (page, slot); positions beyond
+            # the allocated pages land in the null page (masked out of
+            # attention)
+            page_rows = np.zeros(bucket, np.int32)
+            for i in range(bucket):
+                pi = (prefix_len + i) // ps
+                page_rows[i] = pages[pi] if pi < len(pages) else 0
+            return jnp.asarray(page_rows)
+
+        def table_of(pages):
+            table = np.zeros(self.max_pages_per_seq, np.int32)
+            table[:len(pages)] = pages
+            return jnp.asarray(table)
+
+        def by_kind(make):  # window: one of each a kind of pool
+            return ({"full": make(pages), "window": make(wpages)}
+                    if self._window else make(pages))
+
         program = lm.prefill
-        args = (jnp.asarray(page_rows), jnp.int32(len(suffix)),
+        args = (by_kind(rows_of), jnp.int32(len(suffix)),
                 jnp.asarray(positions % ps))
         if prefix_len > 0:
             # attend through the full page table (suffix writes never
             # touch shared pages: every write position is >= prefix_len)
-            table = np.zeros(self.max_pages_per_seq, np.int32)
-            table[:len(pages)] = pages
             program = lm.prefill_with_prefix
-            args += (jnp.asarray(table), jnp.asarray(positions))
+            args += (by_kind(table_of), jnp.asarray(positions))
         tokens = jnp.asarray(tokens)
         ph.vals = (bucket, prefix_len)
         out, did = None, {}  # what the prefill did, by name, for its span
+        if chunked:  # which chunk of how many, of the largest bucket each
+            did = {"chunk": prefix_len // self._chunk,
+                   "chunks": -(-self._prompt_end(req) // self._chunk)}
+            self._count({"prefill_chunks": 1})
         if suffix or not self._block:
             ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
             logits, counted = self._run(program, tokens, *args, slot=slot)
@@ -1109,23 +1335,30 @@ class LLMEngine:
             counted = {name: int(n) for name, n in counted.items()}
             did.update(counted)
             self._count({**counted, "prefills": 1})
-            if logits is not None:  # else no token follows from a prompt
+            if logits is not None and end == self._prompt_end(req):
+                # (else no token follows: block, or more chunks to come)
                 out = self._sample_one(logits, req.params, rng)
         dt = time.monotonic() - t0
-        self._stats["admitted"] += 1
-        self._m["admitted"].inc()
         tid = req.trace_ctx[0] if req.trace_ctx else None
         self._m["prefill_t"].observe(dt, exemplar=tid)
-        qw = max(0.0, t0 - req.submitted_at)
-        self._m["queue_wait"].observe(qw, exemplar=tid)
+        first = not later_chunk
+        if first:
+            self._stats["admitted"] += 1
+            self._m["admitted"].inc()
+            qw = max(0.0, t0 - req.submitted_at)
+            self._m["queue_wait"].observe(qw, exemplar=tid)
         if req.trace_ctx is not None:
             w_end = time.time()
-            self._span(req, "llm.queue", req.submitted_wall,
-                       req.submitted_wall + qw, wait_s=round(qw, 6))
-            self._span(req, "llm.prefill", w_end - dt, w_end, tokens=n,
+            if first:
+                self._span(req, "llm.queue", req.submitted_wall,
+                           req.submitted_wall + qw, wait_s=round(qw, 6))
+            # ONE span a program: ``tokens`` the prompt's through this
+            # chunk, ``prefix_len`` those before it
+            self._span(req, "llm.prefill", w_end - dt, w_end,
+                       tokens=end if chunked else n,
                        prefix_len=prefix_len, resumed=bool(req.preempts),
                        **did)
-        if req.preempts:
+        if req.preempts and first:
             try:
                 from ray_tpu.util import events
 
@@ -1139,7 +1372,7 @@ class LLMEngine:
                     trace_id=tid)
             except Exception:
                 pass
-        return out
+        return out, end
 
     def _reserve(self, n: int) -> bool:
         """Make n pages allocatable, reclaiming prefix-cache pages as
@@ -1390,7 +1623,7 @@ class LLMEngine:
         req.kind = "normal"
         req.kv = None
         req.first_token = None
-        self.allocator.free(s.pages)
+        self._free_pages(s)
         self._slots[i] = None
         self._stats["preempted"] += 1
         self._m["preempted"].inc()
@@ -1456,18 +1689,32 @@ class LLMEngine:
                        self.max_pages_per_seq)
             return need - len(s.pages)
 
+        def need_window(s: _Slot) -> int:
+            # window: the same positions in the window layers' table (a
+            # prompt in chunks takes its pages chunk by chunk)
+            if not self._window or s.prefill_at is not None:
+                return 0
+            return need_pages(s) + len(s.pages) - len(s.wpages)
+
         # one pass over the prefix cache for the whole burst: when it
         # covers the sum every slot below finds its pages free; when it
         # cannot, everything reclaimable is already back and the loop
         # fails at the slot, and preempts the victim, it always did
         self._reserve(sum(max(0, need_pages(s)) for _, s in order))
         for i, s in order:
+            if self._slots[i] is s and s.prefill_at is None:
+                self._trim_window(s)  # window: before the burst's pages
             while self._slots[i] is s:
-                delta = need_pages(s)
-                if delta <= 0:
+                delta, wdelta = need_pages(s), need_window(s)
+                if delta <= 0 and wdelta <= 0:
                     break
-                if self._reserve(delta):
-                    s.pages.extend(self.allocator.allocate(delta))
+                if self._reserve(delta) and (
+                        wdelta <= 0
+                        or self.window_allocator.can_allocate(wdelta)):
+                    s.pages.extend(self.allocator.allocate(max(0, delta)))
+                    if wdelta > 0:
+                        s.wpages.extend(
+                            self.window_allocator.allocate(wdelta))
                     break
                 victim = min(
                     ((j, t) for j, t in enumerate(self._slots)
@@ -1478,8 +1725,9 @@ class LLMEngine:
                 # if we preempted ourselves the while condition exits
 
     def _decode_all(self) -> bool:
+        # (a slot whose prompt is still being computed in chunks waits)
         active_slots = [(i, s) for i, s in enumerate(self._slots)
-                        if s is not None]
+                        if s is not None and s.prefill_at is None]
         if not active_slots:
             return False
         ph = self._ph
@@ -1499,7 +1747,12 @@ class LLMEngine:
         # otherwise burst; admission is impossible until a sequence
         # finishes anyway.
         can_admit = False
-        if any(s is None for s in self._slots):
+        # (nor while a prompt is being computed in chunks: nothing new is
+        # admitted before its last chunk, and a burst of one step between
+        # two chunks would hold every live slot to a token a chunk)
+        if any(s is None for s in self._slots) and not any(
+                s is not None and s.prefill_at is not None
+                for s in self._slots):
             try:
                 head = self._waiting.queue[0]  # type: ignore[attr-defined]
                 n = len(head.prompt_tokens)
@@ -1516,7 +1769,7 @@ class LLMEngine:
         # preempting under pool pressure — slots may vanish here
         self._ensure_capacity(burst)
         active_slots = [(i, s) for i, s in enumerate(self._slots)
-                        if s is not None]
+                        if s is not None and s.prefill_at is None]
         if not active_slots:
             ph.vals = (0, burst)
             return True  # everything preempted; _admit resumes them
@@ -1540,14 +1793,29 @@ class LLMEngine:
         active_dev = jnp.asarray(active)
         # what the paged kernel walks: step j of the burst attends to
         # positions 0..position+j of each active slot, a page at a time
-        pages_read = int(np.minimum(
-            (positions[active][:, None] + np.arange(burst))
-            // self.cfg.page_size + 1, P).sum())
+        reached = np.minimum((positions[active][:, None] + np.arange(burst))
+                             // self.cfg.page_size + 1, P)
+        pages_read = int(reached.sum())
         # what the family adds to a burst's counts, by name: by the kind of
         # its cache here, and below what its steps' programs counted
         named = {}
         if self._latent:
             named["latent_pages_read"] = pages_read
+        if self._window:
+            # window: a second table, and what a layer of each kind walks:
+            # a window layer from the page that holds length - window on
+            # (arithmetic on positions and the window, what a bounded walk
+            # reads: the kernel counts nothing)
+            wtables = np.zeros((B, P), np.int32)
+            for i, s in active_slots:
+                wtables[i, :len(s.wpages)] = s.wpages
+            tables_dev = {"full": tables_dev, "window": jnp.asarray(wtables)}
+            skipped = int((np.maximum(
+                positions[active][:, None] + np.arange(burst) + 1
+                - self._window, 0) // self.cfg.page_size).sum())
+            named.update(full_pages_read=pages_read,
+                         window_pages_read=pages_read - skipped,
+                         window_pages_skipped=skipped)
         if self.state is not None:
             named["state_slot_steps"] = burst * len(active_slots)
         counts = []  # a step's ``counted``, on the device
@@ -1768,7 +2036,7 @@ class LLMEngine:
         self._end_stream(s.request)
         seq = s.request.prompt_tokens + s.generated
         self._register_blocks(seq[:s.num_tokens], s.pages)
-        self.allocator.free(s.pages)
+        self._free_pages(s)
         self._slots[i] = None
 
     # ------------------------- delivery ------------------------------------

@@ -15,6 +15,20 @@ way, which it hands the family's walk as one bundle ``via``:
   a gather through the page table, the decode step and ``block_step``
   ``ops/paged_attention`` (pages read where they lie, at KV-head width; a
   pool may hold more heads than the model, ``_pad_heads``);
+  A model with WINDOW layers beside full ones (``cfg.window`` > 0,
+  models/afmoe.py) has a pool a kind (paged_cache.py), so its pools, page
+  ids and page tables come as dicts ``{"full": ..., "window": ...}`` and
+  the program makes ``attend`` TWICE, ``via["attend_by_kind"][kind]``: the
+  family's walk hands a layer its kind's closure, pool and index among its
+  kind.  Which program sees which pages of which layer: ``prefill`` sees no
+  page of either (this call's own k and v under a causal mask, for a
+  window layer also ``qpos - kpos < window``); ``prefill_with_prefix``
+  gathers a FULL layer's keys through the whole full table and a WINDOW
+  layer's through the ``ceil((window + L) / page_size) + 1`` entries of the
+  window table that the chunk's L positions and the window before them
+  reach (the entries behind are null, paged_cache.py); the decode step
+  walks a full layer's pages ``0 .. length`` and a window layer's from the
+  page that holds ``length - window`` on;
 - ``attend_latent(q_nope, q_rope, row, a, (pool, None, li))``: latent rows
   into the ONE pool, then the prefills REBUILT (K and V made from the rows
   this call wrote or the page table reaches), the decode step ABSORBED
@@ -45,7 +59,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, olmo_hybrid,
+                            sdar_moe)
 from ray_tpu.models.llama import embed, head
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
@@ -67,6 +82,8 @@ def serving_layout(params):
         return olmo_hybrid.serving_layout(params)
     if "wkv_b" in attn or "w_uk" in attn:
         return glm_moe_lite.serving_layout(params)
+    if "wg" in attn or "each" in params["layers"]:
+        return afmoe.serving_layout(params)
     return llama.serving_layout(params)
 
 
@@ -125,12 +142,31 @@ def _conv_and_gates(cfg, mix, qkv, before, b, a):
     return (*olmo_hybrid.delta_inputs(cfg, mix, y, b, a), rows)
 
 
-def _visible(cfg, qpos, kpos):
+def _visible(cfg, qpos, kpos, window: int = 0):
     """[q, k] bool: may the query at ``qpos`` see the key at ``kpos``?
-    Causal; for a block-diffusion configuration causal over blocks."""
+    Causal; for a block-diffusion configuration causal over blocks; in a
+    window layer (``window`` > 0) the last ``window`` keys only, the
+    query's own among them."""
     if cfg.block_length:
         return sdar_moe.block_causal(qpos, kpos, cfg.block_length)
-    return kpos[None, :] <= qpos[:, None]
+    seen = kpos[None, :] <= qpos[:, None]
+    if window:
+        seen &= qpos[:, None] - kpos[None, :] < window
+    return seen
+
+
+def _by_kind(cfg, make):
+    """``via``'s attending closures: ``make(kind, window)`` once, or for a
+    model with window layers once a kind."""
+    if not cfg.window:
+        return {"attend": make(None, 0)}
+    return {"attend_by_kind": {"full": make("full", 0),
+                               "window": make("window", cfg.window)}}
+
+
+def _of(kind, x):
+    """``x``, or its ``kind``'s where a model has a pool a kind."""
+    return x if kind is None else x[kind]
 
 
 def _last_logits(params, x, cfg, true_len):
@@ -163,18 +199,25 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     valid = positions[None, :] < true_len
     mask = causal & valid
 
-    def attend(q, k, v, pools):
-        ck, cv, li = pools
-        # write k/v into this layer's pages (beyond true_len the rows
-        # write into the sequence's own pages — masked out of attention)
-        with jax.named_scope("attn/kv_write"):
-            n_pool = ck.shape[3]
-            ck = ck.at[li, page_rows, slot_positions].set(
-                _pad_heads(k, n_pool))
-            cv = cv.at[li, page_rows, slot_positions].set(
-                _pad_heads(v, n_pool))
-        # within the sequence: this call's own k and v, never the pool
-        return _masked_attention(cfg, q, k, v, mask), (ck, cv)
+    def attend_through(kind, window):
+        rows = _of(kind, page_rows)
+        seen = (mask & _visible(cfg, positions, positions, window)
+                if window else mask)
+
+        def attend(q, k, v, pools):
+            ck, cv, li = pools
+            # write k/v into this layer's pages (beyond true_len the rows
+            # write into the sequence's own pages — masked out of attention)
+            with jax.named_scope("attn/kv_write"):
+                n_pool = ck.shape[3]
+                ck = ck.at[li, rows, slot_positions].set(
+                    _pad_heads(k, n_pool))
+                cv = cv.at[li, rows, slot_positions].set(
+                    _pad_heads(v, n_pool))
+            # within the sequence: this call's own k and v, never the pool
+            return _masked_attention(cfg, q, k, v, seen), (ck, cv)
+
+        return attend
 
     def attend_latent(q_nope, q_rope, row, a, pools):
         pool, _, li = pools  # rebuilt from this call's own rows
@@ -199,7 +242,8 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
     # the scan carries no state: a prefill begins its slot's rows anew
     x, (cache_k, cache_v, _), counted, left = cfg.served_walk(
         params, x, (cache_k, cache_v, None), positions,
-        {"attend": attend, "attend_latent": attend_latent, "recur": recur})
+        {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
+         "recur": recur})
     if state is not None:
         # The slot's rows are written HERE, once, and not in the scan: a
         # row-sized update inside the loop lets XLA choose the carried
@@ -236,26 +280,44 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     Returns (logits at the last suffix token [V], counted, cache_k,
     cache_v, None).
     """
-    refuse(cfg, "prefix_cache", "prefill_with_prefix")
-    P = page_table.shape[0]
-    page_size = cache_k.shape[2]
+    refuse(cfg, "suffix_prefill", "prefill_with_prefix")
+    P = jax.tree.leaves(page_table)[0].shape[0]
+    page_size = jax.tree.leaves(cache_k)[0].shape[2]
     x = embed(params, tokens, cfg)  # [L, D]
 
-    def attend(q, k, v, pools):
-        ck, cv, li = pools
-        # suffix writes go to the sequence's own fresh pages only: matched
-        # prefix pages cover positions < prefix_len and are never written
-        with jax.named_scope("attn/kv_write"):
-            ck = ck.at[li, page_rows, slot_positions].set(k)
-            cv = cv.at[li, page_rows, slot_positions].set(v)
-        with jax.named_scope("attn/attend"):  # the gather is attending
-            keys = ck[li, page_table].reshape(
-                P * page_size, cfg.n_kv_heads, cfg.head_dim)
-            vals = cv[li, page_table].reshape(
-                P * page_size, cfg.n_kv_heads, cfg.head_dim)
-            # [L, T] causal over absolutes
-            mask = _visible(cfg, positions, jnp.arange(P * page_size))
-        return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
+    def attend_through(kind, window):
+        rows, table = _of(kind, page_rows), _of(kind, page_table)
+        reach, kpos = P, None  # pages gathered; the first one's position
+        if window:
+            # a window layer: the pages this call's positions and the
+            # window before the first of them reach, not the whole table
+            # (the entries behind them are null)
+            reach = min(P, -(-(window + tokens.shape[0]) // page_size) + 1)
+            first = jnp.clip((positions[0] - window + 1) // page_size, 0,
+                             P - reach)
+            table = jax.lax.dynamic_slice_in_dim(table, first, reach)
+            kpos = first * page_size
+
+        def attend(q, k, v, pools):
+            ck, cv, li = pools
+            # suffix writes go to the sequence's own fresh pages only:
+            # matched prefix pages cover positions < prefix_len and are
+            # never written
+            with jax.named_scope("attn/kv_write"):
+                ck = ck.at[li, rows, slot_positions].set(k)
+                cv = cv.at[li, rows, slot_positions].set(v)
+            with jax.named_scope("attn/attend"):  # the gather is attending
+                keys = ck[li, table].reshape(
+                    reach * page_size, cfg.n_kv_heads, cfg.head_dim)
+                vals = cv[li, table].reshape(
+                    reach * page_size, cfg.n_kv_heads, cfg.head_dim)
+                # [L, T] causal over absolutes
+                cols = jnp.arange(reach * page_size)
+                mask = _visible(cfg, positions,
+                                cols if kpos is None else kpos + cols, window)
+            return _masked_attention(cfg, q, keys, vals, mask), (ck, cv)
+
+        return attend
 
     def attend_latent(q_nope, q_rope, row, a, pools):
         pool, _, li = pools  # rebuilt from the rows the page table reaches
@@ -268,7 +330,7 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
 
     x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, None), positions,
-        {"attend": attend, "attend_latent": attend_latent})
+        {**_by_kind(cfg, attend_through), "attend_latent": attend_latent})
     return (_last_logits(params, x, cfg, true_len), counted, *caches)
 
 
@@ -286,37 +348,53 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
     (``ops/gated_delta.decode_update``: a live slot's state is read once
     and written once, the others' not at all).
     """
-    P = page_tables.shape[1]
-    page_size = cache_k.shape[2]
+    P = jax.tree.leaves(page_tables)[0].shape[1]
+    page_size = jax.tree.leaves(cache_k)[0].shape[2]
     x = embed(params, tokens, cfg)  # [B, D]
 
-    # where this step's k/v lands: slot b writes page_tables[b, pos//ps].
-    # Inactive slots, and a burst's overshoot past the table's last page,
-    # write into the null page (page 0) — harmless scratch
-    in_table = active & (positions < P * page_size)
-    write_page = jnp.take_along_axis(
-        page_tables, jnp.minimum(positions // page_size, P - 1)[:, None],
-        axis=1)[:, 0]
-    write_page = jnp.where(in_table, write_page, 0)
-    write_slot = positions % page_size
-    # attend up to and including the current token; 0 skips the slot
-    lengths = jnp.where(active, positions + 1, 0)
+    def coordinates(page_tables):
+        """(write_page, write_slot, lengths) through one kind's tables."""
+        # where this step's k/v lands: slot b writes page_tables[b, pos//ps].
+        # Inactive slots, and a burst's overshoot past the table's last page,
+        # write into the null page (page 0) — harmless scratch
+        in_table = active & (positions < P * page_size)
+        write_page = jnp.take_along_axis(
+            page_tables, jnp.minimum(positions // page_size, P - 1)[:, None],
+            axis=1)[:, 0]
+        write_page = jnp.where(in_table, write_page, 0)
+        write_slot = positions % page_size
+        # attend up to and including the current token; 0 skips the slot
+        lengths = jnp.where(active, positions + 1, 0)
+        return write_page, write_slot, lengths
 
-    def attend(q, k, v, pools):  # q: [B, H, d]; k, v: [B, Hkv, d]
-        ck, cv, li = pools
-        n_pool = ck.shape[3]
-        with jax.named_scope("attn/kv_write"):
-            ck = ck.at[li, write_page, write_slot].set(
-                _pad_heads(k, n_pool).astype(ck.dtype))
-            cv = cv.at[li, write_page, write_slot].set(
-                _pad_heads(v, n_pool).astype(cv.dtype))
-        with jax.named_scope("attn/attend"):
-            if n_pool != k.shape[1]:  # a padded pool: one query head each
-                out = paged_decode_attention(
-                    _pad_heads(q, n_pool), ck, cv, page_tables, lengths, li)
-                return out[:, :q.shape[1]], (ck, cv)
-            return (paged_decode_attention(q, ck, cv, page_tables, lengths,
-                                           li), (ck, cv))
+    # one kind of page: every closure below writes and walks through these
+    one = None if cfg.window else coordinates(page_tables)
+    write_page, write_slot, lengths = one or (None,) * 3
+
+    def attend_through(kind, window):
+        # q: [B, H, d]; k, v: [B, Hkv, d]
+        tables = _of(kind, page_tables)
+        write_page, write_slot, lengths = one or coordinates(tables)
+        bound = {"window": window} if window else {}
+
+        def attend(q, k, v, pools):
+            ck, cv, li = pools
+            n_pool = ck.shape[3]
+            with jax.named_scope("attn/kv_write"):
+                ck = ck.at[li, write_page, write_slot].set(
+                    _pad_heads(k, n_pool).astype(ck.dtype))
+                cv = cv.at[li, write_page, write_slot].set(
+                    _pad_heads(v, n_pool).astype(cv.dtype))
+            with jax.named_scope("attn/attend"):
+                if n_pool != k.shape[1]:  # a padded pool: one query head each
+                    out = paged_decode_attention(
+                        _pad_heads(q, n_pool), ck, cv, tables, lengths, li,
+                        **bound)
+                    return out[:, :q.shape[1]], (ck, cv)
+                return (paged_decode_attention(q, ck, cv, tables, lengths,
+                                               li, **bound), (ck, cv))
+
+        return attend
 
     def attend_latent(q_nope, q_rope, row, a, pools):
         pool, _, li = pools  # absorbed: K and V are never made
@@ -344,7 +422,8 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
 
     x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, state), positions,
-        {"attend": attend, "attend_latent": attend_latent, "recur": recur})
+        {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
+         "recur": recur})
     return (head(params, x, cfg), counted, *caches)
 
 

@@ -34,6 +34,36 @@ Converting locality into throughput (ISSUE 14) adds:
   content, so a prompt that diverges INSIDE a cached block still reuses
   the shared slots — the engine copies that single page and prefills only
   from the divergence point (`match_cow`).
+
+Pools by LAYER TYPE (models/afmoe.py: window and full layers in one stack):
+a model whose ``cache_layout()`` names ``window_layers`` and a ``window``
+gets TWO pools of K/V pages, each with its own `PageAllocator` and its own
+page table a sequence, and a page is ``page_size`` tokens of every layer
+OF ITS KIND.
+
+- ``"full"``: ``n_layers`` layers x ``num_pages``.  A sequence holds a page
+  for every ``page_size`` positions it has, for as long as it lives, as the
+  one pool of every other model.
+- ``"window"``: ``window_layers`` layers x ``window_pages``.  A query at
+  position i sees keys ``max(0, i - window + 1) .. i``, so a sequence holds
+  here only the pages that reach into the last ``window`` positions of
+  where it stands, and the chunk or burst about to be written: at most
+  ``ceil((window + largest chunk) / page_size) + 1`` at any instant
+  (``window_pages_per_seq``).  Which of the two forms the issue offered: A
+  TABLE WITH NULL ENTRIES BEHIND THE WINDOW, not a ring.  The window
+  layers' page table is indexed by ABSOLUTE page (position // page_size),
+  as the full layers' is; the engine gives a page back to the window
+  allocator once it lies wholly behind ``next position - window + 1``
+  (after every chunk of a prefill and before every decode burst,
+  ``engine._trim_window``) and puts the null page 0 in its place.  Why the
+  table: write coordinates, the decode kernel's walk and the suffix
+  prefill's gather stay functions of the absolute position, the SAME for
+  both kinds (a ring would give a window layer its own position
+  arithmetic in three programs); what a null entry costs is an int32 of
+  host memory.  Nothing reads a null entry: the decode kernel begins its
+  walk at the page that holds the window's first position
+  (ops/paged_attention.py ``window``), and the suffix prefill gathers only
+  the ``window_reach_pages`` pages that the chunk and its window reach.
 """
 
 from __future__ import annotations
@@ -67,7 +97,11 @@ class CacheConfig:
     ``state_rows``, by name the (count, shape, dtype) of the rows that each
     of ``max_slots`` slots holds for the ``state_layers`` recurrent layers
     between them: fixed in size, beside the pages and not in them,
-    meaningless once the slot is released."""
+    meaningless once the slot is released.  A fourth, beside K/V pages
+    only: ``window_layers`` of the attending layers see the last ``window``
+    positions alone and have a pool of their own, ``window_pages`` pages a
+    layer (the module docstring says how a sequence holds them);
+    ``n_layers`` then counts the FULL layers."""
 
     n_layers: int
     n_kv_heads: int = 0
@@ -79,6 +113,9 @@ class CacheConfig:
     state_rows: Optional[dict] = None
     max_slots: int = 0
     latent_dim: int = 0
+    window_layers: int = 0
+    window: int = 0
+    window_pages: int = 0
 
     def __post_init__(self):
         if bool(self.latent_dim) == bool(self.n_kv_heads * self.head_dim):
@@ -86,26 +123,68 @@ class CacheConfig:
                 f"a page holds K and V of n_kv_heads x head_dim "
                 f"({self.n_kv_heads} x {self.head_dim}) or one latent row "
                 f"of latent_dim ({self.latent_dim}), one of the two")
+        if bool(self.window_layers) != bool(self.window) or (
+                self.window_layers and (self.latent_dim
+                                        or self.window_pages < 2)):
+            raise ValueError(
+                f"window layers ({self.window_layers}) come with a window "
+                f"({self.window}), K/V pages and a pool of their own "
+                f"(window_pages {self.window_pages}), or not at all")
 
     @property
     def tokens_capacity(self) -> int:
         return self.num_pages * self.page_size
 
     @property
-    def bytes_per_token(self) -> int:
-        """Page bytes a cached token takes, all layers."""
+    def _row_bytes(self) -> int:
         row = self.latent_dim or 2 * self.n_kv_heads * self.head_dim
-        return self.n_layers * row * jnp.dtype(self.dtype).itemsize
+        return row * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Page bytes a cached token takes, all layers.  With window layers
+        that is no one number: ``bytes_per_token_at`` a context length."""
+        if self.window_layers:
+            raise ValueError(
+                f"{self.window_layers} window layers keep {self.window} "
+                f"tokens of a sequence and {self.n_layers} full layers all "
+                f"of it: ask bytes_per_token_at(context_tokens)")
+        return self.n_layers * self._row_bytes
+
+    def bytes_per_token_at(self, context_tokens: int) -> float:
+        """Page bytes a token of a sequence ``context_tokens`` long takes,
+        all layers, whole pages counted: a full layer keeps every page of
+        the sequence, a window layer the pages that reach into its last
+        ``window`` positions."""
+        ps = self.page_size
+        pages = -(-context_tokens // ps)
+        kept = pages - max(0, context_tokens - self.window + 1) // ps \
+            if self.window_layers else 0
+        return ((self.n_layers * pages + self.window_layers * kept) * ps
+                * self._row_bytes / max(1, context_tokens))
+
+    def window_pages_per_seq(self, largest_chunk: int) -> int:
+        """The most pages of the window pool one sequence holds at any
+        instant: the window and the chunk (or burst) being written, and
+        one more for a window that begins inside a page."""
+        return -(-(self.window + largest_chunk) // self.page_size) + 1
 
 
 def init_cache(cfg: CacheConfig):
-    """(cache_k, cache_v) zeros; for latent pages (the one pool, None)."""
+    """(cache_k, cache_v) zeros; for latent pages (the one pool, None); for
+    pools by layer type each of the two a dict ``{"full": ..., "window":
+    ...}`` of one pool a kind."""
     dt = jnp.dtype(cfg.dtype)
     if cfg.latent_dim:
         return jnp.zeros((cfg.n_layers, cfg.num_pages, cfg.page_size,
                           cfg.latent_dim), dt), None
     shape = (cfg.n_layers, cfg.num_pages, cfg.page_size,
              cfg.n_kv_heads, cfg.head_dim)
+    if cfg.window_layers:
+        shapes = {"full": shape, "window": (
+            cfg.window_layers, cfg.window_pages, *shape[2:])}
+        return tuple({kind: jnp.zeros(s, dt) for kind, s in shapes.items()}
+                     for _ in "kv")
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 

@@ -143,8 +143,15 @@ class GLMMoELiteConfig:
     # What the engine and the served programs ask of a family (llm/model.py
     # says who owns which decision), beside ``cache_layout`` below.
     block_length = 0  # it generates a token at a time
+    window = 0  # every layer sees every position
     refuses = {"pd": _LATENT_ROWS % "prefill/decode disaggregation",
-               "kv_tier": _LATENT_ROWS % "the KV tier"}
+               "kv_tier": _LATENT_ROWS % "the KV tier",
+               "chunked_prompt": (
+                   "{cfg.__class__.__name__} caches latent rows and its "
+                   "suffix prefill rebuilds K and V from every row the "
+                   "whole page table reaches, a chunk of a long prompt as "
+                   "dearly as a prefix hit of its length: {where} is not "
+                   "computed in chunks")}
 
     def serving_layout(self, params):
         return serving_layout(params)

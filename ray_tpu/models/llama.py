@@ -55,6 +55,7 @@ class LlamaConfig:
     # says who owns which decision).  Class members, not fields: a
     # configuration hashes and compares as before.
     block_length = 0  # positions a block; 0: it generates a token at a time
+    window = 0  # positions a window layer sees; 0: every layer sees them all
     refuses = {}  # feature -> why the engine cannot serve the model with it
 
     def cache_layout(self) -> dict:
@@ -198,6 +199,11 @@ PARTS = (
     # layers' shared expert (models/moe.py)
     "mla/kv_down", "mla/q_proj", "mla/kv_up", "mla/absorb", "mla/attend",
     "mla/unabsorb", "moe/shared",
+    # models/afmoe.py's block: the RMS norm a head on q and k (a part of
+    # its own there; the Qwen3 family's lies inside ``attn/qkv``), the
+    # sigmoid gate on attention's output, and the sandwich block's two
+    # norms BEHIND attention and the feed-forward
+    "attn/qk_norm", "attn/gate", "norm/post",
     # parallel/tp_stream.py: the links of a training trunk whose stream is
     # split over ``tp`` between the products: a group's rows passed round
     # the ring into ``attn/qkv`` and ``mlp/gate_up``, the partial sums of
@@ -211,7 +217,11 @@ PARTS = (
 
 def embed(params, tokens, cfg):
     with jax.named_scope("embed"):
-        return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+        x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+        # a family whose stream begins as a multiple of the table's rows
+        # says so (models/afmoe.py ``embed_scale``); no other has the name
+        scale = getattr(cfg, "embed_scale", None)
+        return x if scale is None else x * jnp.asarray(scale, x.dtype)
 
 
 def rms_norm(x, weight, eps):

@@ -70,6 +70,9 @@ _STATE_BESIDE = ("{cfg.__class__.__name__} has recurrent layers whose state is "
                  "a row a slot beside the pages, which this engine does not "
                  "serve with %s ({where}): pages alone carry nothing of the "
                  "state at their end")
+_NO_STATE_IN_PAGES = ("{where} serves no model with recurrent layers: the "
+                      "pages of a prefix hold nothing of their state at its "
+                      "end")
 
 
 @dataclass(frozen=True)
@@ -130,12 +133,16 @@ class OlmoHybridConfig:
     # What the engine and the served programs ask of a family (llm/model.py
     # says who owns which decision), beside ``cache_layout`` below.
     block_length = 0  # it generates a token at a time
+    window = 0  # its attending layers see every position
     refuses = {
         "pd": _STATE_BESIDE % "prefill/decode disaggregation",
         "kv_tier": _STATE_BESIDE % "the KV tier",
-        "prefix_cache": "{where} serves no model with recurrent layers: the "
-                        "pages of a prefix hold nothing of their state at "
-                        "its end",
+        "prefix_cache": _NO_STATE_IN_PAGES,
+        # (the program that would continue from a prefix's pages, and a
+        # prompt longer than the largest prefill bucket, which would be
+        # continued so chunk by chunk)
+        "suffix_prefill": _NO_STATE_IN_PAGES,
+        "chunked_prompt": _STATE_BESIDE % "a prompt computed in chunks",
     }
 
     def serving_layout(self, params):

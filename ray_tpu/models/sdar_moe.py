@@ -73,6 +73,7 @@ class SDARMoEConfig:
     # chosen experts' weights are not scaled; the tree and the pools are
     # the Llama family's.
     routed_scaling_factor = None
+    window = 0  # every layer sees every position a block may see
     cache_layout = LlamaConfig.cache_layout
     serving_layout = LlamaConfig.serving_layout
     refuses = {

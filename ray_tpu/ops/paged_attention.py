@@ -22,7 +22,11 @@ together into one of two VMEM buffers while the other is being computed on,
 and the prefetch runs across slot boundaries (the last block of a slot
 starts the first block of the next active one).  Pages past a slot's length
 are neither copied nor computed; a slot of length 0 is skipped and returns
-zeros.
+zeros.  With a ``window`` (a layer that sees the last ``window`` positions
+only, models/afmoe.py) the walk also has a LOWER bound: it begins at the
+block that holds position ``max(0, length - window)``, the pages before that
+position's are neither copied nor scored, and the rows before it inside its
+page are masked.  Without one the program is the one it was.
 
 Grouped-query attention without a repeat: a block's K lies in VMEM as
 ``[tokens * n_kv_heads, d]`` rows (the pool's own order), all H query heads
@@ -53,9 +57,12 @@ from ray_tpu.ops.attention import NEG_INF
 BLOCK_TOKENS = 256
 
 
-def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
-                         v_pool, o_ref, k_buf, v_buf, sems, *,
-                         sm_scale: float):
+def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
+                         sm_scale: float, bounded: bool = False):
+    # ``bounded``: a fourth prefetched scalar a slot, the first position
+    # the slot's query sees (0 <= start < length)
+    starts_ref, refs = (refs[0], refs[1:]) if bounded else (None, refs)
+    q_ref, k_pool, v_pool, o_ref, k_buf, v_buf, sems = refs
     B, H, d = q_ref.shape
     _, ppb, ps, n_kv, _ = k_buf.shape
     P = tables_ref.shape[0] // B
@@ -63,14 +70,22 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
     cols = block_tokens * n_kv
     layer = layer_ref[0]
 
+    def first_block(b):
+        """The block a slot's walk begins at."""
+        return starts_ref[b] // block_tokens if bounded else 0
+
     def block_copies(b, blk, buf, wait: bool):
         """Start (or wait for) the copies of block ``blk`` of slot ``b``
-        into buffer ``buf``: only the pages the slot's length reaches."""
+        into buffer ``buf``: only the pages the slot's length reaches
+        (and, ``bounded``, none that lies wholly before its start)."""
         n_pages = (lengths_ref[b] + ps - 1) // ps
         for i in range(ppb):
             pg = blk * ppb + i
+            reached = pg < n_pages
+            if bounded:
+                reached &= pg >= starts_ref[b] // ps
 
-            @pl.when(pg < n_pages)
+            @pl.when(reached)
             def _():
                 # a wait needs the copy's shape and semaphore, not its source
                 page = 0 if wait else tables_ref[b * P + pg]
@@ -101,7 +116,7 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
 
     @pl.when(first < B)
     def _():
-        block_copies(first, 0, 0, wait=False)
+        block_copies(first, first_block(first), 0, wait=False)
 
     def slot(carry):
         b, buf = carry
@@ -116,8 +131,10 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
 
             @pl.when(more | (nxt < B))
             def _():
-                block_copies(jnp.where(more, b, jnp.minimum(nxt, B - 1)),
-                             jnp.where(more, i + 1, 0), 1 - buf, wait=False)
+                after = jnp.minimum(nxt, B - 1)
+                block_copies(jnp.where(more, b, after),
+                             jnp.where(more, i + 1, first_block(after)),
+                             1 - buf, wait=False)
 
             block_copies(b, i, buf, wait=True)
             k = k_buf[buf].reshape(cols, d)
@@ -125,6 +142,8 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             keep = own_head & (col < (length - i * block_tokens) * n_kv)
+            if bounded:
+                keep &= col >= (starts_ref[b] - i * block_tokens) * n_kv
             s = jnp.where(keep, s * sm_scale, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)  # masked columns: exactly 0
@@ -135,10 +154,11 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
                 preferred_element_type=jnp.float32)
             return m_new, l, acc, 1 - buf
 
-        # block 0 holds position 0, which every query head attends to, so
-        # the running max is finite from the first block on
+        # the first block walked holds position 0 (or the slot's start),
+        # which every query head attends to, so the running max is finite
+        # from the first block on
         _, l, acc, buf = jax.lax.fori_loop(
-            0, n_blocks, block,
+            first_block(b), n_blocks, block,
             (jnp.full((H, 1), NEG_INF, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
              jnp.zeros((H, d), jnp.float32), buf))
@@ -150,19 +170,26 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_pool,
 
 @functools.partial(jax.jit,
                    static_argnames=("pages_per_block", "interpret"))
-def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer, *,
-                  pages_per_block: int, interpret: bool):
+def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer,
+                  starts=None, *, pages_per_block: int, interpret: bool):
     B, H, d = q.shape
     _, _, ps, n_kv, _ = k_pool.shape
     P = page_tables.shape[1]
     ppb = min(pages_per_block, P)
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    lengths = jnp.minimum(lengths, P * ps).astype(jnp.int32)
+    kernel = functools.partial(_paged_decode_kernel,
+                               sm_scale=1.0 / math.sqrt(d))
+    bound = ()
+    if starts is not None:  # a start a slot, under its length
+        kernel = functools.partial(kernel, bounded=True)
+        bound = (jnp.clip(starts, 0, jnp.maximum(lengths - 1, 0)).astype(
+            jnp.int32),)
     return pl.pallas_call(
-        functools.partial(_paged_decode_kernel,
-                          sm_scale=1.0 / math.sqrt(d)),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(bound),
             grid=(),
             in_specs=[vmem, any_space, any_space],
             out_specs=vmem,
@@ -174,15 +201,14 @@ def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-    )(jnp.minimum(lengths, P * ps).astype(jnp.int32),
-      page_tables.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
+    )(lengths, page_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *bound,
       q, k_pool, v_pool)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, page_tables: jax.Array,
-                           lengths: jax.Array, layer, *,
+                           lengths: jax.Array, layer, *, window=None,
                            pages_per_block: int | None = None) -> jax.Array:
     """Attention of one query token a slot over that slot's cached tokens.
 
@@ -195,6 +221,11 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     already be in the pool.  Returns [B, H, d] in q's dtype; operands go to
     the MXU in the pool's dtype, scores and the softmax state are float32.
     ``pages_per_block`` defaults to ``BLOCK_TOKENS`` worth of pages.
+    ``window`` (None or 0: no bound, and the program it was): positions a
+    slot's query sees, its own among them, a whole number or an int32
+    scalar (traced or not); the slot then attends to positions ``max(0,
+    lengths[b] - window) .. lengths[b] - 1`` and the table's entries for
+    the pages wholly before them are never read (they may be null).
     """
     if q.ndim != 3 or k_pool.ndim != 5 or k_pool.shape != v_pool.shape:
         raise ValueError(
@@ -220,8 +251,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             f"multiple of 8")
     if pages_per_block is None:
         pages_per_block = max(1, BLOCK_TOKENS // k_pool.shape[2])
+    starts = None
+    if window is not None and not (isinstance(window, int) and window == 0):
+        if isinstance(window, int) and window < 0:
+            raise ValueError(f"a window holds 1 or more positions, the "
+                             f"query's own among them; got {window}")
+        starts = jnp.maximum(lengths - window, 0)
     return _paged_decode(q.astype(k_pool.dtype), k_pool, v_pool, page_tables,
-                         lengths, layer, pages_per_block=pages_per_block,
+                         lengths, layer, starts,
+                         pages_per_block=pages_per_block,
                          interpret=not on_tpu).astype(q.dtype)
 
 

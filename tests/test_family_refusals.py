@@ -11,7 +11,7 @@ import pytest
 
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import afmoe, glm_moe_lite, llama, olmo_hybrid, sdar_moe
 
 VOCAB = 128
 
@@ -26,6 +26,8 @@ FAMILIES = {
                     "serve with"),
     "glm_moe_lite": (glm_moe_lite, glm_moe_lite.GLMMoELiteConfig.tiny(VOCAB),
                      "GLMMoELiteConfig caches latent rows"),
+    "afmoe": (afmoe, afmoe.AfmoeConfig.tiny(VOCAB),
+              "AfmoeConfig keeps a window layer's pages only while"),
 }
 
 # path -> (the feature it needs, where the refusal says it was asked)
@@ -36,6 +38,11 @@ PATHS = {
     "kv_prehydrate": ("kv_tier", "kv_prehydrate"),
     "kv_tier": ("kv_tier", None),  # built, or let go
     "prefix_cache": ("prefix_cache", "prefill_with_prefix"),
+    # a prompt over the largest prefill bucket (64 below), computed in
+    # chunks or refused; and the program the later chunks run
+    "chunked_prompt": ("chunked_prompt", "a prompt of 100 tokens, over the "
+                       "largest prefill bucket 64,"),
+    "suffix_prefill": ("suffix_prefill", "prefill_with_prefix"),
 }
 PROMPT = [5, 6, 7, 8, 9]
 
@@ -59,6 +66,13 @@ def _ask(engine, path, refused):
         return engine.submit(PROMPT, SamplingParams(temperature=0.7))
     if path == "kv_prehydrate":
         return engine.kv_prehydrate([])
+    if path == "chunked_prompt":
+        if not refused:
+            engine.start()
+        got = _drain(engine.submit(list(range(5, 105)),
+                                   SamplingParams(max_tokens=3)))
+        assert len(got) == 3 and engine.stats()["prefill_chunks"] == 2
+        return
     if path == "submit_with_kv" and refused:  # nothing to ship it
         return engine.submit_with_kv(PROMPT, 9, None, None)
     first, kv_k, kv_v, n = engine.prefill_extract(PROMPT)
@@ -90,17 +104,21 @@ def test_the_engine_refuses_what_the_family_declares_and_nothing_else(
     tier = object() if feature == "kv_tier" else None
     engine = LLMEngine(trees[family], cfg, EngineConfig(
         max_slots=2, num_pages=32, page_size=16, max_seq_len=128,
-        prefill_buckets=(64, 128)), kv_tier=tier)
+        prefill_buckets=(64,) if path == "chunked_prompt" else (64, 128)),
+        kv_tier=tier)
     try:
         if path == "kv_tier":
             # a server hands every engine its worker's tier unasked
             assert engine.kv_tier is (None if refused else tier)
         elif path == "prefix_cache":
             assert (engine.prefix_cache is None) == refused
-            if refused:  # and the program that would use one says why
+        elif path == "suffix_prefill":
+            if refused:  # the program that continues from pages says why
                 with pytest.raises(ValueError) as e:
                     lm.refuse(cfg, feature, where)
                 assert "no model with recurrent layers" in str(e.value)
+            else:
+                lm.refuse(cfg, feature, where)
         elif not refused:
             _ask(engine, path, refused)
         else:

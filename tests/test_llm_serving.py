@@ -68,6 +68,18 @@ def tiny_latent_loader():
     return glm_moe_lite.init(cfg, jax.random.PRNGKey(7)), cfg
 
 
+def tiny_windowed_loader():
+    """Window and full attention layers over a page pool a kind, gated
+    attention, routed experts beside a shared one after a leading dense
+    layer (models/afmoe.py), over the byte tokenizer's vocabulary."""
+    import jax
+
+    from ray_tpu.models import afmoe
+
+    cfg = afmoe.AfmoeConfig.tiny(259)
+    return afmoe.init(cfg, jax.random.PRNGKey(7)), cfg
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _cluster(ray_cluster):
     # join the session cluster (conftest.ray_cluster owns the
@@ -235,8 +247,61 @@ def test_latent_model_answers_over_http():
     serve.delete("latent")
 
 
+def test_windowed_model_answers_a_long_prompt_over_http():
+    """The same path a fifth time, chosen by what the model says it caches:
+    build_openai_app -> serve.run -> HTTP -> LLMServer -> LLMEngine over TWO
+    pools.  A prompt of 150 tokens over buckets of 64 at most is computed
+    in three chunks, with a window of 32 most of its window pages are given
+    back before it ends, the answer streams, asked again it answers the
+    same (no prefix index: computed again), sampling works as for any
+    token-at-a-time model, and the counters say what the steps walked."""
+    import json
+
+    app = build_openai_app(LLMConfig(
+        model_id="tiny-windowed", model_loader=tiny_windowed_loader,
+        engine_config=EngineConfig(max_slots=4, num_pages=128, page_size=8,
+                                   max_seq_len=256,
+                                   prefill_buckets=(32, 64)),
+        default_max_tokens=8))
+    serve.run(app, name="windowed", route_prefix="/windowed",
+              _blocking_timeout_s=120)
+    base = f"http://127.0.0.1:{serve.http_port()}/windowed/v1"
+    body = {"prompt": [7 + i % 200 for i in range(150)], "max_tokens": 12,
+            "ignore_eos": True}
+    whole = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert whole["usage"]["completion_tokens"] == 12, whole
+    events = []
+    with requests.post(f"{base}/completions", json={**body, "stream": True},
+                       stream=True, timeout=300) as r:
+        for line in r.iter_lines():
+            if line.startswith(b"data: ") and line != b"data: [DONE]":
+                events.append(json.loads(line[6:]))
+    assert events and all(e["choices"][0]["text"] is not None for e in events)
+    again = requests.post(f"{base}/completions", json=body,
+                          timeout=300).json()
+    assert again["choices"][0]["text"] == whole["choices"][0]["text"]
+    sampled = requests.post(f"{base}/completions",
+                            json={**body, "temperature": 0.7},
+                            timeout=300).json()
+    assert sampled["usage"]["completion_tokens"] == 12, sampled
+    from ray_tpu.serve.handle import DeploymentHandle
+
+    stats = DeploymentHandle(
+        "windowed", "LLMServer:tiny-windowed").engine_stats.remote().result(
+            timeout_s=60)
+    assert stats["prefill_chunks"] == 4 * 3 and stats["admitted"] == 4
+    assert stats["prefill_tokens_saved"] == 0  # no prefix index
+    assert stats["window_pages_freed"] >= 4 * 14
+    assert stats["window_pages_skipped"] > stats["window_pages_read"] > 0
+    assert stats["experts_read"] > 0
+    assert stats["full_pages_in_use"] == stats["window_pages_in_use"] == 0
+    serve.delete("windowed")
+
+
 @pytest.mark.parametrize("loader", [tiny_loader, tiny_sdar_loader,
-                                    tiny_hybrid_loader, tiny_latent_loader])
+                                    tiny_hybrid_loader, tiny_latent_loader,
+                                    tiny_windowed_loader])
 def test_batch_processor_over_dataset(loader):
     from ray_tpu import data as rd
 

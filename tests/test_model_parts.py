@@ -25,7 +25,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
-from ray_tpu.models import glm_moe_lite, llama, moe, olmo_hybrid, sdar_moe
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, moe, olmo_hybrid,
+                            sdar_moe)
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -52,6 +53,12 @@ LATENT = (("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
            "mla/q_proj", "head") + ROUTED[-4:] + ("moe/shared",))
 LATENT_PREFILL = LATENT + ("mla/kv_up", "attn/attend")  # a head a head:
 # the repeat of K and V to the query heads is by one and leaves no operation
+# window and full layers in one stack: gated attention with a QK norm of
+# its own part, sandwich norms, a leading dense layer then routed ones
+# beside a shared expert
+WINDOWED = (DENSE + ROUTED[-4:] + (
+    "moe/shared", "attn/qk_norm", "attn/gate", "norm/post", "attn/kv_write",
+    "head"))
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -159,6 +166,22 @@ def _latent(program):
     return fn, (params, tokens, pool, None, *rest), cfg
 
 
+def _windowed(program):
+    """``program`` of the dense tree over a pool a kind of layer: the page
+    ids and the page tables come a kind too."""
+    cfg = afmoe.AfmoeConfig.tiny()
+    params = lm.serving_layout(afmoe.init(cfg, jax.random.PRNGKey(0)))
+    ck, cv = init_cache(CacheConfig(
+        **lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+        dtype="float32", window_pages=PAGES))
+    fn, (_, tokens, _, _, *rest), _ = program()
+    both = lambda x: {"full": x, "window": x}  # noqa: E731
+    rest[0] = both(rest[0])  # page ids by position, or the page tables
+    if len(rest) == 5:  # prefill_with_prefix: and its page table
+        rest[3] = both(rest[3])
+    return fn, (params, tokens, ck, cv, *rest), cfg
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -216,7 +239,11 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "hybrid_decode_step_greedy": _hybrid_decode,
           "latent_prefill": lambda: _latent(_prefill),
           "latent_prefill_with_prefix": lambda: _latent(_prefill_with_prefix),
-          "latent_decode_step_greedy": lambda: _latent(_decode)}
+          "latent_decode_step_greedy": lambda: _latent(_decode),
+          "windowed_prefill": lambda: _windowed(_prefill),
+          "windowed_prefill_with_prefix":
+              lambda: _windowed(_prefill_with_prefix),
+          "windowed_decode_step_greedy": lambda: _windowed(_decode)}
 TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
     "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
@@ -236,6 +263,9 @@ EXPECTED = {
     "latent_prefill_with_prefix": LATENT_PREFILL,
     "latent_decode_step_greedy": LATENT + ("mla/absorb", "mla/attend",
                                            "mla/unabsorb", "sample"),
+    "windowed_prefill": WINDOWED + ("attn/attend/repeat_kv",),
+    "windowed_prefill_with_prefix": WINDOWED + ("attn/attend/repeat_kv",),
+    "windowed_decode_step_greedy": WINDOWED + ("sample",),
     "llama_grad_remat": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "llama_grad": DENSE + ("attn/attend/repeat_kv", "head", "loss"),
     "moe_grad": ROUTED + ("attn/attend/repeat_kv", "head", "loss"),
@@ -277,6 +307,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
               "latent_prefill": {"moe_grouped_mlp"},
               "latent_decode_step_greedy": {"paged_latent_decode_attention",
                                             "moe_grouped_mlp"},
+              "windowed_decode_step_greedy": {"paged_decode_attention",
+                                              "moe_grouped_mlp"},
               "llama_grad": set(FLASH),
               **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
     assert wanted <= seen
@@ -356,7 +388,7 @@ def _scoped(compiled) -> bool:
 
 # what llm/model.py and llm/engine.py ask of a configuration class
 ASKED = ("cache_layout", "serving_layout", "served_walk", "refuses",
-         "block_length")
+         "block_length", "window")
 # ``lm.cache_layout(cfg)`` as PR 44 returned it, for the benchmark's
 # configurations (benchmarks/configs/*.json) at four layers
 CACHE_LAYOUTS = {
@@ -369,6 +401,10 @@ CACHE_LAYOUTS = {
         "state_rows": {"S": (3, (15, 96, 384), jnp.float32),
                        "conv": (9, (11520,), jnp.dtype("bfloat16"))}},
     "glm47_flash_serve_1chip": {"n_layers": 4, "latent_dim": 640},
+    # (the last four of its five layers: three window layers and the full)
+    "trinity_mini_serve_1chip": {"n_layers": 1, "n_kv_heads": 4,
+                                 "head_dim": 128, "window_layers": 3,
+                                 "window": 2048},
 }
 # how a program found a family out for itself before it was told
 SNIFFED = ('"lin" in', '"experts" in', '"wkv_b"', '"w_uk"',
@@ -397,7 +433,8 @@ def test_a_configuration_class_answers_everything_it_is_asked(config):
     assert cfg == dataclasses.replace(cfg)
     assert hash(cfg) == hash(dataclasses.replace(cfg))
     assert lm.cache_layout(cfg) == cfg.cache_layout() == CACHE_LAYOUTS[config]
-    CacheConfig(**cfg.cache_layout(), max_slots=2)  # the engine's own call
+    CacheConfig(**cfg.cache_layout(), max_slots=2,  # the engine's own call
+                window_pages=8 if cfg.window else 0)
     for feature, why in cfg.refuses.items():  # sentences ``lm.refuse`` fills
         assert "{where}" in why and "{" not in why.format(cfg=cfg, where="x")
 

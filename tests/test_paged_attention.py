@@ -21,7 +21,8 @@ PAGE_SIZE, PAGES_PER_SEQ, N_KV, SLOTS, LAYERS = 16, 20, 2, 4, 2
 FULL = PAGE_SIZE * PAGES_PER_SEQ
 
 
-def dense_reference(q, k_pool, v_pool, page_tables, lengths, layer):
+def dense_reference(q, k_pool, v_pool, page_tables, lengths, layer,
+                    window=None):
     B, H, d = q.shape
     n_kv = k_pool.shape[3]
     T = page_tables.shape[1] * k_pool.shape[2]
@@ -32,6 +33,8 @@ def dense_reference(q, k_pool, v_pool, page_tables, lengths, layer):
     scores = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), keys,
                         precision="highest") / (d ** 0.5)
     mask = jnp.arange(T)[None] < lengths[:, None]
+    if window:  # the last ``window`` positions, the query's own among them
+        mask &= jnp.arange(T)[None] >= (lengths - window)[:, None]
     scores = jnp.where(mask[:, None, :], scores, -1e30)
     out = jnp.einsum("bht,bthd->bhd", jax.nn.softmax(scores, axis=-1), vals,
                      precision="highest")
@@ -83,6 +86,74 @@ def test_kernel_matches_the_dense_formulation(group, head_dim, lengths):
     for b, n in enumerate(lengths):
         if n == 0:
             assert not np.asarray(got[b]).any()
+
+
+def _null_behind(tables, lengths, window):
+    """The page table as the engine leaves a window layer's: the null page
+    where a page lies wholly behind ``length - window``."""
+    tables = np.array(tables)
+    for b, n in enumerate(lengths):
+        tables[b, :max(0, n - window) // PAGE_SIZE] = 0
+    return jnp.asarray(tables)
+
+
+@pytest.mark.parametrize("lengths", [
+    (40, 40, 40, 40), (64, 64, 64, 64), (65, 65, 65, 65),
+    (FULL, FULL, FULL, FULL), (1, 130, 47, FULL), (0, 90, 0, 257),
+], ids=["under", "at", "over_by_one", "full_table", "ragged",
+        "inactive_slots"])
+@pytest.mark.parametrize("window", [64, 70, 1], ids=["whole_pages",
+                                                     "inside_a_page", "one"])
+@pytest.mark.parametrize("pages_per_block", [None, 3])
+def test_kernel_with_a_window_matches_the_dense_formulation(
+        pages_per_block, window, lengths):
+    """Lengths under, at and over the window; a bound that falls inside a
+    page (70 = 4 pages and 6 rows) and inside a block; ragged lengths and
+    inactive slots; the pages behind the bound are the NULL page, whose
+    loud garbage would show if one were copied and scored."""
+    k_pool, v_pool, tables = _pool_and_tables(lengths, 64, jnp.float32)
+    q = jax.random.normal(jax.random.PRNGKey(7), (SLOTS, N_KV * 4, 64),
+                          jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    want = dense_reference(q, k_pool, v_pool, tables, lens, 1, window)
+    got = paged_decode_attention(
+        q, k_pool, v_pool, _null_behind(tables, lengths, window), lens, 1,
+        window=window, pages_per_block=pages_per_block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    for b, n in enumerate(lengths):
+        if n == 0:
+            assert not np.asarray(got[b]).any()
+
+
+def test_no_window_is_bit_for_bit_the_kernel_it_was_and_a_window_may_be_traced():
+    """``window=None`` and ``window=0`` are the program without a bound
+    (one prefetched scalar fewer: the same lowering as before the bound
+    existed), and so bit-equal to each other and to a window that skips
+    nothing; a traced scalar bounds as a whole number does."""
+    lengths = (5, 300, 0, 64)
+    k_pool, v_pool, tables = _pool_and_tables(lengths, 128, jnp.bfloat16)
+    q = jax.random.normal(jax.random.PRNGKey(3), (SLOTS, 8, 128),
+                          jnp.bfloat16)
+    lens = jnp.asarray(lengths, jnp.int32)
+    plain = paged_decode_attention(q, k_pool, v_pool, tables, lens, 1)
+    for window in (0, None, FULL):
+        got = paged_decode_attention(q, k_pool, v_pool, tables, lens, 1,
+                                     window=window)
+        assert np.array_equal(np.asarray(got), np.asarray(plain))
+
+    def text(**kw):
+        return jax.jit(lambda: paged_decode_attention(
+            q, k_pool, v_pool, tables, lens, 1, **kw)).lower().as_text()
+
+    assert text() == text(window=None) == text(window=0) != text(window=64)
+    traced = jax.jit(lambda w: paged_decode_attention(
+        q, k_pool, v_pool, tables, lens, 1, window=w))(jnp.int32(100))
+    whole = paged_decode_attention(q, k_pool, v_pool, tables, lens, 1,
+                                   window=100)
+    assert np.array_equal(np.asarray(traced), np.asarray(whole))
+    with pytest.raises(ValueError, match="1 or more positions"):
+        paged_decode_attention(q, k_pool, v_pool, tables, lens, 1, window=-4)
 
 
 def test_bf16_pool_float32_softmax_and_a_traced_layer():

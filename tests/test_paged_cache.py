@@ -16,7 +16,8 @@ import random
 
 import pytest
 
-from ray_tpu.llm.paged_cache import PageAllocator, PrefixCache
+from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
+                                     init_cache)
 
 
 def _insert_chain(alloc, cache, tokens):
@@ -571,3 +572,54 @@ def test_a_burst_preempts_as_the_per_slot_path_did(cached_pages):
     # takes a preempted slot's pages, scans again: never one a page
     scans = got.stats()["eviction_scans"]
     assert scans == 1 if not preempted else scans <= 1 + 16 + preempted
+
+
+# -- pools by layer type ------------------------------------------------------
+
+WINDOWED = dict(n_layers=2, n_kv_heads=2, head_dim=8, window_layers=6,
+                window=64, page_size=16, num_pages=40, window_pages=24,
+                dtype="float32")
+
+
+def test_pools_by_layer_type_are_two_of_each():
+    k, v = init_cache(CacheConfig(**WINDOWED))
+    assert set(k) == set(v) == {"full", "window"}
+    assert k["full"].shape == v["full"].shape == (2, 40, 16, 2, 8)
+    assert k["window"].shape == v["window"].shape == (6, 24, 16, 2, 8)
+    plain, _ = init_cache(CacheConfig(n_layers=2, n_kv_heads=2, head_dim=8))
+    assert plain.shape == (2, 256, 16, 2, 8)  # one kind: an array, as ever
+
+
+@pytest.mark.parametrize("context, kept", [
+    (1, 1), (16, 1), (63, 4), (64, 4), (65, 5), (79, 4), (80, 4),
+    (81, 5), (1000, 5)])
+def test_a_window_layer_keeps_the_pages_its_window_reaches(context, kept):
+    """``bytes_per_token_at``: a full layer holds every page of a sequence,
+    a window layer those from the page of ``context - window + 1`` on, the
+    position the NEXT query still sees."""
+    cc = CacheConfig(**WINDOWED)
+    pages = -(-context // 16)
+    row = 2 * 2 * 8 * 4
+    assert cc.bytes_per_token_at(context) == pytest.approx(
+        (2 * pages + 6 * kept) * 16 * row / context)
+    assert kept <= cc.window_pages_per_seq(16) == 6
+
+
+@pytest.mark.parametrize("wrong", [
+    dict(window=0), dict(window_layers=0), dict(window_pages=1),
+    dict(n_kv_heads=0, head_dim=0, latent_dim=128)])
+def test_window_layers_come_whole_or_not_at_all(wrong):
+    with pytest.raises(ValueError):
+        CacheConfig(**{**WINDOWED, **wrong})
+
+
+def test_a_window_pool_is_allocated_like_any_other():
+    """The window layers' allocator is a ``PageAllocator``: pages given
+    back while a sequence lives go to the END of the free list, so another
+    sequence takes untouched pages first and a given-back page's rows stay
+    readable until the list comes round."""
+    alloc = PageAllocator(8)
+    held = alloc.allocate(4)
+    alloc.free(held[:2])  # behind the window
+    assert alloc.allocate(3) == [5, 6, 7] and alloc.num_free() == 2
+    assert alloc.allocate(2) == held[:2]

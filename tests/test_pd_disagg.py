@@ -57,6 +57,42 @@ def test_pd_handoff_matches_single_engine(tiny_model):
     decode_engine.stop()
 
 
+@pytest.mark.parametrize("n", [33, 75, 96])
+def test_pd_prefill_of_a_prompt_over_the_largest_bucket_is_the_whole_one(
+        tiny_model, n):
+    """A prompt over the largest bucket is prefilled in chunks before its
+    pages ship: first token and every shipped row as one program leaves
+    them, and a later prompt that hits the registered pages answers as an
+    engine that never saw them (one chunk alone would ship, and index,
+    rows nobody wrote)."""
+    params, cfg = tiny_model
+    prompt = [int(t) for t in
+              np.random.default_rng(n).integers(1, 128, size=n)]
+    sp = SamplingParams(max_tokens=6, temperature=0.0)
+    whole = _engine(tiny_model)
+    chunks = LLMEngine(params, cfg, EngineConfig(
+        max_slots=4, num_pages=64, page_size=8, max_seq_len=256,
+        prefill_buckets=(16, 32)))
+    try:
+        first, kv_k, kv_v, _ = whole.prefill_extract(list(prompt), sp)
+        got, got_k, got_v, m = chunks.prefill_extract(list(prompt), sp)
+        assert m == n and got == first
+        assert chunks.stats()["prefill_chunks"] == -(-n // 32)
+        rows = np.arange(kv_k.shape[1] * 8) < n  # the last page's tail: any
+        for a, b in ((kv_k, got_k), (kv_v, got_v)):
+            a = a.reshape(a.shape[0], -1, *a.shape[3:])[:, rows]
+            b = b.reshape(b.shape[0], -1, *b.shape[3:])[:, rows]
+            np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5)
+        # the registered pages, hit by the same prompt with a tail
+        again = prompt + [3, 4, 5]
+        expected = whole.generate(list(again), sp)
+        assert chunks.generate(list(again), sp) == expected
+        assert chunks.stats()["prefill_tokens_saved"] >= n - n % 8
+    finally:
+        whole.stop()
+        chunks.stop()
+
+
 def test_pd_serve_app(ray_cluster, tiny_model):
     import ray_tpu.serve as serve
     from ray_tpu.llm import LLMConfig, build_pd_openai_app

@@ -144,6 +144,7 @@ def test_preempt_event_carries_request_identity(monkeypatch):
     fake = types.SimpleNamespace(
         _register_blocks=lambda seq, pages: None,
         allocator=types.SimpleNamespace(free=lambda pages: None),
+        _free_pages=lambda s: None,  # (pages of either kind, since PR 46)
         _slots=[object()],
         _stats=collections.defaultdict(int),
         _m={"preempted": types.SimpleNamespace(inc=lambda *a, **k: None)},

@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models import glm_moe_lite, llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import afmoe, glm_moe_lite, llama, olmo_hybrid, sdar_moe
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
@@ -454,6 +454,74 @@ def test_latent_programs_compile_at_glm_widths(topo, as_tpu, program):
     planned = _footprint(compiled)
     assert 0.60 * V5E_BYTES_LIMIT <= planned <= 0.85 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - LATENT_PLANNED_GB[program]) < 0.05
+
+
+# planned bytes a program of configuration ``trinity_mini_serve_1chip``,
+# compiled for the described v5e here (PERF.md section 4): weights 8.48 GB
+# (serving layout), the full layer's pool 1.07 GB and the four window
+# layers' 1.11 GB
+WINDOWED_PLANNED_GB = {"decode_step_greedy": 10.674, 1024: 10.764,
+                       "prefix_64": 10.675, "prefix_1024": 11.296}
+
+
+@pytest.mark.parametrize("program", ["decode_step_greedy", 1024,
+                                     "prefix_64", "prefix_1024"])
+def test_windowed_programs_compile_at_trinity_widths(topo, as_tpu, program):
+    """``decode_step_greedy`` (64 slots, two 512-page tables), the one
+    ``prefill`` bucket a chunked prompt runs and two of
+    ``prefill_with_prefix`` (8,192-token tables) of Trinity-Mini at
+    published widths and 1 dense + 4 sparse layers, over the cell's two
+    pools: each plans between 0.60 and 0.85 of the chip's bytes_limit; both
+    pools are aliased to the output and held once; the routed experts go
+    through the grouped kernel and stay where they lie; the decode step
+    attends through the paged kernel in all five layers, four of them with
+    the window's bound (a fourth prefetched scalar), the prefills never
+    call it; and a window layer's suffix gather reaches 193 pages (a
+    3,088-key mask), not the table's 512."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = afmoe.AfmoeConfig(
+        n_layers=5, n_dense_layers=1, max_seq_len=8192,
+        layer_types=(afmoe.SLIDING,) * 4 + (afmoe.FULL,))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shapes = jax.eval_shape(lambda k: afmoe.init(cfg, k, jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    params = _on(one, jax.eval_shape(lm.serving_layout, shapes))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert abs(weights / 1e9 - 8.483) < 0.001
+    pools = {"full": sds((1, 32768, 16, 4, 128), jnp.bfloat16),
+             "window": sds((4, 8448, 16, 4, 128), jnp.bfloat16)}
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    both = lambda *shape: {"full": i32(*shape), "window": i32(*shape)}  # noqa: E731
+    if program == "decode_step_greedy":
+        compiled = lm.decode_step_greedy.lower(
+            params, i32(64), pools, pools, both(64, 512), i32(64),
+            sds((64,), jnp.bool_), cfg).compile()
+    elif isinstance(program, int):
+        compiled = lm.prefill.lower(
+            params, i32(program), pools, pools, both(program), i32(),
+            i32(program), cfg).compile()
+    else:
+        L = int(program.split("_")[1])
+        compiled = lm.prefill_with_prefix.lower(
+            params, i32(L), pools, pools, both(L), i32(), i32(L), both(512),
+            i32(L), cfg).compile()
+    text = compiled.as_text()
+    assert ("paged_decode_attention" in text) == (
+        program == "decode_step_greedy")
+    assert "moe_grouped_mlp" in text
+    if program == "prefix_1024":
+        assert "pred[1024,3088]" in text and "pred[1024,8192]" in text
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and r.startswith((
+        "bf16[1,32768,16,4,128]", "bf16[4,8448,16,4,128]", "bf16[4,128,",
+        "bf16[128,2048,1024]", "bf16[128,1024,2048]"))]
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 1073741824 + 1107296256
+    planned = _footprint(compiled)
+    print("PLANNED", program, planned / 1e9, m.temp_size_in_bytes / 1e9)
+    assert 0.60 * V5E_BYTES_LIMIT <= planned <= 0.85 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - WINDOWED_PLANNED_GB[program]) < 0.05
 
 
 def test_latent_kernel_refuses_pages_that_are_no_whole_tiles(as_tpu):
