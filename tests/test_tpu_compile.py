@@ -80,23 +80,49 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-@pytest.mark.parametrize("batch,seq,heads,head_dim", [
-    (12, 1024, 12, 64),    # GPT-2 124M, the smoke's train phase
-    (1, 2048, 32, 128),    # Llama-3-8B heads at the four-chip step's length
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim", [
+    (12, 1024, 12, 12, 64),    # GPT-2 124M, the smoke's train phase
+    (1, 2048, 32, 32, 128),    # Llama-3-8B heads at the four-chip step's length
     # shapes the chip's compiler refused before the operands were blocked
     # and padded: whole-sequence operands past VMEM, and a length that is
     # not a multiple of 8
-    (1, 8192, 32, 128),
-    (1, 32768, 4, 128),
-    (1, 100, 4, 64),
+    (1, 8192, 32, 32, 128),
+    (1, 32768, 4, 4, 128),
+    (1, 100, 4, 4, 64),
+    # a device's share of the train cell: four query heads to a KV head,
+    # which the kernels read through their index maps (PR 47)
+    (4, 4095, 16, 4, 128),
 ])
 def test_flash_forward_and_backward_compile(topo, as_tpu, batch, seq, heads,
-                                            head_dim):
+                                            kv_heads, head_dim):
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((batch, seq, heads, head_dim), jnp.bfloat16,
                              sharding=one)
-    compiled = jax.jit(_flash_grad()).lower(x, x, x).compile()
+    kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, head_dim), jnp.bfloat16,
+                              sharding=one)
+    compiled = jax.jit(_flash_grad()).lower(x, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3  # fwd, dkv, dq
+
+
+_SHAPE = re.compile(r"\b(?:bf16|f32)\[([\d,]*)\]")
+
+
+def _flash_calls(text):
+    """{kernel: (result shapes, operand shapes)} of the flash kernels'
+    custom calls in a compiled text; the int32 tile tables left out."""
+    calls = {}
+    for line in text.splitlines():
+        found = re.match(
+            r"\s*%(flash_attention_(?:fwd|bwd_dkv|bwd_dq))[.\d]* = (.*?) "
+            r"custom-call\(", line)
+        if found:
+            operands = line.split("operand_layout_constraints=")[1].split(
+                "metadata=")[0]
+            calls[found.group(1)] = tuple(
+                [tuple(int(n) for n in dims.split(","))
+                 for dims in _SHAPE.findall(part)]
+                for part in (found.group(2), operands))
+    return calls
 
 
 def _smoke_llama(n_layers):
@@ -664,8 +690,11 @@ _CELL_STEP = []  # compiled once (half a minute) for the two tests below
 # input alone kept, +1.10 for the flash kernel's output and row statistics
 # (0.48 GB of stacks: the chip's compiler plans about twice what a scan
 # keeps); the next candidates plan 14.61 (k, v) to 15.09 (q, k, v), over
-# the runner's 0.85 (PERF.md section 6, PR 44)
-TRAIN_PLANNED_GB = 14.219
+# the runner's 0.85 (PERF.md section 6, PR 44).  14.219 until PR 47: the
+# kernels then read K and V at their own heads (no copies at 4 x their
+# size) and write the row statistics with positions in the lanes (a
+# float32 [.., seq, 1] was tiled to 128 x its numbers)
+TRAIN_PLANNED_GB = 14.033
 
 
 def _cell_step(topo):
@@ -697,6 +726,15 @@ def test_cell_step_fits_and_its_loss_keeps_the_head_still(topo, as_tpu):
     # the backward scan reads its kept output and row statistics
     assert len(set(re.findall(r"%(flash_attention_fwd[\w.]*) = ",
                               text))) == 1
+    # what the kernels read and write (PR 47).  A device holds 4 rows x 16
+    # query heads over 4 KV heads: K and V arrive, and dK and dV leave, at
+    # the KV heads' width (nothing repeated, nothing summed after), and the
+    # row statistics ride with positions in the lanes, not as [.., seq, 1]
+    q, kv, rows = (64, 4096, 128), (16, 4096, 128), (64, 1, 4096)
+    assert _flash_calls(text) == {
+        "flash_attention_fwd": ([q, rows], [q, kv, kv]),
+        "flash_attention_bwd_dkv": ([kv, kv], [q, kv, kv, q, rows, rows]),
+        "flash_attention_bwd_dq": ([q], [q, kv, kv, q, rows, rows])}
 
 
 def test_cell_step_passes_its_stream_round_the_ring_beside_products(
