@@ -170,9 +170,17 @@ def _engine_metrics():
                     "llm_page_evictions_total", "Prefix-cache pages "
                     "reclaimed to satisfy allocations"),
                 "eviction_scans": Counter(
-                    "llm_eviction_scans_total", "Passes over the prefix "
-                    "cache's resident blocks (page evictions / scans = "
-                    "pages one pass reclaimed)"),
+                    "llm_eviction_scans_total", "Calls of the prefix "
+                    "cache's eviction, one for all the pages a loop phase "
+                    "is short of (page evictions / scans = pages a call "
+                    "reclaimed)"),
+                "eviction_blocks_examined": Counter(
+                    "llm_eviction_blocks_examined_total", "Entries of the "
+                    "prefix cache's eviction order those calls popped, "
+                    "stale and pinned ones included, and blocks a forced "
+                    "cut walked (examined / page evictions = what a "
+                    "reclaimed page cost; a walk of the index reads in "
+                    "the resident blocks a call)"),
                 "prefill_saved": Counter(
                     "llm_prefill_tokens_saved_total", "Prompt tokens whose "
                     "prefill compute was skipped via resident prefix pages "
@@ -584,7 +592,7 @@ class LLMEngine:
                        "tokens_generated": 0, "deliveries": 0,
                        "deliveries_behind_dispatch": 0, "preempted": 0,
                        "admitted": 0, "page_evictions": 0,
-                       "eviction_scans": 0,
+                       "eviction_scans": 0, "eviction_blocks_examined": 0,
                        "prefill_tokens_saved": 0, "cow_copies": 0,
                        "kv_seals": 0, "kv_pulls": 0, "kv_pull_pages": 0,
                        "kv_pull_fallbacks": 0, "prefill_chunks": 0,
@@ -1377,19 +1385,20 @@ class LLMEngine:
     def _reserve(self, n: int) -> bool:
         """Make n pages allocatable, reclaiming prefix-cache pages as
         needed: ONE `PrefixCache.evict` for all the pages that are short,
-        so a phase pays one pass over the resident blocks however many
-        pages it needs.  Returns False (leaving partial reclaims in place
-        — they were the coldest blocks anyway) if the pool can't cover
-        it."""
+        off the order the cache keeps (`eviction_blocks_examined`: what
+        the call looked at to find them).  Returns False (leaving partial
+        reclaims in place — they were the coldest blocks anyway) if the
+        pool can't cover it."""
         short = n - self.allocator.num_free()
         if short <= 0:
             return True
         pc = self.prefix_cache
         if pc is None:
             return False
+        examined = pc.eviction_blocks_examined
         hits = pc.evict(self.allocator.refcount, short)
-        self._stats["eviction_scans"] += 1
-        self._m["eviction_scans"].inc()
+        self._count({"eviction_scans": 1, "eviction_blocks_examined":
+                     pc.eviction_blocks_examined - examined})
         for page, klass in hits:
             self.allocator.reclaim(page)
             self._stats["page_evictions"] += 1
@@ -1696,7 +1705,7 @@ class LLMEngine:
                 return 0
             return need_pages(s) + len(s.pages) - len(s.wpages)
 
-        # one pass over the prefix cache for the whole burst: when it
+        # one eviction call for the whole burst: when it
         # covers the sum every slot below finds its pages free; when it
         # cannot, everything reclaimable is already back and the loop
         # fails at the slot, and preempts the victim, it always did

@@ -28,8 +28,10 @@ Converting locality into throughput (ISSUE 14) adds:
   instead of walking a global LRU — a burst of unique traffic can no
   longer shred a hot shared root that queued requests are about to hit.
   `evict(refcount, n)` takes all the pages a phase of the engine loop
-  needs in ONE pass over the resident blocks (a heap of the evictable
-  leaves, fed by the parents each eviction bares), not a pass a page;
+  needs from an ORDER the cache keeps between calls (a heap over the
+  leaves, entered when a block becomes one, re-keyed when popped), so a
+  call costs what it takes and what it sets aside, not a pass over the
+  resident blocks;
 - partial-block (copy-on-write) matching: blocks remember their token
   content, so a prompt that diverges INSIDE a cached block still reuses
   the shared slots — the engine copies that single page and prefills only
@@ -213,6 +215,9 @@ class PageAllocator:
         self._free: List[int] = list(range(1, num_pages))
         self._rc: Dict[int, int] = {}
         self._cached: Set[int] = set()
+        # cached pages nobody references, counted where a page changes
+        # state (`_check` holds it to the sum over `_cached`)
+        self._resident = 0
         self.num_pages = num_pages
         # RTPU_DEBUG_ALLOCATOR: assert the page-state partition invariant
         # after every op (O(num_pages) — test/chaos runs only)
@@ -239,13 +244,16 @@ class PageAllocator:
         for p in range(1, self.num_pages):
             assert p in fs or self._rc.get(p, 0) > 0 or p in self._cached, \
                 f"page {p} leaked: not free, not referenced, not cached"
+        resident = sum(1 for p in self._cached if self._rc.get(p, 0) <= 0)
+        assert self._resident == resident, \
+            f"{self._resident} pages counted resident, {resident} are"
 
     def num_free(self) -> int:
         return len(self._free)
 
     def num_resident(self) -> int:
         """Cached pages with no live owner (reclaimable without preempting)."""
-        return sum(1 for p in self._cached if self._rc.get(p, 0) <= 0)
+        return self._resident
 
     def can_allocate(self, n: int) -> bool:
         return len(self._free) >= n
@@ -263,7 +271,10 @@ class PageAllocator:
         """Add a reference to already-resident pages (prefix-cache hit)."""
         for p in pages:
             if p != 0:
-                self._rc[p] = self._rc.get(p, 0) + 1
+                rc = self._rc.get(p, 0)
+                self._rc[p] = rc + 1
+                if rc <= 0 and p in self._cached:
+                    self._resident -= 1
         self._check()
 
     def refcount(self, page: int) -> int:
@@ -282,19 +293,29 @@ class PageAllocator:
             if rc > 0:
                 self._rc[p] = rc
                 continue
-            self._rc.pop(p, None)
+            held = self._rc.pop(p, None) is not None
             if p not in self._cached:
                 self._free.append(p)
+            elif held:
+                self._resident += 1
         self._check()
 
     def mark_cached(self, pages: List[int]) -> None:
-        self._cached.update(p for p in pages if p != 0)
+        for p in pages:
+            if p != 0 and p not in self._cached:
+                self._cached.add(p)
+                if self._rc.get(p, 0) <= 0:
+                    self._resident += 1
         self._check()
 
     def reclaim(self, page: int) -> None:
         """Cache eviction: drop residency; back to the free list if idle."""
-        self._cached.discard(page)
-        if self._rc.get(page, 0) <= 0:
+        idle = self._rc.get(page, 0) <= 0
+        if page in self._cached:
+            self._cached.remove(page)
+            if idle:
+                self._resident -= 1
+        if idle:
             self._rc.pop(page, None)
             if page not in self._free:
                 self._free.append(page)
@@ -313,6 +334,11 @@ class _Block:
     # of a family; False marks a never-reused block (a request's unique
     # tail) — the junk eviction should drain first
     was_hit: bool = False
+    # LRU position: taken from the cache's clock when the block enters
+    # `_blocks` and at every move_to_end, so stamps order as `_blocks` does
+    stamp: int = 0
+    # the eviction order holds an entry for this block (at most one)
+    queued: bool = False
 
 
 @dataclass
@@ -337,6 +363,26 @@ class PrefixCache:
     a block whose child blocks are still resident — so unique traffic
     drains cold chains from the tip instead of cutting hot shared roots
     out from under queued requests.
+
+    THE EVICTION ORDER is kept between calls, not found by a walk of the
+    index at every call: `_order` is a heap of `_evict_key` entries over
+    the blocks that are LEAVES, referenced or not.  A block enters when it
+    becomes a leaf (`insert` of a tip, `_remove` baring a parent) and goes
+    when it is evicted.  Everything that changes a key makes it LARGER
+    (`was_hit` False -> True, a family's `last_hit` and `hits`, the LRU
+    stamp at a move_to_end), so nothing is re-keyed when a block or its
+    family is used: an entry's stored key is a lower bound, `evict`
+    compares it with the block's key of the moment when it pops it and
+    puts it back under that one if it grew, and the first entry popped
+    whose key is current is the least of all (every other entry's current
+    key is at least its stored one).  Memory: a block has at most ONE
+    entry (`_Block.queued`), whatever happens to its key, so hits add
+    none; a leaf that gains a child keeps its entry as a stale one, counted
+    in `_stale` and dropped when popped (or taken up again if the block is
+    bared first), and when the stale ones outnumber the rest by 32 the heap
+    is rebuilt without them (`_compact`: amortised against the insertions
+    that made them stale), so `_order` holds under 2 x the resident leaves
+    + 32 entries however many turns extend a chain or hit a spine.
     """
 
     def __init__(self, page_size: int):
@@ -357,6 +403,13 @@ class PrefixCache:
         self.evictions_cold_family = 0
         self.evictions_hot_root_forced = 0
         self.cow_hits = 0
+        # the eviction order (class docstring) and what `evict` had to look
+        # at to take its pages: entries popped (stale, re-keyed and pinned
+        # ones too) and blocks a forced cut walked
+        self._order: List[tuple] = []
+        self._stale = 0
+        self._clock = 0
+        self.eviction_blocks_examined = 0
 
     # ------------------------- hashing -------------------------------
 
@@ -411,11 +464,20 @@ class PrefixCache:
             if blk is None:
                 break
             if refresh:
-                self._blocks.move_to_end(nd)
-                blk.was_hit = True
+                self._refresh(blk)
             d = nd
             pages.append(blk.page)
         return pages, d, len(pages)
+
+    def _tick(self) -> int:
+        self._clock += 1
+        return self._clock
+
+    def _refresh(self, blk: _Block) -> None:
+        """A reuse: `blk` is the most recently used block and a hit one."""
+        self._blocks.move_to_end(blk.digest)
+        blk.stamp = self._tick()
+        blk.was_hit = True
 
     def _touch_family(self, d: bytes) -> None:
         """Record a reuse on the family owning block `d` (heat signal for
@@ -470,8 +532,7 @@ class PrefixCache:
                 best_src, best_m = blk, m
         if best_src is None or best_m <= 0:
             return pages, None, 0
-        self._blocks.move_to_end(best_src.digest)
-        best_src.was_hit = True
+        self._refresh(best_src)
         self._touch_family(best_src.digest)
         self.cow_hits += 1
         return pages, best_src.page, best_m
@@ -512,7 +573,7 @@ class PrefixCache:
         full = min(len(tokens) // ps, len(pages))
         d = b""
         root = b""
-        new_pages: List[int] = []
+        fresh: List[_Block] = []
         for k in range(full):
             prev = d
             d = self._chain(d, tokens[k * ps:(k + 1) * ps])
@@ -520,20 +581,28 @@ class PrefixCache:
                 root = d
             blk = self._blocks.get(d)
             if blk is not None:
-                self._blocks.move_to_end(d)
-                blk.was_hit = True  # a sibling chain runs through it
+                self._refresh(blk)  # a sibling chain runs through it
                 continue
             page = pages[k]
             if page == 0 or page in self._by_page:
                 continue
-            self._blocks[d] = _Block(
+            blk = self._blocks[d] = _Block(
                 d, page, parent=prev, root=root,
-                tokens=tuple(int(t) for t in tokens[k * ps:(k + 1) * ps]))
+                tokens=tuple(int(t) for t in tokens[k * ps:(k + 1) * ps]),
+                stamp=self._tick())
             self._by_page[page] = d
-            self._children.setdefault(prev, set()).add(d)
+            sibs = self._children.setdefault(prev, set())
+            was_leaf = not sibs  # `prev`, where it is resident
+            sibs.add(d)
             self._families.setdefault(root, _Family()).blocks += 1
-            new_pages.append(page)
-        return new_pages
+            fresh.append(blk)
+            if was_leaf and (parent := self._blocks.get(prev)) is not None \
+                    and parent.queued:
+                self._note_stale()
+        for blk in fresh:  # the tip; more where a page was passed over
+            if self._is_leaf(blk.digest):
+                self._enqueue(blk)
+        return [blk.page for blk in fresh]
 
     def _remove(self, blk: _Block) -> None:
         del self._blocks[blk.digest]
@@ -543,21 +612,82 @@ class PrefixCache:
             sibs.discard(blk.digest)
             if not sibs:
                 del self._children[blk.parent]
+                parent = self._blocks.get(blk.parent)
+                if parent is not None:  # bared: a leaf (again)
+                    if parent.queued:
+                        self._stale -= 1
+                    else:
+                        self._enqueue(parent)
         fam = self._families.get(blk.root)
         if fam is not None:
             fam.blocks -= 1
             if fam.blocks <= 0:
                 del self._families[blk.root]
         self.evictions += 1
+        if blk.queued and self._is_leaf(blk.digest):
+            self._note_stale()  # gone by another way than its entry's pop
 
     def _is_leaf(self, d: bytes) -> bool:
         return not self._children.get(d)
 
+    # ------------------------- the eviction order --------------------
+
+    def _enqueue(self, blk: _Block) -> None:
+        blk.queued = True
+        heapq.heappush(self._order, self._evict_key(blk))
+
+    def _note_stale(self) -> None:
+        """One more entry whose block is no resident leaf; rebuild the heap
+        without them once they outnumber the rest."""
+        self._stale += 1
+        if 2 * self._stale > len(self._order) + 32:
+            self._compact()
+
+    def _compact(self) -> None:
+        live = []
+        for entry in self._order:
+            blk = entry[-1]
+            if self._is_resident_leaf(blk):
+                live.append(entry)
+            else:
+                blk.queued = False
+        self._order[:] = live
+        heapq.heapify(self._order)
+        self._stale = 0
+
+    def _is_resident_leaf(self, blk: _Block) -> bool:
+        return self._blocks.get(blk.digest) is blk \
+            and self._is_leaf(blk.digest)
+
+    def _pop_leaf(self, refcount: Callable[[int], int],
+                  pinned: List[_Block]) -> Optional[_Block]:
+        """The unreferenced leaf that sorts first, taken off the order; None
+        when every leaf is referenced (those go to `pinned`, for the caller
+        to put back)."""
+        order = self._order
+        while order:
+            entry = heapq.heappop(order)
+            self.eviction_blocks_examined += 1
+            blk = entry[-1]
+            if not self._is_resident_leaf(blk):
+                blk.queued = False
+                self._stale -= 1
+                continue
+            key = self._evict_key(blk)
+            if key[:4] != entry[:4]:  # used since it was keyed: grown
+                heapq.heappush(order, key)
+            elif refcount(blk.page) > 0:
+                pinned.append(blk)
+            else:
+                blk.queued = False
+                return blk
+        return None
+
     def evict(self, refcount: Callable[[int], int], n: int
               ) -> List[Tuple[int, str]]:
-        """Reclaim up to `n` blocks in ONE pass over the index; returns
-        (page, class) per block in eviction order, fewer than `n` when
-        every remaining block is pinned.
+        """Reclaim up to `n` blocks; returns (page, class) per block in
+        eviction order, fewer than `n` when every remaining block is
+        pinned.
 
         Candidates are unreferenced blocks with no resident children;
         among them the family least recently hit loses a block (never-hit
@@ -572,52 +702,49 @@ class PrefixCache:
         class "hot_root_forced", the event the bench counts as throwing
         locality away.
 
-        The scan keys every candidate (never-hit before reused, family
-        heat, LRU position) into a heap.  Evicting a block can turn its
-        parent into a leaf, which then joins the heap under its own key
-        (a chain drains from its tip), so the order is the one `n`
-        successive calls for one block give: no match happens inside a
-        call, hence neither heat, `was_hit`, refcounts nor the relative
-        LRU order of the survivors can change under it."""
+        The leaves come off the order the cache keeps (class docstring),
+        each under its key of the moment (never-hit before reused, family
+        heat, LRU stamp).  A leaf whose page is referenced (the tip of a
+        live sequence) is set aside and goes back when the call ends;
+        evicting a block can turn its parent into a leaf, which then joins
+        the order under its own key (a chain drains from its tip).  So the
+        pages, their order and their classes are those of `n` successive
+        walks of the whole index for one block each: no match happens
+        inside a call, hence neither heat, `was_hit`, refcounts nor the
+        relative LRU order of the survivors can change under it.  The cost
+        is O((n + pinned + stale or re-keyed entries) log leaves),
+        `eviction_blocks_examined` counts it; only the forced cuts still
+        walk the index (once a call that reaches them, oldest first)."""
         out: List[Tuple[int, str]] = []
-        if n <= 0:
-            return out
-        # every unreferenced block's LRU position, oldest first (also the
-        # order of the forced cuts); the leaves among them start the heap
-        rank: Dict[bytes, int] = {}
-        heap: List[tuple] = []
-        for i, (d, blk) in enumerate(self._blocks.items()):
-            if refcount(blk.page) > 0:
-                continue
-            rank[d] = i
-            if self._is_leaf(d):
-                heap.append(self._evict_key(blk, i))
-        heapq.heapify(heap)
-        oldest = iter(rank)
+        pinned: List[_Block] = []
+        oldest = None  # the forced cuts' walk, begun when first needed
         while len(out) < n:
-            if heap:
-                blk = heapq.heappop(heap)[-1]
+            blk = self._pop_leaf(refcount, pinned)
+            if blk is not None:
                 klass = "cold_family"
                 self.evictions_cold_family += 1
             else:
-                blk = next((b for d in oldest
-                            if (b := self._blocks.get(d)) is not None), None)
-                if blk is None:
+                if oldest is None:
+                    oldest = iter(list(self._blocks.values()))
+                for blk in oldest:
+                    self.eviction_blocks_examined += 1
+                    if self._blocks.get(blk.digest) is blk \
+                            and refcount(blk.page) <= 0:
+                        break
+                else:
                     break
                 klass = "hot_root_forced"
                 self.evictions_hot_root_forced += 1
             self._remove(blk)
             out.append((blk.page, klass))
-            if blk.parent in rank and blk.parent in self._blocks \
-                    and self._is_leaf(blk.parent):
-                heapq.heappush(heap, self._evict_key(
-                    self._blocks[blk.parent], rank[blk.parent]))
+        for blk in pinned:
+            heapq.heappush(self._order, self._evict_key(blk))
         return out
 
-    def _evict_key(self, blk: _Block, rank: int) -> tuple:
+    def _evict_key(self, blk: _Block) -> tuple:
         fam = self._families.get(blk.root)
         heat = (fam.last_hit, fam.hits) if fam is not None else (0.0, 0)
-        return (blk.was_hit, *heat, rank, blk)
+        return (blk.was_hit, *heat, blk.stamp, blk)
 
     def evict_one(self, refcount: Callable[[int], int]
                   ) -> Optional[Tuple[int, str]]:
@@ -692,6 +819,7 @@ class PrefixCache:
             "evictions_cold_family": self.evictions_cold_family,
             "evictions_hot_root_forced": self.evictions_hot_root_forced,
             "cow_hits": self.cow_hits,
+            "eviction_blocks_examined": self.eviction_blocks_examined,
             "digest_limit": self.digest_limit,
             "hit_rate": round(self.hit_tokens / self.lookup_tokens, 4)
             if self.lookup_tokens else 0.0,

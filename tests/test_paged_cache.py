@@ -226,6 +226,9 @@ def test_allocator_invariant_storm():
             hit = cache.evict_one(alloc.refcount)
             if hit is not None:
                 alloc.reclaim(hit[0])
+        # the counter is the sum it replaced (`_check` asserts it too)
+        assert alloc.num_resident() == sum(
+            1 for p in alloc._cached if alloc.refcount(p) <= 0)
     for pages in live:
         alloc.free(pages)
     while True:
@@ -372,11 +375,15 @@ def _storm(rng):
 
 
 def _cache_state(cache):
+    """The index and the counters; not what `evict` examined, which the
+    reference walk neither counts nor is held to."""
+    stats = cache.stats()
+    del stats["eviction_blocks_examined"]
     return (list(cache._blocks), dict(cache._by_page),
             {k: set(v) for k, v in cache._children.items()},
             {r: (f.hits, f.blocks, f.last_hit)
              for r, f in cache._families.items()},
-            cache.stats())
+            stats)
 
 
 SHAPES = {"unshared_chains": _unshared_chains,
@@ -388,14 +395,266 @@ SHAPES = {"unshared_chains": _unshared_chains,
           "storm": _storm}
 
 
+# Histories: MANY `evict` calls on ONE cache, which keeps its order between
+# them, with everything that changes a key or a leaf in between.
+
+
+class _Twins:
+    """A cache driven through `evict`, and its twin driven through the
+    reference walk, one operation at a time; after every operation both
+    must have answered alike and hold the same index, counters, refcounts
+    and free list.  The clock that family heat reads stands still inside
+    an operation and moves between two, so both sides read the same."""
+
+    def __init__(self, monkeypatch, num_pages, page_size=4):
+        from ray_tpu.llm import paged_cache
+
+        self.now = 100.0
+        monkeypatch.setattr(
+            paged_cache, "time",
+            type("Clock", (), {"monotonic": lambda _: self.now})())
+        self.sides = [(PageAllocator(num_pages), PrefixCache(page_size))
+                      for _ in "gw"]
+        self.evicted = 0
+
+    @property
+    def alloc(self):
+        return self.sides[0][0]
+
+    @property
+    def cache(self):
+        return self.sides[0][1]
+
+    def both(self, op):
+        self.now += 1.0
+        got, want = (op(alloc, cache) for alloc, cache in self.sides)
+        assert got == want
+        self._same()
+        return got
+
+    def evict(self, n):
+        self.now += 1.0
+        (alloc, cache), (ref_alloc, ref_cache) = self.sides
+        got = cache.evict(alloc.refcount, n)
+        want = []
+        for _ in range(n):
+            hit = reference_evict_one(ref_cache, ref_alloc.refcount)
+            if hit is None:
+                break
+            want.append(hit)
+        assert got == want
+        for page, _ in got:
+            alloc.reclaim(page)
+            ref_alloc.reclaim(page)
+        self._same()
+        self.evicted += len(got)
+        return got
+
+    def _same(self):
+        (alloc, cache), (ref_alloc, ref_cache) = self.sides
+        assert _cache_state(cache) == _cache_state(ref_cache)
+        assert (alloc._free, alloc._rc, alloc._cached) == (
+            ref_alloc._free, ref_alloc._rc, ref_alloc._cached)
+        # an entry a block, and every evictable leaf has one
+        queued = [e[-1] for e in cache._order]
+        ids = {id(b) for b in queued}
+        assert len(queued) == len(ids)
+        assert all(b.queued for b in queued)
+        for d, blk in cache._blocks.items():
+            if cache._is_leaf(d):
+                assert blk.queued and id(blk) in ids
+
+    # what an engine does to the pair of them
+
+    def admit(self, tokens, register=True):
+        """match_cow, pin, take the rest; the prompt's full pages are
+        registered while the sequence lives (its tip a pinned leaf).
+        Returns its pages, or None when the pool cannot hold it."""
+        def op(alloc, cache):
+            matched, src, _ = cache.match_cow(tokens)
+            need = len(tokens) // cache.page_size + 1 - len(matched)
+            if alloc.num_free() < need:
+                return None
+            alloc.retain(matched)
+            pages = matched + alloc.allocate(need)
+            if register:
+                alloc.mark_cached(cache.insert(tokens, pages))
+            return pages, src
+        got = self.both(op)
+        return got and got[0]
+
+    def finish(self, tokens, pages):
+        def op(alloc, cache):
+            alloc.mark_cached(cache.insert(tokens, pages))
+            alloc.free(pages)
+        self.both(op)
+
+    def make_room(self, rng, short):
+        """Evict as `_reserve` would, a random number of pages over."""
+        if short > 0:
+            self.evict(short + rng.randrange(0, 4))
+
+
+def _history_unshared(tw, rng):
+    """The serving cells' traffic: unshared prompts, answers that extend
+    them (a prompt's tip gains children at the finish), a pool that is
+    full from early on, so nearly every admission and growth evicts."""
+    live = []
+    for _ in range(rng.randrange(120, 200)):
+        if live and rng.random() < 0.45:
+            toks, pages = live.pop(rng.randrange(len(live)))
+            grown = toks + _fresh(rng, 4 * rng.randrange(0, 4))
+            extra = len(grown) // 4 + 1 - len(pages)
+            tw.make_room(rng, extra - tw.alloc.num_free())
+            if tw.alloc.num_free() >= extra:
+                pages = pages + tw.both(lambda a, c: a.allocate(extra))
+                tw.finish(grown, pages)
+            else:
+                tw.both(lambda a, c: a.free(pages))  # preempted
+        else:
+            toks = _fresh(rng, rng.randrange(5, 40))
+            tw.make_room(rng, len(toks) // 4 + 1 - tw.alloc.num_free())
+            pages = tw.admit(toks, register=rng.random() < 0.8)
+            if pages is not None:
+                live.append((toks, pages))
+
+
+def _history_shared(tw, rng):
+    """Families with a spine: hits re-stamp spines and heat families long
+    after their leaves were keyed; sessions extend their own tips; a COW
+    source is refreshed; peeks (which must change nothing) in between."""
+    spines = [_fresh(rng, 4 * rng.randrange(1, 5)) for _ in range(5)]
+    live, done = [], []
+    for _ in range(rng.randrange(150, 250)):
+        op = rng.randrange(8)
+        if op <= 2:
+            base = rng.choice(done)[:rng.randrange(4, 40)] \
+                if done and rng.random() < 0.3 else rng.choice(spines)
+            toks = base + _fresh(rng, rng.randrange(1, 18))
+            tw.make_room(rng, len(toks) // 4 + 1 - tw.alloc.num_free())
+            pages = tw.admit(toks, register=rng.random() < 0.7)
+            if pages is not None:
+                live.append((toks, pages))
+        elif op <= 4 and live:
+            toks, pages = live.pop(rng.randrange(len(live)))
+            tw.finish(toks, pages)
+            done.append(toks)
+        elif op == 5:
+            toks = rng.choice(spines) + [7]
+            before = _cache_state(tw.cache), [
+                e[:4] for e in tw.cache._order]
+            tw.both(lambda a, c: c.peek_match_tokens(toks))
+            assert before == (_cache_state(tw.cache),
+                              [e[:4] for e in tw.cache._order])
+        elif op == 6:
+            toks = rng.choice(spines) + [7]
+            tw.both(lambda a, c: c.match(toks))
+        else:
+            tw.evict(rng.randrange(1, 7))
+
+
+def _history_storm(tw, rng):
+    """A small alphabet on a small pool: chains share, diverge inside
+    blocks, are pinned through their middles (forced cuts) and come back
+    under digests that were cut."""
+    live = []
+    for _ in range(rng.randrange(400, 700)):
+        op = rng.randrange(6)
+        if op == 0 and tw.alloc.num_free() >= 4:
+            n = rng.randrange(1, 5)
+            live.append(tw.both(lambda a, c: a.allocate(n)))
+        elif op == 1 and live:
+            pages = live.pop(rng.randrange(len(live)))
+            toks = [rng.randrange(3) for _ in range(len(pages) * 4)]
+            tw.finish(toks, pages)
+        elif op in (2, 3):
+            toks = [rng.randrange(3) for _ in range(17)]
+            how = rng.choice(["match", "match_cow", "peek_match_tokens"])
+            got = tw.both(lambda a, c: getattr(c, how)(toks))
+            matched = got if how == "match" else got[0] \
+                if how == "match_cow" else []
+            if matched:
+                tw.both(lambda a, c: a.retain(matched))
+                live.append(matched)
+        elif op == 4 and live:
+            pages = live.pop(rng.randrange(len(live)))
+            tw.both(lambda a, c: a.free(pages))
+        elif tw.alloc.num_free() < 12:
+            tw.evict(rng.randrange(1, 6))
+
+
+def _history_leaf_gains_a_child_and_is_bared(tw, rng):
+    """A tip is extended (its entry goes stale), hit while it is interior
+    (its key grows unseen), and bared again by the eviction of what
+    extended it: it must come out where its key of the moment puts it."""
+    chains = []
+    for _ in range(rng.randrange(4, 8)):
+        toks = _fresh(rng, 4 * rng.randrange(1, 4))
+        tw.finish(toks, tw.admit(toks, register=False))
+        chains.append(toks)
+    for toks in rng.sample(chains, len(chains) - 1):
+        longer = toks + _fresh(rng, 4 * rng.randrange(1, 4))
+        tw.finish(longer, tw.admit(longer, register=False))
+        if rng.random() < 0.5:  # the old tip is hit while interior
+            tw.both(lambda a, c: c.match(toks + [7]))
+    assert tw.cache._stale > 0
+    while tw.evict(rng.randrange(1, 4)):  # tails first, then the bared
+        if rng.random() < 0.3:
+            toks = rng.choice(chains) + [7]
+            tw.both(lambda a, c: c.match(toks))
+    assert len(tw.cache) == 0 and not tw.cache._order
+
+
+def _history_tip_unpinned_between_calls(tw, rng):
+    """Live sequences hold their tips while older and younger chains are
+    evicted round them (set aside, call after call); then they end, one
+    by one, and their tips come out in their place in the order."""
+    held = []
+    for i in range(rng.randrange(12, 20)):
+        toks = _fresh(rng, 4 * rng.randrange(1, 5) + 1)
+        pages = tw.admit(toks)
+        if i % 3:
+            tw.finish(toks, pages)
+        else:
+            held.append(pages)  # registered, its tip a pinned leaf
+    pinned = len(held)
+    tw.evict(3)
+    assert len(tw.cache._order) >= pinned  # set aside, and back
+    while held:
+        pages = held.pop(rng.randrange(len(held)))
+        tw.both(lambda a, c: a.free(pages))
+        tw.evict(rng.randrange(1, 5))
+    while tw.evict(4):
+        pass
+    assert len(tw.cache) == 0 and tw.alloc.num_resident() == 0
+
+
+HISTORIES = {
+    "interleaved_unshared": (_history_unshared, 64),
+    "interleaved_shared_spines": (_history_shared, 96),
+    "interleaved_storm": (_history_storm, 64),
+    "leaf_gains_a_child_and_is_bared":
+        (_history_leaf_gains_a_child_and_is_bared, 256),
+    "tip_unpinned_between_calls":
+        (_history_tip_unpinned_between_calls, 256)}
+
+
 @pytest.mark.parametrize("seed", [11, 2147485001, 30303])
-@pytest.mark.parametrize("shape", [*SHAPES, "n_beyond_evictable"])
-def test_evict_n_is_n_reference_calls(shape, seed):
+@pytest.mark.parametrize("shape", [*SHAPES, "n_beyond_evictable",
+                                   *HISTORIES])
+def test_evict_n_is_n_reference_calls(shape, seed, monkeypatch):
     """`evict(refcount, n)` gives the pages, in the order, with the
     classes and the counters of n calls of the one-block walk, and leaves
     the index as they leave it — on every shape, also when n asks for more
-    than can go."""
+    than can go, and call after call on ONE cache through every history
+    (there the twin is compared after every operation)."""
     rng = random.Random(f"{shape}/{seed}")
+    if shape in HISTORIES:
+        history, num_pages = HISTORIES[shape]
+        tw = _Twins(monkeypatch, num_pages)
+        history(tw, rng)
+        assert tw.evicted >= 10
+        return
     beyond = shape == "n_beyond_evictable"
     build = rng.choice(list(SHAPES.values())) if beyond else SHAPES[shape]
     alloc, cache = build(rng)
@@ -499,6 +758,108 @@ def test_a_burst_over_32_slots_is_one_scan():
     assert st["page_evictions"] == sum(grown) >= 32
     assert st["eviction_scans"] <= 2
     assert st["preempted"] == 0
+
+
+def _serve_unshared(engine, rng, live_slots):
+    """A pool filled as the serving cells fill theirs, pages of 4 for
+    pages of 16: `live_slots` sequences decode (their prompts' full pages
+    registered at admission, so every tip is a pinned leaf, and OLDER than
+    most of what lies round it); every other page is what finished
+    requests left, each a family of its own whose answer extended its
+    prompt's tip at the finish."""
+    from ray_tpu.llm.engine import SamplingParams, _Request, _Slot
+
+    alloc = engine.allocator
+    for i in range(live_slots):
+        prompt = _fresh(rng, rng.randrange(32, 192))
+        pages = alloc.allocate(len(prompt) // 4 + 1)
+        engine._register_blocks(prompt, pages)
+        answer = _fresh(rng, 4 * rng.randrange(1, 24) - len(prompt) % 4 - 1)
+        pages += alloc.allocate((len(prompt) + len(answer)) // 4 + 1
+                                - len(pages))
+        req = _Request(request_id=f"r{i}", prompt_tokens=prompt,
+                       params=SamplingParams(max_tokens=512),
+                       submitted_at=float(i))
+        engine._slots[i] = _Slot(
+            request=req, pages=pages, num_tokens=len(prompt) + len(answer),
+            last_token=1, generated=answer)
+    while alloc.num_free() >= 80:
+        prompt = _fresh(rng, rng.randrange(32, 192))
+        seq = prompt + _fresh(rng, rng.randrange(64, 128))
+        pages = alloc.allocate(len(seq) // 4 + 1)
+        engine._register_blocks(prompt, pages)  # admitted
+        engine._register_blocks(seq, pages)  # finished
+        alloc.free(pages)
+    alloc.allocate(alloc.num_free())  # the rest is held by somebody
+
+
+@pytest.mark.parametrize("num_pages, live_slots", [(3072, 32), (12288, 28)])
+def test_a_phase_examines_what_it_takes_not_what_is_resident(
+        num_pages, live_slots, monkeypatch):
+    """Counts, not times: with the pool full, an admission's `_reserve(14)`
+    and a burst's `_ensure_capacity(8)` pop the pages they take, the live
+    slots' pinned tips and a few stale entries, whatever the pool holds
+    (a walk of the index examines every resident block a call: ~1,700 and
+    ~9,900 on these two pools)."""
+    monkeypatch.setenv("RTPU_DEBUG_ALLOCATOR", "0")  # O(pool) an operation
+    engine = _engine(num_pages, max_slots=64)
+    _serve_unshared(engine, random.Random(num_pages), live_slots)
+    cache = engine.prefix_cache
+    assert engine.allocator.num_free() == 0
+    resident = engine.allocator.num_resident()
+    assert resident > num_pages // 2
+    assert resident == sum(1 for p in engine.allocator._cached
+                           if engine.allocator.refcount(p) <= 0)
+    assert len(cache._order) < len(cache) // 10  # leaves, not blocks
+
+    assert engine._reserve(14)
+    st = engine.stats()
+    assert st["page_evictions"] == 14 and st["eviction_scans"] == 1
+    assert 14 <= st["eviction_blocks_examined"] < 14 + live_slots + 8
+    assert st["prefix_cache"]["eviction_blocks_examined"] \
+        == st["eviction_blocks_examined"]
+    engine.allocator.allocate(14)  # the admitted prompt's
+
+    before = [len(s.pages) for s in engine._slots if s is not None]
+    engine._ensure_capacity(8)
+    grown = sum(len(s.pages) for s in engine._slots if s is not None) \
+        - sum(before)
+    st = engine.stats()
+    assert grown >= live_slots // 2 and st["preempted"] == 0
+    assert st["page_evictions"] == 14 + grown and st["eviction_scans"] == 2
+    assert st["eviction_blocks_examined"] < (14 + live_slots + 8) + (
+        grown + live_slots + 8)
+    assert engine.allocator.num_resident() == resident - 14 - grown
+
+
+def test_the_order_holds_an_entry_a_leaf_however_chains_are_extended(
+        monkeypatch):
+    """Sessions that extend their own tip turn after turn, and hits on
+    their spines between: a tip that gains a child leaves a stale entry,
+    a hit leaves none, and the stale ones are swept before they outnumber
+    the leaves by more than the slack."""
+    monkeypatch.setenv("RTPU_DEBUG_ALLOCATOR", "0")  # O(pool) an operation
+    rng = random.Random(3)
+    alloc, cache = PageAllocator(8192), PrefixCache(4)
+    sessions = [_fresh(rng, 8) for _ in range(40)]
+    for turn in range(30):
+        for i, toks in enumerate(sessions):
+            sessions[i] = toks = toks + _fresh(rng, 8)
+            matched = cache.match(toks)
+            alloc.retain(matched)
+            pages = matched + alloc.allocate(len(toks) // 4 - len(matched))
+            alloc.mark_cached(cache.insert(toks, pages))
+            alloc.free(pages)
+            leaves = sum(1 for d in cache._blocks if cache._is_leaf(d))
+            assert leaves == 40 if turn else leaves == i + 1
+            assert len(cache._order) <= 2 * leaves + 32
+    assert len(cache) == 40 * 62
+    # and the order is still the walk's
+    ref_alloc, ref_cache = copy.deepcopy((alloc, cache))
+    want = [reference_evict_one(ref_cache, ref_alloc.refcount)
+            for _ in range(500)]
+    assert cache.evict(alloc.refcount, 500) == want
+    assert _cache_state(cache) == _cache_state(ref_cache)
 
 
 def _reference_ensure_capacity(engine, steps):
