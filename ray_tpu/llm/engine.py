@@ -25,7 +25,13 @@ slot is live.  The slot is taken at the first chunk and decodes after the
 last (``_Slot.prefill_at``); a ``prefill_only`` request (P/D) holds no slot
 and runs all its chunks inline before its pages ship.  A model with window layers (``cfg.window``,
 paged_cache.py) has a second allocator and a second page list a slot
-(``wpages``); what differs is marked "window" below.
+(``wpages``); what differs is marked "window" below.  A model whose
+recurrent layers take a prompt in chunks (it refuses neither
+``chunked_prompt`` nor ``suffix_prefill``: models/minicpm_sala.py) has the
+later chunks go on from the slot's state rows, which only a prompt's FIRST
+chunk begins anew; what its block-sparse layers read of a slot's pages a
+step its programs count themselves, on the device, from the lists the
+kernel is handed (``counted``; ops/block_sparse.py ``walked``).
 """
 
 from __future__ import annotations
@@ -123,6 +129,30 @@ def _engine_metrics():
                     "LATENT rows (one row a token a layer, key and value "
                     "both): x page_size x the row's bytes x layers = what "
                     "the latent decode kernel has to read"),
+                "sparse_blocks_selected": Counter(
+                    "llm_sparse_blocks_selected_total", "Sparse layers: "
+                    "blocks a decode step's lists held, summed over live "
+                    "slots, KV heads and sparse layers (counted by the "
+                    "step, on the device, from the lists' lengths)"),
+                "sparse_pages_read": Counter(
+                    "llm_sparse_pages_read_total", "Sparse layers: pages "
+                    "the decode kernel's lists held, as far as the query's "
+                    "own position, summed over live slots, KV heads and "
+                    "sparse layers (counted by the step, on the device)"),
+                "sparse_pages_resident": Counter(
+                    "llm_sparse_pages_resident_total", "Sparse layers: "
+                    "pages the slots held at that step, what a walk of "
+                    "the whole table would have read, in the same sum"),
+                "dense_rule_slot_steps": Counter(
+                    "llm_dense_rule_slot_steps_total", "Sparse layers: "
+                    "of the steps a live slot a sparse layer, those whose "
+                    "lists held the whole context (at or under dense_len)"),
+                "index_rows_written": Counter(
+                    "llm_index_rows_written_total", "Sparse layers: rows "
+                    "of pooled keys completed, by prefills (a row a page "
+                    "but the last of the prompt) and by decode steps (the "
+                    "step that fills a page), summed over sparse layers "
+                    "(counted by the programs, on the device)"),
                 "prefill_chunks": Counter(
                     "llm_prefill_chunks_total", "Prefill executions that "
                     "computed one chunk of a prompt longer than the "
@@ -477,6 +507,28 @@ class _Slot:
     wpages: List[int] = field(default_factory=list)
 
 
+@jax.jit
+def _summed(counts: list) -> dict:
+    """The ``counted`` of a burst's steps, summed on the device."""
+    return jax.tree.map(lambda *steps: sum(steps[1:], steps[0]), *counts)
+
+
+def _by_name(counted: dict) -> dict:
+    """A program's fetched ``counted`` by counter name.  A key is a name
+    (its value a scalar) or a TUPLE of names (a vector of as many).  Every
+    array fetched is a round trip of ~0.3 ms on the chip's host: five
+    scalars a step were 40 a burst and 7 % of a token's time for
+    models/minicpm_sala.py, a vector a step 8 and 1.8 % (PERF.md section 6,
+    PR 49), hence one vector a step and ``_summed`` a burst."""
+    out = {}
+    for key, n in counted.items():
+        if isinstance(key, tuple):
+            out.update(zip(key, (int(x) for x in n)))
+        else:
+            out[key] = int(n)
+    return out
+
+
 class LLMEngine:
     """Single-process engine; wrap in an actor for serving (server.py).
 
@@ -493,8 +545,10 @@ class LLMEngine:
     to ``stats()``, the metrics and the spans under that name.
 
     A slot's state rows are begun anew by the prefill that admits a
-    sequence to it (from a zero state, whatever the last tenant left) and
-    mean nothing once it is released; without a ``PrefixCache`` a
+    sequence to it (``lm.prefill``: a prompt's first chunk or all of it,
+    from a zero state, whatever the last tenant left; a later chunk goes on
+    from them) and mean nothing once it is released; without a
+    ``PrefixCache`` a
     preempted sequence's resume prefill recomputes from position 0.
 
     The engine holds the parameters in the SERVING layout, made once here
@@ -597,7 +651,10 @@ class LLMEngine:
                        "kv_seals": 0, "kv_pulls": 0, "kv_pull_pages": 0,
                        "kv_pull_fallbacks": 0, "prefill_chunks": 0,
                        "window_pages_freed": 0, "window_pages_read": 0,
-                       "window_pages_skipped": 0, "full_pages_read": 0}
+                       "window_pages_skipped": 0, "full_pages_read": 0,
+                       "sparse_blocks_selected": 0, "sparse_pages_read": 0,
+                       "sparse_pages_resident": 0,
+                       "dense_rule_slot_steps": 0, "index_rows_written": 0}
         # Hit-aware admission (ISSUE 14): under pool pressure prefer the
         # waiting request whose prefix is resident, but never once the
         # head of the queue has waited longer than this cap (seconds) —
@@ -1333,14 +1390,16 @@ class LLMEngine:
             ph.begin(P_PREFILL_DISPATCH, req, (bucket,))
             logits, counted = self._run(program, tokens, *args, slot=slot)
             if self.state is not None:
+                # (a later chunk goes on from the slot's rows: no reset)
                 did["scan_chunks"] = (-(-bucket // SCAN_CHUNK)
                                       * self._state_layers)
-                self._count({**did, "state_resets": 1})
+                self._count({"scan_chunks": did["scan_chunks"],
+                             "state_resets": int(prefix_len == 0)})
             self._deliver(True)
             ph.begin(P_PREFILL_FETCH, req)  # the host waits for the device
             logits, counted = jax.device_get((logits, counted))
             ph.begin(P_PREFILL_EMIT, req)
-            counted = {name: int(n) for name, n in counted.items()}
+            counted = _by_name(counted)
             did.update(counted)
             self._count({**counted, "prefills": 1})
             if logits is not None and end == self._prompt_end(req):
@@ -1860,9 +1919,10 @@ class LLMEngine:
                 rows[0, i] = self._sample_one(
                     logits_np[i], s.request.params, s.rng)
         if counts[0]:  # computed with the tokens that were just fetched
-            counts = jax.device_get(counts)
-            for name in counts[0]:
-                named[name] = sum(int(c[name]) for c in counts)
+            # (a burst's counts are summed on the device: what the host
+            # fetches is one transfer a burst, not one a step)
+            named.update(_by_name(jax.device_get(
+                _summed(counts) if burst > 1 else counts[0])))
         self._count({"decode_steps": burst, "decode_pages_read": pages_read,
                      **named})
         self._accept_burst(active_slots, rows)

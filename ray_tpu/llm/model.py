@@ -36,6 +36,24 @@ way, which it hands the family's walk as one bundle ``via``:
 - ``recur(mix, qkv, b, a, (state, li))``: ``prefill`` runs the chunked
   recurrence from a ZERO state and writes the slot's rows ONCE after the
   scan, the decode step updates every live slot's row in place.
+- ``recur_fixed(q, k, v, g, (S, li))`` (models/minicpm_sala.py's linear
+  layers, ops/lightning.py): as ``recur``, and ``prefill_with_prefix``
+  takes the SLOT'S ROW as the initial state, so a prompt in chunks carries
+  its state from chunk to chunk; only ``prefill`` (a prompt's first chunk,
+  or all of it) begins from zeros.
+- ``attend_sparse(q, k, v, (ck, cv, pooled, li))`` (its sparse layers,
+  ops/block_sparse.py): K/V rows into pool layer ``li`` and the POOLED KEYS
+  those rows complete into ``pooled`` (a row a page beside the pool,
+  ``cache_layout()["page_rows"]``; it rides in the walk with the pools and
+  is written where it lies), then the choice of blocks a query: both
+  prefills attend by KEY BLOCK under the chosen blocks' mask with a running
+  softmax (``block_sparse.selected_attention``: no [L, context] score
+  matrix is ever whole), the decode step hands ``paged_decode_attention`` a
+  LIST of pages a slot a KV head.  A third thing comes back beside the
+  pools, what the call COUNTED on the device (``block_sparse.walked`` of
+  the lists the kernel was handed, the pooled rows completed): the walk
+  sums it over the sparse layers into the program's ``counted``, one
+  vector under the tuple of its names.
 
 A FAMILY (its configuration class in ``models/``) owns WHAT a row is, how
 its layers are walked and what it refuses, and says so once:
@@ -46,8 +64,10 @@ what it counted BY NAME and the rows to write once), ``refuses`` (feature
 No program finds a family out from the tree's keys.
 
 Every token program hands back ONE shape: (result, counted, cache_k,
-cache_v, state); ``counted`` is a mapping (empty for a dense model),
-``state`` and a latent model's ``cache_v`` None.  Pools and state are
+cache_v, state); ``counted`` is a mapping (empty for a dense model) of a
+counter's name to a scalar, or of a TUPLE of names to one vector of as
+many (what the engine fetches is a transfer a key), ``state`` and a latent
+model's ``cache_v`` None.  Pools and state are
 donated and ride in the layer scan's carry whole, scattered in place at
 ``[li, page, slot]``: nothing pool-sized is sliced, stacked or copied.
 """
@@ -62,7 +82,7 @@ import jax.numpy as jnp
 from ray_tpu.models import (afmoe, glm_moe_lite, llama, olmo_hybrid,
                             sdar_moe)
 from ray_tpu.models.llama import embed, head
-from ray_tpu.ops import gated_delta
+from ray_tpu.ops import block_sparse, gated_delta, lightning
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
                                          paged_latent_decode_attention)
 
@@ -77,6 +97,8 @@ def serving_layout(params):
     """The tree as these programs hold it, for a caller with a tree and no
     configuration (the engine asks ``cfg.serving_layout``).  The ONE place
     in ``ray_tpu/llm/`` that recognises a family by its tree's keys."""
+    if isinstance(params["layers"], tuple):  # minicpm_sala's, laid out
+        return params  # (its layout takes the configuration's mixer_types)
     attn = params["layers"].get("attn", ())
     if "lin" in params["layers"]:
         return olmo_hybrid.serving_layout(params)
@@ -140,6 +162,43 @@ def _conv_and_gates(cfg, mix, qkv, before, b, a):
     norms and gates: (q, k, v, g, beta, the convolution's rows)."""
     y, rows = olmo_hybrid.short_conv(mix["conv"], qkv, before)
     return (*olmo_hybrid.delta_inputs(cfg, mix, y, b, a), rows)
+
+
+def _rows_that_ride(cfg, state):
+    """What of ``state`` a prefill's walk carries: the rows a PAGE holds
+    (``cache_layout()["page_rows"]``), written where they lie as the pools
+    are, and None in the place of the rows a SLOT holds, which the prefill
+    writes itself, once.  None for a family that declares no such rows (its
+    walk carries no state at all)."""
+    paged = cfg.cache_layout().get("page_rows") if state is not None else None
+    if not paged:
+        return None
+    return {name: rows if name in paged else None
+            for name, rows in state.items()}
+
+
+def _write_slot_rows(state, rode, left, slot):
+    """``state`` after a prefill's walk: the page rows as the walk left
+    them (``rode``) and ``left`` (name -> [layers, ...]) in ``slot``'s row.
+    Outside the scans, as ``prefill`` says below."""
+    with jax.named_scope("lightning/state"):
+        out = {**state, **{k: v for k, v in rode.items() if v is not None}}
+        for name, rows in left.items():
+            out[name] = jax.lax.dynamic_update_slice(
+                state[name], rows[:, None].astype(state[name].dtype),
+                (0, slot) + (0,) * (rows.ndim - 1))
+        return out
+
+
+def _pooled_rows(cfg, k, page_size: int):
+    """The pooled keys of k [T, G, d] (whole pages), a row a page, at the
+    sizes the rows' cache takes (ops/block_sparse.py ``check_sizes``)."""
+    block_sparse.check_sizes(cfg, page_size)
+    if k.shape[0] % page_size:
+        raise ValueError(
+            f"a prefill of {k.shape[0]} positions is no whole number of "
+            f"pages of {page_size}: the pooled keys are cached a row a page")
+    return block_sparse.pool_keys(cfg, k)
 
 
 def _visible(cfg, qpos, kpos, window: int = 0):
@@ -239,12 +298,49 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
                     jax.lax.dynamic_slice_in_dim(conv, true_len, taps, 0))
         return o, (None, left)
 
-    # the scan carries no state: a prefill begins its slot's rows anew
-    x, (cache_k, cache_v, _), counted, left = cfg.served_walk(
-        params, x, (cache_k, cache_v, None), positions,
+    def attend_sparse(q, k, v, pools):
+        ck, cv, pooled, li = pools
+        L, G, d = k.shape
+        ps, bs = ck.shape[2], cfg.block_size
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, page_rows, slot_positions].set(k)
+            cv = cv.at[li, page_rows, slot_positions].set(v)
+        T = -(-L // bs) * bs  # whole blocks
+        kp, vp = (jnp.pad(y, ((0, T - L), (0, 0), (0, 0))) for y in (k, v))
+        with jax.named_scope("sparse_attn/index"):
+            # a row a page, at the page of the row's first key (the last
+            # rows are not complete: a later chunk or step writes them
+            # again, and no query sees them before)
+            rows = _pooled_rows(cfg, kp, ps).astype(pooled.dtype)
+            pooled = pooled.at[li, page_rows[::ps]].set(rows[:L // ps])
+        out = block_sparse.selected_attention(
+            cfg, q, positions, rows,
+            lambda at, n: (jax.lax.dynamic_slice_in_dim(kp, at, n),
+                           jax.lax.dynamic_slice_in_dim(vp, at, n)),
+            T, true_len)
+        return out, (ck, cv, pooled), {
+            "index_rows_written": block_sparse.rows_complete(cfg, true_len)}
+
+    def recur_fixed(q, k, v, g, rows):  # q, k, v: [L, H, d]; g: [H]
+        with jax.named_scope("lightning/state"):
+            # a padded position changes nothing: no decay, no write
+            real = (positions < true_len)[:, None]
+            o, S = lightning.chunked(
+                q, jnp.where(real[..., None], k, 0), v,
+                jnp.where(real, g, 0.0),
+                jnp.zeros((q.shape[1], k.shape[2], v.shape[2]), jnp.float32))
+        return o, (rows[0], S)
+
+    # the scan carries no SLOT's state: a prefill begins its slot's rows
+    # anew (the rows a page holds ride with the pools)
+    x, (cache_k, cache_v, rode), counted, left = cfg.served_walk(
+        params, x, (cache_k, cache_v, _rows_that_ride(cfg, state)), positions,
         {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
-         "recur": recur})
-    if state is not None:
+         "recur": recur, "attend_sparse": attend_sparse,
+         "recur_fixed": recur_fixed})
+    if isinstance(left, dict):
+        state = _write_slot_rows(state, rode, left, slot)
+    elif state is not None:
         # The slot's rows are written HERE, once, and not in the scan: a
         # row-sized update inside the loop lets XLA choose the carried
         # state's layout to suit the update, and copy all of the state
@@ -262,10 +358,11 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
             cache_v, state)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2, 3))
+@partial(jax.jit, static_argnames=("cfg",),
+         donate_argnames=("cache_k", "cache_v", "state"))
 def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                         true_len, slot_positions, page_table, positions,
-                        cfg):
+                        cfg, state=None, slot=None):
     """Prefill the SUFFIX of one sequence whose leading pages are already
     resident (prefix-cache hit).
 
@@ -278,7 +375,13 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
     columns from this call's writes — masked at tpos <= position, so the
     null page, padded query rows, and future suffix columns all drop out.
     Returns (logits at the last suffix token [V], counted, cache_k,
-    cache_v, None).
+    cache_v, state).
+
+    A model whose recurrent layers take it (``recur_fixed``) hands
+    ``state`` and ``slot`` as ``prefill`` does: the slot's rows are the
+    recurrence's INITIAL state here, what the positions before this call's
+    left, and its final state goes back into them.  Its sparse layers'
+    chunk begins on a page.
     """
     refuse(cfg, "suffix_prefill", "prefill_with_prefix")
     P = jax.tree.leaves(page_table)[0].shape[0]
@@ -328,10 +431,64 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
         return (_rebuilt_attention(cfg, a, q_nope, q_rope, rows, mask),
                 (pool, None))
 
-    x, caches, counted, _ = cfg.served_walk(
-        params, x, (cache_k, cache_v, None), positions,
-        {**_by_kind(cfg, attend_through), "attend_latent": attend_latent})
-    return (_last_logits(params, x, cfg, true_len), counted, *caches)
+    def attend_sparse(q, k, v, pools):
+        ck, cv, pooled, li = pools
+        L, G, d = k.shape
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, page_rows, slot_positions].set(k)
+            cv = cv.at[li, page_rows, slot_positions].set(v)
+
+        def through(first, n):  # n entries of the table from ``first`` on
+            at = first + jnp.arange(n)
+            return jnp.where((at >= 0) & (at < P),
+                             page_table[jnp.clip(at, 0, P - 1)], 0)
+
+        with jax.named_scope("sparse_attn/index"):
+            # this call's rows, and those that began in the pages before
+            # it and end here: their first keys are the pool's
+            w = cfg.kernel_size // page_size  # pages a row spans
+            first = positions[0] // page_size - (w - 1)
+            before = ck[li, through(first, w - 1)].reshape(-1, G, d)
+            rows = _pooled_rows(cfg, jnp.concatenate([before, k]), page_size)
+            pooled = pooled.at[li, through(first, rows.shape[0])].set(
+                rows.astype(pooled.dtype))
+            rows = pooled[li, page_table]  # the sequence's, [P, G, d]
+
+        def keys_of(at, n):
+            pages = jax.lax.dynamic_slice_in_dim(
+                page_table, at // page_size, n // page_size)
+            return (ck[li, pages].reshape(n, G, d),
+                    cv[li, pages].reshape(n, G, d))
+
+        if (P * page_size) % cfg.block_size:
+            raise ValueError(
+                f"a page table of {P * page_size} positions is no whole "
+                f"number of blocks of {cfg.block_size}")
+        ends = positions[0] + true_len
+        out = block_sparse.selected_attention(cfg, q, positions, rows,
+                                              keys_of, P * page_size, ends)
+        return out, (ck, cv, pooled), {
+            "index_rows_written": block_sparse.rows_complete(cfg, ends)
+            - block_sparse.rows_complete(cfg, positions[0])}
+
+    def recur_fixed(q, k, v, g, rows):  # from what the slot's row holds
+        with jax.named_scope("lightning/state"):
+            real = (jnp.arange(tokens.shape[0]) < true_len)[:, None]
+            S0 = jax.lax.dynamic_slice(
+                state["S"], (rows[1], slot, 0, 0, 0),
+                (1, 1, *state["S"].shape[2:]))[0, 0]
+            o, S = lightning.chunked(q, jnp.where(real[..., None], k, 0), v,
+                                     jnp.where(real, g, 0.0), S0)
+        return o, (rows[0], S)
+
+    x, (cache_k, cache_v, rode), counted, left = cfg.served_walk(
+        params, x, (cache_k, cache_v, _rows_that_ride(cfg, state)), positions,
+        {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
+         "attend_sparse": attend_sparse, "recur_fixed": recur_fixed})
+    if isinstance(left, dict):
+        state = _write_slot_rows(state, rode, left, slot)
+    return (_last_logits(params, x, cfg, true_len), counted, cache_k,
+            cache_v, state)
 
 
 def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
@@ -420,10 +577,46 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
                 st["conv"], conv[1:], li * taps, axis=0)}
         return o, (st, None)
 
+    def attend_sparse(q, k, v, pools):  # q: [B, H, d]; k, v: [B, G, d]
+        ck, cv, pooled, li = pools
+        with jax.named_scope("attn/kv_write"):
+            ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
+            cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
+        with jax.named_scope("sparse_attn/index"):
+            block_sparse.check_sizes(cfg, page_size)
+            # the row this step's key completes: the one that began w - 1
+            # pages before the page this position fills
+            w = cfg.kernel_size // page_size
+            first = lengths // page_size - w
+            done = (lengths > 0) & (lengths % page_size == 0) & (first >= 0)
+            pages = jnp.take_along_axis(
+                page_tables, jnp.clip(first[:, None] + jnp.arange(w), 0,
+                                      P - 1), axis=1)  # [B, w]
+            row = ck[li, pages].astype(jnp.float32).mean(axis=(1, 2))
+            pooled = pooled.at[li, jnp.where(done, pages[:, 0], 0)].set(
+                row.astype(pooled.dtype))
+            lists, held = block_sparse.page_lists(
+                cfg, q, pooled[li, page_tables], page_tables, lengths,
+                block_sparse.list_width(cfg, P))
+            counted = {**block_sparse.walked(cfg, held, lengths),
+                       "index_rows_written": done.sum().astype(jnp.int32)}
+        with jax.named_scope("sparse_attn/attend"):
+            return (paged_decode_attention(q, ck, cv, lists, held, li,
+                                           heads_apart=True),
+                    (ck, cv, pooled), counted)
+
+    def recur_fixed(q, k, v, g, rows):  # q, k, v: [B, H, d]; g: [H]
+        S, li = rows
+        with jax.named_scope("lightning/state"):
+            o, S = lightning.decode_update(
+                S, li, q, k, v, jnp.broadcast_to(g, q.shape[:2]), active)
+        return o, (S, None)
+
     x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, state), positions,
         {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
-         "recur": recur})
+         "recur": recur, "attend_sparse": attend_sparse,
+         "recur_fixed": recur_fixed})
     return (head(params, x, cfg), counted, *caches)
 
 

@@ -103,7 +103,13 @@ class CacheConfig:
     only: ``window_layers`` of the attending layers see the last ``window``
     positions alone and have a pool of their own, ``window_pages`` pages a
     layer (the module docstring says how a sequence holds them);
-    ``n_layers`` then counts the FULL layers."""
+    ``n_layers`` then counts the FULL layers.  A fifth, beside K/V pages
+    too: ``page_rows``, by name the (layers, shape, dtype) of a row that
+    every PAGE holds beside its K and V (models/minicpm_sala.py: the pooled
+    keys its sparse layers choose blocks by).  Such a row is found through
+    the page table as the page is, lives and dies with the page, and is
+    allocated with the state rows (``init_state``: [layers, num_pages,
+    *shape])."""
 
     n_layers: int
     n_kv_heads: int = 0
@@ -118,6 +124,7 @@ class CacheConfig:
     window_layers: int = 0
     window: int = 0
     window_pages: int = 0
+    page_rows: Optional[dict] = None
 
     def __post_init__(self):
         if bool(self.latent_dim) == bool(self.n_kv_heads * self.head_dim):
@@ -191,12 +198,15 @@ def init_cache(cfg: CacheConfig):
 
 
 def init_state(cfg: CacheConfig):
-    """The state rows [count, max_slots, *row] by name, zeros; None for a
-    model that declares none."""
-    if not cfg.state_rows:
+    """The state rows [count, max_slots, *row] and the page rows [layers,
+    num_pages, *row] by name, zeros; None for a model that declares
+    neither."""
+    if not cfg.state_rows and not cfg.page_rows:
         return None
-    return {name: jnp.zeros((count, cfg.max_slots, *shape), dt)
-            for name, (count, shape, dt) in cfg.state_rows.items()}
+    return {**{name: jnp.zeros((count, cfg.max_slots, *shape), dt)
+               for name, (count, shape, dt) in (cfg.state_rows or {}).items()},
+            **{name: jnp.zeros((layers, cfg.num_pages, *shape), dt)
+               for name, (layers, shape, dt) in (cfg.page_rows or {}).items()}}
 
 
 class PageAllocator:

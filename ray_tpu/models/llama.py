@@ -204,6 +204,12 @@ PARTS = (
     # sigmoid gate on attention's output, and the sandwich block's two
     # norms BEHIND attention and the feed-forward
     "attn/qk_norm", "attn/gate", "norm/post",
+    # models/minicpm_sala.py: a sparse layer's products (q, k, v and gate
+    # with their norms; the gate and W_o), its pooled keys and the choice
+    # of blocks, its attention over the chosen ones; a linear layer's
+    # products and norms, its recurrence, its output norm, gate and W_o
+    "sparse_attn/proj", "sparse_attn/index", "sparse_attn/attend",
+    "lightning/proj", "lightning/state", "lightning/out",
     # parallel/tp_stream.py: the links of a training trunk whose stream is
     # split over ``tp`` between the products: a group's rows passed round
     # the ring into ``attn/qkv`` and ``mlp/gate_up``, the partial sums of
@@ -368,7 +374,11 @@ def head(params, x, cfg, true_len=None):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if true_len is not None:
             x = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
-        return x.astype(jnp.float32) @ params["lm_head"]
+        x = x.astype(jnp.float32)
+        # a family whose head reads a multiple of the normed stream says
+        # so (models/minicpm_sala.py ``logit_scale``), as ``embed`` above
+        scale = getattr(cfg, "logit_scale", None)
+        return (x if scale is None else x * scale) @ params["lm_head"]
 
 
 def served_walk(cfg, params, x, caches, positions, via):

@@ -1,0 +1,216 @@
+"""Lightning Attention's recurrence (Qin et al., arXiv:2401.04658): linear
+attention with a FIXED decay a head, three ways.
+
+A head keeps a matrix S [d_k, d_v] (float32) that a token updates:
+
+    S_t = lambda S_{t-1} + k_t^T v_t,        o_t = q_t S_t
+
+with lambda in (0, 1) a constant of the head (``log_decays``: the ALiBi
+slopes).  No write strength and no correction by what the state already
+holds (ops/gated_delta.py's delta rule), so no triangular system: inside a
+chunk the outputs are one masked product, and only the state goes from
+chunk to chunk.
+
+Every form takes the decay as its LOG, a number a token a head (``g`` [L,
+H]), so that a caller can let a padded position change nothing (g = 0 and
+a zero key) with the same program.  The state is held as it is written,
+[H, d_k, d_v]: at d_k = d_v = 128 a head's state is whole (8, 128) tiles
+already, and ``gated_delta.pack_state`` (which lays heads of 192 side by
+side) would be the identity on it.
+
+``recurrent``: the definition, a token at a time (the tests' yardstick).
+``chunked``: a whole (padded) sequence from an initial state S0, for
+prefill and for a later chunk of a prompt, in plain ``jax.numpy``; state
+products at ``highest`` precision, the state float32.
+``decode_update``: one token a slot, a Pallas kernel on the pattern of
+``gated_delta.decode_update``: a live slot's state is read once and written
+once where it lies (aliased to the output), another slot's not at all.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+CHUNK = 64
+_HI = jax.lax.Precision.HIGHEST
+
+
+def log_decays(n_heads: int):
+    """log lambda_h = -2^(-8 (h + 1) / n_heads), h = 0 .. n_heads - 1: the
+    ALiBi slopes, as Lightning Attention sets its decay (float32 [H])."""
+    h = jnp.arange(1, n_heads + 1, dtype=jnp.float32)
+    return -jnp.exp2(-8.0 * h / n_heads)
+
+
+def recurrent(q, k, v, g, S0):
+    """Token by token.  q, k: [L, H, d_k]; v: [L, H, d_v]; g (log decay):
+    [L, H]; S0: [H, d_k, d_v].  Returns (o [L, H, d_v], S_L), float32."""
+    f32 = jnp.float32
+
+    def step(S, x):
+        q, k, v, g = x
+        S = S * jnp.exp(g)[:, None, None] + k[:, :, None] * v[:, None, :]
+        return S, jnp.einsum("hk,hkv->hv", q, S, precision=_HI)
+
+    S, o = jax.lax.scan(step, S0.astype(f32),
+                        tuple(x.astype(f32) for x in (q, k, v, g)))
+    return o, S
+
+
+def chunked(q, k, v, g, S0, chunk: int = CHUNK):
+    """The same as ``recurrent`` by chunks of ``chunk`` tokens (L is padded
+    to a multiple of it with tokens that change nothing).  Returns
+    (o [L, H, d_v] float32, S_L [H, d_k, d_v] float32)."""
+    f32 = jnp.float32
+    L, H, _ = q.shape
+    n = -(-L // chunk)
+    pad = n * chunk - L
+
+    def chunks(x):  # [L, H, ...] -> [n, H, C, ...]
+        x = jnp.pad(x.astype(f32), ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return jnp.moveaxis(x.reshape(n, chunk, *x.shape[1:]), 1, 2)
+
+    q, k, v, g = (chunks(x) for x in (q, k, v, g))
+    gam = jnp.cumsum(g, axis=-1)  # [n, H, C] log of the running decay
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # ratio[t, i] = G_t / G_i where i <= t, as exp of a difference <= 0
+    diff = gam[..., :, None] - gam[..., None, :]
+    ratio = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    qk = jnp.einsum("nhtd,nhid->nhti", q, k, precision=_HI) * ratio
+    inside = jnp.einsum("nhti,nhiv->nhtv", qk, v, precision=_HI)
+    q_in = q * jnp.exp(gam)[..., None]  # what of S_0 a query still sees
+    # what of a write is left at the chunk's end
+    k_out = k * jnp.exp(gam[..., -1:] - gam)[..., None]
+    wrote = jnp.einsum("nhtk,nhtv->nhkv", k_out, v, precision=_HI)
+    decay = jnp.exp(gam[..., -1])  # [n, H]
+
+    def step(S, x):
+        q_in, inside, wrote, decay = x
+        o = inside + jnp.einsum("htk,hkv->htv", q_in, S, precision=_HI)
+        return S * decay[:, None, None] + wrote, o
+
+    S, o = jax.lax.scan(step, S0.astype(f32), (q_in, inside, wrote, decay))
+    return jnp.moveaxis(o, 1, 2).reshape(n * chunk, H, -1)[:L], S
+
+
+# ---------------------------------------------------------------------------
+# decode: one token a slot, the state updated where it lies
+
+
+def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,
+                   o_ref, s_out):
+    del layer_ref, order_ref  # the block indices read them
+    hb, _, dv = s_ref.shape[2:]
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        for p in range(hb):
+            kx = kq_ref[0, 0, 0][:, p:p + 1]  # [d_k, 1]
+            qx = kq_ref[0, 0, 1][:, p:p + 1]
+            at = slice(p * dv, (p + 1) * dv)
+            v, a = (vec_ref[0, 0, r:r + 1, at] for r in range(2))  # [1, d_v]
+            st = s_ref[0, 0, p] * a + kx * v
+            s_out[0, 0, p] = st
+            o_ref[0, 0, :, at] = jnp.sum(st * qx, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("group", "interpret"))
+def _decode_update(state, layer, q, k, v, g, active, *, group: int,
+                   interpret: bool):
+    f32 = jnp.float32
+    B, H, dk = q.shape
+    dv = v.shape[-1]
+    n_groups = H // group
+    width = group * dv
+
+    def by_group(x):  # [B, H, d_k] -> [B, groups, d_k, heads a group]
+        return jnp.swapaxes(x.astype(f32).reshape(B, n_groups, group, dk),
+                            2, 3)
+
+    def by_lane(x):  # [B, H, d_v] -> [B, groups, lanes]
+        return x.astype(f32).reshape(B, n_groups, width)
+
+    kq = jnp.stack([by_group(k), by_group(q)], axis=2)
+    vec = jnp.stack([by_lane(v), by_lane(jnp.broadcast_to(
+        jnp.exp(g.astype(f32))[..., None], (B, H, dv)))], axis=2)
+    # live slots first; the steps past them stay on the last live block
+    # (same index: no copy in, and it is written back once, whole)
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    live = jnp.sum(active).astype(jnp.int32).reshape(1)
+
+    def at(i, j, layer_ref, order_ref, live_ref):
+        last = jnp.maximum(live_ref[0] - 1, 0)
+        on = i < live_ref[0]
+        return (order_ref[jnp.minimum(i, last)],
+                jnp.where(on, j, n_groups - 1))
+
+    def small(i, j, *refs):
+        return (*at(i, j, *refs), 0, 0)
+
+    def columns(i, j, *refs):
+        return (*at(i, j, *refs), 0, 0, 0)
+
+    def rows(i, j, layer_ref, *refs):
+        slot, grp = at(i, j, layer_ref, *refs)
+        return (layer_ref[0], slot, grp, 0, 0)
+
+    o, state = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, n_groups),
+            in_specs=[
+                pl.BlockSpec((1, 1, 2, dk, group), columns),
+                pl.BlockSpec((1, 1, 2, width), small),
+                pl.BlockSpec((1, 1, group, dk, dv), rows),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, 1, width), small),
+                pl.BlockSpec((1, 1, group, dk, dv), rows),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, n_groups, 1, width), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands count the scalars: the state is the sixth
+        input_output_aliases={5: 1},
+        interpret=interpret,
+        name="lightning_update",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), order, live, kq, vec,
+      state)
+    o = jnp.where(active[:, None, None], o.reshape(B, H, dv), 0.0)
+    return o, state
+
+
+def decode_update(state, layer, q, k, v, g, active):
+    """One token for every live slot, in place.
+
+    state: [layers, slots, H, d_k, d_v] float32, every layer's rows; only
+    ``layer`` (an int32 scalar, traced or not) is read and written, and of
+    it only the slots where ``active`` [B] holds.  q, k: [B, H, d_k];
+    v: [B, H, d_v]; g (log decay): [B, H].  Returns (o [B, H, d_v]
+    float32, zeros where not active; the state)."""
+    if state.ndim != 5 or state.dtype != jnp.float32:
+        raise ValueError(
+            f"the lightning update takes the float32 state [layers, slots, "
+            f"H, d_k, d_v]; got {state.dtype}{list(state.shape)}")
+    B, H, dk = q.shape
+    dv = v.shape[-1]
+    if state.shape[1:] != (B, H, dk, dv):
+        raise ValueError(
+            f"state rows {state.shape[1:]} do not hold {B} slots of {H} "
+            f"heads [{dk}, {dv}]")
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and (dv % 128 or dk % 8):
+        raise ValueError(
+            f"on the TPU the lightning update moves whole tiles, and a "
+            f"[{dk}, {dv}] state is not made of them: d_k must be a "
+            f"multiple of 8 and d_v of 128")
+    # heads a block: 0.5 MB of state at [128, 128]
+    group = max(d for d in range(1, H + 1)
+                if H % d == 0 and d * dk * dv * 4 <= (1 << 19) or d == 1)
+    return _decode_update(state, layer, q, k, v, g, active, group=group,
+                          interpret=not on_tpu)

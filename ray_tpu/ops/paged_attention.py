@@ -58,9 +58,13 @@ BLOCK_TOKENS = 256
 
 
 def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
-                         sm_scale: float, bounded: bool = False):
+                         sm_scale: float, bounded: bool = False,
+                         heads_apart: bool = False):
     # ``bounded``: a fourth prefetched scalar a slot, the first position
     # the slot's query sees (0 <= start < length)
+    # ``heads_apart``: a "slot" is one KV head of a slot (slot b's is
+    # b % n_kv, its rows that head's query heads) with a page list of its
+    # own, so only that head's columns of a page are its keys
     starts_ref, refs = (refs[0], refs[1:]) if bounded else (None, refs)
     q_ref, k_pool, v_pool, o_ref, k_buf, v_buf, sems = refs
     B, H, d = q_ref.shape
@@ -124,6 +128,7 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
         n_blocks = (length + block_tokens - 1) // block_tokens
         nxt = next_active(b)
         q = q_ref[b]
+        own = (col % n_kv) == (b % n_kv) if heads_apart else own_head
 
         def block(i, carry):
             m, l, acc, buf = carry
@@ -141,7 +146,7 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
             v = v_buf[buf].reshape(cols, d)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-            keep = own_head & (col < (length - i * block_tokens) * n_kv)
+            keep = own & (col < (length - i * block_tokens) * n_kv)
             if bounded:
                 keep &= col >= (starts_ref[b] - i * block_tokens) * n_kv
             s = jnp.where(keep, s * sm_scale, NEG_INF)
@@ -168,10 +173,11 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
     jax.lax.while_loop(lambda c: c[0] < B, slot, (first, 0))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pages_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret",
+                                             "heads_apart"))
 def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer,
-                  starts=None, *, pages_per_block: int, interpret: bool):
+                  starts=None, *, pages_per_block: int, interpret: bool,
+                  heads_apart: bool = False):
     B, H, d = q.shape
     _, _, ps, n_kv, _ = k_pool.shape
     P = page_tables.shape[1]
@@ -186,6 +192,8 @@ def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer,
         kernel = functools.partial(kernel, bounded=True)
         bound = (jnp.clip(starts, 0, jnp.maximum(lengths - 1, 0)).astype(
             jnp.int32),)
+    if heads_apart:
+        kernel = functools.partial(kernel, heads_apart=True)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -209,7 +217,8 @@ def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer,
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, page_tables: jax.Array,
                            lengths: jax.Array, layer, *, window=None,
-                           pages_per_block: int | None = None) -> jax.Array:
+                           pages_per_block: int | None = None,
+                           heads_apart: bool = False) -> jax.Array:
     """Attention of one query token a slot over that slot's cached tokens.
 
     q: [B, H, d], the new token's (rotated) query heads.  k_pool / v_pool:
@@ -226,7 +235,25 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     scalar (traced or not); the slot then attends to positions ``max(0,
     lengths[b] - window) .. lengths[b] - 1`` and the table's entries for
     the pages wholly before them are never read (they may be null).
+    ``heads_apart`` (a layer whose KV heads each attend to pages of their
+    own choice, ops/block_sparse.py): page_tables [B, n_kv_heads, W] is a
+    LIST of pages a slot a KV head, in the order of their positions, and
+    lengths [B, n_kv_heads] the positions a list holds (its last page as
+    far as the query's own position); a KV head's query heads attend to
+    their list's pages alone, of which they read their own head's rows.
     """
+    shape = q.shape
+    if heads_apart:  # a KV head of a slot is a slot of the kernel's
+        G = k_pool.shape[3]
+        if (q.ndim != 3 or page_tables.shape[:2] != (shape[0], G)
+                or lengths.shape != (shape[0], G) or shape[1] % G):
+            raise ValueError(
+                f"with heads_apart the lists {page_tables.shape} and their "
+                f"lengths {lengths.shape} lead with q's batch and the "
+                f"pool's KV heads ({shape[0]}, {G})")
+        q = q.reshape(shape[0] * G, shape[1] // G, shape[2])
+        page_tables = page_tables.reshape(shape[0] * G, -1)
+        lengths = lengths.reshape(-1)
     if q.ndim != 3 or k_pool.ndim != 5 or k_pool.shape != v_pool.shape:
         raise ValueError(
             f"paged decode attention takes q [B, H, d] and two pools "
@@ -257,10 +284,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             raise ValueError(f"a window holds 1 or more positions, the "
                              f"query's own among them; got {window}")
         starts = jnp.maximum(lengths - window, 0)
-    return _paged_decode(q.astype(k_pool.dtype), k_pool, v_pool, page_tables,
-                         lengths, layer, starts,
-                         pages_per_block=pages_per_block,
-                         interpret=not on_tpu).astype(q.dtype)
+    out = _paged_decode(q.astype(k_pool.dtype), k_pool, v_pool, page_tables,
+                        lengths, layer, starts,
+                        pages_per_block=pages_per_block, interpret=not on_tpu,
+                        **({"heads_apart": True} if heads_apart else {})
+                        ).astype(q.dtype)
+    return out.reshape(shape) if heads_apart else out
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,8 @@ import pytest
 
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.models import afmoe, glm_moe_lite, llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala,
+                            olmo_hybrid, sdar_moe)
 
 VOCAB = 128
 
@@ -28,6 +29,11 @@ FAMILIES = {
                      "GLMMoELiteConfig caches latent rows"),
     "afmoe": (afmoe, afmoe.AfmoeConfig.tiny(VOCAB),
               "AfmoeConfig keeps a window layer's pages only while"),
+    # (pages of 16 below: a pooled row a page, a block four pages)
+    "minicpm_sala": (minicpm_sala, minicpm_sala.MiniCPMSALAConfig.tiny(
+        VOCAB, kernel_size=32, kernel_stride=16, block_size=64,
+        window_size=128, dense_len=384),
+        "MiniCPMSALAConfig keeps a state row a slot .* does not serve with"),
 }
 
 # path -> (the feature it needs, where the refusal says it was asked)
@@ -88,6 +94,9 @@ def test_every_family_declares_only_features_this_test_asks_for():
     for _, cfg, _ in FAMILIES.values():
         assert set(cfg.refuses) <= asked
     assert FAMILIES["llama"][1].refuses == {}
+    # state beside the pages, and yet a prompt in chunks: the chunks carry it
+    assert set(FAMILIES["minicpm_sala"][1].refuses) == {
+        "pd", "kv_tier", "prefix_cache"}
 
 
 @pytest.mark.parametrize("path", list(PATHS))

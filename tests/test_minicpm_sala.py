@@ -1,0 +1,408 @@
+"""MiniCPM-SALA (models/minicpm_sala.py): block-sparse attention layers and
+fixed-decay linear-attention layers, served through the paged engine, held
+to ``benchmarks/reference/minicpm_sala.py`` on the CPU at ``tiny()`` sizes
+(2 KV heads, pages of 8, blocks of 4 pages, ``dense_len`` 8 blocks, both
+kinds of layer), float32.
+
+TOL: both sides compute in float32, in another ORDER (the program by
+chunks, key blocks and pages, at default matmul precision in the stream;
+the reference whole, at ``highest``): logits of O(1) agree to a few 1e-5;
+1e-3 leaves room and a wrong mask, block or state moves them by 1e-1.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.families import minicpm_sala as family
+from benchmarks.reference import minicpm_sala as ref
+from ray_tpu.llm import model as lm
+from ray_tpu.llm.config import EngineConfig, SamplingParams
+from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.models import minicpm_sala as ms
+from ray_tpu.ops import block_sparse, lightning
+from ray_tpu.ops.paged_attention import paged_decode_attention
+
+TOL = 1e-3
+CFG = ms.MiniCPMSALAConfig.tiny()
+PS = CFG.kernel_stride
+
+
+def config_file(cfg=CFG, **engine) -> dict:
+    """``cfg`` as a benchmark configuration file has it."""
+    return {
+        "vocab_size": cfg.vocab_size, "hidden_size": cfg.d_model,
+        "intermediate_size": cfg.d_ff, "num_hidden_layers": cfg.n_layers,
+        "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "lightning_nh": cfg.lightning_heads,
+        "lightning_head_dim": cfg.lightning_head_dim,
+        "mixer_types": list(cfg.mixer_types), "scale_emb": cfg.scale_emb,
+        "scale_depth": cfg.scale_depth, "dim_model_base": cfg.dim_model_base,
+        "published": {"num_hidden_layers": cfg.depth_base},
+        "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.norm_eps,
+        "max_position_embeddings": cfg.max_seq_len, "dtype": cfg.dtype,
+        "sparse_config": {k: getattr(cfg, k) for k in (
+            "kernel_size", "kernel_stride", "block_size", "init_blocks",
+            "window_size", "topk", "dense_len")},
+        "engine": {"page_size": PS, **engine}}
+
+
+C = config_file()
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ms.init(CFG, jax.random.PRNGKey(0))
+
+
+def _engine(params, buckets=(32, 64, 128), slots=4, pages=300, cfg=CFG):
+    return LLMEngine(params, cfg, EngineConfig(
+        max_slots=slots, page_size=PS, max_seq_len=512, num_pages=pages,
+        prefill_buckets=buckets))
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(3, CFG.vocab_size, n).tolist()
+
+
+def _gaps(params, prompt, out):
+    """How far each of ``out`` lies under the reference's best on the
+    sequence's own history."""
+    return ref.verify(C, params, prompt, out, len(out), 512)[0]
+
+
+def test_the_family_file_and_the_model_agree_on_the_configuration():
+    assert family.model_config(C) == CFG
+    shapes = jax.eval_shape(lambda k: ms.init(CFG, k), jax.random.PRNGKey(0))
+    assert family.n_params(C) == sum(
+        x.size for x in jax.tree.leaves(shapes))
+    layout = CFG.cache_layout()
+    assert layout["n_layers"] == 2 and layout["state_layers"] == 3
+    assert set(layout["page_rows"]) == {"pooled_k"}
+    assert CFG.runs() == ((ms.SPARSE, 0, 1), (ms.LINEAR, 0, 2),
+                          (ms.SPARSE, 1, 1), (ms.LINEAR, 2, 1))
+    tree = CFG.serving_layout(ms.init(CFG, jax.random.PRNGKey(1)))
+    assert CFG.serving_layout(tree) is tree
+    assert [jax.tree.leaves(r)[0].shape[0] for r in tree["layers"]] \
+        == [1, 2, 1, 1]
+
+
+@pytest.mark.parametrize("n", [40, 256, 257, 400])
+def test_pinned_program_layers_against_the_reference(params, n):
+    """The program's layers (cacheless, the reference's choice handed in)
+    against the reference, under, at and past ``dense_len``; and the
+    program's own choice is the reference's at float32."""
+    tokens = np.zeros(512, np.int32)
+    tokens[:n] = _prompt(n, n)
+    rows = jnp.asarray([n // 2, n - 1], jnp.int32)
+    want, sel = ref.logits_and_selection(C, params, jnp.asarray(tokens), rows)
+    got, own = family.pinned_logits(C, params, jnp.asarray(tokens), rows, sel)
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+    # (every block that holds a key the query sees: the reference marks
+    # no other, the program's dense rule marks them all)
+    there = (np.arange(sel.shape[-1]) * CFG.block_size
+             <= np.arange(n)[:, None, None])
+    assert bool(jnp.all((own[:, :n] & there) == sel[:, :n]))
+    if n > CFG.dense_len:  # the rule chose: topk blocks, not all of them
+        assert int(sel[0, n - 1, 0].sum()) == CFG.topk
+        assert -(-n // CFG.block_size) > CFG.topk
+
+
+@pytest.mark.parametrize("start", [0, 1])
+def test_chunked_against_recurrent_from_a_state(start):
+    """``chunked`` (with padded positions that change nothing) and
+    ``decode_update`` against ``recurrent``, from a non-zero S0."""
+    L, H, d = 150, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v = (jax.random.normal(key, (L, H, d)) for key in ks[:3])
+    S0 = start * jax.random.normal(ks[3], (H, d, d))
+    g = jnp.broadcast_to(lightning.log_decays(H), (L, H))
+    want_o, want_S = lightning.recurrent(q, k, v, g, S0)
+    real = (jnp.arange(L + 42) < L)[:, None]
+    pad = lambda x: jnp.pad(x, ((0, 42),) + ((0, 0),) * (x.ndim - 1))  # noqa: E731
+    # (v of a padded position is never read: its key is zero)
+    _, S = lightning.chunked(
+        pad(q), jnp.where(real[..., None], pad(k), 0.0),
+        pad(v) + 5.0 * ~real[..., None], jnp.where(real, pad(g), 0.0), S0)
+    o, S2 = lightning.chunked(pad(q), jnp.where(real[..., None], pad(k), 0.0),
+                              pad(v), jnp.where(real, pad(g), 0.0), S0)
+    assert float(jnp.max(jnp.abs(o[:L] - want_o))) < 1e-4
+    assert float(jnp.max(jnp.abs(S2 - want_S))) < 1e-4
+    assert float(jnp.max(jnp.abs(S - want_S))) < 1e-4
+    # the decode form, two slots of which one is live, layer 1 of 2
+    state = jnp.zeros((2, 2, H, d, d)).at[1, 0].set(S0).at[1, 1].set(7.0)
+    for t in range(5):
+        two = lambda x: jnp.stack([x[t], x[t]])  # noqa: E731
+        o1, state = lightning.decode_update(
+            state, 1, two(q), two(k), two(v), two(g),
+            jnp.asarray([True, False]))
+        assert float(jnp.max(jnp.abs(o1[0] - want_o[t]))) < 1e-4
+        assert float(jnp.max(jnp.abs(o1[1]))) == 0.0
+    assert float(jnp.max(jnp.abs(state[1, 1] - 7.0))) == 0.0
+    assert float(jnp.max(jnp.abs(state[0]))) == 0.0
+
+
+@pytest.mark.parametrize("n", [24, 130, 200, 256, 257, 300, 391, 450, 512])
+def test_selection_against_the_reference(n):
+    """Forced blocks, ties (neighbouring blocks share a pooled row; here
+    keys repeat too), a last block half full, contexts under, at and past
+    ``dense_len``: the program's mask is the reference's choice."""
+    r = np.random.default_rng(n)
+    k = jnp.asarray(r.normal(size=(512, 2, 16)), jnp.float32)
+    k = k.at[64:128].set(k[0:64])  # equal rows, equal scores
+    q = jnp.asarray(r.normal(size=(4, 16)), jnp.float32)
+    rows = block_sparse.pool_keys(CFG, k)
+    assert float(jnp.max(jnp.abs(rows - ref.pooled_keys(C, k)))) < 1e-6
+    got = block_sparse.block_mask(CFG, q[None], rows, jnp.asarray([n]))[0]
+    want = ref.choose(C, q, ref.pooled_keys(C, k), n)
+    there = np.arange(16) * CFG.block_size < n
+    assert np.array_equal(np.asarray(got)[:, there], np.asarray(want)[:, there])
+    if n > CFG.dense_len:
+        assert int(want[0].sum()) == CFG.topk
+        last = (n - 1) // CFG.block_size
+        first = max(0, n - CFG.window_size) // CFG.block_size
+        assert bool(want[0, 0]) and bool(want[:, first:last + 1].all())
+
+
+def test_sparse_decode_kernel_against_masked_dense_attention():
+    """``paged_decode_attention`` with a list a KV head (interpret mode)
+    against dense attention over the listed pages' positions, the last
+    page as far as the list's length."""
+    r = np.random.default_rng(0)
+    B, H, G, d, pages, W = 3, 8, 2, 16, 40, 12
+    pool_k, pool_v = (jnp.asarray(r.normal(size=(2, pages, PS, G, d)),
+                                  jnp.float32) for _ in range(2))
+    q = jnp.asarray(r.normal(size=(B, H, d)), jnp.float32)
+    lists = r.integers(1, pages, (B, G, W)).astype(np.int32)
+    lengths = np.array([[5 * PS + 3, W * PS], [1, 2 * PS], [0, 0]], np.int32)
+    got = paged_decode_attention(q, pool_k, pool_v, jnp.asarray(lists),
+                                 jnp.asarray(lengths), 1, heads_apart=True,
+                                 pages_per_block=4)
+    for b in range(B):
+        for g in range(G):
+            n = int(lengths[b, g])
+            heads = slice(g * H // G, (g + 1) * H // G)
+            if n == 0:
+                assert float(jnp.abs(got[b, heads]).max()) == 0.0
+                continue
+            keys = pool_k[1, lists[b, g], :, g].reshape(-1, d)[:n]
+            vals = pool_v[1, lists[b, g], :, g].reshape(-1, d)[:n]
+            w = jax.nn.softmax(q[b, heads] @ keys.T * d ** -0.5, axis=-1)
+            assert float(jnp.abs(got[b, heads] - w @ vals).max()) < 1e-5
+
+
+@pytest.mark.parametrize("n", [200, 250, 400])
+def test_a_prompt_whole_and_in_chunks_then_steps(params, monkeypatch, n):
+    """The same prompt as ONE prefill and in chunks of 64 (the state and
+    the pooled rows handed from chunk to chunk, key blocks of 64), then 24
+    greedy steps, which for 250 cross ``dense_len``: the same tokens, each
+    the reference's best on its own history."""
+    monkeypatch.setattr(block_sparse, "KEY_BLOCK", 64)
+    for f in (lm.prefill, lm.prefill_with_prefix):
+        f.clear_cache()
+    prompt, sp = _prompt(n, n), SamplingParams(max_tokens=24)
+    whole = _engine(params, buckets=(64, 512))
+    chunks = _engine(params, buckets=(32, 64))
+    a, b = whole.generate(prompt, sp), chunks.generate(prompt, sp)
+    whole.stop(), chunks.stop()
+    assert chunks.stats()["prefill_chunks"] == -(-n // 64)
+    assert whole.stats()["prefill_chunks"] == 0
+    assert chunks.stats()["state_resets"] == 1 == whole.stats()["state_resets"]
+    assert a == b
+    assert max(_gaps(params, prompt, a)) < TOL
+    for f in (lm.prefill, lm.prefill_with_prefix):
+        f.clear_cache()
+
+
+def _rows_of(engine, pages, slot):
+    got = family.engine_rows(engine, pages)
+    got["S"] = family.engine_state(engine, slot)
+    return got
+
+
+def test_rows_after_chunks_steps_a_preemption_and_its_recompute(params):
+    """K/V pages, pooled-key rows and state rows the engine's programs left
+    against the reference's: after the chunks alone, after steps, and after
+    a preemption whose recompute goes through the chunks again."""
+    engine = _engine(params, buckets=(32, 64))
+    prompt = _prompt(230, 5)
+    req = engine.submit(prompt, SamplingParams(max_tokens=80))
+    engine._admit()
+    while engine._slots[0].prefill_at is not None:
+        engine._admit()
+    s = engine._slots[0]
+
+    def check(n):
+        want = ref.rows_of(C, params, jnp.asarray(np.pad(
+            prompt + s.generated, (0, 512))[:512], jnp.int32), states=n)
+        got = _rows_of(engine, s.pages, 0)
+        for name in ("k", "v"):
+            assert float(jnp.abs(got[name][:, :n] - want[name][:, :n]).max()) \
+                < TOL
+        done = (n - CFG.kernel_size) // PS + 1
+        assert float(jnp.abs(got["pooled"][:, :done]
+                             - want["pooled"][:, :done]).max()) < TOL
+        assert float(jnp.abs(got["S"] - want["S"]).max()) < TOL * 10
+
+    check(230)  # the chunks alone (the first token is not yet fed)
+    for _ in range(4):
+        engine._decode_all()
+    assert s.num_tokens == 230 + 32 > CFG.dense_len  # steps crossed it
+    check(s.num_tokens)
+    tokens_before = list(s.generated)
+    engine._preempt(0, s)
+    assert engine._slots[0] is None
+    engine._admit()
+    while engine._slots[0].prefill_at is not None:
+        engine._admit()
+    s = engine._slots[0]
+    assert s.request.preempts == 1 and engine.stats()["state_resets"] == 2
+    prompt = list(s.request.prompt_tokens)
+    assert prompt[230:] == tokens_before
+    check(len(prompt))
+    engine._decode_all()
+    check(s.num_tokens)
+    del req
+
+
+def test_two_slots_one_under_and_one_over_dense_len_in_one_step(params):
+    """Two sequences decode together, one under ``dense_len`` (its list is
+    its table) and one past it (its list is the selected blocks'): each
+    continues as it does alone, and as the reference has it."""
+    sp = SamplingParams(max_tokens=16)
+    short, long = _prompt(60, 1), _prompt(420, 2)
+    alone = []
+    for p in (short, long):
+        e = _engine(params)
+        alone.append(e.generate(p, sp))
+        e.stop()
+    both = _engine(params)
+    reqs = [both.submit(p, sp) for p in (short, long)]
+    both.start()
+    got = []
+    for r in reqs:
+        out = []
+        while (item := r.out_queue.get(timeout=300)) is not None:
+            out.extend(item if isinstance(item, (list, tuple)) else [item])
+        got.append(out)
+    both.stop()
+    assert got == alone
+    # what the steps counted on the device from their lists, against the
+    # rule's arithmetic: 16 steps a slot (two bursts of 8), contexts 61..76
+    # under ``dense_len`` and 421..436 past it, 2 sparse layers x 2 KV heads
+    stats = both.stats()
+    assert stats["state_slot_steps"] == 32
+    under, past = np.arange(61, 77), np.arange(421, 437)
+    ps, ppb = CFG.kernel_stride, CFG.block_size // CFG.kernel_stride
+    sums = 2 * CFG.n_kv_heads
+    assert stats["dense_rule_slot_steps"] == 2 * 16
+    assert stats["sparse_pages_resident"] == sums * int(
+        (-(-under // ps)).sum() + (-(-past // ps)).sum())
+    last = (past - 1) % CFG.block_size // ps + 1  # pages of the last block
+    assert stats["sparse_pages_read"] == sums * int(
+        (-(-under // ps)).sum() + ((CFG.topk - 1) * ppb + last).sum())
+    assert stats["sparse_blocks_selected"] == sums * int(
+        (-(-under // CFG.block_size)).sum() + CFG.topk * 16)
+    # rows of pooled keys: a prompt's whole pages less one, then a row a
+    # step that fills a page (64, 72; 424, 432), in both sparse layers
+    assert stats["index_rows_written"] == 2 * ((60 // ps - 1)
+                                               + (420 // ps - 1) + 4)
+    for p, o in zip((short, long), got):
+        assert max(_gaps(params, p, o)) < TOL
+
+
+@pytest.fixture(scope="module")
+def decoded(params):
+    """Two sequences an engine has prefilled in chunks and decoded for two
+    bursts, one under ``dense_len`` and one past it: (engine, [(pages,
+    held, the reference's rows of the same tokens with its queries,
+    attention outputs and choice at the last cached position)])."""
+    engine = _engine(params, buckets=(32, 64))
+    prompts = [_prompt(150, 5), _prompt(330, 6)]
+    for p in prompts:
+        engine.submit(p, SamplingParams(max_tokens=64))
+    while sum(s is not None and s.prefill_at is None
+              for s in engine._slots) < 2:
+        engine._admit()
+    for _ in range(2):
+        engine._decode_all()
+    out = []
+    for p, s in zip(prompts, engine._slots):
+        assert list(s.request.prompt_tokens) == p
+        held = s.num_tokens
+        want = ref.verify(C, params, p, s.generated, 1, 512,
+                          q_at=held - 1)[1]
+        out.append((list(s.pages), held, want))
+    assert out[0][1] <= CFG.dense_len < out[1][1]
+    yield engine, out
+    engine.stop()
+
+
+@pytest.mark.parametrize("fault", [None, "list_page_shifted",
+                                   "other_heads_columns"])
+def test_served_attention_is_the_decode_steps_own(decoded, monkeypatch,
+                                                  fault):
+    """(f) of the chip's ``correct``: the decode step's lists and paged
+    kernel over the ENGINE's pools under the reference's choice give the
+    reference's attention output, under ``dense_len`` and past it; a list
+    that begins a page late and the other KV head's columns (the chip's
+    controls) move it a thousand times further.  The step's own lists hold
+    the pages of the reference's choice."""
+    from ray_tpu.ops import paged_attention
+
+    for mod, name in ((block_sparse, "lists_from"),
+                      (paged_attention, "paged_decode_attention"),
+                      (lm, "paged_decode_attention")):
+        monkeypatch.setattr(mod, name, getattr(mod, name))
+    if fault:
+        getattr(family, f"plant_{fault}")()
+    engine, seqs = decoded
+    for pages, held, want in seqs:
+        sel = np.asarray(want["selection"][:, held - 1])
+        got = family.served_attention(engine, pages, want["q"], held, sel)
+        err = float(jnp.max(jnp.abs(got - want["o"])))
+        assert err < TOL if fault is None else err > 0.05, (fault, held)
+        if held <= CFG.dense_len or fault == "other_heads_columns":
+            continue
+        for li in range(sel.shape[0]):
+            own = family.engine_selection(engine, pages, want["q"][li],
+                                          held, li)
+            theirs = family.selected_pages(engine, pages, sel[li], held)
+            assert (own == theirs) == (fault is None)
+
+
+@pytest.mark.parametrize("fault", ["state_bf16", "topk_short"])
+def test_the_planted_faults_show(params, monkeypatch, fault):
+    """The faults the chip's controls plant move what ``correct`` compares
+    far past what the clean path reads."""
+    if fault == "state_bf16":
+        H, d, T = 4, 16, 300
+        q, k, v = (jax.random.normal(key, (T, H, d))
+                   for key in jax.random.split(jax.random.PRNGKey(0), 3))
+        want = ref.state_at(C, k, v, T)
+
+        def rel():
+            S = family.recurrence_outputs(C, q, k, v, 260, 64)[1]
+            return float(jnp.sqrt(jnp.sum((S - want) ** 2)
+                                  / jnp.sum(want ** 2)))
+
+        clean = rel()
+        monkeypatch.setattr(lightning, "chunked", lightning.chunked)
+        monkeypatch.setattr(lightning, "decode_update",
+                            lightning.decode_update)
+        family.plant_state_bf16()
+        assert clean < 1e-5 and rel() > 1e-3
+        return
+    n = 400
+    tokens = np.zeros(512, np.int32)
+    tokens[:n] = _prompt(n, 9)
+    rows = jnp.asarray([n - 1], jnp.int32)
+    _, sel = ref.logits_and_selection(C, params, jnp.asarray(tokens), rows)
+    short = config_file(ms.MiniCPMSALAConfig.tiny(topk=5))
+    _, own = family.pinned_logits(short, params, jnp.asarray(tokens), rows,
+                                  sel)
+    past = slice(CFG.dense_len, n)
+    share = float((sel[:, past] & own[:, past]).sum() / sel[:, past].sum())
+    assert share <= 5 / 6 + 1e-6

@@ -25,8 +25,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, moe, olmo_hybrid,
-                            sdar_moe)
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala, moe,
+                            olmo_hybrid, sdar_moe)
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -35,10 +35,12 @@ PARTS = frozenset(llama.PARTS)
 FLASH = ("flash_attention_fwd", "flash_attention_bwd_dkv",
          "flash_attention_bwd_dq")
 KERNELS = {**dict.fromkeys(FLASH, "attn/attend"),
-           "paged_decode_attention": "attn/attend",
+           # (over a list of pages a KV head in MiniCPM-SALA's sparse layers)
+           "paged_decode_attention": ("attn/attend", "sparse_attn/attend"),
            "paged_latent_decode_attention": "mla/attend",
            "moe_grouped_mlp": "moe/experts",
-           "gated_delta_update": "lin_attn/state"}
+           "gated_delta_update": "lin_attn/state",
+           "lightning_update": "lightning/state"}
 BLOCK = ("embed", "layers", "attn/norm", "attn/qkv", "attn/rope",
          "attn/attend", "attn/out", "mlp/norm")
 DENSE = BLOCK + ("mlp/gate_up", "mlp/down")
@@ -59,6 +61,15 @@ LATENT_PREFILL = LATENT + ("mla/kv_up", "attn/attend")  # a head a head:
 WINDOWED = (DENSE + ROUTED[-4:] + (
     "moe/shared", "attn/qk_norm", "attn/gate", "norm/post", "attn/kv_write",
     "head"))
+# block-sparse and fixed-decay linear layers in one stack: no ``attn/qkv``,
+# ``attn/attend`` or ``attn/out`` (the sparse layers' products, choice of
+# blocks and attention and the linear layers' products, recurrence and
+# output have parts of their own)
+SPARSE_LINEAR = ("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
+                 "mlp/norm", "mlp/gate_up", "mlp/down", "head",
+                 "sparse_attn/proj", "sparse_attn/index",
+                 "sparse_attn/attend", "lightning/proj", "lightning/state",
+                 "lightning/out")
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -182,6 +193,22 @@ def _windowed(program):
     return fn, (params, tokens, ck, cv, *rest), cfg
 
 
+def _sparse_linear(program):
+    """``program`` of the dense tree over MiniCPM-SALA's caches: pools over
+    the sparse layers, the rows of pooled keys beside them and the linear
+    layers' state rows (pages of 8: a pooled row a page)."""
+    cfg = minicpm_sala.MiniCPMSALAConfig.tiny()
+    params = cfg.serving_layout(minicpm_sala.init(cfg, jax.random.PRNGKey(0)))
+    cc = CacheConfig(**lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+                     dtype="float32", max_slots=4)
+    ck, cv = init_cache(cc)
+    fn, (_, tokens, _, _, *rest), _ = program()
+    rows = {"state": init_state(cc)}
+    if program is not _decode:
+        rows["slot"] = jnp.int32(1)
+    return fn, (params, tokens, ck, cv, *rest), cfg, rows
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -243,7 +270,12 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "windowed_prefill": lambda: _windowed(_prefill),
           "windowed_prefill_with_prefix":
               lambda: _windowed(_prefill_with_prefix),
-          "windowed_decode_step_greedy": lambda: _windowed(_decode)}
+          "windowed_decode_step_greedy": lambda: _windowed(_decode),
+          "sparse_linear_prefill": lambda: _sparse_linear(_prefill),
+          "sparse_linear_prefill_with_prefix":
+              lambda: _sparse_linear(_prefill_with_prefix),
+          "sparse_linear_decode_step_greedy":
+              lambda: _sparse_linear(_decode)}
 TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
     "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
@@ -266,6 +298,9 @@ EXPECTED = {
     "windowed_prefill": WINDOWED + ("attn/attend/repeat_kv",),
     "windowed_prefill_with_prefix": WINDOWED + ("attn/attend/repeat_kv",),
     "windowed_decode_step_greedy": WINDOWED + ("sample",),
+    "sparse_linear_prefill": SPARSE_LINEAR,
+    "sparse_linear_prefill_with_prefix": SPARSE_LINEAR,
+    "sparse_linear_decode_step_greedy": SPARSE_LINEAR + ("sample",),
     # the flash kernels read K and V at their own heads (PR 47): a program
     # that attends through them repeats nothing; the plain XLA path does
     "llama_grad_remat": DENSE + ("head", "loss"),
@@ -310,7 +345,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
         for kernel, part in KERNELS.items():
             if kernel in re.split(r"[/()]", n):
                 seen.add(kernel)
-                assert split_path(n, PARTS)[0] == part, n
+                assert split_path(n, PARTS)[0] in (
+                    part if isinstance(part, tuple) else (part,)), n
     wanted = {"decode_step_greedy": {"paged_decode_attention"},
               "block_step": {"paged_decode_attention", "moe_grouped_mlp"},
               "hybrid_decode_step_greedy": {"paged_decode_attention",
@@ -320,6 +356,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
                                             "moe_grouped_mlp"},
               "windowed_decode_step_greedy": {"paged_decode_attention",
                                               "moe_grouped_mlp"},
+              "sparse_linear_decode_step_greedy": {"paged_decode_attention",
+                                                   "lightning_update"},
               "llama_grad": set(FLASH),
               **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
     assert wanted <= seen

@@ -24,7 +24,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models import afmoe, glm_moe_lite, llama, olmo_hybrid, sdar_moe
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala,
+                            olmo_hybrid, sdar_moe)
 from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
@@ -548,6 +549,91 @@ def test_windowed_programs_compile_at_trinity_widths(topo, as_tpu, program):
     print("PLANNED", program, planned / 1e9, m.temp_size_in_bytes / 1e9)
     assert 0.60 * V5E_BYTES_LIMIT <= planned <= 0.85 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - WINDOWED_PLANNED_GB[program]) < 0.05
+
+
+# planned bytes a program of configuration ``minicpm_sala_serve_1chip``,
+# compiled for the described v5e here (PERF.md section 4): weights 5.64 GB
+# (serving layout), the sparse layers' pools 1.68 GB, the rows of pooled
+# keys 0.05 GB, the state rows 0.40 GB
+SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.779, 2048: 8.060,
+                            "prefix_256": 7.798, "prefix_2048": 7.990}
+
+
+@pytest.mark.parametrize("program", ["decode_step_greedy", 2048,
+                                     "prefix_256", "prefix_2048"])
+def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
+                                                               program):
+    """``decode_step_greedy`` (32 slots, 1,600-page tables), the one
+    ``prefill`` bucket a chunked prompt runs and two of
+    ``prefill_with_prefix`` (25,600-token tables) of MiniCPM-SALA at
+    published widths and layers 9-16 (sparse, 6 linear, sparse), over the
+    cell's 51,201 pages, their rows of pooled keys and 32 slots' state
+    rows: each plans at or under 0.85 of the chip's bytes_limit; pools,
+    pooled rows and state rows are aliased to the outputs and held once;
+    the decode step attends through the paged kernel over a LIST a KV head
+    (64 kernel slots) and updates the state through ``lightning_update``;
+    no prefill holds a [chunk, context] score matrix (32 x 2,048 x 25,600
+    float32 would be 6.7 GB)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = minicpm_sala.MiniCPMSALAConfig(
+        n_layers=8, mixer_types=minicpm_sala.PUBLISHED_MIXERS[9:17])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shapes = jax.eval_shape(
+        lambda k: minicpm_sala.init(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    params = _on(one, jax.eval_shape(cfg.serving_layout, shapes))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert abs(weights / 1e9 - 5.641) < 0.001
+    layout = lm.cache_layout(cfg)
+    cache = sds((layout["n_layers"], 51201, 16, layout["n_kv_heads"],
+                 layout["head_dim"]), jnp.bfloat16)
+    assert cache.shape == (2, 51201, 16, 2, 128)
+    state = {**{name: sds((rows, 32, *shape), dt) for name, (rows, shape, dt)
+                in layout["state_rows"].items()},
+             **{name: sds((rows, 51201, *shape), dt) for name,
+                (rows, shape, dt) in layout["page_rows"].items()}}
+    assert state["S"].shape == (6, 32, 32, 128, 128)
+    assert state["pooled_k"].shape == (2, 51201, 2, 128)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step_greedy":
+        compiled = lm.decode_step_greedy.lower(
+            params, i32(32), cache, cache, i32(32, 1600), i32(32),
+            sds((32,), jnp.bool_), cfg, state).compile()
+        text = compiled.as_text()
+        assert "lightning_update" in text
+        assert "paged_decode_attention" in text
+    elif isinstance(program, int):
+        compiled = lm.prefill.lower(
+            params, i32(program), cache, cache, i32(program), i32(),
+            i32(program), cfg, state, i32()).compile()
+    else:
+        L = int(program.split("_")[1])
+        compiled = lm.prefill_with_prefix.lower(
+            params, i32(L), cache, cache, i32(L), i32(), i32(L), i32(1600),
+            i32(L), cfg, state, i32()).compile()
+    held = (2 * 2 * 51201 * 16 * 2 * 128 * 2 + 6 * 32 * 32 * 128 * 128 * 4
+            + 2 * 51201 * 2 * 128 * 2)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= held  # pools and rows held once
+    planned = _footprint(compiled)
+    print("PLANNED", program, planned / 1e9, m.temp_size_in_bytes / 1e9)
+    assert planned <= 0.85 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - SPARSE_LINEAR_PLANNED_GB[program]) < 0.05
+
+
+def test_sparse_kernel_compiles_for_the_checks_one_sequence(topo, as_tpu):
+    """(f) of the cell's ``correct`` (families/minicpm_sala.py
+    ``served_attention``) hands the paged kernel ONE sequence: two kernel
+    slots, a list of 512 pages a KV head, over the cell's pools."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    pool = sds((2, 51201, 16, 2, 128), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, lists, held: lm.paged_decode_attention(
+        q, k, v, lists, held, 1, heads_apart=True)).lower(
+            sds((1, 32, 128), jnp.bfloat16), pool, pool,
+            sds((1, 2, 512), jnp.int32),
+            sds((1, 2), jnp.int32)).compile().as_text()
+    assert "paged_decode_attention" in text
 
 
 def test_latent_kernel_refuses_pages_that_are_no_whole_tiles(as_tpu):
