@@ -9,8 +9,10 @@ fixture tree instead of the real repo is how the checker tests itself.
 
 from __future__ import annotations
 
+import ast
 import bisect
 import fnmatch
+import functools
 import os
 from dataclasses import dataclass, field
 
@@ -104,6 +106,19 @@ def walk_sources(root: str, exts: tuple[str, ...],
                 rel = os.path.relpath(path, root).replace(os.sep, "/")
                 with open(path, errors="replace") as fh:
                     yield rel, fh.read()
+
+
+@functools.lru_cache(maxsize=None)
+def parse(src: str) -> ast.Module:
+    """``ast.parse``, once a source text however many scans read it (a
+    scan only reads its tree; a SyntaxError is raised to each)."""
+    return ast.parse(src)
+
+
+@functools.lru_cache(maxsize=None)
+def nodes(tree: ast.Module) -> tuple[ast.AST, ...]:
+    """``ast.walk`` of a whole module from ``parse``, walked once."""
+    return tuple(ast.walk(tree))
 
 
 def read_source(root: str, rel: str) -> str | None:

@@ -35,6 +35,8 @@ import re
 
 from ray_tpu._private.staticcheck.common import (
     Violation,
+    nodes,
+    parse,
     read_source,
     walk_sources,
 )
@@ -73,10 +75,10 @@ def _scan_registrations(root: str, violations: list[Violation]):
         if rel.endswith("util/metrics.py") or "/staticcheck/" in rel:
             continue  # the class definitions / this checker itself
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError:
             continue
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if not isinstance(node, ast.Call):
                 continue
             kind = _ctor_kind(node.func)
@@ -127,10 +129,10 @@ def _scan_synthesized(root: str) -> set[str]:
         if "/staticcheck/" in rel:
             continue
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError:
             continue
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if not isinstance(node, ast.Dict):
                 continue
             keys = {k.value for k in node.keys
@@ -162,10 +164,10 @@ def _scan_slo_rules(root: str, registered: set[str],
         if "/staticcheck/" in rel:
             continue
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError:
             continue
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if not (isinstance(node, ast.Constant)
                     and isinstance(node.value, str)):
                 continue
@@ -222,10 +224,10 @@ def _scan_exemplars(root: str, sites: dict, violations: list[Violation]):
         if not rel.endswith("util/metrics.py"):
             continue
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError:
             continue
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if not isinstance(node, ast.Assign):
                 continue
             targets = [t.id for t in node.targets
@@ -263,7 +265,7 @@ def _scan_renderer(root: str, violations: list[Violation]):
     rendered_any = False
     for rel, src in walk_sources(root, (".py",), subdir="ray_tpu/dashboard"):
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError:
             continue
         has_renderer = "_render_prometheus" in src
@@ -279,7 +281,7 @@ def _scan_renderer(root: str, violations: list[Violation]):
                     "metrics/renderer-prefix-missing", rel, 1,
                     "_render_prometheus does not apply the ray_tpu_ prefix "
                     "to pushed families"))
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if isinstance(node, ast.Call) and isinstance(node.func, ast.Name) \
                     and node.func.id == "fam" and node.args:
                 prefix = _fstring_prefix(node.args[0])

@@ -54,6 +54,8 @@ import re
 from ray_tpu._private.staticcheck.common import (
     LineIndex,
     Violation,
+    nodes,
+    parse,
     strip_cc_noise,
     walk_sources,
 )
@@ -188,7 +190,7 @@ def _emits_chaos_event(tree: ast.AST) -> bool:
     """Does this module call ``emit("chaos...")`` /
     ``events.emit("chaos...")`` anywhere?  That call is what puts an
     injection on the cluster event plane (events_push → head bank)."""
-    for node in ast.walk(tree):
+    for node in nodes(tree):
         if not isinstance(node, ast.Call) or not node.args:
             continue
         f = node.func
@@ -246,14 +248,14 @@ def check(root: str) -> list[Violation]:
                 or rel == _FLAGS_REL:
             continue
         try:
-            tree = ast.parse(src)
+            tree = parse(src)
         except SyntaxError as e:
             violations.append(Violation(
                 "proto/parse-error", rel, e.lineno or 1, str(e)))
             continue
         scanned_py = True
         py_refs.visit(tree)
-        for node in ast.walk(tree):
+        for node in nodes(tree):
             if isinstance(node, ast.If):
                 flags_in_test = {c.value for c in _const_strings(node.test)}
                 if flags_in_test and _lane_off_shape(node):

@@ -35,6 +35,7 @@ kernel is handed, and nobody re-derives the rule to count by.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +100,33 @@ def block_scores(sp, q, rows, n):
     return jnp.maximum(own, before)
 
 
+def _ordered(score):
+    """float32 -> uint32 in the same order (-0.0 beside 0.0)."""
+    u = jax.lax.bitcast_convert_type(jnp.where(score == 0, 0.0, score),
+                                     jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+@partial(jax.jit, static_argnames="k")
+def _kth_largest(u, k: int):
+    """u [..., M] uint32 -> [...]: the largest t with at least k of u at or
+    over it (0 where M < k), two bits a round from the top: a counting pass
+    over M for each of a round's three candidates, which fewer of u reach
+    the higher they are, so the candidates that k reach are the round's
+    digit.  (A prefill's masks, a layer a chunk of 2,048 over 400 blocks:
+    every block ranked against every other 1.87 ms; one bit a round in a
+    ``fori_loop`` 2.83, unrolled 0.88; two bits 0.76; four 0.81: my chip
+    run, PR 50.)  A program of its own only for a caller outside any (a
+    test, the benchmark's check): one dispatch, not sixteen rounds of
+    operations; inside a program it is inlined."""
+    t = jnp.zeros(u.shape[:-1], jnp.uint32)
+    for shift in range(30, -1, -2):
+        higher = t[..., None] | (jnp.arange(1, 4, dtype=jnp.uint32) << shift)
+        reached = (u[..., None, :] >= higher[..., None]).sum(axis=-1) >= k
+        t = t | (reached.sum(axis=-1).astype(jnp.uint32) << shift)
+    return t
+
+
 def chosen(sp, b, n):
     """b [Q, G, M] block scores, n [Q] contexts -> [Q, G, M] bool: the
     blocks the rule selects (forced ones, then the highest scores, the
@@ -106,13 +134,18 @@ def chosen(sp, b, n):
     the choice is not made here (every block is selected): the callers
     branch on n.
 
-    The choice is made by RANKING every block against every other (M^2
-    comparisons, which the vector unit does in passing: 0.16 M a slot a KV
-    head at 400 blocks), not by ``lax.top_k``, which the TPU lowers to a
-    full sort: two sorts a sparse layer read 24 % of a decode step at 32
-    slots (PERF.md section 6, PR 49).  Past a few thousand blocks (a
-    context of 100k and more, which no cell reaches) the sort is the
-    cheaper form again."""
+    The choice is made by a THRESHOLD: the ``topk``-th largest score is
+    found by counting (``_kth_largest``: 48 passes over M), every block
+    over it is selected, and of the blocks AT it the lowest indices until
+    ``topk`` in all (a running count).  The work grows with M, whatever M
+    is.  What was measured (PERF.md section 6, PRs 49 and 50), part
+    ``sparse_attn/index`` of two sparse layers, 400 blocks: by
+    ``lax.top_k``, which the TPU lowers to a full sort, two sorts a layer,
+    2.65 ms a decode step at 32 slots; every block ranked against every
+    other, M^2 comparisons, 2.19 ms a step and 3.51 ms a chunk of 2,048;
+    this form 2.15 ms a step and 1.43 ms a chunk.  A decode step's part is
+    two gathers (a slot's pooled rows through its table, a list's page
+    ids), not the choice: 25 us of a layer's 951 were the ranking."""
     M = b.shape[-1]
     m = jnp.arange(M)
     n = n[:, None, None]
@@ -120,9 +153,12 @@ def chosen(sp, b, n):
                                      > n - sp.window_size)
     score = jnp.where(forced, _FORCED, b)
     score = jnp.where(m * sp.block_size < n, score, _OUT)
-    other, own = score[..., None, :], score[..., :, None]
-    ahead = (other > own) | ((other == own) & (m[None, :] < m[:, None]))
-    return (ahead.sum(axis=-1) < sp.topk) & (score > _OUT / 2)
+    u = _ordered(score)
+    least = _kth_largest(u, sp.topk)[..., None]
+    over, at = u > least, u == least
+    room = sp.topk - over.sum(axis=-1, keepdims=True)
+    return ((over | (at & (jnp.cumsum(at, axis=-1) <= room)))
+            & (score > _OUT / 2))
 
 
 def block_mask(sp, q, rows, n):

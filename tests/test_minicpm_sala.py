@@ -10,6 +10,8 @@ the reference whole, at ``highest``): logits of O(1) agree to a few 1e-5;
 1e-3 leaves room and a wrong mask, block or state moves them by 1e-1.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,6 +166,67 @@ def test_selection_against_the_reference(n):
         last = (n - 1) // CFG.block_size
         first = max(0, n - CFG.window_size) // CFG.block_size
         assert bool(want[0, 0]) and bool(want[:, first:last + 1].all())
+
+
+# the published sizes (400 blocks a 25,600-token table), and the same with a
+# window that forces every block: more than ``topk`` equals at the top
+WIDE = ms.MiniCPMSALAConfig()
+ALL_FORCED = dataclasses.replace(WIDE, window_size=400 * 64)
+SCORES = {  # 400 block scores in [-1, 16] -> the case's
+    "distinct": lambda b: b,
+    "two_places": lambda b: np.round(b / 8, 2),
+    "neighbours_equal": lambda b: np.repeat(np.round(b[..., ::2], 1), 2, -1),
+    "half_incomplete": lambda b: np.where(b < 8, -1.0, np.round(b, 1)),
+    "all_equal": lambda b: np.full_like(b, 0.25),
+    "signed_zeros": lambda b: np.where(b < 12, np.where(b < 6, -0.0, 0.0), b),
+}
+# a context inside the window (every block it holds forced), one shorter
+# than ``topk`` blocks, one under / at / past ``dense_len``, a block half
+# full, the whole table
+CONTEXTS = [1000, 2600, 8191, 8192, 8193, 12345, 25600]
+
+
+def _ranked(sp, b, n):
+    """The rule by RANKING every block against every other (the form
+    ``chosen`` had until PR 50), in numpy: the oracle."""
+    M = b.shape[-1]
+    m = np.arange(M)
+    n = np.asarray(n)[:, None, None]
+    forced = (m < sp.init_blocks) | ((m + 1) * sp.block_size
+                                     > n - sp.window_size)
+    score = np.where(forced, np.float32(block_sparse._FORCED), b)
+    score = np.where(m * sp.block_size < n, score,
+                     np.float32(block_sparse._OUT))
+    other, own = score[..., None, :], score[..., :, None]
+    ahead = (other > own) | ((other == own) & (m[None, :] < m[:, None]))
+    return (ahead.sum(axis=-1) < sp.topk) & (score > block_sparse._OUT / 2)
+
+
+@pytest.mark.parametrize("n", CONTEXTS + ["every_block_forced"])
+@pytest.mark.parametrize("scores", list(SCORES))
+def test_the_threshold_chooses_what_the_ranking_chose(scores, n):
+    """``chosen`` finds the ``topk``-th score and admits equals lowest
+    index first; at 400 blocks and ``topk`` 64 its mask is the pairwise
+    ranking's, tie for tie, and ``select`` lists those blocks ascending
+    with their count."""
+    sp, n = (ALL_FORCED, 25600) if n == "every_block_forced" else (WIDE, n)
+    r = np.random.default_rng(len(scores) * 100003 + n)
+    b = SCORES[scores](
+        r.uniform(-1, 16, (3, 2, 400))).astype(np.float32)
+    ns = np.asarray([n, n, max(1, n - 64)])
+    want = _ranked(sp, b, ns)
+    got = np.asarray(block_sparse.chosen(sp, jnp.asarray(b), jnp.asarray(ns)))
+    assert np.array_equal(got, want)
+    held = -(-ns // sp.block_size)
+    assert np.array_equal(want.sum(axis=-1), np.minimum(held, sp.topk)[
+        :, None].repeat(2, axis=1))
+    blocks, count = (np.asarray(x) for x in block_sparse.select(
+        sp, jnp.asarray(b), jnp.asarray(ns)))
+    assert np.array_equal(count, want.sum(axis=-1))
+    for q, g in np.ndindex(*count.shape):
+        assert np.array_equal(blocks[q, g, :count[q, g]],
+                              np.flatnonzero(want[q, g]))
+        assert (blocks[q, g, count[q, g]:] == 400).all()
 
 
 def test_sparse_decode_kernel_against_masked_dense_attention():
