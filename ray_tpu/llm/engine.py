@@ -321,6 +321,36 @@ _PHASE_ATTRS = {
 # ``llm.loop_stall`` event: a burst's decode_fetch is 0.9 s at most.
 STALL_S = 2.0
 
+# What a phase's time is TO A REQUEST that stands behind it (ISSUE 51): a
+# sampled loop keeps one running sum a class, a request takes a reading at
+# each change of its state, and the differences go on its ``llm.queue``,
+# ``llm.admission`` and ``llm.decode`` spans (``tracing.WAIT_ATTRS``).
+# ``step``: a burst on the device and its launch; ``prefill``: some
+# prompt's admission (the request's own apart, ``_Request.own_s``);
+# ``host``: the loop's own work between programs, the banking of its spans
+# included; ``idle``: nothing to do.
+C_STEP, C_PREFILL, C_HOST, C_IDLE = range(4)
+_PHASE_CLASS = {
+    P_DECODE_DISPATCH: C_STEP, P_DECODE_FETCH: C_STEP,
+    P_PREFILL_HOST: C_PREFILL, P_PREFILL_DISPATCH: C_PREFILL,
+    P_PREFILL_FETCH: C_PREFILL, P_PREFILL_EMIT: C_PREFILL,
+    # (an admit that HAS admitted is its prompt's: ``_ADMITTED``)
+    P_ADMIT: C_HOST, P_HYDRATE: C_HOST, P_GAUGES: C_HOST,
+    P_DECODE_HOST: C_HOST, P_DECODE_EMIT: C_HOST, P_IDLE: C_IDLE,
+}
+_ADMITTED = ("admitted",)  # an admit's ``vals`` once it has a request in
+
+
+def _waited(a: Optional[tuple], b: Optional[tuple]) -> dict:
+    """What the loop did between two readings of ``_LoopPhases.reading``,
+    under ``tracing.WAIT_ATTRS``, seconds to 1 us: the five sum to the time
+    between the readings.  {} where the loop is not sampled."""
+    if a is None or b is None:
+        return {}
+    step, prefill, host, idle, own = (y - x for x, y in zip(a[1:], b[1:]))
+    return {k: round(max(0.0, v), 6) for k, v in zip(
+        tracing.WAIT_ATTRS, (step, own, prefill - own, host, idle))}
+
 _mono = time.monotonic  # the loop's one clock (a test stretches it)
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -350,11 +380,16 @@ class _LoopPhases:
     When the loop is NOT sampled nothing else happens: no record, no dict,
     no lock, no other clock.  When it is, a working iteration's phases are
     banked through ``tracing.record_span`` under one ``llm.loop`` span, in
-    traces of the loop's own (``tracing.LoopTrace``)."""
+    traces of the loop's own (``tracing.LoopTrace``), and every stretch of
+    the clock, a phase or what lies between two, is added to the sum of
+    its class (``_PHASE_CLASS``; between two phases ``host``, after an
+    iteration that found nothing ``idle``): ``_mark`` is (when the open
+    stretch began, its class, the four sums up to then), replaced whole
+    at every turn, so ``reading`` needs no lock."""
 
     __slots__ = ("sampled", "it", "name", "t0", "req", "vals", "_ann",
                  "_done", "_trace", "_idle_t0", "_idle_it", "_idle_n",
-                 "_slots")
+                 "_slots", "_mark")
 
     def __init__(self, slots: list):
         self.sampled = False
@@ -369,6 +404,7 @@ class _LoopPhases:
         self._idle_t0: Optional[float] = None
         self._idle_it = self._idle_n = 0
         self._slots = slots  # the engine's slot table, for the stall event
+        self._mark = (_mono(), C_IDLE, (0.0, 0.0, 0.0, 0.0))
 
     def begin(self, name: str, req: Optional[_Request] = None,
               vals: tuple = ()) -> None:
@@ -377,14 +413,53 @@ class _LoopPhases:
         t = _mono()
         if self.name is not None:
             self._close(t)
+        if self.sampled:
+            self._turn(t, _PHASE_CLASS[name])
         self.name, self.t0, self.req, self.vals = name, t, req, vals
         self._ann = jax.profiler.TraceAnnotation(name, it=self.it)
         self._ann.__enter__()
 
     def end(self) -> None:
         if self.name is not None:
-            self._close(_mono())
+            t = _mono()
+            self._close(t)
+            if self.sampled:
+                self._turn(t, C_HOST)
             self.name = self.req = None
+            self.vals = ()
+
+    def _upto(self, t: float) -> tuple:
+        """(the four sums with the open stretch counted up to ``t``, its
+        class, its length so far)."""
+        t0, cls, sums = self._mark  # ONE load: a reader on another thread
+        if cls == C_HOST and self.vals == _ADMITTED:
+            cls = C_PREFILL
+        dt = t - t0
+        return sums[:cls] + (sums[cls] + dt,) + sums[cls + 1:], cls, dt
+
+    def _turn(self, t: float, nxt: int) -> None:
+        """Close the open stretch at ``t`` into its class's sum, and into
+        its request's own where it was that request's prefill; a stretch
+        of class ``nxt`` opens."""
+        sums, cls, dt = self._upto(t)
+        if cls == C_PREFILL and self.req is not None:
+            self.req.own_s += dt
+        self._mark = (t, nxt, sums)
+
+    def reading(self, req: _Request) -> Optional[tuple]:
+        """(wall clock now, step, prefill, host, idle, ``req``'s own
+        prefill): the sums as they stand, the open stretch counted in its
+        class, so that two readings differ by exactly the time between
+        them.  None where the loop is not sampled.  The submitting thread
+        calls this without a lock: its reading is whole (one load of
+        ``_mark``), and stale by at most the stretch that is open, which
+        it may count in the class of the one before (an admit that goes
+        on to admit, or a turn the loop made during the call)."""
+        if not self.sampled:
+            return None
+        sums, cls, dt = self._upto(_mono())
+        own = req.own_s + (dt if cls == C_PREFILL and self.req is req else 0)
+        return (time.time(),) + sums + (own,)
 
     def _close(self, t: float) -> None:
         self._ann.__exit__(None, None, None)
@@ -399,6 +474,8 @@ class _LoopPhases:
         self.end()
         if not self.sampled:
             return
+        if not worked:  # what follows, the sleep too, is idle time
+            self._mark = (self._mark[0], C_IDLE, self._mark[2])
         now = _mono()
         start = min((r[1] for r in self._done), default=now)
         if not worked:
@@ -481,6 +558,18 @@ class _Request:
     span_id: Optional[str] = None
     submitted_wall: float = field(default_factory=time.time)
     preempts: int = 0
+    # What the loop was doing while this request stood (ISSUE 51; a traced
+    # request of a sampled loop only): the loop's time under the prefill
+    # phases that carried THIS request, and its readings of the loop's
+    # sums (``_LoopPhases.reading``) when it joined the queue (submit, or
+    # a preemption), at the first program of its admission (None once
+    # that admission's span is banked) and at its first token; ``chunks``
+    # counts the programs of the admission that is open.
+    own_s: float = 0.0
+    at_queued: Optional[tuple] = None
+    at_program: Optional[tuple] = None
+    at_token: Optional[tuple] = None
+    chunks: int = 0
 
 
 @dataclass
@@ -920,6 +1009,7 @@ class LLMEngine:
         if ctx is not None:
             req.trace_ctx = ctx
             req.span_id = tracing.new_span_id()
+            req.at_queued = self._ph.reading(req)
 
     def _span(self, req: _Request, name: str, t0: float, t1: float,
               ok: bool = True, **attrs) -> None:
@@ -930,6 +1020,40 @@ class LLMEngine:
             req.trace_ctx[0], name, t0, t1, parent_id=req.span_id,
             kind="engine", ok=ok,
             attrs=dict(attrs, request_id=req.request_id))
+
+    def _queue_ends(self, req: _Request, t: float) -> float:
+        """A request's wait for a slot and pages ends at ``t`` (the loop's
+        clock), where the first program of its admission begins (or its
+        shipped pages go in): the wait since its submission, and for a
+        traced request its ``llm.queue`` span, with what the loop did
+        meanwhile.  A resumed request's second wait began at its
+        preemption and has a span of its own."""
+        qw = max(0.0, t - req.submitted_at)
+        if req.trace_ctx is not None:
+            now = self._ph.reading(req)
+            w0, wait = req.submitted_wall, qw
+            if req.preempts and req.at_queued is not None and now is not None:
+                w0 = req.at_queued[0]
+                wait = max(0.0, now[0] - w0)
+            self._span(req, "llm.queue", w0, w0 + wait,
+                       wait_s=round(wait, 6), resumed=bool(req.preempts),
+                       **_waited(req.at_queued, now))
+            req.at_program, req.chunks = now, 0
+        return qw
+
+    def _admission_ends(self, req: _Request, ok: bool = True) -> tuple:
+        """The admission of a traced request ends: at its first token
+        counted (or at a preemption, ``ok`` False).  ONE ``llm.admission``
+        span however many programs computed the prompt: what the loop did
+        between them is on it (``step_s``: the bursts run between this
+        prompt's own chunks).  Returns the reading it ended at."""
+        now = self._ph.reading(req)
+        self._span(req, "llm.admission", req.at_program[0], now[0], ok=ok,
+                   chunks=req.chunks, tokens=len(req.prompt_tokens),
+                   resumed=bool(req.preempts),
+                   **_waited(req.at_program, now))
+        req.at_program = None
+        return now
 
     def _close_request_span(self, req: _Request, ok: bool = True,
                             **attrs) -> None:
@@ -962,12 +1086,14 @@ class LLMEngine:
             w_now = time.time()
             if req.first_token_at is not None:
                 # decode aggregate: first token -> stream end (per-step
-                # spans would be noise; contention shows up as the gap
-                # between this span's rate and the prefill-adjacent TPOT)
+                # spans would be noise).  What the loop did meanwhile is
+                # on it: ``other_prefill_s / (tokens - 1)`` is what other
+                # requests' admissions cost this one a token
                 self._span(req, "llm.decode",
                            w_now - max(0.0, now - req.first_token_at),
                            w_now, tokens=req.emitted,
-                           preempts=req.preempts)
+                           preempts=req.preempts,
+                           **_waited(req.at_token, self._ph.reading(req)))
             self._close_request_span(req, ok=True, tokens=req.emitted)
 
     def _pick_waiting(self) -> Optional[_Request]:
@@ -1157,10 +1283,7 @@ class LLMEngine:
                         jnp.asarray(kv_v, self.cache_v.dtype))
                     last = int(req.first_token)
                     # no prefill here, so stamp the admission wait itself
-                    qw = max(0.0, time.monotonic() - req.submitted_at)
-                    self._span(req, "llm.queue", req.submitted_wall,
-                               req.submitted_wall + qw,
-                               wait_s=round(qw, 6))
+                    self._queue_ends(req, time.monotonic())
                 else:
                     if cow_src is not None:
                         # COW boundary page: duplicate the diverging
@@ -1332,6 +1455,9 @@ class LLMEngine:
         ph = self._ph
         ph.begin(P_PREFILL_HOST, req)
         t0 = time.monotonic()
+        first = not later_chunk
+        if first:  # (before anything can raise: the wait ends here)
+            qw = self._queue_ends(req, t0)
         # pages[:prefix_len // ps] already hold a cached prefix's KV (none
         # without a hit) or the earlier chunks': compute what follows it
         end = self._prompt_end(req)
@@ -1408,17 +1534,13 @@ class LLMEngine:
         dt = time.monotonic() - t0
         tid = req.trace_ctx[0] if req.trace_ctx else None
         self._m["prefill_t"].observe(dt, exemplar=tid)
-        first = not later_chunk
         if first:
             self._stats["admitted"] += 1
             self._m["admitted"].inc()
-            qw = max(0.0, t0 - req.submitted_at)
             self._m["queue_wait"].observe(qw, exemplar=tid)
         if req.trace_ctx is not None:
             w_end = time.time()
-            if first:
-                self._span(req, "llm.queue", req.submitted_wall,
-                           req.submitted_wall + qw, wait_s=round(qw, 6))
+            req.chunks += 1
             # ONE span a program: ``tokens`` the prompt's through this
             # chunk, ``prefix_len`` those before it
             self._span(req, "llm.prefill", w_end - dt, w_end,
@@ -1700,6 +1822,10 @@ class LLMEngine:
             now_w = time.time()
             self._span(req, "llm.preempt", now_w, now_w, ok=False,
                        tokens=s.num_tokens, produced=req.produced)
+            # its second wait begins (and a prompt still in chunks is let go)
+            req.at_queued = (self._admission_ends(req, ok=False)
+                             if req.at_program is not None
+                             else self._ph.reading(req))
         try:
             from ray_tpu.util import events
 
@@ -2129,6 +2255,10 @@ class LLMEngine:
             self._m["ttft"].observe(
                 req.first_token_at - req.submitted_at,
                 exemplar=req.trace_ctx[0] if req.trace_ctx else None)
+        if req.at_program is not None:  # (a traced request's admission)
+            now = self._admission_ends(req)
+            if req.at_token is None:
+                req.at_token = now
         self._m["tokens"].inc(n)
         self._undelivered.append((req.out_queue, tokens))
 

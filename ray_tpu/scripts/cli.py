@@ -343,6 +343,15 @@ def cmd_trace(args):
         flag = "" if node.get("ok", True) else "  [FAILED]"
         print(f"  {'  ' * depth}{node['name']:<{max(1, 40 - 2 * depth)}s} "
               f"{dur:9.2f}ms  {where}{flag}")
+        args = node.get("args") or {}
+        if all(k in args for k in tracing.WAIT_ATTRS):
+            # what the engine loop was doing while the request stood
+            tokens = args.get("tokens") if node["name"] == "llm.decode" else 0
+            print(f"  {'  ' * depth}  = " + " + ".join(
+                f"{k[:-2].replace('_', ' ')} {args[k] * 1e3:.2f}"
+                for k in tracing.WAIT_ATTRS) + " ms" + (
+                f"; {dur / (tokens - 1):.2f} ms a token after the first "
+                f"of {tokens}" if tokens and tokens > 1 else ""))
         for c in node.get("children", ()):
             walk(c, depth + 1)
 

@@ -12,7 +12,7 @@ over the control socket (same pattern as ``metrics_push``);
 :func:`assemble_trace` here to build the tree plus a critical-path summary
 (queue-wait vs. arg-fetch vs. run time).  :func:`trace_to_chrome_events`
 emits chrome-trace flow events (``ph:"s"/"f"``) so Perfetto draws the
-cross-process arrows.  An OpenTelemetry exporter hook stays import-gated.
+cross-process arrows.
 """
 
 from __future__ import annotations
@@ -39,6 +39,13 @@ _lock = threading.Lock()
 _enabled = False
 
 _tls = threading.local()
+
+# What the serving engine's loop was doing while a request stood, by class
+# of phase: attributes (seconds, summing to the span's length) of a
+# request's ``llm.queue``, ``llm.admission`` and ``llm.decode`` spans
+# (llm/engine.py keeps the sums; ``rtpu trace <id>`` prints them).
+WAIT_ATTRS = ("step_s", "own_prefill_s", "other_prefill_s", "host_s",
+              "idle_s")
 
 _flusher_started = False
 _flush_stop = threading.Event()
@@ -557,41 +564,3 @@ def export_chrome_trace(path: str, include_task_events: bool = True) -> int:
     with open(path, "w") as f:
         json.dump({"traceEvents": events}, f)
     return len(events)
-
-
-def export_otel_spans(tracer=None):
-    """Replay collected spans into an OpenTelemetry tracer (import-gated
-    like the reference's exporters, tracing_helper.py): each recorded span
-    becomes an OTel span with its original timestamps and attributes.
-    Returns the number of spans exported.  Without the opentelemetry
-    package use export_chrome_trace() for local inspection."""
-    try:
-        from opentelemetry import trace as otel_trace
-    except ImportError as e:
-        raise ImportError(
-            "opentelemetry is not in the TPU image; use "
-            "export_chrome_trace() for local trace inspection") from e
-    if tracer is None:
-        provider = otel_trace.get_tracer_provider()
-        if type(provider).__name__ in ("NoOpTracerProvider",
-                                       "ProxyTracerProvider"):
-            # no SDK configured: spans would be NonRecording and silently
-            # vanish — misreporting them as exported helps nobody
-            raise RuntimeError(
-                "no OpenTelemetry TracerProvider is configured; call "
-                "opentelemetry.trace.set_tracer_provider(...) first or "
-                "pass an explicit tracer")
-        tracer = otel_trace.get_tracer("ray_tpu")
-    spans = collected_spans()
-    for s in spans:
-        start_ns = int(s["ts"] * 1e3)  # recorded in microseconds
-        end_ns = int((s["ts"] + s["dur"]) * 1e3)
-        span = tracer.start_span(s["name"], start_time=start_ns)
-        for k, v in (s.get("args") or {}).items():
-            # OTel silently drops non-primitive values (set_attribute
-            # never raises): sanitize up front so nothing vanishes
-            span.set_attribute(
-                str(k), v if isinstance(v, (bool, str, int, float))
-                else repr(v))
-        span.end(end_time=end_ns)
-    return len(spans)

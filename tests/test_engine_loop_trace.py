@@ -6,6 +6,10 @@ request; compilations naming the phase they interrupted), the rotation of
 the loop's trace id, the stall event, and the off path: with
 ``RTPU_TRACE_SAMPLE=0`` the loop reaches no span or event code and the
 tokens are what they were.
+
+And the other direction (ISSUE 51): what the loop was doing while a
+request stood, by class of phase, on the request's own ``llm.queue``,
+``llm.admission`` and ``llm.decode`` spans.
 """
 
 import threading
@@ -93,10 +97,12 @@ def test_phases_are_ordered_disjoint_and_cover_their_iteration(sampled_run):
     # the burst before it replayed is delivered (a decode_emit of its own)
     # once the burst's first step is dispatched
     last_burst, after = sorted(its)[-2:]
-    names = [p["name"].rsplit(".", 1)[1] for p in its[last_burst]]
+    # (the gauges' refresh, every 0.25 s, may close any working iteration)
+    burst = [p for p in its[last_burst] if p["name"] != engine_mod.P_GAUGES]
+    names = [p["name"].rsplit(".", 1)[1] for p in burst]
     assert names[-6:] == ["decode_host", "decode_dispatch", "decode_emit",
                           "decode_dispatch", "decode_fetch", "decode_emit"]
-    delivery, replay = (its[last_burst][k]["args"] for k in (-4, -1))
+    delivery, replay = (burst[k]["args"] for k in (-4, -1))
     assert set(delivery) == {"delivered", "it"} and delivery["delivered"] == 8
     assert replay["slots_released"] == 1 and replay["tokens"] >= 1
     assert "delivered" not in replay
@@ -298,3 +304,204 @@ def test_stats_serves_its_counters_without_the_rings(sampled_run, model):
     assert not hasattr(eng, "_queue_waits")
     assert not hasattr(eng, "_prefill_times")
     assert eng.stats()["tokens_generated"] == 0  # before any loop ran
+
+
+# -- a request's time by what the loop was doing (ISSUE 51) ------------------
+
+WAITED = tracing.WAIT_ATTRS
+REQUEST_SPANS = ("llm.queue", "llm.admission", "llm.decode")
+
+
+def _step(eng, between=None):
+    """One iteration of the engine's loop on this thread (``between``:
+    called after the admission, before the burst)."""
+    ph = eng._ph
+    ph.it += 1
+    admitted = eng._admit()
+    if between is not None:
+        between()
+    stepped = eng._decode_all()
+    if not (admitted or stepped):
+        eng._deliver(False)
+    ph.finish_iteration(True)
+
+
+def _busy(eng):
+    return any(s is not None for s in eng._slots) or eng._waiting.qsize()
+
+
+@pytest.fixture(scope="module")
+def stepped_run(model):
+    """A sampled loop stepped by hand, so that who waits behind what is
+    known: A decodes; B, a prompt of three chunks, is submitted before a
+    burst of A's and admitted between A's bursts; once both are done C is
+    preempted mid-answer and resumed; then the loop's own thread serves D,
+    submitted from this one.  Returns {request: its spans, oldest first}."""
+    recs = []
+    mp = pytest.MonkeyPatch()
+    mp.setenv("RTPU_TRACE_SAMPLE", "1.0")
+    orig = tracing._record
+    mp.setattr(tracing, "_record", lambda r: (recs.append(r), orig(r))[1])
+    eng = _engine(model)
+    eng._ph.sampled = True  # (start() reads the flag; nothing started yet)
+
+    def submit(name, prompt, n):
+        with tracing.use_context((name * 32, None)):
+            return eng.submit(prompt, SamplingParams(max_tokens=n))
+
+    try:
+        submit("a", PROMPTS[0], 48)
+        _step(eng, between=lambda: submit("b", list(range(10, 90)), 12))
+        while _busy(eng):
+            _step(eng)
+        submit("c", PROMPTS[1], 30)
+        _step(eng)
+        _step(eng)
+        (i, slot), = [(i, s) for i, s in enumerate(eng._slots) if s]
+        eng._preempt(i, slot)
+        while _busy(eng):
+            _step(eng)
+        eng.start()
+        with tracing.use_context(("d" * 32, None)):
+            eng.generate(PROMPTS[1], SamplingParams(max_tokens=20))
+    finally:
+        eng.stop()
+        mp.undo()
+    return {name: [r for r in recs if r["trace_id"] == name * 32]
+            for name in "abcd"}
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def test_the_five_are_on_every_request_span_and_sum_to_its_length(
+        stepped_run):
+    seen = 0
+    for spans in stepped_run.values():
+        for s in spans:
+            if s["name"] in REQUEST_SPANS:
+                five = [s["args"][k] for k in WAITED]
+                assert min(five) >= 0.0, s
+                assert abs(sum(five) - (s["end_ts"] - s["start_ts"])) \
+                    < 1e-3, s
+                seen += 1
+    assert seen == 4 * 3 + 2  # C's second wait and second admission
+    # a request served by the loop's thread, submitted from another:
+    # behind nobody, its wait is the loop's idling and its own admit
+    queue, = _named(stepped_run["d"], "llm.queue")
+    assert queue["args"]["step_s"] == queue["args"]["other_prefill_s"] == 0
+
+
+def test_a_decoding_request_is_told_whose_prefill_it_stood_behind(
+        stepped_run):
+    a, b = stepped_run["a"], stepped_run["b"]
+    (a_decode,), (b_queue,), (b_adm,) = (
+        _named(a, "llm.decode"), _named(b, "llm.queue"),
+        _named(b, "llm.admission"))
+    # every prefill phase of the loop while A decoded carried B: B's admit
+    # (inside its wait) and its programs (its admission, but for the few
+    # statements between its first token and the next phase)
+    # (A's own: what was left of its last prefill phase at its first token)
+    assert a_decode["args"]["own_prefill_s"] < 1e-3
+    assert a_decode["args"]["other_prefill_s"] == pytest.approx(
+        b_queue["args"]["own_prefill_s"] + b_adm["args"]["own_prefill_s"],
+        abs=1e-3)
+    assert b_adm["args"]["own_prefill_s"] > 0
+    # B was submitted before a burst of A's and waited it out
+    assert b_queue["args"]["step_s"] > 0
+    assert b_queue["args"]["other_prefill_s"] == 0.0
+
+
+def test_a_prompt_in_chunks_has_one_admission_with_the_bursts_between(
+        stepped_run):
+    b = stepped_run["b"]
+    adm, = _named(b, "llm.admission")
+    chunks = _named(b, "llm.prefill")
+    assert [c["args"]["chunk"] for c in chunks] == [0, 1, 2]
+    assert adm["args"]["chunks"] == len(chunks) == 3
+    assert adm["args"]["tokens"] == 80 and adm["args"]["resumed"] is False
+    assert adm["start_ts"] <= chunks[0]["start_ts"] + 1e-3
+    assert adm["end_ts"] >= chunks[-1]["end_ts"] - 1e-3
+    # A's bursts ran between B's chunks, and that is said on B's span
+    assert adm["args"]["step_s"] > 0
+    # (a chunk's last phase runs on past its span, to the burst's first;
+    # a delivery made behind a chunk's dispatch is the host's)
+    assert adm["args"]["own_prefill_s"] + adm["args"]["host_s"] >= sum(
+        c["end_ts"] - c["start_ts"] for c in chunks) - 1e-3
+    assert adm["args"]["own_prefill_s"] > 10 * adm["args"]["host_s"]
+    # it overlaps its chunks' spans: no phase of the SLO burn's attribution
+    from ray_tpu._private import slo
+
+    assert "llm.admission" not in slo._PHASE_BY_SPAN
+    # a prompt of one program: as long as its prefill
+    adm, = _named(stepped_run["a"], "llm.admission")
+    prefill, = _named(stepped_run["a"], "llm.prefill")
+    assert adm["args"]["chunks"] == 1
+    assert adm["end_ts"] - adm["start_ts"] == pytest.approx(
+        prefill["end_ts"] - prefill["start_ts"], abs=2e-3)
+
+
+def test_a_resumed_request_has_a_second_wait_and_admission(stepped_run):
+    c = stepped_run["c"]
+    names = [s["name"] for s in c if s["name"] != "llm.prefill"]
+    assert names == ["llm.queue", "llm.admission", "llm.preempt",
+                     "llm.queue", "llm.admission", "llm.decode",
+                     "llm.request"]
+    first, second = _named(c, "llm.admission")
+    assert (first["args"]["resumed"], second["args"]["resumed"]) \
+        == (False, True)
+    assert [q["args"]["resumed"] for q in _named(c, "llm.queue")] \
+        == [False, True]
+    preempt, = _named(c, "llm.preempt")
+    waited, resumed = _named(c, "llm.queue")[1], second
+    assert waited["start_ts"] == pytest.approx(preempt["start_ts"], abs=1e-3)
+    assert resumed["start_ts"] == pytest.approx(waited["end_ts"], abs=1e-3)
+    # the answer's span runs from the FIRST first token to the end, over
+    # the second wait and admission, whose prefill is its own
+    decode, = _named(c, "llm.decode")
+    assert decode["args"]["preempts"] == 1 and decode["args"]["tokens"] == 30
+    assert decode["start_ts"] == pytest.approx(first["end_ts"], abs=1e-3)
+    assert decode["args"]["own_prefill_s"] >= second["args"]["own_prefill_s"]
+    assert decode["args"]["other_prefill_s"] == 0.0
+
+
+def test_a_reading_counts_the_phase_that_is_open(monkeypatch):
+    now = [100.0]
+    monkeypatch.setattr(engine_mod, "_mono", lambda: now[0])
+    monkeypatch.setattr(tracing, "_record", lambda r: None)
+    ph = engine_mod._LoopPhases([])
+    req, other = (engine_mod._Request(rid, [1], SamplingParams())
+                  for rid in ("req-a", "req-b"))
+    assert ph.reading(req) is None and engine_mod._waited(None, None) == {}
+    ph.sampled = True
+    r0 = ph.reading(req)
+    ph.begin(engine_mod.P_DECODE_FETCH)
+    now[0] += 0.5  # the whole process could stand still here
+    r1 = ph.reading(req)
+    assert engine_mod._waited(r0, r1) == dict(zip(WAITED, (0.5, 0, 0, 0, 0)))
+    ph.begin(engine_mod.P_ADMIT)
+    now[0] += 0.125
+    ph.req = req  # no slot: the loop's own time
+    assert engine_mod._waited(r1, ph.reading(req))["host_s"] == 0.125
+    ph.vals = ("admitted",)  # ... or the request's, once it is admitted
+    assert engine_mod._waited(r1, ph.reading(req))["own_prefill_s"] == 0.125
+    ph.begin(engine_mod.P_PREFILL_FETCH, req)
+    now[0] += 0.25
+    r2 = ph.reading(req)
+    assert engine_mod._waited(r1, r2) == dict(
+        zip(WAITED, (0, 0.375, 0, 0, 0)))
+    assert engine_mod._waited(r1, ph.reading(other)) == dict(
+        zip(WAITED, (0, 0, 0.375, 0, 0)))  # another request's prompt
+    assert req.own_s == 0.125  # (closed phases only; the reading adds)
+    ph.finish_iteration(True)
+    now[0] += 0.0625  # between two iterations: the loop's own time
+    ph.begin(engine_mod.P_ADMIT)
+    ph.finish_iteration(False)
+    now[0] += 2.0  # nothing to do, asleep
+    r3 = ph.reading(req)
+    assert engine_mod._waited(r2, r3) == dict(
+        zip(WAITED, (0, 0, 0, 0.0625, 2.0)))
+    assert req.own_s == 0.375
+    assert sum(engine_mod._waited(r0, r3).values()) == pytest.approx(
+        now[0] - 100.0)

@@ -151,6 +151,9 @@ def test_preempt_event_carries_request_identity(monkeypatch):
         _span=lambda r, name, t0, t1, ok=True, **attrs:
             spans.append((name, ok, attrs)),
         _waiting=types.SimpleNamespace(queue=collections.deque()),
+        # (its second wait begins at a reading of the loop's sums: none of
+        # a loop that is not sampled, since ISSUE 51)
+        _ph=types.SimpleNamespace(reading=lambda r: None),
     )
     engine_mod.LLMEngine._preempt(fake, 0, slot)
 

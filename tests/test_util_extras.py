@@ -147,47 +147,3 @@ def test_mp_pool_closed_raises():
     with pytest.raises(ValueError):
         pool.map(abs, [1])
     pool.terminate()
-
-
-def test_otel_span_export():
-    """export_otel_spans: refuses without a configured provider, exports
-    with an explicit tracer, sanitizes non-primitive attributes."""
-    import pytest as _pytest
-
-    from ray_tpu.util import tracing
-
-    tracing.enable_tracing()
-    t0 = None
-    with tracing.trace_span("otel_probe", a=1, cfg={"lr": 0.1}):
-        pass
-    # this image has opentelemetry-api with the default proxy provider:
-    # exporting into the void must refuse, not report success
-    with _pytest.raises(RuntimeError, match="TracerProvider"):
-        tracing.export_otel_spans()
-
-    class FakeSpan:
-        def __init__(self, name, start):
-            self.name, self.start, self.attrs = name, start, {}
-
-        def set_attribute(self, k, v):
-            self.attrs[k] = v
-
-        def end(self, end_time=None):
-            self.end_time = end_time
-
-    class FakeTracer:
-        def __init__(self):
-            self.spans = []
-
-        def start_span(self, name, start_time=None):
-            s = FakeSpan(name, start_time)
-            self.spans.append(s)
-            return s
-
-    tracer = FakeTracer()
-    n = tracing.export_otel_spans(tracer)
-    assert n == len(tracer.spans) >= 1
-    probe = next(s for s in tracer.spans if s.name == "otel_probe")
-    assert probe.attrs["a"] == 1
-    assert probe.attrs["cfg"] == repr({"lr": 0.1})  # sanitized
-    assert probe.end_time > probe.start  # ns, end after start
