@@ -638,7 +638,11 @@ class LLMEngine:
     from a zero state, whatever the last tenant left; a later chunk goes on
     from them) and mean nothing once it is released; without a
     ``PrefixCache`` a
-    preempted sequence's resume prefill recomputes from position 0.
+    preempted sequence's resume prefill recomputes from position 0.  The
+    same holds for the rows a family's PAGES hold in slot order
+    (paged_cache.py ``CacheConfig``: written from row 0 by that prefill,
+    read as far as the slot's context has completed them): a sequence
+    takes a slot only through a prefill that names it, and never moves.
 
     The engine holds the parameters in the SERVING layout, made once here
     from whichever tree it is handed, and keeps no reference to the
@@ -667,10 +671,12 @@ class LLMEngine:
         # window: what one sequence holds of a window layer's pool at most
         # (CacheConfig.window_pages_per_seq), and the pool's default size
         most = -(-(self._window + self._chunk) // ps) + 1
+        self.max_pages_per_seq = -(-self.cfg.max_seq_len // ps)
         ccfg = CacheConfig(
             **layout, num_pages=self.cfg.num_pages,
             page_size=ps, dtype=model_cfg.dtype,
             max_slots=self.cfg.max_slots,
+            max_pages_per_seq=self.max_pages_per_seq,
             window_pages=(self.cfg.window_pages or max(
                 self.cfg.max_slots * (self._window // ps + 4), most + 1))
             if self._window else 0)
@@ -701,8 +707,6 @@ class LLMEngine:
             PrefixCache(self.cfg.page_size)
             if flags.get("RTPU_PREFIX_CACHE")
             and "prefix_cache" not in model_cfg.refuses else None)
-        self.max_pages_per_seq = -(-self.cfg.max_seq_len
-                                   // self.cfg.page_size)
         # Store-backed KV tier (ISSUE 16): hot family spines seal into
         # the shm store and failure/spill paths pull them back instead
         # of cold-prefilling.  All tier I/O (seal extraction, pull

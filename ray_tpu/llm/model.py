@@ -43,9 +43,14 @@ way, which it hands the family's walk as one bundle ``via``:
   or all of it) begins from zeros.
 - ``attend_sparse(q, k, v, (ck, cv, pooled, li))`` (its sparse layers,
   ops/block_sparse.py): K/V rows into pool layer ``li`` and the POOLED KEYS
-  those rows complete into ``pooled`` (a row a page beside the pool,
-  ``cache_layout()["page_rows"]``; it rides in the walk with the pools and
-  is written where it lies), then the choice of blocks a query: both
+  those rows complete into ``pooled``, a pair: a row a page beside the pool
+  (``cache_layout()["page_rows"]``) and the same rows again in SLOT order
+  (paged_cache.py ``CacheConfig``: row j of a slot is the row of the page
+  at entry j of its table); both ride in the walk with the pools and are
+  written where they lie, the same value at the same place.  The decode
+  step and the suffix prefill READ a sequence's rows in slot order (no
+  gather through the table); the page order is kept for whoever finds
+  rows by page id.  Then the choice of blocks a query: both
   prefills attend by KEY BLOCK under the chosen blocks' mask with a running
   softmax (``block_sparse.selected_attention``: no [L, context] score
   matrix is ever whole), the decode step hands ``paged_decode_attention`` a
@@ -166,14 +171,15 @@ def _conv_and_gates(cfg, mix, qkv, before, b, a):
 
 def _rows_that_ride(cfg, state):
     """What of ``state`` a prefill's walk carries: the rows a PAGE holds
-    (``cache_layout()["page_rows"]``), written where they lie as the pools
-    are, and None in the place of the rows a SLOT holds, which the prefill
-    writes itself, once.  None for a family that declares no such rows (its
-    walk carries no state at all)."""
-    paged = cfg.cache_layout().get("page_rows") if state is not None else None
-    if not paged:
+    (``cache_layout()["page_rows"]``) and their twins in slot order, written
+    where they lie as the pools are, and None in the place of the rows of a
+    slot's STATE (``state_rows``), which the prefill writes itself, once.
+    None for a family that declares no page rows (its walk carries no state
+    at all)."""
+    layout = cfg.cache_layout() if state is not None else {}
+    if not layout.get("page_rows"):
         return None
-    return {name: rows if name in paged else None
+    return {name: None if name in layout["state_rows"] else rows
             for name, rows in state.items()}
 
 
@@ -199,6 +205,17 @@ def _pooled_rows(cfg, k, page_size: int):
             f"a prefill of {k.shape[0]} positions is no whole number of "
             f"pages of {page_size}: the pooled keys are cached a row a page")
     return block_sparse.pool_keys(cfg, k)
+
+
+def _in_slot_order(by_slot, P: int):
+    """``by_slot`` (the pooled rows in slot order, [layers, slots, entries,
+    G, d]) if it has an entry for each of the P entries of a page table."""
+    if by_slot.shape[2] != P:
+        raise ValueError(
+            f"the pooled keys in slot order hold {by_slot.shape[2]} rows a "
+            f"slot and a page table {P} entries: they are read a row an "
+            f"entry (CacheConfig.max_pages_per_seq)")
+    return by_slot
 
 
 def _visible(cfg, qpos, kpos, window: int = 0):
@@ -299,7 +316,7 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         return o, (None, left)
 
     def attend_sparse(q, k, v, pools):
-        ck, cv, pooled, li = pools
+        ck, cv, (by_page, by_slot), li = pools
         L, G, d = k.shape
         ps, bs = ck.shape[2], cfg.block_size
         with jax.named_scope("attn/kv_write"):
@@ -311,14 +328,18 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
             # a row a page, at the page of the row's first key (the last
             # rows are not complete: a later chunk or step writes them
             # again, and no query sees them before)
-            rows = _pooled_rows(cfg, kp, ps).astype(pooled.dtype)
-            pooled = pooled.at[li, page_rows[::ps]].set(rows[:L // ps])
+            rows = _pooled_rows(cfg, kp, ps).astype(by_page.dtype)
+            by_page = by_page.at[li, page_rows[::ps]].set(rows[:L // ps])
+            # and rows [0, L / ps) of the slot this call admits to
+            by_slot = jax.lax.dynamic_update_slice(
+                by_slot, rows[None, None, :min(L // ps, by_slot.shape[2])],
+                (li, slot, 0, 0, 0))
         out = block_sparse.selected_attention(
             cfg, q, positions, rows,
             lambda at, n: (jax.lax.dynamic_slice_in_dim(kp, at, n),
                            jax.lax.dynamic_slice_in_dim(vp, at, n)),
             T, true_len)
-        return out, (ck, cv, pooled), {
+        return out, (ck, cv, (by_page, by_slot)), {
             "index_rows_written": block_sparse.rows_complete(cfg, true_len)}
 
     def recur_fixed(q, k, v, g, rows):  # q, k, v: [L, H, d]; g: [H]
@@ -432,16 +453,19 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                 (pool, None))
 
     def attend_sparse(q, k, v, pools):
-        ck, cv, pooled, li = pools
+        ck, cv, (by_page, by_slot), li = pools
         L, G, d = k.shape
         with jax.named_scope("attn/kv_write"):
             ck = ck.at[li, page_rows, slot_positions].set(k)
             cv = cv.at[li, page_rows, slot_positions].set(v)
 
-        def through(first, n):  # n entries of the table from ``first`` on
-            at = first + jnp.arange(n)
-            return jnp.where((at >= 0) & (at < P),
-                             page_table[jnp.clip(at, 0, P - 1)], 0)
+        def entries(first, n):  # n entries of the table from ``first`` on:
+            at = first + jnp.arange(n)  # where they are, which are in it
+            return at, (at >= 0) & (at < P)
+
+        def through(first, n):  # their page ids, the null page outside it
+            at, inside = entries(first, n)
+            return jnp.where(inside, page_table[jnp.clip(at, 0, P - 1)], 0)
 
         with jax.named_scope("sparse_attn/index"):
             # this call's rows, and those that began in the pages before
@@ -449,10 +473,15 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
             w = cfg.kernel_size // page_size  # pages a row spans
             first = positions[0] // page_size - (w - 1)
             before = ck[li, through(first, w - 1)].reshape(-1, G, d)
-            rows = _pooled_rows(cfg, jnp.concatenate([before, k]), page_size)
-            pooled = pooled.at[li, through(first, rows.shape[0])].set(
-                rows.astype(pooled.dtype))
-            rows = pooled[li, page_table]  # the sequence's, [P, G, d]
+            rows = _pooled_rows(cfg, jnp.concatenate([before, k]),
+                                page_size).astype(by_page.dtype)
+            by_page = by_page.at[li, through(first, rows.shape[0])].set(rows)
+            # the same entries of the slot's rows, those outside dropped
+            at, inside = entries(first, rows.shape[0])
+            by_slot = _in_slot_order(by_slot, P).at[
+                li, slot, jnp.where(inside, at, P)].set(rows, mode="drop")
+            # the sequence's, [P, G, d], where they lie
+            rows = jax.lax.dynamic_index_in_dim(by_slot[li], slot, 0, False)
 
         def keys_of(at, n):
             pages = jax.lax.dynamic_slice_in_dim(
@@ -467,7 +496,7 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
         ends = positions[0] + true_len
         out = block_sparse.selected_attention(cfg, q, positions, rows,
                                               keys_of, P * page_size, ends)
-        return out, (ck, cv, pooled), {
+        return out, (ck, cv, (by_page, by_slot)), {
             "index_rows_written": block_sparse.rows_complete(cfg, ends)
             - block_sparse.rows_complete(cfg, positions[0])}
 
@@ -578,7 +607,7 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
         return o, (st, None)
 
     def attend_sparse(q, k, v, pools):  # q: [B, H, d]; k, v: [B, G, d]
-        ck, cv, pooled, li = pools
+        ck, cv, (by_page, by_slot), li = pools
         with jax.named_scope("attn/kv_write"):
             ck = ck.at[li, write_page, write_slot].set(k.astype(ck.dtype))
             cv = cv.at[li, write_page, write_slot].set(v.astype(cv.dtype))
@@ -592,18 +621,24 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
             pages = jnp.take_along_axis(
                 page_tables, jnp.clip(first[:, None] + jnp.arange(w), 0,
                                       P - 1), axis=1)  # [B, w]
-            row = ck[li, pages].astype(jnp.float32).mean(axis=(1, 2))
-            pooled = pooled.at[li, jnp.where(done, pages[:, 0], 0)].set(
-                row.astype(pooled.dtype))
+            row = ck[li, pages].astype(jnp.float32).mean(
+                axis=(1, 2)).astype(by_page.dtype)
+            by_page = by_page.at[li, jnp.where(done, pages[:, 0], 0)].set(row)
+            # entry ``first`` of its slot's rows; a slot that completes no
+            # row writes nothing (no null row absorbs it here)
+            by_slot = _in_slot_order(by_slot, P).at[
+                li, jnp.arange(tokens.shape[0]),
+                jnp.where(done, first, P)].set(row, mode="drop")
+            # every slot's rows where they lie: no gather through the tables
             lists, held = block_sparse.page_lists(
-                cfg, q, pooled[li, page_tables], page_tables, lengths,
+                cfg, q, by_slot[li], page_tables, lengths,
                 block_sparse.list_width(cfg, P))
             counted = {**block_sparse.walked(cfg, held, lengths),
                        "index_rows_written": done.sum().astype(jnp.int32)}
         with jax.named_scope("sparse_attn/attend"):
             return (paged_decode_attention(q, ck, cv, lists, held, li,
                                            heads_apart=True),
-                    (ck, cv, pooled), counted)
+                    (ck, cv, (by_page, by_slot)), counted)
 
     def recur_fixed(q, k, v, g, rows):  # q, k, v: [B, H, d]; g: [H]
         S, li = rows

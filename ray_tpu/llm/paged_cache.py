@@ -109,7 +109,18 @@ class CacheConfig:
     keys its sparse layers choose blocks by).  Such a row is found through
     the page table as the page is, lives and dies with the page, and is
     allocated with the state rows (``init_state``: [layers, num_pages,
-    *shape])."""
+    *shape]).  Every page row has a TWIN IN SLOT ORDER beside it, under
+    ``<name>_by_slot``: [layers, max_slots, max_pages_per_seq, *shape], row
+    j of slot s the row of the page at entry j of s's page table.  A
+    program that wants a slot's rows EVERY step (the decode step's choice
+    of blocks) reads them there where they lie, where through the table
+    they are a gather of a row an index (13.8 ns a row on the chip, 0.71 ms
+    a layer a step at 32 x 1,600: PERF.md section 6, PRs 50 and 52).
+    Whoever writes a page row writes its twin, the same value at the same
+    place in the program; a twin's row means something as far as its
+    slot's context has completed rows and nothing past it or once the slot
+    is released (the rule of the state rows: the prefill that admits a
+    sequence to a slot writes from row 0)."""
 
     n_layers: int
     n_kv_heads: int = 0
@@ -125,6 +136,7 @@ class CacheConfig:
     window: int = 0
     window_pages: int = 0
     page_rows: Optional[dict] = None
+    max_pages_per_seq: int = 0  # entries of a sequence's page table
 
     def __post_init__(self):
         if bool(self.latent_dim) == bool(self.n_kv_heads * self.head_dim):
@@ -198,15 +210,20 @@ def init_cache(cfg: CacheConfig):
 
 
 def init_state(cfg: CacheConfig):
-    """The state rows [count, max_slots, *row] and the page rows [layers,
-    num_pages, *row] by name, zeros; None for a model that declares
-    neither."""
+    """The state rows [count, max_slots, *row], the page rows [layers,
+    num_pages, *row] and each page row's twin in slot order [layers,
+    max_slots, max_pages_per_seq, *row] (``<name>_by_slot``) by name, zeros;
+    None for a model that declares neither."""
     if not cfg.state_rows and not cfg.page_rows:
         return None
+    page_rows = (cfg.page_rows or {}).items()
     return {**{name: jnp.zeros((count, cfg.max_slots, *shape), dt)
                for name, (count, shape, dt) in (cfg.state_rows or {}).items()},
             **{name: jnp.zeros((layers, cfg.num_pages, *shape), dt)
-               for name, (layers, shape, dt) in (cfg.page_rows or {}).items()}}
+               for name, (layers, shape, dt) in page_rows},
+            **{f"{name}_by_slot": jnp.zeros((layers, cfg.max_slots,
+                                         cfg.max_pages_per_seq, *shape), dt)
+               for name, (layers, shape, dt) in page_rows}}
 
 
 class PageAllocator:

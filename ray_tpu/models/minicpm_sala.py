@@ -29,8 +29,13 @@ What is cached (``cache_layout``): K/V pages over the sparse layers; beside
 each page a row of POOLED KEYS a sparse layer (``page_rows``: the mean of
 the page's keys and the next page's, what the selection scores against,
 complete when both pages are full); a float32 state row a slot a linear
-layer (``state_rows``).  No layer here finds out which program it is in:
-``attend_sparse`` and ``recur_fixed`` are the program's (llm/model.py).
+layer (``state_rows``).  The engine keeps the pooled keys TWICE
+(llm/paged_cache.py ``CacheConfig``): ``pooled_k`` a row a page, found by
+page id, and ``pooled_k_by_slot``, each slot's rows in the order of its
+page table, which the decode step and the suffix prefill read where they
+lie (the walk below carries the pair; the three programs write both).  No
+layer here finds out which program it is in: ``attend_sparse`` and
+``recur_fixed`` are the program's (llm/model.py).
 
 Parameters: ``layers`` = ``{"sparse": leaves stacked over the sparse
 layers, "lin": over the linear ones}``.  ``serving_layout`` makes
@@ -313,7 +318,8 @@ def served_walk(cfg, params, x, caches, positions, via):
     """``llama.served_walk`` over the stack's RUNS.  ``caches`` = (K pool,
     V pool, state), the state a dict of the linear layers' rows ``S`` (or
     None where the program writes them itself, once) and the pooled keys'
-    rows ``pooled_k``.  ``via["attend_sparse"](q, k, v, (ck, cv, pooled,
+    rows ``pooled_k`` and ``pooled_k_by_slot``, handed on as the pair
+    ``pooled``.  ``via["attend_sparse"](q, k, v, (ck, cv, pooled,
     li)) -> (out, (ck, cv, pooled), counted)``, ``counted`` what the call
     counted on the device by name, summed here over the sparse layers (they
     are unrolled) into the third thing returned, ONE vector under the tuple
@@ -322,7 +328,8 @@ def served_walk(cfg, params, x, caches, positions, via):
     leaves them be and hands back as ``left`` the row the program is to
     write: the fourth thing returned, ``{"S": [linear layers, ...]}``."""
     cache_k, cache_v, state = caches
-    S, pooled = state["S"], state["pooled_k"]
+    S = state["S"]
+    pooled = state["pooled_k"], state["pooled_k_by_slot"]
     left, counted = [], {}
 
     def attend(q, k, v, pools):
@@ -353,5 +360,7 @@ def served_walk(cfg, params, x, caches, positions, via):
     left = (None if left[0] is None
             else {"S": jnp.concatenate(left, axis=0)})
     names = tuple(sorted(counted))
-    return (x, (cache_k, cache_v, {"S": S, "pooled_k": pooled}),
+    by_page, by_slot = pooled
+    return (x, (cache_k, cache_v, {"S": S, "pooled_k": by_page,
+                                   "pooled_k_by_slot": by_slot}),
             {names: jnp.stack([counted[n] for n in names])}, left)

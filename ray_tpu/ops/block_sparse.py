@@ -143,9 +143,13 @@ def chosen(sp, b, n):
     ``lax.top_k``, which the TPU lowers to a full sort, two sorts a layer,
     2.65 ms a decode step at 32 slots; every block ranked against every
     other, M^2 comparisons, 2.19 ms a step and 3.51 ms a chunk of 2,048;
-    this form 2.15 ms a step and 1.43 ms a chunk.  A decode step's part is
-    two gathers (a slot's pooled rows through its table, a list's page
-    ids), not the choice: 25 us of a layer's 951 were the ranking."""
+    this form 2.15 ms a step and 1.43 ms a chunk.  A decode step's part
+    was never the choice (25 us of a layer's 951 were the ranking) but two
+    gathers, each paid by the INDEX: a slot's pooled rows through its table
+    (51,200 rows, 686 us a layer) and a list's page ids one by one (16,384,
+    166 us).  Since PR 52 the step reads the rows where they lie, in slot
+    order (llm/paged_cache.py ``CacheConfig``), and ``lists_from`` gathers
+    a block's entries an index: 912 -> 138 us a layer timed apart."""
     M = b.shape[-1]
     m = jnp.arange(M)
     n = n[:, None, None]
@@ -188,10 +192,18 @@ def lists_from(sp, blocks, count, tables, n, width: int):
     B, P = tables.shape
     G = blocks.shape[1]
     ppb = sp.block_size // sp.kernel_stride  # pages a block
-    pages = (blocks[..., None] * ppb + jnp.arange(ppb)).reshape(B, G, -1)
-    sparse = jnp.where(
-        pages < P, jnp.take_along_axis(
-            tables[:, None, :], jnp.minimum(pages, P - 1), axis=2), 0)
+    if P % ppb:
+        raise ValueError(
+            f"a page table of {P} entries is no whole number of blocks of "
+            f"{ppb} pages: a list is gathered a block's entries at a time")
+    # a block's pages are adjacent entries of the table: ONE index a block
+    # (an index at a time is what a gather costs, 10 ns each on the chip:
+    # K x ppb of them a list were 166 us a layer a step, PERF.md section
+    # 6, PR 52); the sentinel M and whatever lies past the table give 0
+    sparse = jax.vmap(partial(jnp.take, axis=0, mode="clip"))(
+        tables.reshape(B, P // ppb, ppb), blocks)  # [B, G, K, ppb]
+    sparse = jnp.where((blocks < P // ppb)[..., None], sparse,
+                       0).reshape(B, G, -1)
     short = width - sparse.shape[-1]
     sparse = (jnp.pad(sparse, ((0, 0), (0, 0), (0, short))) if short >= 0
               else sparse[..., :width])

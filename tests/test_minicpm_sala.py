@@ -22,6 +22,8 @@ from benchmarks.reference import minicpm_sala as ref
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.llm.paged_cache import CacheConfig, init_state
+from ray_tpu.models import afmoe, glm_moe_lite, llama, olmo_hybrid, sdar_moe
 from ray_tpu.models import minicpm_sala as ms
 from ray_tpu.ops import block_sparse, lightning
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -469,3 +471,360 @@ def test_the_planted_faults_show(params, monkeypatch, fault):
     past = slice(CFG.dense_len, n)
     share = float((sel[:, past] & own[:, past]).sum() / sel[:, past].sum())
     assert share <= 5 / 6 + 1e-6
+
+
+# ---------------------------------------------------------------------------
+# The pooled keys in SLOT order (paged_cache.py ``CacheConfig``): the decode
+# step reads a slot's rows where they lie, and its lists' page ids a block
+# an index.
+
+def _complete(n):
+    """Rows of pooled keys a context of n positions completes."""
+    return max(n // PS - (CFG.kernel_size // PS - 1), 0)
+
+
+def _slot_rows_are_the_tables(engine, homes=None):
+    """Every live slot's rows in slot order are ``pooled_k`` through its
+    table, bit for bit, over the rows its context completes; each
+    sequence sits in the slot its last prefill named.  Returns the rows
+    compared."""
+    by_page = np.asarray(engine.state["pooled_k"])
+    by_slot = np.asarray(engine.state["pooled_k_by_slot"])
+    assert by_slot.shape == (2, len(engine._slots), 512 // PS,
+                             CFG.n_kv_heads, CFG.head_dim)
+    seen = 0
+    for i, s in enumerate(engine._slots):
+        if s is None:
+            continue
+        if homes is not None:
+            assert homes[id(s.request)] == i
+        j = _complete(s.num_tokens)
+        for li in range(2):
+            assert np.array_equal(by_slot[li, i, :j],
+                                  by_page[li, np.asarray(s.pages[:j], int)])
+            assert j < 2 or np.abs(by_slot[li, i, :j]).min(axis=(1, 2)).all()
+        seen += j
+    return seen
+
+
+def _prefill_homes(engine):
+    """request -> the slot its last prefill program named (wraps
+    ``engine._prefill``): where a live sequence's rows were written."""
+    homes, prefill = {}, engine._prefill
+
+    def noted(req, pages, rng, prefix_len=0, slot=None, *a, **kw):
+        homes[id(req)] = slot
+        return prefill(req, pages, rng, prefix_len, slot, *a, **kw)
+
+    engine._prefill = noted
+    return homes
+
+
+def _seated(engine, count):
+    return sum(s is not None and s.prefill_at is None
+               for s in engine._slots) == count
+
+
+def _lists_by_entry(engine, i, q):
+    """What slot ``i``'s decode step would list for the queries q [sparse
+    layers, H, d] at its context, from its rows in slot order: per layer
+    and KV head the table ENTRIES listed (page ids differ from engine to
+    engine) as far as the list's length, and the lengths."""
+    s = engine._slots[i]
+    table = jnp.asarray(family._table(engine, s.pages))[None]
+    entry = {page: j for j, page in enumerate(s.pages)}
+    out = []
+    for li in range(2):
+        lists, held = block_sparse.page_lists(
+            CFG, q[li][None], engine.state["pooled_k_by_slot"][li, i][None],
+            table, jnp.asarray([s.num_tokens], jnp.int32),
+            block_sparse.list_width(CFG, table.shape[1]))
+        lists, held = np.asarray(lists[0]), np.asarray(held[0])
+        out.append([[entry[p] for p in lists[g, :-(-held[g] // PS)].tolist()]
+                    + [int(held[g])] for g in range(CFG.n_kv_heads)])
+    return out
+
+
+def _fresh_run(params, prompt, bursts):
+    """(tokens, lists) of ``prompt`` alone in a fresh engine after its
+    chunks and ``bursts`` bursts of 8 steps."""
+    engine = _engine(params, buckets=(32, 64))
+    engine.submit(prompt, SamplingParams(max_tokens=200))
+    while not _seated(engine, 1):
+        engine._admit()
+    for _ in range(bursts):
+        engine._decode_all()
+    s = engine._slots[0]
+    assert s.num_tokens > CFG.dense_len  # the sparse rule chose its lists
+    return list(s.generated), _lists_by_entry(engine, 0, _QUERIES)
+
+
+_QUERIES = jnp.asarray(np.random.default_rng(11).standard_normal(
+    (2, CFG.n_heads, CFG.head_dim)), jnp.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "first_chunk", "later_chunks", "steps_that_complete_a_row",
+    "steps_that_complete_none", "released_then_a_shorter_prompt",
+    "preempted_then_resumed"])
+def test_the_slot_order_holds_what_the_tables_reach(params, case):
+    """``pooled_k_by_slot`` through the engine, two live slots, prompts of
+    four and three chunks of 64: after a first chunk, after each later one
+    (the other slot decoding between them), after steps that fill a page
+    (a row completed) and steps that do not, every live slot's rows equal
+    ``pooled_k[li, table[:j]]`` bit for bit over the rows its context
+    completes, and nobody sits in a slot no prefill of theirs named.  A
+    slot released and taken by a SHORTER prompt (stale rows past its
+    context, another tenant's), and a sequence preempted and resumed, give
+    the tokens and lists of a fresh engine."""
+    engine = _engine(params, buckets=(32, 64))
+    homes = _prefill_homes(engine)
+    sp = SamplingParams(max_tokens=200)
+    if case == "released_then_a_shorter_prompt":
+        engine.submit(_prompt(400, 3), SamplingParams(max_tokens=9))
+        while not _seated(engine, 1):
+            engine._admit()
+        stale = np.asarray(engine.state["pooled_k_by_slot"][:, 0, 41:49])
+        engine._decode_all()
+        assert engine._slots[0] is None  # finished and released
+        short = _prompt(270, 4)
+        engine.submit(short, sp)
+        while not _seated(engine, 1):
+            engine._admit()
+            assert _slot_rows_are_the_tables(engine, homes) > 0
+        assert engine._slots[0] is not None  # the same slot, rows 0-32
+        for _ in range(2):
+            engine._decode_all()
+        # the last tenant's rows lie past what the shorter prompt's chunks
+        # and steps wrote (rows 0-39) yet, and change nothing
+        assert np.array_equal(stale, np.asarray(
+            engine.state["pooled_k_by_slot"][:, 0, 41:49]))
+        assert np.abs(stale).max() > 0
+        got = (list(engine._slots[0].generated),
+               _lists_by_entry(engine, 0, _QUERIES))
+        assert got == _fresh_run(params, short, 2)
+        return
+    if case == "preempted_then_resumed":
+        prompt = _prompt(270, 7)
+        engine.submit(prompt, sp)
+        while not _seated(engine, 1):
+            engine._admit()
+        engine._decode_all()
+        s = engine._slots[0]
+        engine._preempt(0, s)
+        while not _seated(engine, 1):
+            engine._admit()  # from position 0, the tokens folded in
+            assert _slot_rows_are_the_tables(engine, homes) > 0
+        engine._decode_all()
+        s = engine._slots[0]
+        # the first token and a burst's eight folded into the prompt
+        folded = list(s.request.prompt_tokens)
+        assert s.request.preempts == 1 and folded[:270] == prompt
+        assert len(folded) == 279 and s.num_tokens == 279 + 8
+        got = list(s.generated), _lists_by_entry(engine, 0, _QUERIES)
+        assert got == _fresh_run(params, folded, 1)
+        # and the sequence goes on as it would have, never preempted
+        assert (folded[270:] + got[0])[:17] == _fresh_run(params, prompt,
+                                                         2)[0]
+        return
+    a, b = _prompt(230, 5), _prompt(150, 6)
+    engine.submit(a, sp)
+    engine._admit()  # a's first chunk
+    assert engine._slots[0].prefill_at == 64
+    if case == "first_chunk":
+        assert _slot_rows_are_the_tables(engine, homes) == 2 * 7 // 2
+        return
+    while not _seated(engine, 1):
+        engine._admit()
+        if case == "later_chunks":
+            assert _slot_rows_are_the_tables(engine, homes) > 0
+    engine.submit(b, sp)
+    chunks = 0
+    while not _seated(engine, 2):
+        engine._admit()  # b's chunks into slot 1, a's bursts between them
+        engine._decode_all()
+        chunks += 1
+        if case == "later_chunks":
+            assert _slot_rows_are_the_tables(engine, homes) > 0
+    assert chunks == 3 and [s.request.prompt_tokens for s in engine._slots[
+        :2]] == [a, b]
+    if case == "later_chunks":
+        return
+    if case == "steps_that_complete_a_row":
+        # bursts of 8 steps over pages of 8: every burst fills a page a slot
+        for _ in range(3):
+            before = [_complete(s.num_tokens) for s in engine._slots[:2]]
+            engine._decode_all()
+            assert [_complete(s.num_tokens) for s in engine._slots[:2]] == [
+                j + 1 for j in before]
+            assert _slot_rows_are_the_tables(engine, homes) == sum(before) + 2
+        return
+    # a request waits and a slot is free: the engine steps ONE token a call
+    engine.submit(_prompt(40, 8), sp)
+    rose = []
+    for _ in range(9):
+        before = sum(_complete(s.num_tokens) for s in engine._slots[:2])
+        tokens = [s.num_tokens for s in engine._slots[:2]]
+        engine._decode_all()
+        assert [s.num_tokens for s in engine._slots[:2]] == [
+            t + 1 for t in tokens]
+        rose.append(_slot_rows_are_the_tables(engine, homes) - before)
+    # most steps complete no row and write none; one in eight a slot does
+    assert rose.count(0) >= 5 and 0 < sum(rose) <= 4
+
+
+def _elementwise_lists_from(sp, blocks, count, tables, n, width):
+    """``block_sparse.lists_from`` as it was before PR 52: a page id an
+    index (kept here as the reference of the form by block)."""
+    B, P = tables.shape
+    G = blocks.shape[1]
+    ppb = sp.block_size // sp.kernel_stride
+    pages = (blocks[..., None] * ppb + jnp.arange(ppb)).reshape(B, G, -1)
+    sparse = jnp.where(
+        pages < P, jnp.take_along_axis(
+            tables[:, None, :], jnp.minimum(pages, P - 1), axis=2), 0)
+    short = width - sparse.shape[-1]
+    sparse = (jnp.pad(sparse, ((0, 0), (0, 0), (0, short))) if short >= 0
+              else sparse[..., :width])
+    dense = jnp.pad(tables, ((0, 0), (0, max(0, width - P))))[:, None, :width]
+    under = (n <= sp.dense_len)[:, None]
+    held = (count - 1) * sp.block_size + ((n - 1) % sp.block_size + 1)[:, None]
+    lengths = jnp.where(under, n[:, None], held)
+    return (jnp.where(under[..., None], dense, sparse).astype(jnp.int32),
+            jnp.where((n > 0)[:, None], lengths, 0).astype(jnp.int32))
+
+
+# a context for each case (tables of 64 pages of 8, blocks of 4 pages,
+# topk 6, dense_len 256), and how many blocks the selection holds
+SELECTIONS = {
+    "fewer_than_topk_selected": (300, 4),  # the sentinel M behind them
+    "topk_selected": (411, CFG.topk),
+    "under_dense_len": (200, CFG.topk),  # the list is the table's own
+    "not_live": (0, 0),
+    "last_block_partly_filled": (293, CFG.topk),  # 5 of its 32 positions
+    "the_tables_last_block": (512, CFG.topk),
+}
+WIDTHS = {"width_below": CFG.topk * 4 - 8, "width_of_topk_blocks":
+          CFG.topk * 4, "width_above": block_sparse.list_width(CFG, 64)}
+
+
+@pytest.mark.parametrize("width", list(WIDTHS))
+@pytest.mark.parametrize("case", list(SELECTIONS))
+def test_lists_by_block_against_lists_by_page_id(case, width):
+    """``lists_from`` gathers the table a block (4 entries) an index: the
+    lists and lengths of the element-wise form it replaces, element for
+    element, over seeded selections: fewer than ``topk`` blocks (the
+    sentinel M), a slot under ``dense_len``, one that is not live, a last
+    block partly filled, the table's own last block, at a ``width`` under,
+    at and over ``topk`` blocks' pages."""
+    n, held = SELECTIONS[case]
+    P, ppb, K = 64, CFG.block_size // PS, CFG.topk
+    M = P // ppb
+    rng = np.random.default_rng(sum(map(ord, case + width)))
+    B = 3
+    ns = np.asarray([n, n, max(n - 1, 0) if n else 0], np.int32)
+    blocks = np.full((B, CFG.n_kv_heads, K), M, np.int32)
+    count = np.zeros((B, CFG.n_kv_heads), np.int32)
+    for b in range(B):
+        reached = -(-int(ns[b]) // CFG.block_size)
+        for g in range(CFG.n_kv_heads):
+            c = min(held, reached)
+            if c:  # the query's own block is always selected, the last
+                rest = rng.choice(reached - 1, c - 1, replace=False)
+                blocks[b, g, :c] = np.sort(np.append(rest, reached - 1))
+            count[b, g] = c
+    tables = np.zeros((B, P), np.int32)
+    for b in range(B):
+        m = -(-int(ns[b]) // PS)
+        tables[b, :m] = rng.permutation(np.arange(1, 300))[:m]
+    args = (CFG, jnp.asarray(blocks), jnp.asarray(count),
+            jnp.asarray(tables), jnp.asarray(ns), WIDTHS[width])
+    want = _elementwise_lists_from(*args)
+    got = block_sparse.lists_from(*args)
+    for w, g in zip(want, got):
+        assert w.shape == g.shape and w.dtype == g.dtype
+        assert np.array_equal(np.asarray(w), np.asarray(g))
+    if case == "fewer_than_topk_selected":
+        assert (np.asarray(got[0])[:, :, held * ppb:] == 0).all()
+    if case == "not_live":
+        assert not np.asarray(got[1]).any()
+
+
+def test_lists_by_block_want_a_table_of_whole_blocks():
+    """A table that ends inside a block is refused by name, as the suffix
+    prefill refuses it (the engine's tables are ``max_seq_len / page_size``
+    entries, whole blocks for every configuration it serves)."""
+    with pytest.raises(ValueError, match="whole number of blocks"):
+        block_sparse.lists_from(
+            CFG, jnp.zeros((1, 2, CFG.topk), jnp.int32),
+            jnp.ones((1, 2), jnp.int32), jnp.zeros((1, 63), jnp.int32),
+            jnp.asarray([300], jnp.int32), 24)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_page_lists_over_the_slot_order_and_through_the_tables(seed):
+    """``page_lists`` over each slot's rows in slot order (whatever lies
+    past the rows its context completes: here noise, in the engine the last
+    tenant's) against ``page_lists`` over ``pooled[li, tables]``: lists and
+    lengths equal element for element."""
+    rng = np.random.default_rng(seed)
+    B, P, pages = 5, 64, 300
+    ns = np.asarray([0, 200, 257, 390, 512], np.int32)
+    pooled = jnp.asarray(rng.standard_normal(
+        (pages, CFG.n_kv_heads, CFG.head_dim)), jnp.float32)
+    tables = np.zeros((B, P), np.int32)
+    for b in range(B):
+        m = -(-int(ns[b]) // PS)
+        tables[b, :m] = rng.permutation(np.arange(1, pages))[:m]
+    by_slot = rng.standard_normal(
+        (B, P, CFG.n_kv_heads, CFG.head_dim)).astype(np.float32)
+    for b in range(B):
+        j = _complete(int(ns[b]))
+        by_slot[b, :j] = np.asarray(pooled)[tables[b, :j]]
+    q = jnp.asarray(rng.standard_normal((B, CFG.n_heads, CFG.head_dim)),
+                    jnp.float32)
+    tables, ns = jnp.asarray(tables), jnp.asarray(ns)
+    width = block_sparse.list_width(CFG, P)
+    want = block_sparse.page_lists(CFG, q, pooled[tables], tables, ns, width)
+    got = block_sparse.page_lists(CFG, q, jnp.asarray(by_slot), tables, ns,
+                                  width)
+    for w, g in zip(want, got):
+        assert np.array_equal(np.asarray(w), np.asarray(g))
+    assert np.asarray(got[1])[0].tolist() == [0, 0]
+    assert (np.asarray(got[1])[3] == (CFG.topk - 1) * CFG.block_size
+            + (390 - 1) % CFG.block_size + 1).all()
+
+
+OTHER_FAMILIES = {
+    "llama": llama.LlamaConfig, "sdar_moe": sdar_moe.SDARMoEConfig,
+    "olmo_hybrid": olmo_hybrid.OlmoHybridConfig,
+    "glm_moe_lite": glm_moe_lite.GLMMoELiteConfig,
+    "afmoe": afmoe.AfmoeConfig}
+
+
+@pytest.mark.parametrize("name", list(OTHER_FAMILIES))
+def test_init_state_of_the_other_families_is_what_it_was(name):
+    """Only a family that declares ``page_rows`` gets rows in slot order:
+    at the sizes an engine hands over (``max_pages_per_seq`` among them)
+    the five other families' state is what it was, None or the declared
+    state rows [count, max_slots, *shape] key for key; this family's gains
+    the twin, [sparse layers, max_slots, max_pages_per_seq, G, d]."""
+    sizes = dict(num_pages=64, page_size=PS, max_slots=3,
+                 max_pages_per_seq=40)
+    layout = OTHER_FAMILIES[name].tiny().cache_layout()
+    if layout.get("window"):
+        sizes["window_pages"] = 8
+    state = init_state(CacheConfig(**layout, **sizes))
+    rows = layout.get("state_rows")
+    assert "page_rows" not in layout
+    if not rows:
+        assert state is None
+    else:
+        assert name == "olmo_hybrid" and sorted(state) == ["S", "conv"]
+        assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
+            k: ((count, 3, *shape), dt)
+            for k, (count, shape, dt) in rows.items()}
+    own = init_state(CacheConfig(**CFG.cache_layout(), **sizes))
+    assert {k: v.shape for k, v in own.items()} == {
+        "S": (3, 3, 4, 16, 16), "pooled_k": (2, 64, 2, 16),
+        "pooled_k_by_slot": (2, 3, 40, 2, 16)}

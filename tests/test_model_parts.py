@@ -195,12 +195,13 @@ def _windowed(program):
 
 def _sparse_linear(program):
     """``program`` of the dense tree over MiniCPM-SALA's caches: pools over
-    the sparse layers, the rows of pooled keys beside them and the linear
-    layers' state rows (pages of 8: a pooled row a page)."""
+    the sparse layers, the rows of pooled keys beside them (and again in
+    slot order, a row an entry of the programs' 4-entry tables) and the
+    linear layers' state rows (pages of 8: a pooled row a page)."""
     cfg = minicpm_sala.MiniCPMSALAConfig.tiny()
     params = cfg.serving_layout(minicpm_sala.init(cfg, jax.random.PRNGKey(0)))
     cc = CacheConfig(**lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
-                     dtype="float32", max_slots=4)
+                     dtype="float32", max_slots=4, max_pages_per_seq=4)
     ck, cv = init_cache(cc)
     fn, (_, tokens, _, _, *rest), _ = program()
     rows = {"state": init_state(cc)}

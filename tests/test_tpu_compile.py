@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
+from ray_tpu.llm.paged_cache import CacheConfig, init_state
 from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala,
                             olmo_hybrid, sdar_moe)
 from ray_tpu.ops import attention
@@ -558,8 +559,10 @@ def test_windowed_programs_compile_at_trinity_widths(topo, as_tpu, program):
 # threshold): 7.779 / 8.060 / 7.792 / 7.990, the parent 7.778 / 8.060 /
 # 7.790 / 7.990 (its [.., 400, 400] comparisons were fused into their sum:
 # 3.8 MB of temporaries in the decode step, 5.1 now)
-SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.779, 2048: 8.060,
-                            "prefix_256": 7.798, "prefix_2048": 7.990}
+# Re-read at PR 52 (+0.052 GB each: the pooled rows again in slot order,
+# [2, 32, 1600, 2, 128] bf16): 7.839 / 8.112 / 7.844 / 8.043
+SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.839, 2048: 8.112,
+                            "prefix_256": 7.844, "prefix_2048": 8.043}
 
 
 @pytest.mark.parametrize("program", ["decode_step_greedy", 2048,
@@ -592,12 +595,15 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     cache = sds((layout["n_layers"], 51201, 16, layout["n_kv_heads"],
                  layout["head_dim"]), jnp.bfloat16)
     assert cache.shape == (2, 51201, 16, 2, 128)
-    state = {**{name: sds((rows, 32, *shape), dt) for name, (rows, shape, dt)
-                in layout["state_rows"].items()},
-             **{name: sds((rows, 51201, *shape), dt) for name,
-                (rows, shape, dt) in layout["page_rows"].items()}}
+    # what the engine allocates from the family's declaration at the
+    # cell's sizes: the pooled rows a page AND their twin in slot order
+    state = _on(one, jax.eval_shape(lambda: init_state(CacheConfig(
+        **layout, num_pages=51201, page_size=16, dtype="bfloat16",
+        max_slots=32, max_pages_per_seq=1600))))
     assert state["S"].shape == (6, 32, 32, 128, 128)
     assert state["pooled_k"].shape == (2, 51201, 2, 128)
+    assert state["pooled_k_by_slot"].shape == (2, 32, 1600, 2, 128)
+    assert sorted(state) == ["S", "pooled_k", "pooled_k_by_slot"]
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     if program == "decode_step_greedy":
         compiled = lm.decode_step_greedy.lower(
@@ -622,8 +628,18 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     blocks = 1600 * 16 // cfg.block_size
     assert blocks == 400
     assert not re.findall(rf"\w+\[(?:\d+,)*{blocks},{blocks}\]", text)
+    if program == "decode_step_greedy":
+        # a slot's pooled rows are read where they lie and a list's page
+        # ids a block (4) an index: no gather of 32 x 1,600 rows through
+        # the tables, none of 32 x 2 x 256 single page ids
+        gathers = [line.split(" = ")[1] for line in text.splitlines()
+                   if " gather(" in line and " = " in line]
+        assert gathers
+        assert not [g for g in gathers if g.startswith((
+            "bf16[32,1600,2,128]", "f32[32,1600,2,128]", "s32[32,2,256]",
+            "s32[32,2,256,1]", "s32[16384"))], gathers
     held = (2 * 2 * 51201 * 16 * 2 * 128 * 2 + 6 * 32 * 32 * 128 * 128 * 4
-            + 2 * 51201 * 2 * 128 * 2)
+            + 2 * 51201 * 2 * 128 * 2 + 2 * 32 * 1600 * 2 * 128 * 2)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= held  # pools and rows held once
     planned = _footprint(compiled)
