@@ -103,6 +103,19 @@ def _engine_metrics():
                     "of denoising passes, decode steps and prefills read "
                     "(x 3 matrices = the grouped product's weight "
                     "traffic)"),
+                "moe_local_rows": Counter(
+                    "llm_moe_local_rows_total", "A chip's share of an "
+                    "expert-parallel layer: (token, expert) rows the "
+                    "experts HELD here computed, summed over layers "
+                    "(counted by the programs, on the device)"),
+                "moe_zero_picks": Counter(
+                    "llm_moe_zero_picks_total", "Of the router's picks, "
+                    "those on identity experts (no weights: the pick's "
+                    "weight times the layer's input), summed over layers"),
+                "moe_absent_picks": Counter(
+                    "llm_moe_absent_picks_total", "Of the router's picks, "
+                    "those on experts another chip holds, which add "
+                    "nothing here, summed over layers"),
                 "blocks_final": Counter(
                     "llm_blocks_final_total", "Blocks whose K/V a pass "
                     "over their mask-free tokens made final"),
@@ -734,7 +747,9 @@ class LLMEngine:
                        "decode_pages_read": 0, "latent_pages_read": 0,
                        "block_slot_passes": 0,
                        "masks_filled": 0, "blocks_final": 0,
-                       "experts_read": 0, "state_slot_steps": 0,
+                       "experts_read": 0, "moe_local_rows": 0,
+                       "moe_zero_picks": 0, "moe_absent_picks": 0,
+                       "state_slot_steps": 0,
                        "state_resets": 0, "scan_chunks": 0,
                        "tokens_generated": 0, "deliveries": 0,
                        "deliveries_behind_dispatch": 0, "preempted": 0,

@@ -32,7 +32,10 @@ way, which it hands the family's walk as one bundle ``via``:
 - ``attend_latent(q_nope, q_rope, row, a, (pool, None, li))``: latent rows
   into the ONE pool, then the prefills REBUILT (K and V made from the rows
   this call wrote or the page table reaches), the decode step ABSORBED
-  (``paged_latent_decode_attention``: K and V are never made);
+  (``paged_latent_decode_attention``: K and V are never made).  ``li`` is
+  a POOL layer, which need not be a scanned layer: models/longcat_flash.py's
+  scanned layer has two attention sublayers and writes pool layers ``2 i``
+  and ``2 i + 1`` (its ``cache_layout()`` declares twice its layers);
 - ``recur(mix, qkv, b, a, (state, li))``: ``prefill`` runs the chunked
   recurrence from a ZERO state and writes the slot's rows ONCE after the
   scan, the decode step updates every live slot's row in place.
@@ -84,8 +87,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, olmo_hybrid,
-                            sdar_moe)
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
+                            olmo_hybrid, sdar_moe)
 from ray_tpu.models.llama import embed, head
 from ray_tpu.ops import block_sparse, gated_delta, lightning
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
@@ -104,6 +107,8 @@ def serving_layout(params):
     in ``ray_tpu/llm/`` that recognises a family by its tree's keys."""
     if isinstance(params["layers"], tuple):  # minicpm_sala's, laid out
         return params  # (its layout takes the configuration's mixer_types)
+    if "first" in params["layers"]:  # a double layer's two sublayers
+        return longcat_flash.serving_layout(params)
     attn = params["layers"].get("attn", ())
     if "lin" in params["layers"]:
         return olmo_hybrid.serving_layout(params)

@@ -6,6 +6,14 @@ The block is the Llama family's pre-norm one, ``h = x + Attn(norm(x));
 y = h + FFN(norm(h))`` (models/llama.py ``layer``), with both halves its
 own, read off the parameters and not off a flag:
 
+The latent functions below (``latent_qkv``, ``rebuild_kv``, ``absorb``,
+``unabsorb``, ``latent_attention_block``, ``batch_attend``, the layout of a
+layer's ``attn`` leaves) are SHARED: models/longcat_flash.py attends with
+them at 64 heads of 128 + 64 / 128 and with both rank scales (``c_q`` and
+``c_kv`` times ``cfg.q_lora_scale`` / ``cfg.kv_lora_scale`` behind their
+norms; 1.0 here, where no product is emitted).  The head counts and widths
+in what follows are THIS model's.
+
 Latent attention (H = 20 heads; ``q_lora_rank`` 768, ``kv_lora_rank``
 r = 512, ``qk_nope_head_dim`` 192, ``qk_rope_head_dim`` 64, ``v_head_dim``
 256).  For a normed row x:
@@ -111,6 +119,10 @@ class GLMMoELiteConfig:
     rope_theta: float = 1_000_000.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
+    # a factor on c_q and on c_kv behind their norms (a model that scales
+    # its ranks to the stream's width, models/longcat_flash.py): none here
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     def __post_init__(self):
         if not 0 <= self.n_dense_layers < self.n_layers:
@@ -268,28 +280,31 @@ def serving_layout(params):
     ``w_a`` comes back as it is."""
     if "w_a" in params["layers"]["attn"]:
         return params
-
-    def lay_out(a):
-        a = dict(a)
-        (nl, q_rank), r = a["q_norm"].shape, a["kv_norm"].shape[-1]
-        dr = a["wkv_a"].shape[-1] - r
-        # wq_b: H (nope + dr) columns; wkv_b: H (nope + dv); wo: H dv rows
-        H = (a["wq_b"].shape[-1] - a["wkv_b"].shape[-1]
-             + a["wo"].shape[1]) // dr
-        dv = a["wo"].shape[1] // H
-        up = a.pop("wkv_b").reshape(nl, r, H, -1)
-        a["w_uk"] = up[..., :-dv].transpose(0, 2, 3, 1)
-        a["w_uv"] = up[..., -dv:].transpose(0, 2, 1, 3)
-        q = a.pop("wq_b").reshape(nl, q_rank, H, -1)
-        a["wq_up"] = jnp.concatenate(
-            [q[..., :-dr].reshape(nl, q_rank, -1),
-             q[..., -dr:].reshape(nl, q_rank, -1)], axis=-1)
-        a["w_a"] = jnp.concatenate([a.pop("wq_a"), a.pop("wkv_a")], axis=-1)
-        return a
-
     return {**params, **{
-        name: {**params[name], "attn": lay_out(params[name]["attn"])}
+        name: {**params[name],
+               "attn": lay_out_attention(params[name]["attn"])}
         for name in ("dense", "layers")}}
+
+
+def lay_out_attention(a):
+    """One stack of layers' ``attn`` leaves (leading axis the layers) as
+    ``serving_layout`` describes them."""
+    a = dict(a)
+    (nl, q_rank), r = a["q_norm"].shape, a["kv_norm"].shape[-1]
+    dr = a["wkv_a"].shape[-1] - r
+    # wq_b: H (nope + dr) columns; wkv_b: H (nope + dv); wo: H dv rows
+    H = (a["wq_b"].shape[-1] - a["wkv_b"].shape[-1]
+         + a["wo"].shape[1]) // dr
+    dv = a["wo"].shape[1] // H
+    up = a.pop("wkv_b").reshape(nl, r, H, -1)
+    a["w_uk"] = up[..., :-dv].transpose(0, 2, 3, 1)
+    a["w_uv"] = up[..., -dv:].transpose(0, 2, 1, 3)
+    q = a.pop("wq_b").reshape(nl, q_rank, H, -1)
+    a["wq_up"] = jnp.concatenate(
+        [q[..., :-dr].reshape(nl, q_rank, -1),
+         q[..., -dr:].reshape(nl, q_rank, -1)], axis=-1)
+    a["w_a"] = jnp.concatenate([a.pop("wq_a"), a.pop("wkv_a")], axis=-1)
+    return a
 
 
 # ---------------------------------------------------------------------------
@@ -310,8 +325,12 @@ def latent_qkv(cfg, p, h, positions):
             c_kv, k_r = jnp.split(h @ a["wkv_a"].astype(h.dtype), (r,),
                                   axis=-1)
         c_kv = rms_norm(c_kv, a["kv_norm"], cfg.norm_eps)
+        if cfg.kv_lora_scale != 1.0:
+            c_kv = c_kv * jnp.asarray(cfg.kv_lora_scale, c_kv.dtype)
     with jax.named_scope("mla/q_proj"):
         c_q = rms_norm(c_q, a["q_norm"], cfg.norm_eps)
+        if cfg.q_lora_scale != 1.0:
+            c_q = c_q * jnp.asarray(cfg.q_lora_scale, c_q.dtype)
         if "wq_up" in a:  # serving_layout: every head's nope, then the ropes
             q_nope, q_rope = (y.reshape(*h.shape[:-1], cfg.n_heads, -1)
                               for y in jnp.split(

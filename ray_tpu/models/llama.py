@@ -199,6 +199,9 @@ PARTS = (
     # layers' shared expert (models/moe.py)
     "mla/kv_down", "mla/q_proj", "mla/kv_up", "mla/absorb", "mla/attend",
     "mla/unabsorb", "moe/shared",
+    # models/moe.py ``dispatch_share``: the identity experts' picks, their
+    # summed weight times the layer's own input (no product)
+    "moe/zero",
     # models/afmoe.py's block: the RMS norm a head on q and k (a part of
     # its own there; the Qwen3 family's lies inside ``attn/qkv``), the
     # sigmoid gate on attention's output, and the sandwich block's two

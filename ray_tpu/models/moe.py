@@ -16,6 +16,9 @@ static capacity, tokens over it dropped, dispatch as einsums whose
 shardings give XLA the all-to-all.  ``routed_mlp`` is the serving one
 (models/sdar_moe.py through llm/model.py): dropless, assignments sorted by
 expert into ``ops/grouped_matmul``, no (tokens, experts, capacity) tensor.
+``dispatch_share`` is the same for ONE CHIP'S SHARE of an expert-parallel
+layer (models/longcat_flash.py): a router over more columns than the chip
+holds experts, some of them identity experts with no weights at all.
 """
 
 from __future__ import annotations
@@ -184,26 +187,33 @@ def moe_mlp(cfg: MoEConfig, x, router_w, experts):
 
 
 def route(h, router_w, top_k: int, renormalise: bool = True, bias=None,
-          scale=None):
+          scale=None, scoring: str | None = None):
     """The top_k experts of every row and their weights.  h: (N, D).
     Returns (weights (N, k) float32, experts (N, k) int32).
 
-    The scores are a float32 product (on the TPU a float32 matmul runs in
-    bf16 passes unless told otherwise).  Without ``bias``: softmax over the
-    experts, the top_k of it and, ``renormalise``, their weights made to sum
-    to one (Qwen3-MoE, Mixtral).  With ``bias`` (E,), a layer's
-    ``e_score_correction_bias`` (DeepSeek-V3's ``noaux_tc``, one group):
-    s = sigmoid(scores); the experts are the top_k of s + bias, the bias
-    steering the CHOICE only; their weights are s of the chosen, without it,
-    ``renormalise`` divided by their sum, then times ``scale``."""
+    Two things, apart.  HOW a column is scored (``scoring``): ``softmax``
+    over the columns or ``sigmoid`` of each; left out, softmax without a
+    bias and sigmoid with one, as the published models pair them
+    (Qwen3-MoE, Mixtral; DeepSeek-V3's ``noaux_tc``, one group).  WHETHER a
+    bias steers the choice (``bias`` (E,), a layer's
+    ``e_score_correction_bias``): the experts are the top_k of score +
+    bias, the bias for the CHOICE only; their weights are the scores of the
+    chosen, without it (models/longcat_flash.py scores by softmax AND
+    chooses with a bias).  Then, ``renormalise``, the weights divided by
+    their sum, and times ``scale``.  The scores are a float32 product (on
+    the TPU a float32 matmul runs in bf16 passes unless told otherwise)."""
+    softmax = (bias is None) if scoring is None else scoring == "softmax"
+    if scoring not in (None, "softmax", "sigmoid"):
+        raise ValueError(f"a router scores by softmax or by sigmoid, not by "
+                         f"{scoring!r}")
     with jax.named_scope("moe/route"):
         logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        s = (jax.nn.softmax(logits, axis=-1) if softmax
+             else jax.nn.sigmoid(logits))
         if bias is None:
-            top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                           top_k)
+            top_p, top_idx = jax.lax.top_k(s, top_k)
         else:
-            s = jax.nn.sigmoid(logits)
             _, top_idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
             top_p = jnp.take_along_axis(s, top_idx, axis=-1)
         if renormalise:
@@ -214,9 +224,11 @@ def route(h, router_w, top_k: int, renormalise: bool = True, bias=None,
 
 
 def row_tile(assignments: int, n_experts: int) -> int:
-    """Rows a tile of the grouped product: the rows an expert gets on
-    average, as a power of two between bf16's 16 sublanes and the MXU's
-    128 (a larger tile pads every expert's run further)."""
+    """Rows a tile of the grouped product: the rows one of the ``n_experts``
+    experts HELD gets on average of the ``assignments`` that land on them
+    (all of them where every expert is held), as a power of two between
+    bf16's 16 sublanes and the MXU's 128 (a larger tile pads every
+    expert's run further)."""
     per_expert = max(1, assignments // n_experts)
     return min(128, max(16, 1 << (per_expert.bit_length() - 1)))
 
@@ -310,15 +322,56 @@ def served_routed_walk(scan_layers, cfg, params, x, caches, positions, attend,
     return x, (cache_k, cache_v, state), {"experts_read": hit}, None
 
 
+def _lay_out(flat, e: int, tile: int, n_tiles: int, top_k: int,
+             spare: bool = False):
+    """The m assignments ``flat`` (each the index of an expert held, 0 ..
+    e - 1) sorted by expert into a padded layout of ``n_tiles`` tiles, an
+    expert's run padded to whole tiles.  ``spare``: an index of ``e`` means
+    NO expert held here; such assignments sort last, take no row and no
+    tile, and their place in the layout is past its end.  Returns (order,
+    sizes an index, row_sorted: the padded row of each sorted assignment,
+    src: the token a padded row reads, tile_expert, run_end: where each
+    held expert's padded run ends)."""
+    m = flat.shape[0]
+    order = jnp.argsort(flat, stable=True)  # by expert, then by token
+    sizes = jnp.bincount(flat, length=e + spare)
+    padded = -(-sizes // tile) * tile
+    if spare:
+        padded = padded.at[e].set(0)
+    run_end = jnp.cumsum(padded)
+    sorted_e = flat[order]
+    rank = jnp.arange(m) - (jnp.cumsum(sizes) - sizes)[sorted_e]
+    row_sorted = (run_end - padded)[sorted_e] + rank  # its padded row
+    # padded rows with no assignment read token 0; nothing reads them
+    # back
+    src = jnp.zeros(n_tiles * tile, jnp.int32)
+    if spare:  # the spare assignments' rows lie past the layout: dropped
+        src = src.at[jnp.where(sorted_e < e, row_sorted,
+                               n_tiles * tile)].set(
+            (order // top_k).astype(jnp.int32), mode="drop")
+        run_end = run_end[:e]
+    else:
+        src = src.at[row_sorted].set((order // top_k).astype(jnp.int32))
+    tile_expert = jnp.searchsorted(
+        run_end, jnp.arange(n_tiles) * tile, side="right")
+    # the tiles past the last run keep its expert: no block is fetched
+    tile_expert = jnp.minimum(
+        tile_expert, jnp.max(jnp.where(sizes[:e] > 0, jnp.arange(e), 0))
+        if spare else sorted_e[-1])
+    return order, sizes, row_sorted, src, tile_expert, run_end
+
+
 def dispatch(hf, weights, chosen, experts, layer):
     """sum_k weights[n, k] x expert chosen[n, k] of hf[n]: (N, D) -> ((N, D),
-    experts with a row at all, int32).
+    experts with a row at all, int32).  Every index in ``chosen`` is an
+    expert HELD in ``experts`` (``dispatch_share`` takes a router whose
+    columns reach further).
 
     The N x k assignments are sorted by expert; each expert's run is padded
     to whole row tiles and the padded layout is gathered from hf,
     multiplied tile by tile with the tile's expert (``grouped_mlp``), and
     gathered back with the weights.  The padded layout is sized for the
-    worst split (every expert's run ending one row into a tile).
+    worst split (every held expert's run ending one row into a tile).
     """
     from ray_tpu.ops.grouped_matmul import grouped_mlp
 
@@ -329,21 +382,8 @@ def dispatch(hf, weights, chosen, experts, layer):
     n_tiles = min(m, -(-(m + e * (tile - 1)) // tile))
     with jax.named_scope("moe/dispatch"):
         flat = chosen.reshape(m)  # assignment a = token * k + choice
-        order = jnp.argsort(flat, stable=True)  # by expert, then by token
-        sizes = jnp.bincount(flat, length=e)
-        padded = -(-sizes // tile) * tile
-        run_end = jnp.cumsum(padded)
-        sorted_e = flat[order]
-        rank = jnp.arange(m) - (jnp.cumsum(sizes) - sizes)[sorted_e]
-        row_sorted = (run_end - padded)[sorted_e] + rank  # its padded row
-        # padded rows with no assignment read token 0; nothing reads them
-        # back
-        src = jnp.zeros(n_tiles * tile, jnp.int32).at[row_sorted].set(
-            (order // top_k).astype(jnp.int32))
-        tile_expert = jnp.searchsorted(
-            run_end, jnp.arange(n_tiles) * tile, side="right")
-        # the tiles past the last run keep its expert: no block is fetched
-        tile_expert = jnp.minimum(tile_expert, sorted_e[-1])
+        order, sizes, row_sorted, src, tile_expert, run_end = _lay_out(
+            flat, e, tile, n_tiles, top_k)
         rows = hf[src]
     with jax.named_scope("moe/experts"):
         out = grouped_mlp(rows, experts["w_gate"], experts["w_up"],
@@ -355,6 +395,69 @@ def dispatch(hf, weights, chosen, experts, layer):
         out = out[row].reshape(n, top_k, d).astype(jnp.float32)
         return (jnp.einsum("nk,nkd->nd", weights, out).astype(hf.dtype),
                 jnp.sum(sizes > 0).astype(jnp.int32))
+
+
+# what ``dispatch_share`` counts on the device, in this order (one vector)
+SHARE_COUNTED = ("experts_read", "moe_local_rows", "moe_zero_picks",
+                 "moe_absent_picks")
+
+
+def dispatch_share(hf, weights, chosen, experts, layer, *, first: int,
+                   columns: int, identity: int):
+    """``dispatch`` for ONE CHIP'S SHARE of a layer whose router has more
+    columns than this chip holds experts: of the ``columns`` a row may be
+    sent to, ``first`` .. ``first + E - 1`` are the E experts HELD in
+    ``experts`` (index 0 there is column ``first``), the trailing
+    ``identity`` columns are IDENTITY experts (no weights: the output is
+    the pick's weight times the row itself, so it is computed where the
+    row lives), and every other column is an expert held on another chip:
+    such a pick adds nothing here, and nothing stands in for it.
+
+    (N, D) -> ((N, D), counted [4] int32 under ``SHARE_COUNTED``: held
+    experts with a row at all, rows the held experts computed, identity
+    picks, picks on absent experts).  Dropless: the padded layout is sized
+    for every pick landing on a held expert, the row tile for the share of
+    them that does with a uniform router; picks that take no expert sort
+    behind the held experts' runs and take no row."""
+    from ray_tpu.ops.grouped_matmul import grouped_mlp
+
+    (n, d), top_k = hf.shape, chosen.shape[1]
+    e = experts["w_gate"].shape[1]
+    if not 0 <= first <= columns - identity - e:
+        raise ValueError(
+            f"experts {first}..{first + e - 1} are not among the "
+            f"{columns - identity} columns that are experts with weights "
+            f"({columns} columns, the last {identity} identity)")
+    m = n * top_k
+    tile = row_tile(m * e // columns, e)
+    n_tiles = min(m, -(-(m + e * (tile - 1)) // tile))
+    with jax.named_scope("moe/dispatch"):
+        held = (chosen >= first) & (chosen < first + e)  # (N, k)
+        flat = jnp.where(held, chosen - first, e).reshape(m)
+        order, sizes, row_sorted, src, tile_expert, run_end = _lay_out(
+            flat, e, tile, n_tiles, top_k, spare=True)
+        rows = hf[src]
+    with jax.named_scope("moe/experts"):
+        out = grouped_mlp(rows, experts["w_gate"], experts["w_up"],
+                          experts["w_down"], tile_expert,
+                          run_end[-1] // tile, layer, tile=tile)
+    with jax.named_scope("moe/combine"):
+        # a pick with no held expert reads SOME row (its place is past the
+        # layout, the gather clips it); rows no tile computed are
+        # undefined, so what it read is cut off, not multiplied by 0
+        row = jnp.zeros(m, jnp.int32).at[order].set(
+            row_sorted.astype(jnp.int32))
+        out = jnp.where(held[..., None], out[row].reshape(n, top_k, d), 0)
+        out = jnp.einsum("nk,nkd->nd", weights, out.astype(jnp.float32))
+    with jax.named_scope("moe/zero"):
+        zero = chosen >= columns - identity
+        out = (out + jnp.sum(jnp.where(zero, weights, 0.0), axis=-1,
+                             keepdims=True) * hf.astype(jnp.float32))
+        n_local = jnp.sum(sizes[:e])
+        n_zero = jnp.sum(zero)
+        counted = jnp.stack([jnp.sum(sizes[:e] > 0), n_local, n_zero,
+                             m - n_local - n_zero]).astype(jnp.int32)
+    return out.astype(hf.dtype), counted
 
 
 def _layer(cfg: MoEConfig, carry, layer_params, positions, attn_impl, mesh,

@@ -11,8 +11,8 @@ import pytest
 
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala,
-                            olmo_hybrid, sdar_moe)
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
+                            minicpm_sala, olmo_hybrid, sdar_moe)
 
 VOCAB = 128
 
@@ -27,6 +27,11 @@ FAMILIES = {
                     "serve with"),
     "glm_moe_lite": (glm_moe_lite, glm_moe_lite.GLMMoELiteConfig.tiny(VOCAB),
                      "GLMMoELiteConfig caches latent rows"),
+    # (a chip's share of the experts; latent rows as glm_moe_lite's, and
+    # refused what it refuses, by the same sentences)
+    "longcat_flash": (longcat_flash, longcat_flash.LongCatFlashConfig.tiny(
+        VOCAB, n_experts_held=4, first_expert_held=8),
+        "LongCatFlashConfig caches latent rows"),
     "afmoe": (afmoe, afmoe.AfmoeConfig.tiny(VOCAB),
               "AfmoeConfig keeps a window layer's pages only while"),
     # (pages of 16 below: a pooled row a page, a block four pages)

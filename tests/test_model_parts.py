@@ -25,8 +25,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, minicpm_sala, moe,
-                            olmo_hybrid, sdar_moe)
+from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
+                            minicpm_sala, moe, olmo_hybrid, sdar_moe)
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -55,6 +55,9 @@ LATENT = (("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
            "mla/q_proj", "head") + ROUTED[-4:] + ("moe/shared",))
 LATENT_PREFILL = LATENT + ("mla/kv_up", "attn/attend")  # a head a head:
 # the repeat of K and V to the query heads is by one and leaves no operation
+# shortcut-connected double layers over latent rows, a chip's share of the
+# experts: no shared expert, the identity picks' add a part of its own
+SHORTCUT = LATENT[:-1] + ("moe/zero",)
 # window and full layers in one stack: gated attention with a QK norm of
 # its own part, sandwich norms, a leading dense layer then routed ones
 # beside a shared expert
@@ -177,6 +180,20 @@ def _latent(program):
     return fn, (params, tokens, pool, None, *rest), cfg
 
 
+def _shortcut(program):
+    """``program`` of the dense tree over a latent pool of TWO layers a
+    scanned layer, a share of the experts held."""
+    cfg = longcat_flash.LongCatFlashConfig.tiny(
+        n_experts_held=4, first_expert_held=8)
+    params = lm.serving_layout(longcat_flash.init(cfg, jax.random.PRNGKey(0)))
+    pool, none = init_cache(CacheConfig(
+        **lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+        dtype="float32"))
+    assert none is None and pool.shape[0] == 2 * cfg.n_layers
+    fn, (_, tokens, _, _, *rest), _ = program()
+    return fn, (params, tokens, pool, None, *rest), cfg
+
+
 def _windowed(program):
     """``program`` of the dense tree over a pool a kind of layer: the page
     ids and the page tables come a kind too."""
@@ -268,6 +285,10 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "latent_prefill": lambda: _latent(_prefill),
           "latent_prefill_with_prefix": lambda: _latent(_prefill_with_prefix),
           "latent_decode_step_greedy": lambda: _latent(_decode),
+          "shortcut_prefill": lambda: _shortcut(_prefill),
+          "shortcut_prefill_with_prefix":
+              lambda: _shortcut(_prefill_with_prefix),
+          "shortcut_decode_step_greedy": lambda: _shortcut(_decode),
           "windowed_prefill": lambda: _windowed(_prefill),
           "windowed_prefill_with_prefix":
               lambda: _windowed(_prefill_with_prefix),
@@ -296,6 +317,10 @@ EXPECTED = {
     "latent_prefill_with_prefix": LATENT_PREFILL,
     "latent_decode_step_greedy": LATENT + ("mla/absorb", "mla/attend",
                                            "mla/unabsorb", "sample"),
+    "shortcut_prefill": SHORTCUT + ("mla/kv_up", "attn/attend"),
+    "shortcut_prefill_with_prefix": SHORTCUT + ("mla/kv_up", "attn/attend"),
+    "shortcut_decode_step_greedy": SHORTCUT + ("mla/absorb", "mla/attend",
+                                               "mla/unabsorb", "sample"),
     "windowed_prefill": WINDOWED + ("attn/attend/repeat_kv",),
     "windowed_prefill_with_prefix": WINDOWED + ("attn/attend/repeat_kv",),
     "windowed_decode_step_greedy": WINDOWED + ("sample",),
@@ -355,6 +380,9 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
               "latent_prefill": {"moe_grouped_mlp"},
               "latent_decode_step_greedy": {"paged_latent_decode_attention",
                                             "moe_grouped_mlp"},
+              "shortcut_prefill": {"moe_grouped_mlp"},
+              "shortcut_decode_step_greedy": {
+                  "paged_latent_decode_attention", "moe_grouped_mlp"},
               "windowed_decode_step_greedy": {"paged_decode_attention",
                                               "moe_grouped_mlp"},
               "sparse_linear_decode_step_greedy": {"paged_decode_attention",
@@ -451,6 +479,8 @@ CACHE_LAYOUTS = {
         "state_rows": {"S": (3, (15, 96, 384), jnp.float32),
                        "conv": (9, (11520,), jnp.dtype("bfloat16"))}},
     "glm47_flash_serve_1chip": {"n_layers": 4, "latent_dim": 640},
+    # (its own key is ``num_layers``, 4 in the file: two pool layers each)
+    "longcat_flash_serve_1chip": {"n_layers": 8, "latent_dim": 640},
     # (the last four of its five layers: three window layers and the full)
     "trinity_mini_serve_1chip": {"n_layers": 1, "n_kv_heads": 4,
                                  "head_dim": 128, "window_layers": 3,
