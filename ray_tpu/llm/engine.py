@@ -166,6 +166,17 @@ def _engine_metrics():
                     "but the last of the prompt) and by decode steps (the "
                     "step that fills a page), summed over sparse layers "
                     "(counted by the programs, on the device)"),
+                "sparse_prefill_tiles_causal": Counter(
+                    "llm_sparse_prefill_tiles_causal_total", "Sparse "
+                    "layers: tiles of keys that a tile of a prefill's "
+                    "queries reaches (at or under its last position, "
+                    "under the prompt's end), summed over query tiles, KV "
+                    "heads and sparse layers (counted by the program, on "
+                    "the device, from the table its kernel is handed)"),
+                "sparse_prefill_tiles_visited": Counter(
+                    "llm_sparse_prefill_tiles_visited_total", "Of those, "
+                    "tiles in which a query selected a block it sees: "
+                    "the ones the prefills' kernel copies and multiplies"),
                 "prefill_chunks": Counter(
                     "llm_prefill_chunks_total", "Prefill executions that "
                     "computed one chunk of a prompt longer than the "
@@ -762,7 +773,9 @@ class LLMEngine:
                        "window_pages_skipped": 0, "full_pages_read": 0,
                        "sparse_blocks_selected": 0, "sparse_pages_read": 0,
                        "sparse_pages_resident": 0,
-                       "dense_rule_slot_steps": 0, "index_rows_written": 0}
+                       "dense_rule_slot_steps": 0, "index_rows_written": 0,
+                       "sparse_prefill_tiles_causal": 0,
+                       "sparse_prefill_tiles_visited": 0}
         # Hit-aware admission (ISSUE 14): under pool pressure prefer the
         # waiting request whose prefix is resident, but never once the
         # head of the queue has waited longer than this cap (seconds) —

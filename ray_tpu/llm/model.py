@@ -54,14 +54,17 @@ way, which it hands the family's walk as one bundle ``via``:
   step and the suffix prefill READ a sequence's rows in slot order (no
   gather through the table); the page order is kept for whoever finds
   rows by page id.  Then the choice of blocks a query: both
-  prefills attend by KEY BLOCK under the chosen blocks' mask with a running
-  softmax (``block_sparse.selected_attention``: no [L, context] score
-  matrix is ever whole), the decode step hands ``paged_decode_attention`` a
-  LIST of pages a slot a KV head.  A third thing comes back beside the
-  pools, what the call COUNTED on the device (``block_sparse.walked`` of
-  the lists the kernel was handed, the pooled rows completed): the walk
-  sums it over the sparse layers into the program's ``counted``, one
-  vector under the tuple of its names.
+  prefills attend through ONE Pallas kernel under the chosen blocks' mask
+  (``block_sparse.selected_attention``: a tile of scores, a tile of queries
+  with their KV head's 16 query heads against a tile of keys, lives in
+  VMEM and nowhere else; a tile of keys over the diagonal, past the
+  prompt's end or in which no query of the tile selected a block has no
+  grid work), the decode step hands ``paged_decode_attention`` a LIST of
+  pages a slot a KV head.  A third thing comes back beside the pools, what
+  the call COUNTED on the device (``block_sparse.walked`` of the lists the
+  decode kernel was handed, the key tiles the prefills' kernel was handed,
+  the pooled rows completed): the walk sums it over the sparse layers into
+  the program's ``counted``, one vector under the tuple of its names.
 
 A FAMILY (its configuration class in ``models/``) owns WHAT a row is, how
 its layers are walked and what it refuses, and says so once:
@@ -339,12 +342,13 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
             by_slot = jax.lax.dynamic_update_slice(
                 by_slot, rows[None, None, :min(L // ps, by_slot.shape[2])],
                 (li, slot, 0, 0, 0))
-        out = block_sparse.selected_attention(
+        out, tiles = block_sparse.selected_attention(
             cfg, q, positions, rows,
             lambda at, n: (jax.lax.dynamic_slice_in_dim(kp, at, n),
                            jax.lax.dynamic_slice_in_dim(vp, at, n)),
             T, true_len)
         return out, (ck, cv, (by_page, by_slot)), {
+            **tiles,
             "index_rows_written": block_sparse.rows_complete(cfg, true_len)}
 
     def recur_fixed(q, k, v, g, rows):  # q, k, v: [L, H, d]; g: [H]
@@ -499,9 +503,10 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                 f"a page table of {P * page_size} positions is no whole "
                 f"number of blocks of {cfg.block_size}")
         ends = positions[0] + true_len
-        out = block_sparse.selected_attention(cfg, q, positions, rows,
-                                              keys_of, P * page_size, ends)
+        out, tiles = block_sparse.selected_attention(
+            cfg, q, positions, rows, keys_of, P * page_size, ends)
         return out, (ck, cv, (by_page, by_slot)), {
+            **tiles,
             "index_rows_written": block_sparse.rows_complete(cfg, ends)
             - block_sparse.rows_complete(cfg, positions[0])}
 

@@ -1,6 +1,6 @@
 """Which cached blocks a query attends to: InfLLM-v2's selection (MiniCPM4,
 arXiv:2506.07900) in plain ``jax.numpy``, for the prefills and the decode
-step alike.
+step alike, and the prefills' attention under it, a Pallas kernel.
 
 Sizes (``sp``: anything with these attributes; models/minicpm_sala.py's
 configuration): a POOLED KEY is the mean of ``kernel_size`` keys and there
@@ -25,11 +25,21 @@ a decode step's kernel walks (``paged_decode_attention`` with
 ``heads_apart``): the selected blocks' pages in order with a count, so that
 a slot under ``dense_len`` (all its pages) and one past it (``topk``
 blocks') go through one list.  ``selected_attention`` is the prefills'
-attention under the chosen blocks' mask (``masks`` the choice,
-``attend_under`` the attention, keys a block at a time); ``walked`` counts
-what a step's lists held, ``rows_complete`` the pooled rows a context
-holds: the engine's counters are these, made on the device from what the
-kernel is handed, and nobody re-derives the rule to count by.
+attention under the chosen blocks' mask: ``masks`` the choice,
+``attend_under`` the attention, a kernel gridded over (KV head, tile of
+queries, tile of keys) whose score tile, the KV head's query heads as rows
+of ONE product, lives in VMEM and nowhere else (in ``jax.numpy`` a turn's
+[G, rep, L, 512] float32 scores went to HBM and back three times: 0.56 ms
+a turn of 512 keys at a seventh of the MXU's rate, my chip run, PR 55).
+The mask of a tile is made inside it from ``picked`` and ``positions``;
+``tile_table`` lists, from the same two, the key tiles a query tile works
+on, and the others (over the diagonal, past ``ends``, or with no block
+that a query of the tile both sees and selected) get no copy and no
+product.  ``walked`` counts what a step's lists held, ``rows_complete``
+the pooled rows a context holds, ``selected_attention``'s second return
+the tiles of that table: the engine's counters are these, made on the
+device from what the kernel is handed, and nobody re-derives the rule to
+count by.
 """
 
 from __future__ import annotations
@@ -39,9 +49,25 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# positions a key block of ``attend_under`` holds at most
-KEY_BLOCK = 512
+from ray_tpu.ops.attention import _LANES, NEG_INF, _dot
+
+# positions a tile of keys of ``attend_under`` holds at most, and rows (a
+# tile's queries x the query heads of a KV head) a tile of its scores.  At
+# 2,048 x 1,024 the float32 scores are 8 MB, and with ``p``, the operands'
+# double buffers and the accumulators the kernel needs 20 MB, more than the
+# 16 MiB it gets by default (a v5e core has 128 MiB of VMEM).  A grid step
+# costs ~3 us whatever its keys (the accumulators' rescaling, the row
+# statistics) and 5.3 us a 1,024 keys, the MXU's own rate: a layer a chunk
+# of 2,048 behind 6,144 took 6.06 / 3.47 / 2.46 ms at 256 / 512 / 1,024
+# keys, 3.99 / 3.47 / 3.12 at 1,024 / 2,048 / 4,096 rows of 512 (my chip
+# run, PR 55; PERF.md section 6)
+KEY_BLOCK = 1024
+_TILE_ROWS = 2048
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+_M_FLOOR = -1e29  # where a row's running max begins: over a masked score
 
 _FORCED, _OUT = 1e4, -1e4  # a score is in [-1, heads a group]
 
@@ -271,56 +297,189 @@ def masks(sp, q, positions, rows, T: int):
              L, rows.shape[1], T // sp.block_size)
 
 
+def _tile_sizes(L: int, T: int, rep: int, bs: int) -> tuple[int, int]:
+    """(queries, keys) of a score tile for L queries over T key positions:
+    powers of two that divide them, as many queries as keep the group's
+    ``rep`` heads within ``_TILE_ROWS`` rows of one product, as many whole
+    blocks of ``bs`` keys as ``KEY_BLOCK`` holds (and one lane tile of
+    ``picked`` has: a tile's blocks lie in one)."""
+    def power(n):  # the largest power of two at or under n
+        return 1 << max(n, 1).bit_length() - 1
+
+    return (math.gcd(L, power(_TILE_ROWS // rep)),
+            math.gcd(T // bs, power(min(KEY_BLOCK // bs, _LANES))) * bs)
+
+
+def tile_table(sp, positions, picked, ends, tq: int, tk: int):
+    """What the kernel is handed: for each KV head and tile of ``tq``
+    queries, the tiles of ``tk`` keys it works on.  A key tile is REACHED
+    where a query of the tile sees one of its positions (at or under its
+    own, under ``ends``), VISITED where one also selected the block it lies
+    in.  Returns (tiles [G, L / tq, T / tk] int32: the visited tiles in
+    ascending order, then the last of them again; count [G, L / tq]: how
+    many; reached [L / tq])."""
+    L, G, M = picked.shape
+    nb = tk // sp.block_size
+    n_q, n_k = L // tq, M // nb
+    first = jnp.arange(M) * sp.block_size  # a block's first position
+    seen = (first <= positions[:, None]) & (first < ends)  # [L, M]
+    reached = seen.reshape(n_q, tq, n_k, nb).any(axis=(1, 3)).sum(axis=-1)
+    visited = (picked & seen[:, None]).reshape(
+        n_q, tq, G, n_k, nb).any(axis=(1, 4)).transpose(1, 0, 2)
+    before = jnp.cumsum(visited, axis=-1)  # visited tiles up to each
+    count = before[..., -1]
+    step = jnp.minimum(jnp.arange(n_k), jnp.maximum(count[..., None] - 1, 0))
+    # the tile of step s: as many tiles as have at most s visited up to them
+    tiles = (before[..., None, :] <= step[..., None]).sum(axis=-1)
+    return jnp.minimum(tiles, n_k - 1), count, reached
+
+
+def _attend_kernel(tiles_ref, count_ref, ends_ref, q_ref, pos_ref, pick_ref,
+                   k_ref, v_ref, o_ref, qs_ref, acc_ref, m_ref, l_ref, *,
+                   bs: int, sm_scale: float):
+    """One (KV head, query tile, step) of the running softmax.  The group's
+    heads are ROWS of the one product, head-major (row r tq + i is query i
+    of head r), so the mask of a tile is made once, [tq, tk], and every
+    head's scores take it as they are."""
+    g, i, s = (pl.program_id(a) for a in range(3))
+    n_q, n_k = pl.num_programs(1), pl.num_programs(2)
+    rep, tq, d = acc_ref.shape
+    tk = k_ref.shape[0]
+    at = g * n_q + i
+
+    @pl.when(s == 0)
+    def _init():
+        for r in range(rep):
+            qs_ref[r * tq:(r + 1) * tq] = q_ref[:, r * d:(r + 1) * d]
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _M_FLOOR)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(s < count_ref[at])
+    def _fold():
+        j = tiles_ref[at * n_k + s]
+        # a query's choice of the tile's blocks, spread over their keys by
+        # a product: pick_ref holds the lane tile of blocks this key tile's
+        # lie in, one number a query and block
+        lane, col = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, tk), a)
+                     for a in (0, 1))
+        spread = (lane == (j * (tk // bs)) % _LANES + col // bs)
+        picked = _dot(pick_ref[...], spread.astype(pick_ref.dtype), 0)
+        key = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        keep = (picked > 0.5) & (key <= pos_ref[...]) & (key < ends_ref[0])
+        v = v_ref[...]
+        scores = (_dot(qs_ref[...], k_ref[...], 1) * sm_scale).reshape(
+            rep, tq, tk) + jnp.where(keep, 0.0, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)  # 0 where masked: m_new >= _M_FLOOR
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(
+            p.astype(v.dtype).reshape(rep * tq, tk), v, 0).reshape(
+                rep, tq, d)
+        m_ref[...] = m_new
+
+    @pl.when(s == n_k - 1)
+    def _finish():
+        l = l_ref[...]
+        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        for r in range(rep):
+            o_ref[:, r * d:(r + 1) * d] = out[r].astype(o_ref.dtype)
+
+
+@partial(jax.jit, static_argnames=("sp", "tile", "interpret"))
+def _attend(sp, q, positions, picked, k, v, ends, *, tile: tuple[int, int],
+            interpret: bool):
+    """``attend_under`` over the keys whole (k, v [T, G, d]) in score
+    tiles of ``tile`` (queries, keys) -> (out, the tiles it worked on by
+    counter name)."""
+    L, H, d = q.shape
+    T, G, _ = k.shape
+    rep, bs = H // G, sp.block_size
+    tq, tk = tile
+    n_q, n_k = L // tq, T // tk
+    tiles, count, reached = tile_table(sp, positions, picked, ends, tq, tk)
+    # a query's choice a KV head as numbers, whole lane tiles of blocks
+    pick = jnp.pad(picked.transpose(1, 0, 2).astype(jnp.bfloat16),
+                   ((0, 0), (0, 0), (0, -picked.shape[2] % _LANES)))
+
+    def key_tile(g, i, s, tiles_ref, *_):
+        return tiles_ref[(g * n_q + i) * n_k + s]
+
+    def queries(g, i, s, *_):
+        return i, g
+
+    def keys(g, i, s, *refs):
+        return key_tile(g, i, s, *refs), g
+
+    out = pl.pallas_call(
+        partial(_attend_kernel, bs=bs, sm_scale=d ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(G, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((tq, rep * d), queries),
+                pl.BlockSpec((tq, 1), lambda g, i, s, *_: (i, 0)),
+                pl.BlockSpec((None, tq, _LANES), lambda g, i, s, *refs: (
+                    g, i, key_tile(g, i, s, *refs) * (tk // bs) // _LANES)),
+                pl.BlockSpec((tk, d), keys),
+                pl.BlockSpec((tk, d), keys),
+            ],
+            out_specs=pl.BlockSpec((tq, rep * d), queries),
+            scratch_shapes=[pltpu.VMEM((rep * tq, d), q.dtype),
+                            pltpu.VMEM((rep, tq, d), jnp.float32),
+                            pltpu.VMEM((rep, tq, 1), jnp.float32),
+                            pltpu.VMEM((rep, tq, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((L, H * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="sparse_prefill_attention",
+    )(tiles.reshape(-1), count.reshape(-1),
+      jnp.asarray(ends, jnp.int32).reshape(1), q.reshape(L, H * d),
+      positions.astype(jnp.int32)[:, None], pick,
+      k.astype(q.dtype).reshape(T, G * d), v.reshape(T, G * d))
+    return out.reshape(L, H, d), {
+        "sparse_prefill_tiles_causal": G * reached.sum(),
+        "sparse_prefill_tiles_visited": count.sum()}
+
+
+def _attend_over(sp, q, positions, picked, keys_of, T: int, ends):
+    """``attend_under`` and what its kernel's table counted."""
+    k, v = keys_of(0, T)
+    return _attend(
+        sp, q, positions, picked, k, v, ends,
+        tile=_tile_sizes(q.shape[0], T, q.shape[1] // k.shape[1],
+                         sp.block_size),
+        interpret=jax.default_backend() != "tpu")
+
+
 def attend_under(sp, q, positions, picked, keys_of, T: int, ends):
     """Attention of q [L, H, d] at ``positions`` [L] over the blocks
     ``picked`` [L, G, T / block] allows each query and KV head, causal
-    inside them.  ``keys_of(at, n) -> (k, v)`` [n, G, d]: the keys at
-    positions [at, at + n); T (static): positions the keys span, whole
-    blocks; ``ends``: one past the last position any query sees.  The keys
-    come a block of ``KEY_BLOCK`` positions at a time under a running
-    softmax, as far as ``ends``: scores are [H, L, KEY_BLOCK] at any
-    instant."""
-    L, H, d = q.shape
-    G, bs = picked.shape[1], sp.block_size
-    rep, f32 = H // G, jnp.float32
-    kb = math.gcd(T, KEY_BLOCK)
-    kb = kb if kb % bs == 0 else T
-    qg = q.reshape(L, G, rep, d)
-
-    def block(i, carry):
-        m, l, acc = carry
-        k, v = keys_of(i * kb, kb)
-        s = jnp.einsum("qgrd,kgd->grqk", qg, k.astype(q.dtype),
-                       preferred_element_type=f32) * d ** -0.5
-        seen = jnp.repeat(jax.lax.dynamic_slice_in_dim(
-            picked, i * (kb // bs), kb // bs, axis=2), bs, axis=2)
-        seen &= (i * kb + jnp.arange(kb))[None, None, :] \
-            <= positions[:, None, None]
-        seen = seen.transpose(1, 0, 2)[:, None]  # [G, 1, L, kb]
-        s = jnp.where(seen, s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
-                acc * alpha + jnp.einsum(
-                    "grqk,kgd->grqd", p.astype(v.dtype), v,
-                    preferred_element_type=f32))
-
-    # block 0 holds position 0, which every query attends to (the first
-    # block is always selected), so l > 0 from the first on
-    _, l, acc = jax.lax.fori_loop(
-        0, jnp.clip((ends + kb - 1) // kb, 1, T // kb), block,
-        (jnp.full((G, rep, L, 1), -1e30, f32),
-         jnp.zeros((G, rep, L, 1), f32), jnp.zeros((G, rep, L, d), f32)))
-    return (acc / l).transpose(2, 0, 1, 3).reshape(L, H, d).astype(q.dtype)
+    inside them and under ``ends``, one past the last position any query
+    sees.  ``keys_of(at, n) -> (k, v)`` [n, G, d]: the keys at positions
+    [at, at + n), taken once, whole (T, static: the positions they span,
+    whole blocks).  A Pallas kernel (``_attend_kernel``) under a running
+    softmax: a tile of scores lives in fast memory and nowhere else, and a
+    tile of keys that no query of a tile of queries both sees and selected
+    is neither copied nor multiplied (``tile_table``)."""
+    return _attend_over(sp, q, positions, picked, keys_of, T, ends)[0]
 
 
 def selected_attention(sp, q, positions, rows, keys_of, T: int, ends):
     """A prefill's attention over the blocks the rule selects for each
     query and KV head: ``masks`` (part ``sparse_attn/index``) then
     ``attend_under`` (``sparse_attn/attend``); rows [T / stride, G, d] the
-    sequence's pooled keys."""
+    sequence's pooled keys.  Returns (out, counted): beside the attention,
+    int32 sums over the kernel's own table of tiles by the engine's counter
+    names, ``sparse_prefill_tiles_causal`` (key tiles a query tile's causal
+    bound and ``ends`` reach, a KV head) and
+    ``sparse_prefill_tiles_visited`` (those of them in which a query
+    selected a block it sees: the ones the kernel works on)."""
     with jax.named_scope("sparse_attn/index"):
         picked = masks(sp, q, positions, rows, T)
     with jax.named_scope("sparse_attn/attend"):
-        return attend_under(sp, q, positions, picked, keys_of, T, ends)
+        return _attend_over(sp, q, positions, picked, keys_of, T, ends)

@@ -673,6 +673,150 @@ def test_the_slot_order_holds_what_the_tables_reach(params, case):
     assert rose.count(0) >= 5 and 0 < sum(rose) <= 4
 
 
+def _attend_by_key_block(sp, q, positions, picked, keys_of, T, ends):
+    """``block_sparse.attend_under`` as it was before PR 55, plain
+    ``jax.numpy``: a ``fori_loop`` over key blocks of 512 positions under a
+    running softmax, a block's scores [G, rep, L, 512] whole (kept here as
+    the reference of the kernel)."""
+    import math
+
+    L, H, d = q.shape
+    G, bs = picked.shape[1], sp.block_size
+    rep, f32 = H // G, jnp.float32
+    kb = math.gcd(T, 512)
+    kb = kb if kb % bs == 0 else T
+    qg = q.reshape(L, G, rep, d)
+
+    def block(i, carry):
+        m, l, acc = carry
+        k, v = keys_of(i * kb, kb)
+        s = jnp.einsum("qgrd,kgd->grqk", qg, k.astype(q.dtype),
+                       preferred_element_type=f32) * d ** -0.5
+        seen = jnp.repeat(jax.lax.dynamic_slice_in_dim(
+            picked, i * (kb // bs), kb // bs, axis=2), bs, axis=2)
+        seen &= (i * kb + jnp.arange(kb))[None, None, :] \
+            <= positions[:, None, None]
+        seen = seen.transpose(1, 0, 2)[:, None]  # [G, 1, L, kb]
+        s = jnp.where(seen, s, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + jnp.einsum(
+                    "grqk,kgd->grqd", p.astype(v.dtype), v,
+                    preferred_element_type=f32))
+
+    _, l, acc = jax.lax.fori_loop(
+        0, jnp.clip((ends + kb - 1) // kb, 1, T // kb), block,
+        (jnp.full((G, rep, L, 1), -1e30, f32),
+         jnp.zeros((G, rep, L, 1), f32), jnp.zeros((G, rep, L, d), f32)))
+    return (acc / l).transpose(2, 0, 1, 3).reshape(L, H, d).astype(q.dtype)
+
+
+# (queries L, key positions T, the first query's position, real queries,
+# blocks nobody may select, dtype): contexts 1..512, ``dense_len`` 256
+ATTEND_CASES = {
+    "first_chunk_under_dense_len": (128, 128, 0, 128, None, "float32"),
+    "behind_a_prefix_past_dense_len": (64, 512, 400, 64, None, "float32"),
+    "a_chunk_across_dense_len": (64, 512, 224, 64, None, "float32"),
+    "padded_last_chunk": (64, 512, 300, 40, None, "float32"),
+    "whole_key_tiles_unselected": (64, 512, 400, 64, (2, 6), "float32"),
+    "no_bucket_three_blocks": (96, 96, 0, 96, None, "float32"),
+    "bfloat16_products": (64, 512, 400, 64, None, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", list(ATTEND_CASES))
+def test_the_prefills_kernel_against_attention_by_key_block(case,
+                                                            monkeypatch):
+    """``attend_under`` (Pallas, through the interpreter here) against the
+    ``jax.numpy`` body it replaces on every real query's row, the offset
+    and the end TRACED as the suffix prefill hands them; and the two sums
+    ``selected_attention`` hands back are the counts of the kernel's own
+    table, by their definition over (KV head, query tile, key tile).  The
+    test, not the program, makes the tiles small (16 queries, 2 blocks of
+    keys), so that a call has several of each and some to skip."""
+    L, T, start, real, barred, dtype = ATTEND_CASES[case]
+    monkeypatch.setattr(block_sparse, "KEY_BLOCK", 2 * CFG.block_size)
+    monkeypatch.setattr(block_sparse, "_TILE_ROWS",
+                        16 * CFG.n_heads // CFG.n_kv_heads)
+    H, G, d, bs = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim, CFG.block_size
+    tq, tk = block_sparse._tile_sizes(L, T, H // G, bs)
+    assert (tq, tk) == (16, 32 if T == 96 else 64)
+    keys = jax.random.split(jax.random.PRNGKey(sum(map(ord, case))), 3)
+    q, k, v = (jax.random.normal(key, shape, jnp.float32).astype(dtype)
+               for key, shape in zip(keys, ((L, H, d), (T, G, d), (T, G, d))))
+    rows = block_sparse.pool_keys(CFG, k).astype(k.dtype)
+
+    @jax.jit
+    def both(start, ends):
+        positions = start + jnp.arange(L)
+        picked = block_sparse.masks(CFG, q, positions, rows, T)
+        if barred:
+            picked = picked.at[:, :, slice(*barred)].set(False)
+        keys_of = lambda at, n: (  # noqa: E731
+            jax.lax.dynamic_slice_in_dim(k, at, n),
+            jax.lax.dynamic_slice_in_dim(v, at, n))
+        args = (CFG, q, positions, picked, keys_of, T, ends)
+        with jax.named_scope("sparse_attn/attend"):
+            got = block_sparse.attend_under(*args)
+        return (_attend_by_key_block(*args), got, picked,
+                block_sparse._attend_over(*args))
+
+    want, got, picked, (again, counted) = both(jnp.int32(start),
+                                               jnp.int32(start + real))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.array_equal(np.asarray(got), np.asarray(again))
+    err = np.abs(np.asarray(got[:real], np.float32)
+                 - np.asarray(want[:real], np.float32)).max()
+    assert err < (2e-2 if dtype == "bfloat16" else 1e-5)
+    # the table by its definition: a key tile is reached where a query of
+    # the tile sees a position of it, visited where one also selected the
+    # block that position lies in
+    pos = start + np.arange(L)
+    sees = ((np.arange(T) <= pos[:, None])
+            & (np.arange(T) < start + real))  # [L, T]
+    chose = np.repeat(np.asarray(picked), bs, axis=2)  # [L, G, T]
+    by_tile = lambda x: x.reshape(  # noqa: E731
+        L // tq, tq, -1, T // tk, tk).any(axis=(1, 4))
+    reached = by_tile(np.broadcast_to(sees[:, None], chose.shape))
+    visited = by_tile(chose & sees[:, None])
+    assert int(counted["sparse_prefill_tiles_causal"]) == reached.sum()
+    assert int(counted["sparse_prefill_tiles_visited"]) == visited.sum()
+    assert not (visited & ~reached).any() and visited[..., 0].all()
+    if barred:  # blocks 2-5 are key tiles 1 and 2, whole
+        assert reached[..., 1:3].all() and not visited[..., 1:3].any()
+    # no tile at or past the end, whatever a padded query's position
+    assert not reached[..., -(-(start + real) // tk):].any()
+
+
+def test_prefill_spans_carry_the_tiles_the_kernel_was_handed(params,
+                                                              monkeypatch):
+    """A sampled loop: every ``llm.prefill`` span of a prompt in chunks
+    names the key tiles its sparse layers' kernel reached and worked on,
+    and they add up to the counters.  At these sizes a chunk's call is ONE
+    tile a KV head a sparse layer (a bucket of 128 queries; 128 keys for
+    the first chunk, the table's 512 behind a prefix)."""
+    from ray_tpu.util import tracing
+
+    recs = []
+    monkeypatch.setenv("RTPU_TRACE_SAMPLE", "1.0")
+    orig = tracing._record
+    monkeypatch.setattr(tracing, "_record",
+                        lambda r: (recs.append(r), orig(r))[1])
+    engine = _engine(params)
+    with tracing.serving_span("openai.request", path="/v1/x"):
+        engine.generate(_prompt(300, 4), SamplingParams(max_tokens=2))
+    stats = engine.stats()
+    engine.stop()
+    chunks = [r["args"] for r in recs if r["name"] == "llm.prefill"]
+    assert len(chunks) == 3
+    names = ("sparse_prefill_tiles_causal", "sparse_prefill_tiles_visited")
+    for name in names:
+        assert [c[name] for c in chunks] == [2 * CFG.n_kv_heads] * 3
+        assert stats[name] == sum(c[name] for c in chunks)
+
+
 def _elementwise_lists_from(sp, blocks, count, tables, n, width):
     """``block_sparse.lists_from`` as it was before PR 52: a page id an
     index (kept here as the reference of the form by block)."""

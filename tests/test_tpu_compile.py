@@ -28,7 +28,7 @@ from ray_tpu.llm.paged_cache import CacheConfig, init_state
 from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
                             minicpm_sala,
                             olmo_hybrid, sdar_moe)
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, block_sparse
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
     default_optimizer,
@@ -661,8 +661,14 @@ def test_windowed_programs_compile_at_trinity_widths(topo, as_tpu, program):
 # 3.8 MB of temporaries in the decode step, 5.1 now)
 # Re-read at PR 52 (+0.052 GB each: the pooled rows again in slot order,
 # [2, 32, 1600, 2, 128] bf16): 7.839 / 8.112 / 7.844 / 8.043
-SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.839, 2048: 8.112,
-                            "prefix_256": 7.844, "prefix_2048": 8.043}
+# Re-read at PR 55 (the prefills attend through a Pallas kernel): 7.839 /
+# 8.114 / 7.863 / 8.112.  The suffix prefills plan MORE, not less (+0.019 /
+# +0.069 GB): the kernel takes the slot's keys and values whole, [25,600,
+# 2, 128] bf16 twice = 26 MB a layer, where the loop gathered 512 positions
+# a turn, and the turn's [2, 16, 2,048, 512] float32 scores (134 MB) were
+# never what set a prefill's peak (the MLP's [2,048, 16,384] products are)
+SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.839, 2048: 8.114,
+                            "prefix_256": 7.863, "prefix_2048": 8.112}
 
 
 @pytest.mark.parametrize("program", ["decode_step_greedy", 2048,
@@ -679,8 +685,9 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     the decode step attends through the paged kernel over a LIST a KV head
     (64 kernel slots) and updates the state through ``lightning_update``;
     no prefill holds a [chunk, context] score matrix (32 x 2,048 x 25,600
-    float32 would be 6.7 GB); no program ranks the 400 blocks of a table
-    against each other."""
+    float32 would be 6.7 GB): both attend through ``sparse_prefill_attention``
+    (Pallas); no program ranks the 400 blocks of a table against each
+    other."""
     one = SingleDeviceSharding(topo.devices[0])
     cfg = minicpm_sala.MiniCPMSALAConfig(
         n_layers=8, mixer_types=minicpm_sala.PUBLISHED_MIXERS[9:17])
@@ -725,6 +732,8 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     if program == "decode_step_greedy":
         assert "lightning_update" in text
         assert "paged_decode_attention" in text
+    else:
+        assert "sparse_prefill_attention" in text
     blocks = 1600 * 16 // cfg.block_size
     assert blocks == 400
     assert not re.findall(rf"\w+\[(?:\d+,)*{blocks},{blocks}\]", text)
@@ -745,6 +754,36 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     planned = _footprint(compiled)
     assert planned <= 0.85 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - SPARSE_LINEAR_PLANNED_GB[program]) < 0.05
+
+
+@pytest.mark.parametrize("queries,keys,tile", [
+    (2048, 25600, (128, 1024)),  # a chunk behind a prefix: the slot's table
+    (2048, 2048, (128, 1024)),   # a prompt's first chunk
+    (256, 256, (128, 256)),      # the smallest bucket
+    (9728, 9728, (128, 512)),    # the cell's ``correct``, comparison (a)
+])
+def test_sparse_prefill_kernel_compiles_at_the_cells_shapes(topo, queries,
+                                                            keys, tile):
+    """``block_sparse.attend_under``'s kernel at MiniCPM-SALA's heads (32
+    over 2 KV heads of 128, blocks of 64) for the described v5e: the two
+    prefills' calls and the ONE call ``families/minicpm_sala.py``'s
+    ``pinned_logits`` makes over a whole sequence of 9,728, where the form
+    before PR 55 held a turn's [2, 16, 9,728, 512] float32 scores (637
+    MB): a tile of scores is [16 x 128, keys a tile] in VMEM, and what the
+    call plans beside its operands is the table and ``picked`` as
+    numbers."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cfg = minicpm_sala.MiniCPMSALAConfig(
+        n_layers=8, mixer_types=minicpm_sala.PUBLISHED_MIXERS[9:17])
+    assert block_sparse._tile_sizes(queries, keys, 16, 64) == tile
+    kv = sds((keys, 2, 128), jnp.bfloat16)
+    compiled = block_sparse._attend.lower(
+        cfg, sds((queries, 32, 128), jnp.bfloat16), sds((queries,), jnp.int32),
+        sds((queries, 2, keys // 64), jnp.bool_), kv, kv, sds((), jnp.int32),
+        tile=tile, interpret=False).compile()
+    assert "sparse_prefill_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 def test_sparse_kernel_compiles_for_the_checks_one_sequence(topo, as_tpu):
