@@ -39,11 +39,26 @@ way, which it hands the family's walk as one bundle ``via``:
 - ``recur(mix, qkv, b, a, (state, li))``: ``prefill`` runs the chunked
   recurrence from a ZERO state and writes the slot's rows ONCE after the
   scan, the decode step updates every live slot's row in place.
-- ``recur_fixed(q, k, v, g, (S, li))`` (models/minicpm_sala.py's linear
-  layers, ops/lightning.py): as ``recur``, and ``prefill_with_prefix``
-  takes the SLOT'S ROW as the initial state, so a prompt in chunks carries
-  its state from chunk to chunk; only ``prefill`` (a prompt's first chunk,
-  or all of it) begins from zeros.
+- ``recur_fixed(q, k, v, g, (S, li), conv=None)`` (ops/lightning.py's
+  recurrence: models/minicpm_sala.py's linear layers, models/falcon_h1.py's
+  Mamba-2 mixer): as ``recur``, and ``prefill_with_prefix`` takes the
+  SLOT'S ROW as the initial state, so a prompt in chunks carries its state
+  from chunk to chunk; only ``prefill`` (a prompt's first chunk, or all of
+  it) begins from zeros.  ``g`` is the decay's log a head ([H], a constant
+  of the head) or a token a head ([..., H]), told by its rank; keys and
+  queries come a group of heads.  The part it runs under is the family's
+  (``cfg.state_part``).  A family whose recurrence's inputs pass a SHORT
+  CONVOLUTION first (it declares a ``conv`` state row) hands ``conv`` =
+  (taps, bias, the rows to convolve, ``gates``) in place of q, k, v and g:
+  ONE wrapper of the three closures (``_conv_first``) convolves from the
+  rows that came BEFORE this call's (zeros; the slot's ``conv`` rows),
+  makes ``(q, k, v, g, skip) = gates(convolved rows)`` (``skip`` what is
+  added to the recurrence's output, Mamba-2's ``D x``), and the tail goes
+  where the state goes: a prefill's into its slot's rows, a decode step's
+  into the rows of the slots that take the step AND NO OTHER (a slot
+  between two chunks of its prompt keeps its rows through other slots'
+  steps).  What the seam lacked for that: it took q, k and v READY, and a
+  convolution's output depends on rows that only the program can find.
 - ``attend_sparse(q, k, v, (ck, cv, pooled, li))`` (its sparse layers,
   ops/block_sparse.py): K/V rows into pool layer ``li`` and the POOLED KEYS
   those rows complete into ``pooled``, a pair: a row a page beside the pool
@@ -90,8 +105,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
-                            olmo_hybrid, sdar_moe)
+from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
+                            longcat_flash, olmo_hybrid, sdar_moe)
 from ray_tpu.models.llama import embed, head
 from ray_tpu.ops import block_sparse, gated_delta, lightning
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
@@ -191,12 +206,54 @@ def _rows_that_ride(cfg, state):
             for name, rows in state.items()}
 
 
-def _write_slot_rows(state, rode, left, slot):
+def _conv_first(cfg, plain, before_of, keep, ready=lambda rows: rows):
+    """``recur_fixed`` as a program hands it to a walk: ``plain(q, k, v, g,
+    rows)`` on ready inputs, and with ``conv`` = (taps [W, C], bias, x,
+    gates) the recurrence's inputs pass a short convolution first.  What a
+    program says of that, once each: ``before_of(rows, W - 1, x)`` the W - 1
+    rows that precede x's ([W - 1, ..., C]; x [L, C] a sequence's rows or
+    [slots, C] ONE position of many sequences), ``keep(rows, kept, before,
+    rows_in_order [W - 1 + L, ..., C])`` what ``plain`` kept (the rows the
+    walk carries on, what the program writes after it) with the
+    convolution's rows beside the state, and ``ready(rows)`` the rows as
+    ``plain`` takes them.  ``(q, k, v, g, skip) =
+    gates(convolved rows)``; ``skip`` (Mamba-2's ``D x``) is added to the
+    recurrence's output under the state's part."""
+
+    def recur_fixed(q, k, v, g, rows, conv=None):
+        if conv is None:
+            return plain(q, k, v, g, rows)
+        taps, bias, x, gates = conv
+        before = before_of(rows, taps.shape[0] - 1, x)
+        y, in_order = olmo_hybrid.short_conv(
+            taps, x.reshape(-1, *before.shape[1:]), before, bias,
+            falcon_h1.CONV_PART)
+        q, k, v, g, skip = gates(y.reshape(x.shape))
+        o, kept = plain(q, k, v, g, ready(rows))
+        with jax.named_scope(cfg.state_part):
+            o = o + skip
+        with jax.named_scope(falcon_h1.CONV_PART):
+            return o, keep(rows, kept, before, in_order)
+
+    return recur_fixed
+
+
+def _last_inputs(true_len):
+    """A prefill's ``keep``: the state beside the convolution's inputs at
+    the last W - 1 REAL positions (a chunk shorter than that reaches back
+    into ``before``)."""
+    return lambda rows, kept, before, in_order: (kept[0], (
+        kept[1], jax.lax.dynamic_slice_in_dim(in_order, true_len,
+                                              before.shape[0], 0)))
+
+
+def _write_slot_rows(cfg, state, rode, left, slot):
     """``state`` after a prefill's walk: the page rows as the walk left
     them (``rode``) and ``left`` (name -> [layers, ...]) in ``slot``'s row.
     Outside the scans, as ``prefill`` says below."""
-    with jax.named_scope("lightning/state"):
-        out = {**state, **{k: v for k, v in rode.items() if v is not None}}
+    with jax.named_scope(cfg.state_part):
+        out = {**state,
+               **{k: v for k, v in (rode or {}).items() if v is not None}}
         for name, rows in left.items():
             out[name] = jax.lax.dynamic_update_slice(
                 state[name], rows[:, None].astype(state[name].dtype),
@@ -351,15 +408,22 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
             **tiles,
             "index_rows_written": block_sparse.rows_complete(cfg, true_len)}
 
-    def recur_fixed(q, k, v, g, rows):  # q, k, v: [L, H, d]; g: [H]
-        with jax.named_scope("lightning/state"):
+    def recur_fixed(q, k, v, g, rows):
+        # q, k: [L, G, d_k]; v: [L, H, d_v]; g: [H] or [L, H]
+        with jax.named_scope(cfg.state_part):
             # a padded position changes nothing: no decay, no write
             real = (positions < true_len)[:, None]
             o, S = lightning.chunked(
                 q, jnp.where(real[..., None], k, 0), v,
                 jnp.where(real, g, 0.0),
-                jnp.zeros((q.shape[1], k.shape[2], v.shape[2]), jnp.float32))
+                jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), jnp.float32))
         return o, (rows[0], S)
+
+    # (a convolution from zeros: a prompt's first rows)
+    recur_fixed = _conv_first(
+        cfg, recur_fixed,
+        lambda rows, taps, x: jnp.zeros((taps, x.shape[-1]), x.dtype),
+        _last_inputs(true_len))
 
     # the scan carries no SLOT's state: a prefill begins its slot's rows
     # anew (the rows a page holds ride with the pools)
@@ -369,7 +433,7 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
          "recur": recur, "attend_sparse": attend_sparse,
          "recur_fixed": recur_fixed})
     if isinstance(left, dict):
-        state = _write_slot_rows(state, rode, left, slot)
+        state = _write_slot_rows(cfg, state, rode, left, slot)
     elif state is not None:
         # The slot's rows are written HERE, once, and not in the scan: a
         # row-sized update inside the loop lets XLA choose the carried
@@ -511,7 +575,7 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
             - block_sparse.rows_complete(cfg, positions[0])}
 
     def recur_fixed(q, k, v, g, rows):  # from what the slot's row holds
-        with jax.named_scope("lightning/state"):
+        with jax.named_scope(cfg.state_part):
             real = (jnp.arange(tokens.shape[0]) < true_len)[:, None]
             S0 = jax.lax.dynamic_slice(
                 state["S"], (rows[1], slot, 0, 0, 0),
@@ -520,12 +584,19 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                                      jnp.where(real, g, 0.0), S0)
         return o, (rows[0], S)
 
+    recur_fixed = _conv_first(
+        cfg, recur_fixed,
+        lambda rows, taps, x: jax.lax.dynamic_slice(
+            state["conv"], (rows[1] * taps, slot, 0),
+            (taps, 1, x.shape[-1]))[:, 0],
+        _last_inputs(true_len))
+
     x, (cache_k, cache_v, rode), counted, left = cfg.served_walk(
         params, x, (cache_k, cache_v, _rows_that_ride(cfg, state)), positions,
         {**_by_kind(cfg, attend_through), "attend_latent": attend_latent,
          "attend_sparse": attend_sparse, "recur_fixed": recur_fixed})
     if isinstance(left, dict):
-        state = _write_slot_rows(state, rode, left, slot)
+        state = _write_slot_rows(cfg, state, rode, left, slot)
     return (_last_logits(params, x, cfg, true_len), counted, cache_k,
             cache_v, state)
 
@@ -650,12 +721,30 @@ def _decode_impl(params, tokens, cache_k, cache_v, page_tables, positions,
                                            heads_apart=True),
                     (ck, cv, (by_page, by_slot)), counted)
 
-    def recur_fixed(q, k, v, g, rows):  # q, k, v: [B, H, d]; g: [H]
+    def recur_fixed(q, k, v, g, rows):
+        # q, k: [B, G, d_k]; v: [B, H, d_v]; g: [H] or [B, H]
         S, li = rows
-        with jax.named_scope("lightning/state"):
+        with jax.named_scope(cfg.state_part):
             o, S = lightning.decode_update(
-                S, li, q, k, v, jnp.broadcast_to(g, q.shape[:2]), active)
+                S, li, q, k, v, jnp.broadcast_to(g, v.shape[:2]), active)
         return o, (S, None)
+
+    def slots_rows(rows, taps, x):  # layer li's, every slot's
+        return jax.lax.dynamic_slice_in_dim(rows[0]["conv"], rows[1] * taps,
+                                            taps)
+
+    def keep(rows, kept, before, in_order):
+        # A slot that takes no step keeps its rows as its state is kept
+        # (``decode_update`` masks by ``active`` too): it may be between
+        # two CHUNKS of its prompt, and the next chunk convolves from them.
+        tail = jnp.where(active[None, :, None], in_order[1:], before)
+        return {"S": kept[0], "conv": jax.lax.dynamic_update_slice_in_dim(
+            rows[0]["conv"], tail, rows[1] * before.shape[0], axis=0)}, None
+
+    # (a family with a convolution carries its rows through the walk by
+    # name, ``{"S", "conv"}``)
+    recur_fixed = _conv_first(cfg, recur_fixed, slots_rows, keep,
+                              lambda rows: (rows[0]["S"], rows[1]))
 
     x, caches, counted, _ = cfg.served_walk(
         params, x, (cache_k, cache_v, state), positions,

@@ -213,6 +213,11 @@ PARTS = (
     # products and norms, its recurrence, its output norm, gate and W_o
     "sparse_attn/proj", "sparse_attn/index", "sparse_attn/attend",
     "lightning/proj", "lightning/state", "lightning/out",
+    # models/falcon_h1.py's Mamba-2 mixer: W_in and the muP vector, the
+    # short convolution, softplus / decay / dt x, the recurrence (a decode
+    # step's update, a prefill's chunked scan, with D x), gated norm and
+    # W_out
+    "ssm/proj", "ssm/conv", "ssm/gates", "ssm/state", "ssm/out",
     # parallel/tp_stream.py: the links of a training trunk whose stream is
     # split over ``tp`` between the products: a group's rows passed round
     # the ring into ``attn/qkv`` and ``mlp/gate_up``, the partial sums of

@@ -114,6 +114,7 @@ class MiniCPMSALAConfig:
     # left is the next one's initial state.
     block_length = 0  # it generates a token at a time
     window = 0  # no layer of it sees a fixed window of pages alone
+    state_part = "lightning/state"  # where the programs' recurrence shows
     refuses = {
         "pd": _ROWS_BESIDE % "prefill/decode disaggregation",
         "kv_tier": _ROWS_BESIDE % "the KV tier",

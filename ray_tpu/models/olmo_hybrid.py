@@ -298,21 +298,25 @@ def lin_proj(cfg, p, x):
         return [qkv] + [x @ p[w].astype(x.dtype) for w in ("wg", "wb", "wa")]
 
 
-def short_conv(taps, x, before):
+def short_conv(taps, x, before, bias=None, part: str = "lin_attn/conv"):
     """Step 2, time on the FIRST axis.  x: [L, ..., C] a sequence's rows in
     order (a decode step: [1, slots, C]); before: [W - 1, ..., C] the rows
     that precede them (zeros at a sequence's start); taps: [W, C], the last
-    tap on the row itself.  Returns (SiLU of the convolution [L, ..., C]
+    tap on the row itself; ``bias`` [C] where the convolution has one
+    (models/falcon_h1.py), ``part`` the name it runs under.  Returns (SiLU
+    of the convolution [L, ..., C]
     in float32, as ``delta_inputs`` takes it: rounding q, k and v to the
     served type here would be one rounding more than the recurrence needs,
     the rows one behind the other [W - 1 + L, ..., C]: the next call's
     ``before`` is W - 1 of them)."""
-    with jax.named_scope("lin_attn/conv"):
+    with jax.named_scope(part):
         L = x.shape[0]
         rows = jnp.concatenate([before.astype(x.dtype), x], axis=0)
         taps = taps.astype(jnp.float32)
         y = sum(rows[j:j + L].astype(jnp.float32) * taps[j]
                 for j in range(taps.shape[0]))
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
         return jax.nn.silu(y), rows
 
 

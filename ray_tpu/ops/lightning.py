@@ -1,22 +1,34 @@
-"""Lightning Attention's recurrence (Qin et al., arXiv:2401.04658): linear
-attention with a FIXED decay a head, three ways.
+"""The recurrence of linear attention with a decay and no delta term, three
+ways: Lightning Attention's (Qin et al., arXiv:2401.04658) and Mamba-2's
+selective scan (Dao and Gu, arXiv:2405.21060) are two cases of it.
 
 A head keeps a matrix S [d_k, d_v] (float32) that a token updates:
 
-    S_t = lambda S_{t-1} + k_t^T v_t,        o_t = q_t S_t
+    S_t = exp(g_t) S_{t-1} + k_t^T v_t,        o_t = q_t S_t
 
-with lambda in (0, 1) a constant of the head (``log_decays``: the ALiBi
-slopes).  No write strength and no correction by what the state already
-holds (ops/gated_delta.py's delta rule), so no triangular system: inside a
-chunk the outputs are one masked product, and only the state goes from
-chunk to chunk.
+with g_t <= 0 the LOG of the head's decay at that token.  A FIXED decay is
+the case ``g`` constant (models/minicpm_sala.py: ``log_decays``, the ALiBi
+slopes); Mamba-2's is the case ``g = dt A``, ``v = dt x``, ``k = B``,
+``q = C`` (models/falcon_h1.py), with d_k (the state's size N) unequal to
+d_v (a head's width) and B and C shared by the heads of a GROUP.  No write
+strength and no correction by what the state already holds
+(ops/gated_delta.py's delta rule), so no triangular system: inside a chunk
+the outputs are one masked product, and only the state goes from chunk to
+chunk.
 
-Every form takes the decay as its LOG, a number a token a head (``g`` [L,
+Every form takes the decay as its log, a number a token a head (``g`` [L,
 H]), so that a caller can let a padded position change nothing (g = 0 and
-a zero key) with the same program.  The state is held as it is written,
-[H, d_k, d_v]: at d_k = d_v = 128 a head's state is whole (8, 128) tiles
-already, and ``gated_delta.pack_state`` (which lays heads of 192 side by
-side) would be the identity on it.
+a zero key) with the same program, and so that a decay between two tokens
+of a chunk is ``exp(G_t - G_i)``, the exponential of a difference of
+running sums, never a quotient of two running products (at a decay of
+exp(-1.6) a token a chunk's product underflows float32; the difference does
+not).  Keys and queries come a GROUP, [L, G, d_k] with H a multiple of G
+(G = H: a head its own); head h reads group h // (H / G), and no form
+repeats them to H heads: a group's scores are made once, and what is a
+head's own is what its decays make of them.  The state is held as it is written, [H, d_k,
+d_v]: at d_v = 128 and d_k a multiple of 8 a head's state is whole (8, 128)
+tiles already, and ``gated_delta.pack_state`` (which lays heads of 192
+side by side) would be the identity on it.
 
 ``recurrent``: the definition, a token at a time (the tests' yardstick).
 ``chunked``: a whole (padded) sequence from an initial state S0, for
@@ -47,25 +59,54 @@ def log_decays(n_heads: int):
     return -jnp.exp2(-8.0 * h / n_heads)
 
 
+def _by_group(q, v, g, S0):
+    """(v, g, S0) with their heads split [G, H / G], G the groups q has."""
+    G, H = q.shape[1], v.shape[1]
+    if H % G:
+        raise ValueError(
+            f"{H} heads are no whole number of heads for each of the {G} "
+            f"groups the keys and queries come in")
+    f32 = jnp.float32
+    return (v.astype(f32).reshape(v.shape[0], G, H // G, v.shape[-1]),
+            g.astype(f32).reshape(g.shape[0], G, H // G),
+            S0.astype(f32).reshape(G, H // G, *S0.shape[1:]))
+
+
 def recurrent(q, k, v, g, S0):
-    """Token by token.  q, k: [L, H, d_k]; v: [L, H, d_v]; g (log decay):
+    """Token by token.  q, k: [L, G, d_k]; v: [L, H, d_v]; g (log decay):
     [L, H]; S0: [H, d_k, d_v].  Returns (o [L, H, d_v], S_L), float32."""
     f32 = jnp.float32
+    L, H, dv = v.shape
+    v, g, S0 = _by_group(q, v, g, S0)
 
-    def step(S, x):
+    def step(S, x):  # S: [G, R, d_k, d_v]
         q, k, v, g = x
-        S = S * jnp.exp(g)[:, None, None] + k[:, :, None] * v[:, None, :]
-        return S, jnp.einsum("hk,hkv->hv", q, S, precision=_HI)
+        S = (S * jnp.exp(g)[..., None, None]
+             + k[:, None, :, None] * v[:, :, None, :])
+        return S, jnp.einsum("gk,grkv->grv", q, S, precision=_HI)
 
-    S, o = jax.lax.scan(step, S0.astype(f32),
-                        tuple(x.astype(f32) for x in (q, k, v, g)))
-    return o, S
+    S, o = jax.lax.scan(step, S0, (q.astype(f32), k.astype(f32), v, g))
+    return o.reshape(L, H, dv), S.reshape(H, *S.shape[2:])
 
 
 def chunked(q, k, v, g, S0, chunk: int = CHUNK):
     """The same as ``recurrent`` by chunks of ``chunk`` tokens (L is padded
     to a multiple of it with tokens that change nothing).  Returns
-    (o [L, H, d_v] float32, S_L [H, d_k, d_v] float32)."""
+    (o [L, H, d_v] float32, S_L [H, d_k, d_v] float32).  Heads that share a
+    key run the one scan side by side over the group's key and query: its
+    scores are made once a group, each head's decays laid over them."""
+    L, H, dv = v.shape
+    G = q.shape[1]
+    if G == H:
+        return _chunked(q, k, v, g, S0, chunk)
+    v, g, S0 = _by_group(q, v, g, S0)
+    o, S = jax.vmap(lambda v, g, S0: _chunked(q, k, v, g, S0, chunk),
+                    in_axes=(2, 2, 1), out_axes=(2, 1))(v, g, S0)
+    return o.reshape(L, H, dv), S.reshape(H, *S.shape[2:])
+
+
+def _chunked(q, k, v, g, S0, chunk: int):
+    """``chunked`` with a key and a query for every head of v."""
     f32 = jnp.float32
     L, H, _ = q.shape
     n = -(-L // chunk)
@@ -106,12 +147,14 @@ def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,
                    o_ref, s_out):
     del layer_ref, order_ref  # the block indices read them
     hb, _, dv = s_ref.shape[2:]
+    kb = kq_ref.shape[-1]  # the block's keys: one a head, or one for all
 
     @pl.when(pl.program_id(0) < live_ref[0])
     def _():
         for p in range(hb):
-            kx = kq_ref[0, 0, 0][:, p:p + 1]  # [d_k, 1]
-            qx = kq_ref[0, 0, 1][:, p:p + 1]
+            c = p * kb // hb
+            kx = kq_ref[0, 0, 0][:, c:c + 1]  # [d_k, 1]
+            qx = kq_ref[0, 0, 1][:, c:c + 1]
             at = slice(p * dv, (p + 1) * dv)
             v, a = (vec_ref[0, 0, r:r + 1, at] for r in range(2))  # [1, d_v]
             st = s_ref[0, 0, p] * a + kx * v
@@ -123,14 +166,17 @@ def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,
 def _decode_update(state, layer, q, k, v, g, active, *, group: int,
                    interpret: bool):
     f32 = jnp.float32
-    B, H, dk = q.shape
-    dv = v.shape[-1]
-    n_groups = H // group
+    B, G, dk = q.shape
+    H, dv = v.shape[1:]
+    n_groups = H // group  # blocks of ``group`` heads
     width = group * dv
+    # keys a block reads: a head's own (G = H), or the one its heads share
+    kb = group * G // H or 1
+    n_keys = G // kb  # key blocks; ``per`` head blocks read each
+    per = n_groups // n_keys
 
-    def by_group(x):  # [B, H, d_k] -> [B, groups, d_k, heads a group]
-        return jnp.swapaxes(x.astype(f32).reshape(B, n_groups, group, dk),
-                            2, 3)
+    def by_group(x):  # [B, G, d_k] -> [B, key blocks, d_k, keys a block]
+        return jnp.swapaxes(x.astype(f32).reshape(B, n_keys, kb, dk), 2, 3)
 
     def by_lane(x):  # [B, H, d_v] -> [B, groups, lanes]
         return x.astype(f32).reshape(B, n_groups, width)
@@ -153,7 +199,8 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
         return (*at(i, j, *refs), 0, 0)
 
     def columns(i, j, *refs):
-        return (*at(i, j, *refs), 0, 0, 0)
+        slot, grp = at(i, j, *refs)
+        return (slot, grp if per == 1 else grp // per, 0, 0, 0)
 
     def rows(i, j, layer_ref, *refs):
         slot, grp = at(i, j, layer_ref, *refs)
@@ -165,7 +212,7 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
             num_scalar_prefetch=3,
             grid=(B, n_groups),
             in_specs=[
-                pl.BlockSpec((1, 1, 2, dk, group), columns),
+                pl.BlockSpec((1, 1, 2, dk, kb), columns),
                 pl.BlockSpec((1, 1, 2, width), small),
                 pl.BlockSpec((1, 1, group, dk, dv), rows),
             ],
@@ -190,27 +237,29 @@ def decode_update(state, layer, q, k, v, g, active):
 
     state: [layers, slots, H, d_k, d_v] float32, every layer's rows; only
     ``layer`` (an int32 scalar, traced or not) is read and written, and of
-    it only the slots where ``active`` [B] holds.  q, k: [B, H, d_k];
-    v: [B, H, d_v]; g (log decay): [B, H].  Returns (o [B, H, d_v]
-    float32, zeros where not active; the state)."""
+    it only the slots where ``active`` [B] holds.  q, k: [B, G, d_k] (H a
+    multiple of G); v: [B, H, d_v]; g (log decay): [B, H].  Returns
+    (o [B, H, d_v] float32, zeros where not active; the state)."""
     if state.ndim != 5 or state.dtype != jnp.float32:
         raise ValueError(
             f"the lightning update takes the float32 state [layers, slots, "
             f"H, d_k, d_v]; got {state.dtype}{list(state.shape)}")
-    B, H, dk = q.shape
-    dv = v.shape[-1]
-    if state.shape[1:] != (B, H, dk, dv):
+    B, G, dk = q.shape
+    H, dv = v.shape[1:]
+    if state.shape[1:] != (B, H, dk, dv) or H % G:
         raise ValueError(
             f"state rows {state.shape[1:]} do not hold {B} slots of {H} "
-            f"heads [{dk}, {dv}]")
+            f"heads [{dk}, {dv}], {H // G} to each of {G} keys")
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu and (dv % 128 or dk % 8):
         raise ValueError(
             f"on the TPU the lightning update moves whole tiles, and a "
             f"[{dk}, {dv}] state is not made of them: d_k must be a "
             f"multiple of 8 and d_v of 128")
-    # heads a block: 0.5 MB of state at [128, 128]
-    group = max(d for d in range(1, H + 1)
-                if H % d == 0 and d * dk * dv * 4 <= (1 << 19) or d == 1)
+    # heads a block: 0.5 MB of state (8 at [128, 128], 4 at [256, 128]),
+    # and where heads share a key, heads of one key
+    share = H if G == H else H // G
+    group = max(d for d in range(1, share + 1)
+                if share % d == 0 and d * dk * dv * 4 <= (1 << 19) or d == 1)
     return _decode_update(state, layer, q, k, v, g, active, group=group,
                           interpret=not on_tpu)

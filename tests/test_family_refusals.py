@@ -11,8 +11,9 @@ import pytest
 
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
-                            minicpm_sala, olmo_hybrid, sdar_moe)
+from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
+                            longcat_flash, minicpm_sala, olmo_hybrid,
+                            sdar_moe)
 
 VOCAB = 128
 
@@ -39,6 +40,11 @@ FAMILIES = {
         VOCAB, kernel_size=32, kernel_stride=16, block_size=64,
         window_size=128, dense_len=384),
         "MiniCPMSALAConfig keeps a state row a slot .* does not serve with"),
+    # (state rows AND pages in every layer; a prompt in chunks carries the
+    # state and the convolution's tail, as minicpm_sala's carries its state)
+    "falcon_h1": (falcon_h1, falcon_h1.FalconH1Config.tiny(VOCAB),
+                  "FalconH1Config has a recurrent mixer in every block .* "
+                  "does not serve with"),
 }
 
 # path -> (the feature it needs, where the refusal says it was asked)
@@ -100,8 +106,9 @@ def test_every_family_declares_only_features_this_test_asks_for():
         assert set(cfg.refuses) <= asked
     assert FAMILIES["llama"][1].refuses == {}
     # state beside the pages, and yet a prompt in chunks: the chunks carry it
-    assert set(FAMILIES["minicpm_sala"][1].refuses) == {
-        "pd", "kv_tier", "prefix_cache"}
+    for chunks_carry in ("minicpm_sala", "falcon_h1"):
+        assert set(FAMILIES[chunks_carry][1].refuses) == {
+            "pd", "kv_tier", "prefix_cache"}
 
 
 @pytest.mark.parametrize("path", list(PATHS))

@@ -25,8 +25,9 @@ from jax.experimental.compilation_cache import compilation_cache
 from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
-                            minicpm_sala, moe, olmo_hybrid, sdar_moe)
+from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
+                            longcat_flash, minicpm_sala, moe, olmo_hybrid,
+                            sdar_moe)
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.train.step import (create_train_state, default_optimizer,
                                 make_train_step)
@@ -40,7 +41,8 @@ KERNELS = {**dict.fromkeys(FLASH, "attn/attend"),
            "paged_latent_decode_attention": "mla/attend",
            "moe_grouped_mlp": "moe/experts",
            "gated_delta_update": "lin_attn/state",
-           "lightning_update": "lightning/state"}
+           # (the one recurrence kernel, under the family's own part)
+           "lightning_update": ("lightning/state", "ssm/state")}
 BLOCK = ("embed", "layers", "attn/norm", "attn/qkv", "attn/rope",
          "attn/attend", "attn/out", "mlp/norm")
 DENSE = BLOCK + ("mlp/gate_up", "mlp/down")
@@ -73,6 +75,10 @@ SPARSE_LINEAR = ("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
                  "sparse_attn/proj", "sparse_attn/index",
                  "sparse_attn/attend", "lightning/proj", "lightning/state",
                  "lightning/out")
+# a state-space mixer BESIDE attention in every block: the dense block's
+# parts and the mixer's, state rows and pages in one layer
+PARALLEL_SSM = DENSE + ("attn/kv_write", "head", "ssm/proj", "ssm/conv",
+                        "ssm/gates", "ssm/state", "ssm/out")
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -227,6 +233,22 @@ def _sparse_linear(program):
     return fn, (params, tokens, ck, cv, *rest), cfg, rows
 
 
+def _parallel_ssm(program):
+    """``program`` of the dense tree over Falcon-H1's caches: a pool layer
+    AND a state layer (the state and the convolution's tail) for every
+    scanned layer."""
+    cfg = falcon_h1.FalconH1Config.tiny()
+    params = cfg.serving_layout(falcon_h1.init(cfg, jax.random.PRNGKey(0)))
+    cc = CacheConfig(**lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+                     dtype="float32", max_slots=4)
+    ck, cv = init_cache(cc)
+    fn, (_, tokens, _, _, *rest), _ = program()
+    rows = {"state": init_state(cc)}
+    if program is not _decode:
+        rows["slot"] = jnp.int32(1)
+    return fn, (params, tokens, ck, cv, *rest), cfg, rows
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -297,7 +319,12 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "sparse_linear_prefill_with_prefix":
               lambda: _sparse_linear(_prefill_with_prefix),
           "sparse_linear_decode_step_greedy":
-              lambda: _sparse_linear(_decode)}
+              lambda: _sparse_linear(_decode),
+          "parallel_ssm_prefill": lambda: _parallel_ssm(_prefill),
+          "parallel_ssm_prefill_with_prefix":
+              lambda: _parallel_ssm(_prefill_with_prefix),
+          "parallel_ssm_decode_step_greedy":
+              lambda: _parallel_ssm(_decode)}
 TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
     "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
@@ -327,6 +354,10 @@ EXPECTED = {
     "sparse_linear_prefill": SPARSE_LINEAR,
     "sparse_linear_prefill_with_prefix": SPARSE_LINEAR,
     "sparse_linear_decode_step_greedy": SPARSE_LINEAR + ("sample",),
+    "parallel_ssm_prefill": PARALLEL_SSM + ("attn/attend/repeat_kv",),
+    "parallel_ssm_prefill_with_prefix":
+        PARALLEL_SSM + ("attn/attend/repeat_kv",),
+    "parallel_ssm_decode_step_greedy": PARALLEL_SSM + ("sample",),
     # the flash kernels read K and V at their own heads (PR 47): a program
     # that attends through them repeats nothing; the plain XLA path does
     "llama_grad_remat": DENSE + ("head", "loss"),
@@ -387,6 +418,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
                                               "moe_grouped_mlp"},
               "sparse_linear_decode_step_greedy": {"paged_decode_attention",
                                                    "lightning_update"},
+              "parallel_ssm_decode_step_greedy": {"paged_decode_attention",
+                                                  "lightning_update"},
               "llama_grad": set(FLASH),
               **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
     assert wanted <= seen

@@ -25,8 +25,8 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_state
-from ray_tpu.models import (afmoe, glm_moe_lite, llama, longcat_flash,
-                            minicpm_sala,
+from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
+                            longcat_flash, minicpm_sala,
                             olmo_hybrid, sdar_moe)
 from ray_tpu.ops import attention, block_sparse
 from ray_tpu.parallel.mesh import AXIS_ORDER
@@ -419,6 +419,76 @@ def test_hybrid_programs_compile_at_olmo_widths(topo, as_tpu, program):
     planned = _footprint(compiled)
     assert planned <= 0.85 * V5E_BYTES_LIMIT
     assert abs(planned / 1e9 - HYBRID_PLANNED_GB[program]) < 0.05
+
+
+# planned bytes a program of configuration ``falcon_h1_34b_serve_1chip``,
+# compiled for the described v5e here (PERF.md section 4): weights 10.51 GB,
+# both pools 1.21 GB, the state rows 1.62 GB
+SSM_PLANNED_GB = {"decode_step_greedy": 13.341, 64: 13.367, 256: 13.368,
+                  1024: 13.496, "chunk_1024": 13.510}
+
+
+@pytest.mark.parametrize("program", ["decode_step_greedy", 64, 256, 1024,
+                                     "chunk_1024"])
+def test_parallel_ssm_programs_compile_at_falcon_h1_widths(topo, as_tpu,
+                                                           program):
+    """``decode_step_greedy`` (64 slots, 128-page tables), three prefill
+    buckets and a later CHUNK of a prompt over the largest bucket
+    (``prefill_with_prefix`` from the slot's state and convolution rows) of
+    Falcon-H1-34B at published widths and 6 layers, over the
+    cell's 6,144 pages (6 pools of 4-head pages) and 64 slots' state rows:
+    each plans at or under 0.85 of the chip's bytes_limit; pools AND state
+    rows are aliased to the outputs and held once; the decode step updates
+    a slot's [32, 256, 128] state through the ``lightning_update`` kernel
+    (four heads a block, a block's heads reading ONE key column) and
+    attends through the paged one IN THE SAME LAYER."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = falcon_h1.FalconH1Config(n_layers=6, max_seq_len=2048)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shapes = jax.eval_shape(
+        lambda k: falcon_h1.init(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0))
+    params = _on(one, jax.eval_shape(cfg.serving_layout, shapes))
+    layout = lm.cache_layout(cfg)
+    cache = sds((layout["n_layers"], 6144, 16, layout["n_kv_heads"],
+                 layout["head_dim"]), jnp.bfloat16)
+    state = {name: sds((rows, 64, *shape), dt)
+             for name, (rows, shape, dt) in layout["state_rows"].items()}
+    assert cache.shape == (6, 6144, 16, 4, 128)
+    assert state["S"].shape == (6, 64, 32, 256, 128)
+    assert state["conv"].shape == (18, 64, 5120)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step_greedy":
+        compiled = lm.decode_step_greedy.lower(
+            params, i32(64), cache, cache, i32(64, 128), i32(64),
+            sds((64,), jnp.bool_), cfg, state).compile()
+        text = compiled.as_text()
+        assert "lightning_update" in text
+        assert "paged_decode_attention" in text
+    elif program == "chunk_1024":  # a later chunk of a 1,025-2,047 prompt
+        compiled = lm.prefill_with_prefix.lower(
+            params, i32(1024), cache, cache, i32(1024), i32(), i32(1024),
+            i32(128), i32(1024), cfg, state, i32()).compile()
+        text = compiled.as_text()
+    else:
+        compiled = lm.prefill.lower(
+            params, i32(program), cache, cache, i32(program), i32(),
+            i32(program), cfg, state, i32()).compile()
+        text = compiled.as_text()
+    pools = 2 * 6 * 6144 * 16 * 4 * 128 * 2
+    rows = 6 * 64 * 32 * 256 * 128 * 4 + 18 * 64 * 5120 * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pools + rows
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r and r.startswith((
+        "f32[6,64,32,256,128]", "bf16[18,64,5120]",
+        "bf16[6,6144,16,4,128]"))]
+    planned = _footprint(compiled)
+    # a decode step plans 1 MB of temporaries, a 1,024 prefill 0.16 GB
+    assert m.temp_size_in_bytes < (16e6 if program == "decode_step_greedy"
+                                   else 0.2e9)
+    assert planned <= 0.85 * V5E_BYTES_LIMIT
+    assert abs(planned / 1e9 - SSM_PLANNED_GB[program]) < 0.05
 
 
 # planned bytes a program of configuration ``glm47_flash_serve_1chip``,
