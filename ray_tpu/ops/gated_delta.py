@@ -18,7 +18,9 @@ live slot's state once and writes it once, in place (the state array is
 aliased to the output; the layer is a scalar the block index reads), and
 does not touch a slot that is not live.  Plain ``jax.numpy`` under XLA
 reads the state three times (S k, the update, S q): that is why this one is
-a kernel.  Off the TPU it runs through the Pallas interpreter.
+a kernel.  A grid step moves as many head packs of a slot as its buffers may
+take of VMEM (``_packs_a_block``: 5 of the 15 at the served shape, and why
+not all).  Off the TPU it runs through the Pallas interpreter.
 
 ``chunked``: a whole (padded) sequence from an initial state, for prefill,
 in plain ``jax.numpy``: the WY / UT-transform of the delta rule with the
@@ -201,6 +203,32 @@ def chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
 # decode: one token a slot, the state updated where it lies
 
 
+# What sets a block of the update: the form of ``ops/lightning.py``'s rule, the
+# twin of this kernel (its comment has the account), with a budget of its own.
+# A grid step costs 0.3-0.4 us whatever it moves, a step past the live slots
+# too, and past that the time is the stream's (80 % of a v5e's 819 GB/s, reads
+# beside writes): the kernel apart at [12, 32 slots, 15, 96, 384] (my chip
+# runs, PR 58) takes 152.6 us at 19 live slots with 3 packs a step, 133.1 with
+# 5 (0.74 MB, 96 steps; 191.0 at 28 live) and 129.1 with the slot's 15 (2.21
+# MB, 32 steps; 189.1).  The last 3 % are not taken: with the whole slot a
+# block XLA moves the served step's ``conv`` rows (26.5 MB) into VMEM and back
+# around the layers (compiled for a v5e at any limit given the kernel;
+# ``tests/test_tpu_compile.py`` holds the step to no such copy), so a block
+# is the most head packs whose four buffers (the state in and out, each
+# double-buffered) fit 4 MiB, 5 packs at that shape: inside the 16 MiB of
+# VMEM a kernel gets unasked, so this one asks for none.
+STATE_BLOCKS_BYTES = 4 * 1024 * 1024
+
+
+def _packs_a_block(packs: int, dk: int, width: int) -> int:
+    """Head packs of [d_k, width] float32 the update moves a grid step: the
+    divisor of ``packs`` that is the most whose buffers fit
+    ``STATE_BLOCKS_BYTES``."""
+    return max(d for d in range(1, packs + 1)
+               if packs % d == 0
+               and (4 * d * dk * width * 4 <= STATE_BLOCKS_BYTES or d == 1))
+
+
 def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,
                    o_ref, s_out, *, pack: int):
     del layer_ref, order_ref  # the block indices read them
@@ -320,10 +348,6 @@ def decode_update(state, layer, q, k, v, g, beta, active, *, pack: int):
             f"on the TPU the gated delta update moves whole tiles, and a "
             f"[{dk}, {pack} x {dv}] state is not made of them: d_k must be "
             f"a multiple of 8 and pack * d_v of 128")
-    packs = H // pack
-    # head packs a block: ~0.75 MB of state at [96, 384]
-    group = max(d for d in range(1, packs + 1)
-                if packs % d == 0 and d * dk * pack * dv * 4 <= (1 << 20)
-                or d == 1)
     return _decode_update(state, layer, q, k, v, g, beta, active, pack=pack,
-                          group=group, interpret=not on_tpu)
+                          group=_packs_a_block(H // pack, dk, pack * dv),
+                          interpret=not on_tpu)

@@ -36,7 +36,10 @@ prefill and for a later chunk of a prompt, in plain ``jax.numpy``; state
 products at ``highest`` precision, the state float32.
 ``decode_update``: one token a slot, a Pallas kernel on the pattern of
 ``gated_delta.decode_update``: a live slot's state is read once and written
-once where it lies (aliased to the output), another slot's not at all.
+once where it lies (aliased to the output), another slot's not at all.  A
+grid step moves as much of a slot's state as its buffers may take of VMEM,
+the whole slot at the served shapes (``_heads_a_block``): a step costs
+0.3-0.4 us whatever it moves, and past that the time is the stream's.
 """
 
 from __future__ import annotations
@@ -143,6 +146,42 @@ def _chunked(q, k, v, g, S0, chunk: int):
 # decode: one token a slot, the state updated where it lies
 
 
+# What sets a block of the update (PERF.md section 6, PR 58: my chip runs,
+# the kernel apart).  Falcon-H1's [64 slots, 32, 256, 128] with 50 live, 419
+# MB read and written, 512 us at a v5e's 819 GB/s: 704 us in blocks of 4 heads
+# (0.5 MB, 64 x 8 = 512 grid steps), 684 and 680 us at 1 and 2 MB, 660 us
+# with a slot's whole 4.19 MB a step (64 steps); MiniCPM-SALA's [32, 32, 128,
+# 128] with 24 live 178.5 -> 160.2 us (128 -> 32 steps), with 7 live 64.7 ->
+# 50.4.  Two costs lie over the bytes.  A grid step costs 0.3-0.4 us whatever
+# it moves, a step past the live slots too: that is what small blocks lose.
+# And the stream itself: a body that only copies its block takes the same time
+# at every size (659.3 against 659.8 us), reads alone move at 750 GB/s and
+# reads beside writes at 636, so at a slot a step the kernel is at what this
+# pipeline gets from the memory (78 % of the peak) and its arithmetic is free.
+# So a block is the most heads of a slot whose buffers (the state in and out,
+# each double-buffered) fit ``STATE_BLOCKS_BYTES`` of VMEM, the whole slot
+# where it fits.  Where those buffers and ``_VMEM_BESIDE_BYTES`` (the keys',
+# vectors' and outputs' blocks: 0.04-0.23 MiB as compiled for a v5e) pass the
+# 16 MiB a kernel gets unasked, the kernel asks for them and no more (Falcon-
+# H1: 17 MiB): a core has 128 MiB, but what a kernel holds XLA cannot use
+# around it.
+STATE_BLOCKS_BYTES = 20 * 1024 * 1024
+_STATE_BUFFERS = 4
+_VMEM_BESIDE_BYTES = 1024 * 1024
+_VMEM_DEFAULT_BYTES = 16 * 1024 * 1024  # what a kernel gets unasked
+
+
+def _heads_a_block(H: int, G: int, dk: int, dv: int) -> int:
+    """Heads of [d_k, d_v] float32 the update moves a grid step: a divisor
+    of H that is heads of ONE key or every head of several (H / G heads
+    read a key), the most whose buffers fit ``STATE_BLOCKS_BYTES``."""
+    share = H // G
+    return max(d for d in range(1, H + 1)
+               if H % d == 0 and (share % d == 0 or d % share == 0)
+               and (_STATE_BUFFERS * d * dk * dv * 4 <= STATE_BLOCKS_BYTES
+                    or d == 1))
+
+
 def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,
                    o_ref, s_out):
     del layer_ref, order_ref  # the block indices read them
@@ -189,6 +228,8 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
     order = jnp.argsort(~active, stable=True).astype(jnp.int32)
     live = jnp.sum(active).astype(jnp.int32).reshape(1)
 
+    vmem = _STATE_BUFFERS * group * dk * dv * 4 + _VMEM_BESIDE_BYTES
+
     def at(i, j, layer_ref, order_ref, live_ref):
         last = jnp.maximum(live_ref[0] - 1, 0)
         on = i < live_ref[0]
@@ -224,6 +265,8 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operands count the scalars: the state is the sixth
         input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem if vmem > _VMEM_DEFAULT_BYTES else None),
         interpret=interpret,
         name="lightning_update",
     )(jnp.asarray(layer, jnp.int32).reshape(1), order, live, kq, vec,
@@ -256,10 +299,6 @@ def decode_update(state, layer, q, k, v, g, active):
             f"on the TPU the lightning update moves whole tiles, and a "
             f"[{dk}, {dv}] state is not made of them: d_k must be a "
             f"multiple of 8 and d_v of 128")
-    # heads a block: 0.5 MB of state (8 at [128, 128], 4 at [256, 128]),
-    # and where heads share a key, heads of one key
-    share = H if G == H else H // G
-    group = max(d for d in range(1, share + 1)
-                if share % d == 0 and d * dk * dv * 4 <= (1 << 19) or d == 1)
-    return _decode_update(state, layer, q, k, v, g, active, group=group,
+    return _decode_update(state, layer, q, k, v, g, active,
+                          group=_heads_a_block(H, G, dk, dv),
                           interpret=not on_tpu)

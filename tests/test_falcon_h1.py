@@ -146,6 +146,67 @@ def test_decode_update_equals_the_recurrence(shape):
     assert float(jnp.abs(state[0] - 1).max()) == 0  # another layer's rows
 
 
+_LIVE = (False, True, False, True, True, False)  # dead slots between live
+
+
+@pytest.mark.parametrize("shape,groups", [
+    # heads of one key, every head of two keys, the whole slot
+    ((8, 4, 16, 16), (2, 4, 8)),
+    ((12, 2, 8, 16), (3, 6, 12)),
+    ((8, 8, 16, 16), (4, 8)),  # a head its own key
+])
+def test_a_block_of_several_keys_equals_one_head_a_block(shape, groups):
+    """The block the rule chooses (here a slot's whole state: every key)
+    and each smaller one give, BIT FOR BIT, what one head a block gives,
+    and that is the recurrence; a slot that is not live is not written."""
+    H, G, dk, dv = shape
+    B = len(_LIVE)
+    q, k, v, g, _ = _draw(sum(shape), B, H, G, dk, dv)
+    live = jnp.asarray(_LIVE)
+    S = jnp.asarray(np.random.default_rng(H).standard_normal(
+        (2, B, H, dk, dv)), jnp.float32)
+
+    def update(group):
+        return lightning._decode_update(S, jnp.int32(1), q, k, v, g, live,
+                                        group=group, interpret=True)
+
+    assert lightning._heads_a_block(H, G, dk, dv) == H
+    o1, S1 = update(1)
+    for got_o, got_S in (lightning.decode_update(S, jnp.int32(1), q, k, v, g,
+                                                 live),
+                         *(update(group) for group in groups)):
+        np.testing.assert_array_equal(got_o, o1)
+        np.testing.assert_array_equal(got_S, S1)
+    np.testing.assert_array_equal(S1[0], S[0])  # another layer's rows
+    for b in range(B):
+        if not _LIVE[b]:
+            np.testing.assert_array_equal(S1[1, b], S[1, b])
+            assert not np.asarray(o1[b]).any()
+            continue
+        want_o, want_S = lightning.recurrent(
+            q[b:b + 1], k[b:b + 1], v[b:b + 1], g[b:b + 1], S[1, b])
+        np.testing.assert_allclose(o1[b], want_o[0], atol=2e-4, rtol=1e-5)
+        np.testing.assert_allclose(S1[1, b], want_S, atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("shape,heads", [
+    ((32, 2, 256, 128), 32),    # falcon_h1_34b_serve_1chip: 4.19 MB a slot
+    ((32, 32, 128, 128), 32),   # minicpm_sala_serve_1chip: 2.1 MB
+    ((128, 8, 128, 128), 64),   # 8.4 MB a slot: four of the eight keys
+    ((64, 2, 256, 128), 32),    # one key's heads, 4.19 MB of 8.4
+    ((96, 2, 256, 128), 24),    # 48 heads a key: half a key's, no 32
+    ((2, 1, 4096, 1024), 1),    # a head past the budget alone: one a block
+])
+def test_a_block_is_the_most_heads_whose_buffers_fit(shape, heads):
+    """From the state's shape alone: whole keys or heads of one key, a
+    divisor of the heads, four buffers of it inside the budget."""
+    assert lightning._heads_a_block(*shape) == heads
+    H, G, dk, dv = shape
+    assert H % heads == 0 and (heads % (H // G) == 0 or (H // G) % heads == 0)
+    assert (4 * heads * dk * dv * 4 <= lightning.STATE_BLOCKS_BYTES
+            or heads == 1)
+
+
 def test_decode_update_refuses_heads_that_share_no_key():
     q, k, v, g, S0 = _draw(0, 2, H=6, G=4)
     with pytest.raises(ValueError, match="do not hold"):

@@ -148,6 +148,42 @@ def test_chunked_against_recurrent_from_a_state(start):
     assert float(jnp.max(jnp.abs(state[0]))) == 0.0
 
 
+@pytest.mark.parametrize("heads,groups", [(8, (2, 4, 8)), (16, (8, 16))])
+def test_the_whole_slot_in_a_block_equals_one_head_a_block(heads, groups):
+    """A fixed decay, a head its own key: the block the rule chooses (every
+    head of a slot, as many keys) and each smaller one give, BIT FOR BIT,
+    what one head a block gives, and that is the recurrence; dead slots
+    between live ones keep their rows."""
+    d, live = 16, (True, False, False, True, False, True, True)
+    B = len(live)
+    ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+    q, k, v = (jax.random.normal(key, (B, heads, d)) for key in ks[:3])
+    S = jax.random.normal(ks[3], (3, B, heads, d, d))
+    g = jnp.broadcast_to(lightning.log_decays(heads), (B, heads))
+    active = jnp.asarray(live)
+
+    def update(group):
+        return lightning._decode_update(S, 2, q, k, v, g, active, group=group,
+                                        interpret=True)
+
+    assert lightning._heads_a_block(heads, heads, d, d) == heads
+    o1, S1 = update(1)
+    for got_o, got_S in (lightning.decode_update(S, 2, q, k, v, g, active),
+                         *(update(group) for group in groups)):
+        np.testing.assert_array_equal(got_o, o1)
+        np.testing.assert_array_equal(got_S, S1)
+    np.testing.assert_array_equal(S1[:2], S[:2])  # the other layers' rows
+    for b in range(B):
+        if not live[b]:
+            np.testing.assert_array_equal(S1[2, b], S[2, b])
+            assert not np.asarray(o1[b]).any()
+            continue
+        want_o, want_S = lightning.recurrent(
+            q[b:b + 1], k[b:b + 1], v[b:b + 1], g[b:b + 1], S[2, b])
+        np.testing.assert_allclose(o1[b], want_o[0], atol=1e-4)
+        np.testing.assert_allclose(S1[2, b], want_S, atol=1e-4)
+
+
 @pytest.mark.parametrize("n", [24, 130, 200, 256, 257, 300, 391, 450, 512])
 def test_selection_against_the_reference(n):
     """Forced blocks, ties (neighbouring blocks share a pooled row; here

@@ -136,6 +136,63 @@ def test_decode_update_touches_live_slots_of_one_layer_only(live):
         np.testing.assert_allclose(after[1, b], want_S, atol=1e-6)
 
 
+@pytest.mark.parametrize("H,dv,groups", [(16, 64, (2, 4, 8)),
+                                         (10, 64, (5,))])
+def test_every_pack_in_a_block_equals_one_pack_a_block(H, dv, groups):
+    """The block the rule chooses (a slot's whole state: every head pack)
+    and each smaller one give, BIT FOR BIT, what one pack a block gives,
+    and that is the recurrence; dead slots between live ones keep their
+    rows."""
+    dk, live = 16, (False, True, False, False, True, True, False)
+    B = len(live)
+    pack = gated_delta.head_pack(H, dv)
+    packs = H // pack
+    assert packs == groups[-1] > 1
+    q, k, v, g, beta, _ = _draw(H, B, H=H, dk=dk, dv=dv)
+    S = jax.random.normal(jax.random.PRNGKey(dv), (2, B, H, dv, dk)) * 0.3
+    packed = gated_delta.pack_state(S, pack)
+    active = jnp.asarray(live)
+
+    def update(group):
+        return gated_delta._decode_update(
+            packed, jnp.int32(0), q, k, v, g, beta, active, pack=pack,
+            group=group, interpret=True)
+
+    assert gated_delta._packs_a_block(packs, dk, pack * dv) == packs
+    o1, S1 = update(1)
+    for got_o, got_S in (gated_delta.decode_update(
+            packed, jnp.int32(0), q, k, v, g, beta, active, pack=pack),
+                         *(update(group) for group in groups)):
+        np.testing.assert_array_equal(got_o, o1)
+        np.testing.assert_array_equal(got_S, S1)
+    after = gated_delta.unpack_state(S1, pack)
+    np.testing.assert_array_equal(after[1], S[1])  # the other layer's rows
+    for b in range(B):
+        if not live[b]:
+            np.testing.assert_array_equal(after[0, b], S[0, b])
+            assert not np.asarray(o1[b]).any()
+            continue
+        want_o, want_S = gated_delta.recurrent(
+            q[b:b + 1], k[b:b + 1], v[b:b + 1], g[b:b + 1], beta[b:b + 1],
+            S[0, b])
+        np.testing.assert_allclose(o1[b], want_o[0], atol=1e-6)
+        np.testing.assert_allclose(after[0, b], want_S, atol=1e-6)
+
+
+@pytest.mark.parametrize("packs,dk,width,block", [
+    (15, 96, 384, 5),    # olmo_hybrid7b_serve_1chip: 0.74 MB of 2.21 a slot
+    (64, 96, 384, 4),    # 7 would fit, 4 divides
+    (14, 128, 1024, 2),  # 0.5 MB a pack: 2 fit, 7 do not
+    (3, 2048, 1024, 1),  # a pack past the budget alone: one a block
+])
+def test_a_block_is_the_most_packs_whose_buffers_fit(packs, dk, width,
+                                                     block):
+    assert gated_delta._packs_a_block(packs, dk, width) == block
+    assert packs % block == 0
+    assert (4 * block * dk * width * 4 <= gated_delta.STATE_BLOCKS_BYTES
+            or block == 1)
+
+
 def test_decode_update_refuses_what_it_cannot_take_by_name(monkeypatch):
     q, k, v, g, beta, _ = _draw(0, 2, H=4, dk=12, dv=32)
     with pytest.raises(ValueError, match="packed float32 state"):

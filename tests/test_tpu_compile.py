@@ -440,7 +440,7 @@ def test_parallel_ssm_programs_compile_at_falcon_h1_widths(topo, as_tpu,
     each plans at or under 0.85 of the chip's bytes_limit; pools AND state
     rows are aliased to the outputs and held once; the decode step updates
     a slot's [32, 256, 128] state through the ``lightning_update`` kernel
-    (four heads a block, a block's heads reading ONE key column) and
+    (the slot's 32 heads a block, both key columns, in 17 MiB of VMEM) and
     attends through the paged one IN THE SAME LAYER."""
     one = SingleDeviceSharding(topo.devices[0])
     cfg = falcon_h1.FalconH1Config(n_layers=6, max_seq_len=2048)
@@ -911,6 +911,33 @@ def test_state_update_kernel_compiles_and_writes_in_place(topo, as_tpu):
     assert m.alias_size_in_bytes >= 12 * 32 * 15 * 96 * 384 * 4
     assert m.temp_size_in_bytes < 16e6
     assert "gated_delta_update" in compiled.as_text()
+
+
+@pytest.mark.parametrize("slots,heads,keys,dk", [
+    (64, 32, 2, 256),    # falcon_h1_34b_serve_1chip: 16 heads to a key
+    (32, 32, 32, 128),   # minicpm_sala_serve_1chip: a head its own key
+])
+def test_fixed_decay_update_kernel_compiles_and_writes_in_place(
+        topo, as_tpu, slots, heads, keys, dk):
+    """``ops/lightning.decode_update`` alone at the two cells' shapes: a
+    block of its own choosing (a slot's whole state of a layer) fits the
+    VMEM the kernel asks for, the state is aliased to the kernel's output,
+    and nothing state-sized is planned beside it."""
+    from ray_tpu.ops import lightning
+
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+
+    compiled = jax.jit(lightning.decode_update, donate_argnums=0).lower(
+        f32(6, slots, heads, dk, 128),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
+        f32(slots, keys, dk), f32(slots, keys, dk), f32(slots, heads, 128),
+        f32(slots, heads),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 6 * slots * heads * dk * 128 * 4
+    assert m.temp_size_in_bytes < 16e6
+    assert "lightning_update" in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [
