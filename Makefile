@@ -69,33 +69,13 @@ sanitize-store:
 obs-smoke:
 	JAX_PLATFORMS=cpu python -m ray_tpu.scripts.obs_smoke
 
-# Object-store data plane in isolation: StoreClient put/get at 1KB/10MB,
-# single and multi client, one JSON line on stdout (BENCH_core.json's
-# full-stack equivalents are the comparison baseline).
-bench-store:
-	JAX_PLATFORMS=cpu python -m ray_tpu._private.store_bench
-
-# Data-service bench: ViT-style decode+augment pipeline, 4 consumers
-# sharing one named job (first-epoch cache) vs 4 independent pipelines.
-# One JSON line on stdout; the committed BENCH_data.json is its capture.
-bench-data:
-	JAX_PLATFORMS=cpu python -m ray_tpu._private.data_bench | tee BENCH_data.json
-
-# Serving load wall: a concurrency ladder of shared-prefix traffic over
-# two real LLM engines behind the real request routers (pow-2 vs
-# prefix-aware), page pool sized below the working set so the top rung
-# hits eviction + preemption.  The committed BENCH_serve.json is its
-# capture.
-bench-serve:
-	JAX_PLATFORMS=cpu python -m ray_tpu._private.serve_bench | tee BENCH_serve.json
-
 # Control-plane scale envelope: 1M queued plain tasks through the native
 # raylet lane (queue-time spillback path active, shape-indexed backlog),
-# plus the actor/PG/node scenarios.  Writes BENCH_scale.json; the
-# committed file is its round-over-round capture.  The pytest smoke
+# plus the actor/PG/node scenarios.  A host measurement of host code: one
+# JSON line a scenario on stdout, no file.  The pytest smoke
 # (tests/test_scale_smoke.py) runs --quick; the big envelope is the
 # @slow test.
 bench-scale:
 	JAX_PLATFORMS=cpu python -m ray_tpu._private.scale_bench
 
-.PHONY: sanitize sanitize-store check check-fast test obs-smoke bench-store bench-data bench-serve bench-scale
+.PHONY: sanitize sanitize-store check check-fast test obs-smoke bench-scale

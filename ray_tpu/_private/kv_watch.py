@@ -1,7 +1,7 @@
 """Event-driven KV waits over GCS pubsub.
 
-Replaces sleep-polling of GCS KV keys (the round-2 collective rendezvous
-spun at 2ms — VERDICT item: "polling everywhere there should be events").
+Replaces sleep-polling of GCS KV keys (the collective rendezvous spun at
+2ms: polling where there should be events).
 One background thread per (gcs_address, namespace) holds a long-poll
 subscription to the ``kv:<namespace>`` channel and wakes registered waiters
 when their key is written.  Reference counterpart: the long-poll subscriber

@@ -4,7 +4,9 @@ via `ray microbenchmark`): task/actor-call/put throughput on one node.
 Baseline targets from the reference's committed CI numbers
 (release/perf_metrics/microbenchmark.json, BASELINE.md): 1:1 sync actor
 calls 2,020/s; n:n async 27,465/s; multi-client puts 15,797/s.  Run:
-``python -m ray_tpu.scripts.cli microbenchmark``.
+``python -m ray_tpu.scripts.cli microbenchmark``.  It measures the host's
+control plane and prints: one line a row, then the whole record (rates and
+their ratios to the reference's) as the last JSON line.  It writes no file.
 """
 
 from __future__ import annotations
@@ -171,11 +173,9 @@ def main():
         ray_tpu.kill(p)
 
     summary = {r["name"]: round(r["rate_per_s"], 1) for r in results}
-    print(json.dumps({"microbenchmark": summary}))
 
-    # Record against the reference's committed CI numbers
-    # (release/perf_metrics/microbenchmark.json via BASELINE.md) so the
-    # core-perf trajectory is tracked in-repo.
+    # Against the reference's committed CI numbers
+    # (release/perf_metrics/microbenchmark.json via BASELINE.md).
     reference = {
         "1:1 actor calls sync": 2020.0,
         "1:1 actor calls async (batch 50)": 7484.0,
@@ -193,11 +193,7 @@ def main():
         },
         "reference_source": "release/perf_metrics/microbenchmark.json",
     }
-    try:
-        with open("BENCH_core.json", "w") as f:
-            json.dump(record, f, indent=1)
-    except OSError:
-        pass
+    print(json.dumps({"microbenchmark": record}))
     return results
 
 

@@ -24,10 +24,10 @@ control-plane stack at a scaled envelope and records sustained rates:
             in-process nodes, then removed
   nodes   — those 20 nodes registering + heartbeating
 
-Run: ``python -m ray_tpu._private.scale_bench [--quick]``; writes
-BENCH_scale.json at the repo root (tracked round-over-round like
-BENCH_core.json).  The pytest smoke (tests/test_scale_smoke.py) runs the
-same scenarios at 1/50 scale.
+Run: ``python -m ray_tpu._private.scale_bench [--quick]``; it prints one
+JSON line a scenario and the whole record as the last line, and writes no
+file.  The pytest smoke (tests/test_scale_smoke.py) runs the same
+scenarios at 1/50 scale.
 """
 
 from __future__ import annotations
@@ -275,7 +275,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="1/50-scale smoke (CI)")
-    ap.add_argument("--out", default="BENCH_scale.json")
     args = ap.parse_args()
     scale = 50 if args.quick else 1
 
@@ -290,9 +289,6 @@ def main():
         n_nodes=max(3, 20 // scale), n_pgs=max(4, 100 // scale))
     print(json.dumps({"pgs_nodes": record["pgs_nodes"]}), flush=True)
 
-    if not args.quick:
-        with open(args.out, "w") as f:
-            json.dump(record, f, indent=1)
     print(json.dumps({"scale_bench": record}))
 
 

@@ -126,10 +126,10 @@ ALLOWLIST: list[Allow] = [
                  "paging story."),
     Allow("metrics/family-unconsumed",
           "ray_tpu/serve/request_router/base.py", "'serve_",
-          reason="router imbalance/prefix-hit gauges are bench+top "
-                 "diagnostics for routing-policy comparisons "
-                 "(BENCH_serve.json); thresholds are policy-dependent so "
-                 "no fixed rule names them."),
+          reason="router imbalance/prefix-hit gauges are rtpu serve / "
+                 "rtpu top diagnostics for routing-policy comparisons; "
+                 "thresholds are policy-dependent so no fixed rule names "
+                 "them."),
     Allow("metrics/family-unconsumed", "ray_tpu/util/goodput.py",
           "'train_",
           reason="step-anatomy shadows of the goodput report "

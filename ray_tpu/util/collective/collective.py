@@ -120,11 +120,11 @@ def _wait_kv(key: str, timeout: float) -> bytes:
     w = _ctx()
     if w.gcs_address:
         # Event-driven wait: subscribe to the collective KV channel and
-        # sleep until the key's write event arrives (VERDICT round-2: the
-        # 2ms rendezvous spin burned the very core the control plane runs
-        # on).  Register BEFORE checking so a write between check and wait
-        # cannot be lost; periodic re-checks guard against a dropped event
-        # ring (gap wakes handle the common case).
+        # sleep until the key's write event arrives (a 2ms rendezvous
+        # spin burned the very core the control plane runs on).  Register
+        # BEFORE checking so a write between check and wait cannot be
+        # lost; periodic re-checks guard against a dropped event ring (gap
+        # wakes handle the common case).
         from ray_tpu._private import kv_watch
 
         watcher = kv_watch.get_watcher(w.gcs_address, _KV_NS)

@@ -1,9 +1,9 @@
 """Autoscaler v2: instance FSM reconciliation + TPU slice atomicity.
 
-VERDICT round-2 item 7: declarative desired/actual reconciliation and a
-provider whose unit is an atomic multi-host TPU slice.  These tests drive
-the reconciler deterministically (tick by tick) against fake GCS/provider
-shims — the same strategy the reference uses for autoscaler v2 unit tests
+Declarative desired/actual reconciliation and a provider whose unit is an
+atomic multi-host TPU slice.  These tests drive the reconciler
+deterministically (tick by tick) against fake GCS/provider shims — the same
+strategy the reference uses for autoscaler v2 unit tests
 (python/ray/autoscaler/v2/tests/).
 """
 

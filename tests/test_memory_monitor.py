@@ -1,7 +1,7 @@
 """Memory monitor + worker-killing policy.
 
-VERDICT round-2 item 6 (reference: src/ray/common/memory_monitor.h:52 +
-raylet worker_killing_policy_retriable_fifo.cc): memory pressure kills ONE
+Reference: src/ray/common/memory_monitor.h:52 + raylet
+worker_killing_policy_retriable_fifo.cc.  Memory pressure kills ONE
 policy-chosen worker — a retriable task retries transparently, a
 non-retriable one surfaces OutOfMemoryError with provenance — and the node
 (scheduler + store daemon) survives.  Pressure is injected by driving the

@@ -349,9 +349,11 @@ def tiny_model():
     return params, cfg
 
 
-def _run_policy(tiny_model, router_cls, seed):
-    """Two real engines behind a router; shared-prefix traffic; returns
-    the aggregate prefix-cache hit rate across both engines."""
+def _run_policy(tiny_model, router_cls, seed, families, num_pages):
+    """Two real engines behind a router; shared-prefix traffic, ten
+    requests a family in turn; returns the aggregate prefix-cache hit
+    rate across both engines, each engine's page evictions and the
+    prefill tokens the cache saved."""
     from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
 
     params, cfg = tiny_model
@@ -359,18 +361,19 @@ def _run_policy(tiny_model, router_cls, seed):
     reps = []
     for name in (b"e1", b"e2"):
         eng = LLMEngine(params, cfg, EngineConfig(
-            max_slots=4, num_pages=64, page_size=8, max_seq_len=256,
-            prefill_buckets=(16, 32, 64)))
+            max_slots=4, num_pages=num_pages, page_size=8,
+            max_seq_len=256, prefill_buckets=(16, 32, 64)))
         engines[name] = eng
         reps.append(FakeReplica(name))
-    router = router_cls("app", f"bench-{router_cls.__name__}-{seed}")
+    router = router_cls(
+        "app", f"bench-{router_cls.__name__}-{seed}-{families}")
     router.update_replicas(reps)
     random.seed(seed)
     rng = random.Random(seed)
-    groups = [[1 + g, 2 + g, 3 + g, 4 + g] * 6 for g in range(3)]
+    groups = [[1 + g, 2 + g, 3 + g, 4 + g] * 6 for g in range(families)]
     try:
-        for i in range(30):
-            g = i % 3
+        for i in range(10 * families):
+            g = i % families
             prompt = groups[g] + [rng.randrange(1, 128) for _ in range(4)]
             hint = f"group-{g}:" + "p" * 48
             rep = router.choose(hint)
@@ -382,23 +385,41 @@ def _run_policy(tiny_model, router_cls, seed):
                 rid: {"queue_len": 0, "age_s": 0.0,
                       "engine": e.stats()}
                 for rid, e in engines.items()})
-        hits = sum(e.stats()["prefix_cache"]["hit_tokens"]
-                   for e in engines.values())
-        lookups = sum(e.stats()["prefix_cache"]["lookup_tokens"]
-                      for e in engines.values())
-        return hits / max(lookups, 1)
+        stats = [e.stats() for e in engines.values()]
+        hits = sum(s["prefix_cache"]["hit_tokens"] for s in stats)
+        lookups = sum(s["prefix_cache"]["lookup_tokens"] for s in stats)
+        return {"hit_rate": hits / max(lookups, 1),
+                "evictions": [s["page_evictions"] for s in stats],
+                "saved": sum(s["prefill_tokens_saved"] for s in stats)}
     finally:
         for e in engines.values():
             e.stop()
 
 
-def test_prefix_aware_beats_pow2_hit_rate(tiny_model):
-    aware = _run_policy(tiny_model, PrefixAwareRouter, seed=11)
-    pow2 = _run_policy(tiny_model, Pow2Router, seed=11)
+# A family's prefix is three pages and a request holds a fourth.  Roomy:
+# every family stays resident wherever it lands.  Pressed: a prefix-aware
+# home holds three of six families (nine pages) in a pool of ten, so both
+# policies evict prefix pages for the whole run.
+@pytest.mark.parametrize("families,num_pages,pressed",
+                         [(3, 64, False), (6, 10, True)],
+                         ids=["roomy", "pool_below_family_set"])
+def test_prefix_aware_beats_pow2_hit_rate(tiny_model, families, num_pages,
+                                          pressed):
+    aware = _run_policy(tiny_model, PrefixAwareRouter, 11, families,
+                        num_pages)
+    pow2 = _run_policy(tiny_model, Pow2Router, 11, families, num_pages)
     # same traffic, same engines: KV-locality routing must convert more
     # lookups into warm-page hits than blind load balancing
-    assert aware > pow2, (aware, pow2)
-    assert aware >= 0.5, aware  # sticky homes make most prefixes warm
+    assert aware["hit_rate"] > pow2["hit_rate"], (aware, pow2)
+    assert aware["saved"] > 0, aware
+    evictions = aware["evictions"] + pow2["evictions"]
+    if pressed:
+        # the scene is under the load it claims: every engine evicted
+        assert all(evictions), (aware, pow2)
+    else:
+        assert not any(evictions), (aware, pow2)
+        # sticky homes make most prefixes warm
+        assert aware["hit_rate"] >= 0.5, aware
 
 
 # ------------------------------------- cache/COW byte-identical decode

@@ -118,8 +118,8 @@ def test_zigzag_gradients_match_dense():
 
 
 def test_zigzag_balances_causal_work():
-    """The point of zigzag (VERDICT round-2 item 5): with contiguous
-    sharding the per-shard unmasked area ranges ~sp-fold across the ring;
+    """The point of zigzag: with contiguous sharding the per-shard
+    unmasked area ranges ~sp-fold across the ring;
     zigzag pins every shard's total work to within one block of uniform.
     Computed analytically from the layout (multi-device wall-clock cannot
     be observed on a host-emulated mesh)."""

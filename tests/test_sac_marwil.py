@@ -1,10 +1,10 @@
 """SAC (discrete) + MARWIL (offline): learning-progress tests on CartPole.
 
-VERDICT round-2 item 10: +SAC and an offline algorithm on the existing
-env-runner/learner split.  Mirrors the reference's learning tests
-(rllib/algorithms/sac/tests, rllib/algorithms/marwil/tests): train a small
-number of iterations on the CPU mesh and assert a reward threshold — not
-convergence to optimal, which would be flaky on one core.
+SAC and an offline algorithm on the existing env-runner/learner split.
+Mirrors the reference's learning tests (rllib/algorithms/sac/tests,
+rllib/algorithms/marwil/tests): train a small number of iterations on the
+CPU mesh and assert a reward threshold — not convergence to optimal, which
+would be flaky on one core.
 """
 
 import numpy as np

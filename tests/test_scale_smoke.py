@@ -38,8 +38,8 @@ def test_scale_bench_quick_completes():
 
 @pytest.mark.slow
 def test_scale_bench_big_envelope_tasks():
-    """The 1M-queued-task envelope (what `make bench-scale` records in
-    BENCH_scale.json): streamed submit, measured queue peak past 500k,
+    """The 1M-queued-task envelope (what `make bench-scale` prints):
+    streamed submit, measured queue peak past 500k,
     sustained dispatch.  Excluded from tier-1 (`-m 'not slow'`) — this
     is minutes of wall clock."""
     script = (

@@ -1,7 +1,7 @@
 """Wire codec: round trips, struct tolerance, and the malformed-frame fuzz.
 
-VERDICT round-2 item 3: control frames must be schema'd, versioned, and —
-the security property — a malformed frame must not be able to execute code.
+Control frames must be schema'd, versioned, and — the security property —
+a malformed frame must not be able to execute code.
 The fuzz here feeds random bytes, truncations, bit flips, and actual pickle
 payloads to the decoder and asserts the only outcomes are a decoded value or
 ``WireError``.
