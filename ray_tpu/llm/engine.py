@@ -53,6 +53,7 @@ from ray_tpu.llm.config import EngineConfig, SamplingParams
 from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
                                      init_cache, init_state)
+from ray_tpu.ops import paged_attention
 from ray_tpu.ops.gated_delta import CHUNK as SCAN_CHUNK
 from ray_tpu.util import tracing
 
@@ -137,6 +138,13 @@ def _engine_metrics():
                     "llm_decode_pages_read_total", "KV pages the decode "
                     "kernel walked: per step and active slot, the pages "
                     "its position reaches"),
+                "decode_pages_in_runs": Counter(
+                    "llm_decode_pages_in_runs_total", "Of those, pages "
+                    "that moved several to a copy: their table entries "
+                    "were page ids in a row (counted on the host from the "
+                    "tables a burst is handed; a pool whose pages move one "
+                    "by one counts none, nor do walks through lists the "
+                    "device chooses)"),
                 "latent_pages_read": Counter(
                     "llm_latent_pages_read_total", "Of those, pages of "
                     "LATENT rows (one row a token a layer, key and value "
@@ -190,6 +198,11 @@ def _engine_metrics():
                     "decode step's kernel walked a layer, from the page "
                     "that holds length - window to the slot's length, "
                     "summed over steps and active slots"),
+                "window_pages_in_runs": Counter(
+                    "llm_window_pages_in_runs_total", "Window layers: of "
+                    "the pages read, those that moved several to a copy "
+                    "(the window pool's table, as decode_pages_in_runs "
+                    "counts the other's)"),
                 "window_pages_skipped": Counter(
                     "llm_window_pages_skipped_total", "Window layers: "
                     "pages up to a slot's length that the bound left "
@@ -717,6 +730,14 @@ class LLMEngine:
         # pools by layer type each is a dict of a pool a kind)
         self.cache_k, self.cache_v = init_cache(ccfg)
         self._latent = "latent_dim" in layout
+        # pages a block and a copy of the decode kernel's walk (the second
+        # 1: a pool whose pages move one by one, and a family whose walks
+        # go through lists the device chooses: the host sees none of them)
+        pool = jax.tree_util.tree_leaves(self.cache_k)[0]
+        self._walk_block, self._run_pages = paged_attention.walk_blocks(
+            pool.shape, pool.dtype.itemsize, self.max_pages_per_seq)
+        if layout.get("page_rows"):
+            self._run_pages = 1
         # recurrent layers' rows, a slot each (None: the model has none)
         self.state = init_state(ccfg)
         self._state_layers = ccfg.state_layers
@@ -755,7 +776,8 @@ class LLMEngine:
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
         self._stats = {"prefills": 0, "decode_steps": 0,
-                       "decode_pages_read": 0, "latent_pages_read": 0,
+                       "decode_pages_read": 0, "decode_pages_in_runs": 0,
+                       "latent_pages_read": 0, "window_pages_in_runs": 0,
                        "block_slot_passes": 0,
                        "masks_filled": 0, "blocks_final": 0,
                        "experts_read": 0, "moe_local_rows": 0,
@@ -2025,6 +2047,9 @@ class LLMEngine:
         # what the family adds to a burst's counts, by name: by the kind of
         # its cache here, and below what its steps' programs counted
         named = {}
+        if self._run_pages > 1:  # small pages: what moves merged
+            named["decode_pages_in_runs"] = self._pages_in_runs(
+                tables[active], positions[active], burst)
         if self._latent:
             named["latent_pages_read"] = pages_read
         if self._window:
@@ -2042,6 +2067,9 @@ class LLMEngine:
             named.update(full_pages_read=pages_read,
                          window_pages_read=pages_read - skipped,
                          window_pages_skipped=skipped)
+            if self._run_pages > 1:
+                named["window_pages_in_runs"] = self._pages_in_runs(
+                    wtables[active], positions[active], burst, self._window)
         if self.state is not None:
             named["state_slot_steps"] = burst * len(active_slots)
         counts = []  # a step's ``counted``, on the device
@@ -2093,6 +2121,33 @@ class LLMEngine:
                 ph.vals = dict(zip(_PHASE_ATTRS[P_DECODE_EMIT], ph.vals),
                                steps=burst, **named)
         return True
+
+    def _merged_upto(self, tables):
+        """[S, blocks + 1]: the pages of each table's (a slot a row) first
+        0, 1, .. whole blocks that lie in a block the kernel moves several
+        pages a copy (``paged_attention.blocks_in_runs``)."""
+        runs = np.cumsum(paged_attention.blocks_in_runs(
+            tables, self._walk_block, self._run_pages), axis=1)
+        return self._walk_block * np.concatenate(
+            [np.zeros_like(runs[:, :1]), runs], axis=1)
+
+    def _pages_in_runs(self, tables, positions, burst: int,
+                       window: int = 0) -> int:
+        """Of the pages a burst's walks read through ``tables`` (a live
+        slot a row), those in a block the kernel moves several pages a copy
+        (``paged_attention.block_kinds`` == 2 over each step's lengths,
+        here as differences of one running sum a slot)."""
+        ps, ppb = self.cfg.page_size, self._walk_block
+        lengths = np.minimum(positions[:, None] + 1 + np.arange(burst),
+                             tables.shape[1] * ps)
+        # whole blocks under a step's length, and wholly behind its start
+        upto = -(-lengths // ps) // ppb
+        behind = np.minimum(
+            -(-(np.maximum(lengths - window, 0) // ps) // ppb)
+            if window else 0, upto)
+        runs = self._merged_upto(tables)
+        return int((np.take_along_axis(runs, upto, axis=1)
+                    - np.take_along_axis(runs, behind, axis=1)).sum())
 
     def _count(self, did: dict) -> None:
         """Add what was done, by name, to ``stats()`` and the metrics."""
@@ -2150,7 +2205,11 @@ class LLMEngine:
         state = (jnp.asarray(tokens), jnp.asarray(masked),
                  jnp.asarray(starts), jnp.asarray(step))
         counted = ("tokens_generated", "block_slot_passes", "masks_filled",
-                   "blocks_final", "experts_read", "decode_pages_read")
+                   "blocks_final", "experts_read", "decode_pages_read",
+                   "decode_pages_in_runs")
+        # small pages: the pages in merged blocks up to each block of a
+        # slot's table
+        runs = self._merged_upto(tables) if self._run_pages > 1 else None
         before = [self._stats[k] for k in counted]
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))
@@ -2173,7 +2232,7 @@ class LLMEngine:
             self._stats["experts_read"] += row[0][2 * B + 1]  # in every row
             for i, s in active_slots:
                 if self._slots[i] is s:  # else finished earlier in the burst
-                    self._accept_pass(i, s, row[i])
+                    self._accept_pass(i, s, row[i], runs)
         did = {k: self._stats[k] - b for k, b in zip(counted, before)}
         for k in counted[1:]:  # _emit counts the tokens itself
             self._m[k].inc(did[k])
@@ -2183,17 +2242,21 @@ class LLMEngine:
                        did["block_slot_passes"], did["masks_filled"],
                        did["blocks_final"], did["experts_read"], burst)
 
-    def _accept_pass(self, i: int, s: _Slot, record) -> None:
+    def _accept_pass(self, i: int, s: _Slot, record, runs=None) -> None:
         """Replay one pass's record (a list) for slot i: the block after
         the pass [B], its masks after it [B], whether the pass made it
-        final."""
+        final.  ``runs``: a slot a row, the pages in merged blocks up to
+        each block of its table (None: its pool's pages move singly)."""
         B = self._block
         stats = self._stats
         stats["block_slot_passes"] += 1
         # the kernel walked the pages up to the block's end
-        stats["decode_pages_read"] += min(
-            (s.num_tokens + B - 1) // self.cfg.page_size + 1,
-            self.max_pages_per_seq)
+        pages = min((s.num_tokens + B - 1) // self.cfg.page_size + 1,
+                    self.max_pages_per_seq)
+        stats["decode_pages_read"] += pages
+        if runs is not None:
+            stats["decode_pages_in_runs"] += int(
+                runs[i, pages // self._walk_block])
         if record[2 * B]:
             # the input held no mask: the K/V this pass wrote is final and
             # the next block opens, all masks

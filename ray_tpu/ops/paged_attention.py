@@ -20,7 +20,28 @@ layer ride in SMEM (scalar prefetch); q and the output sit whole in VMEM
 hide a copy's latency behind its own compute, so a block's pages are copied
 together into one of two VMEM buffers while the other is being computed on,
 and the prefetch runs across slot boundaries (the last block of a slot
-starts the first block of the next active one).  Pages past a slot's length
+starts the first block of the next active one).
+
+WHAT A COPY COSTS.  A page copy costs the kernel's scalar core the same
+whatever it moves: two predicates ~15 ns, the issue of its descriptor ~12 ns,
+its wait ~3 ns (a v5e, ``time_paged_walk.py``; PERF.md section 6, PR 60),
+and that time is not hidden behind the block's products but ADDED to them
+(the walk with its products taken out and with its copies taken out sum to
+the whole walk; only the bytes' flight is hidden).  ~33 ns is what the
+memory needs for ~24-32 KB: a pool of 32 KB pages pays about its bytes, a
+pool of smaller ones (4 or 2 KV heads, latent rows) pays for the COUNT of
+its copies and predicates.  So a pool whose page is under ``COPY_BYTES``
+walks the same pages in fewer copies, predicates and waits
+(``_run_copies``): a block that is reached whole issues its copies in a
+straight line and waits ONCE a pool, and where every aligned group of
+``RUN_PAGES`` table entries in it is page ids in a row (one contiguous
+stretch of the pool) a group moves in one copy (``block_kinds`` tests the
+table in the program, before the kernel, and a number a block rides in as
+one more prefetched array).  A prompt's pages are ids in a row because the
+allocator goes round its free list in page order (llm/paged_cache.py).  A
+pool of larger pages keeps the page-by-page program text for text.
+
+Pages past a slot's length
 are neither copied nor computed; a slot of length 0 is skipped and returns
 zeros.  With a ``window`` (a layer that sees the last ``window`` positions
 only, models/afmoe.py) the walk also has a LOWER bound: it begins at the
@@ -46,6 +67,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,19 +75,155 @@ from ray_tpu.ops.attention import NEG_INF
 
 # tokens a compute block holds unless the caller says otherwise: on a v5e,
 # 16-token pages of 8 KV heads x 128 read at 419 / 574 / 666 / 643 GB/s in
-# blocks of 4 / 8 / 16 / 32 pages (PERF.md, PR 25)
+# blocks of 4 / 8 / 16 / 32 pages (PERF.md, PR 25), i.e. 0.63 / 0.91 / 1.57 /
+# 3.26 us a block of 8 / 16 / 32 / 64 copies: ~0.3 us a block and ~40 ns a
+# copy until, at 2 MB, the bytes take over
 BLOCK_TOKENS = 256
+
+# The crossover: the page whose bytes take the memory as long as a copy takes
+# the kernel's scalar core (two predicates, the issue and the wait a page: 33
+# ns, (1.958 - 0.911) us over the 32 copies of a Trinity block with and
+# without its copies; 32 KB take 39-47 ns at the 819-685 GB/s the memory
+# gives; Mistral's 1 MB block, page by page, reads 1.63 us where its bytes
+# alone take 1.41: ``time_paged_walk.py``, PERF.md section 6, PR 60).
+# A pool whose page is UNDER it is bound by the count of its copies and moves
+# RUN_PAGES adjacent pages in one wherever its table names them in a row; a
+# page at or over it costs its bytes already and keeps the page-by-page
+# program.  Derived from the timing, read from the pool's shape: no argument,
+# no environment variable, no field of a configuration.
+COPY_BYTES = 32 * 1024
+RUN_PAGES = 4
+
+
+def walk_blocks(pool_shape, itemsize: int, table_width: int,
+                pages_per_block: int | None = None) -> tuple:
+    """(pages a block, pages a copy may move) of a walk, read from the
+    pool's shape (``[layers, pages, page_size, ...]``, K and V or latent
+    rows).  The second is 1, the page-by-page program, for a page at or
+    over ``COPY_BYTES`` and where groups of ``RUN_PAGES`` do not tile a
+    block; else ``RUN_PAGES``."""
+    ps = pool_shape[2]
+    if pages_per_block is None:
+        tokens = LATENT_BLOCK_TOKENS if len(pool_shape) == 4 else BLOCK_TOKENS
+        pages_per_block = max(1, tokens // ps)
+    ppb = min(pages_per_block, table_width)
+    small = math.prod(pool_shape[2:]) * itemsize < COPY_BYTES
+    return ppb, RUN_PAGES if small and ppb % RUN_PAGES == 0 else 1
+
+
+def blocks_in_runs(page_tables, ppb: int, run: int):
+    """[B, P // ppb] bool: whether every aligned group of ``run`` entries in
+    a whole block of a table [B, P] is page ids in a row (``p, p + 1, ..``:
+    one contiguous stretch of the pool), so that the block moves in ``ppb
+    // run`` copies.  Operators only: the host's numpy tables (llm/engine.py
+    counts what a burst will find) and the program's traced ones go through
+    the same test."""
+    B, P = page_tables.shape
+    n = P // ppb
+    t = page_tables[:, :n * ppb].reshape(B, n, ppb // run, run)
+    return (t[..., 1:] - t[..., :-1] == 1).all(axis=(-1, -2))
+
+
+def block_kinds(page_tables, lengths, starts, page_size: int, ppb: int,
+                run: int):
+    """[B, P // ppb] int: how a walk copies each whole block of a table: 0
+    page by page, each page under its predicate (an EDGE: the slot's length
+    ends inside the block, or its start under a window lies inside it or
+    past it); 1 reached whole: its ``ppb`` copies in a straight line and
+    one wait; 2 reached whole and every group a run (``blocks_in_runs``):
+    ``ppb // run`` copies.  Operators only, as ``blocks_in_runs``."""
+    first = ppb * np.arange(page_tables.shape[1] // ppb)
+    whole = first + ppb <= ((lengths + page_size - 1) // page_size)[:, None]
+    if starts is not None:
+        whole &= first >= (starts // page_size)[:, None]
+    return whole * (1 + blocks_in_runs(page_tables, ppb, run))
+
+
+def _page_copies(lengths_ref, starts_ref, tables_ref, layer, moves, P: int,
+                 ppb: int, ps: int):
+    """``block_copies(b, blk, buf, wait)``: start (or wait for) the copies
+    of block ``blk`` of slot ``b`` into buffer ``buf``, a predicate, a copy
+    and a wait a page: only the pages the slot's length reaches (and, with
+    ``starts_ref``, none that lies wholly before its start).  ``moves``:
+    (pool, buffers, semaphore of a buffer) a pool."""
+    def block_copies(b, blk, buf, wait: bool):
+        n_pages = (lengths_ref[b] + ps - 1) // ps
+        for i in range(ppb):
+            pg = blk * ppb + i
+            reached = pg < n_pages
+            if starts_ref is not None:
+                reached &= pg >= starts_ref[b] // ps
+
+            @pl.when(reached)
+            def _():
+                # a wait needs the copy's shape and semaphore, not its source
+                page = 0 if wait else tables_ref[b * P + pg]
+                for pool, dst, sem in moves:
+                    copy = pltpu.make_async_copy(
+                        pool.at[layer, page], dst.at[buf, i], sem(buf))
+                    copy.wait() if wait else copy.start()
+
+    return block_copies
+
+
+def _run_copies(lengths_ref, starts_ref, tables_ref, kinds_ref, layer, moves,
+                P: int, ppb: int, ps: int, run: int):
+    """``_page_copies`` for a pool of small pages (``walk_blocks``): the
+    same pages in fewer copies, predicates and waits.  ``kinds_ref`` says
+    of each block (``block_kinds``) whether it is reached whole (all of a
+    walk but its first block under a window and its last): such a block
+    starts its copies in a straight line, ``run`` pages a copy where every
+    group of it is a run, and waits ONCE a pool for the buffer's bytes; an
+    edge block is ``_page_copies``'s.  Few and flat branches: a predicate
+    costs the kernel about what a copy does (PERF.md, PR 60)."""
+    edge = _page_copies(lengths_ref, starts_ref, tables_ref, layer, moves, P,
+                        ppb, ps)
+    n_whole = P // ppb  # blocks ``kinds_ref`` knows; a later one is an edge
+
+    def copy(move, buf, page, i, n):
+        pool, dst, sem = move
+        return pltpu.make_async_copy(pool.at[layer, pl.ds(page, n)],
+                                     dst.at[buf, pl.ds(i, n)], sem(buf))
+
+    def block_copies(b, blk, buf, wait: bool):
+        kind = jnp.where(
+            blk < n_whole,
+            kinds_ref[b * n_whole + jnp.minimum(blk, n_whole - 1)], 0)
+
+        def straight(n):  # the block's copies, ``n`` pages each
+            for i in range(0, ppb, n):
+                page = tables_ref[b * P + blk * ppb + i]
+                for move in moves:
+                    copy(move, buf, page, i, n).start()
+
+        def once():  # the semaphore counts bytes: every copy's at once
+            for move in moves:
+                copy(move, buf, 0, 0, ppb).wait()
+
+        # one branch for a block in runs, two for the others
+        if wait:
+            jax.lax.cond(kind != 0, once, lambda: edge(b, blk, buf, True))
+        else:
+            jax.lax.cond(
+                kind == 2, lambda: straight(run),
+                lambda: jax.lax.cond(kind == 1, lambda: straight(1),
+                                     lambda: edge(b, blk, buf, False)))
+
+    return block_copies
 
 
 def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
                          sm_scale: float, bounded: bool = False,
-                         heads_apart: bool = False):
+                         heads_apart: bool = False, run: int = 1):
     # ``bounded``: a fourth prefetched scalar a slot, the first position
     # the slot's query sees (0 <= start < length)
     # ``heads_apart``: a "slot" is one KV head of a slot (slot b's is
     # b % n_kv, its rows that head's query heads) with a page list of its
     # own, so only that head's columns of a page are its keys
+    # ``run`` > 1: a last prefetched scalar a block of a slot's table, how
+    # the block is copied (``block_kinds``, ``_run_copies``)
     starts_ref, refs = (refs[0], refs[1:]) if bounded else (None, refs)
+    kinds_ref, refs = (refs[0], refs[1:]) if run > 1 else (None, refs)
     q_ref, k_pool, v_pool, o_ref, k_buf, v_buf, sems = refs
     B, H, d = q_ref.shape
     _, ppb, ps, n_kv, _ = k_buf.shape
@@ -78,26 +236,15 @@ def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, *refs,
         """The block a slot's walk begins at."""
         return starts_ref[b] // block_tokens if bounded else 0
 
-    def block_copies(b, blk, buf, wait: bool):
-        """Start (or wait for) the copies of block ``blk`` of slot ``b``
-        into buffer ``buf``: only the pages the slot's length reaches
-        (and, ``bounded``, none that lies wholly before its start)."""
-        n_pages = (lengths_ref[b] + ps - 1) // ps
-        for i in range(ppb):
-            pg = blk * ppb + i
-            reached = pg < n_pages
-            if bounded:
-                reached &= pg >= starts_ref[b] // ps
-
-            @pl.when(reached)
-            def _():
-                # a wait needs the copy's shape and semaphore, not its source
-                page = 0 if wait else tables_ref[b * P + pg]
-                for s, (pool, dst) in enumerate(((k_pool, k_buf),
-                                                 (v_pool, v_buf))):
-                    copy = pltpu.make_async_copy(
-                        pool.at[layer, page], dst.at[buf, i], sems.at[s, buf])
-                    copy.wait() if wait else copy.start()
+    moves = [(pool, dst, lambda buf, s=s: sems.at[s, buf])
+             for s, (pool, dst) in enumerate(((k_pool, k_buf),
+                                              (v_pool, v_buf)))]
+    if run > 1:
+        block_copies = _run_copies(lengths_ref, starts_ref, tables_ref,
+                                   kinds_ref, layer, moves, P, ppb, ps, run)
+    else:
+        block_copies = _page_copies(lengths_ref, starts_ref, tables_ref,
+                                    layer, moves, P, ppb, ps)
 
     def next_active(b):
         """First slot after ``b`` with something to attend to, or B."""
@@ -194,6 +341,12 @@ def _paged_decode(q, k_pool, v_pool, page_tables, lengths, layer,
             jnp.int32),)
     if heads_apart:
         kernel = functools.partial(kernel, heads_apart=True)
+    _, run = walk_blocks(k_pool.shape, k_pool.dtype.itemsize, P, ppb)
+    if run > 1:  # small pages: how each block is copied
+        kernel = functools.partial(kernel, run=run)
+        bound += (block_kinds(page_tables, lengths,
+                              bound[0] if bound else None, ps, ppb,
+                              run).reshape(-1).astype(jnp.int32),)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -297,35 +450,34 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
 # tokens a compute block of the latent kernel holds: a latent page is one
 # contiguous page_size x width run (20 KB at 16 x 640 bf16), a fifth of a
-# K/V block's bytes a token, so a block takes twice the tokens
+# K/V block's bytes a token, so a block takes twice the tokens: 32 copies
+# of 20 KB, under COPY_BYTES, so the walk is ``_run_copies``'s (apart, GLM's
+# pool: 16 / 32 / 64 pages a block 119.6 / 94.7 / 109.2 us, PERF.md, PR 60)
 LATENT_BLOCK_TOKENS = 512
 
 
-def _paged_latent_kernel(lengths_ref, tables_ref, layer_ref, q_ref, pool,
-                         o_ref, buf, sems, *, sm_scale: float):
+def _paged_latent_kernel(lengths_ref, tables_ref, layer_ref, *refs,
+                         sm_scale: float, run: int = 1):
     """``_paged_decode_kernel`` over ONE pool whose row is key and value
     both: every query head scores against the whole row (its zero tail
     meets the query's), and the weighted sum is taken over the row's first
     ``o_ref.shape[-1]`` values.  No KV heads, so no column of a block
     belongs to another head and nothing but the length is masked."""
+    kinds_ref, refs = (refs[0], refs[1:]) if run > 1 else (None, refs)
+    q_ref, pool, o_ref, buf, sems = refs
     B, H, _ = q_ref.shape
     _, ppb, ps, W = buf.shape
     V = o_ref.shape[-1]
     P = tables_ref.shape[0] // B
     block_tokens = ppb * ps
     layer = layer_ref[0]
-
-    def block_copies(b, blk, which, wait: bool):
-        n_pages = (lengths_ref[b] + ps - 1) // ps
-        for i in range(ppb):
-            pg = blk * ppb + i
-
-            @pl.when(pg < n_pages)
-            def _():
-                page = 0 if wait else tables_ref[b * P + pg]
-                copy = pltpu.make_async_copy(
-                    pool.at[layer, page], buf.at[which, i], sems.at[which])
-                copy.wait() if wait else copy.start()
+    moves = [(pool, buf, lambda which: sems.at[which])]
+    if run > 1:
+        block_copies = _run_copies(lengths_ref, None, tables_ref, kinds_ref,
+                                   layer, moves, P, ppb, ps, run)
+    else:
+        block_copies = _page_copies(lengths_ref, None, tables_ref, layer,
+                                    moves, P, ppb, ps)
 
     def next_active(b):
         return jax.lax.while_loop(
@@ -395,10 +547,18 @@ def _paged_latent(q, pool, page_tables, lengths, layer, *, value_dim: int,
     P = page_tables.shape[1]
     ppb = min(pages_per_block, P)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    lengths = jnp.minimum(lengths, P * ps).astype(jnp.int32)
+    kernel = functools.partial(_paged_latent_kernel, sm_scale=sm_scale)
+    kinds = ()
+    _, run = walk_blocks(pool.shape, pool.dtype.itemsize, P, ppb)
+    if run > 1:  # small pages: how each block is copied
+        kernel = functools.partial(kernel, run=run)
+        kinds = (block_kinds(page_tables, lengths, None, ps, ppb,
+                             run).reshape(-1).astype(jnp.int32),)
     return pl.pallas_call(
-        functools.partial(_paged_latent_kernel, sm_scale=sm_scale),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(kinds),
             grid=(),
             in_specs=[vmem, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=vmem,
@@ -409,9 +569,8 @@ def _paged_latent(q, pool, page_tables, lengths, layer, *, value_dim: int,
         out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
         interpret=interpret,
         name="paged_latent_decode_attention",
-    )(jnp.minimum(lengths, P * ps).astype(jnp.int32),
-      page_tables.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+    )(lengths, page_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *kinds, q, pool)
 
 
 def paged_latent_decode_attention(

@@ -902,8 +902,11 @@ def _reference_ensure_capacity(engine, steps):
 @pytest.mark.parametrize("cached_pages", [0, 5, 11, 200])
 def test_a_burst_preempts_as_the_per_slot_path_did(cached_pages):
     """A pool that cannot cover the burst's sum (and one that can): the
-    same slots grow by the same pages, the same victims are preempted in
-    the same order, and the index and the free list end the same."""
+    same slots grow by as many pages, the same victims are preempted in
+    the same order, and the index and the free list's length end the same.
+    (Not the ids: the free list is in page order, so what the evictions
+    gave back does not come out in their order, and one reserve for the
+    burst takes other ids than a reserve a slot.)"""
     def build():
         rng = random.Random(9)
         engine = _engine(num_pages=300, max_slots=16)
@@ -921,10 +924,13 @@ def test_a_burst_preempts_as_the_per_slot_path_did(cached_pages):
     _reference_ensure_capacity(want, 8)
 
     def outcome(e):
-        return ([(s.request.request_id, s.pages) if s else None
+        held = [p for s in e._slots if s for p in s.pages]
+        assert len(set(held)) == len(held)
+        assert not set(held) & set(e.allocator._free)
+        return ([(s.request.request_id, len(s.pages)) if s else None
                  for s in e._slots],
                 [r.request_id for r in e._waiting.queue],
-                e.allocator._free, _cache_state(e.prefix_cache))
+                len(e.allocator._free), _cache_state(e.prefix_cache))
 
     assert outcome(got) == outcome(want)
     preempted = got.stats()["preempted"]
@@ -976,11 +982,131 @@ def test_window_layers_come_whole_or_not_at_all(wrong):
 
 def test_a_window_pool_is_allocated_like_any_other():
     """The window layers' allocator is a ``PageAllocator``: pages given
-    back while a sequence lives go to the END of the free list, so another
-    sequence takes untouched pages first and a given-back page's rows stay
-    readable until the list comes round."""
+    back while a sequence lives rejoin the free list in page order BEHIND
+    where the allocator stands, so another sequence takes untouched pages
+    first and a given-back page's rows stay readable until the list comes
+    round."""
     alloc = PageAllocator(8)
     held = alloc.allocate(4)
     alloc.free(held[:2])  # behind the window
     assert alloc.allocate(3) == [5, 6, 7] and alloc.num_free() == 2
     assert alloc.allocate(2) == held[:2]
+
+
+# ------------------------------------------- the free list, in page order
+
+class _FifoAllocator(PageAllocator):
+    """The rule the free list had before it was kept in page order, kept
+    here to hold the new one against: ``allocate`` takes the list's head,
+    ``free`` and ``reclaim`` append."""
+
+    def allocate(self, n):
+        if n > len(self._free):
+            raise MemoryError(f"needs {n} pages, {len(self._free)} free")
+        out, self._free = self._free[:n], self._free[n:]
+        for p in out:
+            self._rc[p] = 1
+        return out
+
+    def free(self, pages):
+        for p in pages:
+            if p and self._rc.pop(p, None) is not None:
+                self._free.append(p)
+
+    def _check(self):  # (its list is in no order)
+        pass
+
+
+def _in_runs_of_four(pages):
+    groups = [pages[i:i + 4] for i in range(0, len(pages) - 3, 4)]
+    return sum(all(b - a == 1 for a, b in zip(g, g[1:]))
+               for g in groups) / len(groups)
+
+
+def _serve_rounds(alloc, rng, rounds, live=9):
+    """Requests as a long-document cell's: a prompt in chunks of 64 pages,
+    then pages one at a time, taken in turn by the live sequences, and
+    everything a sequence holds given back when it ends (in a shuffled
+    order).  Yields, a round (one finished sequence), the share of the
+    fresh 64-page chunks since the round before that lay in runs of four
+    (the last reading again where a round took no chunk)."""
+    slots = []
+    shares = [1.0]
+    done = 0
+    while done < rounds:
+        while len(slots) < live:
+            slots.append({"pages": [], "prompt": 64 * rng.randint(2, 4),
+                          "decode": rng.randint(20, 50)})
+        for s in list(slots):
+            if len(s["pages"]) < s["prompt"]:
+                chunk = alloc.allocate(64)
+                shares.append(_in_runs_of_four(chunk))
+                s["pages"] += chunk
+            elif s["decode"]:
+                s["pages"] += alloc.allocate(1)
+                s["decode"] -= 1
+            else:
+                rng.shuffle(s["pages"])
+                alloc.free(s["pages"])
+                slots.remove(s)
+                done += 1
+                yield sum(shares) / len(shares)
+                shares = shares[-1:]
+
+
+@pytest.mark.parametrize("num_pages", [3072, 8448])
+def test_chunks_go_on_coming_out_in_runs_however_long_the_pool_is_used(
+        num_pages, monkeypatch):
+    """After a hundred rounds of chunk allocations, single allocations and
+    frees in any order the free list is in page order (``_check`` holds it
+    after every call) and a fresh 64-page chunk lies in runs of four as it
+    did in the first round; on the first-in-first-out list the share falls
+    to nearly nothing, which is why the kernels' merged copies
+    (ops/paged_attention.py) come with this order."""
+    shares = list(_serve_rounds(PageAllocator(num_pages), random.Random(5),
+                                100))
+    first, last = sum(shares[:10]) / 10, sum(shares[-10:]) / 10
+    assert first > 0.9 and last > 0.9 and last > first - 0.05
+    monkeypatch.setenv("RTPU_DEBUG_ALLOCATOR", "0")
+    fifo = list(_serve_rounds(_FifoAllocator(num_pages), random.Random(5),
+                              100))
+    assert sum(fifo[:3]) / 3 > 0.8 and sum(fifo[-10:]) / 10 < 0.2
+
+
+@pytest.mark.parametrize("seed", [1, 2147485001])
+def test_the_free_list_stays_in_page_order_and_goes_round(seed):
+    """Interleaved allocations of every size, frees and reclaims in any
+    order: the list is sorted after each (``_check``), what an allocation
+    hands out are the free ids that FOLLOW the last one handed out, in page
+    order and round the end (so a run wherever free neighbours exist), and
+    a page given back is not handed out again before the allocator has
+    gone round the list."""
+    rng = random.Random(seed)
+    alloc = PageAllocator(400)
+    held = []
+    for _ in range(600):
+        if held and (rng.random() < 0.45 or alloc.num_free() < 70):
+            rng.shuffle(held)
+            gone = [held.pop() for _ in range(min(len(held),
+                                                  rng.choice((1, 1, 5, 80))))]
+            if rng.random() < 0.3:
+                for p in gone:
+                    alloc.free([p])
+            else:
+                alloc.free(gone)
+            continue
+        n = rng.choice((1, 1, 1, 4, 64))
+        free_before, after = sorted(alloc._free), alloc._next
+        out = alloc.allocate(n)
+        ahead = [p for p in free_before if p >= after]
+        behind = [p for p in free_before if p < after]
+        assert out == (ahead + behind)[:n]
+        held += out
+    assert alloc._free == sorted(alloc._free)
+    # a page given back comes out again only after every page that was free
+    # ahead of the allocator
+    alloc = PageAllocator(16)
+    first = alloc.allocate(6)
+    alloc.free(first[:3])
+    assert alloc.allocate(9) == list(range(7, 16))
+    assert alloc.allocate(3) == first[:3]

@@ -871,6 +871,96 @@ def test_sparse_kernel_compiles_for_the_checks_one_sequence(topo, as_tpu):
     assert "paged_decode_attention" in text
 
 
+@pytest.mark.parametrize("name,pool,slots,table,heads,kw", [
+    ("trinity_full", (1, 32768, 16, 4, 128), 64, 512, 32, {}),
+    ("trinity_window", (4, 8448, 16, 4, 128), 64, 512, 32,
+     {"window": 2048}),
+    ("minicpm_sala_lists", (2, 51201, 16, 2, 128), 32, 392, 32,
+     {"heads_apart": True}),
+    ("falcon_h1", (6, 6144, 16, 4, 128), 64, 128, 20, {}),
+    ("sdar_block_pass", (8, 2048, 16, 4, 128), 32, 64, 128, {}),
+    ("glm_latent", (8, 12288, 16, 640), 64, 256, 32, {}),
+    ("longcat_latent", (8, 12288, 16, 640), 64, 256, 64, {}),
+    ("mistral", (16, 3072, 16, 8, 128), 32, 128, 32, {}),
+    ("olmo_hybrid", (4, 3072, 16, 32, 128), 32, 128, 32, {}),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_paged_kernels_compile_apart_at_every_cells_pool(
+        topo, as_tpu, name, pool, slots, table, heads, kw):
+    """The two paged kernels alone, over each serving cell's pool and
+    table (``time_paged_walk.py``'s shapes): pools of small pages (16 KB of
+    K or V, 8 KB, 20 KB of latent rows) take the walk that moves four
+    adjacent pages a copy and waits once a buffer, one more prefetched
+    scalar array (a flag a group of the table); Mistral's and Olmo-Hybrid's
+    32 KB and 128 KB pages keep the page-by-page program."""
+    from ray_tpu.ops import paged_attention as pa
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    apart = (pool[3],) if kw.get("heads_apart") else ()
+    args = [sds((slots, heads, pool[-1]), jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds((slots, *apart, table), jnp.int32),
+            sds((slots, *apart), jnp.int32), sds((), jnp.int32)]
+    if len(pool) == 4:
+        call = lambda q, p, t, n, li: pa.paged_latent_decode_attention(  # noqa: E731
+            q, p, t, n, li, value_dim=512, sm_scale=0.05)
+    else:
+        args.insert(1, args[1])
+        call = lambda q, k, v, t, n, li: pa.paged_decode_attention(  # noqa: E731
+            q, k, v, t, n, li, **kw)
+    assert "tpu_custom_call" in jax.jit(call).lower(*args).compile().as_text()
+    ppb, run = pa.walk_blocks(pool, 2, table)
+    assert run == (1 if name in ("mistral", "olmo_hybrid") else 4)
+    assert ppb == (32 if len(pool) == 4 else 16)
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub)
+
+    (kernel,) = kernels(jax.make_jaxpr(call)(*args).jaxpr)
+    # lengths, tables, layer (+ the starts under a window) + the blocks'
+    assert kernel.params["grid_mapping"].num_index_operands == (
+        3 + ("window" in kw) + (run > 1))
+
+
+@pytest.mark.parametrize("n_kv,kw,digest", [
+    (8, {}, "ca64bbb75e9fd65a"), (32, {}, "45f37b4ec21f19e1"),
+    (8, {"window": 512}, "3b6fb887ddb20bca")],
+    ids=["mistral", "olmo_hybrid", "a_window"])
+def test_large_pages_keep_the_kernel_the_chip_compiled_before(
+        topo, as_tpu, n_kv, kw, digest):
+    """The Mosaic module a pool of 32 KB (and 128 KB) pages lowers to for
+    the TPU, printed without source locations, is the parent commit's
+    (PR 59's tree, this container's JAX; its digests): Mistral's and
+    Olmo-Hybrid's cells run the program they ran."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    pool = sds((16, 3072, 16, n_kv, 128), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, t, n, li: paged_decode_attention(
+        q, k, v, t, n, li, **kw)).lower(
+            sds((32, 32, 128), jnp.bfloat16), pool, pool,
+            sds((32, 128), jnp.int32), sds((32,), jnp.int32),
+            sds((), jnp.int32)).as_text()
+    (body,) = re.findall(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", text)
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        plain = module.operation.get_asm(enable_debug_info=False)
+    assert hashlib.sha256(plain.encode()).hexdigest()[:16] == digest
+
+
 def test_latent_kernel_refuses_pages_that_are_no_whole_tiles(as_tpu):
     """What the chip's compiler would turn down is refused by name before
     it: a 576-wide row, a value that ends inside a lane tile, 8-token pages
