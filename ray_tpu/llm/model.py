@@ -41,10 +41,12 @@ way, which it hands the family's walk as one bundle ``via``:
   scan, the decode step updates every live slot's row in place.
 - ``recur_fixed(q, k, v, g, (S, li), conv=None)`` (ops/lightning.py's
   recurrence: models/minicpm_sala.py's linear layers, models/falcon_h1.py's
-  Mamba-2 mixer): as ``recur``, and ``prefill_with_prefix`` takes the
-  SLOT'S ROW as the initial state, so a prompt in chunks carries its state
-  from chunk to chunk; only ``prefill`` (a prompt's first chunk, or all of
-  it) begins from zeros.  ``g`` is the decay's log a head ([H], a constant
+  and models/nemotron_h.py's Mamba-2 mixer, the latter's state rows PACKED
+  two heads side by side, which the recurrence takes by the rows' shape):
+  as ``recur``, and ``prefill_with_prefix`` takes the SLOT'S ROW as the
+  initial state, so a prompt in chunks carries its state from chunk to
+  chunk; only ``prefill`` (a prompt's first chunk, or all of it) begins
+  from zeros.  ``g`` is the decay's log a head ([H], a constant
   of the head) or a token a head ([..., H]), told by its rank; keys and
   queries come a group of heads.  The part it runs under is the family's
   (``cfg.state_part``).  A family whose recurrence's inputs pass a SHORT
@@ -106,7 +108,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
-                            longcat_flash, olmo_hybrid, sdar_moe)
+                            longcat_flash, nemotron_h, olmo_hybrid, sdar_moe)
 from ray_tpu.models.llama import embed, head
 from ray_tpu.ops import block_sparse, gated_delta, lightning
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
@@ -127,6 +129,8 @@ def serving_layout(params):
         return params  # (its layout takes the configuration's mixer_types)
     if "first" in params["layers"]:  # a double layer's two sublayers
         return longcat_flash.serving_layout(params)
+    if "M" in params["layers"]:  # layers stacked a KIND
+        return nemotron_h.serving_layout(params)
     attn = params["layers"].get("attn", ())
     if "lin" in params["layers"]:
         return olmo_hybrid.serving_layout(params)
@@ -413,10 +417,12 @@ def prefill(params, tokens, cache_k, cache_v, page_rows, true_len,
         with jax.named_scope(cfg.state_part):
             # a padded position changes nothing: no decay, no write
             real = (positions < true_len)[:, None]
+            # (from zeros laid out as the slot's rows are: a state of
+            # narrow heads is kept packed, ops/lightning.py)
             o, S = lightning.chunked(
                 q, jnp.where(real[..., None], k, 0), v,
                 jnp.where(real, g, 0.0),
-                jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), jnp.float32))
+                jnp.zeros(state["S"].shape[2:], jnp.float32))
         return o, (rows[0], S)
 
     # (a convolution from zeros: a prompt's first rows)

@@ -218,6 +218,10 @@ PARTS = (
     # step's update, a prefill's chunked scan, with D x), gated norm and
     # W_out
     "ssm/proj", "ssm/conv", "ssm/gates", "ssm/state", "ssm/out",
+    # models/nemotron_h.py's routed layer: the experts live in a LATENT
+    # narrower than the stream, one product into it before the dispatch and
+    # one back out of it behind the combine, both shared by every expert
+    "moe/latent_in", "moe/latent_out",
     # parallel/tp_stream.py: the links of a training trunk whose stream is
     # split over ``tp`` between the products: a group's rows passed round
     # the ring into ``attn/qkv`` and ``mlp/gate_up``, the partial sums of
@@ -290,14 +294,15 @@ def _qk_norm(cfg, x, weight):
     return rms_norm(flat, weight, cfg.norm_eps).reshape(x.shape)
 
 
-def qkv_rope(cfg, p, h, positions, ring=None):
-    """The normed stream h (..., d_model) projected and split into heads,
-    q and k rotated: q (..., n_heads, head_dim), k and v at KV-head width.
-    The layer's parameters say how: three weights (training), or the one
-    ``wqkv`` of ``serving_layout``, whose product is split after.  ``ring``
-    (parallel/tp_stream.py): h is a device's rows of a stream split over
-    ``tp``, and q, k, v come back for the whole group's rows at the
-    device's heads."""
+def qkv(cfg, p, h, ring=None):
+    """The normed stream h (..., d_model) projected and split into heads:
+    q (..., n_heads, head_dim), k and v at KV-head width, NOT rotated (a
+    family whose attention carries no position, models/nemotron_h.py, takes
+    them so).  The layer's parameters say how: three weights (training), or
+    the one ``wqkv`` of ``serving_layout``, whose product is split after.
+    ``ring`` (parallel/tp_stream.py): h is a device's rows of a stream
+    split over ``tp``, and q, k, v come back for the whole group's rows at
+    the device's heads."""
     def heads(w, n):
         return (h @ w.astype(h.dtype)).reshape(*h.shape[:-1], n, cfg.head_dim)
 
@@ -320,6 +325,12 @@ def qkv_rope(cfg, p, h, positions, ring=None):
         if "q_norm" in p["attn"]:  # RMS norm pre-rope, by the weight's width
             q = _qk_norm(cfg, q, p["attn"]["q_norm"])
             k = _qk_norm(cfg, k, p["attn"]["k_norm"])
+    return q, k, v
+
+
+def qkv_rope(cfg, p, h, positions, ring=None):
+    """``qkv`` with q and k rotated."""
+    q, k, v = qkv(cfg, p, h, ring)
     return (rope(q, positions, cfg.rope_theta),
             rope(k, positions, cfg.rope_theta), v)
 

@@ -238,6 +238,7 @@ def routed_mlp(h, router_w, experts, layer, *, top_k: int,
     """Dropless top-k routed gated MLP.  h: (..., D) -> (..., D).
 
     ``experts``: w_gate / w_up [layers, E, D, F] and w_down [layers, E, F, D]
+    (no ``w_gate``: experts of two matrices around a squared ReLU)
     stacked over layers, of which ``layer`` is read (ops/grouped_matmul says
     why they come whole); router_w: (D, E), this layer's; ``bias`` and
     ``scale`` as ``route`` takes them.  Every (token, expert) assignment is
@@ -256,8 +257,13 @@ def routed_mlp(h, router_w, experts, layer, *, top_k: int,
 
 
 def shared_mlp(shared, hf):
-    """The shared expert: a gated MLP over every row, one part."""
+    """The shared expert over every row, one part: a gated MLP, or without
+    a ``w_gate`` the two-matrix form ``relu(x W_up)^2 W_down``
+    (ops/grouped_matmul.py's two forms)."""
     with jax.named_scope("moe/shared"):
+        if "w_gate" not in shared:
+            up = jax.nn.relu(hf @ shared["w_up"].astype(hf.dtype))
+            return (up * up) @ shared["w_down"].astype(hf.dtype)
         gate = jax.nn.silu(hf @ shared["w_gate"].astype(hf.dtype))
         up = hf @ shared["w_up"].astype(hf.dtype)
         return (gate * up) @ shared["w_down"].astype(hf.dtype)
@@ -376,7 +382,7 @@ def dispatch(hf, weights, chosen, experts, layer):
     from ray_tpu.ops.grouped_matmul import grouped_mlp
 
     (n, d), top_k = hf.shape, chosen.shape[1]
-    e = experts["w_gate"].shape[1]
+    e = experts["w_down"].shape[1]
     m = n * top_k
     tile = row_tile(m, e)
     n_tiles = min(m, -(-(m + e * (tile - 1)) // tile))
@@ -386,7 +392,7 @@ def dispatch(hf, weights, chosen, experts, layer):
             flat, e, tile, n_tiles, top_k)
         rows = hf[src]
     with jax.named_scope("moe/experts"):
-        out = grouped_mlp(rows, experts["w_gate"], experts["w_up"],
+        out = grouped_mlp(rows, experts.get("w_gate"), experts["w_up"],
                           experts["w_down"], tile_expert,
                           run_end[-1] // tile, layer, tile=tile)
     with jax.named_scope("moe/combine"):
@@ -422,7 +428,7 @@ def dispatch_share(hf, weights, chosen, experts, layer, *, first: int,
     from ray_tpu.ops.grouped_matmul import grouped_mlp
 
     (n, d), top_k = hf.shape, chosen.shape[1]
-    e = experts["w_gate"].shape[1]
+    e = experts["w_down"].shape[1]
     if not 0 <= first <= columns - identity - e:
         raise ValueError(
             f"experts {first}..{first + e - 1} are not among the "
@@ -438,7 +444,7 @@ def dispatch_share(hf, weights, chosen, experts, layer, *, first: int,
             flat, e, tile, n_tiles, top_k, spare=True)
         rows = hf[src]
     with jax.named_scope("moe/experts"):
-        out = grouped_mlp(rows, experts["w_gate"], experts["w_up"],
+        out = grouped_mlp(rows, experts.get("w_gate"), experts["w_up"],
                           experts["w_down"], tile_expert,
                           run_end[-1] // tile, layer, tile=tile)
     with jax.named_scope("moe/combine"):

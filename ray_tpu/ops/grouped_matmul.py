@@ -1,5 +1,10 @@
-"""Grouped gated MLP for routed experts on TPU: rows sorted by expert, each
-expert's three matrices read from HBM at most once a call.
+"""Grouped MLP for routed experts on TPU: rows sorted by expert, each
+expert's matrices read from HBM at most once a call.  An expert is one of
+two FORMS, told by whether it has a gate matrix: the SiLU-gated three
+(``silu(x W_gate) * (x W_up) W_down``: Mixtral, Qwen3-MoE, DeepSeek) or two
+matrices around a squared ReLU (``relu(x W_up)^2 W_down``:
+models/nemotron_h.py's latent experts).  One kernel body for both; the
+form is static, and a gated caller lowers to the text it had.
 
 The dropless routed layer (models/moe.py ``routed_mlp``) lays the
 (token, expert) assignments out expert after expert, each expert's run
@@ -9,14 +14,15 @@ tile's expert, and the expert's ``w_gate`` / ``w_up`` / ``w_down`` blocks
 are fetched by that index.  Consecutive tiles of one expert ask for the
 same blocks, which the pipeline does not fetch again; an expert no row was
 routed to is never asked for.  With few rows an expert (decoding) the call
-is bound by streaming the experts' weights, 3 x d_model x d_expert each.
+is bound by streaming the experts' weights, 3 (or 2) x d_model x d_expert
+each.
 
 The weights come stacked over layers (``[n_layers, n_experts, ...]``) with
 the layer's index as a prefetched scalar, like the page pool of
 ``paged_attention``: slicing a layer out of the stack for a custom call
 would copy it, the whole of what the kernel exists to read once.
 
-An expert whose three matrices do not fit the kernel's VMEM twice over
+An expert whose matrices do not fit the kernel's VMEM twice over
 (models/longcat_flash.py: 3 x 6144 x 2048, 75 MB) is read in COLUMN BLOCKS
 of its hidden width (``f_block``): a second, inner grid axis walks them and
 the tile's output accumulates in float32 scratch.  Every byte of the expert
@@ -45,11 +51,11 @@ WEIGHT_BLOCKS_BYTES = 40 * 1024 * 1024
 LANES = 128
 
 
-def f_block(d: int, f: int, itemsize: int) -> int:
+def f_block(d: int, f: int, itemsize: int, matrices: int = 3) -> int:
     """Columns of an expert's hidden width a grid step reads: all ``f``
-    where its three matrices fit ``WEIGHT_BLOCKS_BYTES`` twice over, else
+    where its ``matrices`` fit ``WEIGHT_BLOCKS_BYTES`` twice over, else
     the widest whole-lane divisor of ``f`` that does."""
-    fits = WEIGHT_BLOCKS_BYTES // (2 * 3 * d * itemsize)
+    fits = WEIGHT_BLOCKS_BYTES // (2 * matrices * d * itemsize)
     if f <= fits:
         return f
     for n in range(2, f // LANES + 1):
@@ -60,26 +66,35 @@ def f_block(d: int, f: int, itemsize: int) -> int:
         f"{WEIGHT_BLOCKS_BYTES} bytes of VMEM twice over")
 
 
+def _hidden(x, w_in):
+    """What an expert's last matrix multiplies, of a tile of rows x: by the
+    matrices before it, ``silu(x W_gate) * (x W_up)`` of two or
+    ``relu(x W_up)^2`` of one."""
+    first = jnp.dot(x, w_in[0][...], preferred_element_type=jnp.float32)
+    if len(w_in) == 1:
+        up = jnp.maximum(first, 0.0)
+        return (up * up).astype(x.dtype)
+    up = jnp.dot(x, w_in[1][...], preferred_element_type=jnp.float32)
+    return (first * jax.nn.sigmoid(first) * up).astype(x.dtype)
+
+
 def _grouped_mlp_kernel(tile_expert_ref, tiles_used_ref, layer_ref, x_ref,
-                        wg_ref, wu_ref, wd_ref, o_ref):
+                        *refs):
     del tile_expert_ref, layer_ref  # read by the index maps
+    *w_in, wd_ref, o_ref = refs
 
     @pl.when(pl.program_id(0) < tiles_used_ref[0])
     def _():
-        x = x_ref[...]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
         o_ref[...] = jnp.dot(
-            h, wd_ref[...],
+            _hidden(x_ref[...], w_in), wd_ref[...],
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _grouped_mlp(x, w_gate, w_up, w_down, tile_expert, tiles_used, layer, *,
+def _grouped_mlp(x, w_in, w_down, tile_expert, tiles_used, layer, *,
                  tile: int, interpret: bool):
     rows, d = x.shape
-    f = w_gate.shape[-1]
+    f = w_down.shape[-2]
 
     def weight(shape):
         return pl.BlockSpec((None, None) + shape,
@@ -93,8 +108,8 @@ def _grouped_mlp(x, w_gate, w_up, w_down, tile_expert, tiles_used, layer, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(rows // tile,),
-            in_specs=[pl.BlockSpec((tile, d), rows_of), weight((d, f)),
-                      weight((d, f)), weight((f, d))],
+            in_specs=[pl.BlockSpec((tile, d), rows_of),
+                      *(weight((d, f)) for _ in w_in), weight((f, d))],
             out_specs=pl.BlockSpec((tile, d), rows_of)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -104,12 +119,13 @@ def _grouped_mlp(x, w_gate, w_up, w_down, tile_expert, tiles_used, layer, *,
         name="moe_grouped_mlp",
     )(tile_expert.astype(jnp.int32),
       jnp.asarray(tiles_used, jnp.int32).reshape(1),
-      jnp.asarray(layer, jnp.int32).reshape(1), x, w_gate, w_up, w_down)
+      jnp.asarray(layer, jnp.int32).reshape(1), x, *w_in, w_down)
 
 
 def _grouped_mlp_blocks_kernel(tile_expert_ref, tiles_used_ref, layer_ref,
-                               x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+                               x_ref, *refs):
     del tile_expert_ref, layer_ref  # read by the index maps
+    *w_in, wd_ref, o_ref, acc_ref = refs
     j = pl.program_id(1)
 
     @pl.when(pl.program_id(0) < tiles_used_ref[0])
@@ -118,10 +134,7 @@ def _grouped_mlp_blocks_kernel(tile_expert_ref, tiles_used_ref, layer_ref,
         def _():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        x = x_ref[...]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+        h = _hidden(x_ref[...], w_in)
         acc_ref[...] += jnp.dot(h, wd_ref[...],
                                 preferred_element_type=jnp.float32)
 
@@ -131,11 +144,11 @@ def _grouped_mlp_blocks_kernel(tile_expert_ref, tiles_used_ref, layer_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "fb", "interpret"))
-def _grouped_mlp_blocks(x, w_gate, w_up, w_down, tile_expert, tiles_used,
-                        layer, *, tile: int, fb: int, interpret: bool):
+def _grouped_mlp_blocks(x, w_in, w_down, tile_expert, tiles_used, layer, *,
+                        tile: int, fb: int, interpret: bool):
     """``_grouped_mlp`` with an expert read in ``f // fb`` column blocks."""
     rows, d = x.shape
-    nj = w_gate.shape[-1] // fb
+    nj = w_down.shape[-2] // fb
 
     def block(i, j, used):  # a tile not computed stays on the last block
         return jnp.where(i < used[0], j, nj - 1)
@@ -150,10 +163,10 @@ def _grouped_mlp_blocks(x, w_gate, w_up, w_down, tile_expert, tiles_used,
             grid=(rows // tile, nj),
             in_specs=[
                 pl.BlockSpec((tile, d), rows_of),
-                pl.BlockSpec((None, None, d, fb), lambda i, j, te, used, li:
-                             (li[0], te[i], 0, block(i, j, used))),
-                pl.BlockSpec((None, None, d, fb), lambda i, j, te, used, li:
-                             (li[0], te[i], 0, block(i, j, used))),
+                *(pl.BlockSpec((None, None, d, fb),
+                               lambda i, j, te, used, li:
+                               (li[0], te[i], 0, block(i, j, used)))
+                  for _ in w_in),
                 pl.BlockSpec((None, None, fb, d), lambda i, j, te, used, li:
                              (li[0], te[i], block(i, j, used), 0))],
             out_specs=pl.BlockSpec((tile, d), rows_of),
@@ -166,14 +179,15 @@ def _grouped_mlp_blocks(x, w_gate, w_up, w_down, tile_expert, tiles_used,
         name="moe_grouped_mlp",
     )(tile_expert.astype(jnp.int32),
       jnp.asarray(tiles_used, jnp.int32).reshape(1),
-      jnp.asarray(layer, jnp.int32).reshape(1), x, w_gate, w_up, w_down)
+      jnp.asarray(layer, jnp.int32).reshape(1), x, *w_in, w_down)
 
 
-def grouped_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+def grouped_mlp(x: jax.Array, w_gate, w_up: jax.Array,
                 w_down: jax.Array, tile_expert: jax.Array, tiles_used,
                 layer, *, tile: int) -> jax.Array:
     """``silu(x @ w_gate[e]) * (x @ w_up[e]) @ w_down[e]`` for every row of
-    x, ``e`` being the expert of the row's tile.
+    x, ``e`` being the expert of the row's tile; with ``w_gate`` None the
+    two-matrix form, ``relu(x @ w_up[e])^2 @ w_down[e]``.
 
     x: [rows, d_model], ``rows`` a multiple of ``tile``.  w_gate / w_up:
     [n_layers, n_experts, d_model, d_expert]; w_down: [n_layers, n_experts,
@@ -187,16 +201,17 @@ def grouped_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     if rows % tile or tile_expert.shape != (rows // tile,):
         raise ValueError(
             f"{rows} rows are not {tile_expert.shape[0]} tiles of {tile}")
-    if (w_gate.ndim != 4 or w_gate.shape != w_up.shape
-            or w_down.shape != w_gate.shape[:2] + w_gate.shape[:1:-1]
-            or w_gate.shape[2] != d):
+    w_in = (w_up,) if w_gate is None else (w_gate, w_up)
+    if (w_up.ndim != 4 or w_in[0].shape != w_up.shape
+            or w_down.shape != w_up.shape[:2] + w_up.shape[:1:-1]
+            or w_up.shape[2] != d):
         raise ValueError(
-            f"grouped_mlp takes w_gate and w_up [layers, experts, {d}, f] "
-            f"and w_down [layers, experts, f, {d}]; got {w_gate.shape}, "
-            f"{w_up.shape}, {w_down.shape}")
-    fb = f_block(d, w_gate.shape[-1], x.dtype.itemsize)
-    blocks = {} if fb == w_gate.shape[-1] else {"fb": fb}
+            f"grouped_mlp takes w_gate (or None) and w_up [layers, experts, "
+            f"{d}, f] and w_down [layers, experts, f, {d}]; got "
+            f"{[w.shape for w in w_in]}, {w_down.shape}")
+    fb = f_block(d, w_up.shape[-1], x.dtype.itemsize, len(w_in) + 1)
+    blocks = {} if fb == w_up.shape[-1] else {"fb": fb}
     return (_grouped_mlp_blocks if blocks else _grouped_mlp)(
-        x, w_gate.astype(x.dtype), w_up.astype(x.dtype),
-        w_down.astype(x.dtype), tile_expert, tiles_used, layer, tile=tile,
+        x, tuple(w.astype(x.dtype) for w in w_in), w_down.astype(x.dtype),
+        tile_expert, tiles_used, layer, tile=tile,
         interpret=jax.default_backend() != "tpu", **blocks)

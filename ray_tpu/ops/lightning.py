@@ -25,10 +25,17 @@ exp(-1.6) a token a chunk's product underflows float32; the difference does
 not).  Keys and queries come a GROUP, [L, G, d_k] with H a multiple of G
 (G = H: a head its own); head h reads group h // (H / G), and no form
 repeats them to H heads: a group's scores are made once, and what is a
-head's own is what its decays make of them.  The state is held as it is written, [H, d_k,
-d_v]: at d_v = 128 and d_k a multiple of 8 a head's state is whole (8, 128)
-tiles already, and ``gated_delta.pack_state`` (which lays heads of 192
-side by side) would be the identity on it.
+head's own is what its decays make of them.  The state is held as it is
+written, [H, d_k, d_v], where d_v fills the lanes: at d_v = 128 and d_k a
+multiple of 8 a head's state is whole (8, 128) tiles already.  A NARROWER
+head (models/nemotron_h.py: Mamba-2 heads of 64) would be half-lane tiles,
+padded to twice its bytes wherever it is stored or moved, so such a state is
+held PACKED (``pack_state``): ``pack`` heads that read the same key side by
+side in the lanes, [H / pack, d_k, pack x d_v], as ``gated_delta.pack_state``
+lays heads of 192.  The update is the same arithmetic on it (a key's column
+against a row of ``pack`` heads' values, each lane under its own head's
+decay), so ``decode_update`` takes either by the rows' shape and ``chunked``
+hands its state back as S0 came.
 
 ``recurrent``: the definition, a token at a time (the tests' yardstick).
 ``chunked``: a whole (padded) sequence from an initial state S0, for
@@ -75,6 +82,39 @@ def _by_group(q, v, g, S0):
             S0.astype(f32).reshape(G, H // G, *S0.shape[1:]))
 
 
+def pack_state(S, pack: int):
+    """[..., H, d_k, d_v] -> [..., H / pack, d_k, pack * d_v]: heads ``pack
+    i .. pack i + pack - 1`` side by side in the lanes (they must read one
+    key: ``decode_update`` checks it)."""
+    if pack == 1:
+        return S
+    *lead, H, dk, dv = S.shape
+    S = S.reshape(*lead, H // pack, pack, dk, dv)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, H // pack, dk, pack * dv)
+
+
+def unpack_state(S, pack: int):
+    """The inverse of ``pack_state``."""
+    if pack == 1:
+        return S
+    *lead, Hp, dk, lanes = S.shape
+    S = S.reshape(*lead, Hp, dk, pack, lanes // pack)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, Hp * pack, dk,
+                                           lanes // pack)
+
+
+def _pack_of(rows_shape, H: int, dk: int, dv: int) -> int:
+    """How many heads lie side by side in state rows [..., H / pack, d_k,
+    pack * d_v] that hold H heads [d_k, d_v]."""
+    pack = rows_shape[-1] // dv if dv else 0
+    if not pack or tuple(rows_shape[-3:]) != (H // pack, dk, pack * dv) \
+            or H % pack:
+        raise ValueError(
+            f"state rows {tuple(rows_shape[-3:])} hold no {H} heads "
+            f"[{dk}, {dv}], as they are or side by side in the lanes")
+    return pack
+
+
 def recurrent(q, k, v, g, S0):
     """Token by token.  q, k: [L, G, d_k]; v: [L, H, d_v]; g (log decay):
     [L, H]; S0: [H, d_k, d_v].  Returns (o [L, H, d_v], S_L), float32."""
@@ -97,15 +137,19 @@ def chunked(q, k, v, g, S0, chunk: int = CHUNK):
     to a multiple of it with tokens that change nothing).  Returns
     (o [L, H, d_v] float32, S_L [H, d_k, d_v] float32).  Heads that share a
     key run the one scan side by side over the group's key and query: its
-    scores are made once a group, each head's decays laid over them."""
+    scores are made once a group, each head's decays laid over them.  S0
+    may come PACKED (``pack_state``); S_L then goes back packed the same."""
     L, H, dv = v.shape
     G = q.shape[1]
+    pack = _pack_of(S0.shape, H, q.shape[2], dv)
+    S0 = unpack_state(S0, pack)
     if G == H:
-        return _chunked(q, k, v, g, S0, chunk)
+        o, S = _chunked(q, k, v, g, S0, chunk)
+        return o, pack_state(S, pack)
     v, g, S0 = _by_group(q, v, g, S0)
     o, S = jax.vmap(lambda v, g, S0: _chunked(q, k, v, g, S0, chunk),
                     in_axes=(2, 2, 1), out_axes=(2, 1))(v, g, S0)
-    return o.reshape(L, H, dv), S.reshape(H, *S.shape[2:])
+    return o.reshape(L, H, dv), pack_state(S.reshape(H, *S.shape[2:]), pack)
 
 
 def _chunked(q, k, v, g, S0, chunk: int):
@@ -207,10 +251,14 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
     f32 = jnp.float32
     B, G, dk = q.shape
     H, dv = v.shape[1:]
-    n_groups = H // group  # blocks of ``group`` heads
-    width = group * dv
+    # the rows as they lie: H heads of d_v lanes, or H / pack of pack * d_v
+    # (a packed head's lanes are its heads' in order, as ``by_lane`` has
+    # them; the kernel sees wider heads and no difference)
+    rows, lanes = state.shape[2], state.shape[4]
+    n_groups = rows // group  # blocks of ``group`` heads
+    width = group * lanes
     # keys a block reads: a head's own (G = H), or the one its heads share
-    kb = group * G // H or 1
+    kb = group * G // rows or 1
     n_keys = G // kb  # key blocks; ``per`` head blocks read each
     per = n_groups // n_keys
 
@@ -228,7 +276,7 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
     order = jnp.argsort(~active, stable=True).astype(jnp.int32)
     live = jnp.sum(active).astype(jnp.int32).reshape(1)
 
-    vmem = _STATE_BUFFERS * group * dk * dv * 4 + _VMEM_BESIDE_BYTES
+    vmem = _STATE_BUFFERS * group * dk * lanes * 4 + _VMEM_BESIDE_BYTES
 
     def at(i, j, layer_ref, order_ref, live_ref):
         last = jnp.maximum(live_ref[0] - 1, 0)
@@ -243,7 +291,7 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
         slot, grp = at(i, j, *refs)
         return (slot, grp if per == 1 else grp // per, 0, 0, 0)
 
-    def rows(i, j, layer_ref, *refs):
+    def rows_of(i, j, layer_ref, *refs):
         slot, grp = at(i, j, layer_ref, *refs)
         return (layer_ref[0], slot, grp, 0, 0)
 
@@ -255,11 +303,11 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
             in_specs=[
                 pl.BlockSpec((1, 1, 2, dk, kb), columns),
                 pl.BlockSpec((1, 1, 2, width), small),
-                pl.BlockSpec((1, 1, group, dk, dv), rows),
+                pl.BlockSpec((1, 1, group, dk, lanes), rows_of),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, 1, width), small),
-                pl.BlockSpec((1, 1, group, dk, dv), rows),
+                pl.BlockSpec((1, 1, group, dk, lanes), rows_of),
             ]),
         out_shape=[jax.ShapeDtypeStruct((B, n_groups, 1, width), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -278,7 +326,9 @@ def _decode_update(state, layer, q, k, v, g, active, *, group: int,
 def decode_update(state, layer, q, k, v, g, active):
     """One token for every live slot, in place.
 
-    state: [layers, slots, H, d_k, d_v] float32, every layer's rows; only
+    state: [layers, slots, H, d_k, d_v] float32, every layer's rows, or
+    PACKED [layers, slots, H / pack, d_k, pack * d_v] (``pack_state``: the
+    heads side by side read one key, so ``pack`` divides H / G); only
     ``layer`` (an int32 scalar, traced or not) is read and written, and of
     it only the slots where ``active`` [B] holds.  q, k: [B, G, d_k] (H a
     multiple of G); v: [B, H, d_v]; g (log decay): [B, H].  Returns
@@ -289,16 +339,19 @@ def decode_update(state, layer, q, k, v, g, active):
             f"H, d_k, d_v]; got {state.dtype}{list(state.shape)}")
     B, G, dk = q.shape
     H, dv = v.shape[1:]
-    if state.shape[1:] != (B, H, dk, dv) or H % G:
+    pack = _pack_of(state.shape, H, dk, dv)
+    if state.shape[1] != B or H % G or (H // G) % pack:
         raise ValueError(
             f"state rows {state.shape[1:]} do not hold {B} slots of {H} "
-            f"heads [{dk}, {dv}], {H // G} to each of {G} keys")
+            f"heads [{dk}, {dv}], {H // G} to each of {G} keys (heads side "
+            f"by side in the lanes read one key)")
     on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and (dv % 128 or dk % 8):
+    if on_tpu and (pack * dv % 128 or dk % 8):
         raise ValueError(
-            f"on the TPU the lightning update moves whole tiles, and a "
-            f"[{dk}, {dv}] state is not made of them: d_k must be a "
-            f"multiple of 8 and d_v of 128")
+            f"on the TPU the lightning update moves whole tiles, and rows "
+            f"[{dk}, {pack * dv}] are not made of them: d_k must be a "
+            f"multiple of 8 and the lanes a head's rows take (d_v, or the "
+            f"d_v of the heads packed side by side) of 128")
     return _decode_update(state, layer, q, k, v, g, active,
-                          group=_heads_a_block(H, G, dk, dv),
+                          group=_heads_a_block(H // pack, G, dk, pack * dv),
                           interpret=not on_tpu)

@@ -12,8 +12,8 @@ import pytest
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
-                            longcat_flash, minicpm_sala, olmo_hybrid,
-                            sdar_moe)
+                            longcat_flash, minicpm_sala, nemotron_h,
+                            olmo_hybrid, sdar_moe)
 
 VOCAB = 128
 
@@ -45,6 +45,11 @@ FAMILIES = {
     "falcon_h1": (falcon_h1, falcon_h1.FalconH1Config.tiny(VOCAB),
                   "FalconH1Config has a recurrent mixer in every block .* "
                   "does not serve with"),
+    # (layers that are one thing each: state rows for the mixers, pages for
+    # the attention layers, a share of the experts; chunks carry the state)
+    "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig.tiny(VOCAB),
+                   "NemotronHConfig has recurrent mixer layers .* does not "
+                   "serve with"),
 }
 
 # path -> (the feature it needs, where the refusal says it was asked)
@@ -106,7 +111,7 @@ def test_every_family_declares_only_features_this_test_asks_for():
         assert set(cfg.refuses) <= asked
     assert FAMILIES["llama"][1].refuses == {}
     # state beside the pages, and yet a prompt in chunks: the chunks carry it
-    for chunks_carry in ("minicpm_sala", "falcon_h1"):
+    for chunks_carry in ("minicpm_sala", "falcon_h1", "nemotron_h"):
         assert set(FAMILIES[chunks_carry][1].refuses) == {
             "pd", "kv_tier", "prefix_cache"}
 

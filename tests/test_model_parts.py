@@ -26,6 +26,7 @@ from benchmarks.trace.device_parts import part_runs, split_path
 from ray_tpu.llm import model as lm
 from ray_tpu.llm.paged_cache import CacheConfig, init_cache, init_state
 from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
+                            nemotron_h,
                             longcat_flash, minicpm_sala, moe, olmo_hybrid,
                             sdar_moe)
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -79,6 +80,14 @@ SPARSE_LINEAR = ("embed", "layers", "attn/norm", "attn/rope", "attn/kv_write",
 # parts and the mixer's, state rows and pages in one layer
 PARALLEL_SSM = DENSE + ("attn/kv_write", "head", "ssm/proj", "ssm/conv",
                         "ssm/gates", "ssm/state", "ssm/out")
+# layers that are ONE thing each (a mixer, attention, a routed feed-forward):
+# no ``attn/rope`` (its attention carries no position), no dense MLP; the
+# experts in a latent with a product into it and one back, a share of them
+# held (``dispatch_share`` with no identity column: its ``moe/zero`` adds 0)
+ONE_KIND = (("embed", "layers", "attn/norm", "attn/qkv", "attn/kv_write",
+             "attn/attend", "attn/out", "mlp/norm", "head", "ssm/proj",
+             "ssm/conv", "ssm/gates", "ssm/state", "ssm/out") + ROUTED[-4:]
+            + ("moe/latent_in", "moe/latent_out", "moe/shared", "moe/zero"))
 PS, PAGES = 8, 16  # page size, pages in the pool
 
 
@@ -249,6 +258,22 @@ def _parallel_ssm(program):
     return fn, (params, tokens, ck, cv, *rest), cfg, rows
 
 
+def _one_kind(program):
+    """``program`` over Nemotron-H's caches: a pool layer for each ``*`` of
+    the pattern and a state layer (packed rows, the convolution's tail) for
+    each ``M``, the held experts stacked over the ``E`` layers."""
+    cfg = nemotron_h.NemotronHConfig.tiny()
+    params = cfg.serving_layout(nemotron_h.init(cfg, jax.random.PRNGKey(0)))
+    cc = CacheConfig(**lm.cache_layout(cfg), num_pages=PAGES, page_size=PS,
+                     dtype="float32", max_slots=4)
+    ck, cv = init_cache(cc)
+    fn, (_, tokens, _, _, *rest), _ = program()
+    rows = {"state": init_state(cc)}
+    if program is not _decode:
+        rows["slot"] = jnp.int32(1)
+    return fn, (params, tokens, ck, cv, *rest), cfg, rows
+
+
 def _grad(model, cfg, **kw):
     """(function of (params, tokens), its arguments)."""
     params = model.init(cfg, jax.random.PRNGKey(0))
@@ -324,7 +349,9 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
           "parallel_ssm_prefill_with_prefix":
               lambda: _parallel_ssm(_prefill_with_prefix),
           "parallel_ssm_decode_step_greedy":
-              lambda: _parallel_ssm(_decode)}
+              lambda: _parallel_ssm(_decode),
+          "one_kind_prefill": lambda: _one_kind(_prefill),
+          "one_kind_decode_step_greedy": lambda: _one_kind(_decode)}
 TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
     "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
@@ -358,6 +385,8 @@ EXPECTED = {
     "parallel_ssm_prefill_with_prefix":
         PARALLEL_SSM + ("attn/attend/repeat_kv",),
     "parallel_ssm_decode_step_greedy": PARALLEL_SSM + ("sample",),
+    "one_kind_prefill": ONE_KIND + ("attn/attend/repeat_kv",),
+    "one_kind_decode_step_greedy": ONE_KIND + ("sample",),
     # the flash kernels read K and V at their own heads (PR 47): a program
     # that attends through them repeats nothing; the plain XLA path does
     "llama_grad_remat": DENSE + ("head", "loss"),
@@ -420,6 +449,10 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
                                                    "lightning_update"},
               "parallel_ssm_decode_step_greedy": {"paged_decode_attention",
                                                   "lightning_update"},
+              "one_kind_prefill": {"moe_grouped_mlp"},
+              "one_kind_decode_step_greedy": {
+                  "paged_decode_attention", "lightning_update",
+                  "moe_grouped_mlp"},
               "llama_grad": set(FLASH),
               **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
     assert wanted <= seen
