@@ -36,6 +36,7 @@ kernel is handed (``counted``; ops/block_sparse.py ``walked``).
 
 from __future__ import annotations
 
+import functools
 import queue as queue_mod
 import threading
 import time
@@ -92,6 +93,14 @@ def _engine_metrics():
                 "decode_steps": Counter(
                     "llm_decode_steps_total", "Batched decode steps (for "
                     "a block-diffusion model, denoising passes)"),
+                "decode_programs": Counter(
+                    "llm_decode_programs_total", "Device programs the "
+                    "token-at-a-time decode loop launched (a greedy burst: "
+                    "one a step and no other)"),
+                "decode_fetches": Counter(
+                    "llm_decode_fetches_total", "Arrays that loop fetched "
+                    "from the device (a greedy burst: ONE, its tokens and "
+                    "what its steps counted together)"),
                 "block_slot_passes": Counter(
                     "llm_block_slot_passes_total", "Denoising passes a "
                     "live slot took part in (tokens / this = tokens a "
@@ -340,13 +349,15 @@ _PHASE_ATTRS = {
     P_PREFILL_EMIT: (), P_DECODE_HOST: ("active_slots", "burst"),
     P_DECODE_DISPATCH: ("burst",), P_DECODE_FETCH: ("burst",),
     # a block-diffusion burst adds what its passes did
-    # ... and a model with recurrent layers names its own (a dict): also
-    # ``steps`` and ``state_slots``, the live slots whose state rows the
-    # burst's steps updated, summed over them; so does a token-at-a-time
-    # model with routed experts or latent pages: ``steps``, the
-    # ``experts_read`` by the burst's steps and the ``latent_pages_read``;
-    # a model with window layers ``window_pages_read``,
-    # ``window_pages_skipped`` and ``full_pages_read`` (a layer of a kind)
+    # ... and a token-at-a-time burst names its own (a dict): ``tokens``,
+    # ``slots_released``, its ``steps``, the device ``programs`` it
+    # launched and the arrays it fetched (``fetches``), and what the
+    # family counts: a model with recurrent layers ``state_slots``, the
+    # live slots whose state rows the burst's steps updated, summed over
+    # them; one with routed experts or latent pages the ``experts_read``
+    # by the burst's steps and the ``latent_pages_read``; a model with
+    # window layers ``window_pages_read``, ``window_pages_skipped`` and
+    # ``full_pages_read`` (a layer of a kind)
     P_DECODE_EMIT: ("tokens", "slots_released", "slot_passes",
                     "masks_filled", "blocks_final", "experts_read",
                     "passes"),
@@ -633,19 +644,14 @@ class _Slot:
     wpages: List[int] = field(default_factory=list)
 
 
-@jax.jit
-def _summed(counts: list) -> dict:
-    """The ``counted`` of a burst's steps, summed on the device."""
-    return jax.tree.map(lambda *steps: sum(steps[1:], steps[0]), *counts)
-
-
 def _by_name(counted: dict) -> dict:
     """A program's fetched ``counted`` by counter name.  A key is a name
     (its value a scalar) or a TUPLE of names (a vector of as many).  Every
     array fetched is a round trip of ~0.3 ms on the chip's host: five
     scalars a step were 40 a burst and 7 % of a token's time for
     models/minicpm_sala.py, a vector a step 8 and 1.8 % (PERF.md section 6,
-    PR 49), hence one vector a step and ``_summed`` a burst."""
+    PR 49), hence one vector a step; a greedy burst's ride in the array
+    its tokens come back in (``_burst_counts``)."""
     out = {}
     for key, n in counted.items():
         if isinstance(key, tuple):
@@ -653,6 +659,19 @@ def _by_name(counted: dict) -> dict:
         else:
             out[key] = int(n)
     return out
+
+
+def _burst_counts(layout: tuple, flat) -> dict:
+    """A greedy burst's counts by counter name: ``flat`` is the sum over
+    the burst's rows of ``acc``'s columns behind the tokens, which hold a
+    step's ``counted`` key after key as ``layout`` (``lm.counted_layout``)
+    says."""
+    counted, at = {}, 0
+    for key, entries in layout:
+        counted[key] = (flat[at:at + entries] if isinstance(key, tuple)
+                        else flat[at])
+        at += entries
+    return _by_name(counted)
 
 
 class LLMEngine:
@@ -664,8 +683,8 @@ class LLMEngine:
     (page pools for the layers that attend, a latent pool, state rows a
     slot beside them), ``serving_layout`` how it holds the tree,
     ``block_length`` whether a decode step is ``decode_step`` /
-    ``decode_step_greedy`` or ``block_step``, and ``refuses`` what it
-    cannot be served with: a feature listed there is refused by the
+    ``decode_step_greedy_chained`` or ``block_step``, and ``refuses`` what
+    it cannot be served with: a feature listed there is refused by the
     family's own sentence (``_refuse``) or not built (``prefix_cache``,
     ``kv_tier``).  What the programs counted comes back by name and goes
     to ``stats()``, the metrics and the spans under that name.
@@ -763,6 +782,9 @@ class LLMEngine:
         self.kv_tier = None if "kv_tier" in model_cfg.refuses else kv_tier
         self._hydrate_q: queue_mod.Queue = queue_mod.Queue()
         self._waiting: queue_mod.Queue = queue_mod.Queue()
+        # a greedy burst's first row index: a constant of the device (the
+        # chained step donates the rest of its carry, never this)
+        self._row0 = jnp.zeros((), jnp.int32)
         # Single-writer design: _slots, the allocator, and _stats are
         # mutated ONLY by the scheduler thread (_loop); other threads
         # submit through the thread-safe _waiting queue and read counters
@@ -776,6 +798,7 @@ class LLMEngine:
         self._thread: Optional[threading.Thread] = None
         # decode-state host mirrors (device arrays rebuilt when they change)
         self._stats = {"prefills": 0, "decode_steps": 0,
+                       "decode_programs": 0, "decode_fetches": 0,
                        "decode_pages_read": 0, "decode_pages_in_runs": 0,
                        "latent_pages_read": 0, "window_pages_in_runs": 0,
                        "block_slot_passes": 0,
@@ -2012,7 +2035,7 @@ class LLMEngine:
                              + self.allocator.num_resident()) >= n_pages
             except IndexError:
                 pass
-        burst = 8 if (all_greedy and not can_admit) else 1
+        burst = lm.BURST_ROWS if (all_greedy and not can_admit) else 1
         # lazy allocation's second half: cover the burst's decode writes,
         # preempting under pool pressure — slots may vanish here
         self._ensure_capacity(burst)
@@ -2035,6 +2058,11 @@ class LLMEngine:
             positions[i] = s.num_tokens  # position of the new token
             tables[i, :len(s.pages)] = s.pages
             active[i] = True
+        if all_greedy:
+            # a greedy burst's ``acc`` goes up FIRST, ahead of the tables:
+            # its first step cannot start before every argument has arrived
+            acc = jnp.asarray(np.zeros(
+                lm.acc_shape(B, self._counted_layout), np.int32))
         toks_dev = jnp.asarray(tokens)
         pos_dev = jnp.asarray(positions)
         tables_dev = jnp.asarray(tables)
@@ -2072,30 +2100,32 @@ class LLMEngine:
                     wtables[active], positions[active], burst, self._window)
         if self.state is not None:
             named["state_slot_steps"] = burst * len(active_slots)
-        counts = []  # a step's ``counted``, on the device
         emitted = self._stats["tokens_generated"]
         ph.vals = (len(active_slots), burst)
         ph.begin(P_DECODE_DISPATCH, vals=(burst,))
         if all_greedy:
-            steps = []
+            # the burst's carry lives on the device: each launch takes the
+            # last one's tokens, positions, row and ``acc`` (a row a step:
+            # its tokens, then what it counted) and NOTHING runs between
+            # two steps; one array comes back, whatever the burst's length
+            row = self._row0
             for j in range(burst):
-                toks_dev, counted = self._run(
-                    lm.decode_step_greedy, toks_dev, tables_dev,
-                    pos_dev + j, active_dev)
-                counts.append(counted)
-                steps.append(toks_dev)
+                (toks_dev, pos_dev, row, acc), _ = self._run(
+                    lm.decode_step_greedy_chained, toks_dev, tables_dev,
+                    pos_dev, active_dev, row, acc)
                 if j == 0 and self._deliver(True) and burst > 1:
                     ph.begin(P_DECODE_DISPATCH, vals=(burst,))
-            # the host waits for the device
+            # the host waits for the device: ONE round trip a burst
             ph.begin(P_DECODE_FETCH, vals=(burst,))
-            # ONE host round trip for the whole burst (stack on device)
-            rows = (np.asarray(jnp.stack(steps)) if burst > 1
-                    else np.asarray(steps[0])[None])
+            acc = np.asarray(acc)[:burst]
             ph.begin(P_DECODE_EMIT)
+            rows = acc[:, :B]
+            named.update(_burst_counts(self._counted_layout,
+                                       acc[:, B:].sum(axis=0)))
+            programs, fetches = burst, 1
         else:
             logits, counted = self._run(lm.decode_step, toks_dev,
                                         tables_dev, pos_dev, active_dev)
-            counts.append(counted)
             self._deliver(True)
             ph.begin(P_DECODE_FETCH, vals=(burst,))
             logits_np = np.asarray(logits)
@@ -2104,22 +2134,23 @@ class LLMEngine:
             for i, s in active_slots:
                 rows[0, i] = self._sample_one(
                     logits_np[i], s.request.params, s.rng)
-        if counts[0]:  # computed with the tokens that were just fetched
-            # (a burst's counts are summed on the device: what the host
-            # fetches is one transfer a burst, not one a step)
-            named.update(_by_name(jax.device_get(
-                _summed(counts) if burst > 1 else counts[0])))
-        self._count({"decode_steps": burst, "decode_pages_read": pages_read,
-                     **named})
+            # what the step counted, a second fetch: every key a transfer
+            named.update(_by_name(jax.device_get(counted)))
+            programs, fetches = 1, 1 + len(counted)
+        self._count({"decode_steps": burst, "decode_programs": programs,
+                     "decode_fetches": fetches,
+                     "decode_pages_read": pages_read, **named})
         self._accept_burst(active_slots, rows)
         if ph.sampled:
-            ph.vals = (self._stats["tokens_generated"] - emitted,
-                       sum(self._slots[i] is not s for i, s in active_slots))
-            if named:  # the span names them too, and the steps
-                if "state_slot_steps" in named:  # (under the span's name)
-                    named["state_slots"] = named.pop("state_slot_steps")
-                ph.vals = dict(zip(_PHASE_ATTRS[P_DECODE_EMIT], ph.vals),
-                               steps=burst, **named)
+            # the span names the steps too, what the burst launched and
+            # fetched (``programs``, ``fetches``) and the family's counts
+            if "state_slot_steps" in named:  # (under the span's name)
+                named["state_slots"] = named.pop("state_slot_steps")
+            ph.vals = dict(
+                tokens=self._stats["tokens_generated"] - emitted,
+                slots_released=sum(self._slots[i] is not s
+                                   for i, s in active_slots),
+                steps=burst, programs=programs, fetches=fetches, **named)
         return True
 
     def _merged_upto(self, tables):
@@ -2154,6 +2185,20 @@ class LLMEngine:
         for name, n in did.items():
             self._stats[name] += n
             self._m[name].inc(n)
+
+    @functools.cached_property
+    def _counted_layout(self) -> tuple:
+        """What a decode step of this model counts on the device, key by
+        key and how many entries each (``lm.counted_layout``: one abstract
+        trace an engine, before its first greedy burst)."""
+        slots = jax.ShapeDtypeStruct((self.cfg.max_slots,), jnp.int32)
+        tables = jax.ShapeDtypeStruct(
+            (self.cfg.max_slots, self.max_pages_per_seq), jnp.int32)
+        return lm.counted_layout(
+            self.params, slots, self.cache_k, self.cache_v,
+            {"full": tables, "window": tables} if self._window else tables,
+            slots, jax.ShapeDtypeStruct(slots.shape, bool), self.model_cfg,
+            state=self.state)
 
     def _run(self, program, tokens, *args, slot=None):
         """One of llm/model.py's token-at-a-time programs over the pools

@@ -95,13 +95,18 @@ Every token program hands back ONE shape: (result, counted, cache_k,
 cache_v, state); ``counted`` is a mapping (empty for a dense model) of a
 counter's name to a scalar, or of a TUPLE of names to one vector of as
 many (what the engine fetches is a transfer a key), ``state`` and a latent
-model's ``cache_v`` None.  Pools and state are
+model's ``cache_v`` None.  A greedy burst's step
+(``decode_step_greedy_chained``) hands back its CARRY as the result (the
+next step's tokens and positions, the row to write next and ``acc``, which
+holds a step's tokens and counts a row) and an empty ``counted``: what it
+counted is in ``acc``, in ``counted_layout``'s order.  Pools and state are
 donated and ride in the layer scan's carry whole, scattered in place at
 ``[li, page, slot]``: nothing pool-sized is sliced, stacked or copied.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -780,6 +785,59 @@ def decode_step_greedy(params, tokens, cache_k, cache_v, page_tables,
         cfg, state)
     with jax.named_scope("sample"):
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
+
+
+BURST_ROWS = 8  # the rows of ``acc``: the engine's longest greedy burst
+
+
+def _layout(counted) -> tuple:
+    """((key, entries), ...) of a step's ``counted`` in the order a row of
+    ``acc`` holds it behind the tokens: a name's scalar, a tuple of names'
+    vector."""
+    return tuple((key, math.prod(n.shape))
+                 for key, n in sorted(counted.items(),
+                                     key=lambda kn: str(kn[0])))
+
+
+def counted_layout(params, tokens, cache_k, cache_v, page_tables, positions,
+                   active, cfg, state=None) -> tuple:
+    """``_layout`` of what a decode step over these arguments counts (the
+    engine sizes ``acc`` by it and names a burst's counts by it).  The step
+    is traced in the abstract: nothing is compiled and nothing runs."""
+    return _layout(jax.eval_shape(
+        partial(_decode_impl, cfg=cfg), params, tokens, cache_k, cache_v,
+        page_tables, positions, active, state=state)[1])
+
+
+def acc_shape(slots: int, layout: tuple) -> tuple:
+    """``acc``'s shape for ``slots`` slots and a step that counts what
+    ``layout`` (``counted_layout``) says: a row a step of the longest
+    burst, a slot's token a column, then a column an entry counted."""
+    return BURST_ROWS, slots + sum(entries for _, entries in layout)
+
+
+@partial(jax.jit, static_argnames=("cfg",),
+         donate_argnames=("tokens", "cache_k", "cache_v", "positions", "acc",
+                          "state"))
+def decode_step_greedy_chained(params, tokens, cache_k, cache_v, page_tables,
+                               positions, active, row, acc, cfg, state=None):
+    """``decode_step_greedy`` for a burst whose carry STAYS on the device:
+    the step takes the last step's tokens and positions (handed back ``+
+    1``: no program of the host's advances them) and writes its argmax
+    tokens, then what it counted (``_layout``'s order), into row ``row`` of
+    ``acc`` ([BURST_ROWS, B + entries] int32, donated as tokens and
+    positions are), so that a burst is as many launches and ONE fetch,
+    tokens and counts together.  Returns ((tokens, positions, row + 1,
+    acc), {}, cache_k, cache_v, state): the counts are in ``acc``."""
+    logits, counted, *rest = _decode_impl(
+        params, tokens, cache_k, cache_v, page_tables, positions, active,
+        cfg, state)
+    with jax.named_scope("sample"):
+        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        acc = jax.lax.dynamic_update_slice(acc, jnp.concatenate(
+            [tokens, *(counted[key].reshape(-1).astype(jnp.int32)
+                       for key, _ in _layout(counted))])[None], (row, 0))
+    return ((tokens, positions + 1, row + 1, acc), {}, *rest)
 
 
 def _fill(cfg, logits, masked, step):

@@ -16,6 +16,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from benchmarks.reference import sdar_moe as sdar_reference
@@ -29,7 +30,7 @@ from test_block_diffusion import _file as file_config
 from test_block_diffusion import _prompts as block_prompts
 
 PROGRAMS = ("prefill", "prefill_with_prefix", "decode_step",
-            "decode_step_greedy", "block_step")
+            "decode_step_greedy", "decode_step_greedy_chained", "block_step")
 PROMPT = [3, 14, 15, 92, 65, 35]
 
 
@@ -217,14 +218,14 @@ def test_a_loop_exception_follows_the_tokens_already_replayed(
     two bursts replayed before it (the second still pending then), the
     error, the terminator; and the engine serves the next request."""
     calls = []
-    step = lm.decode_step_greedy
+    step = lm.decode_step_greedy_chained
 
     def failing(*a, **kw):
         calls.append(1)
         if len(calls) == 17:
             raise RuntimeError("the device fell over")
         return step(*a, **kw)
-    monkeypatch.setattr(lm, "decode_step_greedy", failing)
+    monkeypatch.setattr(lm, "decode_step_greedy_chained", failing)
     engine = _engine(dense)
     req = engine.submit(PROMPT, SamplingParams(max_tokens=40))
     engine.start()
@@ -261,7 +262,7 @@ def test_puts_follow_the_next_dispatch_and_precede_the_next_fetch(
     programs = {e[1] for e in rec.events if e[0] == "dispatch"}
     # (the third prompt shares a boundary page: a copy and a suffix prefill)
     assert programs - {"prefill_with_prefix"} == {
-        "prefill", "decode_step_greedy" if temperature == 0
+        "prefill", "decode_step_greedy_chained" if temperature == 0
         else "decode_step"}
     # the first token of a prefill takes the same road: it is put behind
     # the dispatch that follows its prefill_fetch
@@ -303,6 +304,56 @@ def test_the_last_request_is_delivered_without_a_dispatch(dense, full,
     assert at_dispatch[-1]["deliveries"] >= 2
     assert stats["deliveries"] == stats["deliveries_behind_dispatch"] + 1
     assert stats["tokens_generated"] == 20
+
+
+def test_a_greedy_burst_is_its_steps_and_one_fetch(dense, full, monkeypatch):
+    """Between two fetches a greedy burst launches its steps and nothing
+    else of llm/model.py's, every one the chained program, and what comes
+    back from the device is ONE array; ``stats()`` says the same."""
+    engine = _engine(dense)
+    rec = Recorder(monkeypatch, engine)
+
+    class Numpy:  # the engine's numpy, noting what it takes off the device
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *a, **kw):
+            if isinstance(x, jax.Array):
+                rec._note("get", "np.asarray", x.shape)
+            return np.asarray(x, *a, **kw)
+
+    def device_get(x):
+        rec._note("get", "jax.device_get", len(jax.tree.leaves(x)))
+        return get(x)
+    get = jax.device_get
+    monkeypatch.setattr(engine_mod, "np", Numpy())
+    monkeypatch.setattr(jax, "device_get", device_get)
+    req = rec.submit("only", PROMPT, max_tokens=20)
+    engine.start()
+    try:
+        assert _items(req, timeout=60) == full[:20] + [None]
+    finally:
+        engine.stop()
+    events = [e for e in rec.events if e[0] != "put"]
+    fetches = [k for k, e in enumerate(events) if e[0] == "fetch"]
+    bursts = []
+    for k, nxt in zip(fetches, fetches[1:] + [len(events)]):
+        if events[k][1] != engine_mod.P_DECODE_FETCH:
+            continue
+        # what ran since the fetch before, and what came back after this one
+        before = max(j for j in fetches if j < k)
+        launched = [e[1] for e in events[before + 1:k]
+                    if e[0] == "dispatch"]
+        assert set(launched) == {"decode_step_greedy_chained"}, launched
+        got = [e for e in events[k + 1:nxt] if e[0] == "get"]
+        assert [e[1] for e in got] == ["np.asarray"], got
+        assert got[0][2][0] == lm.BURST_ROWS
+        bursts.append(len(launched))
+    assert bursts == [8, 8, 8]  # 19 tokens after the prefill's
+    stats = engine.stats()
+    assert stats["decode_programs"] == stats["decode_steps"] == sum(bursts)
+    assert stats["decode_fetches"] == len(bursts)
 
 
 def test_stop_delivers_what_the_last_replay_left(dense):

@@ -320,8 +320,8 @@ def test_serving_layout_stacks_qkv_and_touches_nothing_else(family):
 @pytest.mark.parametrize("family,program", [
     ("llama", "prefill"), ("llama", "prefill_with_prefix"),
     ("llama", "decode_step"), ("llama", "decode_step_greedy"),
-    ("sdar_moe", "prefill"), ("sdar_moe", "prefill_with_prefix"),
-    ("sdar_moe", "block_step")])
+    ("llama", "decode_step_greedy_chained"), ("sdar_moe", "prefill"),
+    ("sdar_moe", "prefill_with_prefix"), ("sdar_moe", "block_step")])
 def test_programs_on_the_serving_layout_equal_the_three_weight_tree(
         family, program):
     """Every served program over scattered pages, once with ``wq``, ``wk``,
@@ -371,9 +371,11 @@ def test_programs_on_the_serving_layout_equal_the_three_weight_tree(
                 params, *cache, tables, active, jnp.asarray(tokens),
                 jnp.asarray(masked), jnp.asarray([0, 16], jnp.int32),
                 jnp.zeros(2, jnp.int32), cfg)
+        carry = ((jnp.int32(3), jnp.zeros(lm.acc_shape(2, ()), jnp.int32))
+                 if program == "decode_step_greedy_chained" else ())
         return getattr(lm, program)(
             params, jnp.asarray([0, prompt[16]], jnp.int32), *cache, tables,
-            jnp.asarray([0, 16], jnp.int32), active, cfg)
+            jnp.asarray([0, 16], jnp.int32), active, *carry, cfg)
 
     want, got = run(params), run(llama.serving_layout(params))
     assert jax.tree.structure(got) == jax.tree.structure(want)

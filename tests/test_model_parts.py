@@ -312,6 +312,16 @@ def _train_step(batch=2, **axes):
     return step, (state, jnp.zeros((batch, 33), jnp.int32)), mesh
 
 
+def _chained(decode):
+    """A family's decode step as a burst's chained program: the same
+    arguments, then the row to write and ``acc`` at the family's width."""
+    _, args, cfg, *rows = decode()
+    layout = lm.counted_layout(*args, cfg, **dict(*rows))
+    acc = jnp.full(lm.acc_shape(args[1].shape[0], layout), -1, jnp.int32)
+    return (lm.decode_step_greedy_chained, (*args, jnp.int32(0), acc), cfg,
+            *rows)
+
+
 def _compiled_text(name):
     """The compiled text of one of the programs below."""
     if name in ENGINE:
@@ -352,6 +362,11 @@ ENGINE = {"prefill": _prefill, "prefill_with_prefix": _prefill_with_prefix,
               lambda: _parallel_ssm(_decode),
           "one_kind_prefill": lambda: _one_kind(_prefill),
           "one_kind_decode_step_greedy": lambda: _one_kind(_decode)}
+# a family's greedy decode step, and the burst's chained program over it
+DECODE = {name: build for name, build in ENGINE.items()
+          if name.endswith("decode_step_greedy")}
+ENGINE.update({name + "_chained": (lambda build=build: _chained(build))
+               for name, build in DECODE.items()})
 TRAIN = {"train_step": {}, "train_step_fsdp2_tp2": {
     "batch": 4, "fsdp": 2, "tp": 2}}
 GRADS = {"llama_grad_remat": lambda: _llama_grad(True),
@@ -396,6 +411,9 @@ EXPECTED = {
     "train_step_fsdp2_tp2": DENSE + ("head", "loss", "optim", "tp/gather",
                                      "tp/scatter"),
 }
+# (the chained program is its step's parts: the write into ``acc`` lies
+# under ``sample``)
+EXPECTED.update({name + "_chained": EXPECTED[name] for name in DECODE})
 _TEXTS = {}
 
 
@@ -454,7 +472,8 @@ def test_products_and_kernels_lie_under_exactly_one_part(name):
                   "paged_decode_attention", "lightning_update",
                   "moe_grouped_mlp"},
               "llama_grad": set(FLASH),
-              **dict.fromkeys(TRAIN, set(FLASH))}.get(name, set())
+              **dict.fromkeys(TRAIN, set(FLASH))}.get(
+                  name.removesuffix("_chained"), set())
     assert wanted <= seen
 
 

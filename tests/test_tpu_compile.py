@@ -722,6 +722,65 @@ def test_windowed_programs_compile_at_trinity_widths(topo, as_tpu, program):
     assert abs(planned / 1e9 - WINDOWED_PLANNED_GB[program]) < 0.05
 
 
+class _Chained:
+    """``lm.decode_step_greedy`` as the tests lower it, lowering the chained
+    program over the same shapes and the carry the engine hands it; keeps
+    what it compiled."""
+
+    def lower(self, params, tokens, cache_k, cache_v, tables, positions,
+              active, cfg, state=None):
+        self.layout = lm.counted_layout(
+            params, tokens, cache_k, cache_v, tables, positions, active, cfg,
+            state=state)
+        sds = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, jnp.int32, sharding=tokens.sharding)
+        self.acc = sds(lm.acc_shape(tokens.shape[0], self.layout))
+        self.lowered = lm.decode_step_greedy_chained.lower(
+            params, tokens, cache_k, cache_v, tables, positions, active,
+            sds(()), self.acc, cfg, state)
+        return self
+
+    def compile(self):
+        self.compiled = self.lowered.compile()
+        return self.compiled
+
+
+@pytest.mark.parametrize("step, counts", [
+    ("hybrid_programs_compile_at_olmo_widths", 0),
+    ("parallel_ssm_programs_compile_at_falcon_h1_widths", 0),
+    ("windowed_programs_compile_at_trinity_widths", 1),
+    ("shortcut_moe_programs_compile_at_longcat_widths", 4)])
+def test_the_chained_step_compiles_as_its_step_does(
+        topo, as_tpu, monkeypatch, step, counts):
+    """A greedy burst's chained step (``lm.decode_step_greedy_chained``) at
+    the hybrid's, Falcon-H1's, the windowed and the share's shapes: each of
+    those tests runs here whole with the chained program standing in for
+    the step it lowers, so everything it holds the step to (pools and state
+    rows aliased and held once, no copy of them, the kernels, the planned
+    bytes) is held of the chained program too; and the burst's carry
+    (tokens, positions, ``acc``) comes back in the buffers it came in."""
+    chained = _Chained()
+    monkeypatch.setattr(lm, "decode_step_greedy", chained)
+    globals()["test_" + step](topo, as_tpu, "decode_step_greedy")
+    assert sum(n for _, n in chained.layout) == counts
+    text = chained.compiled.as_text()
+    # the burst's carry comes back in the buffers it came in: ``acc`` and
+    # the two [slots] vectors are parameters the module aliases to outputs
+    aliased = {int(n) for n in re.findall(
+        r"\(\s*(\d+), \{\}, (?:may|must)-alias\)",
+        text.split("input_output_alias={", 1)[1].split("entry_computation",
+                                                       1)[0])}
+    carried = {name: int(n) for n, name in re.findall(
+        r'parameter\((\d+)\).*?op_name="(tokens|positions|acc)"', text)}
+    assert len(carried) == 3 and set(carried.values()) <= aliased, (
+        carried, aliased)
+    width = chained.acc.shape[1]
+    results = [line.split(" = ")[1] for line in text.splitlines()
+               if " = " in line]
+    assert not [r for r in results if " copy(" in r
+                and r.startswith(f"s32[8,{width}]")]
+
+
 # planned bytes a program of configuration ``minicpm_sala_serve_1chip``,
 # compiled for the described v5e here (PERF.md section 4): weights 5.64 GB
 # (serving layout), the sparse layers' pools 1.68 GB, the rows of pooled
