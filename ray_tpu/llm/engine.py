@@ -55,7 +55,6 @@ from ray_tpu.llm.kv_tier import KVPullError
 from ray_tpu.llm.paged_cache import (CacheConfig, PageAllocator, PrefixCache,
                                      init_cache, init_state)
 from ray_tpu.ops import paged_attention
-from ray_tpu.ops.gated_delta import CHUNK as SCAN_CHUNK
 from ray_tpu.util import tracing
 
 # Serving observability (ISSUE 8): the engine-local stats() dict stays the
@@ -760,6 +759,7 @@ class LLMEngine:
         # recurrent layers' rows, a slot each (None: the model has none)
         self.state = init_state(ccfg)
         self._state_layers = ccfg.state_layers
+        self._scan_chunk = ccfg.scan_chunk  # of the family's prefill scan
         self.allocator = PageAllocator(self.cfg.num_pages)
         # Prefix caching (ISSUE 10): finished sequences leave their full
         # prompt pages resident; later prompts sharing a page-aligned
@@ -1594,7 +1594,7 @@ class LLMEngine:
             logits, counted = self._run(program, tokens, *args, slot=slot)
             if self.state is not None:
                 # (a later chunk goes on from the slot's rows: no reset)
-                did["scan_chunks"] = (-(-bucket // SCAN_CHUNK)
+                did["scan_chunks"] = (-(-bucket // self._scan_chunk)
                                       * self._state_layers)
                 self._count({"scan_chunks": did["scan_chunks"],
                              "state_resets": int(prefix_len == 0)})

@@ -595,12 +595,19 @@ def prefill_with_prefix(params, tokens, cache_k, cache_v, page_rows,
                                      jnp.where(real, g, 0.0), S0)
         return o, (rows[0], S)
 
-    recur_fixed = _conv_first(
-        cfg, recur_fixed,
-        lambda rows, taps, x: jax.lax.dynamic_slice(
-            state["conv"], (rows[1] * taps, slot, 0),
-            (taps, 1, x.shape[-1]))[:, 0],
-        _last_inputs(true_len))
+    def conv_before(rows, taps, x):
+        return jax.lax.dynamic_slice_in_dim(conv_rows, rows[1] * taps, taps)
+
+    if state is not None and "conv" in state:
+        # the SLOT's convolution rows [layers x taps, C], read out once and
+        # not in the walk: a loop that carries every slot's rows (11.8 MB at
+        # Falcon-H1's widths) to slice a layer's three out of them lets XLA
+        # stage all of them through VMEM around each layer
+        with jax.named_scope(falcon_h1.CONV_PART):
+            conv_rows = jax.lax.dynamic_index_in_dim(state["conv"], slot, 1,
+                                                     keepdims=False)
+    recur_fixed = _conv_first(cfg, recur_fixed, conv_before,
+                              _last_inputs(true_len))
 
     x, (cache_k, cache_v, rode), counted, left = cfg.served_walk(
         params, x, (cache_k, cache_v, _rows_that_ride(cfg, state)), positions,

@@ -100,7 +100,9 @@ class CacheConfig:
     ``state_rows``, by name the (count, shape, dtype) of the rows that each
     of ``max_slots`` slots holds for the ``state_layers`` recurrent layers
     between them: fixed in size, beside the pages and not in them,
-    meaningless once the slot is released.  A fourth, beside K/V pages
+    meaningless once the slot is released (``scan_chunk``: the tokens a
+    step of those layers' prefill scan takes, for the engine's count of
+    the steps that ran).  A fourth, beside K/V pages
     only: ``window_layers`` of the attending layers see the last ``window``
     positions alone and have a pool of their own, ``window_pages`` pages a
     layer (the module docstring says how a sequence holds them);
@@ -131,6 +133,7 @@ class CacheConfig:
     dtype: str = "bfloat16"
     state_layers: int = 0
     state_rows: Optional[dict] = None
+    scan_chunk: int = 0  # tokens a step of a state layer's prefill scan takes
     max_slots: int = 0
     latent_dim: int = 0
     window_layers: int = 0
@@ -152,6 +155,12 @@ class CacheConfig:
                 f"window layers ({self.window_layers}) come with a window "
                 f"({self.window}), K/V pages and a pool of their own "
                 f"(window_pages {self.window_pages}), or not at all")
+        if self.state_layers and self.scan_chunk <= 0:
+            raise ValueError(
+                f"{self.state_layers} state layers come with the tokens a "
+                f"step of their prefill scan takes (scan_chunk "
+                f"{self.scan_chunk}): cache_layout() declares it beside "
+                f"state_rows, and the engine counts scan_chunks by it")
 
     @property
     def tokens_capacity(self) -> int:

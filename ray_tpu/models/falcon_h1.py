@@ -159,6 +159,7 @@ class FalconH1Config:
         n, taps = self.n_layers, self.conv_width - 1
         return {"n_layers": n, "n_kv_heads": self.n_kv_heads,
                 "head_dim": self.head_dim, "state_layers": n,
+                "scan_chunk": lightning.CHUNK,
                 "state_rows": {
                     "S": (n, (self.ssm_heads, self.ssm_state,
                               self.ssm_head_dim), jnp.float32),

@@ -157,7 +157,7 @@ class MiniCPMSALAConfig:
         n_lin = self.count(LINEAR)
         return {"n_layers": self.count(SPARSE),
                 "n_kv_heads": self.n_kv_heads, "head_dim": self.head_dim,
-                "state_layers": n_lin,
+                "state_layers": n_lin, "scan_chunk": lightning.CHUNK,
                 "state_rows": {"S": (n_lin, (
                     self.lightning_heads, self.lightning_head_dim,
                     self.lightning_head_dim), jnp.float32)},

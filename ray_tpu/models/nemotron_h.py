@@ -186,6 +186,7 @@ class NemotronHConfig:
         n, taps, pack = self.count("M"), self.conv_width - 1, self.state_pack
         return {"n_layers": self.count("*"), "n_kv_heads": self.n_kv_heads,
                 "head_dim": self.head_dim, "state_layers": n,
+                "scan_chunk": lightning.CHUNK,
                 "state_rows": {
                     "S": (n, (self.ssm_heads // pack, self.ssm_state,
                               pack * self.ssm_head_dim), jnp.float32),
@@ -485,9 +486,12 @@ def trunk(params, tokens, cfg: NemotronHConfig, pinned=None):
             (cfg.conv_width - 1, xbc.shape[-1]), xbc.dtype), bias, CONV_PART)
         q, k, v, g, skip = gates(y)
         with jax.named_scope(cfg.state_part):
+            # (zeros laid out as a slot's rows are, ``cache_layout``: the
+            # chunked scan takes narrow heads packed side by side alone)
+            pack = cfg.state_pack
             o, _ = lightning.chunked(q, k, v, g, jnp.zeros(
-                (cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
-                jnp.float32))
+                (cfg.ssm_heads // pack, cfg.ssm_state,
+                 pack * cfg.ssm_head_dim), jnp.float32))
             return o + skip, (cache[0], None)
 
     return walk(cfg, cfg.serving_layout(params)["layers"],

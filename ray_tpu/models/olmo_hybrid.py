@@ -161,6 +161,7 @@ class OlmoHybridConfig:
                 "n_kv_heads": -(-self.n_kv_heads // 8) * 8,
                 "head_dim": self.head_dim,
                 "state_layers": self.n_periods * self.lin_per_period,
+                "scan_chunk": gated_delta.CHUNK,
                 "state_rows": self.state_rows()}
 
     @staticmethod

@@ -39,8 +39,16 @@ hands its state back as S0 came.
 
 ``recurrent``: the definition, a token at a time (the tests' yardstick).
 ``chunked``: a whole (padded) sequence from an initial state S0, for
-prefill and for a later chunk of a prompt, in plain ``jax.numpy``; state
-products at ``highest`` precision, the state float32.
+prefill and for a later chunk of a prompt, ONE Pallas kernel: a grid over
+(block of state rows, chunk of the sequence), the chunks in order; the
+block's state stays in VMEM from the first chunk to the last and a chunk's
+scores, decays and products exist nowhere else; every product at
+``highest`` precision on float32 operands (Mosaic makes six passes of it),
+the state float32.  One kernel for a head its own key, keys a group and
+packed rows, told apart by the shapes it is handed.
+``chunked_plain``: the same by chunks in plain ``jax.numpy`` (what
+``chunked`` was, and its second yardstick): every chunk's scores and writes
+for the whole sequence as float32 arrays in HBM, then a ``lax.scan``.
 ``decode_update``: one token a slot, a Pallas kernel on the pattern of
 ``gated_delta.decode_update``: a live slot's state is read once and written
 once where it lies (aliased to the output), another slot's not at all.  A
@@ -58,7 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CHUNK = 64
+CHUNK = 128
+LANES = 128  # a tile's
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -132,13 +141,13 @@ def recurrent(q, k, v, g, S0):
     return o.reshape(L, H, dv), S.reshape(H, *S.shape[2:])
 
 
-def chunked(q, k, v, g, S0, chunk: int = CHUNK):
-    """The same as ``recurrent`` by chunks of ``chunk`` tokens (L is padded
-    to a multiple of it with tokens that change nothing).  Returns
-    (o [L, H, d_v] float32, S_L [H, d_k, d_v] float32).  Heads that share a
-    key run the one scan side by side over the group's key and query: its
-    scores are made once a group, each head's decays laid over them.  S0
-    may come PACKED (``pack_state``); S_L then goes back packed the same."""
+def chunked_plain(q, k, v, g, S0, chunk: int = CHUNK):
+    """``chunked`` in plain ``jax.numpy`` (the kernel's yardstick beside
+    ``recurrent``; what ``chunked`` was before it was a kernel): a chunk's
+    scores, ``inside`` and ``wrote`` made for the WHOLE sequence at once,
+    float32 [n, H, ...] arrays in HBM, and a ``lax.scan`` over them.  Heads
+    that share a key run the one scan side by side over the group's key and
+    query."""
     L, H, dv = v.shape
     G = q.shape[1]
     pack = _pack_of(S0.shape, H, q.shape[2], dv)
@@ -153,7 +162,7 @@ def chunked(q, k, v, g, S0, chunk: int = CHUNK):
 
 
 def _chunked(q, k, v, g, S0, chunk: int):
-    """``chunked`` with a key and a query for every head of v."""
+    """``chunked_plain`` with a key and a query for every head of v."""
     f32 = jnp.float32
     L, H, _ = q.shape
     n = -(-L // chunk)
@@ -184,6 +193,238 @@ def _chunked(q, k, v, g, S0, chunk: int):
 
     S, o = jax.lax.scan(step, S0.astype(f32), (q_in, inside, wrote, decay))
     return jnp.moveaxis(o, 1, 2).reshape(n * chunk, H, -1)[:L], S
+
+
+# ---------------------------------------------------------------------------
+# prefill: a sequence's chunks in ONE kernel, the state in VMEM between them
+
+
+# What sets a block of the scan.  A grid step holds ``rows`` state rows
+# [d_k, lanes] (a head's, or ``pack`` narrow heads' side by side) through
+# every chunk of the sequence: S0's block in and S_L's block out, each
+# double-buffered, are the VMEM that grows with it, beside a chunk's q, k, v,
+# o and decays.  More rows a step are fewer steps (0.3-0.4 us each whatever
+# they hold, PR 58) and a longer unrolled body; the budget keeps the kernel
+# under the 16 MiB a kernel gets unasked, so that it takes nothing from what
+# XLA keeps in VMEM around it.
+SCAN_BLOCKS_BYTES = 8 * 1024 * 1024
+
+
+def _whole_keys(n: int, keys: int) -> list:
+    """The divisors of ``n`` heads (or state rows) that are heads of ONE key
+    or every head of several (n / keys read a key)."""
+    share = n // keys
+    return [d for d in range(1, n + 1)
+            if n % d == 0 and (share % d == 0 or d % share == 0)]
+
+
+def _rows_a_block(rows: int, keys: int, dk: int, lanes: int, chunk: int,
+                  ) -> int:
+    """State rows [d_k, lanes] float32 the scan holds a grid step: a divisor
+    of ``rows`` that is rows of ONE key or every row of several (rows /
+    keys read a key) and whole sublane tiles of heads (8, or all there
+    are), the most whose buffers fit ``SCAN_BLOCKS_BYTES``."""
+    share = rows // keys
+
+    def buffers(d):  # the state in and out, v and o, the keys and queries
+        return 4 * (_STATE_BUFFERS * d * dk * lanes + 4 * d * chunk * lanes
+                    + 4 * max(d // share, 1) * chunk * dk)
+
+    allowed = [d for d in _whole_keys(rows, keys)
+               if d % 8 == 0 or d == rows]
+    return max([d for d in allowed if buffers(d) <= SCAN_BLOCKS_BYTES],
+               default=allowed[0])
+
+
+def _scan_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, ge_ref, s0_ref, o_ref,
+                 s_ref, *, pack: int):
+    f32 = jnp.float32
+    C = q_ref.shape[0]
+    hb, dk, lanes = s_ref.shape
+    kb = q_ref.shape[1] // dk  # the block's keys: one a row, or one for all
+    dv = lanes // pack
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                                   preferred_element_type=f32)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    # a row's ``pack`` heads lie side by side: in the lanes of its values
+    # (d_v each) and, for what goes on inside the chunk, in the columns of
+    # its scores (C each)
+    def runs(shape, axis, width):  # where ``axis`` is in run u of ``width``
+        at = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        return [(at >= u * width) & (at < (u + 1) * width)
+                for u in range(pack)]
+
+    of_lane = runs((C, lanes), 1, dv)  # the head a value's lane is of
+    of_col = runs((C, pack * C), 1, C)  # the head a score's column is of
+    t, i = (jax.lax.broadcasted_iota(jnp.int32, (C, pack * C), axis)
+            for axis in range(2))
+    lower = functools.reduce(jnp.logical_or, (
+        of_col[u] & (i - u * C <= t) for u in range(pack)))
+    # each head's values against its own columns alone
+    own = functools.reduce(jnp.logical_or, (
+        rows & cols for rows, cols in zip(runs((pack * C, lanes), 0, C),
+                                          runs((pack * C, lanes), 1, dv))))
+
+    def by_head(cols, of):  # [C, pack] a head a column -> a head's a lane
+        out = jnp.broadcast_to(cols[:, :1], of[0].shape)
+        for u in range(1, pack):
+            out = jnp.where(of[u], cols[:, u:u + 1], out)
+        return out
+
+    gam = gc_ref[0, 0]  # [C, heads] log of the running decay, a column a head
+    seen = jnp.exp(gam)  # what of S_0 a query still sees
+    left = jnp.exp(gam[C - 1:C] - gam)  # what of a write is left at the end
+    scored = None  # the key whose scores ``scores`` holds
+    for p in range(hb):
+        c = p * kb // hb
+        q = q_ref[:, c * dk:(c + 1) * dk].astype(f32)
+        k = k_ref[:, c * dk:(c + 1) * dk].astype(f32)
+        if c != scored:  # a key's scores are made once, [C, pack * C]
+            scored, scores = c, dot(q, jnp.concatenate([k] * pack, axis=0),
+                                    ((1,), (1,)))
+        at = slice(p * pack, (p + 1) * pack)
+        # ratio[t, i] = G_t / G_i where i <= t, as exp of a difference <= 0
+        diff = by_head(gam[:, at], of_col) - gr_ref[0, 0, p:p + 1, :]
+        ratio = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+        # the row's heads' values side by side, as its lanes hold them
+        if v_ref.ndim == 3:  # [C, heads, d_v]
+            v = jnp.concatenate([v_ref[:, h, :].astype(f32)
+                                 for h in range(p * pack, (p + 1) * pack)],
+                                axis=1)
+        else:  # [heads x d_v, C]: the tokens in the lanes
+            v = v_ref[p * lanes:(p + 1) * lanes, :].astype(f32).T
+        mine = jnp.concatenate([v] * pack, axis=0)
+        if pack > 1:
+            mine = jnp.where(own, mine, 0.0)
+        S = s_ref[p]
+        o = (dot(scores * ratio, mine, ((1,), (0,)))
+             + dot(q, S, ((1,), (0,))) * by_head(seen[:, at], of_lane))
+        if o_ref.ndim == 3:
+            for u in range(pack):
+                o_ref[:, p * pack + u, :] = o[:, u * dv:(u + 1) * dv]
+        else:
+            o_ref[p * lanes:(p + 1) * lanes, :] = o.T
+        s_ref[p] = (S * jnp.exp(ge_ref[0, :, p * lanes:(p + 1) * lanes])
+                    + dot(k, v * by_head(left[:, at], of_lane),
+                          ((0,), (0,))))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "rows", "interpret"))
+def _scan(q, k, v, g, S0, *, chunk: int, rows: int, interpret: bool):
+    f32 = jnp.float32
+    L, G, dk = q.shape
+    H, dv = v.shape[1:]
+    n_rows, _, lanes = S0.shape  # H heads of d_v lanes, or H / pack of pack
+    pack = lanes // dv
+    C = chunk
+    n = -(-L // C)
+    n_blocks = n_rows // rows
+    # keys a block reads: a row's own (G = H), or the one its rows share
+    kb = rows * G // n_rows or 1
+    per = n_blocks // (G // kb)  # row blocks that read each block of keys
+
+    def padded(x):  # with tokens that change nothing: g = 0, a zero key
+        return jnp.pad(x, ((0, n * C - L),) + ((0, 0),) * (x.ndim - 1))
+
+    q, k, v = (padded(x) for x in (q, k, v))
+    q, k = (x.reshape(n * C, -1) for x in (q, k))
+    # v and o are moved as XLA holds them, so that no pass over them stands
+    # beside the kernel: heads as wide as the lanes a block of [L, H, d_v];
+    # narrower ones (d_v 64) with the TOKENS in the lanes, [H x d_v, L],
+    # which is how XLA lays such an array out wherever a program makes or
+    # reads one (compiled for a v5e: f32[2048,128,64]{0,2,1}; laid out again
+    # as [L, H x d_v] they cost a copy of 64 MB a layer each way)
+    wide = dv % LANES == 0
+    if wide:
+        values = pl.BlockSpec((C, rows * pack, dv), lambda j, c: (c, j, 0))
+        o_shape = (n * C, H, dv)
+    else:
+        v = jnp.moveaxis(v, 0, 2).reshape(H * dv, n * C)
+        values = pl.BlockSpec((rows * lanes, C), lambda j, c: (j, c))
+        o_shape = (H * dv, n * C)
+    # the log of the running decay INSIDE a chunk (H stays in the lanes for
+    # the sum: an array whose last axis is ``pack`` is padded 64-fold), then
+    # a column a head for what goes down the chunk, a row a state row for
+    # what goes along it
+    gam = jnp.cumsum(padded(g.astype(f32)).reshape(n, C, H), axis=1)
+    gc = jnp.swapaxes(gam.reshape(n, C, n_blocks, rows * pack), 1, 2)
+    gr = jnp.swapaxes(gam, 1, 2).reshape(n, n_blocks, rows, pack * C)
+    # and of a whole chunk, a lane (a [1, 1] decay would have to be spread
+    # over sublanes and lanes at once, which Mosaic does not do)
+    ge = jnp.repeat(gam[:, -1].reshape(n, 1, H), dv, axis=-1)
+    width = rows * lanes
+    o, S = pl.pallas_call(
+        functools.partial(_scan_kernel, pack=pack),
+        grid=(n_blocks, n),
+        in_specs=[
+            pl.BlockSpec((C, kb * dk), lambda j, c: (c, j // per)),
+            pl.BlockSpec((C, kb * dk), lambda j, c: (c, j // per)),
+            values,
+            pl.BlockSpec((1, 1, C, rows * pack), lambda j, c: (c, j, 0, 0)),
+            pl.BlockSpec((1, 1, rows, pack * C), lambda j, c: (c, j, 0, 0)),
+            pl.BlockSpec((1, 1, width), lambda j, c: (c, 0, j)),
+            pl.BlockSpec((rows, dk, lanes), lambda j, c: (j, 0, 0)),
+        ],
+        out_specs=[
+            values,
+            # the same block through a sequence's chunks: the state stays
+            # in VMEM between them and is written back once
+            pl.BlockSpec((rows, dk, lanes), lambda j, c: (j, 0, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(o_shape, f32),
+                   jax.ShapeDtypeStruct(S0.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="lightning_scan",
+    )(q, k, v, gc, gr, ge, S0.astype(f32))
+    if not wide:
+        o = jnp.moveaxis(o.reshape(H, dv, n * C), 2, 0)
+    return o[:L], S
+
+
+def chunked(q, k, v, g, S0, chunk: int = CHUNK):
+    """The same as ``recurrent`` by chunks of ``chunk`` tokens (L is padded
+    to a multiple of it with tokens that change nothing), in ONE Pallas
+    kernel: a grid over (block of state rows, chunk), the chunk axis in
+    order; the block's state stays in VMEM from the first chunk to the last
+    (read from S0 once, written to S_L once), and a chunk's scores, decays
+    and products exist nowhere else.  Returns (o [L, H, d_v] float32, S_L
+    float32 laid out as S0 came: [H, d_k, d_v], or PACKED, ``pack_state``).
+    A key's scores are made once and each head's decays laid over them;
+    heads packed side by side are one product over the row's lanes, each
+    lane under its own head's decay."""
+    L, H, dv = v.shape
+    G, dk = q.shape[1:]
+    if H % G:
+        raise ValueError(
+            f"{H} heads are no whole number of heads for each of the {G} "
+            f"groups the keys and queries come in")
+    pack = _pack_of(S0.shape, H, dk, dv)
+    if (H // G) % pack:
+        raise ValueError(
+            f"state rows {tuple(S0.shape)} hold heads of different keys "
+            f"side by side ({H // G} heads read a key)")
+    lanes = pack * dv
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and (lanes % LANES or dk % LANES
+                   or chunk % (16 if dv % LANES == 0 else LANES)):
+        raise ValueError(
+            f"on the TPU the chunked scan moves whole tiles, and chunks of "
+            f"{chunk} keys [{dk}] over rows [{dk}, {lanes}] are not made "
+            f"of them: d_k and the lanes a head's rows take (d_v, or the "
+            f"d_v of the heads packed side by side) must be multiples of "
+            f"{LANES}, the chunk of 16 (of {LANES} where the heads are "
+            f"narrower than the lanes: their tokens lie in them)")
+    return _scan(q, k, v, g, S0, chunk=chunk,
+                 rows=_rows_a_block(H // pack, G, dk, lanes, chunk),
+                 interpret=not on_tpu)
 
 
 # ---------------------------------------------------------------------------
@@ -219,11 +460,9 @@ def _heads_a_block(H: int, G: int, dk: int, dv: int) -> int:
     """Heads of [d_k, d_v] float32 the update moves a grid step: a divisor
     of H that is heads of ONE key or every head of several (H / G heads
     read a key), the most whose buffers fit ``STATE_BLOCKS_BYTES``."""
-    share = H // G
-    return max(d for d in range(1, H + 1)
-               if H % d == 0 and (share % d == 0 or d % share == 0)
-               and (_STATE_BUFFERS * d * dk * dv * 4 <= STATE_BLOCKS_BYTES
-                    or d == 1))
+    return max(d for d in _whole_keys(H, G)
+               if _STATE_BUFFERS * d * dk * dv * 4 <= STATE_BLOCKS_BYTES
+               or d == 1)
 
 
 def _decode_kernel(layer_ref, order_ref, live_ref, kq_ref, vec_ref, s_ref,

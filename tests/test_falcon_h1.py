@@ -263,7 +263,7 @@ def _parent_chunked(q, k, v, g, S0, chunk=lightning.CHUNK):
     return jnp.moveaxis(o, 1, 2).reshape(n * chunk, H, -1)[:L], S
 
 
-@pytest.mark.parametrize("form", ["recurrent", "chunked"])
+@pytest.mark.parametrize("form", ["recurrent", "chunked_plain"])
 def test_the_fixed_decay_case_is_bit_for_bit_the_parents(form):
     cfg = minicpm_sala.MiniCPMSALAConfig.tiny()
     H, d, L = cfg.lightning_heads, cfg.lightning_head_dim, 150
@@ -272,7 +272,8 @@ def test_the_fixed_decay_case_is_bit_for_bit_the_parents(form):
                for _ in range(3))
     g = jnp.broadcast_to(lightning.log_decays(H), (L, H))
     S0 = jnp.asarray(r.standard_normal((H, d, d)), jnp.float32)
-    parent = {"recurrent": _parent_recurrent, "chunked": _parent_chunked}
+    parent = {"recurrent": _parent_recurrent,
+              "chunked_plain": _parent_chunked}
     for got, want in zip(getattr(lightning, form)(q, k, v, g, S0),
                          parent[form](q, k, v, g, S0)):
         np.testing.assert_array_equal(got, want)
@@ -484,8 +485,10 @@ def test_slots_join_leave_and_are_reused(params):
         engine.stop()
     assert st["state_resets"] == 3
     assert st["state_slot_steps"] == 15 + 39 + 11 > st["decode_steps"]
-    # (a prefill's scan runs the bucket's chunks in every layer)
-    assert st["scan_chunks"] == (128 // 64 + 256 // 64 + 128 // 64) * 3
+    # (a prefill's scan runs the bucket's chunks in every layer: the steps
+    # the kernel's grid takes along the sequence, ``lightning.CHUNK`` each)
+    assert lightning.CHUNK == 128
+    assert st["scan_chunks"] == (128 // 128 + 256 // 128 + 128 // 128) * 3
     assert st["prefix_cache"] is None and st["prefill_tokens_saved"] == 0
     for tag, (prompt, out) in enumerate(
             [(first, out_a), (second, out_b), (third, out_c)], 1):

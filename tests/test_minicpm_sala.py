@@ -114,8 +114,13 @@ def test_pinned_program_layers_against_the_reference(params, n):
         assert -(-n // CFG.block_size) > CFG.topk
 
 
+# (a chunk's outputs are float32 sums of ``chunk`` terms in another order
+# than the recurrence's, on outputs of ~10: a chunk of 64 stays under 1e-4
+# absolute as it always has; one of 128, the file's ``CHUNK``, reads 1.3e-4
+# and is held to 2e-4, 2e-5 of the outputs' size)
+@pytest.mark.parametrize("chunk,o_tol", [(64, 1e-4), (128, 2e-4)])
 @pytest.mark.parametrize("start", [0, 1])
-def test_chunked_against_recurrent_from_a_state(start):
+def test_chunked_against_recurrent_from_a_state(start, chunk, o_tol):
     """``chunked`` (with padded positions that change nothing) and
     ``decode_update`` against ``recurrent``, from a non-zero S0."""
     L, H, d = 150, 4, 16
@@ -129,10 +134,12 @@ def test_chunked_against_recurrent_from_a_state(start):
     # (v of a padded position is never read: its key is zero)
     _, S = lightning.chunked(
         pad(q), jnp.where(real[..., None], pad(k), 0.0),
-        pad(v) + 5.0 * ~real[..., None], jnp.where(real, pad(g), 0.0), S0)
+        pad(v) + 5.0 * ~real[..., None], jnp.where(real, pad(g), 0.0), S0,
+        chunk=chunk)
     o, S2 = lightning.chunked(pad(q), jnp.where(real[..., None], pad(k), 0.0),
-                              pad(v), jnp.where(real, pad(g), 0.0), S0)
-    assert float(jnp.max(jnp.abs(o[:L] - want_o))) < 1e-4
+                              pad(v), jnp.where(real, pad(g), 0.0), S0,
+                              chunk=chunk)
+    assert float(jnp.max(jnp.abs(o[:L] - want_o))) < o_tol
     assert float(jnp.max(jnp.abs(S2 - want_S))) < 1e-4
     assert float(jnp.max(jnp.abs(S - want_S))) < 1e-4
     # the decode form, two slots of which one is live, layer 1 of 2

@@ -561,6 +561,7 @@ CACHE_LAYOUTS = {
                                 "head_dim": 128},
     "olmo_hybrid7b_serve_1chip": {
         "n_layers": 1, "n_kv_heads": 32, "head_dim": 128, "state_layers": 3,
+        "scan_chunk": 64,
         "state_rows": {"S": (3, (15, 96, 384), jnp.float32),
                        "conv": (9, (11520,), jnp.dtype("bfloat16"))}},
     "glm47_flash_serve_1chip": {"n_layers": 4, "latent_dim": 640},

@@ -247,7 +247,8 @@ def test_a_model_declares_what_it_caches():
     hybrid = lm.cache_layout(olmo_hybrid.OlmoHybridConfig(n_layers=16))
     rows = hybrid.pop("state_rows")
     assert hybrid == {"n_layers": 4, "n_kv_heads": 32, "head_dim": 128,
-                      "state_layers": 12}
+                      "state_layers": 12,
+                      "scan_chunk": gated_delta.CHUNK}  # what scan_chunks counts
     assert rows["S"] == (12, (15, 96, 384), jnp.float32)
     assert rows["conv"] == (36, (11520,), jnp.dtype("bfloat16"))
     dense = llama.LlamaConfig.tiny()

@@ -980,6 +980,19 @@ def test_window_layers_come_whole_or_not_at_all(wrong):
         CacheConfig(**{**WINDOWED, **wrong})
 
 
+@pytest.mark.parametrize("scan_chunk", [0, -64])
+def test_state_layers_come_with_their_scan_chunk(scan_chunk):
+    """The engine counts ``scan_chunks`` by it: a family that declares
+    state layers and forgets it is told at construction, not by a division
+    in the middle of its first prefill."""
+    rows = {"S": (2, (4, 8, 8), "float32")}
+    with pytest.raises(ValueError, match="scan_chunk"):
+        CacheConfig(n_layers=1, n_kv_heads=2, head_dim=8, state_layers=2,
+                    state_rows=rows, scan_chunk=scan_chunk)
+    assert CacheConfig(n_layers=1, n_kv_heads=2, head_dim=8, state_layers=2,
+                       state_rows=rows, scan_chunk=128).scan_chunk == 128
+
+
 def test_a_window_pool_is_allocated_like_any_other():
     """The window layers' allocator is a ``PageAllocator``: pages given
     back while a sequence lives rejoin the free list in page order BEHIND

@@ -10,6 +10,7 @@ would turn down.  Skipped where the topology cannot be described.
 """
 
 import dataclasses
+import math
 import os
 import re
 
@@ -28,7 +29,7 @@ from ray_tpu.llm.paged_cache import CacheConfig, init_state
 from ray_tpu.models import (afmoe, falcon_h1, glm_moe_lite, llama,
                             longcat_flash, minicpm_sala,
                             olmo_hybrid, sdar_moe)
-from ray_tpu.ops import attention, block_sparse
+from ray_tpu.ops import attention, block_sparse, lightning
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.train.step import (
     default_optimizer,
@@ -421,11 +422,37 @@ def test_hybrid_programs_compile_at_olmo_widths(topo, as_tpu, program):
     assert abs(planned / 1e9 - HYBRID_PLANNED_GB[program]) < 0.05
 
 
+# the chunked scan's kernel by its name in a program's text (NOT the path of
+# tests/test_lightning_scan.py, which the text's table of file names holds
+# wherever a worker traced a shared helper in that file first)
+_SCAN_KERNEL = re.compile(r"lightning_scan(?!\.py)")
+
+
+def _assert_the_scan_is_one_kernel(text, tokens, heads, dk, dv):
+    """A prefill's decay-only recurrence is ``ops/lightning.py``'s kernel
+    and NOT the plain form beside it: the plain form wrote what every chunk
+    writes of every head, float32 [chunks, heads, d_k, d_v] (67 MB a layer
+    at the served buckets), to HBM and scanned over it."""
+    assert _SCAN_KERNEL.search(text)
+    chunks = -(-tokens // lightning.CHUNK)
+    if chunks < 4:  # (a slot's own rows are [1, heads, d_k, d_v])
+        return
+    held = [m.group(0) for m in re.finditer(r"= f32\[([0-9,]+)\]", text)
+            if (dims := tuple(map(int, m.group(1).split(","))))[-2:]
+            == (dk, dv) and len(dims) > 3
+            and math.prod(dims) == chunks * heads * dk * dv]
+    assert not held, held
+
+
 # planned bytes a program of configuration ``falcon_h1_34b_serve_1chip``,
 # compiled for the described v5e here (PERF.md section 4): weights 10.51 GB,
 # both pools 1.21 GB, the state rows 1.62 GB
+# (PR 63: the prefills' chunked scan a kernel: at 1,024 tokens 13.496 ->
+# 13.439 and 13.510 -> 13.411, the plain form's [chunks, heads, 256, 128]
+# float32 arrays gone; the later chunk reads its slot's convolution rows
+# out before the walk)
 SSM_PLANNED_GB = {"decode_step_greedy": 13.341, 64: 13.367, 256: 13.368,
-                  1024: 13.496, "chunk_1024": 13.510}
+                  1024: 13.439, "chunk_1024": 13.411}
 
 
 @pytest.mark.parametrize("program", ["decode_step_greedy", 64, 256, 1024,
@@ -474,6 +501,11 @@ def test_parallel_ssm_programs_compile_at_falcon_h1_widths(topo, as_tpu,
             params, i32(program), cache, cache, i32(program), i32(),
             i32(program), cfg, state, i32()).compile()
         text = compiled.as_text()
+    if program != "decode_step_greedy":
+        _assert_the_scan_is_one_kernel(
+            text, 1024 if program == "chunk_1024" else program, 32, 256, 128)
+    else:
+        assert not _SCAN_KERNEL.search(text)
     pools = 2 * 6 * 6144 * 16 * 4 * 128 * 2
     rows = 6 * 64 * 32 * 256 * 128 * 4 + 18 * 64 * 5120 * 2
     m = compiled.memory_analysis()
@@ -796,8 +828,10 @@ def test_the_chained_step_compiles_as_its_step_does(
 # 2, 128] bf16 twice = 26 MB a layer, where the loop gathered 512 positions
 # a turn, and the turn's [2, 16, 2,048, 512] float32 scores (134 MB) were
 # never what set a prefill's peak (the MLP's [2,048, 16,384] products are)
-SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.839, 2048: 8.114,
-                            "prefix_256": 7.863, "prefix_2048": 8.112}
+# (PR 63: the linear layers' chunked scan a kernel: 8.114 -> 7.977 and
+# 8.112 -> 7.941 at 2,048 tokens)
+SPARSE_LINEAR_PLANNED_GB = {"decode_step_greedy": 7.839, 2048: 7.977,
+                            "prefix_256": 7.863, "prefix_2048": 7.941}
 
 
 @pytest.mark.parametrize("program", ["decode_step_greedy", 2048,
@@ -861,8 +895,11 @@ def test_sparse_linear_programs_compile_at_minicpm_sala_widths(topo, as_tpu,
     if program == "decode_step_greedy":
         assert "lightning_update" in text
         assert "paged_decode_attention" in text
+        assert not _SCAN_KERNEL.search(text)
     else:
         assert "sparse_prefill_attention" in text
+        _assert_the_scan_is_one_kernel(
+            text, program if isinstance(program, int) else L, 32, 128, 128)
     blocks = 1600 * 16 // cfg.block_size
     assert blocks == 400
     assert not re.findall(rf"\w+\[(?:\d+,)*{blocks},{blocks}\]", text)
@@ -1087,6 +1124,49 @@ def test_fixed_decay_update_kernel_compiles_and_writes_in_place(
     assert m.alias_size_in_bytes >= 6 * slots * heads * dk * 128 * 4
     assert m.temp_size_in_bytes < 16e6
     assert "lightning_update" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens,heads,dv,keys,dk,pack,dtype", [
+    # nemotron3_super_120b_serve_1chip: the slot's rows, two heads a row
+    (1024, 128, 64, 8, 128, 2, jnp.float32),
+    # the same family's plain forward (the benchmark's pinned logits run
+    # it on the chip, from zeros laid out as a slot's rows are)
+    (1536, 128, 64, 8, 128, 2, jnp.float32),
+    (2048, 32, 128, 32, 128, 1, jnp.bfloat16),  # minicpm_sala_serve_1chip
+    (256, 32, 128, 2, 256, 1, jnp.float32),     # falcon_h1_34b_serve_1chip
+    (1024, 32, 128, 2, 256, 1, jnp.float32),
+])
+def test_chunked_scan_kernel_compiles_at_the_cells_shapes(
+        topo, as_tpu, tokens, heads, dv, keys, dk, pack, dtype):
+    """``ops/lightning.chunked`` alone at what the three cells' prefills
+    (and a family's plain forward) hand it: ONE ``lightning_scan`` kernel in
+    the VMEM a kernel gets unasked, the state handed back as it came, and
+    nothing of [chunks, heads, d_k, d_v] planned beside it."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda dt, *shape: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    f32 = jnp.float32
+    args = (sds(dtype, tokens, keys, dk), sds(dtype, tokens, keys, dk),
+            sds(dtype, tokens, heads, dv), sds(f32, tokens, heads),
+            sds(f32, heads // pack, dk, pack * dv))
+    compiled = jax.jit(lightning.chunked).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    _assert_the_scan_is_one_kernel(text, tokens, heads, dk, dv)
+    o, state = jax.eval_shape(lightning.chunked, *args)
+    assert o.shape == (tokens, heads, dv) and state.shape == args[-1].shape
+    # q, k, v and o laid out for the kernel, the decays' sums: no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * (
+        tokens * heads * dv * 4) + 16e6
+
+
+def test_chunked_scan_refuses_narrow_heads_a_head_a_row_on_the_chip(as_tpu):
+    """Rows HALF a lane tile wide are no whole tiles: the scan takes such
+    heads packed side by side (``pack_state``) and says so otherwise, where
+    the interpreter would take them (a CPU test could not see it)."""
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)  # noqa: E731
+    with pytest.raises(ValueError, match="whole tiles"):
+        jax.eval_shape(lightning.chunked, z(256, 8, 128), z(256, 8, 128),
+                       z(256, 128, 64), z(256, 128), z(128, 128, 64))
 
 
 @pytest.mark.parametrize("n_heads,n_kv_heads,head_dim", [

@@ -6,6 +6,9 @@ program plans of the chip's memory is read off the plan.  In a file of its
 own: ``tests/test_tpu_compile.py`` already fills one worker's queue under
 ``--dist loadfile``."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -16,13 +19,20 @@ from benchmarks import common
 from benchmarks.families import nemotron_h as family
 from ray_tpu.llm import model as lm
 from ray_tpu.models import nemotron_h
+from ray_tpu.ops import lightning
 
+# (the kernel's name, not the path of tests/test_lightning_scan.py in the
+# text's table of file names)
+_SCAN_KERNEL = re.compile(r"lightning_scan(?!\.py)")
 V5E_BYTES_LIMIT = 16.91e9  # memory_stats()["bytes_limit"] on the chip
 CONFIG = "nemotron3_super_120b_serve_1chip"
 # planned bytes a program of the configuration, compiled for the described
 # v5e here (PERF.md section 4): weights 9.30 GB, the state rows 1.36 GB,
 # both pools 0.27 GB
-PLANNED_GB = {"decode_step_greedy": 10.941, 2048: 11.720}
+# (PR 63: the mixers' chunked scan a kernel: ``prefill`` at 2,048 11.720 ->
+# 11.282, a later chunk 11.418)
+PLANNED_GB = {"decode_step_greedy": 10.941, 2048: 11.282,
+              "chunk_2048": 11.418}
 
 
 @pytest.fixture(scope="module")
@@ -53,9 +63,9 @@ def as_tpu(monkeypatch):
 @pytest.mark.parametrize("program", list(PLANNED_GB))
 def test_programs_compile_at_nemotron3_super_widths(topo, as_tpu, program):
     """``decode_step_greedy`` (64 slots, 192-page tables) and the largest
-    prefill bucket (a later CHUNK of a prompt over it, ``prefill_with_prefix``
-    from the slot's packed state and convolution rows, planned 11.72 GB when
-    PR 61 compiled it; it runs in no cell and is not compiled here), at the
+    prefill bucket, and a later CHUNK of a prompt over it
+    (``prefill_with_prefix`` from the slot's packed state and convolution
+    rows; it runs in no cell), at the
     cell's 16,384 pages (ONE pool layer of 2-head pages) and
     64 slots' state rows over 5 mixer layers: each plans at or under the
     configuration's ``memory_headroom`` of the chip's bytes_limit; pools
@@ -96,11 +106,27 @@ def test_programs_compile_at_nemotron3_super_widths(topo, as_tpu, program):
         for kernel in ("lightning_update", "paged_decode_attention",
                        "moe_grouped_mlp"):
             assert kernel in text
+        assert not _SCAN_KERNEL.search(text)
     else:
-        compiled = lm.prefill.lower(
-            params, i32(program), cache, cache, i32(program), i32(),
-            i32(program), cfg, state, i32()).compile()
+        if program == "chunk_2048":  # a later chunk of a prompt over 2,048
+            compiled = lm.prefill_with_prefix.lower(
+                params, i32(2048), cache, cache, i32(2048), i32(), i32(2048),
+                i32(table), i32(2048), cfg, state, i32()).compile()
+        else:
+            compiled = lm.prefill.lower(
+                params, i32(program), cache, cache, i32(program), i32(),
+                i32(program), cfg, state, i32()).compile()
         text = compiled.as_text()
+        # the recurrence is ``ops/lightning.py``'s kernel over the PACKED
+        # rows, and no float32 [chunks, heads, d_state, d_head] of the plain
+        # form beside it (67 MB a layer a thousand tokens)
+        assert _SCAN_KERNEL.search(text)
+        chunks = 2048 // lightning.CHUNK
+        assert not [m.group(0) for m in re.finditer(r"= f32\[([0-9,]+)\]",
+                                                    text)
+                    if (dims := tuple(map(int, m.group(1).split(","))))[-2:]
+                    == (128, 64) and len(dims) > 3
+                    and math.prod(dims) == chunks * 128 * 128 * 64]
     resident = c["resident_bytes"]
     pools = 2 * 16384 * 16 * 2 * 128 * 2
     rows = 5 * 64 * 64 * 128 * 128 * 4 + 15 * 64 * 10240 * 2
